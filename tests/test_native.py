@@ -149,3 +149,40 @@ def test_factorize_native_and_fallback_agree(monkeypatch):
     # decode both: identical value streams regardless of unique order
     assert [u1[i] for i in inv1] == [u2[i] for i in inv2]
     np.testing.assert_array_equal(nm1, nm2)
+
+
+class TestBuildStaleness:
+    """native/build.py trusts a binary by the digest of its sources and
+    compile command, never by mtime (a copied tree scrambles mtimes)."""
+
+    def test_rebuilds_when_source_content_changes_under_same_mtime(
+            self, tmp_path):
+        import ctypes
+        import importlib
+        import os
+
+        # (the package re-exports the build() function under this name)
+        B = importlib.import_module("transmogrifai_tpu.native.build")
+
+        src = tmp_path / "k.cpp"
+        lib = str(tmp_path / "k.so")
+        src.write_text('extern "C" int answer() { return 1; }\n')
+        mtime = os.stat(src).st_mtime_ns
+        assert B._build(lib, [str(src)], B._FLAGS, False) == lib
+        lib_mtime = os.stat(lib).st_mtime_ns
+        # unchanged content: trusted even though the binary looks OLDER
+        # than its source
+        os.utime(lib, ns=(0, 0))
+        assert B._build(lib, [str(src)], B._FLAGS, False) == lib
+        assert os.stat(lib).st_mtime_ns == 0
+        # changed content, mtime restored to the original: must rebuild
+        src.write_text('extern "C" int answer() { return 2; }\n')
+        os.utime(src, ns=(mtime, mtime))
+        os.utime(lib, ns=(lib_mtime + 10 ** 12, lib_mtime + 10 ** 12))
+        assert B._build(lib, [str(src)], B._FLAGS, False) == lib
+        assert ctypes.CDLL(lib).answer() == 2
+        # a binary without its stamp is not trusted either
+        os.unlink(lib + ".sha256")
+        before = os.stat(lib).st_mtime_ns
+        assert B._build(lib, [str(src)], B._FLAGS, False) == lib
+        assert os.stat(lib).st_mtime_ns != before

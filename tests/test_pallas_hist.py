@@ -105,17 +105,11 @@ class TestBinnedLanes:
             assert np.allclose(np.asarray(tps[l]), np.asarray(t1), atol=1e-3)
             assert np.allclose(np.asarray(fps[l]), np.asarray(f1), atol=1e-3)
 
-    def test_pallas_route_matches_scatter(self, monkeypatch):
-        import functools
+    def test_pallas_route_matches_scatter(self):
         from transmogrifai_tpu.ops import metrics_ops as M
-        monkeypatch.setattr(M.jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(PH, "available", lambda: True)
-        monkeypatch.setattr(PH, "hist_pallas",
-                            functools.partial(PH.hist_pallas,
-                                              interpret=True))
         scores, y, w = self._lanes(L=4, n=1100)  # forces tail padding
-        tps, fps = M.binned_cum_counts_lanes(scores, y, w, 128)
-        monkeypatch.undo()
+        tps, fps = M._binned_cum_counts_lanes_pallas(scores, y, w, 128,
+                                                     interpret=True)
         for l in range(scores.shape[0]):
             t1, f1 = M._binned_cum_counts(scores[l], y, w[l], 128)
             assert np.allclose(np.asarray(tps[l]), np.asarray(t1), atol=1e-3)

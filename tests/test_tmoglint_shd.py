@@ -741,8 +741,7 @@ class TestENV001:
 
     def test_doc_mention_is_boundary_aware(self, tmp_path):
         """A knob that is a PREFIX of a documented knob must not pass
-        on the longer name's mentions (the TMOG_COMPILE_CACHE /
-        TMOG_COMPILE_CACHE_DIR case)."""
+        on the longer name's mentions (a TMOG_X / TMOG_X_DIR pair)."""
         out = lint_tree(tmp_path, {
             "docs/perf.md": "Set `TMOG_CACHE_DIR` to a directory.",
             "knobs.py": """

@@ -30,12 +30,10 @@ from typing import Dict, List
 
 KNOBS: List[Dict[str, str]] = [
     # -- compile cache / platform -------------------------------------------
-    {"name": "TMOG_COMPILE_CACHE_DIR", "default": "~/.cache (auto)",
+    {"name": "TMOG_COMPILE_CACHE_DIR", "default": "<checkout>/.jax_cache",
      "doc": "docs/serving.md",
-     "desc": "persistent XLA compilation cache directory (0/off disables)"},
-    {"name": "TMOG_COMPILE_CACHE", "default": "",
-     "doc": "docs/developer-guide.md",
-     "desc": "legacy spelling of TMOG_COMPILE_CACHE_DIR, still honored"},
+     "desc": "persistent XLA compilation cache directory (0/off disables; "
+             "a set JAX_COMPILATION_CACHE_DIR wins)"},
     {"name": "TMOG_DISABLE_NATIVE", "default": "",
      "doc": "docs/developer-guide.md",
      "desc": "skip the native C++ kernel build, use numpy fallbacks"},

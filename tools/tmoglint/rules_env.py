@@ -173,8 +173,8 @@ def check_env001(ctxs: Sequence[LintContext]) -> List[Finding]:
                     f"not exist under the lint root")
                 if f is not None:
                     findings.append(f)
-            # boundary-aware: TMOG_COMPILE_CACHE must not pass on the
-            # strength of TMOG_COMPILE_CACHE_DIR mentions
+            # boundary-aware: a knob TMOG_X must not pass on the
+            # strength of TMOG_X_DIR mentions
             elif not re.search(re.escape(name) + r"(?![A-Z0-9_])",
                                text):
                 f = ctx.finding(

@@ -498,6 +498,18 @@ class _TreeEstimator(PredictorEstimator):
             return False
         return float(kw.get("subsample", 1.0)) >= 1.0
 
+    @staticmethod
+    def _one_device(Xb) -> bool:
+        """Does the binned matrix live on a single device? The pallas
+        kernels are placed only then: under plain GSPMD a pallas_call is
+        not partitioned (each device would gather and process the whole
+        matrix), so a mesh sweep keeps the chunked XLA histograms, which
+        GSPMD does partition."""
+        try:
+            return len(Xb.sharding.device_set) <= 1
+        except AttributeError:  # host array
+            return True
+
     def _fused_route_ok(self, ctx, y, masks=None, depth=None):
         """Shared gate for the fold-fused booster path: live pallas on a
         single-device TPU above the fold-vmap row limit. Mesh-sharded
@@ -515,11 +527,8 @@ class _TreeEstimator(PredictorEstimator):
                 or not pallas_hist.available()
                 or y.shape[0] <= self._VMAP_FOLD_MAX_ROWS):
             return False
-        try:
-            if len(Xb.sharding.device_set) > 1:
-                return False
-        except AttributeError:
-            pass
+        if not self._one_device(Xb):
+            return False
         if masks is not None and depth is not None:
             # fit_gbt_folds histograms with B = n_bins + 1 slots per bin axis
             if not pallas_hist.fused_hist_fits(
@@ -606,7 +615,7 @@ class _ForestBase(_TreeEstimator):
             Xb, G, w, self._key(), depth=depth, n_bins=n_bins,
             min_instances=float(self.get_param("min_instances_per_node")),
             min_info_gain=float(self.get_param("min_info_gain")),
-            leaf_mode="mean", **cfg)
+            leaf_mode="mean", allow_pallas=self._one_device(Xb), **cfg)
         agg = T.predict_forest_bins(trees, Xb, depth)  # [n, K]
         if not self.classification:
             return agg[:, 0] / cfg["n_trees"]
@@ -841,7 +850,8 @@ class _GBTBase(_TreeEstimator):
         Xb, edges, n_bins = ctx
         kw = self._gbt_kw()
         trees, base = T.fit_gbt(Xb, y, w, self._key(), n_bins=n_bins,
-                                loss=self._loss, **kw)
+                                loss=self._loss,
+                                allow_pallas=self._one_device(Xb), **kw)
         return base + T.predict_forest_bins(trees, Xb, kw["depth"])[:, 0]
 
     def _mask_scores_fused(self, ctx, y, w, masks, n_classes, multiclass):
@@ -1065,7 +1075,8 @@ class _XGBBase(_TreeEstimator):
         if self._regression or not multiclass:
             loss = "squared" if self._regression else "logistic"
             trees, base = T.fit_gbt(Xb, y, w, self._key(), n_bins=n_bins,
-                                    loss=loss, **kw)
+                                    loss=loss,
+                                    allow_pallas=self._one_device(Xb), **kw)
             return base + T.predict_forest_bins(trees, Xb, depth)[:, 0]
         self._check_multiclass_params(True)
         soft_kw = {k: v for k, v in kw.items() if k != "base_score"}

@@ -2,13 +2,15 @@
 
 Compiles native/*.cpp (hashing.cpp text/CSV kernels + trees.cpp
 occupancy-aware tree builder) into _tmog_native.so next to this file with
-the baked-in g++ toolchain; rebuilt when any source is newer than the
-binary. Everything degrades gracefully — when no compiler is available the
-callers fall back to the NumPy/XLA paths (see ops/native_bridge.py,
-ops/trees_host.py).
+the baked-in g++ toolchain. A binary is trusted only when the digest
+stamped beside it equals the digest of the sources and the compile command
+it would be built from now — content, not mtimes, which a copy of the tree
+scrambles. When no compiler is available the callers fall back to the
+NumPy/XLA paths (see ops/native_bridge.py, ops/trees_host.py).
 """
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -29,19 +31,49 @@ def _compile(cmd) -> bool:
     return proc.returncode == 0
 
 
+def _digest(srcs, flags) -> str:
+    h = hashlib.sha256(" ".join(flags).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(lib: str, srcs, flags, force: bool) -> Optional[str]:
+    """Compile `srcs` into `lib` unless the stamp beside it already
+    matches; the new binary lands by rename, so a concurrent loader never
+    maps a half-written file."""
+    stamp = lib + ".sha256"
+    digest = _digest(srcs, flags)
+    if not force and os.path.exists(lib):
+        try:
+            with open(stamp) as f:
+                if f.read().strip() == digest:
+                    return lib
+        except OSError:
+            pass
+    tmp = f"{lib}.{os.getpid()}.tmp"
+    try:
+        if not _compile(["g++"] + flags + ["-o", tmp] + srcs):
+            return None
+        os.replace(tmp, lib)
+        with open(stamp, "w") as f:
+            f.write(digest)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return lib
+
+
+_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC"]
+
+
 def build(force: bool = False) -> Optional[str]:
     """Build (if needed) and return the library path, or None on failure."""
     srcs = [s for s in SOURCES if os.path.exists(s)]
     if not srcs:
         return None
-    if (not force and os.path.exists(LIB)
-            and all(os.path.getmtime(LIB) >= os.path.getmtime(s)
-                    for s in srcs)):
-        return LIB
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", LIB] + srcs
-    if not _compile(cmd):
-        return None
-    return LIB
+    return _build(LIB, srcs, _FLAGS, force)
 
 
 def build_pyext(force: bool = False) -> Optional[str]:
@@ -53,15 +85,8 @@ def build_pyext(force: bool = False) -> Optional[str]:
     """
     if not os.path.exists(PYEXT_SRC):
         return None
-    if (not force and os.path.exists(PYEXT_LIB)
-            and os.path.getmtime(PYEXT_LIB) >= os.path.getmtime(PYEXT_SRC)):
-        return PYEXT_LIB
     import sysconfig
     inc = sysconfig.get_paths().get("include")
     if not inc:
         return None
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-I", inc,
-           "-o", PYEXT_LIB, PYEXT_SRC]
-    if not _compile(cmd):
-        return None
-    return PYEXT_LIB
+    return _build(PYEXT_LIB, [PYEXT_SRC], _FLAGS + ["-I", inc], force)

@@ -1348,8 +1348,8 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
                       preferred_element_type=jnp.float32) + b0_f[None, :]
 
 
-# recompile-tracker fallback (utils/tracing): on jax builds without
-# jax.monitoring the tracker samples these entries' lowered-executable
+# recompile-tracker fallback (utils/tracing): with no compile listener
+# installed the tracker samples these entries' lowered-executable
 # counts at span boundaries instead of listening for compile events — the
 # sweep kernels are exactly the programs whose "bounded recompiles on the
 # bucket ladder" claim the tracer exists to verify

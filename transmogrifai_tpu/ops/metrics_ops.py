@@ -127,19 +127,26 @@ def binned_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
     one-hot contractions over VMEM tiles. CPU/fallback: vmap of the
     scatter path. Identical results.
     """
-    L, n = scores.shape
+    if jax.default_backend() == "tpu":
+        from . import pallas_hist
+        if pallas_hist.available():
+            return _binned_cum_counts_lanes_pallas(scores, labels, w_lanes,
+                                                   n_bins)
+    return _binned_cum_counts_lanes_jnp(scores, labels, w_lanes, n_bins)
 
-    def _vmapped():
-        return jax.vmap(
-            lambda s, wl: _binned_cum_counts(s, labels, wl, n_bins)
-        )(scores, w_lanes)
 
-    if jax.default_backend() != "tpu":
-        return _vmapped()
+def _binned_cum_counts_lanes_jnp(scores, labels, w_lanes, n_bins):
+    """vmap of the scatter path — the CPU route and the pallas route's
+    reference."""
+    return jax.vmap(
+        lambda s, wl: _binned_cum_counts(s, labels, wl, n_bins)
+    )(scores, w_lanes)
+
+
+def _binned_cum_counts_lanes_pallas(scores, labels, w_lanes, n_bins,
+                                    interpret: bool = False):
     from . import pallas_hist
-    if not pallas_hist.available():
-        return _vmapped()
-
+    L, n = scores.shape
     idx = _bin_idx(scores, n_bins)
     pos_w = w_lanes * labels[None, :]
     neg_w = w_lanes * (1.0 - labels[None, :])
@@ -149,8 +156,9 @@ def binned_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
     flat = lambda a: a.reshape(1, total)
     pay = jnp.concatenate([flat(pos_w), flat(neg_w)], axis=0)
     # ragged totals pad inside the kernel call (dropped-slot rows)
-    hist = pallas_hist.hist_pallas(flat(idx), pay, flat(lane),
-                                   n_slots=L, n_bins=n_bins)  # [L*2, bins]
+    hist = pallas_hist.hist_pallas(flat(idx), pay, flat(lane), n_slots=L,
+                                   n_bins=n_bins,
+                                   interpret=interpret)  # [L*2, bins]
     hist = hist.reshape(L, 2, n_bins)
     tps = jnp.cumsum(hist[:, 0, ::-1], axis=1)
     fps = jnp.cumsum(hist[:, 1, ::-1], axis=1)

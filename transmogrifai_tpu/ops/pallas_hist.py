@@ -37,28 +37,22 @@ import numpy as np
 _BLK = 4096
 
 
-def _is_v5_plus() -> bool:
-    """Device-generation probe shared by every VMEM budget: v5e+ carries
-    128MB of VMEM per core, older generations 16-32MB. False on a
-    backend that cannot report a device (budgets then stay at the
-    conservative older-generation values)."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return False
-    return any(s in kind for s in ("v5", "v6", "v7"))
+# Budgets off-TPU (interpret-mode tests): small enough to exercise the
+# tile-shrinking and gate logic, unrelated to any chip.
+_CPU_TILE_BUDGET = 4 << 20
+_CPU_VMEM_LIMIT = 12 << 20
 
 
 def _tile_budget() -> int:
-    """VMEM budget for the [cols, blk] f32 one-hot tile, by device
-    generation. On v5e+ a 16MB tile (plus the accumulator and payload
-    tiles, all much smaller) clears the compiler's headroom while
-    cutting the grid-step count 4x vs the old 4MB budget — at 10M rows
-    the per-step loop overhead and the skinny [S*C, 256] matmuls were
-    the tree sweep's real wall (8.5s warm fit, BENCH_NOTES r3). Older
-    generations keep the conservative 4MB budget known to compile
-    there."""
-    return (24 << 20) if _is_v5_plus() else (4 << 20)
+    """Size budget for the nominal [cols, blk] f32 one-hot tile: 3/16 of
+    the device's VMEM (24 MiB on v5e — 4x fewer grid steps than a 4 MiB
+    tile; at 10M rows the per-step overhead and the skinny matmuls were
+    the tree sweep's wall). Nominal: Mosaic builds the one-hot in
+    pieces and never holds it whole (a 128 MiB tile compiled on v5e,
+    PERF.md PR 21), so this sets the grid-step count, not a VMEM bound."""
+    from ..utils.platform import device_spec
+    spec = device_spec()
+    return _CPU_TILE_BUDGET if spec is None else spec.vmem_bytes * 3 // 16
 
 
 def block_rows(n_onehot_cols: int) -> int:
@@ -73,12 +67,23 @@ def block_rows(n_onehot_cols: int) -> int:
 
 
 def _vmem_limit() -> int:
-    """Usable VMEM per core, with compiler headroom held back (100 of
-    128MB on v5e+, 12 of 16MB older). The limit gates kernel forms
-    whose residents scale with problem shape (the fused fold
-    histogram's output block) — exceeding it is a Mosaic compile
-    error, not a slowdown."""
-    return (100 << 20) if _is_v5_plus() else (12 << 20)
+    """VMEM one kernel may claim: the device's physical VMEM less a
+    quarter held back for the compiler's own scratch (96 of 128 MiB on
+    v5e). ONE figure with two readers: plan_fused_hist gates kernel
+    forms whose residents scale with problem shape (the fused output
+    block) against it, and every pallas_call passes it as Mosaic's
+    `vmem_limit_bytes`, so a shape the gate admits is not refused for
+    the compiler's smaller default scoped limit. (The flagship shape
+    compiles under the default too — PERF.md, PR 21.)"""
+    from ..utils.platform import device_spec
+    spec = device_spec()
+    return _CPU_VMEM_LIMIT if spec is None else spec.vmem_bytes * 3 // 4
+
+
+def _compiler_params():
+    """Mosaic params shared by every kernel here (see _vmem_limit)."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -295,14 +300,13 @@ def set_enabled(enabled: bool) -> None:
 
 
 def available() -> bool:
-    """Pallas path usable? (enabled + TPU backend + pallas importable.)"""
+    """Pallas path usable? (enabled + TPU backend.) On the TPU backend a
+    pallas that cannot be imported raises here: a broken install must
+    not turn into the jnp twin at 10M rows."""
     if not _enabled or jax.default_backend() != "tpu":
         return False
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:
-        return False
+    from jax.experimental import pallas  # noqa: F401
+    from jax.experimental.pallas import tpu  # noqa: F401
     return True
 
 
@@ -313,8 +317,7 @@ def available() -> bool:
 # 3D intermediate and no reshape at all, a genuinely different Mosaic
 # lowering path in case the reshape form is what stalled the round-3
 # 10M-row first contact (note jnp.repeat would NOT qualify: it lowers to
-# the same broadcast+reshape). Runtime-switchable so
-# tools/tpu_staged_probe.py can try both. NOTE: the bf16 input mode
+# the same broadcast+reshape). Runtime-switchable (set_variant). NOTE: the bf16 input mode
 # always builds its one-hot with the per-feature concat form (a full-size
 # f32 one-hot next to its bf16 copy would overflow the scoped-VMEM stack,
 # and Mosaic rejects bf16 compares), so this A/B lever only
@@ -518,6 +521,7 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
             memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
             (n_folds * n_slots * Co, F * B), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(Xb_t, pay_t, slot_t)
 
@@ -608,8 +612,11 @@ def _route_kernel(xb_ref, node_ref, tbl_ref, out_ref, *, F, n_pad,
         node = node_ref[k:k + 1, :]                         # [1, blk]
         noh = (ni == node).astype(jnp.float32)              # [n_pad, blk]
         tbl = tbl_ref[3 * k:3 * k + 3, :]                   # [3, n_pad]
+        # HIGHEST: one default bf16 pass is exact only for table values
+        # below 2^8 — feature ids and 256-bin thresholds go past that
         ftm = jax.lax.dot_general(                          # [3, blk]
             tbl, noh, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         mask = (fi == ftm[0:1, :]).astype(jnp.float32)      # [F, blk]
         xsel = jnp.sum(xf * mask, axis=0, keepdims=True)    # [1, blk]
@@ -668,6 +675,7 @@ def route_pallas(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
         out_specs=pl.BlockSpec((Fo, blk), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Fo, N), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(Xb_t, node_t, tbl)
     return out[:, :n_orig]
@@ -737,8 +745,11 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, tbl_ref, hist_ref,
         node = node_ref[k:k + 1, :]                         # [1, blk]
         noh = (ni == node).astype(jnp.float32)              # [n_pad, blk]
         tbl = tbl_ref[3 * k:3 * k + 3, :]                   # [3, n_pad]
+        # HIGHEST: one default bf16 pass is exact only for table values
+        # below 2^8 — feature ids and 256-bin thresholds go past that
         ftm = jax.lax.dot_general(                          # [3, blk]
             tbl, noh, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         mask = (fi == ftm[0:1, :]).astype(jnp.float32)      # [F, blk]
         xsel = jnp.sum(xf * mask, axis=0, keepdims=True)    # [1, blk]
@@ -826,6 +837,7 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
             jax.ShapeDtypeStruct((Fo * n_nodes * Co, F * B), jnp.float32),
             jax.ShapeDtypeStruct((Fo, N), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(Xb_t, pay_t, node_t, tbl)
     return hist, node_out[:, :n_orig]
@@ -855,6 +867,15 @@ def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
             n_bins=n_bins, interpret=interpret,
             use_bf16=allow_bf16 and _HIST_BF16,
             derive_count=derive_count)
+    return _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl,
+                           n_nodes=n_nodes, n_bins=n_bins,
+                           derive_count=derive_count)
+
+
+def _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *, n_nodes,
+                    n_bins, derive_count=False):
+    """Pure-jnp twin of the fused route+hist kernel: the gather-form
+    route chained with the segment-sum histogram."""
     new_node = _route_level_jnp(Xb_t, node_t, f_lvl, t_lvl, m_lvl)
     right = new_node - 2.0 * node_t                          # 0/1
     slots = node_t + float(n_nodes) * right                  # left keeps id
@@ -871,8 +892,12 @@ def _lookup_kernel(tbl_ref, idx_ref, out_ref, *, m_pad, n_folds):
     for k in range(n_folds):
         idx = idx_ref[k:k + 1, :]                           # [1, blk]
         noh = (mi == idx).astype(jnp.float32)               # [m_pad, blk]
+        # HIGHEST: the MXU's default f32 matmul multiplies in one bf16
+        # pass, which would round every looked-up value to 8 mantissa
+        # bits (measured on v5e: 7.7e-3 absolute on unit-scale leaves)
         rows.append(jax.lax.dot_general(
             tbl_ref[k:k + 1, :], noh, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32))            # [1, blk]
     out_ref[:] = rows[0] if n_folds == 1 else \
         jnp.concatenate(rows, axis=0)
@@ -915,6 +940,7 @@ def table_lookup_pallas(tbl: jax.Array, idx_t: jax.Array, *,
         out_specs=pl.BlockSpec((Fo, blk), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Fo, N), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(tblp, idx_t)[:, :n_orig]
 
@@ -926,6 +952,11 @@ def table_lookup(tbl: jax.Array, idx_t: jax.Array, *,
     out-of-range -> 0 contract)."""
     if interpret or available():
         return table_lookup_pallas(tbl, idx_t, interpret=interpret)
+    return _table_lookup_jnp(tbl, idx_t)
+
+
+def _table_lookup_jnp(tbl: jax.Array, idx_t: jax.Array) -> jax.Array:
+    """Gather twin of table_lookup_pallas (out-of-range ids -> 0)."""
     M = tbl.shape[1]
     idx = idx_t.astype(jnp.int32)
     vals = jnp.take_along_axis(tbl, jnp.clip(idx, 0, M - 1), axis=1)

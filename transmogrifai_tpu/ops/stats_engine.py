@@ -1089,8 +1089,8 @@ def rank_matrices(X, y, w=None, *, col_block: int = 128
     return jnp.concatenate(rx_parts, 1), jnp.concatenate(ry_parts, 1)
 
 
-# recompile-tracker fallback (utils/tracing): on jax builds without
-# jax.monitoring the tracker samples these entries' lowered-executable
+# recompile-tracker fallback (utils/tracing): with no compile listener
+# installed the tracker samples these entries' lowered-executable
 # counts at span boundaries — the stats engine's "one program per shape"
 # claim is exactly what the tracer verifies
 from ..utils import tracing as _tracing  # noqa: E402

@@ -850,13 +850,13 @@ def predict_forest_bins(trees: Tree, Xb: jax.Array, depth: int) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=("n_trees", "depth", "n_bins", "leaf_mode",
-                     "feature_frac", "bootstrap"))
+                     "feature_frac", "bootstrap", "allow_pallas"))
 def fit_forest(Xb: jax.Array, G: jax.Array, H: jax.Array, key: jax.Array, *,
                n_trees: int, depth: int, n_bins: int,
                subsample: float = 1.0, feature_frac: float = 1.0,
                reg_lambda: float = 0.0, min_instances: float = 1.0,
                min_info_gain: float = 0.0, leaf_mode: str = "mean",
-               bootstrap: bool = True) -> Tree:
+               bootstrap: bool = True, allow_pallas: bool = True) -> Tree:
     """Random forest: scan of independent trees with Poisson bootstrap row
     weights (Spark's with-replacement bagging) and per-node feature subsets.
 
@@ -875,7 +875,8 @@ def fit_forest(Xb: jax.Array, G: jax.Array, H: jax.Array, key: jax.Array, *,
                          n_bins=n_bins, reg_lambda=reg_lambda,
                          min_instances=min_instances,
                          min_info_gain=min_info_gain, leaf_mode=leaf_mode,
-                         feature_frac=feature_frac, normalize_gain=True)
+                         feature_frac=feature_frac, normalize_gain=True,
+                         allow_pallas=allow_pallas)
         return None, tree
     _, trees = jax.lax.scan(one, None, jax.random.split(key, n_trees))
     return trees
@@ -896,7 +897,7 @@ def _squared_grad(pred, y, w):
     jax.jit,
     static_argnames=("n_rounds", "depth", "n_bins", "loss", "subsample",
                      "feature_frac", "alpha", "max_delta_step",
-                     "colsample_bylevel", "base_score"))
+                     "colsample_bylevel", "base_score", "allow_pallas"))
 def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
             n_rounds: int, depth: int, n_bins: int,
             learning_rate: float = 0.1, reg_lambda: float = 1.0,
@@ -905,7 +906,8 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
             subsample: float = 1.0, feature_frac: float = 1.0,
             loss: str = "logistic", alpha: float = 0.0,
             max_delta_step: float = 0.0, colsample_bylevel: float = 1.0,
-            base_score: Optional[float] = None) -> Tuple[Tree, jax.Array]:
+            base_score: Optional[float] = None,
+            allow_pallas: bool = True) -> Tuple[Tree, jax.Array]:
     """Second-order boosted trees (XGBoost `hist` equivalent, one XLA program).
 
     loss='logistic' -> binary margins; loss='squared' -> regression. Returns
@@ -914,6 +916,10 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
     (better-calibrated start); a float pins the initial margin exactly the
     XGBoost way (probability for logistic, raw value for squared —
     OpXGBoostClassifier.setBaseScore on the reference wrapper).
+    `allow_pallas=False` keeps every level on the chunked XLA histograms:
+    callers pass it when Xb is laid out over several devices — a
+    pallas_call under plain GSPMD is not partitioned, each device would
+    gather and histogram the whole matrix.
     """
     grad_fn = _logistic_grad if loss == "logistic" else _squared_grad
     wsum = w.sum() + EPS
@@ -946,6 +952,7 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
                          min_info_gain=min_info_gain, gamma=gamma,
                          leaf_mode="newton", feature_mask=fm,
                          learning_rate=learning_rate, normalize_gain=False,
+                         allow_pallas=allow_pallas,
                          alpha=alpha, max_delta_step=max_delta_step,
                          level_feature_frac=colsample_bylevel,
                          feature_mask_count=(
@@ -965,8 +972,7 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
 # padded to the worst-level slot count (2^(depth-2)) with inactive slots
 # masked, so the traced program — and its Mosaic route_hist kernel — exists
 # ONCE per fit instead of once per level. Program size and trace/compile
-# wall become O(1) in depth (the compile-knee attack; measurement harness
-# tools/tpu_fuse_compile_knee.py). =0 restores the legacy depth-unrolled
+# wall become O(1) in depth (the compile-knee attack). =0 restores the legacy depth-unrolled
 # path, which produces bit-identical trees and margins.
 _TREE_SCAN = os.environ.get("TMOG_TREE_SCAN", "").strip().lower() \
     not in ("0", "false", "off")
@@ -1569,7 +1575,7 @@ _SHARDED_FIT_CACHE: dict = {}
 
 class _ShardedJitProbe:
     """Stable register_jit_fallback entry for the sharded fit programs:
-    no-monitoring compile counting samples the LIVE cache only, and
+    listener-less compile counting samples the LIVE cache only, and
     cleared programs become unreachable (no unbounded retention across
     set_tree_scan / pallas-toggle cache clears)."""
 
@@ -1791,8 +1797,8 @@ _register_pallas_consumers()
 
 
 def _register_trace_fallback():
-    """Recompile-tracker fallback registration (utils/tracing): on jax
-    builds without jax.monitoring, the span tree counts compiles of the
+    """Recompile-tracker fallback registration (utils/tracing): with no
+    compile listener installed, the span tree counts compiles of the
     tree-fit drivers by sampling their lowered-executable counts at span
     boundaries — the models/trees._timed_fused_fit kernel spans then
     still carry true recompile attribution."""

@@ -30,46 +30,27 @@ _active_mesh: Optional[Mesh] = None
 def shard_vary(tree, axis_name):
     """Under shard_map's varying-manual-axes tracking a scan carry becomes
     batch-varying inside the body; the initial zeros must carry the same
-    type. pcast is the current spelling; pvary the deprecated one on older
-    jax. Shared by every sharded streaming kernel (GLM sweep, stats
-    engine) so the version shims live in one place."""
+    type. Shared by every sharded streaming kernel (GLM sweep, stats
+    engine, trees)."""
     if axis_name is None:
         return tree
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(tree, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(tree, axis_name)
-    return tree
+    return jax.lax.pcast(tree, axis_name, to="varying")
 
 
 def build_shard_map(core, mesh, in_specs, out_specs):
-    """shard_map with the version shims every sharded streaming route
-    needs: import location (jax >= 0.8 top-level), and replication
-    checking off — jax 0.4.x shard_map has no replication rule for
-    `while` (accumulator psums make every carry replicated by
-    construction); jax >= 0.6 renamed the knob check_rep -> check_vma.
+    """shard_map with varying-manual-axes checking off: the accumulator
+    psums inside the streaming kernels' `while` loops make every carry
+    replicated by construction, which the checker cannot see.
 
-    check_rep=False also means NOTHING at runtime verifies a replicated
+    check_vma=False also means NOTHING at runtime verifies a replicated
     out_spec was actually psum-merged — and at 1 device per shard (every
     CI mesh) a forgotten psum is the identity. That contract is enforced
     statically instead: tmoglint SHD001-SHD005 resolve every
     build_shard_map/shard_map call site, bind the P(...) axis names, and
     prove each replicated out_spec reduced through the body's dataflow
     (docs/static_analysis.md)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect as _inspect
-    sig = _inspect.signature(shard_map)
-    if "check_rep" in sig.parameters:
-        extra = {"check_rep": False}
-    elif "check_vma" in sig.parameters:
-        extra = {"check_vma": False}
-    else:
-        extra = {}
-    return shard_map(core, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **extra)
+    return jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_batch_count(mesh) -> int:

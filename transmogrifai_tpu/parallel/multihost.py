@@ -15,9 +15,8 @@ split used to provide:
 - `initialize()`         — jax.distributed bring-up (coordinator + rank
                            from args, TMOG_COORD_ADDR / TMOG_PROC_COUNT /
                            TMOG_PROC_ID, or the JAX_COORDINATOR_ADDRESS /
-                           JAX_NUM_PROCESSES / JAX_PROCESS_ID spellings),
-                           including the CPU gloo collectives bring-up
-                           jax 0.4.x needs before the backend exists;
+                           JAX_NUM_PROCESSES / JAX_PROCESS_ID spellings;
+                           CPU pods ride jax's default gloo collectives);
 - `global_mesh()`        — a Mesh over ALL processes' devices;
 - `padded_global_rows(n)`— the device-count row multiple arrays pad to;
 - `process_row_range(n)` — which REAL rows of a global dataset this host
@@ -78,30 +77,6 @@ def _env_first(*names: str) -> str:
     return ""
 
 
-def _enable_cpu_collectives() -> None:
-    """Configure gloo CPU cross-process collectives BEFORE backend init.
-
-    jax 0.4.x ships `make_gloo_tcp_collectives` in jaxlib, but two traps
-    make it unreachable by accident: the `jax_cpu_collectives_implementation`
-    enum flag never reads the JAX_CPU_COLLECTIVES_IMPLEMENTATION env var
-    (0.4.x flag holders are config-API only), and the TFRT CPU client is
-    created without collectives unless the flag is already set — after
-    which every multi-process program fails to compile with "Multiprocess
-    computations aren't implemented on the CPU backend". So this must run
-    before `jax.distributed.initialize` / the first device touch, via the
-    config API. No-op when the flag is already set, absent (other jax
-    versions), or gloo is missing — TPU/GPU backends bring their own
-    collectives and ignore it entirely."""
-    import jax
-    try:
-        cur = getattr(jax.config, "jax_cpu_collectives_implementation",
-                      None)
-        if cur in (None, "", "none"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # flag/gloo unavailable: the backend decides, as before
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None) -> None:
@@ -134,7 +109,6 @@ def initialize(coordinator_address: Optional[str] = None,
             "unknown — pass it or set TMOG_PROC_COUNT/JAX_NUM_PROCESSES")
     if num_processes == 1 and not explicit:
         return
-    _enable_cpu_collectives()
     import jax
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,

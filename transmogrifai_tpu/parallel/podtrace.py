@@ -833,18 +833,14 @@ def _mfu_table(live: List[Dict[str, Any]],
                top: int = 3) -> List[Dict[str, Any]]:
     """Top measured sinks with analytic FLOPs/bytes attributed — the
     "where did the pod's wall go, and how far from the roof was it"
-    table every traced fit emits. MFU needs a known FLOPs roof
-    (utils.metrics.flops_roof_gflops); off-TPU the sinks still rank by
-    wall with mfu omitted."""
+    table every traced fit emits. MFU needs the device's bf16 peak
+    (utils/platform.DEVICE_SPECS; an unknown TPU kind raises); off-TPU
+    the sinks still rank by wall with mfu omitted."""
     roof_gflops = None
-    try:
-        from ..utils import metrics as M
-        jmod = sys.modules.get("jax")
-        if jmod is not None:
-            kind = jmod.devices()[0].device_kind
-            roof_gflops = M.flops_roof_gflops(kind)
-    except Exception:
-        roof_gflops = None
+    if sys.modules.get("jax") is not None:
+        from ..utils.platform import device_spec
+        spec = device_spec()
+        roof_gflops = spec.bf16_flops / 1e9 if spec else None
     agg: Dict[str, List[float]] = {}
     total_wall = 0.0
     for r in live:

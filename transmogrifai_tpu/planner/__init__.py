@@ -17,7 +17,7 @@ miniature:
   corpora from different runs and boxes compose per backend.
 * :mod:`model` — the cost model: analytic roofline priors (delegating
   to the kernels' own traffic models plus a compile-cost term fit to
-  the ``tpu_fuse_compile_knee`` measurements) blended with
+  the round-5 compile-knee measurements) blended with
   nearest-shape measured observations in log-shape space. A cold
   corpus yields the pure prior, and the prior reproduces today's hand
   defaults — a cold planner is a no-op, not a regression.

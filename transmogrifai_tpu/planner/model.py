@@ -6,7 +6,7 @@ Two ingredients, in strict priority order:
   (``HAND_DEFAULTS`` — the same numbers the knobs' own modules carry)
   plus the analytic cost terms the kernels already publish: the HBM
   traffic models in ``ops/pallas_hist`` / ``ops/stats_engine`` and a
-  **compile-cost knee term** fit to the ``tools/tpu_fuse_compile_knee``
+  **compile-cost knee term** fit to the round-5 compile-knee
   measurements (r5 session 2: ~75 s Mosaic compiles at the 8 MB fused
   out-block cap, 20+ minutes at a 16 MB block). A cold corpus yields
   exactly the priors, so a cold planner reproduces today's hand plan

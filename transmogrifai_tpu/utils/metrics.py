@@ -57,39 +57,6 @@ class StageMetric:
         return dict(self.__dict__)
 
 
-# HBM roof (GB/s) by device-kind substring, most specific first — the
-# denominator of every %-of-roof figure the kernel spans report. Sources:
-# published per-chip HBM bandwidth specs for each TPU generation.
-HBM_ROOF_GBPS = [("v6e", 1640.0), ("v6", 1640.0), ("v5p", 2765.0),
-                 ("v5", 819.0), ("v4", 1228.0), ("v3", 900.0),
-                 ("v2", 700.0)]
-
-
-def hbm_roof_gbps(device_kind: str) -> Optional[float]:
-    """HBM bandwidth roof for a jax device_kind string, or None when the
-    generation is unknown (CPU hosts, new hardware)."""
-    kind = (device_kind or "").lower()
-    return next((r for s, r in HBM_ROOF_GBPS if s in kind), None)
-
-
-# Peak dense-compute roof (GFLOP/s, bf16 matmul peak per chip) by
-# device-kind substring — the denominator of the pod flight recorder's
-# MFU column (parallel/podtrace.py). Sources: published per-chip peak
-# compute specs for each TPU generation. Same substring-match contract
-# as HBM_ROOF_GBPS: most specific first, None off-TPU.
-FLOPS_ROOF_GFLOPS = [("v6e", 918000.0), ("v6", 918000.0),
-                     ("v5p", 459000.0), ("v5", 197000.0),
-                     ("v4", 275000.0), ("v3", 123000.0),
-                     ("v2", 45000.0)]
-
-
-def flops_roof_gflops(device_kind: str) -> Optional[float]:
-    """Peak-compute roof for a jax device_kind string, or None when the
-    generation is unknown (CPU hosts, new hardware)."""
-    kind = (device_kind or "").lower()
-    return next((r for s, r in FLOPS_ROOF_GFLOPS if s in kind), None)
-
-
 def roofline_fields(wall_seconds: float, bytes_hbm: float,
                     roof_gbps: Optional[float]) -> Dict[str, Any]:
     """THE achieved-GB/s / %-of-roof arithmetic, shared by every
@@ -111,10 +78,11 @@ class KernelRoofline:
     bytes_hbm comes from the kernel's own traffic model (e.g.
     ops/pallas_hist.fused_fit_bytes) — analytic by construction, since
     per-invocation byte counters cannot exist inside a jitted program.
-    achieved_gbps = bytes_hbm / wall; pct_of_roof is against the device
-    generation's published HBM bandwidth (None off-TPU). cold=True marks
-    the first run of a program: its wall includes jit trace + compile,
-    so only cold=False spans are valid bandwidth claims."""
+    achieved_gbps = bytes_hbm / wall; pct_of_roof is against the device's
+    published HBM bandwidth (utils/platform.DEVICE_SPECS; None off-TPU).
+    cold=True marks the first run of a program: its wall includes jit
+    trace + compile, so only cold=False spans are valid bandwidth
+    claims."""
 
     kernel: str
     wall_seconds: float
@@ -480,8 +448,8 @@ class MetricsCollector:
             self.trace = TraceTree()
             # activate BEFORE opening the root span so the fallback
             # tracker samples the root too — compiles landing at run
-            # level (between child spans) must not be invisible on
-            # monitoring-less jax
+            # level (between child spans) must not be invisible in
+            # fallback mode
             tracing.tracker.activate(self.trace)
             self.trace.open(app_name, "run")
 
@@ -638,13 +606,9 @@ class MetricsCollector:
             if not self.enabled:
                 return None
             cur, trace = self.current, self.trace
-        roof = None
-        try:
-            import jax
-            if jax.default_backend() == "tpu":
-                roof = hbm_roof_gbps(jax.devices()[0].device_kind)
-        except Exception:
-            pass
+        from .platform import device_spec
+        spec = device_spec()  # None off-TPU; an unknown TPU kind raises
+        roof = spec.hbm_bytes_per_s / 1e9 if spec else None
         rec = KernelRoofline(
             kernel=name, wall_seconds=round(wall_seconds, 4),
             bytes_hbm=float(bytes_hbm), cold=cold,

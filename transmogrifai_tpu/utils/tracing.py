@@ -8,7 +8,8 @@ attributed per span:
 
 - **XLA recompiles** — a `jax.monitoring` listener counts every backend
   compile and books it to the innermost open span (with a
-  lowered-executable-count fallback for jax builds without monitoring),
+  lowered-executable-count fallback for a run that was enabled before
+  jax was imported),
   making claims like PR 3's "bounded recompiles on the bucket ladder"
   runtime-verifiable from any traced run;
 - **device-memory watermarks** — `Device.memory_stats()` sampled at span
@@ -52,7 +53,7 @@ __all__ = [
     "requests_report_rc", "fmt_table",
 ]
 
-# the monitoring event one XLA backend compilation emits (jax >= 0.4.x).
+# the monitoring event one XLA backend compilation emits.
 # NOTE (measured on this image's jaxlib): a persistent-compilation-cache
 # HIT emits it too — but a hit is PRECEDED by the cache-retrieval event
 # below, so the tracker classifies the pair and keeps a separate
@@ -269,7 +270,8 @@ class TraceTree:
 
 # -- recompile attribution ---------------------------------------------------
 
-# jitted entry points registered for the no-monitoring fallback: the sum of
+# jitted entry points registered for the listener-less fallback (collection
+# enabled before jax was imported): the sum of
 # their lowered-executable cache sizes is sampled at span open/close and the
 # delta (minus what nested spans already booked) becomes the span's compile
 # count. Coarser than the listener — it only sees registered functions —
@@ -279,7 +281,7 @@ _FALLBACK_JITS: List[Any] = []
 
 def register_jit_fallback(*fns: Any) -> None:
     """Register jitted callables whose executable count stands in for the
-    compile counter on jax builds without `jax.monitoring`. Idempotent."""
+    compile counter when no listener is installed. Idempotent."""
     for fn in fns:
         if fn is not None and all(fn is not g for g in _FALLBACK_JITS):
             _FALLBACK_JITS.append(fn)
@@ -304,7 +306,8 @@ class RecompileTracker:
     Primary path: a `jax.monitoring` duration listener on
     /jax/core/compile/backend_compile_duration (registered once, gated on
     an active tree so an idle process pays one dict lookup per compile).
-    Fallback (monitoring-less jax): lowered-executable-count sampling over
+    Fallback (collection enabled before jax was imported):
+    lowered-executable-count sampling over
     `register_jit_fallback` functions at span boundaries."""
 
     def __init__(self) -> None:
@@ -368,20 +371,12 @@ class RecompileTracker:
         # module contract): a host-only process enabling collection must
         # not pay the jax import here. With jax absent BOTH tracker paths
         # are inert — there is nothing compiling to count.
-        jmod = sys.modules.get("jax")
-        if jmod is None:
-            return False
-        try:
-            import jax.monitoring  # cheap: jax itself is loaded
-            return hasattr(jax.monitoring,
-                           "register_event_duration_secs_listener")
-        except Exception:
-            return False
+        return sys.modules.get("jax") is not None
 
     def _install_listener(self) -> None:
         if self._listener_installed:
             return
-        import jax
+        import jax.monitoring
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
         self._listener_installed = True
 
