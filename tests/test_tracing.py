@@ -349,70 +349,6 @@ class TestRecompileTracker:
                    if s.kind == "sweep_round"]
         assert set(buckets) <= {8, 16, 32}
 
-    def test_fallback_no_double_booking_on_grandparents(self, monkeypatch):
-        """One compile deep in the tree must book ONCE: ancestors two+
-        levels up subtract the whole subtree's booked compiles from their
-        own cache-size delta, not just direct children's."""
-        monkeypatch.setattr(T.tracker, "_use_monitoring", False)
-        h = jax.jit(lambda x: x - 1.0)
-        T.register_jit_fallback(h)
-        x = jnp.zeros(13, jnp.float32)
-        jax.block_until_ready(x)
-        c = MetricsCollector()
-        c.enable("fb2")
-        with c.trace_span("a", kind="workflow"):
-            with c.trace_span("b", kind="layer"):
-                with c.trace_span("c", kind="stage"):
-                    jax.block_until_ready(h(x))
-        c.finish()
-        c.disable()
-        by = spans_by_name(c)
-        assert by["c"].attrs.get("compiles", 0) == 1
-        assert by["b"].attrs.get("compiles", 0) == 0
-        assert by["a"].attrs.get("compiles", 0) == 0
-        assert by["fb2"].attrs.get("compiles", 0) == 0
-        assert T.tracker.total_compiles == 1
-
-    def test_fallback_counts_registered_jits(self, monkeypatch):
-        """Older-jax path: without jax.monitoring the tracker samples
-        registered jitted functions' executable counts at span
-        boundaries."""
-        monkeypatch.setattr(T.tracker, "_use_monitoring", False)
-        g = jax.jit(lambda x: x + 1.0)
-        T.register_jit_fallback(g)
-        x = jnp.zeros(11, jnp.float32)
-        jax.block_until_ready(x)
-        c = MetricsCollector()
-        c.enable("fb")
-        with c.trace_span("fb_fresh", kind="stage"):
-            jax.block_until_ready(g(x))
-        with c.trace_span("fb_warm", kind="stage"):
-            jax.block_until_ready(g(x))
-        c.finish()
-        c.disable()
-        by = spans_by_name(c)
-        assert by["fb_fresh"].attrs.get("compiles", 0) == 1
-        assert by["fb_warm"].attrs.get("compiles", 0) == 0
-        # no sampling key leaks into the export
-        assert "_jit_cache0" not in by["fb_fresh"].attrs
-
-    def test_fallback_books_root_level_compiles(self, monkeypatch):
-        """A compile at run level (no child span open) books on the ROOT
-        span — the tracker activates before the root opens."""
-        monkeypatch.setattr(T.tracker, "_use_monitoring", False)
-        r = jax.jit(lambda x: x * 3.0)
-        T.register_jit_fallback(r)
-        x = jnp.zeros(17, jnp.float32)
-        jax.block_until_ready(x)
-        c = MetricsCollector()
-        c.enable("fbroot")
-        jax.block_until_ready(r(x))  # no child span open
-        c.finish()
-        c.disable()
-        root = spans_by_name(c)["fbroot"]
-        assert root.attrs.get("compiles", 0) == 1
-        assert T.tracker.total_compiles == 1
-
 
 # -- event log ---------------------------------------------------------------
 

@@ -28,6 +28,22 @@ from ...models.base import PredictionModel, PredictorEstimator
 from ...models.prediction import make_prediction_column
 from ...ops import metrics_ops as M
 from ...stages.params import ParamMap
+from ...utils.metrics import collector
+
+
+def _phase(name: str, **attrs: Any):
+    """One top-level phase of validate(): a `validate_phase` span directly
+    beneath the `validate` root. The phases (with the sweep_fit /
+    sweep_eval spans, which keep their kinds) are disjoint, so a
+    profiler trace splits a sweep's host seconds between them
+    (docs/observability.md); spans nested inside a phase are `host_step`."""
+    return collector.trace_span(name, kind="validate_phase", **attrs)
+
+
+def _host_bytes(*arrays: Any) -> int:
+    """Bytes of the arguments that live on the host: what placing them
+    moves to the device."""
+    return int(sum(a.nbytes for a in arrays if isinstance(a, np.ndarray)))
 
 
 @dataclass
@@ -270,59 +286,70 @@ class Validator:
         (leakage-free in-fold DAG refits, OpValidator.applyDAG:228) feeds
         one fold-fitted matrix at a time with that fold's single mask, so
         its inner (model x grid) sweep rides the same device routes."""
-        if w is None:
-            w = np.ones_like(y, np.float32)
-        if masks is None:
-            masks = self.fold_masks(y)
-            self._external_mask_tag = ""
-        else:
-            # checkpoint cells must be keyed by WHICH masks ran: external
-            # per-fold masks can share a data fingerprint across calls
-            import hashlib
-            self._external_mask_tag = hashlib.sha1(
-                np.ascontiguousarray(masks, np.float32).tobytes()
-            ).hexdigest()[:12]
-        metric = self.evaluator.default_metric
-        larger = self.evaluator.is_larger_better()
+        n_folds = int(masks.shape[0] if masks is not None
+                      else getattr(self, "num_folds", 1))
+        with collector.trace_span(
+                type(self).__name__, kind="validate", rows=len(y),
+                folds=n_folds, models=len(models),
+                grid_points=sum(max(len(g), 1) for _, g in models)):
+            with _phase("fold_assign"):
+                if w is None:
+                    w = np.ones_like(y, np.float32)
+                if masks is None:
+                    masks = self.fold_masks(y)
+                    self._external_mask_tag = ""
+                else:
+                    # checkpoint cells must be keyed by WHICH masks ran:
+                    # external per-fold masks can share a data fingerprint
+                    # across calls
+                    import hashlib
+                    self._external_mask_tag = hashlib.sha1(
+                        np.ascontiguousarray(masks, np.float32).tobytes()
+                    ).hexdigest()[:12]
+            metric = self.evaluator.default_metric
+            larger = self.evaluator.is_larger_better()
 
-        # a user-supplied metric (Evaluators.custom) has no device kernel:
-        # every candidate goes through the sequential per-fold route, which
-        # is the only one that calls evaluator.evaluate on host columns
-        device_metric = getattr(self.evaluator, "device_metric", True)
+            # a user-supplied metric (Evaluators.custom) has no device kernel:
+            # every candidate goes through the sequential per-fold route, which
+            # is the only one that calls evaluator.evaluate on host columns
+            device_metric = getattr(self.evaluator, "device_metric", True)
 
-        validated: List[ValidatedModel] = []
-        for est, grids in models:
-            if not grids:
-                grids = [dict()]
-            if not device_metric:
-                validated.extend(self._validate_sequential(
-                    est, grids, X, y, w, masks))
-            elif self._streamable(est, grids, problem_type, X,
-                                  masks.shape[0]):
-                validated.extend(self._validate_streamed(
-                    est, grids, X, y, w, masks, metric, problem_type))
-            elif self._vmappable(est, grids, problem_type):
-                validated.extend(self._validate_vmapped(
-                    est, grids, X, y, w, masks, metric, problem_type))
-            elif (self.mask_fold_trees
-                  and getattr(est, "supports_mask_folds", False)
-                  and problem_type in getattr(est, "problem_types", ())):
-                validated.extend(self._validate_mask_folds(
-                    est, grids, X, y, w, masks, metric, problem_type))
-            else:
-                validated.extend(self._validate_sequential(
-                    est, grids, X, y, w, masks))
+            validated: List[ValidatedModel] = []
+            for est, grids in models:
+                if not grids:
+                    grids = [dict()]
+                if not device_metric:
+                    validated.extend(self._validate_sequential(
+                        est, grids, X, y, w, masks))
+                elif self._streamable(est, grids, problem_type, X,
+                                      masks.shape[0]):
+                    validated.extend(self._validate_streamed(
+                        est, grids, X, y, w, masks, metric, problem_type))
+                elif self._vmappable(est, grids, problem_type):
+                    validated.extend(self._validate_vmapped(
+                        est, grids, X, y, w, masks, metric, problem_type))
+                elif (self.mask_fold_trees
+                      and getattr(est, "supports_mask_folds", False)
+                      and problem_type in getattr(est, "problem_types", ())):
+                    validated.extend(self._validate_mask_folds(
+                        est, grids, X, y, w, masks, metric, problem_type))
+                else:
+                    validated.extend(self._validate_sequential(
+                        est, grids, X, y, w, masks))
 
-        if not validated:
-            raise ValueError("No models to validate")
-        key = (lambda v: v.mean_metric if np.isfinite(v.mean_metric)
-               else (-np.inf if larger else np.inf))
-        best = max(validated, key=key) if larger else min(validated, key=key)
-        winner = next(e for e, _ in models
-                      if e.uid == best.model_uid).copy(**best.grid)
-        return BestEstimator(name=best.model_name, estimator=winner,
-                             best_grid=best.grid,
-                             best_metric=best.mean_metric, validated=validated)
+            with _phase("winner"):
+                if not validated:
+                    raise ValueError("No models to validate")
+                key = (lambda v: v.mean_metric if np.isfinite(v.mean_metric)
+                       else (-np.inf if larger else np.inf))
+                best = max(validated, key=key) if larger \
+                    else min(validated, key=key)
+                winner = next(e for e, _ in models
+                              if e.uid == best.model_uid).copy(**best.grid)
+                return BestEstimator(name=best.model_name, estimator=winner,
+                                     best_grid=best.grid,
+                                     best_metric=best.mean_metric,
+                                     validated=validated)
 
     # -- vmapped GLM path --------------------------------------------------
     @staticmethod
@@ -426,54 +453,56 @@ class Validator:
         """Place sweep arrays on device; with a mesh, rows pad to the batch
         axis (zero weight = inert everywhere: fits see mask*w, metrics see
         (1-mask)*w) and shard across it."""
-        if self.mesh is None:
-            return (jnp.asarray(X, dtype), jnp.asarray(y, jnp.float32),
-                    jnp.asarray(w, jnp.float32),
-                    jnp.asarray(masks, jnp.float32))
-        from ...parallel.mesh import (
-            BATCH_AXIS, batch_sharding, mesh_is_multiprocess,
-            pad_rows_to_multiple, sharded_along,
-        )
-        if mesh_is_multiprocess(self.mesh):
-            # SPMD pod sweep: X/y/w/masks hold THIS PROCESS's rows; each
-            # block lands as the process's batch-axis stripe of a global
-            # array (same pad semantics as the single-host branch below:
-            # X repeats its last row, weights pad 0 = inert, masks pad 1)
-            from ...parallel import multihost as MH
-            layout = MH.row_layout(np.asarray(X).shape[0], self.mesh)
-            return (
-                MH.host_local_block(
-                    np.asarray(np.asarray(X), jnp.dtype(dtype)),
-                    self.mesh, layout, pad_value=None),
-                MH.host_local_block(np.asarray(y, np.float32),
-                                    self.mesh, layout),
-                MH.host_local_block(np.asarray(w, np.float32),
-                                    self.mesh, layout),
-                MH.host_local_block(np.asarray(masks, np.float32),
-                                    self.mesh, layout, pad_value=1.0,
-                                    axis=1),
+        with _phase("device_place", h2d_bytes=_host_bytes(X, y, w, masks)):
+            if self.mesh is None:
+                return (jnp.asarray(X, dtype), jnp.asarray(y, jnp.float32),
+                        jnp.asarray(w, jnp.float32),
+                        jnp.asarray(masks, jnp.float32))
+            from ...parallel.mesh import (
+                BATCH_AXIS, batch_sharding, mesh_is_multiprocess,
+                pad_rows_to_multiple, sharded_along,
             )
-        nb = self.mesh.shape[BATCH_AXIS]
-        # X pads by repeating the last real row (pad_value=None): tree
-        # quantile binning is unweighted, so synthetic values would shift
-        # bin edges. Labels/weights pad with zeros — inert in every
-        # weighted reduction; masks pad with 1s (irrelevant under w=0).
-        X, _ = pad_rows_to_multiple(np.asarray(X), nb, pad_value=None)
-        y, _ = pad_rows_to_multiple(np.asarray(y, np.float32), nb)
-        w, _ = pad_rows_to_multiple(np.asarray(w, np.float32), nb)
-        masks = pad_rows_to_multiple(
-            np.asarray(masks, np.float32).T, nb, pad_value=1.0)[0].T
-        # device_put host arrays DIRECTLY with the sharding: jnp.asarray
-        # first would commit the whole matrix to device 0 before resharding
-        # — an OOM at exactly the >1-chip scale the mesh exists for
-        put = jax.device_put
-        return (
-            put(np.asarray(X, jnp.dtype(dtype)), batch_sharding(self.mesh, 2)),
-            put(np.asarray(y, np.float32), batch_sharding(self.mesh, 1)),
-            put(np.asarray(w, np.float32), batch_sharding(self.mesh, 1)),
-            put(np.asarray(masks, np.float32),
-                sharded_along(self.mesh, 1, 2)),
-        )
+            if mesh_is_multiprocess(self.mesh):
+                # SPMD pod sweep: X/y/w/masks hold THIS PROCESS's rows; each
+                # block lands as the process's batch-axis stripe of a global
+                # array (same pad semantics as the single-host branch below:
+                # X repeats its last row, weights pad 0 = inert, masks pad 1)
+                from ...parallel import multihost as MH
+                layout = MH.row_layout(np.asarray(X).shape[0], self.mesh)
+                return (
+                    MH.host_local_block(
+                        np.asarray(np.asarray(X), jnp.dtype(dtype)),
+                        self.mesh, layout, pad_value=None),
+                    MH.host_local_block(np.asarray(y, np.float32),
+                                        self.mesh, layout),
+                    MH.host_local_block(np.asarray(w, np.float32),
+                                        self.mesh, layout),
+                    MH.host_local_block(np.asarray(masks, np.float32),
+                                        self.mesh, layout, pad_value=1.0,
+                                        axis=1),
+                )
+            nb = self.mesh.shape[BATCH_AXIS]
+            # X pads by repeating the last real row (pad_value=None): tree
+            # quantile binning is unweighted, so synthetic values would shift
+            # bin edges. Labels/weights pad with zeros — inert in every
+            # weighted reduction; masks pad with 1s (irrelevant under w=0).
+            X, _ = pad_rows_to_multiple(np.asarray(X), nb, pad_value=None)
+            y, _ = pad_rows_to_multiple(np.asarray(y, np.float32), nb)
+            w, _ = pad_rows_to_multiple(np.asarray(w, np.float32), nb)
+            masks = pad_rows_to_multiple(
+                np.asarray(masks, np.float32).T, nb, pad_value=1.0)[0].T
+            # device_put host arrays DIRECTLY with the sharding: jnp.asarray
+            # first would commit the whole matrix to device 0 before resharding
+            # — an OOM at exactly the >1-chip scale the mesh exists for
+            put = jax.device_put
+            return (
+                put(np.asarray(X, jnp.dtype(dtype)),
+                    batch_sharding(self.mesh, 2)),
+                put(np.asarray(y, np.float32), batch_sharding(self.mesh, 1)),
+                put(np.asarray(w, np.float32), batch_sharding(self.mesh, 1)),
+                put(np.asarray(masks, np.float32),
+                    sharded_along(self.mesh, 1, 2)),
+            )
 
     def _sweep_path(self, base: str) -> str:
         """Checkpoint path tag: a mesh run pads rows (shifting tree bin
@@ -497,29 +526,30 @@ class Validator:
         vs physically-split binning, sweep dtype): metrics from one path
         must never be replayed into another, since they can legitimately
         differ enough to flip the winner."""
-        from .checkpoint import data_fingerprint, sweep_key
-        ckpt = self._checkpoint()
-        if ckpt is None:
-            return None, [None] * len(grids), {}
-        data_fp = data_fingerprint(X, y)
-        base_params = est.param_values() if hasattr(est, "param_values") \
-            else None
-        # a custom metric is an arbitrary function: its identity must be
-        # part of the cell key, or editing the function silently replays
-        # the OLD function's cached fold metrics (the name alone is not a
-        # fingerprint the way built-in metric names are)
-        metric_key = getattr(self.evaluator, "metric_key", metric)
-        keys = [sweep_key(type(est).__name__, g, n_folds,
-                          self.seed, self.stratify, metric_key,
-                          data_fp=data_fp, base_params=base_params,
-                          path=path)
-                for g in grids]
-        results = {}
-        for gi, key in enumerate(keys):
-            done = ckpt.get(key)
-            if done is not None:
-                results[gi] = [float(v) for v in done["fold_metrics"]]
-        return ckpt, keys, results
+        with _phase("bookkeeping"):
+            from .checkpoint import data_fingerprint, sweep_key
+            ckpt = self._checkpoint()
+            if ckpt is None:
+                return None, [None] * len(grids), {}
+            data_fp = data_fingerprint(X, y)
+            base_params = est.param_values() if hasattr(est, "param_values") \
+                else None
+            # a custom metric is an arbitrary function: its identity must be
+            # part of the cell key, or editing the function silently replays
+            # the OLD function's cached fold metrics (the name alone is not a
+            # fingerprint the way built-in metric names are)
+            metric_key = getattr(self.evaluator, "metric_key", metric)
+            keys = [sweep_key(type(est).__name__, g, n_folds,
+                              self.seed, self.stratify, metric_key,
+                              data_fp=data_fp, base_params=base_params,
+                              path=path)
+                    for g in grids]
+            results = {}
+            for gi, key in enumerate(keys):
+                done = ckpt.get(key)
+                if done is not None:
+                    results[gi] = [float(v) for v in done["fold_metrics"]]
+            return ckpt, keys, results
 
     def _validate_vmapped(self, est, grids, X, y, w, masks, metric,
                           problem_type) -> List[ValidatedModel]:
@@ -543,7 +573,6 @@ class Validator:
             path=self._sweep_path(f"vmapped:{jnp.dtype(dtype).name}"))
         pending = [gi for gi in range(len(grids)) if gi not in results]
         if pending:
-            from ...utils.metrics import collector
             Xd, yd, wd, md = self._device_arrays(X, y, w, masks, dtype)
             thr_d = jnp.asarray(margin_thr, jnp.float32)
             rank_bins = self._rank_bins(X.shape[0])
@@ -565,13 +594,14 @@ class Validator:
                                  problem_type=problem_type,
                                  n_classes=n_classes, rank_bins=rank_bins)
                     out = np.asarray(out)  # [F, chunk]
-                for j, gi in enumerate(idx):
-                    fm = [float(v) for v in out[:, j]]
-                    results[gi] = fm
-                    if ckpt is not None:
-                        ckpt.record(keys[gi], type(est).__name__, grids[gi],
-                                    fm, metric)
-                    self._cell_event(est, gi, fm, "vmapped")
+                with _phase("record", cells=len(idx)):
+                    for j, gi in enumerate(idx):
+                        fm = [float(v) for v in out[:, j]]
+                        results[gi] = fm
+                        if ckpt is not None:
+                            ckpt.record(keys[gi], type(est).__name__,
+                                        grids[gi], fm, metric)
+                        self._cell_event(est, gi, fm, "vmapped")
         return [
             ValidatedModel(model_name=type(est).__name__, model_uid=est.uid,
                            grid=g, metric_name=metric,
@@ -619,7 +649,6 @@ class Validator:
         (all fold metrics exist) — the resumable unit of the sweep
         checkpoint, streamed so `tail -f events.jsonl` shows sweep
         progress cell by cell."""
-        from ...utils.metrics import collector
         finite = [v for v in fm if np.isfinite(v)]
         collector.event(
             "sweep_cell_landed", model=type(est).__name__,
@@ -629,7 +658,6 @@ class Validator:
     def _record_sweep_telemetry(self, est, info):
         self.last_streamed_telemetry = dict(info,
                                             model=type(est).__name__)
-        from ...utils.metrics import collector
         if collector.enabled:
             collector.sweep_convergence(
                 family=type(est).__name__, kernel=info["kernel"],
@@ -681,7 +709,6 @@ class Validator:
         if loss != "squared" and GS.env_on("TMOG_GLM_ROUNDS"):
             rc, rkey, state = self._round_checkpoint(keys, pending,
                                                      fit_kwargs)
-            from ...utils.metrics import collector
 
             def on_round(st):
                 # one event per retirement boundary: the tail of
@@ -740,7 +767,6 @@ class Validator:
             path=self._sweep_path(f"streamed:{jnp.dtype(dtype).name}"))
         pending = [gi for gi in range(len(grids)) if gi not in results]
         if pending:
-            from ...utils.metrics import collector
             Xd, yd, wd, md = self._device_arrays(X, y, w, masks, dtype)
             fit_kwargs = dict(
                 loss=est.streamed_loss,
@@ -777,19 +803,22 @@ class Validator:
                             b0[f, jnp.asarray(padded)], thr_d, metric=metric,
                             problem_type=problem_type, rank_bins=rank_bins,
                             chunk=chunk, use_lanes=self.mesh is None)
-                        out[f, idx] = np.asarray(vals)[:len(idx)]
-            for j, gi in enumerate(pending):
-                fm = [float(v) for v in out[:, j]]
-                results[gi] = fm
-                if ckpt is not None:
-                    ckpt.record(keys[gi], type(est).__name__, grids[gi],
-                                fm, metric)
-                self._cell_event(est, gi, fm, "streamed")
-            if round_ckpt is not None:
-                # only NOW are all cells in the JSONL checkpoint: a
-                # preemption during the evaluation above resumes from the
-                # fully-retired round state instead of refitting
-                round_ckpt.clear()
+                        with collector.trace_span("metric_fetch",
+                                                  kind="host_step"):
+                            out[f, idx] = np.asarray(vals)[:len(idx)]
+            with _phase("record", cells=len(pending)):
+                for j, gi in enumerate(pending):
+                    fm = [float(v) for v in out[:, j]]
+                    results[gi] = fm
+                    if ckpt is not None:
+                        ckpt.record(keys[gi], type(est).__name__,
+                                    grids[gi], fm, metric)
+                    self._cell_event(est, gi, fm, "streamed")
+                if round_ckpt is not None:
+                    # only NOW are all cells in the JSONL checkpoint: a
+                    # preemption during the evaluation above resumes from
+                    # the fully-retired round state instead of refitting
+                    round_ckpt.clear()
         return [
             ValidatedModel(model_name=type(est).__name__, model_uid=est.uid,
                            grid=g, metric_name=metric,
@@ -866,7 +895,6 @@ class Validator:
             for gi in pending:
                 groups.setdefault(bins_of(gi), []).append(gi)
             multicls = problem_type == "multiclass"
-            from ...utils.metrics import collector
 
             # config-fusion gate, resolved ONCE per sweep through the
             # plan-time autotuner (docs/planning.md): an explicitly-set
@@ -904,22 +932,28 @@ class Validator:
                 plan_fuse_on = os.environ.get(
                     "TMOG_GRID_FUSE", "").strip().lower() \
                     in ("1", "true", "on")
-            for _, group in sorted(groups.items(), key=lambda kv: str(kv[0])):
+            for bins, group in sorted(groups.items(),
+                                      key=lambda kv: str(kv[0])):
                 # n_valid: mesh runs pad rows (repeat-last) — the quantile
                 # sketch must see only the real rows so mesh and meshless
                 # sweeps grow from identical bin edges
-                ctx = est.copy(**grids[group[0]]).mask_sweep_context(
-                    Xd, n_valid=X.shape[0], mesh=self.mesh)
+                with _phase("tree_bin", bins=int(bins or 0),
+                            configs=len(group)):
+                    ctx = est.copy(**grids[group[0]]).mask_sweep_context(
+                        Xd, n_valid=X.shape[0], mesh=self.mesh)
 
                 def record(gi, scores_f, route=None):
-                    out = np.asarray(fold_metrics(scores_f, yd, wd, md,
-                                                  thr_d))
-                    fm = [float(v) for v in out]
-                    results[gi] = fm
-                    if ckpt is not None:
-                        ckpt.record(keys[gi], type(est).__name__, grids[gi],
-                                    fm, metric)
-                    self._cell_event(est, gi, fm, route or "mask_folds")
+                    with _phase("fold_metrics", lanes=int(md.shape[0]),
+                                depth=depth_of(gi)):
+                        out = np.asarray(fold_metrics(scores_f, yd, wd, md,
+                                                      thr_d))
+                    with _phase("record", cells=1):
+                        fm = [float(v) for v in out]
+                        results[gi] = fm
+                        if ckpt is not None:
+                            ckpt.record(keys[gi], type(est).__name__,
+                                        grids[gi], fm, metric)
+                        self._cell_event(est, gi, fm, route or "mask_folds")
 
                 # config fusion: grid points whose structural signature
                 # matches fit ONE fold-fused device program (lanes =
@@ -940,10 +974,14 @@ class Validator:
                     # both the wall and the compile knee
                     if key[0] == "fuse" and len(gis) > 1 and plan_fuse_on:
                         try:
-                            fused = est.mask_fit_scores_grid(
-                                ctx, yd, wd, md, [grids[gi] for gi in gis],
-                                n_classes=n_classes, multiclass=multicls,
-                                mesh=self.mesh)
+                            with _phase("tree_fit",
+                                        lanes=int(md.shape[0]) * len(gis),
+                                        depth=depth_of(gis[0])):
+                                fused = est.mask_fit_scores_grid(
+                                    ctx, yd, wd, md,
+                                    [grids[gi] for gi in gis],
+                                    n_classes=n_classes,
+                                    multiclass=multicls, mesh=self.mesh)
                         except Exception as e:  # never lose the sweep to
                             # the fast path: per-config route is the
                             # correctness baseline — but a route that
@@ -992,9 +1030,12 @@ class Validator:
                         continue
                     for gi in gis:
                         est_g = est.copy(**grids[gi])
-                        record(gi, est_g.mask_fit_scores(
-                            ctx, yd, wd, md, n_classes=n_classes,
-                            multiclass=multicls))
+                        with _phase("tree_fit", lanes=int(md.shape[0]),
+                                    depth=depth_of(gi)):
+                            scores = est_g.mask_fit_scores(
+                                ctx, yd, wd, md, n_classes=n_classes,
+                                multiclass=multicls)
+                        record(gi, scores)
                 del ctx  # free the binned matrix before the next group
             if fuse_failures:
                 import logging
@@ -1030,18 +1071,23 @@ class Validator:
                 continue
             est_g = est.copy(**g)
             fold_vals: List[float] = []
-            for f in range(masks.shape[0]):
-                tr = masks[f] > 0
-                va = ~tr
-                model = est_g.fit_arrays(X[tr], y[tr], w[tr])
-                pred, raw, prob = model.predict_arrays(X[va])
-                col = make_prediction_column(pred, raw, prob)
-                fold_vals.append(self.evaluator.evaluate(y[va], col, w[va]))
-            results[gi] = fold_vals
-            if ckpt is not None:
-                ckpt.record(keys[gi], type(est).__name__, g, fold_vals,
-                            metric)
-            self._cell_event(est, gi, fold_vals, "sequential")
+            with collector.trace_span(
+                    f"sequential:{type(est).__name__}", kind="sweep_fit",
+                    folds=int(masks.shape[0]), grid_index=gi):
+                for f in range(masks.shape[0]):
+                    tr = masks[f] > 0
+                    va = ~tr
+                    model = est_g.fit_arrays(X[tr], y[tr], w[tr])
+                    pred, raw, prob = model.predict_arrays(X[va])
+                    col = make_prediction_column(pred, raw, prob)
+                    fold_vals.append(
+                        self.evaluator.evaluate(y[va], col, w[va]))
+            with _phase("record", cells=1):
+                results[gi] = fold_vals
+                if ckpt is not None:
+                    ckpt.record(keys[gi], type(est).__name__, g, fold_vals,
+                                metric)
+                self._cell_event(est, gi, fold_vals, "sequential")
         return [
             ValidatedModel(model_name=type(est).__name__, model_uid=est.uid,
                            grid=g, metric_name=metric,

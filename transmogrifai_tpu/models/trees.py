@@ -451,11 +451,18 @@ class _TreeEstimator(PredictorEstimator):
         achieved GB/s. `span` ("tree_level_scan" / "tree_shard_merge")
         additionally wraps the fit in a named trace span so a Perfetto
         view shows which growth/merge form ran and the RecompileTracker
-        books the fit's compiles to it (docs/observability.md)."""
-        from ..utils.metrics import collector
-        if not collector.enabled:
-            return call()
+        books the fit's compiles to it (docs/observability.md). The span
+        is there with collection off too (its profiler annotation costs
+        nothing then); the fence and the kernel record are not: they
+        change what a timed sweep measures."""
         import contextlib
+        from ..utils.metrics import collector
+        cm = collector.trace_span(span, kind="tree_fused",
+                                  lanes=int(lanes), depth=int(depth)) \
+            if span else contextlib.nullcontext()
+        if not collector.enabled:
+            with cm:
+                return call()
         import time
         from ..ops import pallas_hist
         # keyed by backend AND growth form: a set_tree_scan flip clears
@@ -464,9 +471,6 @@ class _TreeEstimator(PredictorEstimator):
         sig = (jax.default_backend(), T.tree_scan_enabled(), label,
                Xb.shape, str(Xb.dtype), lanes, depth, n_rounds)
         cold = sig not in _TreeEstimator._WARM_FUSED_SHAPES
-        cm = collector.trace_span(span, kind="tree_fused",
-                                  lanes=int(lanes), depth=int(depth)) \
-            if span else contextlib.nullcontext()
         t0 = time.perf_counter()
         with cm:
             out = call()

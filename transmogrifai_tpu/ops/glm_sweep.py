@@ -1199,7 +1199,9 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 gA, hA, g0A, h0A, B, b0j, wsum_l, l1j, l2j,
                 fit_intercept=bool(fit_intercept))
             it += 1
-            delta = np.asarray(delta_dev)  # [Lb]: the round's only fetch
+            with _collector.trace_span("round_fetch", kind="host_step"):
+                # [Lb]: the round's only fetch
+                delta = np.asarray(delta_dev)
             if float(delta.max()) <= tol_f:
                 break
         return np.asarray(B)[:, :d], np.asarray(b0j), delta, it
@@ -1214,7 +1216,8 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 _podtrace.pod_round(st["rounds"], bucket=int(Lb),
                                     active=int(k)):
             args = None
-            with _podtrace.compute("glm_prep", lanes=int(Lb)):
+            with _collector.trace_span("round_prep", kind="host_step"), \
+                    _podtrace.compute("glm_prep", lanes=int(Lb)):
                 sel = np.zeros((F, Lb), np.float32)
                 sel[lane_fold[idx], np.arange(k)] = 1.0
                 l1b = np.zeros(Lb, np.float32)
@@ -1249,6 +1252,11 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             elif mesh is None:
                 Bb, b0b, db, it = sweep_glm_round(
                     *args, loss=loss, fit_intercept=fit_intercept)
+                with _collector.trace_span("round_fetch",
+                                           kind="host_step"):
+                    # the host waits here for the round's program
+                    Bb, b0b, db, it = (np.asarray(Bb), np.asarray(b0b),
+                                       np.asarray(db), int(it))
             else:
                 # the psum lives INSIDE the jitted round program, so the
                 # collective window on the multi-process path is program
@@ -1262,10 +1270,12 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                              iters=int(budget)):
                     Bb, b0b, db, it = _sharded_round_fn(
                         mesh, loss, bool(fit_intercept))(*args)
-                    Bb = np.asarray(Bb)
-                    b0b = np.asarray(b0b)
-                    db = np.asarray(db)
-                    it = int(it)
+                    with _collector.trace_span("round_fetch",
+                                               kind="host_step"):
+                        Bb = np.asarray(Bb)
+                        b0b = np.asarray(b0b)
+                        db = np.asarray(db)
+                        it = int(it)
             with _podtrace.compute("glm_retire", active=int(k)):
                 st["B"][idx] = np.asarray(Bb)[:k]
                 st["b0"][idx] = np.asarray(b0b)[:k]
@@ -1346,16 +1356,3 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
     (bf16 X stays bf16; f32 accumulation)."""
     return jnp.matmul(X, B_f.T.astype(X.dtype),
                       preferred_element_type=jnp.float32) + b0_f[None, :]
-
-
-# recompile-tracker fallback (utils/tracing): with no compile listener
-# installed the tracker samples these entries' lowered-executable
-# counts at span boundaries instead of listening for compile events — the
-# sweep kernels are exactly the programs whose "bounded recompiles on the
-# bucket ladder" claim the tracer exists to verify
-from ..utils import tracing as _tracing  # noqa: E402
-
-_tracing.register_jit_fallback(
-    sweep_glm_round, sweep_glm_streamed, sweep_glm_squared_gram,
-    glm_standardize_stats, _source_prep_step, _source_round_step,
-    _source_round_update)

@@ -1087,14 +1087,3 @@ def rank_matrices(X, y, w=None, *, col_block: int = 128
     if len(rx_parts) == 1:
         return rx_parts[0], ry_parts[0]
     return jnp.concatenate(rx_parts, 1), jnp.concatenate(ry_parts, 1)
-
-
-# recompile-tracker fallback (utils/tracing): with no compile listener
-# installed the tracker samples these entries' lowered-executable
-# counts at span boundaries — the stats engine's "one program per shape"
-# claim is exactly what the tracer verifies
-from ..utils import tracing as _tracing  # noqa: E402
-
-_tracing.register_jit_fallback(_fused_stats_jit, _stream_tile_jit,
-                               _rank_block_jit, _tileplane_step_jit,
-                               _tile_shift_jit)

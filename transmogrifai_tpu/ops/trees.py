@@ -1565,29 +1565,8 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
 
 
 #: jitted shard_map program per (mesh, static config) — an explicit dict
-#: (not lru_cache) so the kill switches can DROP programs for real:
-#: registering each rebuilt jit with the tracing fallback would retain
-#: every cleared generation's executables forever, so instead ONE stable
-#: probe (_ShardedJitProbe, registered at import) sums executable counts
-#: over whatever programs are currently live here.
+#: (not lru_cache) so the kill switches can DROP programs for real.
 _SHARDED_FIT_CACHE: dict = {}
-
-
-class _ShardedJitProbe:
-    """Stable register_jit_fallback entry for the sharded fit programs:
-    listener-less compile counting samples the LIVE cache only, and
-    cleared programs become unreachable (no unbounded retention across
-    set_tree_scan / pallas-toggle cache clears)."""
-
-    @staticmethod
-    def _cache_size():
-        total = 0
-        for fn in _SHARDED_FIT_CACHE.values():
-            try:
-                total += int(fn._cache_size())
-            except Exception:
-                pass
-        return total
 
 
 def _sharded_gbt_fn(mesh, static_kw):
@@ -1794,21 +1773,6 @@ def _register_pallas_consumers():
 
 
 _register_pallas_consumers()
-
-
-def _register_trace_fallback():
-    """Recompile-tracker fallback registration (utils/tracing): with no
-    compile listener installed, the span tree counts compiles of the
-    tree-fit drivers by sampling their lowered-executable counts at span
-    boundaries — the models/trees._timed_fused_fit kernel spans then
-    still carry true recompile attribution."""
-    from ..utils import tracing
-    tracing.register_jit_fallback(grow_tree, fit_forest, fit_gbt,
-                                  fit_gbt_folds, fit_gbt_softmax,
-                                  _bin_tile_jit, _ShardedJitProbe())
-
-
-_register_trace_fallback()
 
 
 # -- host-side (numpy) ensemble traversal for serving -----------------------

@@ -3,8 +3,7 @@
 Reference: utils/.../spark/OpSparkListener.scala:56-164 — per-stage/job/app
 metrics (durations, GC, shuffle/IO bytes) collected by a Spark listener,
 opt-in via OpParams.collectStageMetrics, surfaced at app end. The TPU
-equivalents are per-stage wall clock + row counts + XLA compile counts, and
-a `trace()` context manager around jax.profiler for device timelines.
+equivalents are per-stage wall clock + row counts + XLA compile counts.
 
 Collection is opt-in and process-local: `enable()` (or
 OpParams.collect_stage_metrics=True through the runner) turns it on; the
@@ -18,17 +17,24 @@ KernelRoofline / SweepConvergence lists stay exactly as before so
 AppMetrics.to_json() remains byte-compatible for existing consumers — the
 tree adds a "spans" key in save(), a Chrome-trace export
 (save_chrome_trace) and an optional streaming event log
-(attach_event_log / event)."""
+(attach_event_log / event).
+
+Every span()/trace_span() ALSO writes a host annotation `tmog.<kind>:<name>`
+into the jax profiler's trace, whether or not collection is on: inside a
+`jax.profiler.start_trace` session the program's spans sit on the device
+ops' clock (docs/observability.md "Spans on the profiler's clock"); with
+no session an annotation records nothing."""
 from __future__ import annotations
 
 import collections
 import contextlib
 import json
 import math
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, ContextManager, Dict, Iterator, List, Optional
 
 from . import tracing
 from .tracing import EventLog, TraceTree
@@ -412,6 +418,29 @@ class AppMetrics:
         return "\n".join(lines)
 
 
+_META_BREAKERS = str.maketrans("#,=", "___")
+
+
+def _annotation(kind: str, name: str, attrs: Dict[str, Any]
+                ) -> ContextManager[Any]:
+    """The profiler-trace sink of one span: a host TraceMe
+    `tmog.<kind>:<name>` carrying the span's scalar attrs, on the clock
+    the device ops are stamped with. Never fences, never reads device
+    memory; without a profiler session it records nothing (~1 us). jax
+    comes from sys.modules like everywhere in utils/tracing: a host-only
+    process that never imported it has no profiler to write to."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    # TraceMe packs metadata as `name#k=v,k=v#`: those three characters
+    # inside a name or value would cut the rest off
+    meta = {k: v.translate(_META_BREAKERS) if isinstance(v, str) else v
+            for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+    return jax.profiler.TraceAnnotation(
+        f"tmog.{kind}:{name}".translate(_META_BREAKERS), **meta)
+
+
 class MetricsCollector:
     """Process-local registry (the listener's slot in this runtime)."""
 
@@ -446,10 +475,6 @@ class MetricsCollector:
             self.current = AppMetrics(app_name=app_name,
                                       start_time=time.time())
             self.trace = TraceTree()
-            # activate BEFORE opening the root span so the fallback
-            # tracker samples the root too — compiles landing at run
-            # level (between child spans) must not be invisible in
-            # fallback mode
             tracing.tracker.activate(self.trace)
             self.trace.open(app_name, "run")
 
@@ -525,7 +550,10 @@ class MetricsCollector:
         """Generic span context: nests under the innermost open span,
         records error/error_type when the body raises, samples the device
         memory watermark and recompile attribution at close. Yields the
-        Span (None when collection is off) so callers can add attrs."""
+        Span (None when collection is off) so callers can add attrs.
+        Collection on or off, the span is also a `tmog.<kind>:<name>`
+        annotation in the profiler's trace (_annotation; entered outside
+        the lock, tmoglint THR002)."""
         with self._lock:
             if not self.enabled:
                 sp = trace = None
@@ -535,28 +563,31 @@ class MetricsCollector:
                 # must land on the tree the span belongs to
                 trace = self.trace
                 sp = trace.open(name, kind, **attrs)
-        if sp is None:
-            yield None
-            return
-        if kind in self._EVENTED_KINDS:
-            self.event("span_start", name=name, kind=kind)
-        err: Optional[str] = None
-        try:
-            yield sp
-        except BaseException as e:
-            err = type(e).__name__
-            raise
-        finally:
-            trace.close(sp, error_type=err)
+        with _annotation(kind, name, attrs):
+            if sp is None:
+                yield None
+                return
             if kind in self._EVENTED_KINDS:
-                self.event("span_end", name=name, kind=kind,
-                           wall_seconds=round(sp.duration, 6),
-                           error=err is not None,
-                           **({"error_type": err} if err else {}))
+                self.event("span_start", name=name, kind=kind)
+            err: Optional[str] = None
+            try:
+                yield sp
+            except BaseException as e:
+                err = type(e).__name__
+                raise
+            finally:
+                trace.close(sp, error_type=err)
+                if kind in self._EVENTED_KINDS:
+                    self.event("span_end", name=name, kind=kind,
+                               wall_seconds=round(sp.duration, 6),
+                               error=err is not None,
+                               **({"error_type": err} if err else {}))
 
     @contextlib.contextmanager
     def span(self, stage_name: str, uid: str, phase: str,
              n_rows: int = 0, n_stages_fused: int = 1) -> Iterator[None]:
+        attrs = dict(uid=uid, phase=phase, n_rows=n_rows,
+                     n_stages_fused=n_stages_fused)
         with self._lock:
             if not self.enabled:
                 sp = trace = cur = None
@@ -564,32 +595,33 @@ class MetricsCollector:
                 t0 = time.time()
                 trace = self.trace
                 cur = self.current
-                sp = trace.open(stage_name, "stage", uid=uid,
-                                phase=phase, n_rows=n_rows,
-                                n_stages_fused=n_stages_fused)
-        if sp is None:
-            yield
-            return
-        self.event("stage_start", stage=stage_name, uid=uid, phase=phase)
-        err: Optional[str] = None
-        try:
-            yield
-        except BaseException as e:
-            # the span records even when the body raises; WITHOUT the
-            # error mark a failed fit reads exactly like a fast one
-            err = type(e).__name__
-            raise
-        finally:
-            trace.close(sp, error_type=err)
-            wall = time.time() - t0
-            cur.stage_metrics.append(StageMetric(
-                stage_name=stage_name, uid=uid, phase=phase,
-                wall_seconds=wall, n_rows=n_rows,
-                n_stages_fused=n_stages_fused,
-                error=err is not None, error_type=err))
-            self.event("stage_end", stage=stage_name, uid=uid, phase=phase,
-                       wall_seconds=round(wall, 6), error=err is not None,
-                       **({"error_type": err} if err else {}))
+                sp = trace.open(stage_name, "stage", **attrs)
+        with _annotation("stage", stage_name, attrs):
+            if sp is None:
+                yield
+                return
+            self.event("stage_start", stage=stage_name, uid=uid,
+                       phase=phase)
+            err: Optional[str] = None
+            try:
+                yield
+            except BaseException as e:
+                # the span records even when the body raises; WITHOUT the
+                # error mark a failed fit reads exactly like a fast one
+                err = type(e).__name__
+                raise
+            finally:
+                trace.close(sp, error_type=err)
+                wall = time.time() - t0
+                cur.stage_metrics.append(StageMetric(
+                    stage_name=stage_name, uid=uid, phase=phase,
+                    wall_seconds=wall, n_rows=n_rows,
+                    n_stages_fused=n_stages_fused,
+                    error=err is not None, error_type=err))
+                self.event("stage_end", stage=stage_name, uid=uid,
+                           phase=phase, wall_seconds=round(wall, 6),
+                           error=err is not None,
+                           **({"error_type": err} if err else {}))
 
     def kernel(self, name: str, wall_seconds: float, bytes_hbm: float,
                cold: Optional[bool] = None,
@@ -761,15 +793,3 @@ class MetricsCollector:
 
 # the process-wide collector the workflow engine reports to
 collector = MetricsCollector()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Device-timeline tracing via jax.profiler (the reference's Spark UI /
-    event-log slot). View with TensorBoard or xprof."""
-    import jax
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

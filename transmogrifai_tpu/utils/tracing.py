@@ -7,11 +7,9 @@ kernel / sweep-round) with the two costs that dominate JAX/TPU runs
 attributed per span:
 
 - **XLA recompiles** — a `jax.monitoring` listener counts every backend
-  compile and books it to the innermost open span (with a
-  lowered-executable-count fallback for a run that was enabled before
-  jax was imported),
-  making claims like PR 3's "bounded recompiles on the bucket ladder"
-  runtime-verifiable from any traced run;
+  compile and books it to the innermost open span, making claims like
+  PR 3's "bounded recompiles on the bucket ladder" runtime-verifiable
+  from any traced run;
 - **device-memory watermarks** — `Device.memory_stats()` sampled at span
   close (None-safe: CPU hosts report nothing and the attrs are omitted).
 
@@ -47,7 +45,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
     "Span", "TraceTree", "RecompileTracker", "tracker", "EventLog",
-    "register_jit_fallback", "device_memory_attrs", "chrome_trace",
+    "device_memory_attrs", "chrome_trace",
     "write_chrome_trace", "trace_report", "trace_report_rc",
     "event_log_paths", "iter_events", "requests_report",
     "requests_report_rc", "fmt_table",
@@ -138,8 +136,8 @@ class TraceTree:
         self._stack: List[Span] = []
         self._next_id = 1
         self._lock = threading.RLock()
-        # parent span_id -> children, so subtree walks (fallback compile
-        # accounting, self-time) stay O(subtree), not O(all spans)
+        # parent span_id -> children, so subtree walks (self-time) stay
+        # O(subtree), not O(all spans)
         self._children: Dict[int, List[Span]] = {}
 
     # -- clock -------------------------------------------------------------
@@ -161,7 +159,6 @@ class TraceTree:
             if parent is not None:
                 self._children.setdefault(parent, []).append(sp)
             self._stack.append(sp)
-        tracker.on_span_open(sp)
         return sp
 
     def children_of(self, span_id: int) -> List[Span]:
@@ -190,10 +187,8 @@ class TraceTree:
                         break
                     if top.t_end is None:
                         top.t_end = sp.t_end
-                    top.attrs.pop("_jit_cache0", None)
         if already_closed:
             return
-        tracker.on_span_close(sp, self)
         mem = device_memory_attrs()
         if mem:
             sp.attrs.update(mem)
@@ -248,10 +243,8 @@ class TraceTree:
 
     def close_all(self) -> None:
         # pop-then-close WITHOUT holding the tree lock across close():
-        # close() re-enters the lock itself and then calls the tracker
-        # hooks outside it — holding the lock here would invert the
-        # tracker->tree order _on_event uses (THR003: a compile landing
-        # on another thread during close_all would deadlock)
+        # close() re-enters the lock itself and samples device memory
+        # outside it
         while True:
             with self._lock:
                 if not self._stack:
@@ -270,54 +263,19 @@ class TraceTree:
 
 # -- recompile attribution ---------------------------------------------------
 
-# jitted entry points registered for the listener-less fallback (collection
-# enabled before jax was imported): the sum of
-# their lowered-executable cache sizes is sampled at span open/close and the
-# delta (minus what nested spans already booked) becomes the span's compile
-# count. Coarser than the listener — it only sees registered functions —
-# but needs nothing from jax beyond the public-ish _cache_size().
-_FALLBACK_JITS: List[Any] = []
-
-
-def register_jit_fallback(*fns: Any) -> None:
-    """Register jitted callables whose executable count stands in for the
-    compile counter when no listener is installed. Idempotent."""
-    for fn in fns:
-        if fn is not None and all(fn is not g for g in _FALLBACK_JITS):
-            _FALLBACK_JITS.append(fn)
-
-
-def _fallback_cache_size() -> int:
-    total = 0
-    for fn in _FALLBACK_JITS:
-        size = getattr(fn, "_cache_size", None)
-        if size is None:
-            continue
-        try:
-            total += int(size())
-        except Exception:
-            pass
-    return total
-
-
 class RecompileTracker:
     """Books every XLA backend compile to the innermost open span.
 
-    Primary path: a `jax.monitoring` duration listener on
+    A `jax.monitoring` duration listener on
     /jax/core/compile/backend_compile_duration (registered once, gated on
     an active tree so an idle process pays one dict lookup per compile).
-    Fallback (collection enabled before jax was imported):
-    lowered-executable-count sampling over
-    `register_jit_fallback` functions at span boundaries."""
+    jax is only consulted when something else already imported it (the
+    module contract): in a process without it nothing compiles and there
+    is nothing to count."""
 
     def __init__(self) -> None:
         self._tree: Optional[TraceTree] = None
         self._listener_installed = False
-        # override switch (tests force the fallback path with it); the
-        # per-activation choice lives in _mode so a pre-jax enable()
-        # falling back does not permanently disable the listener path
-        self._use_monitoring = True
-        self._mode = "monitoring"
         self.total_compiles = 0
         self.total_compile_seconds = 0.0
         self.total_cache_hits = 0
@@ -333,8 +291,7 @@ class RecompileTracker:
         # concurrently, and `total_compiles += 1` unlocked loses
         # updates exactly where the zero-recompile contract reads them.
         # Ordering: _lock may be held while taking the tree's lock,
-        # never the reverse (TraceTree calls the tracker hooks OUTSIDE
-        # its own lock)
+        # never the reverse
         self._lock = threading.RLock()
 
     @property
@@ -354,24 +311,12 @@ class RecompileTracker:
             self.total_cache_hits = 0
             self._pending = threading.local()
             self.by_program = {}
-            if self._monitoring_available():
+            if sys.modules.get("jax") is not None:
                 self._install_listener()
-                self._mode = "monitoring"
-            else:
-                self._mode = "fallback"
 
     def deactivate(self) -> None:
         with self._lock:
             self._tree = None
-
-    def _monitoring_available(self) -> bool:
-        if not self._use_monitoring:
-            return False
-        # only consult jax when something else already imported it (the
-        # module contract): a host-only process enabling collection must
-        # not pay the jax import here. With jax absent BOTH tracker paths
-        # are inert — there is nothing compiling to count.
-        return sys.modules.get("jax") is not None
 
     def _install_listener(self) -> None:
         if self._listener_installed:
@@ -380,15 +325,12 @@ class RecompileTracker:
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
         self._listener_installed = True
 
-    # -- monitoring path ---------------------------------------------------
     def _on_event(self, event: str, duration: float, **_kw: Any) -> None:
         with self._lock:
             tree = self._tree
             # the listener survives activate/deactivate cycles (jax has
-            # no public unregister); in fallback mode it must stay
-            # silent or a later re-activation would double-book with
-            # the sampler
-            if tree is None or self._mode != "monitoring":
+            # no public unregister)
+            if tree is None:
                 return
             if event == _CACHE_HIT_EVENT:
                 # a persistent-cache retrieval fires immediately BEFORE
@@ -423,42 +365,6 @@ class RecompileTracker:
                         int(sp.attrs.get("cache_hits", 0)) + 1
                 self.by_program[sp.name] = \
                     self.by_program.get(sp.name, 0) + 1
-
-    # -- fallback path (span-boundary sampling) ----------------------------
-    def on_span_open(self, sp: Span) -> None:
-        with self._lock:
-            if self._tree is None or self._mode != "fallback":
-                return
-        sp.attrs["_jit_cache0"] = _fallback_cache_size()
-
-    def on_span_close(self, sp: Span, tree: TraceTree) -> None:
-        with self._lock:
-            active = self._tree is tree and self._mode == "fallback"
-        if not active:
-            sp.attrs.pop("_jit_cache0", None)
-            return
-        base = sp.attrs.pop("_jit_cache0", None)
-        if base is None:
-            return
-        delta = _fallback_cache_size() - int(base)
-        # subtract everything already booked in the WHOLE subtree (not
-        # just direct children): compiles of a grandchild are inside this
-        # span's cache-size delta too, and counting them again would
-        # inflate every ancestor of the booking span. The children index
-        # keeps this O(subtree) per close.
-        booked = 0
-        todo = tree.children_of(sp.span_id)
-        while todo:
-            s = todo.pop()
-            booked += int(s.attrs.get("compiles", 0))
-            todo.extend(tree.children_of(s.span_id))
-        own = max(delta - booked, 0)
-        if own:
-            sp.attrs["compiles"] = int(sp.attrs.get("compiles", 0)) + own
-            with self._lock:
-                self.by_program[sp.name] = \
-                    self.by_program.get(sp.name, 0) + own
-                self.total_compiles += own
 
 
 #: process-wide tracker the collector activates per enable()
