@@ -25,6 +25,14 @@ from transmogrifai_tpu.stages.params import param_grid
 from transmogrifai_tpu.utils.metrics import MetricsCollector, collector
 
 
+@pytest.fixture(autouse=True)
+def collection_off():
+    """test_serving, test_monitor_serving and test_ingest leave the
+    process-wide collector enabled; whichever file shares their worker
+    must not inherit that."""
+    collector.disable()
+
+
 def profiled(tmp_path, body):
     """Run body() inside a profiler session (Python tracer off, as the
     benchmark's); returns (body's value, the `tmog.` host events as dicts
@@ -243,9 +251,13 @@ def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
         assert len(fetches) == 3    # folds x one chunk of two grid points
         assert all(inside(f, ev) for f in fetches)
         place, = named(events, "tmog.validate_phase:device_place")
-        # X, y and the default weights and masks all came from the host
-        assert place["stats"]["h2d_bytes"] == \
-            X.nbytes + y.nbytes + y.nbytes + 3 * y.nbytes
+        # X and y came from the host; the default weights and the masks
+        # were made on the device (folds.assign_fold_masks)
+        assert place["stats"]["h2d_bytes"] == X.nbytes + y.nbytes
+        fa, = named(events, "tmog.validate_phase:fold_assign")
+        assert fa["stats"]["route"] == "device"
+        assert fa["stats"]["rows"] == 400 and fa["stats"]["folds"] == 3
+        assert "stratify" in fa["stats"]
     if route == "mask_folds":
         for e in named(events, "tmog.validate_phase:tree_fit"):
             assert e["stats"]["lanes"] == 3 and e["stats"]["depth"] == 2

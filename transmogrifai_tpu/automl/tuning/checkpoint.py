@@ -4,12 +4,13 @@ Reference gap filled per SURVEY §5: the reference has no mid-sweep recovery
 (Spark task retry is its whole failure story); the TPU build checkpoints the
 model-selection sweep so a preempted run resumes without refitting finished
 (model x grid) cells — deterministic replay comes from the seeded fold
-assignment (Validator._assign_folds) plus this record.
+assignment (folds.assign_fold_masks: the same folds from the same seed on
+every backend) plus this record.
 
 Format: JSON-lines, one record per validated (model, grid) with its fold
 metrics, keyed by a stable hash of (model class, grid, folds, seed,
-stratify, metric). Orbax-style atomic append (write + flush) keeps partial
-lines out.
+stratify, metric, fold-assignment version). Orbax-style atomic append
+(write + flush) keeps partial lines out.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import hashlib
 import json
 import os
 from typing import Any, Dict, List, Optional
+
+from .folds import FOLD_ASSIGNMENT_VERSION
 
 
 def data_fingerprint(X, y) -> str:
@@ -43,6 +46,9 @@ def sweep_key(model_class: str, grid: Dict[str, Any], n_folds: int,
     payload = json.dumps(
         {"model": model_class, "grid": {k: grid[k] for k in sorted(grid)},
          "folds": n_folds, "seed": seed, "stratify": stratify,
+         # WHICH rows each fold holds out follows from (seed, stratify) and
+         # the algorithm: records written under another one invalidate
+         "fold_assignment": FOLD_ASSIGNMENT_VERSION,
          "metric": metric, "data": data_fp,
          # compute path + its statistically relevant knobs (e.g.
          # "mask_folds" vs "sequential" tree fits, sweep dtype) — metrics

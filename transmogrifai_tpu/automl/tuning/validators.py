@@ -29,6 +29,7 @@ from ...models.prediction import make_prediction_column
 from ...ops import metrics_ops as M
 from ...stages.params import ParamMap
 from ...utils.metrics import collector
+from .folds import assign_fold_masks, fold_key
 
 
 def _phase(name: str, **attrs: Any):
@@ -256,25 +257,25 @@ class Validator:
         self.mesh = mesh
 
     # -- folds -------------------------------------------------------------
-    def fold_masks(self, y: np.ndarray) -> np.ndarray:
-        """[F, n] float32 train-membership masks (1=train, 0=validation)."""
+    def _fold_spec(self) -> Dict[str, Any]:
+        """Static arguments of folds.assign_fold_masks that say how this
+        validator holds rows out: `n_folds`, `val_fraction`."""
         raise NotImplementedError
 
-    def _assign_folds(self, y: np.ndarray, n_folds: int) -> np.ndarray:
-        """Per-row fold id; stratified round-robin within each class when
-        stratify is on (reference prepareStratification:203)."""
-        rng = np.random.default_rng(self.seed)
-        n = len(y)
-        fold_of = np.empty(n, np.int32)
-        if self.stratify:
-            for cls in np.unique(y):
-                idx = np.flatnonzero(y == cls)
-                rng.shuffle(idx)
-                fold_of[idx] = np.arange(len(idx)) % n_folds
-        else:
-            perm = rng.permutation(n)
-            fold_of[perm] = np.arange(n) % n_folds
-        return fold_of
+    def device_fold_masks(self, y) -> jax.Array:
+        """[F, n] float32 train-membership masks (1=train, 0=validation) on
+        the device: one dispatch of folds.assign_fold_masks, a function of
+        (seed, rows, folds or ratio, and `y` when stratified) alone —
+        the same for a host `y` and a device `y`, on any backend."""
+        return assign_fold_masks(
+            fold_key(self.seed),
+            jnp.asarray(y, jnp.float32) if self.stratify else None,
+            n=len(y), stratify=self.stratify, **self._fold_spec())
+
+    def fold_masks(self, y) -> np.ndarray:
+        """The same masks on the host: what validate() ran on, bit for
+        bit, for callers that index them with numpy."""
+        return np.asarray(self.device_fold_masks(y))
 
     # -- validation --------------------------------------------------------
     def validate(self, models: Sequence[Tuple[PredictorEstimator, List[ParamMap]]],
@@ -292,11 +293,13 @@ class Validator:
                 type(self).__name__, kind="validate", rows=len(y),
                 folds=n_folds, models=len(models),
                 grid_points=sum(max(len(g), 1) for _, g in models)):
-            with _phase("fold_assign"):
+            with _phase("fold_assign",
+                        route="device" if masks is None else "external",
+                        rows=len(y), folds=n_folds, stratify=self.stratify):
                 if w is None:
-                    w = np.ones_like(y, np.float32)
+                    w = jnp.ones(len(y), jnp.float32)
                 if masks is None:
-                    masks = self.fold_masks(y)
+                    masks = self.device_fold_masks(y)
                     self._external_mask_tag = ""
                 else:
                     # checkpoint cells must be keyed by WHICH masks ran:
@@ -1060,6 +1063,8 @@ class Validator:
     def _validate_sequential(self, est, grids, X, y, w, masks
                              ) -> List[ValidatedModel]:
         metric = self.evaluator.default_metric
+        # the one route that indexes rows on the host
+        w, masks = np.asarray(w), np.asarray(masks)
         ckpt, keys, results = self._cell_bookkeeping(
             est, grids, X, y, metric, masks.shape[0],
             path=self._sweep_path(
@@ -1108,12 +1113,8 @@ class CrossValidation(Validator):
             raise ValueError("num_folds must be >= 2")
         self.num_folds = int(num_folds)
 
-    def fold_masks(self, y: np.ndarray) -> np.ndarray:
-        fold_of = self._assign_folds(y, self.num_folds)
-        masks = np.ones((self.num_folds, len(y)), np.float32)
-        for f in range(self.num_folds):
-            masks[f, fold_of == f] = 0.0
-        return masks
+    def _fold_spec(self) -> Dict[str, Any]:
+        return {"n_folds": self.num_folds}
 
 
 class TrainValidationSplit(Validator):
@@ -1129,18 +1130,5 @@ class TrainValidationSplit(Validator):
             raise ValueError("train_ratio must be in (0, 1)")
         self.train_ratio = float(train_ratio)
 
-    def fold_masks(self, y: np.ndarray) -> np.ndarray:
-        rng = np.random.default_rng(self.seed)
-        n = len(y)
-        mask = np.ones((1, n), np.float32)
-        if self.stratify:
-            for cls in np.unique(y):
-                idx = np.flatnonzero(y == cls)
-                rng.shuffle(idx)
-                n_val = int(round(len(idx) * (1.0 - self.train_ratio)))
-                mask[0, idx[:n_val]] = 0.0
-        else:
-            perm = rng.permutation(n)
-            n_val = int(round(n * (1.0 - self.train_ratio)))
-            mask[0, perm[:n_val]] = 0.0
-        return mask
+    def _fold_spec(self) -> Dict[str, Any]:
+        return {"n_folds": 1, "val_fraction": 1.0 - self.train_ratio}
