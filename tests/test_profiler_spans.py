@@ -183,6 +183,12 @@ def _route_case(route, monkeypatch):
             common, device_place=1, record=1,
             **{"sweep_fit:glm_streamed:OpLogisticRegression": 1,
                "sweep_eval:glm_streamed_eval:OpLogisticRegression": 1})
+    if route == "streamed_multiclass":
+        monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+        return OpLogisticRegression(max_iter=10), lr_grid, dict(
+            common, label_classes=1, device_place=1, record=1,
+            **{"sweep_fit:glm_streamed:OpLogisticRegression": 1,
+               "sweep_eval:glm_streamed_eval:OpLogisticRegression": 1})
     if route == "vmapped":
         return OpLogisticRegression(max_iter=10), lr_grid, dict(
             common, device_place=1, record=1,
@@ -197,18 +203,24 @@ def _route_case(route, monkeypatch):
         common, record=2, **{"sweep_fit:sequential:OpNaiveBayes": 2})
 
 
-@pytest.mark.parametrize("route", ["streamed", "vmapped", "mask_folds",
-                                   "sequential"])
+@pytest.mark.parametrize("route", ["streamed", "streamed_multiclass",
+                                   "vmapped", "mask_folds", "sequential"])
 def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
     est, grids, expect = _route_case(route, monkeypatch)
     X, y = _data()
     if route == "sequential":
         X = np.abs(X)       # naive Bayes wants non-negative features
-    cv = CrossValidation(Evaluators.BinaryClassification.au_roc(),
-                         num_folds=3, seed=5)
+    evaluator, problem = Evaluators.BinaryClassification.au_roc(), "binary"
+    if route == "streamed_multiclass":
+        y = y + (X[:, 0] > 0.5)         # three classes
+        evaluator = Evaluators.MultiClassification.error()
+        problem = "multiclass"
+    cv = CrossValidation(evaluator, num_folds=3, seed=5)
     best, events = profiled(
-        tmp_path, lambda: cv.validate([(est, grids)], X, y))
-    assert {v.route.split(":")[0] for v in best.validated} == {route}
+        tmp_path, lambda: cv.validate([(est, grids)], X, y,
+                                      problem_type=problem))
+    assert {v.route.split(":")[0] for v in best.validated} \
+        == {"streamed" if route.startswith("streamed") else route}
 
     root, = [e for e in events if e["name"].startswith("tmog.validate:")]
     assert root["name"] == "tmog.validate:CrossValidation"
@@ -234,19 +246,38 @@ def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
         if e not in top:
             assert sum(inside(e, t) for t in top) == 1, e["name"]
 
-    if route == "streamed":
+    if route in ("streamed", "streamed_multiclass"):
+        classes = 3 if route == "streamed_multiclass" else 2
         fit, = named(events,
                      "tmog.sweep_fit:glm_streamed:OpLogisticRegression")
-        rounds = [e for e in events
-                  if e["name"].startswith("tmog.sweep_round:glm_round[")]
+        assert fit["stats"]["classes"] == classes
+        rounds = [e for e in events if e["name"].startswith(
+            "tmog.sweep_round:" + ("mlr" if classes == 3 else "glm")
+            + "_round[")]
         assert rounds and all(inside(r, fit) for r in rounds)
         for r in rounds:    # one prep and one fetch a round
             assert r["stats"]["bucket"] >= r["stats"]["active"] >= 1
             for step in ("round_prep", "round_fetch"):
                 assert sum(inside(e, r) for e in
                            named(events, f"tmog.host_step:{step}")) == 1
+        gram = named(events, "tmog.host_step:gram_factor")
+        if classes == 3:
+            # 10 iterations in rounds of 5, no lane retired; the Gram and
+            # factor step once a sweep, before the first round
+            assert len(rounds) == 2
+            assert all(r["stats"]["classes"] == 3
+                       and r["stats"]["iters_budget"] == 5 for r in rounds)
+            assert len(gram) == 1 and inside(gram[0], fit)
+            assert gram[0]["end"] <= rounds[0]["start"]
+            assert gram[0]["stats"]["folds"] == 3
+            lc, = named(events, "tmog.validate_phase:label_classes")
+            fa, = named(events, "tmog.validate_phase:fold_assign")
+            assert lc["end"] <= fa["start"]
+        else:
+            assert not gram
         ev, = named(events,
                     "tmog.sweep_eval:glm_streamed_eval:OpLogisticRegression")
+        assert ev["stats"]["classes"] == classes
         fetches = named(events, "tmog.host_step:metric_fetch")
         assert len(fetches) == 3    # folds x one chunk of two grid points
         assert all(inside(f, ev) for f in fetches)

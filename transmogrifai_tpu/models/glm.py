@@ -195,6 +195,11 @@ class OpLogisticRegression(PredictorEstimator):
     # streamed coefficients match this estimator's fit_arrays within tol
     # (tests/test_glm_convergence.py pins it).
     streamed_loss = "logistic"
+    # large multiclass sweeps stream the multinomial rounds
+    # (ops/glm_sweep.sweep_mlr_streamed_rounds): fit_softmax's solver lane
+    # for lane, up to the full max_iter (the vmapped route and the winner
+    # refit below cap it at 30)
+    streamed_multiclass_loss = "softmax"
 
     @classmethod
     def _declare_params(cls):

@@ -999,9 +999,12 @@ def _source_round_acc0(Lb: int, d_work: int):
             jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32))
 
 
-def _new_round_state(L: int, d: int) -> Dict[str, Any]:
-    return {"B": np.zeros((L, d), np.float32),
-            "b0": np.zeros(L, np.float32),
+def _new_round_state(L: int, d: int, n_classes: int = 0) -> Dict[str, Any]:
+    """Resumable state of a round driver; the multinomial driver's
+    coefficients carry a class axis ([L, d, K], [L, K])."""
+    tail = (n_classes,) if n_classes else ()
+    return {"B": np.zeros((L, d) + tail, np.float32),
+            "b0": np.zeros((L,) + tail, np.float32),
             "delta": np.full(L, np.inf, np.float32),
             "iters": np.zeros(L, np.int32),
             "retired": np.zeros(L, bool), "warmed": False,
@@ -1356,3 +1359,353 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
     (bf16 X stays bf16; f32 accumulation)."""
     return jnp.matmul(X, B_f.T.astype(X.dtype),
                       preferred_element_type=jnp.float32) + b0_f[None, :]
+
+
+# -- streamed multinomial route (softmax loss, Boehning's bound) --------------
+#
+# The multiclass sibling of the IRLS rounds above, for ONE device and a
+# resident matrix (a mesh or a RowSource keeps the vmapped route). The
+# solver is ops/glm.fit_softmax's, lane for lane: Boehning's bound makes the
+# curvature the CONSTANT A_l = 0.5 (1 - 1/K) Xs^T W_f Xs / sum(W_f) +
+# (l2_l + 1e-6) I, so the weighted Gram is built once per FOLD and sweep
+# (`mlr_gram_factor`), Cholesky-factored once per lane, and every iteration
+# is one pass over X: tile-wise logits -> softmax -> residual -> gradient,
+# nothing [n, K] resident. Departures from fit_softmax: standardization uses
+# the global weights (module docstring), a lane stops at its own delta <= tol
+# (fit_softmax always runs max_iter), and no pathwise warm round — an MM
+# step is cheap next to a Newton step, and a cold start keeps a lane's
+# iterates those of fit_softmax.
+
+# bytes of one [lanes * K, c] f32 logits block; the softmax holds a handful.
+# On the v5e the round is quickest while they all stay in VMEM (128 MiB):
+# 0.108 s an iteration at 16 MB (c = 8 192 for 16 lanes x 32 classes) and
+# 0.109 at 32 MB at 25M x 64; with four more blocks live (before the
+# softmax's broadcasts were fused, _mlr_round_core) 32 MB was past the edge,
+# 0.397 against 0.204, and 64 MB 0.751 (PERF.md, PR 25). 16 MB keeps a
+# factor 2 from it.
+_MLR_BLOCK_BYTES = 16 << 20
+
+
+def _mlr_row_block(lanes_k: int, n: int) -> int:
+    c = _ROW_BLOCK
+    while c > 1_024 and c * lanes_k * 4 > _MLR_BLOCK_BYTES:
+        c //= 2
+    return min(c, n)
+
+
+def streamed_mlr_route_ok(d: int, lanes: int, n_classes: int,
+                          budget_bytes: float) -> bool:
+    """Can the multinomial rounds take a (d features, lanes, K classes)
+    sweep within `budget_bytes`? The Gram step is the narrow einsum, so
+    d <= TRI_MAX_D; the transients are ~8 blocks of [bucket * K, c] f32 at
+    the smallest row block."""
+    if d > TRI_MAX_D:
+        return False
+    return bucket_lanes(lanes) * n_classes * 1_024 * 4.0 * 8.0 \
+        <= budget_bytes
+
+
+def _split_low(B, dtype):
+    """(hi, lo) parts of f32 coefficients for a contraction against a
+    `dtype` matrix: bf16 X keeps the MXU's bf16 path, and the second part
+    gives back what rounding B to bf16 would lose (4e-3 relative, the same
+    for every row, so it would shift the fixed point). lo is None for an
+    f32 matrix."""
+    hi = B.astype(dtype)
+    if jnp.dtype(dtype) == jnp.float32:
+        return hi, None
+    return hi, (B - hi.astype(jnp.float32)).astype(dtype)
+
+
+def _mlr_blocks(n: int, c: int, XT, *rows):
+    """(number of row blocks, take(i)) over the resident matrix WITHOUT a
+    padded, reshaped or re-laid-out copy of it. XT is X.T [d, n]: on the
+    chip a [n, d] matrix of few columns lives rows-minor, so its transpose
+    is the layout it already has, and a block is one dynamic slice along
+    the minor axis. take(i) -> (xT [d, c], fresh [c], row arrays' blocks):
+    the last block starts early (clamped) and `fresh` zeroes the rows the
+    block before already had; multiply it into the block's weights.
+    `rows` are arrays whose LAST axis is the row axis."""
+    nb = -(-n // c)
+
+    def take(i):
+        start = jnp.minimum(i * c, n - c)
+        fresh = ((start + jnp.arange(c)) >= i * c).astype(jnp.float32)
+        cut = [jax.lax.dynamic_slice_in_dim(a, start, c, axis=a.ndim - 1)
+               for a in (XT,) + rows]
+        return (cut[0], fresh) + tuple(cut[1:])
+    return nb, take
+
+
+def mlr_logits_t(xT, Bt_hi, Bt_lo):
+    """[lanes * K, c] f32 logits of one row block xT [d, c], rows on the
+    minor axis (the softmax then reduces over sublanes, the block stays
+    dense). The two parts of the coefficients are ONE contraction over
+    2 d ([hi | lo] against the block stacked on itself): two contractions
+    write the [lanes * K, c] result twice, and on the v5e the write, not
+    the MXU, is what a d = 64 contraction costs."""
+    if Bt_lo is None:
+        return jnp.matmul(Bt_hi, xT, preferred_element_type=jnp.float32)
+    return jnp.matmul(jnp.concatenate([Bt_hi, Bt_lo], axis=1),
+                      jnp.concatenate([xT, xT], axis=0),
+                      preferred_element_type=jnp.float32)
+
+
+def _standardized_t(xT, mean, inv_std, dtype):
+    """The block standardized on the fly, back in the matrix's dtype (the
+    contractions stay on the bf16 MXU path, like the binary rounds)."""
+    return ((xT.astype(jnp.float32) - mean[:, None]) * inv_std[:, None]) \
+        .astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n_classes",))
+def mlr_gram_factor(X: jax.Array, w: jax.Array, fold_masks: jax.Array,
+                    mean: jax.Array, std: jax.Array, lane_fold: jax.Array,
+                    l2: jax.Array, *, n_classes: int
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Once per sweep: ONE pass builds the F per-fold weighted Grams of the
+    standardized matrix; every lane's bound matrix A_l is its fold's Gram
+    plus its own ridge, factored here and never again. Returns (chol
+    [L, d, d] lower, hdiag [L, d])."""
+    n, d = X.shape
+    F = fold_masks.shape[0]
+    c = min(_ROW_BLOCK, n)
+    nb, take = _mlr_blocks(n, c, X.T, w, fold_masks)
+    inv_std = 1.0 / std
+
+    def body(i, Gf):
+        xT, fresh, w_blk, m_blk = take(i)
+        xs = _standardized_t(xT, mean, inv_std, X.dtype)         # [d, c]
+        wl = m_blk * (w_blk * fresh)[None, :]                    # [F, c]
+        xw = (wl[:, None, :] * xs.astype(jnp.float32)[None]) \
+            .astype(X.dtype).reshape(F * d, c)
+        return Gf + jnp.matmul(xw, xs.T,
+                               preferred_element_type=jnp.float32)
+
+    Gf = jax.lax.fori_loop(0, nb, body, jnp.zeros((F * d, d), jnp.float32))
+    wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)
+    coef = 0.5 * (1.0 - 1.0 / n_classes)
+    A = coef * (Gf.reshape(F, d, d) / wsum_f[:, None, None])[lane_fold] \
+        + (l2[:, None, None] + 1e-6) * jnp.eye(d, dtype=jnp.float32)[None]
+    hdiag = jnp.maximum(jnp.diagonal(A, axis1=1, axis2=2), EPS)
+    return jnp.linalg.cholesky(A), hdiag
+
+
+def _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
+                    chol, hdiag, iters_budget, tol, *, fit_intercept):
+    """Up to `iters_budget` bound-optimisation steps for one compacted lane
+    bucket, the multinomial twin of _round_core: sel [F, Lb] maps bucket
+    lanes to folds (all-zero columns are inert padding), B0 [Lb, d, K] /
+    b00 [Lb, K] carry standardized-space state between rounds, chol/hdiag
+    are the bucket's rows of mlr_gram_factor's result. The while cond
+    leaves as soon as every lane's delta clears tol. Returns (B, b0,
+    delta [Lb], iters)."""
+    n, d = X.shape
+    Lb, _, K = B0.shape
+    f32 = jnp.float32
+    coef = 0.5 * (1.0 - 1.0 / K)
+    wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
+    wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
+    c = _mlr_row_block(Lb * K, n)
+    nb, take = _mlr_blocks(n, c, X.T, y, w, fold_masks)
+    classes = jnp.arange(K, dtype=f32)[:, None]                   # [K, 1]
+    inv_std = 1.0 / std
+
+    def accumulate(B, b0):
+        Bt_hi, Bt_lo = _split_low(
+            B.transpose(0, 2, 1).reshape(Lb * K, d), X.dtype)
+
+        def body(i, acc):
+            gA, g0A = acc
+            xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
+            xs = _standardized_t(xT, mean, inv_std, X.dtype)
+            # the barrier keeps the softmax three-dimensional: without it
+            # XLA sinks the reshape below the elementwise ops, and the
+            # per-(lane, row) and per-(class, row) operands can then only
+            # be broadcast by writing four [Lb, K, c] blocks (a third of
+            # the round's time on the v5e)
+            z = jax.lax.optimization_barrier(
+                mlr_logits_t(xs, Bt_hi, Bt_lo).reshape(Lb, K, c)) \
+                + b0[:, :, None]
+            e = jnp.exp(z - z.max(axis=1, keepdims=True))
+            # one reciprocal a (lane, row), not one division a class
+            P = e * (1.0 / e.sum(axis=1, keepdims=True))  # [Lb, K, c]
+            Y = (y_blk[None, :] == classes).astype(f32)  # [K, c]
+            # lane weights: exact for any w (sel is 0/1, the MXU's default
+            # pass would round w to bf16)
+            wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
+                            precision=jax.lax.Precision.HIGHEST)  # [Lb, c]
+            R = (P - Y[None]) * wl[:, None, :]
+            gA = gA + jnp.einsum(
+                "lkc,dc->lkd", R.astype(X.dtype), xs,
+                preferred_element_type=f32).reshape(Lb * K, d)
+            return gA, g0A + R.sum(axis=2)
+
+        gA, g0A = jax.lax.fori_loop(
+            0, nb, body,
+            (jnp.zeros((Lb * K, d), f32), jnp.zeros((Lb, K), f32)))
+        return gA.reshape(Lb, K, d).transpose(0, 2, 1), g0A
+
+    def cond(state):
+        i, _, _, delta = state
+        return (i < iters_budget) & (delta.max() > tol)
+
+    def body(state):
+        i, B, b0, _ = state
+        gA, g0A = accumulate(B, b0)
+        G = gA / wsum_l[:, None, None] + l2[:, None, None] * B
+        B_new = B - jax.scipy.linalg.cho_solve((chol, True), G)
+        B_new = (jnp.sign(B_new) * jnp.maximum(
+            jnp.abs(B_new) - (l1[:, None] / hdiag)[:, :, None], 0.0))
+        b0_new = b0 - (g0A / wsum_l[:, None]) / coef if fit_intercept \
+            else b0
+        delta = jnp.abs(B_new - B).max(axis=(1, 2)) \
+            + jnp.abs(b0_new - b0).max(axis=1)
+        return i + 1, B_new, b0_new, delta
+
+    state = (jnp.asarray(0, jnp.int32), B0.astype(f32), b00.astype(f32),
+             jnp.full((Lb,), jnp.inf, f32))
+    i, B, b0, delta = jax.lax.while_loop(cond, body, state)
+    return B, b0, delta, i
+
+
+@functools.partial(jax.jit, static_argnames=("fit_intercept",))
+def sweep_mlr_round(X: jax.Array, y: jax.Array, w: jax.Array,
+                    fold_masks: jax.Array, sel: jax.Array, l1: jax.Array,
+                    l2: jax.Array, B0: jax.Array, b00: jax.Array,
+                    mean: jax.Array, std: jax.Array, chol: jax.Array,
+                    hdiag: jax.Array, iters_budget, tol, *,
+                    fit_intercept: bool = True):
+    """One retirement round of the multinomial sweep (see _mlr_round_core).
+    Compiled per (n, d, F, bucket, K) shape; iters_budget/tol are traced."""
+    return _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
+                           std, chol, hdiag, iters_budget, tol,
+                           fit_intercept=fit_intercept)
+
+
+def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
+                              n_classes: int, max_iter: int = 50,
+                              tol: float = 1e-6, fit_intercept: bool = True,
+                              standardize: bool = True,
+                              round_iters: Optional[int] = None,
+                              state: Optional[Dict[str, Any]] = None,
+                              on_round: Optional[Callable] = None
+                              ) -> Tuple[np.ndarray, np.ndarray,
+                                         Dict[str, Any]]:
+    """Host-driven streamed sweep of multinomial logistic regression: the
+    retirement loop of sweep_glm_streamed_rounds (rounds of K iterations,
+    lanes retire at their own delta <= tol or at max_iter, survivors
+    compact into `bucket_lanes` buckets, `state` / `on_round` checkpoint
+    every boundary and resume bit-identically) around `sweep_mlr_round`,
+    after ONE `mlr_gram_factor` pass. X/y/w/fold_masks are device arrays
+    on one device; y holds class ids 0..n_classes-1 as floats.
+
+    Returns (B [F, G, d, K] f32 RAW units, b0 [F, G, K], info)."""
+    from ..utils.metrics import collector as _collector
+
+    regs = np.asarray(regs, np.float32)
+    alphas = np.asarray(alphas, np.float32)
+    F, d, K = int(fold_masks.shape[0]), int(X.shape[1]), int(n_classes)
+    Gn = int(regs.shape[0])
+    L = F * Gn
+    Kr = max(int(round_iters if round_iters is not None
+                 else os.environ.get("TMOG_GLM_ROUND_ITERS",
+                                     str(ROUND_ITERS_DEFAULT))), 1)
+    max_iter, tol_f = int(max_iter), float(tol)
+    if standardize:
+        mean, std = glm_standardize_stats(X, w)
+    else:
+        mean, std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    lane_fold = np.repeat(np.arange(F, dtype=np.int32), Gn)
+    l1v = np.tile(regs * alphas, F).astype(np.float32)
+    l2v = np.tile(regs * (1.0 - alphas), F).astype(np.float32)
+    with _collector.trace_span("gram_factor", kind="host_step", folds=F,
+                               lanes=L, classes=K):
+        chol, hdiag = mlr_gram_factor(
+            X, w, fold_masks, mean, std, jnp.asarray(lane_fold),
+            jnp.asarray(l2v), n_classes=K)
+    st = state if state is not None else _new_round_state(L, d, K)
+
+    def run_round(idx, budget):
+        k = len(idx)
+        Lb = bucket_lanes(k)
+        with _collector.trace_span(
+                f"mlr_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
+                active=int(k), iters_budget=int(budget), classes=K):
+            with _collector.trace_span("round_prep", kind="host_step"):
+                # padding lanes: no fold (zero weights), lane idx[0]'s
+                # factor; B = 0 is their fixed point
+                lanes = np.full(Lb, idx[0], np.int64)
+                lanes[:k] = idx
+                sel = np.zeros((F, Lb), np.float32)
+                sel[lane_fold[idx], np.arange(k)] = 1.0
+                B0 = np.zeros((Lb, d, K), np.float32)
+                B0[:k] = st["B"][idx]
+                b00 = np.zeros((Lb, K), np.float32)
+                b00[:k] = st["b0"][idx]
+                pick = jnp.asarray(lanes)
+                args = (X, y, w, fold_masks, jnp.asarray(sel),
+                        jnp.asarray(l1v[lanes]), jnp.asarray(l2v[lanes]),
+                        jnp.asarray(B0), jnp.asarray(b00), mean, std,
+                        chol[pick], hdiag[pick],
+                        jnp.asarray(budget, jnp.int32),
+                        jnp.asarray(tol_f, jnp.float32))
+            out = sweep_mlr_round(*args, fit_intercept=bool(fit_intercept))
+            with _collector.trace_span("round_fetch", kind="host_step"):
+                # the host waits here for the round's program
+                Bb, b0b, db, it = (np.asarray(out[0]), np.asarray(out[1]),
+                                   np.asarray(out[2]), int(out[3]))
+        st["B"][idx] = Bb[:k]
+        st["b0"][idx] = b0b[:k]
+        st["delta"][idx] = db[:k]
+        st["iters"][idx] += it
+        st["rounds"] += 1
+        st["data_passes"] += it
+        st["lane_passes"] += it * k
+        st["padded_lane_passes"] += it * Lb
+        st["active_per_round"].append(k)
+        st["iters_per_round"].append(it)
+        st["bucket_sizes"].append(Lb)
+
+    while True:
+        active = np.flatnonzero(~st["retired"])
+        if active.size == 0:
+            break
+        run_round(active, max(1, min(
+            Kr, int((max_iter - st["iters"][active]).min()))))
+        st["retired"][active] = (st["delta"][active] <= tol_f) \
+            | (st["iters"][active] >= max_iter)
+        if on_round is not None:
+            on_round(st)
+
+    mean_h, std_h = np.asarray(mean, np.float32), np.asarray(std, np.float32)
+    B = st["B"] / std_h[None, :, None]
+    b0 = st["b0"] - (B * mean_h[None, :, None]).sum(1, dtype=np.float32)
+    info = {"route": "streamed", "kernel": "mlr_rounds",
+            "driver": "resident", "classes": K,
+            "glm_rounds": int(st["rounds"]),
+            # every full read of X by the route's programs: the round
+            # iterations, the Gram pass, the two passes of the moments
+            "data_passes": int(st["data_passes"]) + 1
+            + (2 if standardize else 0),
+            "gram_passes": F,
+            "lane_passes": int(st["lane_passes"]),
+            "padded_lane_passes": int(st["padded_lane_passes"]),
+            "lanes_total": L,
+            "lanes_retired": int((st["delta"] <= tol_f).sum()),
+            "lanes_at_cap": int(((st["delta"] > tol_f)
+                                 & (st["iters"] >= max_iter)).sum()),
+            "active_per_round": [int(v) for v in st["active_per_round"]],
+            "iters_per_round": [int(v) for v in st["iters_per_round"]],
+            "bucket_sizes": [int(v) for v in st["bucket_sizes"]]}
+    return B.reshape(F, Gn, d, K), b0.reshape(F, Gn, K), info
+
+
+def sweep_logits_fold_t(xT: jax.Array, B_f: jax.Array, b0_f: jax.Array
+                        ) -> jax.Array:
+    """[Gc, K, c] f32 logits of one row block xT [d, c] under one fold's
+    grid chunk of multinomial coefficients B_f [Gc, d, K], b0_f [Gc, K]."""
+    Gc, d, K = B_f.shape
+    hi, lo = _split_low(B_f.transpose(0, 2, 1).reshape(Gc * K, d),
+                        xT.dtype)
+    return mlr_logits_t(xT, hi, lo).reshape(Gc, K, -1) + b0_f[:, :, None]

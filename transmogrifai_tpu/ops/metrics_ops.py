@@ -296,17 +296,9 @@ class MultiMetrics(NamedTuple):
     error: jax.Array
 
 
-@partial(jax.jit, static_argnames=("n_classes",))
-def multiclass_metrics(pred: jax.Array, labels: jax.Array, n_classes: int,
-                       w: Optional[jax.Array] = None) -> MultiMetrics:
-    """Weighted precision/recall/F1/error from predicted & true class ids."""
-    pred = jnp.asarray(pred)
-    labels = jnp.asarray(labels)
-    if w is None:
-        w = jnp.ones(pred.shape, jnp.float32)
-    P = jax.nn.one_hot(pred.astype(jnp.int32), n_classes, dtype=w.dtype)
-    Y = jax.nn.one_hot(labels.astype(jnp.int32), n_classes, dtype=w.dtype) * w[:, None]
-    conf = Y.T @ P  # [true, pred], row-weighted once via Y
+def multiclass_metrics_from_confusion(conf: jax.Array) -> MultiMetrics:
+    """Weighted precision/recall/F1/error from one weighted confusion
+    count conf[true, pred]."""
     tp = jnp.diag(conf)
     per_pred = conf.sum(axis=0)
     per_true = conf.sum(axis=1)
@@ -319,6 +311,39 @@ def multiclass_metrics(pred: jax.Array, labels: jax.Array, n_classes: int,
     f1 = (f1_c * weights).sum()
     error = 1.0 - tp.sum() / jnp.maximum(conf.sum(), EPS)
     return MultiMetrics(precision=precision, recall=recall, f1=f1, error=error)
+
+
+@partial(jax.jit, static_argnames=("n_classes",))
+def multiclass_metrics(pred: jax.Array, labels: jax.Array, n_classes: int,
+                       w: Optional[jax.Array] = None) -> MultiMetrics:
+    """Weighted precision/recall/F1/error from predicted & true class ids."""
+    pred = jnp.asarray(pred)
+    labels = jnp.asarray(labels)
+    if w is None:
+        w = jnp.ones(pred.shape, jnp.float32)
+    P = jax.nn.one_hot(pred.astype(jnp.int32), n_classes, dtype=w.dtype)
+    Y = jax.nn.one_hot(labels.astype(jnp.int32), n_classes, dtype=w.dtype) * w[:, None]
+    # [true, pred], row-weighted once via Y
+    return multiclass_metrics_from_confusion(Y.T @ P)
+
+
+def confusion_lanes(pred: jax.Array, labels: jax.Array, w: jax.Array,
+                    n_classes: int) -> jax.Array:
+    """[G, K, K] weighted confusion counts conf[g, true, pred] of G lanes
+    of predicted class ids pred [G, c] over the same rows (labels [c],
+    weights [c]): the lane-batched count the streamed multiclass metric
+    pass sums block by block. Equal to multiclass_metrics' confusion on
+    the same predictions: the one-hots are exact in any precision, and
+    `highest` keeps the weights' f32 mantissas on the MXU; counts of unit
+    weights are exact integers up to 2**24 a cell. A label or prediction
+    outside 0..K-1 counts nowhere (jax.nn.one_hot's rule)."""
+    classes = jnp.arange(n_classes, dtype=jnp.int32)
+    P = (pred.astype(jnp.int32)[:, None, :] == classes[None, :, None]) \
+        .astype(jnp.float32)                                  # [G, K, c]
+    Y = (labels.astype(jnp.int32)[None, :] == classes[:, None]) \
+        .astype(jnp.float32) * w[None, :]                     # [K, c]
+    return jnp.einsum("tc,gpc->gtp", Y, P,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 class ThresholdMetrics(NamedTuple):
