@@ -383,3 +383,98 @@ class TestShardedStreamed:
             loss="logistic", max_iter=20, standardize=True)
         assert np.isfinite(np.asarray(B)).all()
         assert np.abs(np.asarray(B)).max() < 100.0  # no exploded scales
+
+
+class TestHeldoutOnceEval:
+    """The binned in-sweep rank metric scores every row ONCE and bins it
+    into its one held-out fold when the folds' held-out sets are disjoint
+    (validators._streamed_eval_heldout); the fold-by-fold loop over the
+    whole matrix stays for every other case and is its reference."""
+
+    @staticmethod
+    def _sweep(monkeypatch, val, grids, X, y, w, masks, force_loop):
+        """(fold metrics [G, F], telemetry, B [F, G, d], b0 [F, G]) of a
+        streamed sweep under external `masks`; force_loop makes the
+        validator see them as overlapping."""
+        fits = []
+        orig = V.Validator._streamed_fit
+
+        def spy(self, *a, **k):
+            out = orig(self, *a, **k)
+            fits.append((np.asarray(out[0]), np.asarray(out[1])))
+            return out
+        monkeypatch.setattr(V.Validator, "_streamed_fit", spy)
+        if force_loop:
+            monkeypatch.setattr(V, "_held_out_at_most_once",
+                                lambda masks: False)
+        best = val.validate([(OpLogisticRegression(max_iter=6), grids)],
+                            X, y, w=w, masks=masks)
+        assert {v.route for v in best.validated} == {"streamed"}
+        return (np.array([v.fold_metrics for v in best.validated]),
+                val.last_streamed_telemetry, *fits[-1])
+
+    @pytest.mark.parametrize("n_grid", [3, 11])
+    @pytest.mark.parametrize("folds", [1, 3, 5])
+    @pytest.mark.parametrize("metric", ["au_pr", "au_roc"])
+    def test_one_pass_equals_the_fold_by_fold_loop(self, monkeypatch,
+                                                   metric, folds, n_grid):
+        from transmogrifai_tpu.automl.tuning.validators import (
+            TrainValidationSplit)
+        from transmogrifai_tpu.ops import metrics_ops as M
+        monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+        monkeypatch.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
+        X, y = _binary(n=2500, d=6, seed=folds)
+        w = np.random.default_rng(9).uniform(0.5, 2.0, len(y)) \
+            .astype(np.float32)
+        ev = getattr(Evaluators.BinaryClassification, metric)()
+        # one split holds a quarter of the rows out and the rest by none
+        make = (lambda: TrainValidationSplit(ev, train_ratio=0.75, seed=3)) \
+            if folds == 1 else \
+            (lambda: CrossValidation(ev, num_folds=folds, seed=3))
+        masks = make().fold_masks(y)
+        assert masks.shape == (folds, len(y))
+        grids = [{"reg_param": float(r)}
+                 for r in np.geomspace(1e-4, 1.0, n_grid)]
+        once, tele, B, b0 = self._sweep(monkeypatch, make(), grids, X, y, w,
+                                        masks, force_loop=False)
+        loop, tele_loop, _, _ = self._sweep(monkeypatch, make(), grids, X,
+                                            y, w, masks, force_loop=True)
+        chunks = -(-n_grid // V.Validator._STREAMED_EVAL_CHUNK)
+        assert (tele["eval_route"], tele["passes"]) \
+            == ("heldout_once", chunks)
+        assert (tele_loop["eval_route"], tele_loop["passes"]) \
+            == ("per_fold", folds * chunks)
+        assert once.shape == (n_grid, folds)
+        np.testing.assert_allclose(once, loop, rtol=0, atol=1e-6)
+        # ... and the exact sorted metric of the sweep's own coefficients
+        # within the binned contract (ops/metrics_ops.au_pr_binned)
+        exact_fn = getattr(M, metric)
+        exact = np.array([[float(exact_fn(
+            jnp.asarray(X @ B[f, g] + b0[f, g]), jnp.asarray(y),
+            jnp.asarray((1.0 - masks[f]) * w))) for f in range(folds)]
+            for g in range(n_grid)])
+        np.testing.assert_allclose(once, exact, rtol=0, atol=2e-3)
+
+    def test_overlapping_external_masks_keep_the_loop(self, monkeypatch):
+        """A row held out by two folds breaks the one-pass route's
+        premise: the validator sees it on the masks and runs the loop,
+        whose values are those of the same masks forced through it."""
+        monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+        monkeypatch.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
+        X, y = _binary(n=1500, d=6, seed=4)
+        masks = _masks(y, folds=3)
+        assert V._held_out_at_most_once(masks)
+        assert V._held_out_at_most_once(jnp.asarray(masks))
+        masks[1, np.flatnonzero(masks[0] == 0)[0]] = 0.0
+        assert not V._held_out_at_most_once(masks)
+        assert not V._held_out_at_most_once(jnp.asarray(masks))
+        ev = Evaluators.BinaryClassification.au_pr()
+        grids = [{"reg_param": 0.01}, {"reg_param": 0.1}]
+        seen, tele, _, _ = self._sweep(
+            monkeypatch, CrossValidation(ev, num_folds=3, seed=3), grids,
+            X, y, None, masks, force_loop=False)
+        assert (tele["eval_route"], tele["passes"]) == ("per_fold", 3)
+        forced, _, _, _ = self._sweep(
+            monkeypatch, CrossValidation(ev, num_folds=3, seed=3), grids,
+            X, y, None, masks, force_loop=True)
+        np.testing.assert_array_equal(seen, forced)

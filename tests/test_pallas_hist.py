@@ -132,6 +132,107 @@ class TestBinnedLanes:
             assert abs(vals[l] - ref) < 1e-4
 
 
+class TestHeldoutOnceCounts:
+    """The k-fold rank metric's one-pass counts
+    (metrics_ops.heldout_cum_counts_lanes): a row goes into the histograms
+    of the ONE fold that holds it out, Gc grid points at a time."""
+
+    @staticmethod
+    def _rows(n, n_folds, Gc, seed):
+        rng = np.random.default_rng(seed)
+        scores = rng.normal(size=(Gc, n)).astype(np.float32)
+        y = (rng.uniform(size=n) < 0.4).astype(np.float32)
+        w = rng.uniform(0.2, 2.0, size=n).astype(np.float32)
+        # fold n_folds: held out by no fold, the row must vanish
+        fold_of = rng.integers(0, n_folds + 1, size=n).astype(np.int32)
+        return scores, y, w, fold_of
+
+    @staticmethod
+    def _numpy_counts(idx, y, w, fold_of, n_folds, n_bins):
+        """[n_folds, Gc, 2, n_bins] weighted (positive, negative)
+        histograms, float64, row by row in numpy."""
+        Gc, n = idx.shape
+        out = np.zeros((n_folds, Gc, 2, n_bins))
+        keep = fold_of < n_folds
+        for g in range(Gc):
+            for c, wv in enumerate((w * y, w * (1.0 - y))):
+                np.add.at(out[:, g, c], (fold_of[keep], idx[g, keep]),
+                          wv[keep])
+        return out
+
+    @pytest.mark.parametrize("layout", ["grid_as_features", "grid_in_slot"])
+    @pytest.mark.parametrize("n_folds,Gc,n", [(5, 6, 1100), (3, 8, 2048),
+                                               (1, 3, 777)])
+    def test_one_hist_call_matches_numpy(self, layout, n_folds, Gc, n):
+        """ONE hist_pallas call (interpret mode) over a ragged N with
+        dropped rows, in the form the sweep uses (grid points as the
+        kernel's features, the fold as its slot) and in the flattened form
+        with the slot `fold_of * Gc + g` (n_slots = F x Gc): same counts,
+        numpy's."""
+        from transmogrifai_tpu.ops import metrics_ops as M
+        n_bins = 128
+        scores, y, w, fold_of = self._rows(n, n_folds, Gc, seed=n)
+        idx = np.asarray(M._bin_idx(jnp.asarray(scores), n_bins))
+        ref = self._numpy_counts(idx, y, w, fold_of, n_folds, n_bins)
+        pay = np.stack([w * y, w * (1.0 - y)])
+        if layout == "grid_as_features":
+            hist = PH.hist_pallas(
+                jnp.asarray(idx), jnp.asarray(pay),
+                jnp.asarray(fold_of, jnp.float32)[None, :],
+                n_slots=n_folds, n_bins=n_bins, interpret=True)
+            got = np.asarray(hist).reshape(n_folds, 2, Gc, n_bins) \
+                .transpose(0, 2, 1, 3)
+        else:
+            S = n_folds * Gc
+            slot = np.where(fold_of[None, :] < n_folds,
+                            fold_of[None, :] * Gc + np.arange(Gc)[:, None],
+                            S)
+            hist = PH.hist_pallas(
+                jnp.asarray(idx.reshape(1, -1)),
+                jnp.asarray(np.tile(pay, (1, Gc))),
+                jnp.asarray(slot.reshape(1, -1), jnp.float32),
+                n_slots=S, n_bins=n_bins, interpret=True)
+            got = np.asarray(hist).reshape(n_folds, Gc, 2, n_bins)
+        np.testing.assert_allclose(got, ref, rtol=0, atol=1e-3)
+        assert abs(got.sum() - w[fold_of < n_folds].sum() * Gc) < 1e-1
+
+    @pytest.mark.parametrize("n_folds,Gc,n", [(5, 6, 1100), (1, 3, 777)])
+    def test_pallas_route_matches_jnp_twin_and_lanes_route(self, n_folds,
+                                                            Gc, n):
+        """The dispatcher's two routes agree, and both with the
+        (fold x grid)-lane route fed whole-matrix weights that are zero
+        outside the row's own fold — what the fold-by-fold loop bins."""
+        from transmogrifai_tpu.ops import metrics_ops as M
+        scores, y, w, fold_of = map(jnp.asarray,
+                                    self._rows(n, n_folds, Gc, seed=n + 1))
+        tp, fp = M._heldout_cum_counts_lanes_pallas(
+            scores, y, w, fold_of, n_folds, 256, interpret=True)
+        tj, fj = M.heldout_cum_counts_lanes(scores, y, w, fold_of, n_folds,
+                                            256)    # CPU: the jnp twin
+        assert tp.shape == fp.shape == (n_folds, Gc, 256)
+        np.testing.assert_allclose(np.asarray(tp), np.asarray(tj),
+                                   rtol=0, atol=1e-3)
+        np.testing.assert_allclose(np.asarray(fp), np.asarray(fj),
+                                   rtol=0, atol=1e-3)
+        for f in range(n_folds):
+            wl = jnp.broadcast_to((w * (fold_of == f))[None, :], (Gc, n))
+            tl, fl = M.binned_cum_counts_lanes(scores, y, wl, 256)
+            np.testing.assert_allclose(np.asarray(tj[f]), np.asarray(tl),
+                                       rtol=0, atol=1e-3)
+            np.testing.assert_allclose(np.asarray(fj[f]), np.asarray(fl),
+                                       rtol=0, atol=1e-3)
+        for fn, lanes in ((M.au_pr_heldout_lanes, M.au_pr_binned_lanes),
+                          (M.au_roc_heldout_lanes, M.au_roc_binned_lanes)):
+            vals = np.asarray(fn(scores, y, w, fold_of, n_folds, 256))
+            assert vals.shape == (n_folds, Gc)
+            for f in range(n_folds):
+                wl = jnp.broadcast_to((w * (fold_of == f))[None, :],
+                                      (Gc, n))
+                np.testing.assert_allclose(
+                    vals[f], np.asarray(lanes(scores, y, wl, 256)),
+                    rtol=0, atol=1e-6)
+
+
 def test_set_pallas_enabled_toggles_and_clears_caches():
     from transmogrifai_tpu.ops import trees as T2
     orig = T2.pallas_enabled()

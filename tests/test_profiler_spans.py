@@ -278,6 +278,10 @@ def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
         ev, = named(events,
                     "tmog.sweep_eval:glm_streamed_eval:OpLogisticRegression")
         assert ev["stats"]["classes"] == classes
+        # 400 rows: the exact (sorted) metric, and the multiclass counts,
+        # keep the fold-by-fold loop and its fetch a fold and grid chunk
+        assert ev["stats"]["eval_route"] == "per_fold"
+        assert ev["stats"]["passes"] == 3
         fetches = named(events, "tmog.host_step:metric_fetch")
         assert len(fetches) == 3    # folds x one chunk of two grid points
         assert all(inside(f, ev) for f in fetches)
@@ -294,3 +298,47 @@ def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
             assert e["stats"]["lanes"] == 3 and e["stats"]["depth"] == 2
         tb, = named(events, "tmog.validate_phase:tree_bin")
         assert tb["stats"]["bins"] == 8 and tb["stats"]["configs"] == 2
+
+
+@pytest.mark.parametrize("case,n_grid,route,passes", [
+    ("device_masks", 2, "heldout_once", 1),
+    ("device_masks", 11, "heldout_once", 2),    # two chunks, ragged tail
+    ("external_partition", 2, "heldout_once", 1),
+    ("external_overlap", 2, "per_fold", 3),
+])
+def test_binned_sweep_eval_says_its_route_and_fetches_once(
+        case, n_grid, route, passes, tmp_path, monkeypatch):
+    """A binned rank metric over folds whose held-out sets are disjoint
+    runs ONE metric program a grid chunk and ONE fetch a sweep, and the
+    sweep_eval span and last_streamed_telemetry say so; a row held out by
+    two folds keeps the loop and its fetch a fold."""
+    monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+    monkeypatch.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
+    X, y = _data()
+    cv = CrossValidation(Evaluators.BinaryClassification.au_pr(),
+                         num_folds=3, seed=5)
+    masks = None
+    if case != "device_masks":
+        masks = np.array(cv.fold_masks(y))      # writable
+        if case == "external_overlap":
+            masks[1, np.flatnonzero(masks[0] == 0)[0]] = 0.0
+    grids = param_grid(reg_param=list(np.geomspace(1e-3, 1.0, n_grid)))
+    best, events = profiled(
+        tmp_path, lambda: cv.validate(
+            [(OpLogisticRegression(max_iter=5), grids)], X, y, masks=masks))
+    assert {v.route for v in best.validated} == {"streamed"}
+    ev, = named(events,
+                "tmog.sweep_eval:glm_streamed_eval:OpLogisticRegression")
+    assert ev["stats"]["eval_route"] == route
+    assert ev["stats"]["passes"] == passes
+    assert ev["stats"]["cells"] == n_grid
+    tele = cv.last_streamed_telemetry
+    assert (tele["eval_route"], tele["passes"]) == (route, passes)
+    fetches = named(events, "tmog.host_step:metric_fetch")
+    assert len(fetches) == (1 if route == "heldout_once" else passes)
+    assert all(inside(f, ev) for f in fetches)
+    fa, = named(events, "tmog.validate_phase:fold_assign")
+    assert fa["stats"]["route"] == ("device" if masks is None
+                                    else "external")
+    assert all(len(v.fold_metrics) == 3 and np.all(np.isfinite(
+        v.fold_metrics)) for v in best.validated)

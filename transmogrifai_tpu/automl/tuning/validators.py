@@ -159,6 +159,16 @@ def _lanes_metric_fn(metric: str, problem_type: str, rank_bins):
     return None
 
 
+def _held_out_at_most_once(masks) -> bool:
+    """Does every row have validation weight (mask != 1) in at most one of
+    the [F, n] fold masks? Host masks are numpy's to count; device masks
+    are reduced on the device and ONE scalar comes back."""
+    if masks.shape[0] == 1:
+        return True
+    xp = jnp if isinstance(masks, jax.Array) else np
+    return int(xp.max(xp.sum(masks != 1.0, axis=0))) <= 1
+
+
 def label_classes(y) -> int:
     """Classes of a label vector of ids 0..K-1: max + 1. A device array is
     reduced on the device and ONE scalar comes back; a host array is
@@ -214,10 +224,60 @@ def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
     return jax.vmap(lambda col: mfn(col, y, vw, thr), in_axes=1)(s)
 
 
-# _streamed_eval's executables bake the lanes-kernel (pallas) choice in;
-# the kill switch clears them on toggle
+def _heldout_scores(X, masks, Bc, b0c):
+    """([Gc, n] f32 margins of every row under the grid chunk fitted by the
+    fold that holds the row out, fold_of [n] int32 with F for "held out by
+    none"): X is read ONCE, in row blocks, each ONE contraction against all
+    F x Gc coefficient columns (sweep_scores_fold's products: X's dtype,
+    float32 accumulation), the row's own fold's Gc scores selected from
+    the block. Nothing [n, F x Gc] is resident. Bc [F, Gc, d], b0c [F, Gc];
+    masks [F, n] whose held-out sets (entries != 1) are disjoint."""
+    from ...ops import glm_sweep as GS
+    F, Gc, d = Bc.shape
+    n = X.shape[0]
+    out = masks != 1.0
+    fold_of = jnp.where(jnp.any(out, axis=0), jnp.argmax(out, axis=0),
+                        F).astype(jnp.int32)
+    Ball = Bc.reshape(F * Gc, d).astype(X.dtype)
+    c = GS._mlr_row_block(F * Gc, n)
+    nb, take = GS._mlr_blocks(n, c, X.T, fold_of)
+
+    def body(i, scores):
+        xT, _, f_blk = take(i)
+        s = (jnp.matmul(Ball, xT, preferred_element_type=jnp.float32)
+             + b0c.reshape(F * Gc, 1)).reshape(F, Gc, c)
+        own = s[0]
+        for f in range(1, F):
+            own = jnp.where(f_blk[None, :] == f, s[f], own)
+        # the last block starts early: rows written twice, same values
+        return jax.lax.dynamic_update_slice_in_dim(
+            scores, own, jnp.minimum(i * c, n - c), axis=1)
+
+    return jax.lax.fori_loop(0, nb, body,
+                             jnp.zeros((Gc, n), jnp.float32)), fold_of
+
+
+@partial(jax.jit, static_argnames=("metric", "rank_bins"))
+def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, *, metric, rank_bins):
+    """[F, Gc] binned rank metrics of EVERY fold's grid chunk in one pass
+    over X, for folds whose held-out sets are disjoint (k-fold, a single
+    split): _streamed_eval called fold by fold scores and bins all n rows
+    F times, all but a row's own fold with weight zero. Values equal the
+    per-fold route's up to float32 summation order. The program's name
+    keeps `streamed_eval`: traces and the benchmark find the metric pass
+    by it."""
+    scores, fold_of = _heldout_scores(X, masks, Bc, b0c)
+    vw = (1.0 - jnp.min(masks, axis=0)) * w
+    fn = {"au_pr": M.au_pr_heldout_lanes,
+          "au_roc": M.au_roc_heldout_lanes}[metric]
+    return fn(scores, y, vw, fold_of, Bc.shape[0], rank_bins)
+
+
+# the metric programs' executables bake the lanes-kernel (pallas) choice
+# in; the kill switch clears them on toggle
 from ...ops import pallas_hist as _pallas_hist  # noqa: E402
 _pallas_hist.register_cache_consumer(_streamed_eval)
+_pallas_hist.register_cache_consumer(_streamed_eval_heldout)
 
 
 @partial(jax.jit,
@@ -268,6 +328,9 @@ class Validator:
         # utils/metrics.collector.sweep_convergence when collection is on)
         self.last_streamed_telemetry: Optional[Dict[str, Any]] = None
         self._external_mask_tag = ""  # set per validate() call
+        # are the folds' held-out sets disjoint? (set per validate() call;
+        # the streamed sweep's one-pass metric route needs it)
+        self._heldout_once = False
         # grid points swept per XLA call (None = auto from the HBM budget);
         # checkpoints land after every chunk, so a preempted vmapped sweep
         # resumes mid-grid
@@ -340,7 +403,10 @@ class Validator:
                 if masks is None:
                     masks = self.device_fold_masks(y)
                     self._external_mask_tag = ""
+                    # k folds or one split: a row is held out at most once
+                    self._heldout_once = True
                 else:
+                    self._heldout_once = _held_out_at_most_once(masks)
                     # checkpoint cells must be keyed by WHICH masks ran:
                     # external per-fold masks can share a data fingerprint
                     # across calls
@@ -865,29 +931,55 @@ class Validator:
                     keys, pending)
                 if sp is not None:
                     sp.attrs["kernel"] = sweep_info.get("kernel")
-            self._record_sweep_telemetry(est, sweep_info)
             rank_bins = self._rank_bins(X.shape[0])
             thr_d = jnp.asarray(margin_thr, jnp.float32)
+            F = int(masks.shape[0])
             chunk = min(self._STREAMED_EVAL_CHUNK, len(pending))
-            out = np.empty((masks.shape[0], len(pending)), np.float64)
+            # (cells, the same padded: every call shares one compiled shape)
+            chunks = []
+            for s in range(0, len(pending), chunk):
+                idx = list(range(s, min(s + chunk, len(pending))))
+                chunks.append(
+                    (idx, jnp.asarray(idx + [idx[-1]] * (chunk - len(idx)))))
+            # disjoint held-out sets and a lane-batched binned metric on one
+            # device: every row is scored and binned ONCE for all folds;
+            # otherwise fold by fold over the whole matrix
+            heldout_once = (
+                self._heldout_once and self.mesh is None and _lanes_metric_fn(
+                    metric, problem_type, rank_bins) is not None)
+            eval_info = {
+                "eval_route": "heldout_once" if heldout_once else "per_fold",
+                "passes": len(chunks) * (1 if heldout_once else F)}
+            self._record_sweep_telemetry(est, dict(sweep_info, **eval_info))
+            out = np.empty((F, len(pending)), np.float64)
             with collector.trace_span(
                     f"glm_streamed_eval:{type(est).__name__}",
                     kind="sweep_eval", cells=len(pending),
-                    classes=int(n_classes)):
-                for f in range(masks.shape[0]):
-                    vw = (1.0 - md[f]) * wd
-                    for s in range(0, len(pending), chunk):
-                        idx = list(range(s, min(s + chunk, len(pending))))
-                        padded = idx + [idx[-1]] * (chunk - len(idx))
-                        vals = _streamed_eval(
-                            Xd, yd, vw, B[f, jnp.asarray(padded)],
-                            b0[f, jnp.asarray(padded)], thr_d, metric=metric,
-                            problem_type=problem_type, n_classes=n_classes,
-                            rank_bins=rank_bins, chunk=chunk,
-                            use_lanes=self.mesh is None)
-                        with collector.trace_span("metric_fetch",
-                                                  kind="host_step"):
-                            out[f, idx] = np.asarray(vals)[:len(idx)]
+                    classes=int(n_classes), **eval_info):
+                if heldout_once:
+                    vals = [_streamed_eval_heldout(
+                        Xd, yd, wd, md, B[:, padded], b0[:, padded],
+                        metric=metric, rank_bins=rank_bins)
+                        for _, padded in chunks]
+                    # the chunks' [F, Gc] values wait on the device for
+                    # ONE fetch a sweep; the tail's padding comes last
+                    with collector.trace_span("metric_fetch",
+                                              kind="host_step"):
+                        out[:] = np.asarray(jnp.concatenate(
+                            vals, axis=1))[:, :len(pending)]
+                else:
+                    for f in range(F):
+                        vw = (1.0 - md[f]) * wd
+                        for idx, padded in chunks:
+                            vals = _streamed_eval(
+                                Xd, yd, vw, B[f, padded], b0[f, padded],
+                                thr_d, metric=metric,
+                                problem_type=problem_type,
+                                n_classes=n_classes, rank_bins=rank_bins,
+                                chunk=chunk, use_lanes=self.mesh is None)
+                            with collector.trace_span("metric_fetch",
+                                                      kind="host_step"):
+                                out[f, idx] = np.asarray(vals)[:len(idx)]
             with _phase("record", cells=len(pending)):
                 for j, gi in enumerate(pending):
                     fm = [float(v) for v in out[:, j]]

@@ -165,6 +165,65 @@ def _binned_cum_counts_lanes_pallas(scores, labels, w_lanes, n_bins,
     return tps, fps
 
 
+def heldout_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
+                             w: jax.Array, fold_of: jax.Array,
+                             n_folds: int, n_bins: int
+                             ) -> Tuple[jax.Array, jax.Array]:
+    """Weighted TP/FP cumulative counts [n_folds, Gc, n_bins] of a k-fold
+    sweep in ONE pass over the rows: a row is held out by at most one fold,
+    so it is binned once, into the histograms of that fold alone.
+
+    scores [Gc, n]: each row's margins under ITS OWN held-out fold's Gc
+    grid points; labels [n]; w [n] held-out weights; fold_of [n] int32,
+    the fold that holds the row out (n_folds: none does, the row is
+    dropped). n x Gc elements are binned, where binned_cum_counts_lanes
+    over (fold x grid) lanes of whole-matrix weights bins n_folds times as
+    many, all but one of every n_folds with weight zero. Same bins
+    (_bin_idx), weights and products as that route, same dispatch: on the
+    TPU ONE pallas histogram call in the tree histograms' own form — the
+    Gc grid points are its features, the fold is its slot, so the weights
+    and the fold are read once a ROW (ops/pallas_hist.py)."""
+    if jax.default_backend() == "tpu":
+        from . import pallas_hist
+        if pallas_hist.available():
+            return _heldout_cum_counts_lanes_pallas(
+                scores, labels, w, fold_of, n_folds, n_bins)
+    return _heldout_cum_counts_lanes_jnp(scores, labels, w, fold_of,
+                                         n_folds, n_bins)
+
+
+def _cum_from_high(hist: jax.Array) -> jax.Array:
+    return jnp.cumsum(hist[..., ::-1], axis=-1)
+
+
+def _heldout_cum_counts_lanes_jnp(scores, labels, w, fold_of, n_folds,
+                                  n_bins):
+    """One scatter-add a class over (fold, grid point, bin) cells — the
+    CPU route and the pallas route's reference."""
+    Gc = scores.shape[0]
+    idx = _bin_idx(scores, n_bins)
+    lane = jnp.arange(Gc)[:, None]
+
+    def hist(wv):   # fold n_folds is the spill plane, cut away
+        return jnp.zeros((n_folds + 1, Gc, n_bins), jnp.float32) \
+            .at[fold_of[None, :], lane, idx] \
+            .add(jnp.broadcast_to(wv[None, :], idx.shape))[:n_folds]
+    return (_cum_from_high(hist(w * labels)),
+            _cum_from_high(hist(w * (1.0 - labels))))
+
+
+def _heldout_cum_counts_lanes_pallas(scores, labels, w, fold_of, n_folds,
+                                     n_bins, interpret: bool = False):
+    from . import pallas_hist
+    Gc = scores.shape[0]
+    hist = pallas_hist.hist_pallas(
+        _bin_idx(scores, n_bins), jnp.stack([w * labels, w * (1.0 - labels)]),
+        fold_of.astype(jnp.float32)[None, :], n_slots=n_folds,
+        n_bins=n_bins, interpret=interpret)      # [n_folds * 2, Gc * bins]
+    hist = hist.reshape(n_folds, 2, Gc, n_bins)
+    return _cum_from_high(hist[:, 0]), _cum_from_high(hist[:, 1])
+
+
 def _au_pr_from_counts(tps: jax.Array, fps: jax.Array) -> jax.Array:
     """Average precision from cumulative counts; bins on the LAST axis
     (shared by the scalar and lane-batched routes)."""
@@ -201,6 +260,23 @@ def au_roc_binned_lanes(scores: jax.Array, labels: jax.Array,
     """[L] AuROC values from per-lane binned counts."""
     return _au_roc_from_counts(
         *binned_cum_counts_lanes(scores, labels, w_lanes, n_bins))
+
+
+def au_pr_heldout_lanes(scores: jax.Array, labels: jax.Array, w: jax.Array,
+                        fold_of: jax.Array, n_folds: int,
+                        n_bins: int) -> jax.Array:
+    """[n_folds, Gc] average-precision values, every row binned once
+    (heldout_cum_counts_lanes; au_pr_binned's approximation contract)."""
+    return _au_pr_from_counts(*heldout_cum_counts_lanes(
+        scores, labels, w, fold_of, n_folds, n_bins))
+
+
+def au_roc_heldout_lanes(scores: jax.Array, labels: jax.Array,
+                         w: jax.Array, fold_of: jax.Array, n_folds: int,
+                         n_bins: int) -> jax.Array:
+    """[n_folds, Gc] AuROC values, every row binned once."""
+    return _au_roc_from_counts(*heldout_cum_counts_lanes(
+        scores, labels, w, fold_of, n_folds, n_bins))
 
 
 def au_pr_binned(scores: jax.Array, labels: jax.Array,
