@@ -285,9 +285,8 @@ def device_sweeps(X, y, cfg, sweep_dtype, errors):
     if glm_route == "streamed" and glm_info:
         # convergence telemetry: the executed-FLOP model and the
         # acceptance gates read these (monotone active-lane shrink,
-        # one-pass squared sweeps). The legacy "global" kernel has no
-        # round counters — emit only the keys that exist rather than
-        # JSON nulls that break numeric consumers.
+        # one-pass squared sweeps). Emit only the keys that exist rather
+        # than JSON nulls that break numeric consumers.
         out["glm_telemetry"] = glm_info
         for k in ("glm_rounds", "lanes_retired", "data_passes"):
             if glm_info.get(k) is not None:

@@ -24,7 +24,6 @@ from transmogrifai_tpu.ops.glm_sweep import (
     bucket_lanes,
     sweep_glm_round,
     sweep_glm_squared_gram,
-    sweep_glm_streamed,
     sweep_glm_streamed_rounds,
 )
 
@@ -46,10 +45,30 @@ def _regression(n=2000, d=6, seed=0):
     return X, y
 
 
+def _multiclass(n=1500, d=5, k=3, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    logits = X @ rng.normal(size=(d, k)).astype(np.float32)
+    y = (logits + rng.gumbel(size=logits.shape)).argmax(1)
+    return X, y.astype(np.float32)
+
+
 def _masks(y, folds=2, seed=1):
     rng = np.random.default_rng(seed)
     fold = rng.integers(0, folds, size=len(y))
     return np.stack([(fold != k).astype(np.float32) for k in range(folds)])
+
+
+def _assert_lanes_match(B, b0, fit_lane, atol):
+    """Every (fold, grid) lane of a sweep's B [F, G, d], b0 [F, G] against
+    `fit_lane(f, g)`, the per-lane solver of ops/glm.py on that fold's
+    weights."""
+    for f in range(B.shape[0]):
+        for g in range(B.shape[1]):
+            beta_ref, b0_ref = fit_lane(f, g)
+            assert np.allclose(B[f, g], np.asarray(beta_ref),
+                               atol=atol), (f, g)
+            assert abs(float(b0[f, g]) - float(b0_ref)) < atol, (f, g)
 
 
 class TestGramFastPath:
@@ -153,22 +172,22 @@ class TestRoundDriver:
     """(b) retirement: a retired lane's coefficients match letting it keep
     iterating, within tol; active-lane counts shrink monotonically."""
 
-    def test_matches_legacy_streamed_logistic(self):
+    def test_matches_per_lane_logistic(self):
         X, y = _binary()
         masks = _masks(y, folds=2)
         w = np.ones_like(y)
         regs = np.array([0.005, 0.05, 0.3], np.float32)
         alphas = np.array([0.0, 0.25, 0.5], np.float32)
-        Bl, b0l = sweep_glm_streamed(
-            jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
-            jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
-            loss="logistic", max_iter=30, standardize=False)
         Br, b0r, info = sweep_glm_streamed_rounds(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), regs, alphas, loss="logistic",
             max_iter=30, tol=1e-6, standardize=False, round_iters=3)
-        assert np.allclose(np.asarray(Bl), Br, atol=5e-3)
-        assert np.allclose(np.asarray(b0l), b0r, atol=5e-3)
+        _assert_lanes_match(
+            Br, b0r, lambda f, g: fit_logistic(
+                jnp.asarray(X), jnp.asarray(y), jnp.asarray(masks[f] * w),
+                jnp.asarray(regs[g]), jnp.asarray(alphas[g]), max_iter=30,
+                tol=1e-6, standardize=False),
+            atol=5e-3)
         assert info["lanes_retired"] == info["lanes_total"] == 6
         assert info["data_passes"] == sum(info["iters_per_round"])
 
@@ -255,23 +274,25 @@ class TestRoundDriver:
         assert info["lanes_at_cap"] == info["lanes_total"]
         assert np.isfinite(B).all()
 
-    def test_standardize_matches_legacy(self):
+    def test_standardize_matches_per_lane(self):
+        """The rounds standardize once with the global weights, the
+        per-lane solver with its fold's: means and stds differ at
+        O(1/sqrt(n)), and the coefficients by that times the penalty."""
         X, y = _binary(n=2400, d=5, seed=12)
         X = X * 2.0 + 1.0
         masks = _masks(y, folds=2, seed=4)
         w = np.ones_like(y)
-        Bl, b0l = sweep_glm_streamed(
-            jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
-            jnp.asarray(masks), jnp.asarray([0.02], np.float32),
-            jnp.asarray([0.0], np.float32), loss="logistic", max_iter=30,
-            standardize=True)
         Br, b0r, _ = sweep_glm_streamed_rounds(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), np.asarray([0.02], np.float32),
             np.asarray([0.0], np.float32), loss="logistic", max_iter=30,
             tol=1e-6, standardize=True, round_iters=4)
-        assert np.allclose(np.asarray(Bl), Br, atol=5e-3)
-        assert np.allclose(np.asarray(b0l), b0r, atol=5e-3)
+        _assert_lanes_match(
+            Br, b0r, lambda f, g: fit_logistic(
+                jnp.asarray(X), jnp.asarray(y), jnp.asarray(masks[f] * w),
+                jnp.asarray(0.02), jnp.asarray(0.0), max_iter=30, tol=1e-6,
+                standardize=True),
+            atol=5e-3)
 
 
 class TestBucketLadder:
@@ -314,26 +335,26 @@ class TestBucketLadder:
             assert all(b & (b - 1) == 0 for b in info["bucket_sizes"])
 
     def test_traced_tol_max_iter_share_executable(self):
-        """Satellite: tol/max_iter are traced scalars on the legacy
-        streamed kernel too — retuning them must NOT recompile."""
-        if not hasattr(sweep_glm_streamed, "_cache_size"):
+        """tol/max_iter are traced scalars of the round program —
+        retuning them must NOT recompile."""
+        if not hasattr(sweep_glm_round, "_cache_size"):
             pytest.skip("jit cache introspection unavailable")
         X, y = _binary(n=700, d=4, seed=8)
         masks = _masks(y, folds=2, seed=9)
         w = np.ones_like(y)
 
         def run(mi, tl):
-            return sweep_glm_streamed(
+            return sweep_glm_streamed_rounds(
                 jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
-                jnp.asarray(masks), jnp.asarray([0.05], np.float32),
-                jnp.asarray([0.0], np.float32), loss="logistic",
+                jnp.asarray(masks), np.asarray([0.05], np.float32),
+                np.asarray([0.0], np.float32), loss="logistic",
                 max_iter=mi, tol=tl, standardize=False)
 
         run(10, 1e-5)
-        size_after_first = sweep_glm_streamed._cache_size()
+        size_after_first = sweep_glm_round._cache_size()
         run(17, 1e-4)
         run(23, 1e-7)
-        assert sweep_glm_streamed._cache_size() == size_after_first
+        assert sweep_glm_round._cache_size() == size_after_first
 
 
 class TestRoundCheckpoint:
@@ -362,6 +383,45 @@ class TestRoundCheckpoint:
         assert np.array_equal(B_full, B_res)
         assert np.array_equal(b0_full, b0_res)
         assert info_res["glm_rounds"] == info_full["glm_rounds"]
+
+    @pytest.mark.parametrize("family", ["binary", "multinomial"])
+    def test_interrupted_after_round_1_reports_the_same_info(self, family):
+        """Both host drivers run the ONE retirement loop: killed at the
+        first round boundary and resumed from that state, a sweep hands
+        back the uninterrupted sweep's coefficients and its `info`, key
+        by key."""
+        if family == "binary":
+            X, y = _binary(n=1400, d=5, seed=10)
+            driver, kw = sweep_glm_streamed_rounds, dict(
+                loss="logistic", warm_start=True)
+        else:
+            X, y = _multiclass(seed=10)
+            driver, kw = GS.sweep_mlr_streamed_rounds, dict(n_classes=3)
+        args = (jnp.asarray(X), jnp.asarray(y),
+                jnp.ones(len(y), jnp.float32),
+                jnp.asarray(_masks(y, folds=2, seed=11)),
+                np.array([0.005, 0.08, 0.4], np.float32),
+                np.full(3, 0.1, np.float32))
+        kw.update(max_iter=12, tol=1e-6, standardize=True, round_iters=2)
+        B_full, b0_full, info_full = driver(*args, **kw)
+        assert info_full["glm_rounds"] > 2
+
+        class Killed(RuntimeError):
+            pass
+        saved = []
+
+        def kill(st):
+            saved.append(copy.deepcopy(st))
+            raise Killed()
+        with pytest.raises(Killed):
+            driver(*args, on_round=kill, **kw)
+        assert saved[0]["rounds"] == 1
+        B_res, b0_res, info_res = driver(*args, state=saved[0], **kw)
+        assert np.array_equal(B_full, B_res)
+        assert np.array_equal(b0_full, b0_res)
+        assert info_res.keys() == info_full.keys()
+        for key in info_full:
+            assert info_res[key] == info_full[key], key
 
     def test_roundcheckpoint_file_roundtrip(self, tmp_path):
         from transmogrifai_tpu.automl.tuning.checkpoint import (
@@ -540,36 +600,28 @@ class TestValidatorRouting:
         for a, b in zip(bv.validated, bs.validated):
             assert np.allclose(a.fold_metrics, b.fold_metrics, atol=5e-3)
 
-    def test_kill_switches_fall_back_to_legacy(self, monkeypatch):
+    @pytest.mark.parametrize("est,evaluator,problem_type,data,kernel", [
+        (OpLogisticRegression, Evaluators.BinaryClassification.au_pr,
+         "binary", _binary, "rounds"),
+        (OpLinearSVC, Evaluators.BinaryClassification.au_roc,
+         "binary", _binary, "rounds"),
+        (OpLinearRegression, Evaluators.Regression.rmse,
+         "regression", _regression, "gram"),
+        (OpLogisticRegression, Evaluators.MultiClassification.error,
+         "multiclass", _multiclass, "mlr_rounds"),
+    ], ids=["logistic", "squared_hinge", "squared", "softmax"])
+    def test_one_streamed_kernel_a_loss(self, monkeypatch, est, evaluator,
+                                        problem_type, data, kernel):
+        """_streamed_fit dispatches on the loss alone."""
         monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
-        monkeypatch.setenv("TMOG_GLM_ROUNDS", "0")
-        monkeypatch.setenv("TMOG_GLM_GRAM", "0")
-        X, y = _binary(n=900)
-        ev = Evaluators.BinaryClassification.au_pr()
-        val = CrossValidation(ev, num_folds=2, seed=2)
-        best = val.validate([(OpLogisticRegression(max_iter=15),
-                              [{"reg_param": 0.01}])], X, y)
-        assert np.isfinite(best.best_metric)
-        assert val.last_streamed_telemetry["kernel"] == "global"
-        Xr, yr = _regression(n=900)
-        valr = CrossValidation(Evaluators.Regression.rmse(), num_folds=2,
-                               seed=2)
-        bestr = valr.validate([(OpLinearRegression(max_iter=15),
-                                [{"reg_param": 0.01}])], Xr, yr,
-                              problem_type="regression")
-        assert np.isfinite(bestr.best_metric)
-        assert valr.last_streamed_telemetry["kernel"] == "global"
-
-    def test_svc_routes_rounds(self, monkeypatch):
-        monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
-        X, y = _binary(n=1200)
-        ev = Evaluators.BinaryClassification.au_roc()
-        val = CrossValidation(ev, num_folds=2, seed=3)
-        best = val.validate([(OpLinearSVC(max_iter=15),
+        X, y = data(n=1200)
+        val = CrossValidation(evaluator(), num_folds=2, seed=3)
+        best = val.validate([(est(max_iter=15),
                               [{"reg_param": 0.01}, {"reg_param": 0.1}])],
-                            X, y)
+                            X, y, problem_type=problem_type)
         assert np.isfinite(best.best_metric)
-        assert val.last_streamed_telemetry["kernel"] == "rounds"
+        assert {v.route for v in best.validated} == {"streamed"}
+        assert val.last_streamed_telemetry["kernel"] == kernel
 
     def test_collector_records_sweep_convergence(self, monkeypatch):
         from transmogrifai_tpu.utils.metrics import collector

@@ -13,8 +13,8 @@ from transmogrifai_tpu.evaluators.evaluators import Evaluators
 from transmogrifai_tpu.models.glm import (
     OpLinearRegression, OpLinearSVC, OpLogisticRegression,
 )
+from transmogrifai_tpu.ops import glm_sweep as GS
 from transmogrifai_tpu.ops.glm import fit_logistic
-from transmogrifai_tpu.ops.glm_sweep import sweep_glm_streamed
 
 
 def _binary(n=3000, d=8, seed=0):
@@ -32,6 +32,24 @@ def _masks(y, folds=3, seed=1):
     return np.stack([(fold != k).astype(np.float32) for k in range(folds)])
 
 
+def _streamed(X, y, w, masks, regs, alphas, *, loss, max_iter, standardize,
+              mesh=None):
+    """(B [F, G, d], b0 [F, G]) from THE streamed kernel of the loss, the
+    one Validator._streamed_fit takes: the Gram fast path for `squared`,
+    the retirement rounds (cold start: no pathwise seed, so a lane's
+    iterates are the per-lane solver's) for the IRLS losses. Arrays may be
+    host arrays or already placed on `mesh`."""
+    regs, alphas = jnp.asarray(regs), jnp.asarray(alphas)
+    if loss == "squared":
+        B, b0, _ = GS.sweep_glm_squared_gram(
+            X, y, w, masks, regs, alphas, max_iter, standardize=standardize)
+    else:
+        B, b0, _ = GS.sweep_glm_streamed_rounds(
+            X, y, w, masks, regs, alphas, loss=loss, max_iter=max_iter,
+            standardize=standardize, warm_start=False, mesh=mesh)
+    return np.asarray(B), np.asarray(b0)
+
+
 class TestKernelParity:
     def test_streamed_matches_per_lane_logistic(self):
         X, y = _binary()
@@ -39,7 +57,7 @@ class TestKernelParity:
         w = np.ones_like(y)
         regs = np.array([0.001, 0.01, 0.1], np.float32)
         alphas = np.array([0.0, 0.25, 0.5], np.float32)
-        B, b0 = sweep_glm_streamed(
+        B, b0 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=25, standardize=False)
@@ -65,7 +83,7 @@ class TestKernelParity:
         w = np.ones_like(y)
         regs = np.array([0.01], np.float32)
         alphas = np.array([0.0], np.float32)
-        B, b0 = sweep_glm_streamed(
+        B, b0 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=25, standardize=True)
@@ -83,7 +101,7 @@ class TestKernelParity:
         regs = np.array([0.01, 0.1], np.float32)
         alphas = np.zeros(2, np.float32)
         for loss in ("squared", "squared_hinge"):
-            B, b0 = sweep_glm_streamed(
+            B, b0 = _streamed(
                 jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
                 jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
                 loss=loss, max_iter=20, standardize=False)
@@ -109,7 +127,7 @@ class TestKernelParity:
         w = np.ones_like(y)
         regs = np.array([0.01, 0.3], np.float32)
         alphas = np.zeros(2, np.float32)
-        B, b0 = sweep_glm_streamed(
+        B, b0 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=20, standardize=False)
@@ -135,7 +153,7 @@ class TestKernelParity:
         w = np.ones_like(y)
         regs = np.array([0.01, 0.1, 1.0], np.float32)
         alphas = np.zeros(3, np.float32)
-        B, b0 = sweep_glm_streamed(
+        B, b0 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="squared_hinge", max_iter=30, standardize=False)
@@ -241,7 +259,7 @@ class TestStreamedProperties:
         masks = _masks(y, folds=2, seed=seed)
         regs = np.array([0.01], np.float32)
         alphas = np.array([0.25], np.float32)
-        B, b0 = sweep_glm_streamed(
+        B, b0 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=25, standardize=False)
@@ -256,16 +274,19 @@ class TestStreamedProperties:
 
     def test_row_block_boundary_sizes(self, monkeypatch):
         """n exactly at, one under, and one over the scan block size."""
-        from transmogrifai_tpu.ops import glm_sweep as GS
         monkeypatch.setattr(GS, "_ROW_BLOCK", 512)
+        # the unjitted round: no program traced under the patched block
+        # stays in sweep_glm_round's cache for a later test of this process
+        monkeypatch.setattr(GS, "sweep_glm_round",
+                            GS.sweep_glm_round.__wrapped__)
         for n in (511, 512, 513, 1024, 1030):
             X, y = _binary(n=n, d=4, seed=3)
             w = np.ones_like(y)
             masks = _masks(y, folds=2, seed=4)
-            B, b0 = GS.sweep_glm_streamed.__wrapped__(
+            B, b0 = _streamed(
                 jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
-                jnp.asarray(masks), jnp.asarray([0.01], np.float32),
-                jnp.asarray([0.0], np.float32),
+                jnp.asarray(masks), np.array([0.01], np.float32),
+                np.array([0.0], np.float32),
                 loss="logistic", max_iter=15, standardize=False)
             beta_ref, _ = fit_logistic(
                 jnp.asarray(X), jnp.asarray(y), jnp.asarray(masks[0] * w),
@@ -285,8 +306,6 @@ class TestShardedStreamed:
         sweep (psum'd accumulators are the only difference)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from transmogrifai_tpu.ops.glm_sweep import (
-            sweep_glm_streamed_sharded)
 
         mesh = self._mesh()
         n = 4096  # multiple of the 4-way batch axis
@@ -296,7 +315,7 @@ class TestShardedStreamed:
         regs = np.array([0.01, 0.1], np.float32)
         alphas = np.array([0.0, 0.5], np.float32)
 
-        B1, b01 = sweep_glm_streamed(
+        B1, b01 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=20, standardize=False)
@@ -304,12 +323,11 @@ class TestShardedStreamed:
         row = NamedSharding(mesh, P("batch", None))
         vec = NamedSharding(mesh, P("batch"))
         mrow = NamedSharding(mesh, P(None, "batch"))
-        B2, b02 = sweep_glm_streamed_sharded(
-            mesh,
+        B2, b02 = _streamed(
             jax.device_put(X, row), jax.device_put(y, vec),
             jax.device_put(w, vec), jax.device_put(masks, mrow),
-            jnp.asarray(regs), jnp.asarray(alphas),
-            loss="logistic", max_iter=20, standardize=False)
+            regs, alphas, loss="logistic", max_iter=20, standardize=False,
+            mesh=mesh)
         assert np.allclose(np.asarray(B1), np.asarray(B2), atol=2e-3)
         assert np.allclose(np.asarray(b01), np.asarray(b02), atol=2e-3)
 
@@ -318,8 +336,6 @@ class TestShardedStreamed:
         the single-device two-pass."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from transmogrifai_tpu.ops.glm_sweep import (
-            sweep_glm_streamed_sharded)
 
         mesh = self._mesh()
         X, y = _binary(n=2048, d=5, seed=9)
@@ -328,18 +344,18 @@ class TestShardedStreamed:
         masks = _masks(y, folds=2, seed=2)
         regs = np.array([0.05], np.float32)
         alphas = np.array([0.0], np.float32)
-        B1, b01 = sweep_glm_streamed(
+        B1, b01 = _streamed(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             loss="logistic", max_iter=25, standardize=True)
         row = NamedSharding(mesh, P("batch", None))
         vec = NamedSharding(mesh, P("batch"))
         mrow = NamedSharding(mesh, P(None, "batch"))
-        B2, b02 = sweep_glm_streamed_sharded(
-            mesh, jax.device_put(X, row), jax.device_put(y, vec),
+        B2, b02 = _streamed(
+            jax.device_put(X, row), jax.device_put(y, vec),
             jax.device_put(w, vec), jax.device_put(masks, mrow),
-            jnp.asarray(regs), jnp.asarray(alphas),
-            loss="logistic", max_iter=25, standardize=True)
+            regs, alphas, loss="logistic", max_iter=25, standardize=True,
+            mesh=mesh)
         assert np.allclose(np.asarray(B1), np.asarray(B2), atol=5e-3)
 
     def test_validator_mesh_routes_streamed(self, monkeypatch):
@@ -365,8 +381,6 @@ class TestShardedStreamed:
         (two-pass psum'd moments; the one-pass form cancels in f32)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from transmogrifai_tpu.ops.glm_sweep import (
-            sweep_glm_streamed_sharded)
 
         mesh = self._mesh()
         X, y = _binary(n=2048, d=4, seed=13)
@@ -376,11 +390,11 @@ class TestShardedStreamed:
         row = NamedSharding(mesh, P("batch", None))
         vec = NamedSharding(mesh, P("batch"))
         mrow = NamedSharding(mesh, P(None, "batch"))
-        B, b0 = sweep_glm_streamed_sharded(
-            mesh, jax.device_put(X, row), jax.device_put(y, vec),
+        B, b0 = _streamed(
+            jax.device_put(X, row), jax.device_put(y, vec),
             jax.device_put(w, vec), jax.device_put(masks, mrow),
-            jnp.asarray([0.05], np.float32), jnp.asarray([0.0], np.float32),
-            loss="logistic", max_iter=20, standardize=True)
+            np.array([0.05], np.float32), np.array([0.0], np.float32),
+            loss="logistic", max_iter=20, standardize=True, mesh=mesh)
         assert np.isfinite(np.asarray(B)).all()
         assert np.abs(np.asarray(B)).max() < 100.0  # no exploded scales
 

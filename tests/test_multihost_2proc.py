@@ -131,12 +131,12 @@ B1, b01, _ = GS.sweep_glm_squared_gram(
     jnp.asarray(X), jnp.asarray(y), jnp.asarray(w), jnp.asarray(masks),
     jnp.asarray(regs), jnp.asarray(alphas))
 out["glm_gram_err"] = max(err(B2, B1), err(b02, b01))
-B4, b04 = GS.sweep_glm_streamed_sharded(
-    mesh, X[lo:hi], y[lo:hi], w[lo:hi], masks[:, lo:hi], regs, alphas,
-    loss="logistic")
-B3, b03 = GS.sweep_glm_streamed(
+B4, b04, _ = GS.sweep_glm_streamed_rounds(
+    X[lo:hi], y[lo:hi], w[lo:hi], masks[:, lo:hi], regs, alphas,
+    loss="logistic", mesh=mesh)
+B3, b03, _ = GS.sweep_glm_streamed_rounds(
     jnp.asarray(X), jnp.asarray(y), jnp.asarray(w), jnp.asarray(masks),
-    jnp.asarray(regs), jnp.asarray(alphas), loss="logistic")
+    regs, alphas, loss="logistic")
 out["glm_irls_err"] = max(err(B4, B3), err(b04, b03))
 
 from transmogrifai_tpu.ops import trees as T

@@ -52,7 +52,7 @@ def test_histograms_pallas_wrapper_shapes(monkeypatch):
     XLA paths (interpret mode, forced availability). With the tree
     consumers' bf16 contraction inputs forced OFF the values must match
     the segment path near-exactly; with them on (the default,
-    TMOG_HIST_BF16) the g/h channels carry ~0.4% relative quantization
+    PH._HIST_BF16) the g/h channels carry ~0.4% relative quantization
     while the unit-count channel stays exact."""
     monkeypatch.setattr(PH, "available", lambda: True)
     import functools
@@ -273,30 +273,6 @@ def test_lanes_4096_bins_block_sizing():
                            atol=1e-3)
         assert np.allclose(np.cumsum(hist[l, 1][::-1]), np.asarray(f1),
                            atol=1e-3)
-
-
-def test_concat_variant_matches_reshape():
-    """The two kernel lowerings (3D-reshape one-hot vs concatenated 2D tiles) are
-    alternative Mosaic paths for the SAME math — interpret-mode outputs
-    must be identical."""
-    Xb, G, H, cu, node, n_nodes, B = _inputs(PH._BLK)
-    K = G.shape[1]
-    pay = jnp.concatenate([G.T, H[None], cu[None]], axis=0)
-    slot = node[None].astype(jnp.float32)
-    try:
-        h_reshape = np.asarray(PH.hist_pallas(
-            Xb.T, pay, slot, n_slots=n_nodes, n_bins=B, interpret=True))
-        PH.set_variant("concat")
-        h_concat = np.asarray(PH.hist_pallas(
-            Xb.T, pay, slot, n_slots=n_nodes, n_bins=B, interpret=True))
-    finally:
-        PH.set_variant("reshape")
-    np.testing.assert_array_equal(h_reshape, h_concat)
-
-
-def test_set_variant_rejects_unknown():
-    with pytest.raises(ValueError):
-        PH.set_variant("bogus")
 
 
 class TestFusedVmemGuard:

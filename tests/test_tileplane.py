@@ -266,6 +266,13 @@ class TestGLMConsumer:
         return X, y, w, masks
 
     def test_source_rounds_match_device_rounds(self, monkeypatch):
+        """Both drivers obey one retire rule (delta <= tol), so they take
+        the same rounds — at a tolerance float32 can resolve. 1e-7 cannot
+        be asserted: it is under one float32 ulp of the update (2^-23 ~
+        1.19e-7), and after 10 iterations both drivers' deltas ARE that
+        rounding noise; the order in which 400-row tiles are summed then
+        decides whether a lane reads 6.0e-8 or 1.27e-7 and takes a third
+        round."""
         monkeypatch.setattr(
             "transmogrifai_tpu.parallel.tileplane.tile_rows_for",
             lambda *a, **k: 400)  # force a multi-tile pass
@@ -275,11 +282,11 @@ class TestGLMConsumer:
         B_dev, b0_dev, info_dev = GS.sweep_glm_streamed_rounds(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), regs, alphas, loss="logistic",
-            max_iter=25, tol=1e-7, warm_start=False)
+            max_iter=25, tol=1e-6, warm_start=False)
         src = TP.ArraySource(X, y, w, masks.T.copy(), chunk_rows=300)
         B_src, b0_src, info_src = GS.sweep_glm_streamed_rounds(
             src, None, None, None, regs, alphas, loss="logistic",
-            max_iter=25, tol=1e-7, warm_start=False)
+            max_iter=25, tol=1e-6, warm_start=False)
         assert info_src["driver"] == "tileplane"
         assert info_src["glm_rounds"] == info_dev["glm_rounds"]
         np.testing.assert_allclose(B_src, B_dev, rtol=5e-3, atol=5e-4)

@@ -53,12 +53,6 @@ KNOBS: List[Dict[str, str]] = [
     {"name": "TMOG_NO_PALLAS", "default": "",
      "doc": "docs/performance.md",
      "desc": "force the pure-jnp twins of every pallas kernel"},
-    {"name": "TMOG_PALLAS_HIST_VARIANT", "default": "reshape",
-     "doc": "docs/performance.md",
-     "desc": "histogram kernel inner-loop variant selector"},
-    {"name": "TMOG_HIST_BF16", "default": "1",
-     "doc": "docs/performance.md",
-     "desc": "bf16 histogram payload accumulation in the fused kernels"},
     # -- tree sweep ---------------------------------------------------------
     {"name": "TMOG_TREE_SCAN", "default": "1",
      "doc": "docs/performance.md",
@@ -78,19 +72,6 @@ KNOBS: List[Dict[str, str]] = [
     {"name": "TMOG_GRID_FUSE_MAX_FAILURES", "default": "3",
      "doc": "docs/performance.md",
      "desc": "fused-route failures tolerated before the sweep raises"},
-    # -- GLM sweep ----------------------------------------------------------
-    {"name": "TMOG_GLM_GRAM", "default": "1",
-     "doc": "docs/performance.md",
-     "desc": "squared-loss Gram-cached fast path (0 = streamed IRLS)"},
-    {"name": "TMOG_GLM_ROUNDS", "default": "1",
-     "doc": "docs/performance.md",
-     "desc": "convergence-aware round driver with lane retirement"},
-    {"name": "TMOG_GLM_ROUND_ITERS", "default": "5",
-     "doc": "docs/performance.md",
-     "desc": "Newton iterations per retirement round"},
-    {"name": "TMOG_GLM_WARMSTART", "default": "1",
-     "doc": "docs/performance.md",
-     "desc": "glmnet-style pathwise warm start across the reg path"},
     # -- statistics engine --------------------------------------------------
     {"name": "TMOG_STATS_FUSED", "default": "1",
      "doc": "docs/performance.md",

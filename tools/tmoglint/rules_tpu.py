@@ -319,8 +319,8 @@ _DISPATCH_HINTS = {
     "validate", "fit_arrays", "predict_arrays",
     # ops-level sweep/fit drivers
     "fit_gbt", "fit_gbt_folds", "fit_gbt_softmax", "fit_forest",
-    "grow_tree", "sweep_glm_streamed", "sweep_glm_streamed_rounds",
-    "sweep_glm_round", "sweep_glm_squared_gram", "route_hist",
+    "grow_tree", "sweep_glm_streamed_rounds", "sweep_glm_round",
+    "sweep_glm_squared_gram", "route_hist",
     "hist_folds", "knockout_deltas",
 }
 _SYNC_NAMES = {"block_until_ready"}

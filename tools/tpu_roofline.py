@@ -62,7 +62,7 @@ def main():
     Xb = bin_matrix(X, edges)
     Xb_t = jnp.asarray(Xb.T)                      # [F, N] int8
     del X
-    bf16 = os.environ.get("TMOG_HIST_BF16", "1") != "0"
+    bf16 = True  # the tree fits' histogram inputs (pallas_hist._HIST_BF16)
     pay_np = np.random.default_rng(1).normal(
         size=(folds * 3, n)).astype(np.float32)
     pay_t = jnp.asarray(pay_np)

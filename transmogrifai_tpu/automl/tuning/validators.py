@@ -759,10 +759,13 @@ class Validator:
         import json as _json
 
         from .checkpoint import RoundCheckpoint
+        # the third element was the value of an environment variable that
+        # is gone; "" is what it read unset, so a round checkpoint written
+        # before it went still resumes
         payload = _json.dumps(
             [[keys[gi] for gi in pending],
              {k: repr(v) for k, v in sorted(fit_kwargs.items())},
-             os.environ.get("TMOG_GLM_ROUND_ITERS", "")], sort_keys=True)
+             ""], sort_keys=True)
         rkey = hashlib.sha256(payload.encode()).hexdigest()[:24]
         rc = RoundCheckpoint(self.checkpoint_path + ".glm_rounds.npz")
         return rc, rkey, rc.load(rkey)
@@ -796,15 +799,14 @@ class Validator:
 
     def _streamed_fit(self, est, fit_kwargs, Xd, yd, wd, md, regs_p,
                       alphas_p, keys, pending):
-        """Fit every pending (fold x grid) lane through the best streamed
-        kernel for the loss (docs/performance.md "Convergence-aware GLM
-        sweep"): squared loss -> sufficient-statistics Gram fast path
-        (ONE streaming pass for the whole sweep); IRLS losses -> the
-        host-driven round loop with per-lane retirement and bucket-ladder
-        compaction (round-granular checkpointing when a checkpoint path is
-        set); TMOG_GLM_GRAM=0 / TMOG_GLM_ROUNDS=0 fall back to the legacy
-        single-program global-max route. Returns (B [F, Gp, d] jnp RAW
-        units, b0, telemetry info dict, round-checkpoint or None — the
+        """Fit every pending (fold x grid) lane through THE streamed
+        kernel of the loss (docs/performance.md "Convergence-aware GLM
+        sweep"): softmax -> the multinomial rounds; squared ->
+        sufficient-statistics Gram fast path (ONE streaming pass for the
+        whole sweep); every other loss -> the host-driven round loop with
+        per-lane retirement and bucket-ladder compaction (the rounds
+        checkpoint when a checkpoint path is set). Returns (B [F, Gp, d]
+        jnp RAW units, b0, telemetry info dict, round-checkpoint or None — the
         CALLER clears it only after the cells land in the JSONL
         checkpoint, so a preemption during metric evaluation still
         resumes from the fully-retired round state instead of
@@ -844,7 +846,7 @@ class Validator:
                 Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
                 state=state, on_round=on_round, **fk)
             return jnp.asarray(B), jnp.asarray(b0), info, rc
-        if loss == "squared" and GS.env_on("TMOG_GLM_GRAM"):
+        if loss == "squared":
             fk = {k: v for k, v in fit_kwargs.items() if k != "loss"}
             mi, tl = fk.pop("max_iter"), fk.pop("tol")
             if self.mesh is not None:
@@ -860,37 +862,30 @@ class Validator:
                     "lanes_total": L, "lanes_retired": L,
                     "gram_solve_iters": int(giters)}
             return B, b0, info, None
-        if loss != "squared" and GS.env_on("TMOG_GLM_ROUNDS"):
-            rc, state, on_round = round_hooks()
-            # across-time warm seed (retrain refit): the previous
-            # champion's raw coefficients, threaded selector -> validator
-            # (ModelSelector.fit_arrays). The sweep ignores a seed whose
-            # dimension disagrees with this vectorization.
-            seed = getattr(self, "warm_seed", None)
-            seed_t = None
-            if isinstance(seed, dict) and seed.get("beta") is not None:
-                seed_t = (np.asarray(seed["beta"], np.float32),
-                          float(seed.get("intercept", 0.0)))
-            B, b0, info = GS.sweep_glm_streamed_rounds(
-                Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
-                mesh=self.mesh, state=state, on_round=on_round,
-                warm_seed=seed_t, **fit_kwargs)
-            return jnp.asarray(B), jnp.asarray(b0), info, rc
-        if self.mesh is not None:
-            B, b0 = GS.sweep_glm_streamed_sharded(
-                self.mesh, Xd, yd, wd, md, regs_p, alphas_p, **fit_kwargs)
-        else:
-            B, b0 = GS.sweep_glm_streamed(Xd, yd, wd, md, regs_p,
-                                          alphas_p, **fit_kwargs)
-        return B, b0, {"route": "streamed", "kernel": "global",
-                       "lanes_total": L}, None
+        # the IRLS rounds; _residual_curvature refuses a loss it does not
+        # know
+        rc, state, on_round = round_hooks()
+        # across-time warm seed (retrain refit): the previous champion's
+        # raw coefficients, threaded selector -> validator
+        # (ModelSelector.fit_arrays). The sweep ignores a seed whose
+        # dimension disagrees with this vectorization.
+        seed = getattr(self, "warm_seed", None)
+        seed_t = None
+        if isinstance(seed, dict) and seed.get("beta") is not None:
+            seed_t = (np.asarray(seed["beta"], np.float32),
+                      float(seed.get("intercept", 0.0)))
+        B, b0, info = GS.sweep_glm_streamed_rounds(
+            Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
+            mesh=self.mesh, state=state, on_round=on_round,
+            warm_seed=seed_t, **fit_kwargs)
+        return jnp.asarray(B), jnp.asarray(b0), info, rc
 
     def _validate_streamed(self, est, grids, X, y, w, masks, metric,
                            problem_type, n_classes=2
                            ) -> List[ValidatedModel]:
         """Streamed convergence-aware sweep: every pending (fold x grid)
         cell fits through _streamed_fit (Gram fast path / retirement round
-        driver / legacy single program / multinomial rounds); metrics then
+        driver / multinomial rounds); metrics then
         run per fold in grid chunks of one scoring matmul each (multiclass:
         one block-scanned confusion count each)."""
         multiclass = problem_type == "multiclass"

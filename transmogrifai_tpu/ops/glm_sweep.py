@@ -28,7 +28,7 @@ every thread refitting against the same cached DataFrame):
   batched dense linalg on [L, d, d] — microscopic next to the scan.
 
 Convergence awareness (docs/performance.md "Convergence-aware GLM
-sweep") adds three routes on top of the shared scan machinery:
+sweep"): one streamed kernel a loss, on top of the shared scan machinery.
 
 1. `sweep_glm_squared_gram` — loss="squared" sufficient-statistics fast
    path. The squared-loss curvature is identically 1, so the lane Hessian
@@ -40,23 +40,23 @@ sweep") adds three routes on top of the shared scan machinery:
    elastic-net lanes by proximal Newton on the cached Gram
    (`ops/glm.prox_newton_gram`, seeded from the ridge solution). Up to
    max_iter full-data passes become exactly one.
-2. `sweep_glm_round` + the host driver `sweep_glm_streamed_rounds` — for
-   IRLS losses (logistic, squared_hinge) the run-to-global-convergence
-   while_loop is replaced by rounds of K iterations with a PER-LANE delta
-   vector in the carry; after each round the host retires converged lanes
-   (coefficients frozen — matching the per-lane solvers' own tol
-   semantics, `ops/glm._newton_prox_fit`) and compacts survivors into the
-   next round's program. The lane axis pads to a power-of-two bucket
-   ladder (`bucket_lanes`) so recompiles are bounded and the jit cache is
-   shared across rounds, chunks and sweeps; inert padded lanes carry zero
-   fold weights. Round 0 optionally fits only each fold's
-   strongest-regularization lane and seeds the rest of the fold from it
-   (glmnet-style pathwise continuation).
-3. `sweep_glm_streamed` — the legacy single-program global-max route,
-   kept as the kill-switch fallback (TMOG_GLM_ROUNDS=0 / TMOG_GLM_GRAM=0)
-   and the parity reference in tests. `tol`/`max_iter` are traced scalars
-   on every route (they only feed while-loop conds), so tuning them never
-   recompiles.
+2. `sweep_glm_round` + the host driver `sweep_glm_streamed_rounds` — the
+   IRLS losses (logistic, squared_hinge) run rounds of K iterations with
+   a PER-LANE delta vector in the carry; after each round the host
+   retires converged lanes (coefficients frozen — matching the per-lane
+   solvers' own tol semantics, `ops/glm._newton_prox_fit`) and compacts
+   survivors into the next round's program. The lane axis pads to a
+   power-of-two bucket ladder (`bucket_lanes`) so recompiles are bounded
+   and the jit cache is shared across rounds, chunks and sweeps; inert
+   padded lanes carry zero fold weights. Round 0 optionally fits only
+   each fold's strongest-regularization lane and seeds the rest of the
+   fold from it (glmnet-style pathwise continuation).
+3. `sweep_mlr_round` + `sweep_mlr_streamed_rounds` — the softmax loss: the
+   same retirement loop (`_run_rounds`, written once for both drivers)
+   around the multinomial round program.
+
+`tol`/`max_iter` are traced scalars on every route (they only feed
+while-loop conds), so tuning them never recompiles.
 
 Fold masks enter as weights (mask * w), exactly like the vmapped path, so
 fold semantics are identical; the elementwise residual/curvature rules per
@@ -67,7 +67,7 @@ shard_map over the mesh `batch` axis — each shard scans its local rows,
 then every accumulator reduction psums over ICI/DCN (the Spark-shuffle /
 Rabit-allreduce slot of SURVEY §2.9); the tiny replicated solves run on
 every shard. Sharded standardization uses one-pass psum'd moments. The
-replicated-out_spec claims of all four sharded drivers are proved
+replicated-out_spec claims of the sharded drivers are proved
 statically by tmoglint SHD001 (a missing psum is invisible on the
 1-device CI mesh — docs/static_analysis.md).
 
@@ -131,8 +131,7 @@ _MAX_TILE_PAIRS = 406
 
 # Newton iterations per jitted round on the retirement route; the
 # retirement granularity / wasted-iteration tradeoff (a lane converging
-# mid-round keeps iterating until the round ends). TMOG_GLM_ROUND_ITERS
-# overrides per process.
+# mid-round keeps iterating until the round ends).
 ROUND_ITERS_DEFAULT = 5
 
 # Smallest lane bucket on the compaction ladder: buckets below this save
@@ -303,10 +302,8 @@ def _blocked(Xs, y, w, fold_masks, c: int):
 
 
 def env_on(name: str, default: str = "1") -> bool:
-    """Tri-state TMOG_* toggle parse, shared by every sweep knob
-    (TMOG_GLM_GRAM / TMOG_GLM_ROUNDS in the validator routing,
-    TMOG_GLM_WARMSTART here) so the accepted falsy spellings cannot
-    drift between modules."""
+    """Tri-state TMOG_* toggle parse (TMOG_PLAN, TMOG_STATS_FUSED import
+    it) so the accepted falsy spellings cannot drift between modules."""
     return os.environ.get(name, default).strip().lower() \
         not in ("0", "false", "off")
 
@@ -314,11 +311,11 @@ def env_on(name: str, default: str = "1") -> bool:
 def _newton_prox_update(B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye,
                         assemble, fit_intercept: bool):
     """THE damped-Newton + proximal-L1 + intercept update from streamed
-    accumulators, shared by the legacy global-max kernel and the
-    retirement round kernel — the parity contract between the two routes
-    (and the moment-space replay in ops/glm.prox_newton_gram) lives in
-    this one function, so a change to the update rule reaches every route
-    at once. Returns (B_new, b0_new, delta_vec [L])."""
+    accumulators, shared by the resident round kernel and the tileplane
+    source rounds — the parity contract between them (and the
+    moment-space replay in ops/glm.prox_newton_gram) lives in this one
+    function, so a change to the update rule reaches every route at
+    once. Returns (B_new, b0_new, delta_vec [L])."""
     g = gA / wsum_l[:, None] + l2[:, None] * B
     H = assemble(hA) / wsum_l[:, None, None]
     H = H + (l2[:, None, None] + 1e-6) * eye[None]
@@ -412,199 +409,6 @@ def _sharded_stats_fn(mesh):
                           in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS)),
                           out_specs=(P(None), P(None)))
     return jax.jit(sm)
-
-
-# -- legacy single-program route (global-max convergence) --------------------
-
-def _streamed_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
-                   loss, fit_intercept, standardize,
-                   axis_name: Optional[str] = None):
-    """The sweep body. Under shard_map, X/y/w/fold_masks hold this shard's
-    LOCAL rows and `axis_name` names the mesh axis every accumulator
-    reduction psums over; axis_name=None is the single-device path.
-    max_iter/tol are traced scalars (they only feed the while-loop cond),
-    so tuning them never triggers a recompile."""
-    n, d = X.shape
-    F = fold_masks.shape[0]
-    Gn = regs.shape[0]
-    L = F * Gn
-    rc = _residual_curvature(loss)
-    tiled, d_work, bt, tile_pairs = _tiling(d)
-    if d_work > d:
-        # zero columns are inert end to end: mean 0 -> centered 0,
-        # grad 0, H diagonal = l2 + 1e-6 ridge -> Newton step 0, so
-        # padded betas stay exactly 0 and are sliced off on return
-        X = jnp.pad(X, ((0, 0), (0, d_work - d)))
-
-    def allreduce(v):
-        return jax.lax.psum(v, axis_name) if axis_name else v
-
-    if standardize:
-        if axis_name is None:
-            Xs, mean, std = G._standardize(X, w)
-        else:
-            mean, std = _psum_moments(X, w, allreduce)
-            Xs = ((X.astype(jnp.float32) - mean[None, :]) / std[None, :]) \
-                .astype(X.dtype)
-    else:
-        Xs = X
-        mean = jnp.zeros(d_work, jnp.float32)
-        std = jnp.ones(d_work, jnp.float32)
-
-    # lane layout: l = f * Gn + g  (fold-major, so per-fold weights expand
-    # by broadcast over the grid axis)
-    l1 = jnp.tile(regs * alphas, F)                     # [L]
-    l2 = jnp.tile(regs * (1.0 - alphas), F)             # [L]
-    wsum_f = jnp.maximum(
-        allreduce((fold_masks * w[None, :]).sum(1)), EPS)         # [F]
-    wsum_l = jnp.repeat(wsum_f, Gn)                     # [L]
-
-    c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), n)
-    xs = _blocked(Xs, y, w, fold_masks, c)
-
-    eye = jnp.eye(d_work, dtype=jnp.float32)
-    hess_blocks, assemble, h_acc0 = _gram_fns(tiled, d_work, L, bt,
-                                              tile_pairs)
-
-    def accumulate(B, b0):
-        """One streaming pass: per-lane (g [L,d], Hessian blocks, g0, h0)."""
-        Bt = B.T.astype(Xs.dtype)                       # [d, L]
-
-        def body(acc, sl):
-            x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
-            gA, hA, g0A, h0A = acc
-            eta = jnp.matmul(x_blk, Bt,
-                             preferred_element_type=jnp.float32) + b0[None, :]
-            r0, s0 = rc(eta, y_blk)                     # [c, L]
-            wlf = m_blk.T * w_blk[:, None]              # [c, F]
-            wl = jnp.repeat(wlf, Gn, axis=1)            # [c, L] lane weights
-            R = r0 * wl
-            S = s0 * wl
-            xf = x_blk.astype(jnp.float32)
-            gA = gA + jnp.matmul(xf.T, R,
-                                 preferred_element_type=jnp.float32).T
-            hA = hA + hess_blocks(xf, S)
-            return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
-
-        acc0 = _shard_vary(
-            (jnp.zeros((L, d_work), jnp.float32), h_acc0,
-             jnp.zeros(L, jnp.float32), jnp.zeros(L, jnp.float32)),
-            axis_name)
-        (gA, hA, g0A, h0A), _ = jax.lax.scan(body, acc0, xs)
-        # the Rabit-allreduce/Spark-shuffle slot: partial per-shard sums
-        # combine over ICI/DCN
-        return (allreduce(gA), allreduce(hA),
-                allreduce(g0A), allreduce(h0A))
-
-    def cond(state):
-        i, _, _, delta = state
-        return (i < max_iter) & (delta > tol)
-
-    def body(state):
-        i, B, b0, _ = state
-        gA, hA, g0A, h0A = accumulate(B, b0)
-        B_new, b0_new, delta_vec = _newton_prox_update(
-            B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye, assemble,
-            fit_intercept)
-        return i + 1, B_new, b0_new, delta_vec.max()
-
-    state = (jnp.asarray(0, jnp.int32), jnp.zeros((L, d_work), jnp.float32),
-             jnp.zeros(L, jnp.float32), jnp.asarray(jnp.inf, jnp.float32))
-    _, B, b0, _ = jax.lax.while_loop(cond, body, state)
-
-    if standardize:
-        B = B / std[None, :]
-        b0 = b0 - (B * mean[None, :]).sum(1)
-    B = B[:, :d]  # drop inert padded columns on the tiled path
-    return B.reshape(F, Gn, d), b0.reshape(F, Gn)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loss", "fit_intercept", "standardize"))
-def sweep_glm_streamed(X: jax.Array, y: jax.Array, w: jax.Array,
-                       fold_masks: jax.Array, regs: jax.Array,
-                       alphas: jax.Array, *, loss: str = "logistic",
-                       max_iter=50, tol=1e-6,
-                       fit_intercept: bool = True,
-                       standardize: bool = True
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """All (fold, grid) fits in one program: returns (B [F, G, d] f32,
-    b0 [F, G]) in RAW feature units (unstandardized). max_iter/tol are
-    traced (distinct values share one executable)."""
-    return _streamed_core(X, y, w, fold_masks, regs, alphas, max_iter, tol,
-                          loss=loss, fit_intercept=fit_intercept,
-                          standardize=standardize, axis_name=None)
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_sweep_fn(mesh, loss, fit_intercept, standardize):
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import BATCH_AXIS
-
-    def core(X, y, w, fold_masks, regs, alphas, max_iter, tol):
-        return _streamed_core(X, y, w, fold_masks, regs, alphas, max_iter,
-                              tol, loss=loss, fit_intercept=fit_intercept,
-                              standardize=standardize, axis_name=BATCH_AXIS)
-
-    sm = _build_shard_map(
-        core, mesh,
-        in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
-                  P(None, BATCH_AXIS), P(None), P(None), P(), P()),
-        out_specs=(P(None, None, None), P(None, None)))
-    return jax.jit(sm)
-
-
-def sweep_glm_streamed_sharded(mesh, X, y, w, fold_masks, regs, alphas, *,
-                               loss: str = "logistic", max_iter=50,
-                               tol=1e-6, fit_intercept: bool = True,
-                               standardize: bool = True
-                               ) -> Tuple[jax.Array, jax.Array]:
-    """Row-sharded streamed sweep over the mesh `batch` axis.
-
-    Same math as sweep_glm_streamed; rows must be padded to the batch-axis
-    multiple with zero weights (the validator's mesh device_put does
-    this). Each shard scans only its local rows; accumulator psums ride
-    ICI within a slice and DCN across slices. Sharded standardization uses
-    one-pass psum'd moments (f32), which differs from the single-device
-    two-pass by f32 rounding only.
-
-    On a MULTI-PROCESS mesh, host (or fully-addressable) X/y/w/fold_masks
-    are treated as THIS PROCESS's rows and landed as the process's
-    batch-axis block of one global array (_land_rows_multihost); the
-    accumulator psums then cross hosts over DCN. Already-global inputs
-    pass through untouched."""
-    fn = _sharded_sweep_fn(mesh, loss, bool(fit_intercept),
-                           bool(standardize))
-    if _mesh_is_mp(mesh):
-        from ..parallel import multihost as MH
-        from ..parallel import podtrace
-
-        if not _is_global_array(X):
-            X, y, w, fold_masks = _land_rows_multihost(mesh, X, y, w,
-                                                       fold_masks)
-        # flight recorder: the psums are inside the jitted program, so
-        # the collective window is the whole sharded call; the explicit
-        # block (recording only) pins the barrier wall to this bracket
-        # instead of the caller's eventual fetch
-        with podtrace.collective(
-                "glm_sweep", rows=int(X.shape[0]), feat=int(X.shape[1]),
-                lanes=int(np.asarray(regs).shape[0])) as _psp:
-            out = fn(
-                X, y, w, fold_masks,
-                MH.replicated_global(np.asarray(regs, np.float32), mesh),
-                MH.replicated_global(np.asarray(alphas, np.float32),
-                                     mesh),
-                MH.replicated_global(np.asarray(int(max_iter), np.int32),
-                                     mesh),
-                MH.replicated_global(np.asarray(float(tol), np.float32),
-                                     mesh))
-            if _psp is not None:
-                jax.block_until_ready(out)
-        return out
-    return fn(
-        X, y, w, fold_masks, regs, alphas,
-        jnp.asarray(max_iter, jnp.int32), jnp.asarray(tol, jnp.float32))
 
 
 # -- squared-loss sufficient-statistics fast path ----------------------------
@@ -736,8 +540,14 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
                                               jax.Array]:
     """Row-sharded Gram fast path: each shard accumulates its local rows'
     per-fold moments, one psum combines them, the grid solves replicated.
-    Multi-process meshes follow sweep_glm_streamed_sharded's landing
-    contract (host inputs = this process's rows)."""
+    Rows must be padded to the batch-axis multiple with zero weights (the
+    validator's mesh device_put does this).
+
+    On a MULTI-PROCESS mesh, host (or fully-addressable) X/y/w/fold_masks
+    are treated as THIS PROCESS's rows and landed as the process's
+    batch-axis block of one global array (_land_rows_multihost); the
+    accumulator psums then cross hosts over DCN. Already-global inputs
+    pass through untouched."""
     fn = _sharded_gram_fn(mesh, bool(fit_intercept), bool(standardize))
     if _mesh_is_mp(mesh):
         from ..parallel import multihost as MH
@@ -746,9 +556,10 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
         if not _is_global_array(X):
             X, y, w, fold_masks = _land_rows_multihost(mesh, X, y, w,
                                                        fold_masks)
-        # collective window = sharded call + block (recording only):
-        # the Gram psum is inside the program — see sweep_glm_streamed_
-        # sharded above for the attribution contract
+        # flight recorder: the Gram psum is inside the jitted program, so
+        # the collective window is the whole sharded call; the explicit
+        # block (recording only) pins the barrier wall to this bracket
+        # instead of the caller's eventual fetch
         with podtrace.collective(
                 "glm_gram", rows=int(X.shape[0]), feat=int(X.shape[1]),
                 lanes=int(np.asarray(regs).shape[0])) as _psp:
@@ -1014,11 +825,69 @@ def _new_round_state(L: int, d: int, n_classes: int = 0) -> Dict[str, Any]:
             "bucket_sizes": []}
 
 
+def _record_round(st, idx, Lb: int, Bb, b0b, db, it: int) -> None:
+    """Land one finished round of bucket `Lb` (host arrays; rows past
+    len(idx) are the ladder's padding) in the lanes `idx`, and bill it."""
+    k = len(idx)
+    st["B"][idx] = Bb[:k]
+    st["b0"][idx] = b0b[:k]
+    st["delta"][idx] = db[:k]
+    st["iters"][idx] += it
+    st["rounds"] += 1
+    st["data_passes"] += it
+    # useful work (active lanes) vs executed work (the padded bucket the
+    # device actually ran) — the FLOP model bills the latter
+    st["lane_passes"] += it * k
+    st["padded_lane_passes"] += it * Lb
+    st["active_per_round"].append(k)
+    st["iters_per_round"].append(it)
+    st["bucket_sizes"].append(Lb)
+
+
+def _retire(st, idx, tol: float, max_iter: int) -> None:
+    """THE retire rule: a lane stops at its own delta <= tol or at the
+    iteration cap, its coefficients frozen."""
+    st["retired"][idx] = (st["delta"][idx] <= tol) \
+        | (st["iters"][idx] >= max_iter)
+
+
+def _run_rounds(st, run_round: Callable, round_iters: int, max_iter: int,
+                tol: float, on_round: Optional[Callable]) -> None:
+    """The retirement loop of both host drivers: while a lane is active,
+    `run_round(active, budget)` (the driver's own programs; it ends in
+    _record_round), retire, checkpoint hook. The budget never carries a
+    lane past max_iter."""
+    while True:
+        active = np.flatnonzero(~st["retired"])
+        if active.size == 0:
+            return
+        run_round(active, max(1, min(
+            round_iters, int((max_iter - st["iters"][active]).min()))))
+        _retire(st, active, tol, max_iter)
+        if on_round is not None:
+            on_round(st)
+
+
+def _rounds_info(st, tol: float, max_iter: int) -> Dict[str, Any]:
+    """The convergence telemetry every round driver reports."""
+    return {"glm_rounds": int(st["rounds"]),
+            "data_passes": int(st["data_passes"]),
+            "lane_passes": int(st["lane_passes"]),
+            "padded_lane_passes": int(st["padded_lane_passes"]),
+            "lanes_total": int(st["retired"].shape[0]),
+            "lanes_retired": int((st["delta"] <= tol).sum()),
+            "lanes_at_cap": int(((st["delta"] > tol)
+                                 & (st["iters"] >= max_iter)).sum()),
+            "active_per_round": [int(v) for v in st["active_per_round"]],
+            "iters_per_round": [int(v) for v in st["iters_per_round"]],
+            "bucket_sizes": [int(v) for v in st["bucket_sizes"]]}
+
+
 def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                               loss: str, max_iter: int = 50,
                               tol: float = 1e-6, fit_intercept: bool = True,
                               standardize: bool = True, mesh=None,
-                              round_iters: Optional[int] = None,
+                              round_iters: int = ROUND_ITERS_DEFAULT,
                               warm_start: bool = True,
                               warm_seed: Optional[Tuple] = None,
                               state: Optional[Dict[str, Any]] = None,
@@ -1027,15 +896,15 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                                          Dict[str, Any]]:
     """Host-driven convergence-aware streamed sweep for the IRLS losses.
 
-    Runs `sweep_glm_round` (K = round_iters or TMOG_GLM_ROUND_ITERS,
-    default ROUND_ITERS_DEFAULT, Newton iterations per jitted round); after
-    each round, lanes whose own delta cleared `tol` — or that exhausted
-    `max_iter` — RETIRE with their coefficients frozen, and the survivors
-    compact into the next round's power-of-two bucket (`bucket_lanes`).
+    Runs `sweep_glm_round` (`round_iters` Newton iterations per jitted
+    round); after each round, lanes whose own delta cleared `tol` — or
+    that exhausted `max_iter` — RETIRE with their coefficients frozen, and
+    the survivors compact into the next round's power-of-two bucket
+    (`bucket_lanes`).
     When `warm_start`, round 0 fits only each fold's
     strongest-regularization lane and seeds the rest of the fold from it
     (glmnet-style pathwise continuation), so low-reg lanes start near
-    their optimum instead of at zero; TMOG_GLM_WARMSTART=0 disables.
+    their optimum instead of at zero.
 
     `warm_seed` is the SAME continuation applied ACROSS TIME instead of
     across the regularization path (the retrain controller's refit):
@@ -1049,7 +918,7 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     coefficients.
 
     X/y/w/fold_masks are device arrays (pre-sharded when `mesh` is given,
-    exactly like sweep_glm_streamed_sharded's contract) — OR X is a
+    exactly like sweep_glm_squared_gram_sharded's contract) — OR X is a
     `parallel.tileplane.RowSource` whose chunks yield
     (x [c, d], y [c], w [c], fold_masks [c, F]) with y/w/fold_masks
     passed as None: then every data pass (the standardization prep pass
@@ -1099,10 +968,7 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
         d = int(X.shape[1])
     Gn = int(regs.shape[0])
     L = F * Gn
-    K = int(round_iters if round_iters is not None
-            else os.environ.get("TMOG_GLM_ROUND_ITERS",
-                                str(ROUND_ITERS_DEFAULT)))
-    K = max(K, 1)
+    K = max(int(round_iters), 1)
     max_iter = int(max_iter)
     tol_f = float(tol)
 
@@ -1249,17 +1115,20 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                             land(B0, np.float32), land(b00, np.float32),
                             mean, std, land(budget, np.int32),
                             land(tol_f, np.float32))
+
+            def fetch(out):
+                with _collector.trace_span("round_fetch",
+                                           kind="host_step"):
+                    # the host waits here for the round's program
+                    return (np.asarray(out[0]), np.asarray(out[1]),
+                            np.asarray(out[2]), int(out[3]))
+
             if src_mode:
                 Bb, b0b, db, it = _run_source_round(sel, l1b, l2b, B0,
                                                     b00, budget)
             elif mesh is None:
-                Bb, b0b, db, it = sweep_glm_round(
-                    *args, loss=loss, fit_intercept=fit_intercept)
-                with _collector.trace_span("round_fetch",
-                                           kind="host_step"):
-                    # the host waits here for the round's program
-                    Bb, b0b, db, it = (np.asarray(Bb), np.asarray(b0b),
-                                       np.asarray(db), int(it))
+                Bb, b0b, db, it = fetch(sweep_glm_round(
+                    *args, loss=loss, fit_intercept=fit_intercept))
             else:
                 # the psum lives INSIDE the jitted round program, so the
                 # collective window on the multi-process path is program
@@ -1271,37 +1140,12 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 with bracket("glm_round", rows=int(X.shape[0]),
                              feat=int(d), lanes=int(Lb),
                              iters=int(budget)):
-                    Bb, b0b, db, it = _sharded_round_fn(
-                        mesh, loss, bool(fit_intercept))(*args)
-                    with _collector.trace_span("round_fetch",
-                                               kind="host_step"):
-                        Bb = np.asarray(Bb)
-                        b0b = np.asarray(b0b)
-                        db = np.asarray(db)
-                        it = int(it)
+                    Bb, b0b, db, it = fetch(_sharded_round_fn(
+                        mesh, loss, bool(fit_intercept))(*args))
             with _podtrace.compute("glm_retire", active=int(k)):
-                st["B"][idx] = np.asarray(Bb)[:k]
-                st["b0"][idx] = np.asarray(b0b)[:k]
-                st["delta"][idx] = np.asarray(db)[:k]
-                it = int(it)
-                st["iters"][idx] += it
-                st["rounds"] += 1
-                st["data_passes"] += it
-                # useful work (active lanes) vs executed work (the
-                # padded bucket the device actually ran) — the FLOP
-                # model bills the latter
-                st["lane_passes"] += it * k
-                st["padded_lane_passes"] += it * Lb
-                st["active_per_round"].append(k)
-                st["iters_per_round"].append(it)
-                st["bucket_sizes"].append(Lb)
+                _record_round(st, idx, Lb, Bb, b0b, db, it)
 
-    def retire(idx):
-        st["retired"][idx] = (st["delta"][idx] <= tol_f) \
-            | (st["iters"][idx] >= max_iter)
-
-    if (warm_start and env_on("TMOG_GLM_WARMSTART") and not st["warmed"]
-            and Gn > 1
+    if (warm_start and not st["warmed"] and Gn > 1
             and not st["retired"].any() and int(st["iters"].max()) == 0):
         g_star = int(np.argmax(regs))
         warm_idx = np.arange(F, dtype=np.int64) * Gn + g_star
@@ -1313,41 +1157,22 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             others = rows[rows != warm_idx[f]]
             st["B"][others] = st["B"][warm_idx[f]]
             st["b0"][others] = st["b0"][warm_idx[f]]
-        retire(warm_idx)
+        _retire(st, warm_idx, tol_f, max_iter)
         st["warmed"] = True
         if on_round is not None:
             on_round(st)
 
-    while True:
-        active = np.flatnonzero(~st["retired"])
-        if active.size == 0:
-            break
-        budget = max(1, min(K, int((max_iter - st["iters"][active]).min())))
-        run_round(active, budget)
-        retire(active)
-        if on_round is not None:
-            on_round(st)
+    _run_rounds(st, run_round, K, max_iter, tol_f, on_round)
 
-    # host-side unstandardize, f32 like the on-device legacy route
-    # (source-mode mean/std are column-padded to d_work; the pads are
-    # inert — slice back to d)
+    # host-side unstandardize in f32 (source-mode mean/std are
+    # column-padded to d_work; the pads are inert — slice back to d)
     mean_h = np.asarray(mean, np.float32)[:d]
     std_h = np.asarray(std, np.float32)[:d]
     B = st["B"] / std_h[None, :]
     b0 = st["b0"] - (B * mean_h[None, :]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "rounds",
             "driver": "tileplane" if src_mode else "resident",
-            "glm_rounds": int(st["rounds"]),
-            "data_passes": int(st["data_passes"]),
-            "lane_passes": int(st["lane_passes"]),
-            "padded_lane_passes": int(st["padded_lane_passes"]),
-            "lanes_total": L,
-            "lanes_retired": int((st["delta"] <= tol_f).sum()),
-            "lanes_at_cap": int(((st["delta"] > tol_f)
-                                 & (st["iters"] >= max_iter)).sum()),
-            "active_per_round": [int(v) for v in st["active_per_round"]],
-            "iters_per_round": [int(v) for v in st["iters_per_round"]],
-            "bucket_sizes": [int(v) for v in st["bucket_sizes"]],
+            **_rounds_info(st, tol_f, max_iter),
             "warm_start": bool(st["warmed"]),
             "warm_seeded": warm_seeded}
     return B.reshape(F, Gn, d), b0.reshape(F, Gn), info
@@ -1587,16 +1412,17 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                               n_classes: int, max_iter: int = 50,
                               tol: float = 1e-6, fit_intercept: bool = True,
                               standardize: bool = True,
-                              round_iters: Optional[int] = None,
+                              round_iters: int = ROUND_ITERS_DEFAULT,
                               state: Optional[Dict[str, Any]] = None,
                               on_round: Optional[Callable] = None
                               ) -> Tuple[np.ndarray, np.ndarray,
                                          Dict[str, Any]]:
     """Host-driven streamed sweep of multinomial logistic regression: the
-    retirement loop of sweep_glm_streamed_rounds (rounds of K iterations,
-    lanes retire at their own delta <= tol or at max_iter, survivors
-    compact into `bucket_lanes` buckets, `state` / `on_round` checkpoint
-    every boundary and resume bit-identically) around `sweep_mlr_round`,
+    retirement loop of sweep_glm_streamed_rounds (_run_rounds: rounds of
+    `round_iters` iterations, lanes retire at their own delta <= tol or at
+    max_iter, survivors compact into `bucket_lanes` buckets, `state` /
+    `on_round` checkpoint every boundary and resume bit-identically)
+    around `sweep_mlr_round`,
     after ONE `mlr_gram_factor` pass. X/y/w/fold_masks are device arrays
     on one device; y holds class ids 0..n_classes-1 as floats.
 
@@ -1608,9 +1434,6 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     F, d, K = int(fold_masks.shape[0]), int(X.shape[1]), int(n_classes)
     Gn = int(regs.shape[0])
     L = F * Gn
-    Kr = max(int(round_iters if round_iters is not None
-                 else os.environ.get("TMOG_GLM_ROUND_ITERS",
-                                     str(ROUND_ITERS_DEFAULT))), 1)
     max_iter, tol_f = int(max_iter), float(tol)
     if standardize:
         mean, std = glm_standardize_stats(X, w)
@@ -1655,49 +1478,19 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 # the host waits here for the round's program
                 Bb, b0b, db, it = (np.asarray(out[0]), np.asarray(out[1]),
                                    np.asarray(out[2]), int(out[3]))
-        st["B"][idx] = Bb[:k]
-        st["b0"][idx] = b0b[:k]
-        st["delta"][idx] = db[:k]
-        st["iters"][idx] += it
-        st["rounds"] += 1
-        st["data_passes"] += it
-        st["lane_passes"] += it * k
-        st["padded_lane_passes"] += it * Lb
-        st["active_per_round"].append(k)
-        st["iters_per_round"].append(it)
-        st["bucket_sizes"].append(Lb)
+        _record_round(st, idx, Lb, Bb, b0b, db, it)
 
-    while True:
-        active = np.flatnonzero(~st["retired"])
-        if active.size == 0:
-            break
-        run_round(active, max(1, min(
-            Kr, int((max_iter - st["iters"][active]).min()))))
-        st["retired"][active] = (st["delta"][active] <= tol_f) \
-            | (st["iters"][active] >= max_iter)
-        if on_round is not None:
-            on_round(st)
+    _run_rounds(st, run_round, int(round_iters), max_iter, tol_f, on_round)
 
     mean_h, std_h = np.asarray(mean, np.float32), np.asarray(std, np.float32)
     B = st["B"] / std_h[None, :, None]
     b0 = st["b0"] - (B * mean_h[None, :, None]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "mlr_rounds",
             "driver": "resident", "classes": K,
-            "glm_rounds": int(st["rounds"]),
-            # every full read of X by the route's programs: the round
-            # iterations, the Gram pass, the two passes of the moments
-            "data_passes": int(st["data_passes"]) + 1
-            + (2 if standardize else 0),
-            "gram_passes": F,
-            "lane_passes": int(st["lane_passes"]),
-            "padded_lane_passes": int(st["padded_lane_passes"]),
-            "lanes_total": L,
-            "lanes_retired": int((st["delta"] <= tol_f).sum()),
-            "lanes_at_cap": int(((st["delta"] > tol_f)
-                                 & (st["iters"] >= max_iter)).sum()),
-            "active_per_round": [int(v) for v in st["active_per_round"]],
-            "iters_per_round": [int(v) for v in st["iters_per_round"]],
-            "bucket_sizes": [int(v) for v in st["bucket_sizes"]]}
+            **_rounds_info(st, tol_f, max_iter), "gram_passes": F}
+    # every full read of X by the route's programs: the round iterations,
+    # the Gram pass, the two passes of the moments
+    info["data_passes"] += 1 + (2 if standardize else 0)
     return B.reshape(F, Gn, d, K), b0.reshape(F, Gn, K), info
 
 

@@ -310,30 +310,12 @@ def available() -> bool:
     return True
 
 
-# Kernel variant selector (first-contact A/B lever): the "reshape" form
-# builds the (feature, bin) one-hot as a 3D broadcast-compare reshaped
-# [F, B, blk] -> [F*B, blk] (a leading-dim merge); "concat" builds it as F
-# independent [B, blk] 2D compares concatenated along the leading dim — no
-# 3D intermediate and no reshape at all, a genuinely different Mosaic
-# lowering path in case the reshape form is what stalled the round-3
-# 10M-row first contact (note jnp.repeat would NOT qualify: it lowers to
-# the same broadcast+reshape). Runtime-switchable (set_variant). NOTE: the bf16 input mode
-# always builds its one-hot with the per-feature concat form (a full-size
-# f32 one-hot next to its bf16 copy would overflow the scoped-VMEM stack,
-# and Mosaic rejects bf16 compares), so this A/B lever only
-# distinguishes lowerings on the f32 path — which is exactly what the
-# probe's pallas_direct stage runs (it does not pass allow_bf16).
-_VARIANTS = ("reshape", "concat")
-_VARIANT = os.environ.get("TMOG_PALLAS_HIST_VARIANT", "reshape").strip() \
-    or "reshape"
-
 # Histogram contraction input dtype. bf16 doubles the MXU ceiling (the
 # fused fold fit runs near the f32 matmul peak); the one-hot operand is
 # EXACT in bf16 (0/1) and counts stay integer-exact (1.0 payloads, f32
 # accumulation) — only the g/h payload channels quantize (~0.4%
-# relative). Flip with TMOG_HIST_BF16=0 to fall back to full-f32 inputs.
-_HIST_BF16 = os.environ.get("TMOG_HIST_BF16", "1").strip().lower() \
-    not in ("0", "false", "off")
+# relative). set_hist_bf16(False) is the lever of the float32 parity tests.
+_HIST_BF16 = True
 
 
 def set_hist_bf16(enabled: bool) -> None:
@@ -349,33 +331,22 @@ def set_hist_bf16(enabled: bool) -> None:
         fn.clear_cache()
 
 
-def set_variant(name: str) -> None:
-    global _VARIANT
-    if name not in _VARIANTS:
-        raise ValueError(f"unknown pallas hist variant: {name!r}")
-    if name != _VARIANT:
-        _VARIANT = name
-        for fn in _cache_consumers:
-            fn.clear_cache()
-        _hist_pallas_jit.clear_cache()
-        _route_hist_pallas_jit.clear_cache()
-
-
-def _feature_onehot(xf, *, F, B, blk, variant, use_bf16):
+def _feature_onehot(xf, *, F, B, blk, use_bf16):
     """(feature, bin) one-hot tile [F*B, blk] — the shared VPU expansion
     both histogram kernels contract against. Comparisons must run in f32
     (Mosaic rejects bf16 cmpf vectors, like the f32-iota restriction
     below); bf16 mode therefore builds the one-hot feature-by-feature,
     casting each [B, blk] slice down immediately — one full-size f32
     one-hot next to its bf16 copy would blow the 16MB scoped-VMEM stack.
+    f32 mode (the metric pass) builds it as one 3D broadcast-compare
+    reshaped [F, B, blk] -> [F*B, blk], a leading-dim merge.
     Mosaic's tpu.iota only produces integer vectors; build int32 and cast
     (f32 iota verified fine in interpret mode but fails TPU lowering)."""
-    mxu_dtype = jnp.bfloat16 if use_bf16 else jnp.float32
-    if variant == "concat" or use_bf16:
+    if use_bf16:
         bins2 = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0) \
             .astype(jnp.float32)                            # [B, 1]
         return jnp.concatenate(
-            [(xf[f:f + 1, :] == bins2).astype(mxu_dtype)    # [B, blk]
+            [(xf[f:f + 1, :] == bins2).astype(jnp.bfloat16)  # [B, blk]
              for f in range(F)], axis=0)                    # [F*B, blk]
     bins = jax.lax.broadcasted_iota(jnp.int32, (1, B, 1), 1) \
         .astype(jnp.float32)
@@ -396,7 +367,7 @@ def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count):
 
 
 def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
-            n_folds, variant, use_bf16=False, derive_count=False):
+            n_folds, use_bf16=False, derive_count=False):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -406,8 +377,7 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
     blk = xb_ref.shape[1]
     mxu_dtype = jnp.bfloat16 if use_bf16 else jnp.float32
     xf = xb_ref[:].astype(jnp.float32)                      # [F, blk]
-    oh = _feature_onehot(xf, F=F, B=B, blk=blk, variant=variant,
-                         use_bf16=use_bf16)
+    oh = _feature_onehot(xf, F=F, B=B, blk=blk, use_bf16=use_bf16)
 
     # fold-fused: each fold contributes its own slot one-hot x payload
     # rows to ONE contraction, so the (feature, bin) one-hot above — the
@@ -455,7 +425,7 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
     HBM plane (Co = C + 1; counts stay integer-exact, bf16 included).
 
     allow_bf16: opt-in to bf16 contraction INPUTS (f32 accumulation) when
-    the module flag agrees (TMOG_HIST_BF16, default on) — the tree-fit
+    the module flag agrees (_HIST_BF16, on) — the tree-fit
     consumers take it (one-hots and unit counts are exact in bf16; the
     g/h payloads quantize ~0.4% relative, within the tree-quality gates);
     the rank-metric consumer keeps full-precision weights. The resolved
@@ -467,13 +437,6 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
                             n_bins=n_bins, interpret=interpret,
                             use_bf16=allow_bf16 and _HIST_BF16,
                             derive_count=derive_count)
-
-
-def _check_variant():
-    if _VARIANT not in _VARIANTS:  # env typo must not silently re-run
-        raise ValueError(          # the default variant as false evidence
-            f"TMOG_PALLAS_HIST_VARIANT={_VARIANT!r}; expected one of "
-            f"{_VARIANTS}")
 
 
 @functools.partial(jax.jit,
@@ -501,10 +464,9 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
                          constant_values=float(n_slots))  # dropped
         N += pad
 
-    _check_variant()
     kernel = functools.partial(_kernel, F=F, B=B, C=C, n_slots=n_slots,
-                               n_folds=n_folds, variant=_VARIANT,
-                               use_bf16=use_bf16, derive_count=derive_count)
+                               n_folds=n_folds, use_bf16=use_bf16,
+                               derive_count=derive_count)
     return pl.pallas_call(
         kernel,
         grid=(N // blk,),
@@ -721,7 +683,7 @@ def route(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
 def _route_hist_kernel(xb_ref, pay_ref, node_ref, tbl_ref, hist_ref,
                        node_out_ref, *, F: int, B: int, C: int, n_nodes: int,
                        n_pad: int, n_folds: int,
-                       variant, use_bf16=False, derive_count=False):
+                       use_bf16=False, derive_count=False):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -731,8 +693,7 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, tbl_ref, hist_ref,
     blk = xb_ref.shape[1]
     mxu_dtype = jnp.bfloat16 if use_bf16 else jnp.float32
     xf = xb_ref[:].astype(jnp.float32)                      # [F, blk]
-    oh = _feature_onehot(xf, F=F, B=B, blk=blk, variant=variant,
-                         use_bf16=use_bf16)
+    oh = _feature_onehot(xf, F=F, B=B, blk=blk, use_bf16=use_bf16)
     fi = jax.lax.broadcasted_iota(jnp.int32, (F, blk), 0) \
         .astype(jnp.float32)
     ni = jax.lax.broadcasted_iota(jnp.int32, (n_pad, blk), 0) \
@@ -809,11 +770,9 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
                          constant_values=float(n_pad))
         N += pad
 
-    _check_variant()
     kernel = functools.partial(_route_hist_kernel, F=F, B=B, C=C,
                                n_nodes=n_nodes, n_pad=n_pad, n_folds=Fo,
-                               variant=_VARIANT, use_bf16=use_bf16,
-                               derive_count=derive_count)
+                               use_bf16=use_bf16, derive_count=derive_count)
     hist, node_out = pl.pallas_call(
         kernel,
         grid=(N // blk,),

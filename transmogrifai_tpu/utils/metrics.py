@@ -113,7 +113,7 @@ class SweepConvergence:
     sweep's `padded_lane_passes`: bucket_size x iterations, what the
     device actually executed). kernel: "gram" (squared-loss sufficient
     statistics, exactly one pass), "rounds" (retirement driver) or
-    "global" (legacy run-to-global-convergence fallback)."""
+    "mlr_rounds" (the multinomial retirement driver)."""
 
     family: str
     kernel: str
