@@ -294,10 +294,7 @@ def device_sweeps(X, y, cfg, sweep_dtype, errors):
     if kernel_roofline:
         out["kernel_roofline"] = kernel_roofline
     if best_tree is not None:
-        # TMOG_TREE_SCAN A/B marker + the compile-wall proxy it moves:
-        # artifacts from scan-on and scan-off runs stay attributable
-        from transmogrifai_tpu.ops import trees as _T
-        out["tree_scan"] = bool(_T.tree_scan_enabled())
+        # the fused tree fit's compile-wall proxy
         tts = tree_trace_seconds(kernel_roofline)
         if tts:
             out["tree_trace_s"] = tts
@@ -311,10 +308,10 @@ def tree_trace_seconds(kernel_roofline):
     spans: a cold span's wall includes jit trace + Mosaic compile, so
     subtracting the median warm wall of the same kernel label leaves the
     trace+compile share. Labels with no warm twin contribute their full
-    cold wall (an upper bound). This is the number the level-scan rewrite
-    attacks — O(1) programs in depth — so BENCH JSON carries it as
-    `tree_trace_s` next to the `tree_scan` flag for TMOG_TREE_SCAN A/B
-    runs (docs/performance.md). Spans group by (kernel, bytes_hbm):
+    cold wall (an upper bound). The fused fit's program count grows with
+    depth (one route_hist program a level), so BENCH JSON carries the
+    number as `tree_trace_s` (docs/performance.md). Spans group by
+    (kernel, bytes_hbm):
     analytic bytes are a pure function of the program shape (rows,
     lanes, depth, rounds, itemsize), so a grid sweep whose chunking
     emits several lane counts under one label never mixes one shape's

@@ -1308,7 +1308,7 @@ rm -rf "$RETRAIN_TMP"
 # tree-sweep smoke on the 2-device CPU mesh: the mesh-sharded fused sweep
 # (TMOG_GRID_FUSE=1 + a mesh validator) must take the
 # mask_folds:grid_fused_sharded route, match the meshless fused kernel's
-# margins at the metric level, and — the level-scan contract — a re-sweep
+# margins at the metric level, and — the one-executable contract — a re-sweep
 # at the same (shape, depth) must book ZERO true compiles, asserted from
 # the saved span artifact (not just in-process state)
 TMOG_GRID_FUSE=1 PYTHONPATH="$PWD" python - "$TRACE_DIR" <<'PY'
@@ -1369,7 +1369,7 @@ collector.detach_event_log()
 collector.disable()
 
 # compile count FROM THE ARTIFACT: the warm re-sweep's tree_shard_merge
-# spans must book 0 compiles (the level-scan program for this (shape,
+# spans must book 0 compiles (the fused-fit program for this (shape,
 # depth) already exists), while the cold sweep compiled at least one
 doc = json.load(open(out + "/tree_mesh_stage_metrics.json"))
 spans = doc["spans"]
@@ -1398,7 +1398,7 @@ merge_spans = [s for s in spans if s["name"] == "tree_shard_merge"]
 assert merge_spans, "sharded sweep must record tree_shard_merge spans"
 cold = compiles_in(subtree_ids("tree_sweep_cold"))
 # the warm sweep may re-jit validator-local helpers (fresh fold_metrics
-# closure per validate); the level-scan contract is about the FUSED FIT:
+# closure per validate); the one-executable contract is about the FUSED FIT:
 # its tree_shard_merge spans must book zero compiles on the re-sweep
 warm_merge = compiles_in(subtree_ids("tree_sweep_warm"),
                          name="tree_shard_merge")

@@ -30,10 +30,8 @@ def _isolated_planner(tmp_path, monkeypatch):
     monkeypatch.delenv("TMOG_PLAN", raising=False)
     for knob in ("TMOG_TILE_MB", "TMOG_STATS_TILE_ROWS",
                  "TMOG_SCORE_TILE_ROWS", "TMOG_GRID_FUSE",
-                 "TMOG_GRID_FUSE_HBM_LANES", "TMOG_GRID_FUSE_OUT_MB",
-                 "TMOG_TREE_SCAN"):
+                 "TMOG_GRID_FUSE_HBM_LANES", "TMOG_GRID_FUSE_OUT_MB"):
         monkeypatch.delenv(knob, raising=False)
-    from transmogrifai_tpu.models.trees import _TreeEstimator
     from transmogrifai_tpu.ops import glm_sweep as GS
 
     def _reset():
@@ -42,7 +40,6 @@ def _isolated_planner(tmp_path, monkeypatch):
         P._overrides_logged.clear()
         P._plans_logged.clear()
         GS._bucket_floor_cached = None       # once-per-process caches
-        _TreeEstimator._plan_scan_applied = None
     _reset()
     yield tmp_path / "corpus"
     _reset()
@@ -165,15 +162,12 @@ def test_cold_corpus_plan_equals_hand_defaults():
     assert GS.bucket_lanes(3) == GS._BUCKET_MIN
     assert P.glm_streamed_min_rows(64, 60) == V.STREAMED_SWEEP_MIN_ROWS
     assert P.planned_grid_fuse_caps() == (64, 8.0)
-    # no measured evidence -> None: leave the current growth form alone
-    # (a cold prior must not reverse a programmatic set_tree_scan)
-    assert P.planned_tree_scan() is None
     assert P.grid_fuse_enabled(10_000, 64, 5, 4, 6, 32) is False  # opt-in
     # the serving ladder is exactly the hand ladder
     assert E.planned_bucket_ladder(64) == E.bucket_ladder(64)
     plan = P.plan_fit(1_000_000, 64, n_folds=5, n_grids=12, depth=6,
                       n_bins=32)
-    for name in ("glm_streamed_min_rows", "tree_scan", "grid_fuse",
+    for name in ("glm_streamed_min_rows", "grid_fuse",
                  "grid_fuse_hbm_lanes", "grid_fuse_out_mb", "tile_mb",
                  "stats_tile_rows", "score_tile_rows",
                  "glm_bucket_floor"):
@@ -270,59 +264,6 @@ def test_env_override_logged_once_as_event(tmp_path, monkeypatch):
 def test_unparsable_override_falls_through(monkeypatch):
     monkeypatch.setenv("TMOG_TILE_MB", "not-a-number")
     assert P.planned_tile_mb() == HAND_DEFAULTS["tile_mb"]
-
-
-def test_tree_scan_env_means_hands_off(monkeypatch):
-    monkeypatch.setenv("TMOG_TREE_SCAN", "0")
-    # None = caller leaves the current growth form alone (hand wins)
-    assert P.planned_tree_scan() is None
-
-
-def test_tree_scan_programmatic_lever_not_reversed():
-    """set_tree_scan is a hand lever too: with no measured evidence the
-    fused-fit plan consult must leave a programmatic flip in place."""
-    from transmogrifai_tpu.models.trees import _TreeEstimator
-    from transmogrifai_tpu.ops import trees as T
-    prev = T.tree_scan_enabled()
-    try:
-        T.set_tree_scan(False)
-        _TreeEstimator._plan_growth_form()
-        assert T.tree_scan_enabled() is False  # cold prior: hands off
-    finally:
-        T.set_tree_scan(prev)
-
-
-def test_tree_scan_measured_preference_applies():
-    corpus = Corpus(P.corpus_dir())
-    shape = {"rows": 1e4, "depth": 6.0, "lanes": 5.0}
-    corpus.append([
-        rec("tree_fit", route="scan", wall=5.0, shape=shape, work=1e4),
-        rec("tree_fit", route="unrolled", wall=1.0, shape=shape,
-            work=1e4)])
-    assert P.planned_tree_scan() is False  # measured
-    plan = P.plan_fit(10_000, 8, depth=6, n_folds=5)
-    assert plan.decisions["tree_scan"].source == "measured"
-
-
-def test_tree_scan_lever_beats_measured_model():
-    """Even a MEASURED preference must not reverse a lever someone else
-    flipped at runtime — set_tree_scan is a hand setting, like the env
-    var."""
-    from transmogrifai_tpu.models.trees import _TreeEstimator
-    from transmogrifai_tpu.ops import trees as T
-    corpus = Corpus(P.corpus_dir())
-    shape = {"rows": 1e4, "depth": 6.0, "lanes": 5.0}
-    corpus.append([  # measured: scan wins — default state, no conflict
-        rec("tree_fit", route="scan", wall=1.0, shape=shape, work=1e4),
-        rec("tree_fit", route="unrolled", wall=5.0, shape=shape,
-            work=1e4)])
-    prev = T.tree_scan_enabled()
-    try:
-        T.set_tree_scan(False)  # a runtime A/B flipped the lever
-        _TreeEstimator._plan_growth_form()
-        assert T.tree_scan_enabled() is False  # hand beats model
-    finally:
-        T.set_tree_scan(prev)
 
 
 def test_streamable_row_floor_hand_override_wins(monkeypatch):

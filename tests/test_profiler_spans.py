@@ -150,11 +150,11 @@ class TestAnnotationSink:
         before = len(collector.current.kernel_metrics)
         out, events = profiled(
             tmp_path, lambda: _TreeEstimator._timed_fused_fit(
-                "tree_sweep_fold_fused", Xb, 6, 3, 2, lambda: "ran",
-                span="tree_level_scan"))
+                "tree_sweep_fold_fused", Xb, 6, 3, 2, lambda: "ran"))
         assert out == "ran"     # not a jax value: no fence touched it
-        ev, = named(events, "tmog.tree_fused:tree_level_scan")
+        ev, = named(events, "tmog.tree_fused:tree_levels")
         assert ev["stats"]["lanes"] == 6 and ev["stats"]["depth"] == 3
+        assert ev["stats"]["slot_passes"] == 1 + 2
         assert len(collector.current.kernel_metrics) == before
 
 

@@ -715,7 +715,7 @@ class TestENV001:
             import os
 
             def f():
-                return os.environ.get("TMOG_TREE_SCAN", "")
+                return os.environ.get("TMOG_TREE_SHARD", "")
         """, rules=["ENV001"])
         assert out == []
 

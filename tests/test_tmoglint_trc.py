@@ -454,7 +454,7 @@ class TestPLN001:
             import os
 
             def ladder(self):
-                return os.environ["TMOG_TREE_SCAN"]
+                return os.environ["TMOG_GRID_FUSE"]
         """, path="serve/engine.py", rules=["PLN001"])
         assert len(out) == 1
 
@@ -477,8 +477,8 @@ class TestPLN001:
         out = lint("""
             import os
 
-            _TREE_SCAN = os.environ.get("TMOG_TREE_SCAN", "1") != "0"
-        """, path="ops/trees.py", rules=["PLN001"])
+            _TILE_MB = int(os.environ.get("TMOG_TILE_MB", "32"))
+        """, path="parallel/tileplane.py", rules=["PLN001"])
         assert out == []
 
     def test_planner_fallback_idiom_silent(self):
@@ -769,8 +769,8 @@ class TestRepoScan:
             [os.path.join(REPO_ROOT, "transmogrifai_tpu", "planner",
                           "plan.py")], REPO_ROOT)
         governed = _governed_knobs(ctxs)
-        assert len(governed) >= 9
-        assert {"TMOG_TILE_MB", "TMOG_TREE_SCAN",
+        assert len(governed) >= 8
+        assert {"TMOG_TILE_MB", "TMOG_GRID_FUSE",
                 "TMOG_STATS_TILE_ROWS"} <= governed
 
 

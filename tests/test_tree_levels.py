@@ -1,24 +1,28 @@
-"""Whole-tree level-scan growth + mesh-sharded sweep lanes.
+"""Fold-fused tree growth at each level's own slot count + mesh lanes.
 
-The fused tree fit (ops/trees.fit_gbt_folds) grows every mid-tree level
-inside ONE lax.scan with fixed max-shape carries (TMOG_TREE_SCAN, default
-on), so program size — and the Mosaic compile wall it drives — is O(1) in
-depth instead of O(depth). Contracts pinned here:
+The fused tree fit (ops/trees.fit_gbt_folds) unrolls a tree over its
+depth: level d splits 1 << d nodes and its fused route+histogram pass
+(pallas_hist.route_hist) is traced at exactly that slot count — a level
+with one live node never pays for the deepest level's 2^(depth-2).
+Contracts pinned here:
 
-  1. the scan form is DECISION/MARGIN BIT-EXACT with the legacy unrolled
-     form across a parity zoo (depths 1-6, colsample_bylevel,
-     alpha/max_delta_step, per-lane scalar vectors, squared loss,
-     subsample, non-unit weights);
-  2. jitted program count is depth-independent for a fixed shape: a
-     re-sweep at the same (shape, depth) costs 0 true compiles and a
-     depth change costs exactly 1 (RecompileTracker);
-  3. the mesh route: fit_gbt_folds_sharded (shard_map over the batch
+  1. the multi-lane fused fit is DECISION/MARGIN BIT-EXACT with the
+     per-fold single-lane fit across a parity zoo (depths 1-6,
+     colsample_bylevel, alpha/max_delta_step, per-lane scalar vectors,
+     squared loss, subsample, non-unit weights): the fold axis only
+     batches;
+  2. route_hist is traced with n_nodes == 1 << d at every fused level,
+     single-device and under the sharded driver, and the `tree_fused`
+     span's slot_passes is the sum of exactly those ints;
+  3. one executable per (shape, depth): a re-sweep at the same
+     (shape, depth) costs 0 true compiles and a depth change costs
+     exactly 1 (RecompileTracker);
+  4. the mesh route: fit_gbt_folds_sharded (shard_map over the batch
      axis, psum-merged per-level histograms) matches the single-device
      fused fit on the 2-device CPU mesh, and mask_fit_scores_grid takes
      it instead of falling back per-fold;
-  4. uint8 binning for 128..255 bins is decision-identical to int32.
+  5. uint8 binning for 128..255 bins is decision-identical to int32.
 """
-import contextlib
 import functools
 
 import numpy as np
@@ -26,6 +30,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from transmogrifai_tpu.ops import pallas_hist as PH
 from transmogrifai_tpu.ops import trees as T
 from transmogrifai_tpu.parallel.mesh import make_mesh
 from transmogrifai_tpu.utils.metrics import collector
@@ -42,22 +47,25 @@ def _data(n=700, f=6, b=7, folds=3, seed=0, unit_w=True):
     return jnp.asarray(Xb), jnp.asarray(y), jnp.asarray(W)
 
 
-@contextlib.contextmanager
-def scan_mode(on: bool):
-    prev = T.tree_scan_enabled()
-    T.set_tree_scan(on)
-    try:
-        yield
-    finally:
-        T.set_tree_scan(prev)
+def _fit_lanes_and_each(Xb, y, W, key, **kw):
+    """The fused fit over every lane of W, and each lane alone through
+    the same program at Fo == 1 (per-lane [Fo] vectors sliced along)."""
+    fused = T.fit_gbt_folds(Xb, y, W, key, **kw)
+    singles = []
+    for k in range(W.shape[0]):
+        kw_k = {n: v[k:k + 1] if getattr(v, "ndim", 0) == 1 else v
+                for n, v in kw.items()}
+        singles.append(T.fit_gbt_folds(Xb, y, W[k:k + 1], key, **kw_k))
+    return fused, singles
 
 
-def _fit_both(Xb, y, W, key, **kw):
-    with scan_mode(False):
-        un = T.fit_gbt_folds(Xb, y, W, key, **kw)
-    with scan_mode(True):
-        sc = T.fit_gbt_folds(Xb, y, W, key, **kw)
-    return un, sc
+def _assert_lanes_equal_singles(fused, singles, msg=""):
+    trees, base, margins = fused
+    for k, single in enumerate(singles):
+        lane = (T.Tree(*(getattr(trees, fld)[:, k:k + 1]
+                         for fld in T.Tree._fields)),
+                base[k:k + 1], margins[k:k + 1])
+        _assert_fit_equal(lane, single, f"{msg} lane={k}")
 
 
 def _assert_fit_equal(a, b, msg=""):
@@ -73,16 +81,18 @@ def _assert_fit_equal(a, b, msg=""):
                                   err_msg=f"{msg} margins")
 
 
-class TestScanParityZoo:
-    """Scan vs unrolled: every tree decision and every margin bit-exact."""
+class TestLaneParityZoo:
+    """Fused lanes vs each lane alone: every tree decision and every
+    margin bit-exact (each lane's contraction rows are disjoint)."""
 
     @pytest.mark.parametrize("depth", [1, 2, 3, 4, 5, 6])
     def test_depths(self, depth):
         Xb, y, W = _data()
         kw = dict(n_rounds=2, depth=depth, n_bins=7, learning_rate=0.3,
                   reg_lambda=1.0, loss="logistic")
-        un, sc = _fit_both(Xb, y, W, jax.random.PRNGKey(7), **kw)
-        _assert_fit_equal(un, sc, f"depth={depth}")
+        fused, singles = _fit_lanes_and_each(Xb, y, W,
+                                             jax.random.PRNGKey(7), **kw)
+        _assert_lanes_equal_singles(fused, singles, f"depth={depth}")
 
     @pytest.mark.parametrize("kw", [
         dict(colsample_bylevel=0.5),
@@ -99,12 +109,13 @@ class TestScanParityZoo:
         base = dict(n_rounds=3, depth=3, n_bins=7, learning_rate=0.2,
                     reg_lambda=1.5, loss="logistic")
         base.update(kw)
-        un, sc = _fit_both(Xb, y, W, jax.random.PRNGKey(11), **base)
-        _assert_fit_equal(un, sc, str(kw))
+        fused, singles = _fit_lanes_and_each(
+            Xb, y, W, jax.random.PRNGKey(11), **base)
+        _assert_lanes_equal_singles(fused, singles, str(kw))
 
     def test_per_lane_scalar_vectors(self):
         """The config-fused sweep's per-lane eta/lambda/mcw/gamma vectors
-        ride through the scan carries unchanged."""
+        give each lane what its own scalars give it alone."""
         Xb, y, W = _data(folds=3, seed=5)
         kw = dict(
             n_rounds=3, depth=4, n_bins=7, loss="logistic",
@@ -112,38 +123,98 @@ class TestScanParityZoo:
             reg_lambda=jnp.asarray([1.0, 2.0, 0.5], jnp.float32),
             min_child_weight=jnp.asarray([0.0, 1.0, 0.0], jnp.float32),
             gamma=jnp.asarray([0.0, 0.05, 0.0], jnp.float32))
-        un, sc = _fit_both(Xb, y, W, jax.random.PRNGKey(42), **kw)
-        _assert_fit_equal(un, sc, "lane vectors")
+        fused, singles = _fit_lanes_and_each(
+            Xb, y, W, jax.random.PRNGKey(42), **kw)
+        _assert_lanes_equal_singles(fused, singles, "lane vectors")
 
-    def test_kill_switch_selects_the_legacy_path(self, monkeypatch):
-        """TMOG_TREE_SCAN=0 (set_tree_scan(False)) must trace the legacy
-        unrolled body — not the scan with different plumbing."""
-        Xb, y, W = _data(n=320)
-        kw = dict(n_rounds=1, depth=2, n_bins=7)
 
-        def boom(*a, **k):
-            raise AssertionError("scan path used under TMOG_TREE_SCAN=0")
+@pytest.fixture
+def route_hist_slots(monkeypatch):
+    """The n_nodes of every pallas_hist.route_hist call traced while the
+    fixture is live, in call order."""
+    seen = []
+    real = PH.route_hist
 
-        with scan_mode(False):
-            monkeypatch.setattr(T, "_grow_tree_folds_scan", boom)
-            T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0), **kw)
-        monkeypatch.undo()
+    def spy(*a, n_nodes, **k):
+        seen.append(n_nodes)
+        return real(*a, n_nodes=n_nodes, **k)
 
-        def boom2(*a, **k):
-            raise AssertionError("unrolled path used with scan enabled")
+    monkeypatch.setattr(PH, "route_hist", spy)
+    return seen
 
-        with scan_mode(True):
-            monkeypatch.setattr(T, "_grow_tree_folds_unrolled", boom2)
-            T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0), **kw)
+
+def _one_tree_of(seen, depth):
+    """The per-level slot counts of ONE tree out of a spy log: the round
+    scan may trace its body more than once, every trace a whole tree."""
+    per_tree = depth - 1
+    assert seen and len(seen) % per_tree == 0, seen
+    trees = [seen[i:i + per_tree] for i in range(0, len(seen), per_tree)]
+    assert all(t == trees[0] for t in trees), seen
+    return trees[0]
+
+
+class TestLevelSlotCounts:
+    """Each fused level runs at its OWN slot count — the test that fails
+    if a form padded to the deepest level's slots comes back."""
+
+    @pytest.mark.parametrize("depth", [3, 4, 5, 6])
+    def test_route_hist_traced_at_level_width(self, depth,
+                                              route_hist_slots):
+        # a row count no other test fits at: the jit cache cannot hold
+        # this program, so the fit really traces
+        Xb, y, W = _data(n=300 + depth, folds=2, seed=depth)
+        T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0), n_rounds=2,
+                        depth=depth, n_bins=7)
+        assert _one_tree_of(route_hist_slots, depth) == \
+            [1 << d for d in range(depth - 1)]
+
+    @pytest.mark.parametrize("depth,slot_passes", [(6, 31), (4, 7)])
+    def test_span_slot_passes_is_what_route_hist_saw(
+            self, depth, slot_passes, route_hist_slots):
+        from transmogrifai_tpu.models.trees import _TreeEstimator
+        Xb, y, W = _data(n=310 + depth, folds=2, seed=depth)
+        c = collector
+        c.enable("tree_levels_span")
+        try:
+            _TreeEstimator._timed_fused_fit(
+                "tree_sweep_fold_fused", Xb, W.shape[0], depth, 1,
+                lambda: T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0),
+                                        n_rounds=1, depth=depth,
+                                        n_bins=7))
+            c.finish()
+        finally:
+            c.disable()
+        sp, = [s for s in c.trace.spans if s.kind == "tree_fused"]
+        assert sp.name == "tree_levels"
+        assert sp.attrs["lanes"] == 2 and sp.attrs["depth"] == depth
+        assert sp.attrs["slot_passes"] == slot_passes
+        assert sp.attrs["slot_passes"] == \
+            sum(_one_tree_of(route_hist_slots, depth))
+
+    def test_sharded_fit_traces_the_same_level_widths(
+            self, monkeypatch, route_hist_slots):
+        depth = 5
+        Xb, y, W = _data(n=352, folds=2, seed=12)
+        kw = dict(n_rounds=1, depth=depth, n_bins=7)
+        key = jax.random.PRNGKey(2)
+        T.fit_gbt_folds(Xb, y, W, key, **kw)
+        single = _one_tree_of(list(route_hist_slots), depth)
+        del route_hist_slots[:]
+        # a private program dict: a cached shard_map program would not
+        # trace again
+        monkeypatch.setattr(T, "_SHARDED_FIT_CACHE", {})
+        T.fit_gbt_folds_sharded(Xb, y, W, key,
+                                mesh=make_mesh(n_batch=2, n_model=1), **kw)
+        assert _one_tree_of(route_hist_slots, depth) == single \
+            == [1, 2, 4, 8]
 
 
 class TestProgramCount:
-    """The compile-knee contract: one executable per (shape, depth)."""
+    """One executable per (shape, depth), however many levels it unrolls."""
 
     def _run(self, Xb, y, W, depth):
-        with scan_mode(True):
-            out = T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(1),
-                                  n_rounds=2, depth=depth, n_bins=7)
+        out = T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(1),
+                              n_rounds=2, depth=depth, n_bins=7)
         jax.block_until_ready(out)
         return out
 
@@ -153,7 +224,7 @@ class TestProgramCount:
         # depth 3's fit executable
         self._run(Xb, y, W, 3)
         c = collector
-        c.enable("tree_scan_compiles")
+        c.enable("tree_levels_compiles")
         try:
             with c.trace_span("resweep", kind="sweep_fit"):
                 self._run(Xb, y, W, 3)
@@ -223,24 +294,6 @@ class TestShardedLanes:
                                    rtol=1e-6)
         np.testing.assert_allclose(np.asarray(m2), np.asarray(m1),
                                    rtol=1e-4, atol=1e-5)
-
-    def test_sharded_unrolled_kill_switch(self):
-        """TMOG_TREE_SCAN=0 works under the sharded driver too (the
-        psums live in both growth forms); identical summation structure
-        on both sides makes this comparison exact regardless of ties."""
-        Xb, y, W = _data(n=512, folds=2, seed=4)
-        mesh = make_mesh(n_batch=2, n_model=1)
-        key = jax.random.PRNGKey(6)
-        kw = dict(n_rounds=3, depth=3, n_bins=7, learning_rate=0.3,
-                  reg_lambda=1.0, loss="logistic")
-        with scan_mode(True):
-            _, _, m_scan = T.fit_gbt_folds_sharded(Xb, y, W, key,
-                                                   mesh=mesh, **kw)
-        with scan_mode(False):
-            _, _, m_un = T.fit_gbt_folds_sharded(Xb, y, W, key,
-                                                 mesh=mesh, **kw)
-        np.testing.assert_array_equal(np.asarray(m_scan),
-                                      np.asarray(m_un))
 
     def test_sharded_rejects_subsample(self):
         Xb, y, W = _data(n=512, folds=2)
@@ -373,18 +426,17 @@ class TestUint8Bins:
         np.testing.assert_array_equal(got, want)
 
 
-def test_fused_folds_still_equal_single_fold_runs_under_scan():
+def test_fused_folds_still_equal_single_fold_runs_in_interpret_mode():
     """The PR 1 contract (each lane's contraction rows are disjoint)
-    holds under the scan form too — interpret-mode pallas kernels inside
-    lax.scan."""
+    through the interpret-mode pallas kernels, every level at its own
+    slot count."""
     Xb, y, W = _data(n=513, f=5, b=7, folds=2, seed=8)
     kw = dict(n_rounds=2, depth=3, n_bins=7, interpret=True)
-    with scan_mode(True):
-        fit = functools.partial(T.fit_gbt_folds, Xb, y,
-                                key=jax.random.PRNGKey(7), **kw)
-        _, base, margins = fit(W=W)
-        for k in range(W.shape[0]):
-            _, base1, m1 = fit(W=W[k:k + 1])
-            np.testing.assert_array_equal(np.asarray(margins[k]),
-                                          np.asarray(m1[0]))
-            assert float(base[k]) == float(base1[0])
+    fit = functools.partial(T.fit_gbt_folds, Xb, y,
+                            key=jax.random.PRNGKey(7), **kw)
+    _, base, margins = fit(W=W)
+    for k in range(W.shape[0]):
+        _, base1, m1 = fit(W=W[k:k + 1])
+        np.testing.assert_array_equal(np.asarray(margins[k]),
+                                      np.asarray(m1[0]))
+        assert float(base[k]) == float(base1[0])

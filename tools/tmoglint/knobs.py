@@ -54,9 +54,6 @@ KNOBS: List[Dict[str, str]] = [
      "doc": "docs/performance.md",
      "desc": "force the pure-jnp twins of every pallas kernel"},
     # -- tree sweep ---------------------------------------------------------
-    {"name": "TMOG_TREE_SCAN", "default": "1",
-     "doc": "docs/performance.md",
-     "desc": "whole-tree level-scan growth (0 = legacy unrolled form)"},
     {"name": "TMOG_TREE_SHARD", "default": "1",
      "doc": "docs/performance.md",
      "desc": "mesh-sharded fused tree sweep route (0 = per-fold fallback)"},

@@ -433,9 +433,9 @@ def check_trc005(ctx: LintContext) -> List[Finding]:
 #: scan does not include the planner (fixture scans); a scanned
 #: planner/plan.py always wins so the governed set cannot drift
 _GOVERNED_FALLBACK = frozenset({
-    "TMOG_TREE_SCAN", "TMOG_GRID_FUSE", "TMOG_GRID_FUSE_HBM_LANES",
-    "TMOG_GRID_FUSE_OUT_MB", "TMOG_TILE_MB", "TMOG_STATS_TILE_ROWS",
-    "TMOG_SCORE_TILE_ROWS", "TMOG_TILE_PREFETCH", "TMOG_INGEST_WORKERS",
+    "TMOG_GRID_FUSE", "TMOG_GRID_FUSE_HBM_LANES", "TMOG_GRID_FUSE_OUT_MB",
+    "TMOG_TILE_MB", "TMOG_STATS_TILE_ROWS", "TMOG_SCORE_TILE_ROWS",
+    "TMOG_TILE_PREFETCH", "TMOG_INGEST_WORKERS",
 })
 
 _PLANNER_GETTER_TAILS = {"plan_serving", "plan_fit", "grid_fuse_enabled",

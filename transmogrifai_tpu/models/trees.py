@@ -344,9 +344,7 @@ class _TreeEstimator(PredictorEstimator):
             else "grid_fused"
         label = "tree_sweep_grid_fused_sharded" if sharded \
             else "tree_sweep_grid_fused"
-        self._plan_growth_form()
-        span = "tree_shard_merge" if sharded else (
-            "tree_level_scan" if T.tree_scan_enabled() else None)
+        span = "tree_shard_merge" if sharded else "tree_levels"
         loss = "squared" if regression else "logistic"
         outs = []
         for lo in range(0, G, chunk):
@@ -403,44 +401,8 @@ class _TreeEstimator(PredictorEstimator):
     _WARM_FUSED_SHAPES: set = set()
 
     @staticmethod
-    def _plan_growth_form() -> None:
-        """Plan-time scan-vs-unrolled choice for the fused fits
-        (docs/planning.md): consult the measured cost model and apply
-        it through ops/trees.set_tree_scan BEFORE the span label and
-        jit-cache signature are read. planned_tree_scan returns None —
-        the current form stays untouched, no cache clear, no behavior
-        change — unless the corpus MEASURED a preference; and even
-        then, a lever someone ELSE flipped stays flipped: an
-        explicitly-set TMOG_TREE_SCAN and a programmatic set_tree_scan
-        call (the documented runtime A/B lever) are both hand settings
-        and beat the model. The guard: the planner only moves the form
-        when it currently sits where the planner (or the hand default)
-        left it. Any planner fault leaves the form alone."""
-        try:
-            from ..planner.plan import planned_tree_scan
-            want = planned_tree_scan()
-        except Exception:
-            return
-        if want is None:
-            return
-        cur = T.tree_scan_enabled()
-        baseline = _TreeEstimator._plan_scan_applied
-        if baseline is None:
-            baseline = True  # ops/trees' hand default (scan on); an
-            #                  env-set TMOG_TREE_SCAN returned None above
-        if cur != baseline:
-            return  # hand-flipped at runtime: hand beats model
-        if want != cur:
-            T.set_tree_scan(want)
-        _TreeEstimator._plan_scan_applied = want
-
-    #: the last growth form the PLANNER applied (None = never) — the
-    #: hands-off guard above compares the live lever against this
-    _plan_scan_applied = None
-
-    @staticmethod
     def _timed_fused_fit(label, Xb, lanes, depth, n_rounds, call,
-                         span=None):
+                         span="tree_levels"):
         """Run one fused-sweep fit; when stage metrics are being
         collected, time it to completion and record a kernel-roofline
         span (analytic HBM bytes from the single traffic model in
@@ -448,27 +410,26 @@ class _TreeEstimator(PredictorEstimator):
         %-of-roof without a hand-run roofline script. The first span per
         (backend, label, shape) carries cold=True: its wall contains the
         compile, not just the kernel, and would wildly understate
-        achieved GB/s. `span` ("tree_level_scan" / "tree_shard_merge")
-        additionally wraps the fit in a named trace span so a Perfetto
-        view shows which growth/merge form ran and the RecompileTracker
-        books the fit's compiles to it (docs/observability.md). The span
-        is there with collection off too (its profiler annotation costs
-        nothing then); the fence and the kernel record are not: they
-        change what a timed sweep measures."""
-        import contextlib
+        achieved GB/s. Every fit also runs inside a named `tree_fused`
+        trace span — "tree_levels", or "tree_shard_merge" on a mesh — so
+        a Perfetto view shows which merge form ran and the
+        RecompileTracker books the fit's compiles to it
+        (docs/observability.md). Its `slot_passes` is the sum of the
+        slot counts the level loop hands route_hist for one tree
+        (ops/trees.fused_level_slots): 31 at depth 6. The span is there
+        with collection off too (its profiler annotation costs nothing
+        then); the fence and the kernel record are not: they change
+        what a timed sweep measures."""
         from ..utils.metrics import collector
-        cm = collector.trace_span(span, kind="tree_fused",
-                                  lanes=int(lanes), depth=int(depth)) \
-            if span else contextlib.nullcontext()
+        cm = collector.trace_span(
+            span, kind="tree_fused", lanes=int(lanes), depth=int(depth),
+            slot_passes=sum(T.fused_level_slots(int(depth))))
         if not collector.enabled:
             with cm:
                 return call()
         import time
         from ..ops import pallas_hist
-        # keyed by backend AND growth form: a set_tree_scan flip clears
-        # the jit caches (the executables differ), so the other form's
-        # first fit recompiles and must be classified cold again
-        sig = (jax.default_backend(), T.tree_scan_enabled(), label,
+        sig = (jax.default_backend(), label,
                Xb.shape, str(Xb.dtype), lanes, depth, n_rounds)
         cold = sig not in _TreeEstimator._WARM_FUSED_SHAPES
         t0 = time.perf_counter()
@@ -863,14 +824,12 @@ class _GBTBase(_TreeEstimator):
         if not self._fused_route_ok(ctx, y, masks, kw["depth"]):
             return None
         Xb, edges, n_bins = ctx
-        self._plan_growth_form()
         _, _, margins = self._timed_fused_fit(
             "tree_sweep_fold_fused", Xb, masks.shape[0], kw["depth"],
             kw["n_rounds"],
             lambda: T.fit_gbt_folds(
                 Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
-                loss=self._loss, **kw),
-            span="tree_level_scan" if T.tree_scan_enabled() else None)
+                loss=self._loss, **kw))
         return margins
 
     def _mask_score_host(self, ctx, y, w, n_classes, multiclass):
@@ -1061,15 +1020,13 @@ class _XGBBase(_TreeEstimator):
         if not self._fused_route_ok(ctx, y, masks, kw["depth"]):
             return None
         Xb, edges, n_bins = ctx
-        self._plan_growth_form()
         _, _, margins = self._timed_fused_fit(
             "tree_sweep_fold_fused", Xb, masks.shape[0], kw["depth"],
             kw["n_rounds"],
             lambda: T.fit_gbt_folds(
                 Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
                 loss="squared" if self._regression else "logistic",
-                **kw),
-            span="tree_level_scan" if T.tree_scan_enabled() else None)
+                **kw))
         return margins
 
     def _mask_score(self, ctx, y, w, n_classes, multiclass):

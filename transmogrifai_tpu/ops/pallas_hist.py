@@ -112,11 +112,11 @@ def plan_fused_hist(n_feat: int, n_bins: int, lanes: int, depth: int,
     (256 bins, depth 6, a few hundred features, 3-5 folds) would sail
     past a Mosaic compile failure with no library-level fallback. Worst
     level is the deepest histogram pass: sibling subtraction halves the
-    slot count, so n_slots = 2^(depth-2) for depth >= 2. Under the
-    level-scan fit (ops/trees, TMOG_TREE_SCAN default) this is not just
-    the worst case but THE per-program shape: every fused pass runs at
-    the padded 2^(depth-2) slot width, and Mosaic compiles exactly one
-    route_hist program per (shape, depth) instead of one per level.
+    slot count, so n_slots = 2^(depth-2) for depth >= 2. The fused fit
+    (ops/trees._grow_tree_folds) runs level d at its own 1 << d slots,
+    one Mosaic route_hist program a level, so this is the LARGEST of a
+    fit's programs and not the shape of each: what fits here fits at
+    every shallower level.
     Residents:
     output block + the [F*B, blk] f32 one-hot tile (+ a bf16 copy when
     the bf16 input mode is on) + the f32 Xb/payload/slot tiles + the

@@ -44,7 +44,6 @@ Design (TPU-first, not a port):
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -966,37 +965,7 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
     return trees, base
 
 
-# -- fold-fused growth: whole-tree level scan vs depth unroll ----------------
-# TMOG_TREE_SCAN gates the whole-tree level-scan form of the fused fit
-# (default ON): levels 0..depth-2 run inside ONE lax.scan whose carries are
-# padded to the worst-level slot count (2^(depth-2)) with inactive slots
-# masked, so the traced program — and its Mosaic route_hist kernel — exists
-# ONCE per fit instead of once per level. Program size and trace/compile
-# wall become O(1) in depth (the compile-knee attack). =0 restores the legacy depth-unrolled
-# path, which produces bit-identical trees and margins.
-_TREE_SCAN = os.environ.get("TMOG_TREE_SCAN", "").strip().lower() \
-    not in ("0", "false", "off")
-
-
-def tree_scan_enabled() -> bool:
-    """Is the level-scan fused fit active? (env TMOG_TREE_SCAN,
-    default on; runtime toggle set_tree_scan)."""
-    return _TREE_SCAN
-
-
-def set_tree_scan(enabled: bool) -> None:
-    """Runtime toggle for the level-scan fused fit (the bench A/B lever).
-    The choice is read at trace time — it is NOT part of the jit key — so
-    flipping clears the fused-fit caches: a compiled unrolled program
-    must never satisfy a scan request or vice versa."""
-    global _TREE_SCAN
-    if _TREE_SCAN == bool(enabled):
-        return
-    _TREE_SCAN = bool(enabled)
-    fit_gbt_folds.clear_cache()
-    _SHARDED_FIT_CACHE.clear()
-
-
+# -- fold-fused growth ------------------------------------------------------
 def _allreduce(v, axis_name):
     """psum under the row-sharded driver (the Rabit-allreduce slot of the
     XGBoost hist design); identity on a single device."""
@@ -1072,6 +1041,21 @@ def _fold_leaves(last, *, n_leaves, reg_lambda, alpha, max_delta_step,
                          learning_rate)
 
 
+def level_slots(depth: int) -> tuple:
+    """Live nodes of each level of one depth-`depth` tree, root first.
+    The fused fit splits level d at exactly this many slots; every level
+    but the last then runs one fused route+histogram pass at that slot
+    count (fused_level_slots), and the last one only routes."""
+    return tuple(1 << d for d in range(depth))
+
+
+def fused_level_slots(depth: int) -> tuple:
+    """`n_nodes` of each pallas_hist.route_hist pass of one tree, in
+    level order — the ints _grow_tree_folds' level loop passes, and what
+    the `tree_fused` span sums into `slot_passes`."""
+    return level_slots(depth)[:-1]
+
+
 def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                      reg_lambda, min_child_weight, min_instances,
                      min_info_gain, gamma, learning_rate, feature_mask,
@@ -1094,11 +1078,13 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     is the grow_tree math vmapped over the fold axis. On CPU the
     dispatchers drop to gather/segment-sum fallbacks (same decisions).
 
-    Two program forms, decision/margin bit-identical (tests/
-    test_tree_scan.py): the level-SCAN form (default) runs all mid-tree
-    levels in one lax.scan at the fixed worst-level shape, the legacy
-    unrolled form (TMOG_TREE_SCAN=0) emits one program section per
-    level. `axis_name` names a shard_map mesh axis rows are sharded
+    One growth form, unrolled over depth: level d emits its own program
+    section and its fused pass runs at the level's OWN slot count
+    (`n_nodes = 1 << d`, level_slots), so a level with one live node
+    does not pay for the deepest level's 2^(depth-2). The cost is
+    program size O(depth): one Mosaic route_hist program a level
+    (depth 6: fit_gbt_folds compiles in ~124 s on the chip, PERF.md §6
+    PR 28). `axis_name` names a shard_map mesh axis rows are sharded
     over: every level histogram psums across shards before the split
     algebra (DrJAX-style psum-merged MapReduce), routing stays local.
 
@@ -1107,185 +1093,6 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     bitwise what predict_bins returns for each fold's tree, read off the
     final routing state instead of re-traversed.
     """
-    kw = dict(depth=depth, n_bins=n_bins, reg_lambda=reg_lambda,
-              min_child_weight=min_child_weight,
-              min_instances=min_instances, min_info_gain=min_info_gain,
-              gamma=gamma, learning_rate=learning_rate,
-              feature_mask=feature_mask, interpret=interpret, alpha=alpha,
-              max_delta_step=max_delta_step,
-              level_feature_frac=level_feature_frac, level_key=level_key,
-              feature_mask_count=feature_mask_count, axis_name=axis_name)
-    if tree_scan_enabled() and depth >= 1:
-        return _grow_tree_folds_scan(Xb_t, G, H, **kw)
-    return _grow_tree_folds_unrolled(Xb_t, G, H, **kw)
-
-
-def _grow_tree_folds_scan(Xb_t, G, H, *, depth, n_bins, reg_lambda,
-                          min_child_weight, min_instances, min_info_gain,
-                          gamma, learning_rate, feature_mask,
-                          interpret=False, alpha=0.0, max_delta_step=0.0,
-                          level_feature_frac=1.0, level_key=None,
-                          feature_mask_count=None, axis_name=None):
-    """Whole-tree level-scan form of _grow_tree_folds.
-
-    Levels 0..depth-2 run inside ONE lax.scan with fixed max-shape
-    carries: the slot axis of every histogram/table is padded to
-    S = 2^(depth-2) (the worst level the fused route+hist pass serves —
-    exactly the shape plan_fused_hist already budgets), level d uses the
-    first 2^d slots and masks the rest. One route_hist program — not
-    depth-1 of them — reaches Mosaic, and the interleave/cumsum/argmax
-    split algebra exists once in the HLO. The final level splits and
-    routes outside the scan (its tables are twice the scan width and it
-    needs no histogram pass), reusing the same split closure, so total
-    program size is O(1) in depth.
-
-    Bit-exactness vs the unrolled form: per-slot histogram sums are
-    independent of the kernel's slot count, the split algebra is the
-    same expression on the same values, and padded slots can never be
-    selected by a row (their tables hold the dead all-left encoding).
-    """
-    from . import pallas_hist
-
-    F, N = Xb_t.shape
-    Fo = G.shape[0]
-    B = n_bins + 1
-    split_scores_f = _fold_split_scores(reg_lambda, min_child_weight, gamma)
-    use_level_mask = level_feature_frac < 1.0 and level_key is not None
-    key0 = level_key if level_key is not None \
-        else jnp.zeros((2,), jnp.uint32)
-
-    node = jnp.zeros((Fo, N), jnp.float32)
-    pay = jnp.stack([G, H], axis=1).reshape(2 * Fo, N)
-
-    def level_tables(full, n_act, lkey):
-        """Split algebra for ONE level at padded slot width: cumsums over
-        the shifted bin axis, sparsity-aware gains, argmax. Slots >=
-        n_act (scan padding; None = all live) hold zero histograms —
-        their gains are forced out so they land the dead all-left
-        encoding (feat 0, thresh B-1, miss 0) deterministically; live
-        slots see bit-identical algebra to the unrolled path."""
-        S_pad = full.shape[1]
-        hg = full[:, :, 0][..., None]                     # [Fo,S,F,B,1]
-        hh = full[:, :, 1]                                # [Fo,S,F,B]
-        hc = full[:, :, 2]
-        GL = jnp.cumsum(hg, axis=3)
-        HL = jnp.cumsum(hh, axis=3)
-        CL = jnp.cumsum(hc, axis=3)
-        Gt, Ht, Ct = GL[:, :, 0, -1, :], HL[:, :, 0, -1], CL[:, :, 0, -1]
-        Gm, Hm, Cm = hg[:, :, :, 0, :], hh[:, :, :, 0], hc[:, :, :, 0]
-        gain = split_scores_f(GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm,
-                              reg_lambda, min_child_weight, min_instances,
-                              min_info_gain, gamma, alpha, False)
-        if feature_mask is not None:
-            gain = jnp.where(feature_mask[None, None, :, None, None],
-                             gain, -jnp.inf)
-        if use_level_mask:
-            # colsample_bylevel: one fresh subset per level, shared by
-            # every fold (fold parity with the sequential loop), nested
-            # inside the bytree subset exactly as grow_tree does
-            lkey, sub = jax.random.split(lkey)
-            fml = _level_feature_mask(sub, F, level_feature_frac,
-                                      feature_mask, feature_mask_count)
-            gain = jnp.where(fml[None, None, :, None, None],
-                             gain, -jnp.inf)
-        flat = gain.reshape(Fo, S_pad, F * B * 2)
-        best = jnp.argmax(flat, axis=2)                   # [Fo, S]
-        best_gain = jnp.take_along_axis(flat, best[..., None],
-                                        axis=2)[..., 0]
-        ok = jnp.isfinite(best_gain)
-        if n_act is not None:
-            ok = ok & (jnp.arange(S_pad, dtype=jnp.int32)[None, :] < n_act)
-        f_lvl = jnp.where(ok, (best // (B * 2)).astype(jnp.int32), 0)
-        t_lvl = jnp.where(ok, ((best // 2) % B).astype(jnp.int32), B - 1)
-        m_lvl = jnp.where(ok, (best % 2).astype(jnp.int32), 0)
-        last = (GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm, f_lvl, t_lvl, m_lvl)
-        return f_lvl, t_lvl, m_lvl, lkey, last
-
-    # root histogram: all rows slot 0, one plain batched pass — partial
-    # sums psum-merge across row shards under the sharded driver
-    root = _allreduce(pallas_hist.hist_folds(
-        Xb_t, pay, node, n_slots=1, n_bins=B, interpret=interpret,
-        allow_bf16=True, derive_count=True), axis_name)
-    root = root.reshape(Fo, 1, 3, F, B)
-
-    feats, threshs, misses = [], [], []
-    if depth >= 2:
-        S = 1 << (depth - 2)
-        if S > 1:
-            histL0 = jnp.concatenate(
-                [root, jnp.zeros((Fo, S - 1, 3, F, B), jnp.float32)],
-                axis=1)
-        else:
-            histL0 = root
-        # seeding histL = prev = padded root makes the body UNIFORM: the
-        # level-0 interleave yields [root, root - root, 0, ...] — the
-        # root level's full histogram with no branch on the level index
-        n_act_levels = jnp.asarray([1 << d for d in range(depth - 1)],
-                                   jnp.int32)
-        carry0 = _shard_vary_opt((node, histL0, histL0, key0), axis_name)
-
-        def body(carry, n_act):
-            node, prevh, histL, lkey = carry
-            # full level histogram by sibling subtraction at the PADDED
-            # width: slot 2p = left child (histL), 2p+1 = parent - left;
-            # truncating the interleave at S keeps the carry fixed-shape
-            # (levels inside the scan have at most S live nodes)
-            full = jnp.stack([histL, prevh - histL], axis=2).reshape(
-                Fo, 2 * S, 3, F, B)[:, :S]
-            f_lvl, t_lvl, m_lvl, lkey, _ = level_tables(full, n_act, lkey)
-            # fused pass: route with this level's tables AND accumulate
-            # the next level's left-child histograms in ONE Xb read;
-            # n_nodes is the padded width every level, so Mosaic sees
-            # exactly one route_hist shape per fit
-            hist, node = pallas_hist.route_hist(
-                Xb_t, pay, node, f_lvl, t_lvl, m_lvl, n_nodes=S,
-                n_bins=B, interpret=interpret, allow_bf16=True,
-                derive_count=True)
-            hist = _allreduce(hist, axis_name)
-            return ((node, full, hist.reshape(Fo, S, 3, F, B), lkey),
-                    (f_lvl, t_lvl, m_lvl))
-
-        (node, prevh, histL, key0), (fs, ts, ms) = jax.lax.scan(
-            body, carry0, n_act_levels)
-        full_f = jnp.stack([histL, prevh - histL], axis=2).reshape(
-            Fo, 2 * S, 3, F, B)
-        for d in range(depth - 1):
-            feats.append(fs[d][:, :1 << d])
-            threshs.append(ts[d][:, :1 << d])
-            misses.append(ms[d][:, :1 << d])
-    else:
-        full_f = root
-
-    # final level: split + plain routing pass (no further histogram) —
-    # one unrolled copy of the level body at twice the scan width
-    n_half = 1 << (depth - 1)
-    f_lvl, t_lvl, m_lvl, key0, last = level_tables(full_f, None, key0)
-    feats.append(f_lvl)
-    threshs.append(t_lvl)
-    misses.append(m_lvl)
-    node = pallas_hist.route(Xb_t, node, f_lvl, t_lvl, m_lvl,
-                             n_nodes=n_half, interpret=interpret)
-
-    leaf = _fold_leaves(last, n_leaves=1 << depth, reg_lambda=reg_lambda,
-                        alpha=alpha, max_delta_step=max_delta_step,
-                        learning_rate=learning_rate)
-    leaf_rows = pallas_hist.table_lookup(
-        leaf[:, :, 0], node, interpret=interpret)         # [Fo, N]
-    tree = Tree(jnp.concatenate(feats, axis=1),
-                jnp.concatenate(threshs, axis=1), leaf,
-                jnp.concatenate(misses, axis=1))
-    return tree, leaf_rows
-
-
-def _grow_tree_folds_unrolled(Xb_t, G, H, *, depth, n_bins,
-                              reg_lambda, min_child_weight, min_instances,
-                              min_info_gain, gamma, learning_rate,
-                              feature_mask, interpret=False, alpha=0.0,
-                              max_delta_step=0.0, level_feature_frac=1.0,
-                              level_key=None, feature_mask_count=None,
-                              axis_name=None):
-    """Legacy depth-unrolled form (TMOG_TREE_SCAN=0 kill switch): one
-    program section per level, O(depth) HLO. See _grow_tree_folds."""
     from . import pallas_hist
 
     F, N = Xb_t.shape
@@ -1307,8 +1114,7 @@ def _grow_tree_folds_unrolled(Xb_t, G, H, *, depth, n_bins,
     last = None
     prev = None
     hist = None
-    for d in range(depth):
-        n_nodes = 1 << d
+    for d, n_nodes in enumerate(level_slots(depth)):
         if d == 0:
             # root histogram: all rows slot 0, one plain batched pass
             hist = _allreduce(pallas_hist.hist_folds(
@@ -1539,10 +1345,9 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
     one-hots that dominate the histogram kernel, with a contraction M dim
     (slots x 3 payload channels) far under the 128-row MXU tile. Here the
     folds share every Xb pass (fold-fused pallas histograms + routing) and
-    stack their payload rows into the same contraction. Whole trees grow
-    in ONE lax.scan over levels by default (TMOG_TREE_SCAN, see
-    _grow_tree_folds), so the traced program is O(1) — not O(depth) — in
-    size and one (shape, depth) compiles exactly one executable.
+    stack their payload rows into the same contraction. Each level's
+    fused pass runs at that level's own slot count (_grow_tree_folds);
+    one (shape, depth) compiles exactly one executable.
 
     Xb [N, F] binned (bin_matrix layout); y [N]; W [Fo, N] per-fold
     weights (0 = row excluded from that fold's fit). Per-fold quantities

@@ -215,9 +215,9 @@ def _cal_glm_routes(backend: str, scale: float) -> List[PlanRecord]:
 
 
 def _cal_tree_routes(backend: str, scale: float) -> List[PlanRecord]:
-    """Scan-vs-unrolled growth form AND grid-fused-vs-per-config lane
-    batching on the real fused fit, with compile walls recorded from
-    the cold calls (the knee term's measured companion)."""
+    """Grid-fused-vs-per-config lane batching on the real fused fit,
+    with the compile wall recorded from the cold call (the knee term's
+    measured companion)."""
     import jax
     import jax.numpy as jnp
     from ..ops import trees as T
@@ -245,24 +245,6 @@ def _cal_tree_routes(backend: str, scale: float) -> List[PlanRecord]:
         return warm, max(cold - warm, 0.0)
 
     out: List[PlanRecord] = []
-    prev = T.tree_scan_enabled()
-    try:
-        for route, scan in (("scan", True), ("unrolled", False)):
-            T.set_tree_scan(scan)
-            warm, compile_s = fit(lanes=4)
-            shape = {"rows": float(rows), "feat": float(F),
-                     "lanes": 4.0, "depth": float(depth)}
-            work = float(rows) * F * 4 * depth
-            out.append(PlanRecord(
-                family="tree_fit", backend=backend, route=route,
-                shape=shape, wall_s=warm, work=work, src="calibrate"))
-            out.append(PlanRecord(
-                family="tree_fit", backend=backend, route=route,
-                shape=shape, compile_s=compile_s, work=work, cold=True,
-                src="calibrate"))
-    finally:
-        T.set_tree_scan(prev)
-
     # grid fusion: 4 configs x 2 folds as ONE 8-lane program vs 4
     # sequential 2-lane programs (identical total work)
     warm8, compile8 = fit(lanes=8)

@@ -38,8 +38,6 @@ from .corpus import Corpus, PlanRecord
 HAND_DEFAULTS: Dict[str, Any] = {
     # automl/tuning/validators.STREAMED_SWEEP_MIN_ROWS
     "glm_streamed_min_rows": 200_000,
-    # ops/trees TMOG_TREE_SCAN default (scan on)
-    "tree_scan": True,
     # validators TMOG_GRID_FUSE default (opt-in because of the knee)
     "grid_fuse": False,
     # ops/pallas_hist TMOG_GRID_FUSE_HBM_LANES / _OUT_MB defaults

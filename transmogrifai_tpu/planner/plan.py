@@ -8,8 +8,6 @@ decision                    consumed by
 ==========================  ===========================================
 glm_streamed_min_rows       validators._streamable (streamed-vs-
                             materialized GLM sweep route)
-tree_scan                   models/trees fused fits (scan-vs-unrolled
-                            growth form, via ops/trees.set_tree_scan)
 grid_fuse                   validators' config-fused sweep gate
 grid_fuse_hbm_lanes/out_mb  ops/pallas_hist.plan_lane_chunk caps
 tile_mb                     parallel/tileplane.tile_budget_bytes
@@ -51,7 +49,6 @@ _DEFAULT_CORPUS_DIR = os.path.join("~", ".cache", "transmogrifai_tpu",
 #: decision name -> the env knob that hand-overrides it (decisions that
 #: were bare constants before this PR have no override knob)
 _ENV_FOR: Dict[str, str] = {
-    "tree_scan": "TMOG_TREE_SCAN",
     "grid_fuse": "TMOG_GRID_FUSE",
     "grid_fuse_hbm_lanes": "TMOG_GRID_FUSE_HBM_LANES",
     "grid_fuse_out_mb": "TMOG_GRID_FUSE_OUT_MB",
@@ -148,8 +145,6 @@ def _env_override(name: str) -> Optional[PlanDecision]:
     try:
         if name == "grid_fuse":
             value: Any = raw.lower() in ("1", "true", "on")
-        elif name == "tree_scan":
-            value = raw.lower() not in ("0", "false", "off")
         elif isinstance(default, float):
             value = float(raw)
         else:
@@ -350,42 +345,6 @@ def glm_streamed_min_rows(n_feat: int = 0, lanes: int = 0) -> int:
     return int(_min_rows_decision(n_feat, lanes).value)
 
 
-def planned_tree_scan() -> Optional[bool]:
-    """Scan-vs-unrolled fused tree growth, or None when the caller
-    should leave the current form alone: env override set (hand wins),
-    planner off, or NO measured evidence — ops/trees' set_tree_scan is
-    also a programmatic hand lever (runtime A/B runs flip it without
-    the env var), so only a MEASURED route preference may move the
-    form; a cold-corpus prior must not reverse the lever. models/trees
-    applies a non-None answer via set_tree_scan before each fused
-    fit."""
-    if _ENV_FOR["tree_scan"] in os.environ:
-        _note_override("tree_scan", _ENV_FOR["tree_scan"],
-                       os.environ[_ENV_FOR["tree_scan"]].strip())
-        return None
-    if not plan_enabled():
-        return None
-
-    # the decision is deliberately SHAPE-FREE (unit-cost comparison
-    # over all measured records, one stable answer per corpus): a
-    # per-shape answer could flip between the depth-2 and depth-6
-    # configs of ONE grid sweep, and every flip clears the fused-fit
-    # jit caches — recompiling mid-sweep costs more than any per-shape
-    # gain the growth form could buy
-    decision = _decide("tree_scan", _tree_scan_compute)
-    if decision.source != "measured":
-        return None
-    return bool(decision.value)
-
-
-def _tree_scan_compute(model: CostModel) -> PlanDecision:
-    route, source, alts = model.choose_route(
-        "tree_fit", ("scan", "unrolled"),
-        "scan" if HAND_DEFAULTS["tree_scan"] else "unrolled", {})
-    return PlanDecision(name="tree_scan", value=(route == "scan"),
-                        source=source, alternatives=alts)
-
-
 def grid_fuse_enabled(n_rows: int = 0, n_feat: int = 0, n_folds: int = 0,
                       n_grids: int = 0, depth: int = 0,
                       n_bins: int = 0, n_shards: int = 1) -> bool:
@@ -523,15 +482,6 @@ def plan_fit(n_rows: int, n_feat: int, *, n_folds: int = 1,
 
     decisions["glm_streamed_min_rows"] = _min_rows_decision(n_feat,
                                                             lanes)
-    env_scan = _ENV_FOR["tree_scan"] in os.environ
-    ts = planned_tree_scan()
-    decisions["tree_scan"] = PlanDecision(
-        name="tree_scan",
-        value=_env_override("tree_scan").value if env_scan
-        else (HAND_DEFAULTS["tree_scan"] if ts is None else ts),
-        source="env" if env_scan
-        else ("off" if not plan_enabled()
-              else ("prior" if ts is None else "measured")))
     decisions["grid_fuse"] = _grid_fuse_decision(
         n_rows, n_feat, n_folds, n_grids, depth, n_bins, n_shards)
     decisions["grid_fuse_hbm_lanes"] = hbm_lanes_dec
