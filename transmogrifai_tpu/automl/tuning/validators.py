@@ -536,15 +536,29 @@ class Validator:
         if X.shape[0] < min_rows and (
                 multiclass or getattr(self, "warm_seed", None) is None):
             return False
-        from ...ops.glm_sweep import streamed_mlr_route_ok, streamed_route_ok
+        from ...ops import glm_sweep as GS
         lanes = n_folds * max(len(grids), 1)
-        if not (streamed_mlr_route_ok(X.shape[1], lanes, n_classes,
-                                      SWEEP_LANE_BUDGET_BYTES) if multiclass
-                else streamed_route_ok(X.shape[1], lanes,
-                                       SWEEP_LANE_BUDGET_BYTES)):
+        d = X.shape[1]
+        if multiclass:
+            ok = GS.streamed_mlr_route_ok(d, lanes, n_classes,
+                                          SWEEP_LANE_BUDGET_BYTES)
+        elif self._wide_rounds(est.streamed_loss, d):
+            ok = GS.streamed_wide_route_ok(d, lanes, SWEEP_LANE_BUDGET_BYTES)
+        else:
+            ok = GS.streamed_route_ok(d, lanes, SWEEP_LANE_BUDGET_BYTES)
+        if not ok:
             return False
         _, axes = est.batched_fit_fn()
         return self._constant_off_axis(est, grids, axes)
+
+    def _wide_rounds(self, loss: str, d: int) -> bool:
+        """Does a binary sweep of this loss over d columns take the wide
+        rounds (ops/glm_sweep.sweep_glm_wide_round)? Chosen from what is
+        observed: logistic loss, more columns than the narrow Gram einsum
+        holds, one device (a mesh keeps the feature-tiled rounds, which
+        have a shard_map form and stop at 1 792 columns)."""
+        from ...ops.glm_sweep import TRI_MAX_D
+        return loss == "logistic" and d > TRI_MAX_D and self.mesh is None
 
     # -- shared helpers for the device-sweep paths --------------------------
     def _margin_threshold(self, est) -> float:
@@ -862,8 +876,6 @@ class Validator:
                     "lanes_total": L, "lanes_retired": L,
                     "gram_solve_iters": int(giters)}
             return B, b0, info, None
-        # the IRLS rounds; _residual_curvature refuses a loss it does not
-        # know
         rc, state, on_round = round_hooks()
         # across-time warm seed (retrain refit): the previous champion's
         # raw coefficients, threaded selector -> validator
@@ -874,6 +886,16 @@ class Validator:
         if isinstance(seed, dict) and seed.get("beta") is not None:
             seed_t = (np.asarray(seed["beta"], np.float32),
                       float(seed.get("intercept", 0.0)))
+        if self._wide_rounds(loss, int(Xd.shape[1])):
+            # thousands of columns: the bound-optimisation rounds, which
+            # never form a [d, d] array a lane
+            fk = {k: v for k, v in fit_kwargs.items() if k != "loss"}
+            B, b0, info = GS.sweep_glm_wide_streamed_rounds(
+                Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
+                state=state, on_round=on_round, warm_seed=seed_t, **fk)
+            return jnp.asarray(B), jnp.asarray(b0), info, rc
+        # the IRLS rounds; _residual_curvature refuses a loss it does not
+        # know
         B, b0, info = GS.sweep_glm_streamed_rounds(
             Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
             mesh=self.mesh, state=state, on_round=on_round,
@@ -916,10 +938,16 @@ class Validator:
                 if base.has_param("standardization") else True)
             if multiclass:
                 fit_kwargs["n_classes"] = int(n_classes)
+            fit_attrs = dict(folds=int(masks.shape[0]), grids=len(pending),
+                             classes=int(n_classes))
+            if not multiclass and self._wide_rounds(fit_kwargs["loss"],
+                                                    int(X.shape[1])):
+                from ...ops.glm_sweep import wide_padded_cols
+                d = int(X.shape[1])
+                fit_attrs.update(cols=d, padded_cols=wide_padded_cols(d))
             with collector.trace_span(
                     f"glm_streamed:{type(est).__name__}", kind="sweep_fit",
-                    folds=int(masks.shape[0]), grids=len(pending),
-                    classes=int(n_classes)) as sp:
+                    **fit_attrs) as sp:
                 B, b0, sweep_info, round_ckpt = self._streamed_fit(
                     est, fit_kwargs, Xd, yd, wd, md,
                     jnp.asarray(regs[pending]), jnp.asarray(alphas[pending]),

@@ -1181,7 +1181,12 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
 def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
                       ) -> jax.Array:
     """[n, Gc] margins for one fold's grid chunk: one MXU contraction
-    (bf16 X stays bf16; f32 accumulation)."""
+    (bf16 X stays bf16; f32 accumulation). Past TRI_MAX_D columns the
+    coefficients go in as two bf16 parts (`_wide_contract`): a margin is then a
+    sum over thousands of products, and coefficients rounded to bf16 would
+    move it by more than the fit resolves."""
+    if X.shape[1] > TRI_MAX_D:
+        return _wide_contract(B_f, X.T).T + b0_f[None, :]
     return jnp.matmul(X, B_f.T.astype(X.dtype),
                       preferred_element_type=jnp.float32) + b0_f[None, :]
 
@@ -1502,3 +1507,349 @@ def sweep_logits_fold_t(xT: jax.Array, B_f: jax.Array, b0_f: jax.Array
     hi, lo = _split_low(B_f.transpose(0, 2, 1).reshape(Gc * K, d),
                         xT.dtype)
     return mlr_logits_t(xT, hi, lo).reshape(Gc, K, -1) + b0_f[:, :, None]
+
+
+# -- streamed wide route (binary logistic, d > TRI_MAX_D, one device) ---------
+#
+# Past TRI_MAX_D columns the IRLS rounds above cannot pay for themselves: a
+# [d, d] Hessian a lane an iteration is n d^2 work and L d^2 memory. The wide
+# rounds never form one. Boehning's bound p (1 - p) <= 1/4 and the fact that a
+# fold's training rows are a subset of all rows give every lane of the sweep
+# ONE constant curvature matrix,
+#
+#     H_l(B, b0) <= kappa_l [[Gs, 0], [0, W]],   kappa_l = 1 / (4 W_f),
+#
+# with Gs = sum_i w_i xs_i xs_i' the ALL-rows Gram of the standardised
+# columns (built once a sweep by `wide_gram`), W = sum_i w_i and W_f the
+# lane's fold's training weight. The off-diagonal block vanishes because the
+# columns are centred on the all-rows mean. One outer iteration of a lane is
+#
+#   1. ONE pass over X for every lane at once: eta = X (B / std) + b0', the
+#      residual r = m_f w (sigmoid(eta) - y), its moments X' r and sum r;
+#      from them the exact gradient g of the lane's data term in the
+#      standardised coordinates. X is read as it is: centre and scale are
+#      applied to the coefficients going in and to the moments coming out.
+#   2. `_WIDE_INNER_STEPS` FISTA steps, from z = v = B and theta = 1, on the
+#      bound's model  g'(z - B) + kappa_l/2 (z - B)' Gs (z - B) + l2/2 |z|^2
+#      + l1 |z|_1  with step t_l = 1 / (kappa_l lam + l2), lam from
+#      `wide_gram`'s power iteration: v-gradient, u = v - t grad, z+ = soft(u,
+#      t l1), theta+ = (1 + sqrt(1 + 4 theta^2)) / 2, v+ = z+ + (theta - 1) /
+#      theta+ (z+ - z). Each is one [lanes, d] x [d, d] product; no pass over
+#      X, no factorisation. The prox is exact, so the fixed point is the
+#      elastic-net optimum itself.
+#   3. b0+ = b0 - 4 sum r / W (the bound's intercept step).
+#
+# from B = 0, b0 = 0 until the lane's delta = max |B+ - B| + |b0+ - b0| is
+# <= tol or max_iter iterations are done. Upstream runs L-BFGS / OWL-QN; this
+# is a departure (benchmark/reference_wide.py repeats it step for step).
+
+#: FISTA steps on the bound's model per pass over X: each costs a [lanes, d]
+#: x [d, d] float32 product (71 MB read at d = 4 224, ~0.1 ms on a v5e)
+#: where the pass costs ~10 ms, so the model is solved nearly as far as it
+#: is worth
+_WIDE_INNER_STEPS = 16
+
+#: power-iteration steps for the bound's largest eigenvalue, and the margin
+#: laid over the Rayleigh quotient they end on (it approaches from below)
+_WIDE_POWER_ITERS = 32
+_WIDE_LAM_MARGIN = 1.05
+
+
+def _wide_row_block(d: int, n: int) -> int:
+    """Rows a block of the wide passes: at most 2^26 elements of X."""
+    c = _ROW_BLOCK
+    while c > 1_024 and c * d > (1 << 26):
+        c //= 2
+    return min(c, n)
+
+
+def streamed_wide_route_ok(d: int, lanes: int, budget_bytes: float) -> bool:
+    """Can the wide rounds take a (d features, lanes) sweep within
+    `budget_bytes` beside the matrix? Three [d, d] float32 matrices a SWEEP
+    (the raw Gram, the standardised one, a transient) and the per-block
+    [rows, 2 x bucket] float32 margins and residuals; nothing grows with
+    lanes x d x d."""
+    if d <= TRI_MAX_D:
+        return False
+    return wide_footprint_bytes(d, lanes) <= budget_bytes
+
+
+def wide_padded_cols(d: int) -> int:
+    """Columns the chip's (8, 128)-tiled layout holds a [.., d] row in."""
+    return -(-d // 128) * 128
+
+
+def wide_footprint_bytes(d: int, lanes: int) -> float:
+    """Planned device bytes of the wide rounds beside X (see
+    streamed_wide_route_ok)."""
+    Lb = bucket_lanes(lanes)
+    dp = wide_padded_cols(d)
+    return 3.0 * dp * dp * 4.0 + 6.0 * _wide_row_block(d, 1 << 30) \
+        * 2 * Lb * 4.0 + 8.0 * Lb * dp * 4.0
+
+
+def _two_parts(B, dtype):
+    """_split_low for the wide route, with the high part cut by an explicit
+    `reduce_precision`: on the v5e the metric program's fused f32 -> bf16 ->
+    f32 round trip of a PARAMETER came back unrounded (excess precision is
+    allowed inside a fusion), the low part was zero, and the sweep scored
+    with coefficients rounded to bf16 (PERF.md, PR 29). lo is None for an
+    f32 matrix."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return B.astype(dtype), None
+    hi = jax.lax.reduce_precision(B, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(dtype), (B - hi).astype(dtype)
+
+
+def _wide_contract(A, xT, over_rows: bool = False):
+    """float32 A against a block xT [d, c] of X.T (the matrix's dtype),
+    accumulated in float32: A [k, d] -> the [k, c] margins A xT, or, with
+    `over_rows`, A [k, c] -> the [k, d] moments A xT'. A bf16 matrix keeps
+    the MXU's bf16 path: A goes in as its two bf16 parts stacked
+    (`_two_parts`; one contraction of twice the height, which a 128-wide
+    MXU does for nothing while 2 k <= 128) and the halves are added."""
+    dims = (((1,), (1 if over_rows else 0,)), ((), ()))
+    hi, lo = _two_parts(A, xT.dtype)
+    if lo is None:
+        return jax.lax.dot_general(hi, xT, dims,
+                                   precision=jax.lax.Precision.HIGHEST)
+    k = A.shape[0]
+    out = jax.lax.dot_general(jnp.concatenate([hi, lo], axis=0), xT, dims,
+                              preferred_element_type=jnp.float32)
+    return out[:k] + out[k:]
+
+
+@jax.jit
+def wide_gram(X: jax.Array, w: jax.Array, mean: jax.Array,
+              inv_std: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Once per sweep: (Gs [d, d] float32, lam). ONE pass accumulates the raw
+    weighted Gram X' diag(w) X in float32 (a bf16 matrix of counts is exact
+    in it; non-unit weights are rounded into the matrix's dtype, which
+    loosens only the bound); centre and scale are then applied in moment
+    space, Gs = D^-1 (G - W mean mean') D^-1. lam is _WIDE_LAM_MARGIN x the
+    Rayleigh quotient after _WIDE_POWER_ITERS power-iteration steps from the
+    constant vector: the bound's largest eigenvalue, which sets the inner
+    step."""
+    n, d = X.shape
+    c = _wide_row_block(d, n)
+    nb, take = _mlr_blocks(n, c, X.T, w)        # see _wide_round_core
+    hp = jax.lax.Precision.HIGHEST
+
+    def body(i, G):
+        xT, fresh, wb = take(i)                                 # [d, c]
+        xw = (xT.astype(jnp.float32) * (wb * fresh)[None, :]).astype(X.dtype)
+        return G + jax.lax.dot_general(
+            xw, xT, (((1,), (1,)), ((), ())), precision=hp,
+            preferred_element_type=jnp.float32)
+
+    G = jax.lax.fori_loop(0, nb, body, jnp.zeros((d, d), jnp.float32))
+    Gs = (G - w.sum() * mean[:, None] * mean[None, :]) \
+        * inv_std[:, None] * inv_std[None, :]
+    Gs = 0.5 * (Gs + Gs.T)
+
+    def power(_, v):
+        u = jnp.matmul(Gs, v, precision=hp)
+        return u / jnp.maximum(jnp.linalg.norm(u), EPS)
+
+    v = jax.lax.fori_loop(0, _WIDE_POWER_ITERS, power,
+                          jnp.full((d,), d ** -0.5, jnp.float32))
+    lam = _WIDE_LAM_MARGIN * jnp.vdot(v, jnp.matmul(Gs, v, precision=hp))
+    return Gs, lam
+
+
+def _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
+                     inv_std, Gs, lam, iters_budget, tol, *, fit_intercept):
+    """Up to `iters_budget` outer iterations of the wide solver (the
+    section comment above) for one compacted lane bucket: sel [F, Lb] maps
+    bucket lanes to folds (all-zero columns are inert padding), B0 [Lb, d] /
+    b00 [Lb] carry standardised-space state between rounds. The while cond
+    leaves as soon as every lane's delta clears tol. Returns (B, b0,
+    delta [Lb], iters)."""
+    n, d = X.shape
+    Lb = sel.shape[1]
+    f32 = jnp.float32
+    hp = jax.lax.Precision.HIGHEST
+    wsum = jnp.maximum(w.sum(), EPS)
+    wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
+    wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
+    kappa = 0.25 / wsum_l
+    step = 1.0 / (kappa * lam + l2)
+    c = _wide_row_block(d, n)
+    # blocks are slices of X.T along its minor axis, rows on the lanes
+    # (_mlr_blocks): a matrix whose width is no multiple of 128 lives
+    # rows-minor on the chip (the layout that pads nothing), so X.T is the
+    # layout it already has; a [c, d] block made XLA copy all of X into the
+    # other layout first
+    nb, take = _mlr_blocks(n, c, X.T, y, w, fold_masks)
+
+    def accumulate(B, b0):
+        Braw = B * inv_std[None, :]                     # [Lb, d] raw units
+        b0_raw = b0 - (Braw * mean[None, :]).sum(1)
+
+        def body(i, acc):
+            gA, g0A = acc
+            xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
+            eta = _wide_contract(Braw, xT) + b0_raw[:, None]    # [Lb, c]
+            # lane weights: exact for any w (sel is 0/1)
+            wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
+                            precision=hp)
+            R = (jax.nn.sigmoid(eta) - y_blk[None, :]) * wl
+            return (gA + _wide_contract(R, xT, over_rows=True),
+                    g0A + R.sum(1))
+
+        gA, g0A = jax.lax.fori_loop(
+            0, nb, body, (jnp.zeros((Lb, d), f32), jnp.zeros(Lb, f32)))
+        # r' X -> the standardised columns' moments: centre, then scale
+        g = (gA - mean[None, :] * g0A[:, None]) * inv_std[None, :]
+        return g / wsum_l[:, None], g0A
+
+    def cond(state):
+        i, _, _, delta = state
+        return (i < iters_budget) & (delta.max() > tol)
+
+    def body(state):
+        i, B, b0, _ = state
+        g, g0A = accumulate(B, b0)
+
+        def inner(_, s):
+            Z, V, th = s
+            grad = g + kappa[:, None] * jnp.matmul(V - B, Gs, precision=hp) \
+                + l2[:, None] * V
+            U = V - step[:, None] * grad
+            Zn = jnp.sign(U) * jnp.maximum(
+                jnp.abs(U) - (step * l1)[:, None], 0.0)
+            thn = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * th * th))
+            return Zn, Zn + ((th - 1.0) / thn) * (Zn - Z), thn
+
+        B_new, _, _ = jax.lax.fori_loop(
+            0, _WIDE_INNER_STEPS, inner, (B, B, jnp.asarray(1.0, f32)))
+        b0_new = b0 - 4.0 * g0A / wsum if fit_intercept else b0
+        delta = jnp.abs(B_new - B).max(axis=1) + jnp.abs(b0_new - b0)
+        return i + 1, B_new, b0_new, delta
+
+    state = (jnp.asarray(0, jnp.int32), B0.astype(f32), b00.astype(f32),
+             jnp.full((Lb,), jnp.inf, f32))
+    i, B, b0, delta = jax.lax.while_loop(cond, body, state)
+    return B, b0, delta, i
+
+
+@functools.partial(jax.jit, static_argnames=("fit_intercept",))
+def sweep_glm_wide_round(X: jax.Array, y: jax.Array, w: jax.Array,
+                         fold_masks: jax.Array, sel: jax.Array,
+                         l1: jax.Array, l2: jax.Array, B0: jax.Array,
+                         b00: jax.Array, mean: jax.Array, inv_std: jax.Array,
+                         Gs: jax.Array, lam: jax.Array, iters_budget, tol, *,
+                         fit_intercept: bool = True):
+    """One retirement round of the wide sweep (see _wide_round_core).
+    Compiled per (n, d, F, bucket) shape; iters_budget/tol are traced."""
+    return _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
+                            inv_std, Gs, lam, iters_budget, tol,
+                            fit_intercept=fit_intercept)
+
+
+def sweep_glm_wide_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
+                                   max_iter: int = 50, tol: float = 1e-6,
+                                   fit_intercept: bool = True,
+                                   standardize: bool = True,
+                                   round_iters: int = ROUND_ITERS_DEFAULT,
+                                   warm_seed: Optional[Tuple] = None,
+                                   state: Optional[Dict[str, Any]] = None,
+                                   on_round: Optional[Callable] = None
+                                   ) -> Tuple[np.ndarray, np.ndarray,
+                                              Dict[str, Any]]:
+    """Host-driven streamed sweep of BINARY logistic regression over a
+    matrix wider than TRI_MAX_D columns, resident on one device: the
+    retirement loop of sweep_glm_streamed_rounds (_run_rounds: rounds of
+    `round_iters` iterations, lanes retire at their own delta <= tol or at
+    max_iter, survivors compact into `bucket_lanes` buckets, `state` /
+    `on_round` checkpoint every boundary and resume bit-identically) around
+    `sweep_glm_wide_round`, after the column moments and ONE `wide_gram`
+    pass. Lanes start at zero — or, with `warm_seed` ((beta_raw [d],
+    b0_raw), the retrain refit's across-time continuation), at that model.
+    The columns are centred whenever an intercept is fitted (with
+    standardize=False that is a reparametrisation: the optimum is the
+    same) and scaled when `standardize`.
+
+    Returns (B [F, G, d] f32 RAW units, b0 [F, G], info)."""
+    from ..utils.metrics import collector as _collector
+
+    regs = np.asarray(regs, np.float32)
+    alphas = np.asarray(alphas, np.float32)
+    F, d = int(fold_masks.shape[0]), int(X.shape[1])
+    Gn = int(regs.shape[0])
+    L = F * Gn
+    max_iter, tol_f = int(max_iter), float(tol)
+    stats_passes = 0
+    mean, inv_std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    if standardize or fit_intercept:
+        mean, std = glm_standardize_stats(X, w)
+        stats_passes = 2
+        if standardize:
+            inv_std = 1.0 / std
+    lane_fold = np.repeat(np.arange(F, dtype=np.int32), Gn)
+    l1v = np.tile(regs * alphas, F).astype(np.float32)
+    l2v = np.tile(regs * (1.0 - alphas), F).astype(np.float32)
+    with _collector.trace_span("gram_factor", kind="host_step", folds=F,
+                               lanes=L, cols=d, factorizations=0):
+        Gs, lam = wide_gram(X, w, mean, inv_std)
+    st = state if state is not None else _new_round_state(L, d)
+
+    warm_seeded = False
+    if (warm_seed is not None and not st["retired"].any()
+            and int(st["iters"].max()) == 0):
+        seed_b = np.asarray(warm_seed[0], np.float32).reshape(-1)
+        if seed_b.shape[0] == d:
+            # RAW-unit seed -> this sweep's standardised space (the final
+            # unstandardize below inverts exactly this map)
+            st["B"][:] = (seed_b / np.asarray(inv_std))[None, :]
+            st["b0"][:] = float(warm_seed[1]) \
+                + float((seed_b * np.asarray(mean)).sum())
+            warm_seeded = True
+
+    def run_round(idx, budget):
+        k = len(idx)
+        Lb = bucket_lanes(k)
+        with _collector.trace_span(
+                f"glm_wide_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
+                active=int(k), iters_budget=int(budget)):
+            with _collector.trace_span("round_prep", kind="host_step"):
+                sel = np.zeros((F, Lb), np.float32)
+                sel[lane_fold[idx], np.arange(k)] = 1.0
+                l1b = np.zeros(Lb, np.float32)
+                l1b[:k] = l1v[idx]
+                # inert pads: no fold (zero weights), l2 = 1; B = 0 is
+                # their fixed point
+                l2b = np.ones(Lb, np.float32)
+                l2b[:k] = l2v[idx]
+                B0 = np.zeros((Lb, d), np.float32)
+                B0[:k] = st["B"][idx]
+                b00 = np.zeros(Lb, np.float32)
+                b00[:k] = st["b0"][idx]
+                args = (X, y, w, fold_masks, jnp.asarray(sel),
+                        jnp.asarray(l1b), jnp.asarray(l2b), jnp.asarray(B0),
+                        jnp.asarray(b00), mean, inv_std, Gs, lam,
+                        jnp.asarray(budget, jnp.int32),
+                        jnp.asarray(tol_f, jnp.float32))
+            out = sweep_glm_wide_round(*args,
+                                       fit_intercept=bool(fit_intercept))
+            with _collector.trace_span("round_fetch", kind="host_step"):
+                # the host waits here for the round's program; ONE
+                # transfer for its four results
+                Bb, b0b, db, it = jax.device_get(out)
+        _record_round(st, idx, Lb, Bb, b0b, db, int(it))
+
+    _run_rounds(st, run_round, int(round_iters), max_iter, tol_f, on_round)
+
+    mean_h, inv_std_h = jax.device_get((mean, inv_std))
+    B = st["B"] * inv_std_h[None, :]
+    b0 = st["b0"] - (B * mean_h[None, :]).sum(1, dtype=np.float32)
+    info = {"route": "streamed", "kernel": "wide_rounds",
+            "driver": "resident", **_rounds_info(st, tol_f, max_iter),
+            "warm_seeded": warm_seeded, "cols": d,
+            "padded_cols": wide_padded_cols(d),
+            "gram_passes": 1, "factorizations": 0,
+            "inner_steps": _WIDE_INNER_STEPS}
+    # every whole read of X by the route's programs: the outer iterations
+    # (data_passes), the Gram pass, the two passes of the moments
+    info["x_passes"] = info["data_passes"] + 1 + stats_passes
+    return B.reshape(F, Gn, d), b0.reshape(F, Gn), info
