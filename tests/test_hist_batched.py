@@ -15,6 +15,11 @@ correctness contract is that batching must not change a result:
      plain histogram of the surviving left children, bit-for-bit;
   4. the pure-jnp CPU fallback matches interpret-mode pallas up to f32
      summation order;
+  4b. the routing and lookup kernels are sized by the level's own node
+     count (PH.node_rows) and stay EXACT at every size: routed ids
+     bitwise equal to the gather twin from 1 node to 2 048 with feature
+     ids past 300 and thresholds up to 256, looked-up values bitwise
+     equal to the gather for full-mantissa f32 tables 2 to 4 096 wide;
   5. the planner (plan_lane_chunk) honors every budget and the CPU
      fallback smoke runs on a tiny matrix — the tier-1 liveness check
      ci.sh exercises on every run (no TPU required).
@@ -217,6 +222,120 @@ def test_route_hist_cpu_fallback_decisions_match():
                                   n_slots=n_nodes, n_bins=b,
                                   derive_count=True)
     assert np.allclose(np.asarray(hist_c), np.asarray(hist_i), atol=1e-4)
+
+
+# -- routing and lookup at the level's own node count ----------------------
+
+NODE_COUNTS = [1, 2, 4, 8, 16, 32, 64, 256, 2048]
+
+
+def _route_inputs(n_nodes, lanes, n, seed, wide):
+    """A level's tables and rows. wide: int32 bins up to 256 over 310
+    features, so feature ids pass 300 and thresholds 2^8 (one default
+    bf16 pass would round both); else int8 bins over 40 features. Bin 0
+    (missing) is present and both directions are drawn; every 7th row
+    carries a node id past the table."""
+    rng = np.random.default_rng(seed)
+    f, top, dt = (310, 256, jnp.int32) if wide else (40, 31, jnp.int8)
+    Xb_t = jnp.asarray(rng.integers(0, top + 1, size=(f, n)), dt)
+    node = rng.integers(0, n_nodes, size=(lanes, n))
+    stray = np.arange(n) % 7 == 3
+    node[:, stray] = n_nodes + rng.integers(0, 3, size=(lanes, stray.sum()))
+    f_lvl = rng.integers(0, f, size=(lanes, n_nodes))
+    t_lvl = rng.integers(0, top + 1, size=(lanes, n_nodes))
+    f_lvl[:, 0], t_lvl[:, 0] = f - 1, top       # the largest of each
+    if n_nodes > 1:
+        f_lvl[:, 1], t_lvl[:, 1] = f - 9, top - 1
+    m_lvl = rng.integers(0, 2, size=(lanes, n_nodes))
+    return (Xb_t, jnp.asarray(node, jnp.float32),
+            jnp.asarray(f_lvl, jnp.int32), jnp.asarray(t_lvl, jnp.int32),
+            jnp.asarray(m_lvl, jnp.int32), stray)
+
+
+def _assert_routed_like_twin(got, Xb_t, node, f_lvl, t_lvl, m_lvl, stray):
+    got = np.asarray(got)
+    keep = ~stray
+    want = PH._route_level_jnp(Xb_t[:, keep], node[:, keep], f_lvl, t_lvl,
+                               m_lvl)
+    np.testing.assert_array_equal(got[:, keep], np.asarray(want))
+    # a node id past the table owns no entry: the row goes left
+    np.testing.assert_array_equal(got[:, stray],
+                                  2.0 * np.asarray(node)[:, stray])
+
+
+@pytest.mark.parametrize("lanes", [1, 10])
+@pytest.mark.parametrize("n_nodes", NODE_COUNTS)
+def test_route_pallas_exact_at_every_node_count(n_nodes, lanes):
+    wide = n_nodes <= 256
+    n = PH._ROUTE_BLK + 37 if n_nodes <= 64 else 517    # ragged either way
+    Xb_t, node, f_lvl, t_lvl, m_lvl, stray = _route_inputs(
+        n_nodes, lanes, n, seed=n_nodes + lanes, wide=wide)
+    got = PH.route_pallas(Xb_t, node, f_lvl, t_lvl, m_lvl,
+                          n_nodes=n_nodes, interpret=True)
+    _assert_routed_like_twin(got, Xb_t, node, f_lvl, t_lvl, m_lvl, stray)
+
+
+@pytest.mark.parametrize("lanes", [1, 10])
+@pytest.mark.parametrize("n_nodes", NODE_COUNTS)
+def test_route_hist_exact_at_every_node_count(n_nodes, lanes):
+    """The fused pass routes like the twin and its histogram is bitwise
+    route_pallas THEN hist_pallas, at every level width. Wide bins (ids
+    past 300, thresholds to 256) ride the narrow levels; the 2 048-node
+    level keeps two lanes (its histogram block is lanes x 6 144 rows);
+    517 rows are two ragged grid steps at 40 x 32 one-hot columns."""
+    wide = n_nodes <= 2
+    lanes = min(lanes, 2) if n_nodes > 256 else lanes
+    b = 257 if wide else 32
+    Xb_t, node, f_lvl, t_lvl, m_lvl, stray = _route_inputs(
+        n_nodes, lanes, 517, seed=3 * n_nodes + lanes, wide=wide)
+    rng = np.random.default_rng(n_nodes)
+    pay = jnp.asarray(rng.integers(-8, 9, size=(2 * lanes, 517)),
+                      jnp.float32)
+    hist, new_node = PH.route_hist(Xb_t, pay, node, f_lvl, t_lvl, m_lvl,
+                                   n_nodes=n_nodes, n_bins=b,
+                                   interpret=True, allow_bf16=True,
+                                   derive_count=True)
+    _assert_routed_like_twin(new_node, Xb_t, node, f_lvl, t_lvl, m_lvl,
+                             stray)
+    want_node = PH.route_pallas(Xb_t, node, f_lvl, t_lvl, m_lvl,
+                                n_nodes=n_nodes, interpret=True)
+    np.testing.assert_array_equal(np.asarray(new_node),
+                                  np.asarray(want_node))
+    slots = node + float(n_nodes) * (want_node - 2.0 * node)
+    want_hist = PH.hist_pallas(Xb_t, pay, slots, n_slots=n_nodes, n_bins=b,
+                               interpret=True, allow_bf16=True,
+                               derive_count=True)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(want_hist))
+
+
+@pytest.mark.parametrize("lanes", [1, 10])
+@pytest.mark.parametrize("width", [2, 64, 100, 4096])
+def test_table_lookup_bitwise_at_every_width(width, lanes):
+    """Full 24-bit mantissas over many binades come back bit for bit
+    (one bf16 pass would round them to 8 bits); ids outside the table
+    read 0."""
+    rng = np.random.default_rng(width + lanes)
+    mant = rng.integers(1 << 23, 1 << 24, size=(lanes, width)) | 1
+    tbl = (mant * rng.choice([-1.0, 1.0], size=mant.shape)
+           * 2.0 ** rng.integers(-40, 17, size=mant.shape))
+    tbl = jnp.asarray(tbl, jnp.float32)
+    n = PH._ROUTE_BLK + 37 if width <= 100 else 517
+    idx = rng.integers(-1, width + 1, size=(lanes, n))
+    idx[:, ::11] = PH.node_rows(width, 2) + 5
+    idx = jnp.asarray(idx, jnp.float32)
+    got = PH.table_lookup_pallas(tbl, idx, interpret=True)
+    want = PH._table_lookup_jnp(tbl, idx)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.asarray(want)[:, ::11].max() == 0.0
+
+
+@pytest.mark.parametrize("n,f32_rows,bf16_rows", [
+    (1, 8, 16), (2, 8, 16), (8, 8, 16), (9, 16, 16), (32, 32, 32),
+    (64, 64, 64), (100, 104, 112), (128, 128, 128), (129, 256, 256),
+    (2048, 2048, 2048)])
+def test_node_rows_is_the_table_at_one_sublane_tile(n, f32_rows, bf16_rows):
+    assert PH.node_rows(n) == f32_rows
+    assert PH.node_rows(n, 2) == bf16_rows
 
 
 class TestPlanner:

@@ -191,6 +191,79 @@ class TestLevelSlotCounts:
         assert sp.attrs["slot_passes"] == \
             sum(_one_tree_of(route_hist_slots, depth))
 
+    @pytest.mark.parametrize("depth,node_rows", [(1, 24), (3, 40),
+                                                 (6, 144)])
+    def test_span_route_node_rows_is_what_the_kernels_laid_out(
+            self, depth, node_rows, monkeypatch):
+        """The span's counter and the kernels read ONE function: every
+        node axis a tree's routing passes and its leaf lookup were traced
+        with (interpret mode: the real kernels), summed."""
+        from transmogrifai_tpu.models.trees import _TreeEstimator
+        laid = {}
+        real = PH.node_rows
+
+        def spy(n, itemsize=4):
+            # keyed: a second trace of the round body lays out the same
+            laid[(n, itemsize)] = real(n, itemsize)
+            return laid[(n, itemsize)]
+
+        Xb, y, W = _data(n=320 + depth, folds=2, seed=depth)
+        # the kernels' wrappers are jits of their own: a level another
+        # test already traced at this shape would not size itself again
+        for fn in (PH.route_pallas, PH._route_hist_pallas_jit,
+                   PH.table_lookup_pallas):
+            fn.clear_cache()
+        c = collector
+        c.enable("tree_levels_node_rows")
+        try:
+            # the span reads the counter before the spy goes in
+            with monkeypatch.context() as m:
+                _TreeEstimator._timed_fused_fit(
+                    "tree_sweep_fold_fused", Xb, W.shape[0], depth, 1,
+                    lambda: (m.setattr(PH, "node_rows", spy),
+                             T.fit_gbt_folds(
+                                 Xb, y, W, jax.random.PRNGKey(0),
+                                 n_rounds=1, depth=depth, n_bins=7,
+                                 interpret=True))[1])
+            c.finish()
+        finally:
+            c.disable()
+        sp, = [s for s in c.trace.spans if s.kind == "tree_fused"]
+        assert sp.attrs["route_node_rows"] == node_rows \
+            == PH.route_node_rows(depth)
+        # levels 0..depth-1 route at 1 << d nodes (f32 rows), the lookup
+        # reads 1 << depth leaves (bf16 rows)
+        assert sorted(laid) == sorted(
+            [(1 << d, 4) for d in range(depth)] + [(1 << depth, 2)])
+        assert sum(laid.values()) == node_rows
+
+    def test_plan_route_resident_follows_the_node_axis(self, monkeypatch):
+        """plan_fused_hist budgets the routing half at the level's node
+        rows, one lane group, and the flagship shape still fits the
+        v5e's VMEM."""
+        from transmogrifai_tpu.utils import platform as P
+        monkeypatch.setattr(P, "device_spec",
+                            lambda kind=None: P.DEVICE_SPECS["TPU v5 lite"])
+        for (f, b, lanes, depth) in [(64, 33, 10, 6), (64, 33, 10, 3),
+                                     (64, 33, 1, 12), (300, 257, 5, 6)]:
+            plan = PH.plan_fused_hist(f, b, lanes, depth)
+            cols = f * b
+            onehot = cols * plan.blk * (4 + 2)
+            minor = (f + lanes * 3 + lanes) * plan.blk * 8
+            route_b = plan.vmem_bytes - plan.out_bytes - onehot - minor
+            rows = PH.route_group_rows(plan.n_slots, lanes)
+            assert route_b == 2 * rows * plan.blk * 4
+            assert rows <= max(PH._ROUTE_GROUP_ROWS,
+                               PH.node_rows(plan.n_slots))
+            assert rows % PH.node_rows(plan.n_slots) == 0
+        # depth 6: 16 nodes x 10 lanes = 160 rows where one lane's
+        # one-hot alone was 128
+        assert PH.route_group_rows(16, 10) == 160
+        assert PH.route_group_rows(1, 10) == 80
+        assert PH.route_group_rows(2048, 10) == 2048
+        assert PH.fused_hist_fits(64, 33, 10, 6)
+        assert PH.plan_fused_hist(64, 33, 10, 6).blk == 2048
+
     def test_sharded_fit_traces_the_same_level_widths(
             self, monkeypatch, route_hist_slots):
         depth = 5

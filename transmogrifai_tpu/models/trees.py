@@ -416,19 +416,23 @@ class _TreeEstimator(PredictorEstimator):
         RecompileTracker books the fit's compiles to it
         (docs/observability.md). Its `slot_passes` is the sum of the
         slot counts the level loop hands route_hist for one tree
-        (ops/trees.fused_level_slots): 31 at depth 6. The span is there
+        (ops/trees.fused_level_slots): 31 at depth 6; `route_node_rows`
+        the node rows a lane the routing and lookup kernels lay out for
+        one tree (pallas_hist.route_node_rows: 144 at depth 6, 896 when
+        every table was padded to 128). The span is there
         with collection off too (its profiler annotation costs nothing
         then); the fence and the kernel record are not: they change
         what a timed sweep measures."""
+        from ..ops import pallas_hist
         from ..utils.metrics import collector
         cm = collector.trace_span(
             span, kind="tree_fused", lanes=int(lanes), depth=int(depth),
-            slot_passes=sum(T.fused_level_slots(int(depth))))
+            slot_passes=sum(T.fused_level_slots(int(depth))),
+            route_node_rows=pallas_hist.route_node_rows(int(depth)))
         if not collector.enabled:
             with cm:
                 return call()
         import time
-        from ..ops import pallas_hist
         sig = (jax.default_backend(), label,
                Xb.shape, str(Xb.dtype), lanes, depth, n_rounds)
         cold = sig not in _TreeEstimator._WARM_FUSED_SHAPES

@@ -120,8 +120,8 @@ def plan_fused_hist(n_feat: int, n_bins: int, lanes: int, depth: int,
     Residents:
     output block + the [F*B, blk] f32 one-hot tile (+ a bf16 copy when
     the bf16 input mode is on) + the f32 Xb/payload/slot tiles + the
-    route-fused node one-hot tile (the route+hist kernel keeps a
-    [n_pad, blk] node one-hot alive next to the histogram operands).
+    routing half's (lane, node) rows: the selected bins of one lane
+    group and their decisions (_route_right), f32 [rows, blk] each.
     """
     cols = n_feat * n_bins
     n_slots = 1 << max(depth - 2, 0)
@@ -131,10 +131,10 @@ def plan_fused_hist(n_feat: int, n_bins: int, lanes: int, depth: int,
     if _HIST_BF16:
         onehot_b += cols * blk * 2
     minor_b = (n_feat + lanes * channels + lanes) * blk * 8
-    # route-fused node one-hot: worst routed level has 2^(depth-2) nodes,
-    # minor-padded to 128 lanes (the final level routes through the
-    # standalone route kernel, whose residents are strictly smaller)
-    route_b = max(-(-n_slots // 128) * 128, 128) * blk * 4
+    # worst routed level has 2^(depth-2) nodes, laid out at node_rows
+    # a lane, one lane group at a time (the final level routes through
+    # the standalone route kernel: twice the nodes, no histogram operands)
+    route_b = 2 * route_group_rows(n_slots, lanes) * blk * 4
     vmem = out_b + onehot_b + minor_b + route_b
     return HistPlan(lanes=lanes, n_slots=n_slots, blk=blk, out_bytes=out_b,
                     vmem_bytes=vmem, fits=vmem <= _vmem_limit())
@@ -545,47 +545,127 @@ def hist_folds(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array, *,
 # Training-time routing (rel' = 2*rel + go_right) is one read of the binned
 # matrix per level, but the XLA gather-free form (trees._onehot_route_step)
 # materializes [chunk, F] f32 selection products in HBM — 48ms/level at the
-# 10M-row config vs ~1ms of Xb traffic. Here the one-hots and products live
-# only in VMEM, and (like the histograms) a fold axis shares the Xb read
-# across every CV fold's tree.
+# 10M-row config vs ~1ms of Xb traffic. Here everything lives in VMEM, and
+# (like the histograms) a fold axis shares the Xb read across every CV
+# fold's tree. The work is sized by the level's OWN node count, in node
+# space: a level's tables name one feature a (lane, node), so ONE selection
+# contraction [lanes * nodes, F] x [F, blk] picks every node's bin of every
+# row for all lanes at once (0/1 against bin ids: exact in bf16), the
+# decision is a compare a (lane, node) row against that node's threshold
+# column, and a row takes the decision of its own node by an [nodes, blk]
+# compare. No per-row table lookup is left: no node one-hot goes to the
+# MXU, nothing is padded to 128 nodes, no [F, blk] mask a lane (PERF.md §6,
+# PR 30: what a fused level adds to the histogram alone fell from 27 ms to
+# 3 ms a 10M-row pass at 10 lanes on the v5e).
 
 _ROUTE_BLK = 4096
 
-
-def _pad_minor(a: jax.Array, mult: int = 128) -> jax.Array:
-    """Pad the minor axis up to a Mosaic-friendly multiple; padded slots
-    are inert wherever a one-hot over REAL ids selects columns."""
-    pad = (-a.shape[-1]) % mult
-    if pad:
-        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-    return a
+# (lane, node) rows one selection contraction holds: lanes are taken in
+# groups of this many rows, so the routing residents do not grow with the
+# lane count (a 2 048-node level is one lane a group)
+_ROUTE_GROUP_ROWS = 512
 
 
-def _route_kernel(xb_ref, node_ref, tbl_ref, out_ref, *, F, n_pad,
+def node_rows(n: int, itemsize: int = 4) -> int:
+    """Rows the routing and lookup kernels lay out for a table of `n`
+    entries: `n` rounded up to one sublane tile of the array that carries
+    the node axis (8 rows of f32, 16 of bf16) — 8 at the root's children,
+    32 at depth 6's last level, 64 leaves — and to a multiple of 128 past
+    128, where the axis is also a contraction's minor one. The ONE place
+    the node axis is sized: the kernels, plan_fused_hist and the
+    `route_node_rows` counter of the tree_fused span all read it."""
+    tile = 32 // itemsize if n <= 128 else 128
+    return -(-n // tile) * tile
+
+
+def _group_lanes(n_r: int) -> int:
+    """Whole lanes of `n_r` node rows in one selection contraction."""
+    return max(_ROUTE_GROUP_ROWS // n_r, 1)
+
+
+def route_group_rows(n_nodes: int, lanes: int) -> int:
+    """(lane, node) rows of one selection contraction of the routing
+    kernels at this level: whole lanes, up to _ROUTE_GROUP_ROWS."""
+    n_r = node_rows(n_nodes)
+    return min(_group_lanes(n_r), lanes) * n_r
+
+
+def route_node_rows(depth: int) -> int:
+    """Node rows the kernels lay out a lane for ONE depth-`depth` tree of
+    the fused fit: its route_hist passes (levels 0..depth-2), the last
+    level's route and the leaf lookup — 144 at depth 6, where padding
+    every table to 128 was 896."""
+    return sum(node_rows(1 << d) for d in range(depth)) \
+        + node_rows(1 << depth, 2)
+
+
+def _route_tables(f_lvl, t_lvl, m_lvl, *, n_nodes, n_feat):
+    """A level's split tables in node space, (lane, node) rows lane-major
+    with each lane's nodes padded to node_rows: `sel` [Fo * n_r, F] bf16,
+    the one-hot of the row's split feature (padded rows select nothing),
+    and `tm` [Fo * n_r, 2] f32, its threshold and missing direction."""
+    Fo = f_lvl.shape[0]
+    n_r = node_rows(n_nodes)
+    pad = ((0, 0), (0, n_r - n_nodes))
+    f = jnp.pad(f_lvl.astype(jnp.int32), pad, constant_values=-1)
+    sel = (f[:, :, None] == jnp.arange(n_feat, dtype=jnp.int32)) \
+        .astype(jnp.bfloat16).reshape(Fo * n_r, n_feat)
+    tm = jnp.stack([jnp.pad(t_lvl.astype(jnp.float32), pad).reshape(-1),
+                    jnp.pad(m_lvl.astype(jnp.float32), pad).reshape(-1)],
+                   axis=1)
+    return sel, tm, n_r
+
+
+def _route_right(xf, node_ref, sel_ref, tm_ref, *, one_byte, n_r, n_folds):
+    """Every lane's routing decision [1, blk] (f32 0/1) for one row block.
+
+    xf [F, blk] f32 bins. Bin ids reach the MXU as bf16, exact up to 256:
+    a one-byte matrix goes whole, a wider one as x = 256 * hi + lo (exact
+    below 2^16). Each output of the selection contraction is one selected
+    term, so `xs` holds the bin itself. A node id outside [0, n_nodes)
+    owns no row of `dec` and goes left."""
+    blk = xf.shape[1]
+    if one_byte:
+        lo, hi = xf.astype(jnp.bfloat16), None
+    else:
+        hi = jnp.floor(xf * (1.0 / 256.0))
+        lo = (xf - 256.0 * hi).astype(jnp.bfloat16)
+        hi = hi.astype(jnp.bfloat16)
+
+    def select(sel, xp):                                    # [R, blk] f32
+        return jax.lax.dot_general(sel, xp, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    ni = jax.lax.broadcasted_iota(jnp.int32, (n_r, blk), 0) \
+        .astype(jnp.float32)
+    group = _group_lanes(n_r)
+    rights = []
+    for k0 in range(0, n_folds, group):
+        k1 = min(k0 + group, n_folds)
+        rows = slice(k0 * n_r, k1 * n_r)
+        sel = sel_ref[rows, :]                              # [R, F] bf16
+        xs = select(sel, lo)
+        if hi is not None:
+            xs = 256.0 * select(sel, hi) + xs
+        dec = jnp.logical_or(
+            xs > tm_ref[rows, 0:1],
+            jnp.logical_and(xs == 0.0, tm_ref[rows, 1:2] > 0.5))
+        for k in range(k0, k1):
+            own = jnp.logical_and(
+                ni == node_ref[k:k + 1, :],
+                dec[(k - k0) * n_r:(k - k0 + 1) * n_r, :])  # [n_r, blk]
+            rights.append(jnp.max(own.astype(jnp.float32), axis=0,
+                                  keepdims=True))
+    return rights
+
+
+def _route_kernel(xb_ref, node_ref, sel_ref, tm_ref, out_ref, *, n_r,
                   n_folds):
-    blk = xb_ref.shape[1]
     xf = xb_ref[:].astype(jnp.float32)                      # [F, blk]
-    fi = jax.lax.broadcasted_iota(jnp.int32, (F, blk), 0) \
-        .astype(jnp.float32)
-    ni = jax.lax.broadcasted_iota(jnp.int32, (n_pad, blk), 0) \
-        .astype(jnp.float32)
-    rows = []
-    for k in range(n_folds):
-        node = node_ref[k:k + 1, :]                         # [1, blk]
-        noh = (ni == node).astype(jnp.float32)              # [n_pad, blk]
-        tbl = tbl_ref[3 * k:3 * k + 3, :]                   # [3, n_pad]
-        # HIGHEST: one default bf16 pass is exact only for table values
-        # below 2^8 — feature ids and 256-bin thresholds go past that
-        ftm = jax.lax.dot_general(                          # [3, blk]
-            tbl, noh, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        mask = (fi == ftm[0:1, :]).astype(jnp.float32)      # [F, blk]
-        xsel = jnp.sum(xf * mask, axis=0, keepdims=True)    # [1, blk]
-        right = jnp.logical_or(
-            xsel > ftm[1:2, :],
-            jnp.logical_and(xsel == 0.0, ftm[2:3, :] > 0.5))
-        rows.append(2.0 * node + right.astype(jnp.float32))
+    rights = _route_right(xf, node_ref, sel_ref, tm_ref,
+                          one_byte=xb_ref.dtype.itemsize == 1, n_r=n_r,
+                          n_folds=n_folds)
+    rows = [2.0 * node_ref[k:k + 1, :] + r for k, r in enumerate(rights)]
     out_ref[:] = rows[0] if n_folds == 1 else \
         jnp.concatenate(rows, axis=0)
 
@@ -601,8 +681,8 @@ def route_pallas(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
     level's ids [n_folds, N] f32 (2*node + right; right uses the learned
     missing direction for bin 0 — same decision as trees._onehot_route_step
     and the serving traversals). Out-of-range node ids (e.g. row padding)
-    select no table entry and route as node 0's split of feature 0 — the
-    caller slices padded rows away.
+    own no table entry and go left (2*node) — the caller slices padded
+    rows away.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -610,19 +690,16 @@ def route_pallas(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
     F, N = Xb_t.shape
     n_orig = N
     Fo = node_t.shape[0]
-    tbl = jnp.stack([f_lvl.astype(jnp.float32),
-                     t_lvl.astype(jnp.float32),
-                     m_lvl.astype(jnp.float32)], axis=1)    # [Fo, 3, n]
-    tbl = _pad_minor(tbl.reshape(3 * Fo, n_nodes))          # [3Fo, n_pad]
-    n_pad = tbl.shape[1]
+    sel, tm, n_r = _route_tables(f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
+                                 n_feat=F)
     blk = _ROUTE_BLK
     pad = (-N) % blk
     if pad:
         Xb_t = jnp.pad(Xb_t, ((0, 0), (0, pad)))
         node_t = jnp.pad(node_t, ((0, 0), (0, pad)),
-                         constant_values=float(n_pad))      # inert
+                         constant_values=float(n_r))        # inert
         N += pad
-    kernel = functools.partial(_route_kernel, F=F, n_pad=n_pad, n_folds=Fo)
+    kernel = functools.partial(_route_kernel, n_r=n_r, n_folds=Fo)
     out = pl.pallas_call(
         kernel,
         grid=(N // blk,),
@@ -631,7 +708,9 @@ def route_pallas(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((Fo, blk), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((3 * Fo, n_pad), lambda i: (0, 0),
+            pl.BlockSpec(sel.shape, lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(tm.shape, lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((Fo, blk), lambda i: (0, i),
@@ -639,7 +718,7 @@ def route_pallas(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Fo, N), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(Xb_t, node_t, tbl)
+    )(Xb_t, node_t, sel, tm)
     return out[:, :n_orig]
 
 
@@ -661,7 +740,7 @@ def route(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
           interpret: bool = False) -> jax.Array:
     """Level-routing dispatcher: route_pallas on a live TPU / in
     interpret mode, the gather form on CPU (identical decisions — the
-    pallas selected-bin is a single f32-exact one-hot term)."""
+    pallas selected-bin is a single exact one-hot term)."""
     if interpret or available():
         return route_pallas(Xb_t, node_t, f_lvl, t_lvl, m_lvl,
                             n_nodes=n_nodes, interpret=interpret)
@@ -670,19 +749,20 @@ def route(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
 
 # -- fused route + histogram ------------------------------------------------
 # One pass of the binned matrix per level instead of two: the level-d
-# split tables route every row IN VMEM and the surviving (left-child)
-# slot ids feed the level-(d+1) histogram contraction in the same grid
-# step — the route pass's separate HBM read of Xb disappears. Works
-# because new_node = 2*node + right is even exactly when the row goes
-# left, and sibling subtraction histograms LEFT children only: the
-# level-(d+1) slot id of a left row is its OLD node id, known the moment
-# `right` is computed. Fold lanes (CV folds x fused config lanes) share
-# the Xb read and the (feature, bin) one-hot exactly as in _kernel.
+# split tables route every row IN VMEM (_route_right: the routing above,
+# at this level's node count) and the surviving (left-child) slot ids
+# feed the level-(d+1) histogram contraction in the same grid step — the
+# route pass's separate HBM read of Xb disappears. Works because
+# new_node = 2*node + right is even exactly when the row goes left, and
+# sibling subtraction histograms LEFT children only: the level-(d+1) slot
+# id of a left row is its OLD node id, known the moment `right` is
+# computed. Fold lanes (CV folds x fused config lanes) share the Xb read
+# and the (feature, bin) one-hot exactly as in _kernel.
 
 
-def _route_hist_kernel(xb_ref, pay_ref, node_ref, tbl_ref, hist_ref,
+def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
                        node_out_ref, *, F: int, B: int, C: int, n_nodes: int,
-                       n_pad: int, n_folds: int,
+                       n_r: int, n_folds: int,
                        use_bf16=False, derive_count=False):
     import jax.experimental.pallas as pl
 
@@ -694,30 +774,16 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, tbl_ref, hist_ref,
     mxu_dtype = jnp.bfloat16 if use_bf16 else jnp.float32
     xf = xb_ref[:].astype(jnp.float32)                      # [F, blk]
     oh = _feature_onehot(xf, F=F, B=B, blk=blk, use_bf16=use_bf16)
-    fi = jax.lax.broadcasted_iota(jnp.int32, (F, blk), 0) \
-        .astype(jnp.float32)
-    ni = jax.lax.broadcasted_iota(jnp.int32, (n_pad, blk), 0) \
-        .astype(jnp.float32)
+    rights = _route_right(xf, node_ref, sel_ref, tm_ref,
+                          one_byte=xb_ref.dtype.itemsize == 1, n_r=n_r,
+                          n_folds=n_folds)
     slots = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, blk), 0) \
         .astype(jnp.float32)
     Co = C + (1 if derive_count else 0)
     rows, qs = [], []
     for k in range(n_folds):
         node = node_ref[k:k + 1, :]                         # [1, blk]
-        noh = (ni == node).astype(jnp.float32)              # [n_pad, blk]
-        tbl = tbl_ref[3 * k:3 * k + 3, :]                   # [3, n_pad]
-        # HIGHEST: one default bf16 pass is exact only for table values
-        # below 2^8 — feature ids and 256-bin thresholds go past that
-        ftm = jax.lax.dot_general(                          # [3, blk]
-            tbl, noh, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        mask = (fi == ftm[0:1, :]).astype(jnp.float32)      # [F, blk]
-        xsel = jnp.sum(xf * mask, axis=0, keepdims=True)    # [1, blk]
-        rightf = jnp.logical_or(
-            xsel > ftm[1:2, :],
-            jnp.logical_and(xsel == 0.0, ftm[2:3, :] > 0.5)
-        ).astype(jnp.float32)                               # [1, blk]
+        rightf = rights[k]                                  # [1, blk]
         rows.append(2.0 * node + rightf)
         # next level's LEFT-child slot id = old node for left rows; right
         # rows shift past the iota range (node + n_nodes >= n_nodes) —
@@ -753,25 +819,22 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
     C = pay_t.shape[0] // Fo
     Co = C + (1 if derive_count else 0)
     B = n_bins
-    tbl = jnp.stack([f_lvl.astype(jnp.float32),
-                     t_lvl.astype(jnp.float32),
-                     m_lvl.astype(jnp.float32)], axis=1)    # [Fo, 3, n]
-    tbl = _pad_minor(tbl.reshape(3 * Fo, n_nodes))          # [3Fo, n_pad]
-    n_pad = tbl.shape[1]
+    sel, tm, n_r = _route_tables(f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
+                                 n_feat=F)
     blk = block_rows(F * B)
     pad = (-N) % blk
     if pad:
         Xb_t = jnp.pad(Xb_t, ((0, 0), (0, pad)))
         pay_t = jnp.pad(pay_t, ((0, 0), (0, pad)))
-        # padded rows carry node id n_pad: they select no table entry
-        # (route as feature-0/thresh-0, then are sliced away) and can
-        # never match a histogram slot (payload is zero anyway)
+        # padded rows carry node id n_r: they own no table entry (go
+        # left, then are sliced away) and can never match a histogram
+        # slot (payload is zero anyway)
         node_t = jnp.pad(node_t, ((0, 0), (0, pad)),
-                         constant_values=float(n_pad))
+                         constant_values=float(n_r))
         N += pad
 
     kernel = functools.partial(_route_hist_kernel, F=F, B=B, C=C,
-                               n_nodes=n_nodes, n_pad=n_pad, n_folds=Fo,
+                               n_nodes=n_nodes, n_r=n_r, n_folds=Fo,
                                use_bf16=use_bf16, derive_count=derive_count)
     hist, node_out = pl.pallas_call(
         kernel,
@@ -783,7 +846,9 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((Fo, blk), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((3 * Fo, n_pad), lambda i: (0, 0),
+            pl.BlockSpec(sel.shape, lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(tm.shape, lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -798,7 +863,7 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(Xb_t, pay_t, node_t, tbl)
+    )(Xb_t, pay_t, node_t, sel, tm)
     return hist, node_out[:, :n_orig]
 
 
@@ -843,21 +908,41 @@ def _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *, n_nodes,
     return hist, new_node
 
 
-def _lookup_kernel(tbl_ref, idx_ref, out_ref, *, m_pad, n_folds):
+# -- leaf lookup ------------------------------------------------------------
+# out[k, i] = tbl[k, idx[k, i]] keeps a per-row one-hot (the value IS a
+# table entry), but the one-hot goes to the MXU as bf16 (0/1: exact) at
+# the table's own width, and it is the tiny TABLE that is cut into three
+# bf16 parts, once, outside the row loop: hi + mid + lo restores the f32
+# bit for bit, and each part's output is a single selected term. One bf16
+# pass a lane with the three parts as three rows of its lhs tile.
+
+_LOOKUP_ROWS = 16   # a lane's lhs tile: one bf16 sublane tile, 3 rows used
+
+
+def _three_parts(t: jax.Array):
+    """f32 -> (hi, mid, lo) bf16 with hi + mid + lo == t exactly. The
+    cuts are lax.reduce_precision, not an astype round trip: fused, that
+    came back unrounded for a program parameter on the v5e (PERF.md §6,
+    PR 29)."""
+    hi = jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
+    rest = t - hi
+    mid = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+    return [p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid)]
+
+
+def _lookup_kernel(tbl_ref, idx_ref, out_ref, *, m_r, n_folds):
     blk = idx_ref.shape[1]
-    mi = jax.lax.broadcasted_iota(jnp.int32, (m_pad, blk), 0) \
+    mi = jax.lax.broadcasted_iota(jnp.int32, (m_r, blk), 0) \
         .astype(jnp.float32)
     rows = []
     for k in range(n_folds):
         idx = idx_ref[k:k + 1, :]                           # [1, blk]
-        noh = (mi == idx).astype(jnp.float32)               # [m_pad, blk]
-        # HIGHEST: the MXU's default f32 matmul multiplies in one bf16
-        # pass, which would round every looked-up value to 8 mantissa
-        # bits (measured on v5e: 7.7e-3 absolute on unit-scale leaves)
-        rows.append(jax.lax.dot_general(
-            tbl_ref[k:k + 1, :], noh, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32))            # [1, blk]
+        noh = (mi == idx).astype(jnp.bfloat16)              # [m_r, blk]
+        p = jax.lax.dot_general(
+            tbl_ref[_LOOKUP_ROWS * k:_LOOKUP_ROWS * (k + 1), :], noh,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [16, blk]
+        rows.append(p[0:1, :] + p[1:2, :] + p[2:3, :])
     out_ref[:] = rows[0] if n_folds == 1 else \
         jnp.concatenate(rows, axis=0)
 
@@ -870,7 +955,8 @@ def table_lookup_pallas(tbl: jax.Array, idx_t: jax.Array, *,
     tbl [n_folds, M] f32 (e.g. leaf payloads); idx_t [n_folds, N] f32 ids.
     Out-of-range ids (>= M, e.g. row padding) return 0. TPU gathers from
     tiny tables by huge index vectors serialize; the one-hot contraction
-    here stays on the MXU/VPU and reads idx_t exactly once.
+    here stays on the MXU/VPU and reads idx_t exactly once. Values come
+    back bit for bit (finite f32 whose lowest part is not subnormal).
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -878,20 +964,22 @@ def table_lookup_pallas(tbl: jax.Array, idx_t: jax.Array, *,
     Fo, M = tbl.shape
     N = idx_t.shape[1]
     n_orig = N
-    tblp = _pad_minor(tbl)
-    m_pad = tblp.shape[1]
+    m_r = node_rows(M, 2)
+    parts = jnp.stack(_three_parts(tbl.astype(jnp.float32)), axis=1)
+    tblp = jnp.pad(parts, ((0, 0), (0, _LOOKUP_ROWS - 3), (0, m_r - M))) \
+        .reshape(Fo * _LOOKUP_ROWS, m_r)                    # [16 Fo, m_r]
     blk = _ROUTE_BLK
     pad = (-N) % blk
     if pad:
         idx_t = jnp.pad(idx_t, ((0, 0), (0, pad)),
-                        constant_values=float(m_pad))       # -> 0
+                        constant_values=float(m_r))         # -> 0
         N += pad
-    kernel = functools.partial(_lookup_kernel, m_pad=m_pad, n_folds=Fo)
+    kernel = functools.partial(_lookup_kernel, m_r=m_r, n_folds=Fo)
     return pl.pallas_call(
         kernel,
         grid=(N // blk,),
         in_specs=[
-            pl.BlockSpec((Fo, m_pad), lambda i: (0, 0),
+            pl.BlockSpec(tblp.shape, lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((Fo, blk), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
