@@ -327,6 +327,9 @@ class Validator:
         # executed-FLOP accounting reads it; also mirrored into
         # utils/metrics.collector.sweep_convergence when collection is on)
         self.last_streamed_telemetry: Optional[Dict[str, Any]] = None
+        # forest-lane counts of the last sweep's tree families (None: no
+        # forest ran as lanes) — _count_tree_lanes
+        self.last_tree_telemetry: Optional[Dict[str, Any]] = None
         self._external_mask_tag = ""  # set per validate() call
         # are the folds' held-out sets disjoint? (set per validate() call;
         # the streamed sweep's one-pass metric route needs it)
@@ -796,6 +799,20 @@ class Validator:
             grid_index=int(gi), route=route, n_folds=len(fm),
             mean_metric=float(np.mean(finite)) if finite else None)
 
+    def _count_tree_lanes(self, est, lanes):
+        """Sum one grid point's forest-lane counts into
+        last_tree_telemetry (tree_lanes, lane_groups, bootstrap_draws
+        add up over a sweep's points; lanes_per_group is the widest)."""
+        tele = self.last_tree_telemetry or {
+            "model": type(est).__name__, "route": "forest_lanes",
+            "tree_lanes": 0, "lane_groups": 0, "lanes_per_group": 0,
+            "bootstrap_draws": 0}
+        for key in ("tree_lanes", "lane_groups", "bootstrap_draws"):
+            tele[key] += int(lanes[key])
+        tele["lanes_per_group"] = max(tele["lanes_per_group"],
+                                      int(lanes["lanes_per_group"]))
+        self.last_tree_telemetry = tele
+
     def _record_sweep_telemetry(self, est, info):
         self.last_streamed_telemetry = dict(info,
                                             model=type(est).__name__)
@@ -1034,6 +1051,7 @@ class Validator:
         fallback re-sliced X per fold, 'exactly the Spark-era shape'). The
         fold axis is vmapped; grids stay sequential because tree params
         (depth, rounds) are XLA-static."""
+        self.last_tree_telemetry = None
         margin_thr = self._margin_threshold(est)
         ckpt, keys, results = self._cell_bookkeeping(
             est, grids, X, y, metric, masks.shape[0],
@@ -1232,7 +1250,13 @@ class Validator:
                             scores = est_g.mask_fit_scores(
                                 ctx, yd, wd, md, n_classes=n_classes,
                                 multiclass=multicls)
-                        record(gi, scores)
+                        # a forest that ran as (tree, fold) lanes of the
+                        # fused passes says so, and what it counted
+                        lanes = getattr(est_g, "last_lane_telemetry", None)
+                        if lanes:
+                            fused_gis[gi] = "mask_folds:forest_lanes"
+                            self._count_tree_lanes(est, lanes)
+                        record(gi, scores, route=fused_gis.get(gi))
                 del ctx  # free the binned matrix before the next group
             if fuse_failures:
                 import logging

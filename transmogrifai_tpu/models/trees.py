@@ -559,8 +559,28 @@ def _feature_frac(strategy: str, n_feat: int, classification: bool) -> float:
         return 1.0
 
 
+#: Where forest sweeps take the lane route: the backends that have fused
+#: kernels to run it on, and the row floor (the fold-vmap limit: under it
+#: folds are vmapped over single-lane trees). Hand overrides for tests
+#: and the benchmark's CPU rehearsal, where the kernels' jnp twins run the
+#: same call shapes; nothing else sets them.
+FOREST_LANE_BACKENDS = ("tpu",)
+FOREST_LANE_MIN_ROWS = _TreeEstimator._VMAP_FOLD_MAX_ROWS
+
+
+def forest_lane_route_ok(est, n_rows: int, n_feat: int, n_folds: int,
+                         multiclass: bool = False) -> bool:
+    """Does `est`'s mask-fold sweep of an [n_rows, n_feat] matrix run as
+    (tree, fold) lanes of the fused passes here? Shapes only: a caller
+    (the benchmark) asks before it makes any data."""
+    plan = getattr(est, "forest_lane_plan", None)
+    return plan is not None and plan(n_rows, n_feat, n_folds,
+                                     multiclass=multiclass)[0] > 0
+
+
 class _ForestBase(_TreeEstimator):
     classification = True
+    last_lane_telemetry: Optional[Dict[str, int]] = None
 
     def _forest_cfg(self, n_feat: int) -> Dict[str, Any]:
         return dict(
@@ -571,29 +591,143 @@ class _ForestBase(_TreeEstimator):
                 self.classification)),
             bootstrap=True)
 
+    def _one_channel(self, n_classes: int, multiclass: bool) -> bool:
+        """Does the sweep's payload fit ONE channel? A regression target
+        does; a binary label does too: the variance gain of [w y] is half
+        the two-class Gini gain of [w (1 - y), w y] at every candidate, so
+        the same trees grow once minInfoGain is halved with it, and the
+        class-1 mean IS the leaf's distribution. It holds while every
+        tree scores every row: a tree counts 0 for a row on a
+        training-empty leaf, which the two-channel vote renormalises away
+        and no row can reach where a split needs a row a side
+        (min_instances_per_node >= 1)."""
+        if not self.classification:
+            return True
+        return (not multiclass and n_classes == 2
+                and float(self.get_param("min_instances_per_node")) >= 1.0)
+
     def _mask_score(self, ctx, y, w, n_classes, multiclass):
         Xb, edges, n_bins = ctx
         cfg = self._forest_cfg(Xb.shape[1])
         depth = int(self.get_param("max_depth"))
-        if self.classification:
+        one = self._one_channel(n_classes, multiclass)
+        min_info_gain = float(self.get_param("min_info_gain"))
+        if one:
+            G = (y * w)[:, None]
+            if self.classification:  # Spark's threshold is two-class
+                min_info_gain *= 0.5
+        else:
             G = jax.nn.one_hot(y.astype(jnp.int32), n_classes,
                                dtype=jnp.float32) * w[:, None]
-        else:
-            G = (y * w)[:, None]
         trees = T.fit_forest(
             Xb, G, w, self._key(), depth=depth, n_bins=n_bins,
             min_instances=float(self.get_param("min_instances_per_node")),
-            min_info_gain=float(self.get_param("min_info_gain")),
+            min_info_gain=min_info_gain,
             leaf_mode="mean", allow_pallas=self._one_device(Xb), **cfg)
         agg = T.predict_forest_bins(trees, Xb, depth)  # [n, K]
-        if not self.classification:
-            return agg[:, 0] / cfg["n_trees"]
+        if one:
+            return T.forest_vote_scores(
+                agg[:, 0], n_trees=cfg["n_trees"],
+                classification=self.classification)
         prob = jnp.clip(agg / cfg["n_trees"], 0.0, None)
         prob = prob / jnp.maximum(prob.sum(axis=1, keepdims=True), 1e-12)
         if multiclass:
             return prob  # [n, c] class scores (argmax = predicted class)
         p1 = jnp.clip(prob[:, 1], 1e-7, 1.0 - 1e-7)
         return jnp.log(p1 / (1.0 - p1))  # margin for the binary metrics
+
+    # -- lane route: (tree, fold) lanes of the fused passes -----------------
+    def forest_lane_plan(self, n_rows: int, n_feat: int, n_folds: int,
+                         n_classes: int = 2, multiclass: bool = False):
+        """(trees a lane group, "") where this estimator's mask-fold sweep
+        takes the lane route (ops/trees.fit_forest_lanes) at this shape
+        on this backend, (0, why not) where it keeps the sequential
+        trees. THE gate of the route: mask_sweep_context, the fused hook
+        and the benchmark's predicate (forest_lane_route_ok) all ask it."""
+        from ..ops import pallas_hist
+        backend = jax.default_backend()
+        if backend not in FOREST_LANE_BACKENDS or (
+                backend == "tpu" and not pallas_hist.available()):
+            return 0, f"backend {backend}: no fused kernels to run on"
+        if n_rows <= FOREST_LANE_MIN_ROWS:
+            return 0, (f"{n_rows} rows: at or under the fold-vmap limit "
+                       f"{FOREST_LANE_MIN_ROWS}")
+        if not self._one_channel(n_classes, multiclass):
+            return 0, ("a payload of more than one channel (a multiclass "
+                       "forest, or min_instances_per_node < 1): the fused "
+                       "passes carry [g, h, count]")
+        cfg = self._forest_cfg(n_feat)
+        depth = int(self.get_param("max_depth"))
+        group = pallas_hist.plan_forest_group(
+            n_rows, n_feat, int(self.get_param("max_bins")) + 1, n_folds,
+            cfg["n_trees"], depth)
+        if group == 0:
+            return 0, (f"depth {depth}: the planner refuses the slot-dense "
+                       f"output block of its deepest level")
+        return group, ""
+
+    def mask_sweep_context(self, X, n_valid: int = None, mesh=None):
+        """The lane route runs on the device context whatever the backend
+        (a CPU rehearsal takes the kernels' jnp twins, not the native
+        builder). Asked for one fold, the most the plan can admit: a
+        sweep the hook then declines runs the device trees."""
+        if mesh is None and self.forest_lane_plan(
+                int(n_valid or X.shape[0]), int(X.shape[1]), 1)[0]:
+            return self._bin(X, n_valid=n_valid)
+        return super().mask_sweep_context(X, n_valid=n_valid, mesh=mesh)
+
+    def _mask_scores_fused(self, ctx, y, w, masks, n_classes, multiclass):
+        """Every fold's forest as lane groups of the fused passes; None
+        (with a `forest_lane_route_declined` event that says why) where
+        forest_lane_plan declines or the matrix is laid over a mesh."""
+        from ..ops import pallas_hist
+        from ..utils.metrics import collector
+        Xb, edges, n_bins = ctx
+        n, n_feat, folds = int(Xb.shape[0]), int(Xb.shape[1]), \
+            int(masks.shape[0])
+        group, why = self.forest_lane_plan(n, n_feat, folds, n_classes,
+                                           multiclass)
+        if group and not self._one_device(Xb):
+            group, why = 0, "the binned matrix is laid over a mesh"
+        if not group:
+            collector.event("forest_lane_route_declined",
+                            model=type(self).__name__, reason=why)
+            return None
+        cfg = self._forest_cfg(n_feat)
+        depth = int(self.get_param("max_depth"))
+        n_trees = cfg["n_trees"]
+        min_info_gain = float(self.get_param("min_info_gain")) \
+            * (0.5 if self.classification else 1.0)
+        key = self._key()
+        W = masks * w[None, :]
+        votes = jnp.zeros((folds, n), jnp.float32)
+        groups = -(-n_trees // group)
+        for gi in range(groups):
+            with collector.trace_span(
+                    "forest_bootstrap", kind="tree_fused", trees=group,
+                    rows=n, draws=group * n):
+                rw, node_keys = T.forest_bootstrap(
+                    key, gi * group, cfg["subsample"], n_rows=n,
+                    n_trees=n_trees, group=group,
+                    bootstrap=cfg["bootstrap"])
+            with collector.trace_span(
+                    "forest_group", kind="tree_fused",
+                    lanes=group * folds, trees=group, folds=folds,
+                    depth=depth,
+                    slot_passes=sum(T.fused_level_slots(depth)),
+                    route_node_rows=pallas_hist.route_node_rows(depth)):
+                votes, _, _ = T.fit_forest_lanes(
+                    Xb, y, W, rw, node_keys, votes, depth=depth,
+                    n_bins=n_bins, feature_frac=cfg["feature_frac"],
+                    min_instances=float(
+                        self.get_param("min_instances_per_node")),
+                    min_info_gain=min_info_gain)
+        self.last_lane_telemetry = dict(
+            tree_lanes=n_trees * folds, lane_groups=groups,
+            lanes_per_group=group * folds,
+            bootstrap_draws=groups * group * n)
+        return T.forest_vote_scores(votes, n_trees=n_trees,
+                                    classification=self.classification)
 
     def _mask_score_host(self, ctx, y, w, n_classes, multiclass):
         """Numpy/native twin of _mask_score (CPU sweeps)."""

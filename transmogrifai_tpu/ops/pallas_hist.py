@@ -1008,3 +1008,48 @@ def _table_lookup_jnp(tbl: jax.Array, idx_t: jax.Array) -> jax.Array:
     idx = idx_t.astype(jnp.int32)
     vals = jnp.take_along_axis(tbl, jnp.clip(idx, 0, M - 1), axis=1)
     return jnp.where((idx >= 0) & (idx < M), vals, 0.0)
+
+
+# -- forest lane groups -------------------------------------------------------
+# A forest's (tree, fold) lanes go through the fused passes a GROUP at a
+# time (ops/trees.fit_forest_lanes). What bounds a group is what bounds
+# the boosters' lanes — the VMEM plan, the fused output block, the lane
+# planes in HBM — at other figures: a forest lane carries five row planes
+# (two payload channels, node ids in and out, leaf rows) where a booster
+# lane carries four, and the planes' sublane axis pads to 8 in HBM.
+
+#: cap of the deepest level's output block [lanes * slots * 3, F * B] f32.
+#: Three quarters of the 16 MB at which r5 saw 20-minute Mosaic compiles;
+#: PERF.md §6 (PR 31) has the compile and pass times that were measured
+#: under it.
+_FOREST_OUT_BLOCK_BYTES = 12 << 20
+_FOREST_LANE_PLANES = 5
+
+
+def plan_forest_group(n_rows: int, n_feat: int, n_bins: int, n_folds: int,
+                      n_trees: int, depth: int) -> int:
+    """Trees a lane group of the forest route: the most whose (tree x
+    fold) lanes clear plan_fused_hist, the output-block cap and 7/16 of
+    the device's HBM in lane planes (rows count here: the planes are what
+    grows with them), then evened out over the groups the forest needs —
+    20 trees at a most of 6 a group make 4 groups of 5, not 3 of 6 and a
+    2. 0: not even one tree's fold lanes fit (depth 12: the slot-dense
+    output block alone is 130 MB), and the caller keeps its sequential
+    path. `n_bins` counts the missing-value bin."""
+    from ..utils.platform import device_spec
+    spec = device_spec()
+
+    def ok(trees: int) -> bool:
+        lanes = trees * n_folds
+        plan = plan_fused_hist(n_feat, n_bins, lanes, depth)
+        planes = _FOREST_LANE_PLANES * (-(-lanes // 8) * 8) * n_rows * 4
+        return (plan.fits and plan.out_bytes <= _FOREST_OUT_BLOCK_BYTES
+                and (spec is None or planes <= spec.hbm_bytes * 7 // 16))
+
+    most = 0
+    while most < n_trees and ok(most + 1):
+        most += 1
+    if most == 0:
+        return 0
+    groups = -(-n_trees // most)
+    return -(-n_trees // groups)

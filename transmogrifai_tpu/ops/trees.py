@@ -846,6 +846,41 @@ def predict_forest_bins(trees: Tree, Xb: jax.Array, depth: int) -> jax.Array:
 
 # -- random forest ----------------------------------------------------------
 
+# Terms of the Poisson inverse CDF below: the tail past them is under
+# 1e-13 at rate 1 and under 1e-7 at rate 4, far finer than the 2^-23 steps
+# of a float32 uniform
+_POISSON_TERMS = 24
+
+
+def _bootstrap_weights(key: jax.Array, n_rows: int, subsample,
+                       bootstrap: bool = True) -> jax.Array:
+    """One tree's row weights [n_rows] f32: Poisson(subsample) counts
+    (Spark's with-replacement bagging) or, without bootstrap, a 0/1 draw
+    at rate `subsample`. ONE uniform a row and the inverse CDF as an
+    unrolled compare-and-count: one elementwise pass, where
+    jax.random.poisson loops a rejection sampler over the whole vector.
+    The count is sum_k [1 - u <= P(X > k)], the survival function summed
+    from its SMALLEST term up: a CDF summed from the top sticks a few
+    ulps under 1 in float32, and the largest uniforms then count every
+    term (the chip drew 24s, PERF.md §6 PR 31). The one draw of both
+    forest routes (fit_forest, forest_bootstrap): a seed grows the same
+    trees on either."""
+    u = jax.random.uniform(key, (n_rows,))
+    if not bootstrap:
+        return (u < subsample).astype(jnp.float32)
+    lam = jnp.asarray(subsample, jnp.float32)
+    pmf = [jnp.exp(-lam)]
+    for k in range(1, _POISSON_TERMS + 1):
+        pmf.append(pmf[-1] * lam / k)
+    v = 1.0 - u                       # (0, 1], exact: u is k * 2^-23
+    tail = jnp.zeros((), jnp.float32)
+    count = jnp.zeros((n_rows,), jnp.float32)
+    for k in range(_POISSON_TERMS, 0, -1):
+        tail = tail + pmf[k]          # P(X > k - 1)
+        count = count + (v <= tail)
+    return count
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_trees", "depth", "n_bins", "leaf_mode",
@@ -864,12 +899,7 @@ def fit_forest(Xb: jax.Array, G: jax.Array, H: jax.Array, key: jax.Array, *,
     """
     def one(_, k):
         kb, kf = jax.random.split(k)
-        if bootstrap:
-            rw = jax.random.poisson(kb, subsample,
-                                    (Xb.shape[0],)).astype(jnp.float32)
-        else:
-            rw = (jax.random.uniform(kb, (Xb.shape[0],))
-                  < subsample).astype(jnp.float32)
+        rw = _bootstrap_weights(kb, Xb.shape[0], subsample, bootstrap)
         tree = grow_tree(Xb, G * rw[:, None], H * rw, kf, depth=depth,
                          n_bins=n_bins, reg_lambda=reg_lambda,
                          min_instances=min_instances,
@@ -1000,14 +1030,18 @@ def _fold_split_scores(reg_lambda, min_child_weight, gamma):
 
 
 def _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
-                  learning_rate):
-    """Per-fold newton leaves from leaf sufficient statistics [Fo, L(, K)]
-    — the one shared leaf rule of both fused growth forms."""
-    rl_col = reg_lambda[:, None] if getattr(reg_lambda, "ndim", 0) == 1 \
-        else reg_lambda
-    leaf = -_soft_l1(Gl, alpha) / (Hl + rl_col + EPS)[..., None]
-    if max_delta_step > 0.0:  # [Fo, L, 1] — cap raw newton step
-        leaf = jnp.clip(leaf, -max_delta_step, max_delta_step)
+                  learning_rate, leaf_mode="newton"):
+    """Per-fold leaves from leaf sufficient statistics [Fo, L(, K)] —
+    the one leaf rule of the fused growth form: newton steps for the
+    boosters, grow_tree's weighted mean G / H for forest lanes."""
+    if leaf_mode == "mean":
+        leaf = Gl / (Hl + EPS)[..., None]
+    else:
+        rl_col = reg_lambda[:, None] \
+            if getattr(reg_lambda, "ndim", 0) == 1 else reg_lambda
+        leaf = -_soft_l1(Gl, alpha) / (Hl + rl_col + EPS)[..., None]
+        if max_delta_step > 0.0:  # [Fo, L, 1] — cap raw newton step
+            leaf = jnp.clip(leaf, -max_delta_step, max_delta_step)
     leaf = jnp.where(Cl[..., None] >= 0.5, leaf, 0.0)
     lr_col = learning_rate[:, None, None] \
         if getattr(learning_rate, "ndim", 0) == 1 else learning_rate
@@ -1015,7 +1049,7 @@ def _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
 
 
 def _fold_leaves(last, *, n_leaves, reg_lambda, alpha, max_delta_step,
-                 learning_rate):
+                 learning_rate, leaf_mode="newton"):
     """Leaf payloads [Fo, n_leaves, 1] read off the LAST level's
     cumulative histograms (`last` as produced by the level split) — same
     free-leaf trick as grow_tree's leaf pass, vmapped over folds."""
@@ -1038,7 +1072,7 @@ def _fold_leaves(last, *, n_leaves, reg_lambda, alpha, max_delta_step,
     Gl, Hl, Cl = jax.vmap(leaf_of)(GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm,
                                    f_lvl, t_lvl, m_lvl)
     return _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
-                         learning_rate)
+                         learning_rate, leaf_mode)
 
 
 def level_slots(depth: int) -> tuple:
@@ -1061,7 +1095,9 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                      min_info_gain, gamma, learning_rate, feature_mask,
                      interpret=False, alpha=0.0, max_delta_step=0.0,
                      level_feature_frac=1.0, level_key=None,
-                     feature_mask_count=None, axis_name=None):
+                     feature_mask_count=None, axis_name=None,
+                     normalize_gain=False, leaf_mode="newton",
+                     node_feature_frac=1.0, node_keys=None):
     """Grow one tree PER FOLD level-wise in shared fused passes.
 
     Xb_t [F, N] transposed bins (N pre-padded to the route block size by
@@ -1088,10 +1124,20 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     over: every level histogram psums across shards before the split
     algebra (DrJAX-style psum-merged MapReduce), routing stays local.
 
-    Returns (Tree with leading [Fo] axes, leaf_rows [Fo, N]) where
-    leaf_rows are the learning-rate-scaled per-row leaf payloads —
+    Forest lanes (fit_forest_lanes) take the same loop with Spark's
+    rules: `normalize_gain` compares the gain a weighted row with
+    min_info_gain, `leaf_mode="mean"` makes leaves G / H, and
+    `node_feature_frac` < 1 draws a fresh feature subset at EVERY node
+    from `node_keys` [T, 2] — one key a tree, split level by level exactly
+    as grow_tree splits its own, its subsets shared by the Fo // T lanes
+    (tree-major) that are the tree's folds.
+
+    Returns (Tree with leading [Fo] axes, leaf_rows [Fo, N], subsets)
+    where leaf_rows are the learning-rate-scaled per-row leaf payloads —
     bitwise what predict_bins returns for each fold's tree, read off the
-    final routing state instead of re-traversed.
+    final routing state instead of re-traversed — and subsets is the
+    [T, 2^depth - 1, F] bool record of the per-node draws (None without
+    them).
     """
     from . import pallas_hist
 
@@ -1110,7 +1156,7 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     # [Fo*C]; g/h are level-invariant, so build [Fo, 2, N] -> [2Fo, N]
     # once — the count channel is derived in VMEM (derive_count)
     pay = jnp.stack([G, H], axis=1).reshape(2 * Fo, N)
-    feats, threshs, misses = [], [], []
+    feats, threshs, misses, subsets = [], [], [], []
     last = None
     prev = None
     hist = None
@@ -1149,10 +1195,21 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
 
         gain = split_scores_f(GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm,
                               reg_lambda, min_child_weight, min_instances,
-                              min_info_gain, gamma, alpha, False)
+                              min_info_gain, gamma, alpha, normalize_gain)
         if feature_mask is not None:
             gain = jnp.where(feature_mask[None, None, :, None, None],
                              gain, -jnp.inf)
+        if node_feature_frac < 1.0 and node_keys is not None:
+            # Spark featureSubsetStrategy: a subset a NODE, one stream a
+            # tree (grow_tree's own key walk), the tree's fold lanes alike
+            node_keys, subs = jnp.moveaxis(
+                jax.vmap(jax.random.split)(node_keys), 1, 0)
+            fm = jax.vmap(lambda k: _feature_mask(
+                k, n_nodes, F, node_feature_frac))(subs)      # [T, n, F]
+            subsets.append(fm)
+            gain = jnp.where(
+                jnp.repeat(fm, Fo // fm.shape[0], axis=0)[..., None, None],
+                gain, -jnp.inf)
         if level_feature_frac < 1.0 and level_key is not None:
             # colsample_bylevel: one fresh subset per level, shared by
             # every fold (fold parity with the sequential loop, which
@@ -1198,17 +1255,18 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
         Cl = _allreduce((H > 0).astype(jnp.float32).sum(axis=1),
                         axis_name)[:, None]
         leaf = _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha,
-                             max_delta_step, learning_rate)
+                             max_delta_step, learning_rate, leaf_mode)
     else:
         leaf = _fold_leaves(last, n_leaves=n_leaves, reg_lambda=reg_lambda,
                             alpha=alpha, max_delta_step=max_delta_step,
-                            learning_rate=learning_rate)
+                            learning_rate=learning_rate, leaf_mode=leaf_mode)
     leaf_rows = pallas_hist.table_lookup(
         leaf[:, :, 0], node, interpret=interpret)         # [Fo, N]
     tree = Tree(jnp.concatenate(feats, axis=1),
                 jnp.concatenate(threshs, axis=1), leaf,
                 jnp.concatenate(misses, axis=1))
-    return tree, leaf_rows
+    return tree, leaf_rows, \
+        (jnp.concatenate(subsets, axis=1) if subsets else None)
 
 
 def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
@@ -1298,7 +1356,7 @@ def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
         # like grow_tree splits its key, so the fused and sequential
         # routes draw identical level subsets); per-node resampling stays
         # unused — boosting samples features per tree/level, not per node
-        tree, leaf_rows = _grow_tree_folds(
+        tree, leaf_rows, _ = _grow_tree_folds(
             Xb_t, g, h, depth=depth, n_bins=n_bins,
             reg_lambda=reg_lambda, min_child_weight=min_child_weight,
             min_instances=min_instances, min_info_gain=min_info_gain,
@@ -1367,6 +1425,100 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
         feature_frac=feature_frac, loss=loss, interpret=interpret,
         alpha=alpha, max_delta_step=max_delta_step,
         colsample_bylevel=colsample_bylevel, base_score=base_score)
+
+
+# -- forest lanes -----------------------------------------------------------
+# A forest's trees do not depend on one another, so (tree, fold) pairs are
+# LANES of the passes the boosters share among folds: one read of the
+# binned matrix and one (feature, bin) one-hot a level serve every lane of
+# a group, and the histogram contraction's M grows to lanes x slots x 3.
+# models/trees._ForestBase drives it a group at a time: forest_bootstrap
+# draws the group's row weights on the device ([trees, N]: all of a
+# forest's at once would be gigabytes), fit_forest_lanes grows the group
+# and adds its votes, forest_vote_scores turns the votes into scores.
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_trees", "group",
+                                             "bootstrap"))
+def forest_bootstrap(key: jax.Array, start, subsample, *, n_rows: int,
+                     n_trees: int, group: int, bootstrap: bool = True):
+    """Row weights and node-subset keys of trees `start .. start + group`
+    of the forest keyed `key`: (rw [group, n_rows] f32, node_keys
+    [group, 2]). Tree t's key is fit_forest's — split(key, n_trees)[t],
+    split again into the bootstrap draw and grow_tree's key — so the lane
+    route and the sequential one grow the same forest from one seed. A
+    slot past n_trees (the last group of a forest the group size does not
+    divide) weighs nothing: it grows a dead tree whose every leaf is 0."""
+    keys = jax.random.split(key, n_trees)
+    keys = jnp.pad(keys, ((0, group), (0, 0)))
+    ks = jax.lax.dynamic_slice(keys, (start, 0), (group, keys.shape[1]))
+    kb, kf = jnp.moveaxis(jax.vmap(jax.random.split)(ks), 1, 0)
+    rw = jax.vmap(lambda k: _bootstrap_weights(k, n_rows, subsample,
+                                               bootstrap))(kb)
+    live = (start + jnp.arange(group)) < n_trees
+    return rw * live[:, None].astype(jnp.float32), kf
+
+
+@functools.partial(jax.jit, static_argnames=("depth", "n_bins",
+                                             "feature_frac", "interpret"))
+def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
+                     rw: jax.Array, node_keys: jax.Array, votes: jax.Array,
+                     *, depth: int, n_bins: int, feature_frac: float = 1.0,
+                     min_instances=1.0, min_info_gain=0.0,
+                     interpret: bool = False):
+    """Grow one GROUP of a forest's trees for every CV fold in the fused
+    passes and add their votes: lanes = (tree, fold), tree-major.
+
+    Xb [N, F] binned; y [N] the one payload channel a unit of weight — the
+    class-1 indicator of a binary label, or a regression target; W
+    [folds, N] fold mask x sample weight; rw [T, N] the trees' bootstrap
+    weights and node_keys [T, 2] their node-subset keys (forest_bootstrap);
+    votes [folds, N] the running sum of leaf values. Lane (t, f) fits
+    weights rw[t] * W[f], exactly fit_forest's tree t on fold f's rows:
+    the tree's folds share its bootstrap vector and its per-node feature
+    subsets (the sequential route hands every fold the same key), gains
+    are Spark's (normalised by the node's weight, `min_instances`,
+    `min_info_gain`), leaves weighted means.
+
+    `min_info_gain` is compared with THIS payload's gain: the variance
+    gain of one channel. For a binary label that is half the two-class
+    Gini gain grow_tree sums over a [w (1 - y), w y] payload, so a caller
+    holding Spark's minInfoGain passes half of it (models/trees).
+
+    Returns (votes + sum over the group's trees of the leaf value each
+    row lands on, read off the final routing state — no tree is traversed
+    a second time; Tree with leading [T * folds] axes; the per-node
+    subsets [T, 2^depth - 1, F] or None)."""
+    from . import pallas_hist
+    folds, n_orig = W.shape
+    pad = (-n_orig) % pallas_hist._ROUTE_BLK
+    H = (rw[:, None, :] * W[None, :, :]).reshape(-1, n_orig)  # [T*folds, N]
+    G = H * y[None, :]
+    if pad:  # inert: zero payloads, as in _fit_gbt_folds_impl
+        Xb = jnp.pad(Xb, ((0, pad), (0, 0)))
+        G = jnp.pad(G, ((0, 0), (0, pad)))
+        H = jnp.pad(H, ((0, 0), (0, pad)))
+    trees, leaf_rows, subsets = _grow_tree_folds(
+        Xb.T, G, H, depth=depth, n_bins=n_bins, reg_lambda=0.0,
+        min_child_weight=0.0, min_instances=min_instances,
+        min_info_gain=min_info_gain, gamma=0.0, learning_rate=1.0,
+        feature_mask=None, interpret=interpret, normalize_gain=True,
+        leaf_mode="mean", node_feature_frac=feature_frac,
+        node_keys=node_keys)
+    group_votes = leaf_rows[:, :n_orig].reshape(-1, folds, n_orig).sum(0)
+    return votes + group_votes, trees, subsets
+
+
+@functools.partial(jax.jit, static_argnames=("n_trees", "classification"))
+def forest_vote_scores(votes: jax.Array, *, n_trees: int,
+                       classification: bool) -> jax.Array:
+    """[folds, N] scores from summed leaf values: the mean vote of a
+    regression forest; for a binary one the logit of the mean class-1
+    vote, clipped as _ForestBase._mask_score clips it."""
+    mean = votes / n_trees
+    if not classification:
+        return mean
+    p1 = jnp.clip(mean, 1e-7, 1.0 - 1e-7)
+    return jnp.log(p1 / (1.0 - p1))
 
 
 #: jitted shard_map program per (mesh, static config) — an explicit dict
@@ -1573,7 +1725,7 @@ def _register_pallas_consumers():
     kill switch must be able to clear them (set_pallas_enabled)."""
     from . import pallas_hist
     for fn in (grow_tree, fit_forest, fit_gbt, fit_gbt_folds,
-               fit_gbt_softmax, _ShardedCacheClearer()):
+               fit_forest_lanes, fit_gbt_softmax, _ShardedCacheClearer()):
         pallas_hist.register_cache_consumer(fn)
 
 
