@@ -1,0 +1,94 @@
+"""Kernels of the main path compiled for a DESCRIBED TPU v5e at the widths
+the benchmark runs them at: the TPU's compiler is installed here and refuses
+what the chip's would (a slice off the tiling, too much VMEM, a layout it
+cannot take), at no chip time. Nothing runs, so nothing here is a result or
+a time. The topology is described inside a fixture and never at import: one
+process at a time may load the TPU's library (on-chip-measurement guide).
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from transmogrifai_tpu.ops import glm_sweep as GS
+from transmogrifai_tpu.ops import pallas_hist
+from transmogrifai_tpu.utils import platform as P
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_v5e(monkeypatch):
+    """What the program asks of its backend answered as on the chip (the VMEM
+    figures the kernels size themselves by; Mosaic is there), and no
+    persistent cache: what is compiled for a described chip cannot be read
+    back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(P, "device_spec",
+                        lambda kind=None: P.DEVICE_SPECS["TPU v5 lite"])
+    monkeypatch.setattr(pallas_hist, "available", lambda: True)
+    GS.sweep_mlr_round.clear_cache()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    GS.sweep_mlr_round.clear_cache()
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _round_shapes(one_chip, n, d, K, Lb, F, dtype):
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (S((n, d), dtype), S((n,), F32), S((n,), F32), S((F, n), F32),
+            S((F, Lb), F32), S((Lb,), F32), S((Lb,), F32), S((Lb, d, K), F32),
+            S((Lb, K), F32), S((d,), F32), S((d,), F32), S((Lb, d, d), F32),
+            S((Lb, d), F32), S((), jnp.int32), S((), F32))
+
+
+@pytest.mark.parametrize("n,d,K,Lb,F,dtype", [
+    (25_000_000, 64, 32, 16, 5, BF16),      # sweep-mlr-k32's round
+    (25_000_000, 64, 32, 1, 5, BF16),       # its smallest bucket
+    (1_000_003, 37, 3, 4, 3, BF16),         # ragged rows, columns, classes
+    (1_000_003, 100, 2, 2, 3, F32),
+], ids=["k32-bucket16", "k32-bucket1", "ragged-bf16", "ragged-f32"])
+def test_fused_multinomial_round_compiles_for_a_v5e(
+        one_chip, as_v5e, n, d, K, Lb, F, dtype):
+    """The whole round program around the fused pass: Mosaic takes the
+    kernel, and the program holds no second copy of X (the matrix lives
+    rows-minor on the chip, so X.T is the layout it has)."""
+    compiled = GS.sweep_mlr_round.lower(
+        *_round_shapes(one_chip, n, d, K, Lb, F, dtype),
+        fit_intercept=True).compile()
+    assert "mlr_gradient" in compiled.as_text()
+    x_bytes = n * d * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * x_bytes
+
+
+def test_a_128_column_matrix_would_be_copied(one_chip, as_v5e, monkeypatch):
+    """Why mlr_round_kernel leaves 128 columns to the XLA blocks: the chip
+    keeps such a matrix columns-minor, and the fused round would hold a
+    transposed copy of it beside the original."""
+    n, d = 4_000_000, 128
+    assert GS.mlr_round_kernel(d) == "xla_blocks"
+    monkeypatch.setattr(GS, "mlr_round_kernel", lambda d: "pallas_fused")
+    compiled = GS.sweep_mlr_round.lower(
+        *_round_shapes(one_chip, n, d, 5, 8, 5, BF16),
+        fit_intercept=True).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes >= n * d * 2

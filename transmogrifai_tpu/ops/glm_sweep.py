@@ -53,7 +53,9 @@ sweep"): one streamed kernel a loss, on top of the shared scan machinery.
    fold from it (glmnet-style pathwise continuation).
 3. `sweep_mlr_round` + `sweep_mlr_streamed_rounds` — the softmax loss: the
    same retirement loop (`_run_rounds`, written once for both drivers)
-   around the multinomial round program.
+   around the multinomial round program, whose pass over X is ONE Pallas
+   program on a backend that has Mosaic (`ops/pallas_softmax.py`) and an
+   XLA loop over row blocks elsewhere (`mlr_round_kernel`).
 
 `tol`/`max_iter` are traced scalars on every route (they only feed
 while-loop conds), so tuning them never recompiles.
@@ -89,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import glm as G
+from . import pallas_hist, pallas_softmax
 
 EPS = 1e-12
 
@@ -1206,13 +1209,16 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
 # step is cheap next to a Newton step, and a cold start keeps a lane's
 # iterates those of fit_softmax.
 
-# bytes of one [lanes * K, c] f32 logits block; the softmax holds a handful.
-# On the v5e the round is quickest while they all stay in VMEM (128 MiB):
-# 0.108 s an iteration at 16 MB (c = 8 192 for 16 lanes x 32 classes) and
-# 0.109 at 32 MB at 25M x 64; with four more blocks live (before the
-# softmax's broadcasts were fused, _mlr_round_core) 32 MB was past the edge,
-# 0.397 against 0.204, and 64 MB 0.751 (PERF.md, PR 25). 16 MB keeps a
-# factor 2 from it.
+# bytes of one [lanes * K, c] f32 logits block of the XLA row-block loops:
+# the metric pass's (validators._streamed_confusion, on every backend) and
+# the rounds' own where there is no Mosaic (_mlr_gradient_blocks: the CPU,
+# TMOG_NO_PALLAS, a 128-column matrix); on the chip the rounds run
+# ops/pallas_softmax.mlr_gradient, which sizes its own tiles. A softmax
+# holds a handful of such blocks, and on the v5e a loop is quickest while
+# they all stay in VMEM (128 MiB): the rounds' loop ran 0.108 s an
+# iteration at 16 MB (c = 8 192 for 16 lanes x 32 classes) and 0.109 at
+# 32 MB at 25M x 64, 0.751 at 64 MB (PERF.md, PR 25). 16 MB keeps a factor
+# 2 from the edge.
 _MLR_BLOCK_BYTES = 16 << 20
 
 
@@ -1321,59 +1327,95 @@ def mlr_gram_factor(X: jax.Array, w: jax.Array, fold_masks: jax.Array,
     return jnp.linalg.cholesky(A), hdiag
 
 
+def mlr_round_kernel(d: int) -> str:
+    """Which body a round's pass over a [n, d] matrix runs: "pallas_fused"
+    (ops/pallas_softmax.mlr_gradient, one Mosaic program a pass) where the
+    backend has one, as the tree kernels choose theirs
+    (pallas_hist.available()), else "xla_blocks" (_mlr_gradient_blocks). A
+    matrix of exactly 128 columns stays with the blocks: the chip keeps it
+    columns-minor, so X.T is not the layout it has and the program would
+    hold a transposed copy of it (compiled for a v5e: 6.4 GB at 25M rows;
+    64 and 100 columns live rows-minor and are read in place)."""
+    if d % 128 == 0 or not pallas_hist.available():
+        return "xla_blocks"
+    return "pallas_fused"
+
+
+def _mlr_gradient_blocks(X, y, w, fold_masks, sel, Bt_hi, Bt_lo, b0, mean,
+                         inv_std):
+    """The round's pass over X as an XLA loop over row blocks, for a backend
+    without Mosaic: (gA [Lb * K, d], g0A [Lb, K]), the sums over rows of
+    R x xs' and of R (pallas_softmax.mlr_gradient is the same arithmetic in
+    one program)."""
+    n, d = X.shape
+    Lb, K = b0.shape
+    f32 = jnp.float32
+    c = _mlr_row_block(Lb * K, n)
+    nb, take = _mlr_blocks(n, c, X.T, y, w, fold_masks)
+    classes = jnp.arange(K, dtype=f32)[:, None]                   # [K, 1]
+
+    def body(i, acc):
+        gA, g0A = acc
+        xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
+        xs = _standardized_t(xT, mean, inv_std, X.dtype)
+        # the barrier keeps the softmax three-dimensional: without it
+        # XLA sinks the reshape below the elementwise ops, and the
+        # per-(lane, row) and per-(class, row) operands can then only
+        # be broadcast by writing four [Lb, K, c] blocks (a third of
+        # the round's time on the v5e)
+        z = jax.lax.optimization_barrier(
+            mlr_logits_t(xs, Bt_hi, Bt_lo).reshape(Lb, K, c)) \
+            + b0[:, :, None]
+        e = jnp.exp(z - z.max(axis=1, keepdims=True))
+        # one reciprocal a (lane, row), not one division a class
+        P = e * (1.0 / e.sum(axis=1, keepdims=True))  # [Lb, K, c]
+        Y = (y_blk[None, :] == classes).astype(f32)  # [K, c]
+        # lane weights: exact for any w (sel is 0/1, the MXU's default
+        # pass would round w to bf16)
+        wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
+                        precision=jax.lax.Precision.HIGHEST)  # [Lb, c]
+        R = (P - Y[None]) * wl[:, None, :]
+        gA = gA + jnp.einsum(
+            "lkc,dc->lkd", R.astype(X.dtype), xs,
+            preferred_element_type=f32).reshape(Lb * K, d)
+        return gA, g0A + R.sum(axis=2)
+
+    return jax.lax.fori_loop(
+        0, nb, body,
+        (jnp.zeros((Lb * K, d), f32), jnp.zeros((Lb, K), f32)))
+
+
 def _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
                     chol, hdiag, iters_budget, tol, *, fit_intercept):
     """Up to `iters_budget` bound-optimisation steps for one compacted lane
     bucket, the multinomial twin of _round_core: sel [F, Lb] maps bucket
     lanes to folds (all-zero columns are inert padding), B0 [Lb, d, K] /
     b00 [Lb, K] carry standardized-space state between rounds, chol/hdiag
-    are the bucket's rows of mlr_gram_factor's result. The while cond
-    leaves as soon as every lane's delta clears tol. Returns (B, b0,
-    delta [Lb], iters)."""
+    are the bucket's rows of mlr_gram_factor's result. mlr_round_kernel(d)
+    names the body of the pass over X; the iteration around it is one. The
+    while cond leaves as soon as every lane's delta clears tol. Returns
+    (B, b0, delta [Lb], iters)."""
     n, d = X.shape
     Lb, _, K = B0.shape
     f32 = jnp.float32
     coef = 0.5 * (1.0 - 1.0 / K)
     wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
     wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
-    c = _mlr_row_block(Lb * K, n)
-    nb, take = _mlr_blocks(n, c, X.T, y, w, fold_masks)
-    classes = jnp.arange(K, dtype=f32)[:, None]                   # [K, 1]
     inv_std = 1.0 / std
+    fused = mlr_round_kernel(d) == "pallas_fused"
+    if fused:       # once a round program, outside the iteration
+        y_rows, w_rows = (pallas_softmax.dense_rows(v) for v in (y, w))
 
     def accumulate(B, b0):
         Bt_hi, Bt_lo = _split_low(
             B.transpose(0, 2, 1).reshape(Lb * K, d), X.dtype)
-
-        def body(i, acc):
-            gA, g0A = acc
-            xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
-            xs = _standardized_t(xT, mean, inv_std, X.dtype)
-            # the barrier keeps the softmax three-dimensional: without it
-            # XLA sinks the reshape below the elementwise ops, and the
-            # per-(lane, row) and per-(class, row) operands can then only
-            # be broadcast by writing four [Lb, K, c] blocks (a third of
-            # the round's time on the v5e)
-            z = jax.lax.optimization_barrier(
-                mlr_logits_t(xs, Bt_hi, Bt_lo).reshape(Lb, K, c)) \
-                + b0[:, :, None]
-            e = jnp.exp(z - z.max(axis=1, keepdims=True))
-            # one reciprocal a (lane, row), not one division a class
-            P = e * (1.0 / e.sum(axis=1, keepdims=True))  # [Lb, K, c]
-            Y = (y_blk[None, :] == classes).astype(f32)  # [K, c]
-            # lane weights: exact for any w (sel is 0/1, the MXU's default
-            # pass would round w to bf16)
-            wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
-                            precision=jax.lax.Precision.HIGHEST)  # [Lb, c]
-            R = (P - Y[None]) * wl[:, None, :]
-            gA = gA + jnp.einsum(
-                "lkc,dc->lkd", R.astype(X.dtype), xs,
-                preferred_element_type=f32).reshape(Lb * K, d)
-            return gA, g0A + R.sum(axis=2)
-
-        gA, g0A = jax.lax.fori_loop(
-            0, nb, body,
-            (jnp.zeros((Lb * K, d), f32), jnp.zeros((Lb, K), f32)))
+        if fused:
+            gA, g0A = pallas_softmax.mlr_gradient(
+                X.T, y_rows, w_rows, fold_masks, sel, Bt_hi, Bt_lo, b0,
+                mean, inv_std)
+        else:
+            gA, g0A = _mlr_gradient_blocks(
+                X, y, w, fold_masks, sel, Bt_hi, Bt_lo, b0, mean, inv_std)
         return gA.reshape(Lb, K, d).transpose(0, 2, 1), g0A
 
     def cond(state):
@@ -1407,10 +1449,15 @@ def sweep_mlr_round(X: jax.Array, y: jax.Array, w: jax.Array,
                     hdiag: jax.Array, iters_budget, tol, *,
                     fit_intercept: bool = True):
     """One retirement round of the multinomial sweep (see _mlr_round_core).
-    Compiled per (n, d, F, bucket, K) shape; iters_budget/tol are traced."""
+    Compiled per (n, d, F, bucket, K) shape; iters_budget/tol are traced.
+    The executable bakes mlr_round_kernel(d)'s answer in, so the Pallas
+    switch clears this function's cache."""
     return _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
                            std, chol, hdiag, iters_budget, tol,
                            fit_intercept=fit_intercept)
+
+
+pallas_hist.register_cache_consumer(sweep_mlr_round)
 
 
 def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
@@ -1453,13 +1500,15 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             X, w, fold_masks, mean, std, jnp.asarray(lane_fold),
             jnp.asarray(l2v), n_classes=K)
     st = state if state is not None else _new_round_state(L, d, K)
+    round_kernel = mlr_round_kernel(d)
 
     def run_round(idx, budget):
         k = len(idx)
         Lb = bucket_lanes(k)
         with _collector.trace_span(
                 f"mlr_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
-                active=int(k), iters_budget=int(budget), classes=K):
+                active=int(k), iters_budget=int(budget), classes=K,
+                kernel=round_kernel):
             with _collector.trace_span("round_prep", kind="host_step"):
                 # padding lanes: no fold (zero weights), lane idx[0]'s
                 # factor; B = 0 is their fixed point
@@ -1491,7 +1540,7 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     B = st["B"] / std_h[None, :, None]
     b0 = st["b0"] - (B * mean_h[None, :, None]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "mlr_rounds",
-            "driver": "resident", "classes": K,
+            "driver": "resident", "classes": K, "round_kernel": round_kernel,
             **_rounds_info(st, tol_f, max_iter), "gram_passes": F}
     # every full read of X by the route's programs: the round iterations,
     # the Gram pass, the two passes of the moments
