@@ -334,6 +334,34 @@ class TestZeroRecompilesUnderTraffic:
         assert m["latency"]["total"]["count"] >= 24
 
 
+class TestWatchWithCollectionOff:
+    """The tracker is always on (PR 33): the prewarm summary and the
+    post-warm-up recompile watch no longer need collector.enable()."""
+
+    def test_prewarm_counts_and_the_watch_fires(self, fitted):
+        import jax
+        import jax.numpy as jnp
+        assert not collector.enabled
+        model, rows, _ = fitted
+        x = jnp.ones(3)   # its own one-op program, before the warm-up
+        eng = ServingEngine(model, max_batch=8)
+        summary = eng.prewarm()
+        for key in ("compiles", "cache_hits"):
+            assert isinstance(summary[key], int) and summary[key] >= 0, key
+            assert eng.metrics()["prewarm"][key] == summary[key]
+        assert all(isinstance(b["compiles"], int)
+                   for b in summary["per_bucket"])
+        recs = [{k: v for k, v in r.items() if k != "y"} for r in rows[:5]]
+        eng.score_batch(recs)
+        assert eng.post_warmup_compiles == 0
+        # a shape that escaped the ladder: a program no cache can hold
+        # (its constant is this moment), compiled after warm-up
+        jax.jit(lambda v: v * time.time())(x).block_until_ready()
+        eng.score_batch(recs)
+        assert eng.post_warmup_compiles == 1
+        assert eng.metrics()["post_warmup_compiles"] == 1
+
+
 class TestMicroBatcher:
     def _engine_stub(self, fitted, delay=0.0):
         model, _, _ = fitted

@@ -13,6 +13,10 @@ user can switch with minimal relearning.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T0 = _time.time()   # the start-up ledger's t0 (utils/tracing.tracker)
+
 __version__ = "0.4.0"
 
 # Persistent XLA compilation cache: cold processes (examples, CI, serving
@@ -21,6 +25,11 @@ __version__ = "0.4.0"
 # directory in the checkout; see utils/platform.enable_compilation_cache.
 from .utils.platform import enable_compilation_cache as _ecc
 _ecc()
+
+# The process's one compile listener, always on: every trace, lowering,
+# cache load and compile from here on is in platform.startup_record().
+from .utils.tracing import tracker as _tracker
+_tracker.install()
 
 from . import types
 from .types import *  # noqa: F401,F403 — feature type hierarchy
@@ -42,3 +51,5 @@ from .data.vector import VectorColumnMetadata, VectorMetadata
 from . import dsl  # installs rich feature syntax (reference dsl/ implicits)
 
 __all__ = [n for n in dir() if not n.startswith("_")]
+
+_tracker.mark_import(_T0, _time.time())   # t1: keep this statement last

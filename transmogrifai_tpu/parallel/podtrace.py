@@ -60,6 +60,8 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+from ..utils.tracing import union_seconds
+
 __all__ = [
     "enabled", "active", "start", "finish", "beat", "pod_round",
     "compute", "collective", "ingest", "note_collective",
@@ -532,22 +534,6 @@ def _rank_rounds(spans: List[Dict[str, Any]]
     return out
 
 
-def _union_seconds(ivals: List[Tuple[float, float]]) -> float:
-    """Total length of the union of [t0, t1] intervals (overlapping
-    brackets — a tile span inside a pod_compute — must not double
-    count toward coverage)."""
-    total = 0.0
-    end = None
-    for t0, t1 in sorted(ivals):
-        if end is None or t0 > end:
-            total += max(t1 - t0, 0.0)
-            end = t1
-        elif t1 > end:
-            total += t1 - end
-            end = t1
-    return total
-
-
 def _median(vals: List[float]) -> float:
     if not vals:
         return 0.0
@@ -675,11 +661,11 @@ def merge_pod(pod_dir: str, out: Optional[str] = None,
                     coll_ivals.append(w)
                 if kind in _COVER_KINDS:
                     cover.append(w)
-            coll = _union_seconds(coll_ivals)
+            coll = union_seconds(coll_ivals)
             per_cell[(r["rank"], idx)] = {
                 "wall": wall, "collective": coll,
                 "compute": max(wall - coll, 0.0),
-                "coverage": (_union_seconds(cover) / wall
+                "coverage": (union_seconds(cover) / wall
                              if wall > 0 else 1.0)}
 
     # skew per round

@@ -21,11 +21,12 @@ lower/compile discipline pjit training uses (PAPERS arxiv 2204.06514):
   of the tileplane's donated carry — across the H2D boundary XLA owns
   the copy, so reuse on the host side is where allocation can actually
   be saved);
-- a RECOMPILE WATCH: after warmup the engine samples the PR 4
-  RecompileTracker after every batch; any compile that lands post-warmup
-  increments ``post_warmup_compiles`` and emits a ``serve_recompile``
-  event, which ``trace-report --check`` treats as a failure — "zero
-  recompiles under traffic" is pinned at runtime, not asserted.
+- a RECOMPILE WATCH: after warmup the engine samples the always-on
+  RecompileTracker after every batch, span collection on or off; any
+  compile that lands post-warmup increments ``post_warmup_compiles`` and
+  emits a ``serve_recompile`` event, which ``trace-report --check``
+  treats as a failure — "zero recompiles under traffic" is pinned at
+  runtime, not asserted.
 
 Observability: per-batch ``batch_assemble``/``device_score`` spans (span
 emission stops after TMOG_SERVE_SPAN_BUDGET batches so the in-memory
@@ -522,11 +523,9 @@ class ServingEngine:
                 self.post_warmup_compiles = 0
             summary = {"buckets": list(self.buckets),
                        "wall_s": round(wall, 4),
-                       "compiles": (self._warm_compiles - compiles0
-                                    if collector.enabled else None),
+                       "compiles": self._warm_compiles - compiles0,
                        "cache_hits": (tracing.tracker.total_cache_hits
-                                      - hits0 if collector.enabled
-                                      else None),
+                                      - hits0),
                        "compile_cache_dir": compile_cache_dir(),
                        "per_bucket": per_bucket}
             with self._stat_lock:
@@ -624,11 +623,11 @@ class ServingEngine:
                 path=path)
 
     def _check_recompiles(self) -> None:
-        """Post-warmup compile watch: with the tracker active (collection
-        enabled), any XLA compile after prewarm is booked and flagged —
-        the runtime pin behind the zero-recompiles-under-traffic claim."""
-        if not collector.enabled:
-            return
+        """Post-warmup compile watch: any true XLA compile after prewarm
+        is counted and warned about, collection on or off (the tracker is
+        always on) — the runtime pin behind the
+        zero-recompiles-under-traffic claim. The `serve_recompile` event
+        needs an attached event log."""
         with self._stat_lock:
             if not self.warm:
                 return

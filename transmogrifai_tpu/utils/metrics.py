@@ -563,25 +563,37 @@ class MetricsCollector:
                 # must land on the tree the span belongs to
                 trace = self.trace
                 sp = trace.open(name, kind, **attrs)
-        with _annotation(kind, name, attrs):
-            if sp is None:
-                yield None
-                return
-            if kind in self._EVENTED_KINDS:
-                self.event("span_start", name=name, kind=kind)
-            err: Optional[str] = None
-            try:
-                yield sp
-            except BaseException as e:
-                err = type(e).__name__
-                raise
-            finally:
-                trace.close(sp, error_type=err)
-                if kind in self._EVENTED_KINDS:
-                    self.event("span_end", name=name, kind=kind,
-                               wall_seconds=round(sp.duration, 6),
-                               error=err is not None,
-                               **({"error_type": err} if err else {}))
+        # a job's root span: its close ends the process's start-up ledger
+        # (tracing.tracker.te), collection on or off
+        job = kind in tracing.JOB_KINDS
+        if job:
+            tracing.tracker.job_enter()
+        ok = False
+        try:
+            with _annotation(kind, name, attrs):
+                if sp is None:
+                    yield None
+                else:
+                    if kind in self._EVENTED_KINDS:
+                        self.event("span_start", name=name, kind=kind)
+                    err: Optional[str] = None
+                    try:
+                        yield sp
+                    except BaseException as e:
+                        err = type(e).__name__
+                        raise
+                    finally:
+                        trace.close(sp, error_type=err)
+                        if kind in self._EVENTED_KINDS:
+                            self.event(
+                                "span_end", name=name, kind=kind,
+                                wall_seconds=round(sp.duration, 6),
+                                error=err is not None,
+                                **({"error_type": err} if err else {}))
+            ok = True
+        finally:
+            if job:
+                tracing.tracker.job_exit(ok)
 
     @contextlib.contextmanager
     def span(self, stage_name: str, uid: str, phase: str,

@@ -1,4 +1,5 @@
-"""Process start-up: platform forcing, the device table, the compile cache.
+"""Process start-up: platform forcing, the device table, the compile cache,
+the start-up record.
 
 Two ways the program runs. On a TPU host JAX picks the chip by default and
 one process owns it. Everywhere else (tests, the driver's multichip
@@ -77,6 +78,18 @@ def device_spec(device_kind: Optional[str] = None) -> Optional[DeviceSpec]:
             f"its published peaks (with source) to "
             f"transmogrifai_tpu/utils/platform.py")
     return spec
+
+
+def startup_record() -> dict:
+    """Where this process's start-up went, from the package's import to
+    its first finished job (`validate()`, `train()`, `score()`): the six
+    `startup_*_s` seconds that add up to `first_contact_s`, the count of
+    programs loaded or compiled, and the per-program rows (`fun_name`,
+    trace, lower, load or compile seconds, `cache_hit`), slowest first.
+    Always on; read it after one job. The ledger is utils/tracing's
+    `tracker` (RecompileTracker.startup_record has every field)."""
+    from .tracing import tracker
+    return tracker.startup_record()
 
 
 def compile_cache_dir() -> Optional[str]:

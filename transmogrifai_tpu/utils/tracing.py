@@ -6,10 +6,14 @@ here is a process-local span TREE (run -> workflow -> layer -> stage ->
 kernel / sweep-round) with the two costs that dominate JAX/TPU runs
 attributed per span:
 
-- **XLA recompiles** — a `jax.monitoring` listener counts every backend
-  compile and books it to the innermost open span, making claims like
-  PR 3's "bounded recompiles on the bucket ladder" runtime-verifiable
-  from any traced run;
+- **XLA recompiles** — the process's ONE `jax.monitoring` listener
+  (`tracker`, always on) keeps every trace, lowering, cache load and
+  compile by program: the start-up ledger behind
+  `utils/platform.startup_record()` and the counter the serving engine's
+  zero-recompile watch reads. While a tree is active it also books each
+  compile to the innermost open span, making claims like PR 3's "bounded
+  recompiles on the bucket ladder" runtime-verifiable from any traced
+  run;
 - **device-memory watermarks** — `Device.memory_stats()` sampled at span
   close (None-safe: CPU hosts report nothing and the attrs are omitted).
 
@@ -51,16 +55,28 @@ __all__ = [
     "requests_report_rc", "fmt_table",
 ]
 
-# the monitoring event one XLA backend compilation emits.
+# the monitoring events of one program's way to the device. jax 0.9.0
+# reports each as a duration, on the thread that did the work, the moment
+# it ends, with `fun_name`: tracing (`f`), lowering to MLIR, Mosaic bodies
+# included (`jit(f)`), and the backend compile (`jit(f)`).
 # NOTE (measured on this image's jaxlib): a persistent-compilation-cache
-# HIT emits it too — but a hit is PRECEDED by the cache-retrieval event
-# below, so the tracker classifies the pair and keeps a separate
-# total_cache_hits counter (total_compiles keeps counting both, byte-
-# compatible with every pre-serving consumer; true compiles =
-# total_compiles - total_cache_hits, what the serving engine's
-# post-warmup recompile watch reads)
+# HIT emits the compile event too — but a hit is PRECEDED by the
+# cache-retrieval event below, so the tracker classifies the pair and keeps
+# a separate total_cache_hits counter (total_compiles keeps counting both;
+# true compiles = total_compiles - total_cache_hits, what the serving
+# engine's post-warmup recompile watch reads)
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: span kinds whose outermost close is a finished job: the first one to
+#: close without an error ends the process's start-up (Te)
+JOB_KINDS = ("validate", "workflow")
+
+#: program events the ledger keeps with their intervals; past it only the
+#: counters run (a process that recompiles without end must not grow)
+_MAX_EVENTS = 1 << 16
 
 
 @dataclass
@@ -261,14 +277,91 @@ class TraceTree:
         return [s.to_json() for s in self.spans]
 
 
-# -- recompile attribution ---------------------------------------------------
+# -- the start-up ledger and recompile attribution -----------------------------
+
+_EVENT_KINDS = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+                _COMPILE_EVENT: "compile", _CACHE_HIT_EVENT: "hit"}
+
+
+def _program(fun_name: Any) -> str:
+    """One name a program: jax reports tracing under `f` and lowering and
+    the backend compile under `jit(f)` (`pmap(f)`)."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        name = name[name.index("(") + 1:-1]
+    return name
+
+
+def union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    """Total length of the union of [start, end] intervals: a trace nested
+    in a trace, a compile on a helper thread beside another, a tile span
+    inside a pod_compute bracket, is counted once."""
+    total, hi = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if e > hi:
+            total += max(e - max(s, hi), 0.0)
+            hi = e
+    return total
+
+
+def _process_start() -> Optional[float]:
+    """The process's own start on the time.time() clock, from
+    /proc/self/stat (field 22, ticks since boot) and /proc/uptime; None
+    where they cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+        return time.time() - age
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _program_rows(events: List[Tuple[str, float, float, str]]
+                  ) -> List[Dict[str, Any]]:
+    """Per-program seconds of the events, slowest first. A row holds the
+    program's OWN events: a trace nested in another program's trace is in
+    both rows (the totals of the record count it once)."""
+    rows: Dict[str, Dict[str, Any]] = {}
+    for kind, s, e, name in events:
+        row = rows.get(name)
+        if row is None:
+            row = rows[name] = {
+                "fun_name": name, "trace_s": 0.0, "lower_s": 0.0,
+                "load_s": 0.0, "compile_s": 0.0, "loads": 0, "compiles": 0}
+        if kind == "cache_load":
+            row["load_s"] += e - s
+            row["loads"] += 1
+        else:
+            row[kind + "_s"] += e - s
+            if kind == "compile":
+                row["compiles"] += 1
+    for row in rows.values():
+        row["cache_hit"] = row["loads"] > 0 and row["compiles"] == 0
+    return sorted(rows.values(), key=lambda r: -(
+        r["trace_s"] + r["lower_s"] + r["load_s"] + r["compile_s"]))
+
 
 class RecompileTracker:
-    """Books every XLA backend compile to the innermost open span.
+    """The process's start-up ledger and its one compile counter.
 
-    A `jax.monitoring` duration listener on
-    /jax/core/compile/backend_compile_duration (registered once, gated on
-    an active tree so an idle process pays one dict lookup per compile).
+    A `jax.monitoring` duration listener, registered once when the package
+    is imported and ALWAYS on (there is no switch: none of its events
+    fires in a warm steady state, so the hot path pays nothing). Every
+    trace, lowering and backend compile is kept by program name with its
+    interval on `time.time()` (the clock jax stamps them with), the
+    compile marked a persistent-cache LOAD or a true compile by the
+    retrieval event that precedes a load on the same thread. With
+    `t0`/`t1` (first and last statement of the package's `__init__`) and
+    `te` (the close of the first root job span) that is the whole of
+    `startup_record()`. `true_compiles` / `total_cache_hits` run for the
+    process's life; callers take differences.
+
+    While a collected run's tree is active (`collector.enable()`), each
+    compile is ALSO booked to the innermost open span (`compiles`,
+    `compile_seconds`, `cache_hits` attrs) — a view, not the record.
     jax is only consulted when something else already imported it (the
     module contract): in a process without it nothing compiles and there
     is nothing to count."""
@@ -277,18 +370,23 @@ class RecompileTracker:
         self._tree: Optional[TraceTree] = None
         self._listener_installed = False
         self.total_compiles = 0
-        self.total_compile_seconds = 0.0
         self.total_cache_hits = 0
         # a retrieval event and ITS compile event fire back-to-back on
         # the SAME thread, so the pairing flag is thread-local: compiles
         # interleaving from helper threads cannot steal another thread's
         # pending hit and misclassify a true compile as a cache load
         self._pending = threading.local()
-        self.by_program: Dict[str, int] = {}
-        # guards the counters + activation state (tmoglint THR001): the
-        # jax.monitoring listener fires on whatever thread compiles — a
-        # serving dispatcher and a prewarm can land compiles
-        # concurrently, and `total_compiles += 1` unlocked loses
+        # nesting of JOB_KINDS spans, a thread: te is the OUTERMOST close
+        self._jobs = threading.local()
+        self.t0 = self.t1 = time.time()   # mark_import() sets the real ones
+        self.te: Optional[float] = None
+        self._events: List[Tuple[str, float, float, str]] = []
+        self.events_dropped = 0
+        self.listener_seconds = 0.0
+        # guards the counters, the ledger and the activation state
+        # (tmoglint THR001): the jax.monitoring listener fires on whatever
+        # thread compiles — a serving dispatcher and a prewarm can land
+        # compiles concurrently, and `total_compiles += 1` unlocked loses
         # updates exactly where the zero-recompile contract reads them.
         # Ordering: _lock may be held while taking the tree's lock,
         # never the reverse
@@ -303,71 +401,158 @@ class RecompileTracker:
             return max(self.total_compiles - self.total_cache_hits, 0)
 
     # -- lifecycle ---------------------------------------------------------
+    def install(self) -> None:
+        """Register the listener (once), if jax is imported."""
+        with self._lock:
+            if not self._listener_installed \
+                    and sys.modules.get("jax") is not None:
+                import jax.monitoring
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_event)
+                self._listener_installed = True
+
+    def mark_import(self, t0: float, t1: float) -> None:
+        """The package's own import, [first, last] statement of its
+        `__init__` on time.time()."""
+        with self._lock:
+            self.t0, self.t1 = float(t0), float(t1)
+
     def activate(self, tree: TraceTree) -> None:
+        """Book compiles to `tree`'s innermost open span from now on. The
+        counts are the process's: nothing is reset."""
         with self._lock:
             self._tree = tree
-            self.total_compiles = 0
-            self.total_compile_seconds = 0.0
-            self.total_cache_hits = 0
-            self._pending = threading.local()
-            self.by_program = {}
-            if sys.modules.get("jax") is not None:
-                self._install_listener()
+            self.install()
 
     def deactivate(self) -> None:
         with self._lock:
             self._tree = None
 
-    def _install_listener(self) -> None:
-        if self._listener_installed:
+    # -- the first root job: where start-up ends ---------------------------
+    def job_enter(self) -> None:
+        self._jobs.depth = getattr(self._jobs, "depth", 0) + 1
+
+    def job_exit(self, ok: bool) -> None:
+        """Close of a JOB_KINDS span; the first OUTERMOST one that did not
+        raise is the process's first result."""
+        # a span finalised on another thread than it was entered on (an
+        # abandoned generator, an executor hand-off) finds no depth there:
+        # it must not raise inside a finally and mask the job's own error
+        depth = self._jobs.depth = max(
+            getattr(self._jobs, "depth", 1) - 1, 0)
+        if ok and depth == 0:
+            with self._lock:
+                if self.te is None:
+                    self.te = time.time()
+
+    # -- the listener --------------------------------------------------------
+    def _on_event(self, event: str, duration: float, **kw: Any) -> None:
+        kind = _EVENT_KINDS.get(event)
+        if kind is None:
             return
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-        self._listener_installed = True
-
-    def _on_event(self, event: str, duration: float, **_kw: Any) -> None:
+        t_in = time.perf_counter()
+        end = time.time()   # the event fires as the work ends
         with self._lock:
-            tree = self._tree
-            # the listener survives activate/deactivate cycles (jax has
-            # no public unregister)
-            if tree is None:
-                return
-            if event == _CACHE_HIT_EVENT:
-                # a persistent-cache retrieval fires immediately BEFORE
-                # its compile event (measured order, same thread); mark
-                # the pair so THIS thread's next compile books as a
-                # cache LOAD, not a true XLA compile
-                self._pending.cache_hit = True
-                return
-            if event != _COMPILE_EVENT:
-                return
-            hit = getattr(self._pending, "cache_hit", False)
-            self._pending.cache_hit = False
-            self.total_compiles += 1
-            self.total_compile_seconds += float(duration)
-            if hit:
-                self.total_cache_hits += 1
-            # the whole read-modify-write under BOTH locks (tracker then
-            # tree — the documented order): the listener may fire from
-            # helper threads, and an unlocked attrs update would race
-            # close()'s watermark update
-            with tree._lock:
-                sp = tree.current()
-                if sp is None:
+            try:
+                if kind == "hit":
+                    # a persistent-cache retrieval fires immediately
+                    # BEFORE its compile event (measured order, same
+                    # thread); mark the pair so THIS thread's next compile
+                    # books as a cache LOAD, not a true XLA compile
+                    self._pending.cache_hit = True
                     return
-                sp.attrs["compiles"] = \
-                    int(sp.attrs.get("compiles", 0)) + 1
-                sp.attrs["compile_seconds"] = round(
-                    float(sp.attrs.get("compile_seconds", 0.0))
-                    + float(duration), 4)
-                if hit:
-                    sp.attrs["cache_hits"] = \
-                        int(sp.attrs.get("cache_hits", 0)) + 1
-                self.by_program[sp.name] = \
-                    self.by_program.get(sp.name, 0) + 1
+                if kind == "compile":
+                    hit = getattr(self._pending, "cache_hit", False)
+                    self._pending.cache_hit = False
+                    self.total_compiles += 1
+                    if hit:
+                        self.total_cache_hits += 1
+                        kind = "cache_load"
+                    if self._tree is not None:
+                        self._book_to_span(self._tree, float(duration), hit)
+                if len(self._events) < _MAX_EVENTS:
+                    self._events.append((kind, end - float(duration), end,
+                                         _program(kw.get("fun_name", "?"))))
+                else:
+                    self.events_dropped += 1
+            finally:
+                # every path, the retrieval's too: the listener's own cost
+                self.listener_seconds += time.perf_counter() - t_in
+
+    @staticmethod
+    def _book_to_span(tree: TraceTree, duration: float, hit: bool) -> None:
+        # the whole read-modify-write under BOTH locks (tracker then
+        # tree — the documented order): the listener may fire from
+        # helper threads, and an unlocked attrs update would race
+        # close()'s watermark update
+        with tree._lock:
+            sp = tree.current()
+            if sp is None:
+                return
+            sp.attrs["compiles"] = int(sp.attrs.get("compiles", 0)) + 1
+            sp.attrs["compile_seconds"] = round(
+                float(sp.attrs.get("compile_seconds", 0.0)) + duration, 4)
+            if hit:
+                sp.attrs["cache_hits"] = \
+                    int(sp.attrs.get("cache_hits", 0)) + 1
+
+    # -- the record ----------------------------------------------------------
+    def startup_record(self) -> Dict[str, Any]:
+        """Where the process's start-up went, t0 to its first result (to
+        NOW while no root job has closed: `complete` False).
+
+        Six seconds that add up to `first_contact_s` by construction:
+        `startup_import_s` (t1 - t0), `startup_reach_device_s` (t1 to the
+        first program event: backend initialisation and whatever the
+        caller did before its first jitted call), then every instant from
+        there on booked ONCE — to `startup_compile_s` if a true backend
+        compile was running, else `startup_cache_load_s` if a cache load
+        was, else `startup_trace_lower_s` if a trace or a lowering was,
+        else `startup_run_s` (data made on the device, the first job's
+        execution). `startup_programs` counts the programs loaded or
+        compiled, eager one-op programs included. `programs` are the rows
+        up to the first result, `later_programs` what traced, loaded or
+        compiled after it (a warm server: the recompile's name)."""
+        now = time.time()
+        with self._lock:
+            t0, t1, te = self.t0, self.t1, self.te
+            events = list(self._events)
+            out: Dict[str, Any] = {
+                "true_compiles": self.true_compiles,
+                "cache_hits": self.total_cache_hits,
+                "events_dropped": self.events_dropped,
+                "listener_s": self.listener_seconds}
+        end = max(te if te is not None else now, t1)
+        early = [ev for ev in events if ev[1] < end]
+        clipped = [(k, max(s, t1), min(e, end)) for k, s, e, _ in early
+                   if e > t1]
+        first = min((s for _, s, _ in clipped), default=end)
+        compile_s = union_seconds([(s, e) for k, s, e in clipped
+                            if k == "compile"])
+        load_s = union_seconds([(s, e) for k, s, e in clipped
+                         if k in ("compile", "cache_load")]) - compile_s
+        busy = union_seconds([(s, e) for _, s, e in clipped])
+        start = _process_start()
+        out.update({
+            "complete": te is not None,
+            "before_import_s": None if start is None else t0 - start,
+            "first_contact_s": end - t0,
+            "startup_import_s": t1 - t0,
+            "startup_reach_device_s": first - t1,
+            "startup_trace_lower_s": busy - compile_s - load_s,
+            "startup_cache_load_s": load_s,
+            "startup_compile_s": compile_s,
+            "startup_run_s": (end - first) - busy,
+            "startup_programs": sum(k in ("compile", "cache_load")
+                                    for k, _, _, _ in early),
+            "programs": _program_rows(early),
+            "later_programs": _program_rows(
+                [ev for ev in events if ev[1] >= end])})
+        return out
 
 
-#: process-wide tracker the collector activates per enable()
+#: THE process-wide tracker: the package's __init__ installs it, the
+#: collector activates its span view per enable()
 tracker = RecompileTracker()
 
 
