@@ -44,11 +44,13 @@ def as_v5e(monkeypatch):
                         lambda kind=None: P.DEVICE_SPECS["TPU v5 lite"])
     monkeypatch.setattr(pallas_hist, "available", lambda: True)
     GS.sweep_mlr_round.clear_cache()
+    GS.sweep_glm_wide_round.clear_cache()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield
     GS.sweep_mlr_round.clear_cache()
+    GS.sweep_glm_wide_round.clear_cache()
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -82,13 +84,45 @@ def test_fused_multinomial_round_compiles_for_a_v5e(
 
 
 def test_a_128_column_matrix_would_be_copied(one_chip, as_v5e, monkeypatch):
-    """Why mlr_round_kernel leaves 128 columns to the XLA blocks: the chip
+    """Why round_kernel leaves 128 columns to the XLA blocks: the chip
     keeps such a matrix columns-minor, and the fused round would hold a
     transposed copy of it beside the original."""
     n, d = 4_000_000, 128
-    assert GS.mlr_round_kernel(d) == "xla_blocks"
-    monkeypatch.setattr(GS, "mlr_round_kernel", lambda d: "pallas_fused")
+    assert GS.round_kernel(d) == "xla_blocks"
+    monkeypatch.setattr(GS, "round_kernel", lambda d: "pallas_fused")
     compiled = GS.sweep_mlr_round.lower(
         *_round_shapes(one_chip, n, d, 5, 8, 5, BF16),
         fit_intercept=True).compile()
     assert compiled.memory_analysis().temp_size_in_bytes >= n * d * 2
+
+
+def _wide_round_shapes(one_chip, n, d, Lb, F, dtype):
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (S((n, d), dtype), S((n,), F32), S((n,), F32), S((F, n), F32),
+            S((F, Lb), F32), S((Lb,), F32), S((Lb,), F32), S((Lb, d), F32),
+            S((Lb,), F32), S((d,), F32), S((d,), F32), S((d, d), F32),
+            S((), F32), S((), jnp.int32), S((), F32))
+
+
+@pytest.mark.parametrize("n,d,Lb,F,tile", [
+    (786_432, 4_104, 64, 5, 2_048),     # sweep-glm-wide4k's round
+    (786_432, 4_104, 8, 5, 2_048),      # its smallest bucket
+    (100_003, 264, 16, 3, 2_048),       # ragged rows, a width off the
+                                        # 16-row tile
+    (100_003, 8_200, 128, 5, 1_024),    # a width that halves the tile, at
+                                        # the bucket the VMEM model is for
+], ids=["wide4k-bucket64", "wide4k-bucket8", "ragged", "half-tile"])
+def test_fused_wide_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
+                                             tile):
+    """The whole wide round program around the fused pass: Mosaic takes the
+    kernel at the tile `pallas_wide.tile_rows` chooses for the chip's VMEM,
+    and the program holds no second copy of X."""
+    from transmogrifai_tpu.ops import pallas_wide
+    assert GS.wide_round_kernel(d, BF16) == "pallas_fused"
+    assert pallas_wide.tile_rows(d) == tile
+    compiled = GS.sweep_glm_wide_round.lower(
+        *_wide_round_shapes(one_chip, n, d, Lb, F, BF16),
+        fit_intercept=True).compile()
+    assert "wide_gradient" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2

@@ -233,3 +233,30 @@ def test_warm_seed_starts_every_lane_at_the_seed():
     assert info["warm_seeded"]
     # one iteration from fold 0's answer stays beside it
     assert np.abs(Bs[0, 0] - B[0, 0]).max() < 5e-3
+
+
+def test_telemetry_and_span_name_the_body_of_the_pass(monkeypatch):
+    """Which body the rounds' pass over X ran is in the sweep's telemetry
+    (`round_kernel`) and on every round's span (`kernel`): the XLA blocks on
+    the CPU (tests/test_wide_fused_kernel.py steers the other answer);
+    `kernel` in the telemetry stays the route's name."""
+    from transmogrifai_tpu.utils.metrics import collector
+    monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+    X, y = _hashed(2048, 32, dtype="bfloat16")
+    val = CrossValidation(Evaluators.BinaryClassification.au_pr(),
+                          num_folds=2, seed=42)
+    collector.disable()     # whatever an earlier test file left behind
+    collector.enable("wide_round_kernel")
+    try:
+        val.validate([(OpLogisticRegression(max_iter=6, tol=1e-6),
+                       [{"reg_param": 0.1, "elastic_net_param": 0.5}])], X, y)
+        spans = [s for s in collector.trace.spans if s.kind == "sweep_round"]
+    finally:
+        collector.finish()
+        collector.disable()
+    tele = val.last_streamed_telemetry
+    assert tele["kernel"] == "wide_rounds"
+    assert tele["round_kernel"] == "xla_blocks"
+    assert len(spans) == 2      # 6 iterations in rounds of 5
+    assert all(s.name.startswith("glm_wide_round[") for s in spans)
+    assert {s.attrs["kernel"] for s in spans} == {"xla_blocks"}

@@ -192,7 +192,7 @@ def _streamed(regs=(0.01, 0.1)):
 
 
 def test_telemetry_and_span_name_the_xla_body_on_the_cpu():
-    assert GS.mlr_round_kernel(64) == "xla_blocks"
+    assert GS.round_kernel(64) == "xla_blocks"
     _, _, info, spans = _streamed()
     assert info["round_kernel"] == "xla_blocks"
     assert spans and {s.attrs["kernel"] for s in spans} == {"xla_blocks"}
@@ -204,9 +204,9 @@ def test_telemetry_and_span_name_the_fused_body_where_it_runs(backend):
     so in `round_kernel` and on every round's span; its answer is the XLA
     body's to float32 rounding. 128 columns stay with the blocks."""
     backend(True)
-    assert GS.mlr_round_kernel(64) == "pallas_fused"
-    assert GS.mlr_round_kernel(100) == "pallas_fused"
-    assert GS.mlr_round_kernel(128) == "xla_blocks"
+    assert GS.round_kernel(64) == "pallas_fused"
+    assert GS.round_kernel(100) == "pallas_fused"
+    assert GS.round_kernel(128) == "xla_blocks"
     B, b0, info, spans = _streamed()
     assert info["round_kernel"] == "pallas_fused"
     assert spans and {s.attrs["kernel"] for s in spans} == {"pallas_fused"}

@@ -277,3 +277,47 @@ class TestLifecycle:
         assert not [fn for fn in monitoring.get_event_time_span_listeners()
                     if isinstance(getattr(fn, "__self__", None),
                                   RecompileTracker)]
+
+
+@pytest.mark.parametrize("pinned,kernels_on,libtpu,starts", [
+    ("cpu", True, True, False),     # the tests, CPU serving
+    ("", True, True, True),         # a TPU host: nothing pinned
+    ("tpu", True, True, True),
+    ("tpu,cpu", True, True, True),
+    ("", False, True, False),       # TMOG_NO_PALLAS
+    ("", True, False, False),       # a laptop, a GPU host: no libtpu
+], ids=["cpu", "unpinned", "tpu", "tpu-then-cpu", "kernels-off",
+        "no-libtpu"])
+def test_kernel_modules_are_imported_on_a_thread_where_kernels_may_run(
+        monkeypatch, pinned, kernels_on, libtpu, starts):
+    """platform.prefetch_kernel_modules: jax's Pallas modules, on a daemon
+    thread whose interval the ledger keeps, unless JAX is pinned off the
+    TPU, the host has no libtpu or the kernels are off; a failing import
+    stays in the thread."""
+    import jax
+    from transmogrifai_tpu.ops import pallas_hist
+    from transmogrifai_tpu.utils.tracing import tracker
+    monkeypatch.setattr(type(jax.config), "jax_platforms",
+                        property(lambda self: pinned))
+    monkeypatch.setattr(pallas_hist, "_enabled", kernels_on)
+    monkeypatch.setattr(platform.importlib.util, "find_spec",
+                        lambda name: object() if libtpu else None)
+    monkeypatch.setattr(tracker, "kernel_import", None)
+    seen = []
+
+    def fake_import(name):
+        seen.append(name)
+        if name.endswith(".tpu"):
+            raise ImportError("a broken install")
+    monkeypatch.setattr(platform.importlib, "import_module", fake_import)
+    thread = platform.prefetch_kernel_modules()
+    assert (thread is not None) == starts
+    if starts:
+        thread.join(10)
+        assert thread.daemon and not thread.is_alive()
+        assert seen == ["jax.experimental.pallas",
+                        "jax.experimental.pallas.tpu"]
+        assert platform.startup_record()["kernel_import_s"] >= 0.0
+    else:
+        assert seen == []
+        assert platform.startup_record()["kernel_import_s"] is None

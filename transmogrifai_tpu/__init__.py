@@ -52,4 +52,9 @@ from . import dsl  # installs rich feature syntax (reference dsl/ implicits)
 
 __all__ = [n for n in dir() if not n.startswith("_")]
 
+# jax's Pallas modules, imported on a thread while the process goes on to
+# reach its device (a TPU host with the kernels on; no thread elsewhere).
+from .utils.platform import prefetch_kernel_modules as _pkm
+_pkm()
+
 _tracker.mark_import(_T0, _time.time())   # t1: keep this statement last

@@ -55,7 +55,7 @@ sweep"): one streamed kernel a loss, on top of the shared scan machinery.
    same retirement loop (`_run_rounds`, written once for both drivers)
    around the multinomial round program, whose pass over X is ONE Pallas
    program on a backend that has Mosaic (`ops/pallas_softmax.py`) and an
-   XLA loop over row blocks elsewhere (`mlr_round_kernel`).
+   XLA loop over row blocks elsewhere (`round_kernel`).
 
 `tol`/`max_iter` are traced scalars on every route (they only feed
 while-loop conds), so tuning them never recompiles.
@@ -91,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import glm as G
-from . import pallas_hist, pallas_softmax
+from . import pallas_hist, pallas_softmax, pallas_wide
 
 EPS = 1e-12
 
@@ -1327,18 +1327,35 @@ def mlr_gram_factor(X: jax.Array, w: jax.Array, fold_masks: jax.Array,
     return jnp.linalg.cholesky(A), hdiag
 
 
-def mlr_round_kernel(d: int) -> str:
-    """Which body a round's pass over a [n, d] matrix runs: "pallas_fused"
-    (ops/pallas_softmax.mlr_gradient, one Mosaic program a pass) where the
+def round_kernel(d: int, tile_rows: int = 128) -> str:
+    """Which body a round's pass over a [n, d] matrix runs, for both round
+    families that have a fused one (the multinomial and the wide binary
+    rounds): "pallas_fused" (ops/pallas_softmax.mlr_gradient,
+    ops/pallas_wide.wide_gradient: one Mosaic program a pass) where the
     backend has one, as the tree kernels choose theirs
-    (pallas_hist.available()), else "xla_blocks" (_mlr_gradient_blocks). A
-    matrix of exactly 128 columns stays with the blocks: the chip keeps it
-    columns-minor, so X.T is not the layout it has and the program would
-    hold a transposed copy of it (compiled for a v5e: 6.4 GB at 25M rows;
-    64 and 100 columns live rows-minor and are read in place)."""
-    if d % 128 == 0 or not pallas_hist.available():
+    (pallas_hist.available()), else "xla_blocks" (_mlr_gradient_blocks,
+    _wide_gradient_blocks). A matrix whose width is a multiple of 128 stays
+    with the blocks: the chip keeps it columns-minor, so X.T is not the
+    layout it has and the program would hold a transposed copy of it
+    (compiled for a v5e: 6.4 GB at 25M x 128; 64, 100 and 4 104 columns
+    live rows-minor and are read in place). `tile_rows` is how many rows of
+    X a grid step of the kernel can hold in VMEM at this width: under 128
+    there is no tile, and the blocks run (the multinomial kernel tiles any
+    width it is routed; pallas_wide.tile_rows)."""
+    if d % 128 == 0 or tile_rows < 128 or not pallas_hist.available():
         return "xla_blocks"
     return "pallas_fused"
+
+
+def wide_round_kernel(d: int, dtype) -> str:
+    """`round_kernel` for the wide rounds: the fused pass holds a [d, tile]
+    tile of X.T in VMEM, and it is written for the two-part contraction of
+    a bfloat16 matrix; a float32 matrix contracts at HIGHEST, which is
+    another program (compiled for a v5e it spills 133 MB of registers at
+    the tile bfloat16 runs), and stays with the blocks."""
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla_blocks"
+    return round_kernel(d, pallas_wide.tile_rows(d))
 
 
 def _mlr_gradient_blocks(X, y, w, fold_masks, sel, Bt_hi, Bt_lo, b0, mean,
@@ -1391,7 +1408,7 @@ def _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     bucket, the multinomial twin of _round_core: sel [F, Lb] maps bucket
     lanes to folds (all-zero columns are inert padding), B0 [Lb, d, K] /
     b00 [Lb, K] carry standardized-space state between rounds, chol/hdiag
-    are the bucket's rows of mlr_gram_factor's result. mlr_round_kernel(d)
+    are the bucket's rows of mlr_gram_factor's result. round_kernel(d)
     names the body of the pass over X; the iteration around it is one. The
     while cond leaves as soon as every lane's delta clears tol. Returns
     (B, b0, delta [Lb], iters)."""
@@ -1402,7 +1419,7 @@ def _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
     wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
     inv_std = 1.0 / std
-    fused = mlr_round_kernel(d) == "pallas_fused"
+    fused = round_kernel(d) == "pallas_fused"
     if fused:       # once a round program, outside the iteration
         y_rows, w_rows = (pallas_softmax.dense_rows(v) for v in (y, w))
 
@@ -1450,7 +1467,7 @@ def sweep_mlr_round(X: jax.Array, y: jax.Array, w: jax.Array,
                     fit_intercept: bool = True):
     """One retirement round of the multinomial sweep (see _mlr_round_core).
     Compiled per (n, d, F, bucket, K) shape; iters_budget/tol are traced.
-    The executable bakes mlr_round_kernel(d)'s answer in, so the Pallas
+    The executable bakes round_kernel(d)'s answer in, so the Pallas
     switch clears this function's cache."""
     return _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
                            std, chol, hdiag, iters_budget, tol,
@@ -1500,7 +1517,7 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             X, w, fold_masks, mean, std, jnp.asarray(lane_fold),
             jnp.asarray(l2v), n_classes=K)
     st = state if state is not None else _new_round_state(L, d, K)
-    round_kernel = mlr_round_kernel(d)
+    pass_body = round_kernel(d)
 
     def run_round(idx, budget):
         k = len(idx)
@@ -1508,7 +1525,7 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
         with _collector.trace_span(
                 f"mlr_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
                 active=int(k), iters_budget=int(budget), classes=K,
-                kernel=round_kernel):
+                kernel=pass_body):
             with _collector.trace_span("round_prep", kind="host_step"):
                 # padding lanes: no fold (zero weights), lane idx[0]'s
                 # factor; B = 0 is their fixed point
@@ -1540,7 +1557,7 @@ def sweep_mlr_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     B = st["B"] / std_h[None, :, None]
     b0 = st["b0"] - (B * mean_h[None, :, None]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "mlr_rounds",
-            "driver": "resident", "classes": K, "round_kernel": round_kernel,
+            "driver": "resident", "classes": K, "round_kernel": pass_body,
             **_rounds_info(st, tol_f, max_iter), "gram_passes": F}
     # every full read of X by the route's programs: the round iterations,
     # the Gram pass, the two passes of the moments
@@ -1578,6 +1595,11 @@ def sweep_logits_fold_t(xT: jax.Array, B_f: jax.Array, b0_f: jax.Array
 #      from them the exact gradient g of the lane's data term in the
 #      standardised coordinates. X is read as it is: centre and scale are
 #      applied to the coefficients going in and to the moments coming out.
+#      Where the backend has Mosaic the pass is ONE program that holds a
+#      tile of X in VMEM from the margins to the moments
+#      (ops/pallas_wide.wide_gradient); elsewhere an XLA loop over row
+#      blocks whose two contractions each read the block
+#      (`wide_round_kernel` says which).
 #   2. `_WIDE_INNER_STEPS` FISTA steps, from z = v = B and theta = 1, on the
 #      bound's model  g'(z - B) + kappa_l/2 (z - B)' Gs (z - B) + l2/2 |z|^2
 #      + l1 |z|_1  with step t_l = 1 / (kappa_l lam + l2), lam from
@@ -1706,23 +1728,15 @@ def wide_gram(X: jax.Array, w: jax.Array, mean: jax.Array,
     return Gs, lam
 
 
-def _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
-                     inv_std, Gs, lam, iters_budget, tol, *, fit_intercept):
-    """Up to `iters_budget` outer iterations of the wide solver (the
-    section comment above) for one compacted lane bucket: sel [F, Lb] maps
-    bucket lanes to folds (all-zero columns are inert padding), B0 [Lb, d] /
-    b00 [Lb] carry standardised-space state between rounds. The while cond
-    leaves as soon as every lane's delta clears tol. Returns (B, b0,
-    delta [Lb], iters)."""
+def _wide_gradient_blocks(X, y, w, fold_masks, sel, Braw, b0_raw):
+    """The wide round's pass over X as an XLA loop over row blocks, for a
+    backend without Mosaic: (gA [Lb, d], g0A [Lb]), the sums over rows of
+    R x' and of R in raw units (pallas_wide.wide_gradient is the same
+    arithmetic in one program that reads each block once: here the margins
+    and the moments are a fusion each, and each reads it)."""
     n, d = X.shape
     Lb = sel.shape[1]
     f32 = jnp.float32
-    hp = jax.lax.Precision.HIGHEST
-    wsum = jnp.maximum(w.sum(), EPS)
-    wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
-    wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
-    kappa = 0.25 / wsum_l
-    step = 1.0 / (kappa * lam + l2)
     c = _wide_row_block(d, n)
     # blocks are slices of X.T along its minor axis, rows on the lanes
     # (_mlr_blocks): a matrix whose width is no multiple of 128 lives
@@ -1731,23 +1745,52 @@ def _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
     # other layout first
     nb, take = _mlr_blocks(n, c, X.T, y, w, fold_masks)
 
+    def body(i, acc):
+        gA, g0A = acc
+        xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
+        eta = _wide_contract(Braw, xT) + b0_raw[:, None]    # [Lb, c]
+        # lane weights: exact for any w (sel is 0/1)
+        wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
+                        precision=jax.lax.Precision.HIGHEST)
+        R = (jax.nn.sigmoid(eta) - y_blk[None, :]) * wl
+        return (gA + _wide_contract(R, xT, over_rows=True),
+                g0A + R.sum(1))
+
+    return jax.lax.fori_loop(
+        0, nb, body, (jnp.zeros((Lb, d), f32), jnp.zeros(Lb, f32)))
+
+
+def _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
+                     inv_std, Gs, lam, iters_budget, tol, *, fit_intercept):
+    """Up to `iters_budget` outer iterations of the wide solver (the
+    section comment above) for one compacted lane bucket: sel [F, Lb] maps
+    bucket lanes to folds (all-zero columns are inert padding), B0 [Lb, d] /
+    b00 [Lb] carry standardised-space state between rounds.
+    wide_round_kernel(d, dtype) names the body of the pass over X; the
+    iteration around it is one. The while cond leaves as soon as every
+    lane's delta clears tol. Returns (B, b0, delta [Lb], iters)."""
+    d = X.shape[1]
+    Lb = sel.shape[1]
+    f32 = jnp.float32
+    hp = jax.lax.Precision.HIGHEST
+    wsum = jnp.maximum(w.sum(), EPS)
+    wsum_f = jnp.maximum((fold_masks * w[None, :]).sum(1), EPS)   # [F]
+    wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
+    kappa = 0.25 / wsum_l
+    step = 1.0 / (kappa * lam + l2)
+    fused = wide_round_kernel(d, X.dtype) == "pallas_fused"
+    if fused:       # once a round program, outside the iteration
+        rows = pallas_wide.side_rows(y, w, fold_masks)
+
     def accumulate(B, b0):
         Braw = B * inv_std[None, :]                     # [Lb, d] raw units
         b0_raw = b0 - (Braw * mean[None, :]).sum(1)
-
-        def body(i, acc):
-            gA, g0A = acc
-            xT, fresh, y_blk, w_blk, m_blk = take(i)    # m_blk [F, c]
-            eta = _wide_contract(Braw, xT) + b0_raw[:, None]    # [Lb, c]
-            # lane weights: exact for any w (sel is 0/1)
-            wl = jnp.matmul(sel.T, m_blk * (w_blk * fresh)[None, :],
-                            precision=hp)
-            R = (jax.nn.sigmoid(eta) - y_blk[None, :]) * wl
-            return (gA + _wide_contract(R, xT, over_rows=True),
-                    g0A + R.sum(1))
-
-        gA, g0A = jax.lax.fori_loop(
-            0, nb, body, (jnp.zeros((Lb, d), f32), jnp.zeros(Lb, f32)))
+        if fused:
+            gA, g0A = pallas_wide.wide_gradient(
+                X.T, rows, sel, *_two_parts(Braw, X.dtype), b0_raw)
+        else:
+            gA, g0A = _wide_gradient_blocks(X, y, w, fold_masks, sel, Braw,
+                                            b0_raw)
         # r' X -> the standardised columns' moments: centre, then scale
         g = (gA - mean[None, :] * g0A[:, None]) * inv_std[None, :]
         return g / wsum_l[:, None], g0A
@@ -1790,10 +1833,15 @@ def sweep_glm_wide_round(X: jax.Array, y: jax.Array, w: jax.Array,
                          Gs: jax.Array, lam: jax.Array, iters_budget, tol, *,
                          fit_intercept: bool = True):
     """One retirement round of the wide sweep (see _wide_round_core).
-    Compiled per (n, d, F, bucket) shape; iters_budget/tol are traced."""
+    Compiled per (n, d, F, bucket) shape; iters_budget/tol are traced. The
+    executable bakes wide_round_kernel's answer in, so the Pallas switch
+    clears this function's cache."""
     return _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
                             inv_std, Gs, lam, iters_budget, tol,
                             fit_intercept=fit_intercept)
+
+
+pallas_hist.register_cache_consumer(sweep_glm_wide_round)
 
 
 def sweep_glm_wide_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
@@ -1842,6 +1890,7 @@ def sweep_glm_wide_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                                lanes=L, cols=d, factorizations=0):
         Gs, lam = wide_gram(X, w, mean, inv_std)
     st = state if state is not None else _new_round_state(L, d)
+    pass_body = wide_round_kernel(d, X.dtype)
 
     warm_seeded = False
     if (warm_seed is not None and not st["retired"].any()
@@ -1860,7 +1909,8 @@ def sweep_glm_wide_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
         Lb = bucket_lanes(k)
         with _collector.trace_span(
                 f"glm_wide_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
-                active=int(k), iters_budget=int(budget)):
+                active=int(k), iters_budget=int(budget),
+                kernel=pass_body):
             with _collector.trace_span("round_prep", kind="host_step"):
                 sel = np.zeros((F, Lb), np.float32)
                 sel[lane_fold[idx], np.arange(k)] = 1.0
@@ -1893,7 +1943,8 @@ def sweep_glm_wide_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     B = st["B"] * inv_std_h[None, :]
     b0 = st["b0"] - (B * mean_h[None, :]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "wide_rounds",
-            "driver": "resident", **_rounds_info(st, tol_f, max_iter),
+            "driver": "resident", "round_kernel": pass_body,
+            **_rounds_info(st, tol_f, max_iter),
             "warm_seeded": warm_seeded, "cols": d,
             "padded_cols": wide_padded_cols(d),
             "gram_passes": 1, "factorizations": 0,

@@ -1,5 +1,5 @@
 """Process start-up: platform forcing, the device table, the compile cache,
-the start-up record.
+the kernel modules' import, the start-up record.
 
 Two ways the program runs. On a TPU host JAX picks the chip by default and
 one process owns it. Everywhere else (tests, the driver's multichip
@@ -10,9 +10,12 @@ backend initializes.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import logging
 import os
 import re
+import threading
+import time
 from typing import Optional
 
 _COUNT_FLAG = "--xla_force_host_platform_device_count"
@@ -90,6 +93,61 @@ def startup_record() -> dict:
     `tracker` (RecompileTracker.startup_record has every field)."""
     from .tracing import tracker
     return tracker.startup_record()
+
+
+#: what a process imports before its first Pallas kernel can be traced
+_KERNEL_MODULES = ("jax.experimental.pallas", "jax.experimental.pallas.tpu")
+
+
+def prefetch_kernel_modules() -> Optional[threading.Thread]:
+    """Import jax's Pallas modules on a daemon thread while the main thread
+    reaches its device; the thread, or None where none was started.
+
+    The import is 1.2 s on the v5e's host (0.8 s of it compiling modules to
+    bytecode, 0.7 s under the GPU flavour of Mosaic that `pallas_call`
+    imports on every backend) and the main thread pays it at its first
+    kernel (`pallas_hist.available()`) unless it is done by then. The
+    package starts this as the LAST act of its import, so nothing of the
+    package's own import runs beside it and what follows is the backend's
+    initialisation, 7-13 s that the main thread spends outside Python. The
+    thread's interval goes to the start-up ledger (`startup_record()`'s
+    `kernel_import_s`; it lies under `startup_reach_device_s`, not in the
+    six seconds): 1.7-1.9 s beside the main thread, and in 3 of 14 measured
+    runs as long as the backend took to come up (PERF.md, PR 34).
+
+    A main thread that asks for one of these modules meanwhile waits on that
+    module's import lock. Where two threads' imports wait on each other
+    Python lets one go on with a module half made; that takes a cycle
+    across the two, and there is none to have: jax is imported whole before
+    the thread starts, and nothing that jax's core or a backend imports
+    later reaches back into jax.experimental.pallas. An import that fails
+    here is left for the main thread's own to raise.
+
+    Nothing is started where the kernels cannot run: JAX pinned to a
+    platform other than the TPU (the tests, CPU serving), no libtpu
+    installed (a laptop, a GPU host), or `TMOG_NO_PALLAS`."""
+    import jax
+    from ..ops import pallas_hist
+    from .tracing import tracker
+
+    pinned = (jax.config.jax_platforms or "").strip().lower()
+    if (not pallas_hist.enabled() or (pinned and "tpu" not in pinned)
+            or importlib.util.find_spec("libtpu") is None):
+        return None
+
+    def load():
+        start = time.time()
+        try:
+            for name in _KERNEL_MODULES:
+                importlib.import_module(name)
+        except Exception:       # the main thread's own import reports it
+            pass
+        tracker.mark_kernel_import(start, time.time())
+
+    thread = threading.Thread(target=load, name="tmog-kernel-imports",
+                              daemon=True)
+    thread.start()
+    return thread
 
 
 def compile_cache_dir() -> Optional[str]:

@@ -380,6 +380,8 @@ class RecompileTracker:
         self._jobs = threading.local()
         self.t0 = self.t1 = time.time()   # mark_import() sets the real ones
         self.te: Optional[float] = None
+        # platform.prefetch_kernel_modules' thread, [start, end]
+        self.kernel_import: Optional[Tuple[float, float]] = None
         self._events: List[Tuple[str, float, float, str]] = []
         self.events_dropped = 0
         self.listener_seconds = 0.0
@@ -416,6 +418,11 @@ class RecompileTracker:
         `__init__` on time.time()."""
         with self._lock:
             self.t0, self.t1 = float(t0), float(t1)
+
+    def mark_kernel_import(self, start: float, end: float) -> None:
+        """The interval of platform.prefetch_kernel_modules' thread."""
+        with self._lock:
+            self.kernel_import = (float(start), float(end))
 
     def activate(self, tree: TraceTree) -> None:
         """Book compiles to `tree`'s innermost open span from now on. The
@@ -512,10 +519,17 @@ class RecompileTracker:
         execution). `startup_programs` counts the programs loaded or
         compiled, eager one-op programs included. `programs` are the rows
         up to the first result, `later_programs` what traced, loaded or
-        compiled after it (a warm server: the recompile's name)."""
+        compiled after it (a warm server: the recompile's name).
+
+        Beside the six, `kernel_import_s`: how long the thread that imports
+        jax's Pallas modules took (platform.prefetch_kernel_modules; None
+        where none ran or it has not ended). It starts at t1 and runs
+        beside the main thread, under `startup_reach_device_s` where the
+        backend takes longer to come up than the import."""
         now = time.time()
         with self._lock:
             t0, t1, te = self.t0, self.t1, self.te
+            kernel_import = self.kernel_import
             events = list(self._events)
             out: Dict[str, Any] = {
                 "true_compiles": self.true_compiles,
@@ -537,6 +551,8 @@ class RecompileTracker:
             "complete": te is not None,
             "before_import_s": None if start is None else t0 - start,
             "first_contact_s": end - t0,
+            "kernel_import_s": None if kernel_import is None
+            else kernel_import[1] - kernel_import[0],
             "startup_import_s": t1 - t0,
             "startup_reach_device_s": first - t1,
             "startup_trace_lower_s": busy - compile_s - load_s,
