@@ -1,0 +1,306 @@
+"""A sweep over a matrix that lives ROW-SHARDED on the device: validate()
+reads the mesh from where X lives (no `mesh=`), the fold masks are the
+one-device program's bit for bit, the rounds and the held-out metric pass
+run on the mesh, nothing passes through the host, and the spans and the
+telemetry say which layout ran. On 4 of conftest's 8 host devices."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from transmogrifai_tpu.automl.tuning import validators as V
+from transmogrifai_tpu.automl.tuning.folds import (
+    FOLD_ASSIGNMENT_VERSION, assign_fold_masks, assign_fold_masks_sharded,
+    fold_key,
+)
+from transmogrifai_tpu.automl.tuning.validators import (
+    CrossValidation, TrainValidationSplit,
+)
+from transmogrifai_tpu.evaluators.evaluators import Evaluators
+from transmogrifai_tpu.models.glm import OpLogisticRegression
+from transmogrifai_tpu.ops import glm_sweep as GS
+from transmogrifai_tpu.parallel.mesh import (
+    batch_sharding, make_mesh, replicated, resident_row_mesh, sharded_along,
+)
+from transmogrifai_tpu.utils.metrics import collector
+
+N, D, FOLDS = 4096, 8, 3
+GRIDS = [dict(reg_param=r, elastic_net_param=a)
+         for r in (1e-4, 1e-2, 0.3) for a in (0.0, 0.5)]
+# fold metrics of the mesh sweep against the one-device sweep: the same
+# rows, products and bins, float32 sums in another order
+TOL_LAYOUT = 1e-5
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return make_mesh(n_batch=4, n_model=1, devices=jax.devices()[:4])
+
+
+@pytest.fixture(scope="module")
+def data(mesh):
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(N, D)).astype(np.float32)
+    beta = rng.normal(size=D) / np.sqrt(D)
+    y = (rng.random(N) < 1 / (1 + np.exp(-X @ beta))).astype(np.float32)
+    return {"X": X, "y": y,
+            "X1": jnp.asarray(X, jnp.bfloat16), "y1": jnp.asarray(y),
+            "Xs": jax.device_put(jnp.asarray(X, jnp.bfloat16),
+                                 batch_sharding(mesh, 2)),
+            "ys": jax.device_put(y, batch_sharding(mesh, 1))}
+
+
+@pytest.fixture
+def small_routes(monkeypatch):
+    """The toy size takes the routes the chip takes at 128M rows."""
+    monkeypatch.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+    monkeypatch.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
+
+
+def _sweep(X, y, spans=None, **kw):
+    val = CrossValidation(Evaluators.BinaryClassification.au_pr(),
+                          num_folds=FOLDS, seed=42,
+                          sweep_dtype=jnp.bfloat16, **kw)
+    models = [(OpLogisticRegression(max_iter=15, standardization=False),
+               [dict(g) for g in GRIDS])]
+    if spans is None:
+        return val, val.validate(models, X, y)
+    collector.enable("mesh_test")
+    try:
+        best = val.validate(models, X, y)
+        spans.extend((s.kind, s.name, dict(s.attrs))
+                     for s in collector.trace.spans)
+    finally:
+        collector.finish()
+        collector.disable()
+    return val, best
+
+
+# -- the way in ---------------------------------------------------------------
+
+def test_resident_row_mesh_reads_only_a_row_sharded_device_array(mesh, data):
+    assert resident_row_mesh(data["Xs"]) == mesh
+    assert resident_row_mesh(data["ys"]) == mesh
+    assert resident_row_mesh(data["X"]) is None          # host
+    assert resident_row_mesh(data["X1"]) is None         # one device
+    assert resident_row_mesh(jax.device_put(
+        data["X"], replicated(mesh))) is None
+    assert resident_row_mesh(jax.device_put(
+        data["X"], sharded_along(mesh, 1, 2))) is None   # columns
+    one = make_mesh(n_batch=1, n_model=1, devices=jax.devices()[:1])
+    assert resident_row_mesh(jax.device_put(
+        data["X"], batch_sharding(one, 2))) is None
+
+
+# -- folds ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("shards", [2, 4])
+@pytest.mark.parametrize("stratify", [False, True])
+@pytest.mark.parametrize("spec", [dict(n_folds=5),
+                                  dict(n_folds=1, val_fraction=0.25)],
+                         ids=["kfold", "split"])
+def test_fold_masks_on_a_mesh_are_the_one_device_masks(data, shards, stratify,
+                                                       spec):
+    m = make_mesh(n_batch=shards, n_model=1, devices=jax.devices()[:shards])
+    y1 = data["y1"] if stratify else None
+    ys = jax.device_put(data["y"], batch_sharding(m, 1)) if stratify else None
+    one = assign_fold_masks(fold_key(7), y1, n=N, stratify=stratify, **spec)
+    on_mesh = assign_fold_masks_sharded(m, fold_key(7), ys, n=N,
+                                        stratify=stratify, **spec)
+    assert FOLD_ASSIGNMENT_VERSION == 2
+    assert on_mesh.sharding.is_equivalent_to(sharded_along(m, 1, 2), 2)
+    # no chip holds the whole [F, n] block
+    assert {s.data.shape for s in on_mesh.addressable_shards} == \
+        {(one.shape[0], N // shards)}
+    assert np.array_equal(np.asarray(one), np.asarray(on_mesh))
+
+
+@pytest.mark.parametrize("cls,kw", [
+    (CrossValidation, dict(num_folds=5)),
+    (TrainValidationSplit, dict(train_ratio=0.75))])
+def test_validator_masks_do_not_depend_on_the_layout(mesh, data, cls, kw):
+    val = cls(Evaluators.BinaryClassification.au_pr(), seed=11,
+              stratify=True, **kw)
+    assert np.array_equal(
+        np.asarray(val.device_fold_masks(data["y1"])),
+        np.asarray(val.device_fold_masks(data["ys"], mesh=mesh)))
+
+
+# -- the sweep ------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def sweeps(data):
+    """The same sweep on one device and on the resident sharded matrix."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(V, "STREAMED_SWEEP_MIN_ROWS", 0)
+        mp.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
+        spans1, spans4 = [], []
+        one = _sweep(data["X1"], data["y1"], spans1)
+        four = _sweep(data["Xs"], data["ys"], spans4)
+    return {"one": one, "four": four, "spans1": spans1, "spans4": spans4}
+
+
+def test_mesh_sweep_answers_as_the_one_device_sweep(sweeps):
+    (_, b1), (_, b4) = sweeps["one"], sweeps["four"]
+    assert b1.best_grid == b4.best_grid
+    for a, b in zip(b1.validated, b4.validated):
+        assert a.grid == b.grid and a.route == b.route == "streamed"
+        assert np.max(np.abs(np.array(a.fold_metrics)
+                             - np.array(b.fold_metrics))) <= TOL_LAYOUT
+
+
+def test_telemetry_says_which_layout_ran(sweeps):
+    t1 = sweeps["one"][0].last_streamed_telemetry
+    t4 = sweeps["four"][0].last_streamed_telemetry
+    assert (t1["shards"], t1["psums"], t1["psum_bytes"],
+            t1["rows_per_shard"]) == (1, 0, 0, N)
+    assert t4["shards"] == 4 and t4["rows_per_shard"] == N // 4
+    assert t4["eval_route"] == t1["eval_route"] == "heldout_once"
+    # one collective an iteration and one a round program, one a chunk of
+    # the metric pass
+    assert t4["psums"] == t4["data_passes"] + t4["glm_rounds"] + t4["passes"]
+    rounds = sum(it * GS.round_psum_bytes(Lb, D) for it, Lb in
+                 zip(t4["iters_per_round"], t4["bucket_sizes"]))
+    assert t4["psum_bytes"] == rounds + 2 * FOLDS * 6 * V.RANK_METRIC_BINS * 4
+    for key in ("glm_rounds", "data_passes", "padded_lane_passes",
+                "bucket_sizes"):
+        assert t1[key] == t4[key]       # the same retirement history
+
+
+def _attrs(spans, kind, name):
+    return [a for k, n, a in spans if k == kind and n.startswith(name)]
+
+
+@pytest.mark.parametrize("which,shards,route", [
+    ("spans1", 1, "one_device"), ("spans4", 4, "resident_sharded")])
+def test_spans_name_the_layout(sweeps, which, shards, route):
+    spans = sweeps[which]
+    assert _attrs(spans, "validate", "CrossValidation")[0]["shards"] == shards
+    assert _attrs(spans, "validate_phase", "fold_assign")[0]["shards"] \
+        == shards
+    place = _attrs(spans, "validate_phase", "device_place")[0]
+    assert place["route"] == route and place["h2d_bytes"] == 0
+    assert _attrs(spans, "sweep_fit", "glm_streamed")[0]["shards"] == shards
+    rounds = _attrs(spans, "sweep_round", "glm_round")
+    assert rounds and all(r["shards"] == shards for r in rounds)
+    assert all(r["psums"] == int(shards > 1) for r in rounds)
+    assert all(r["psum_bytes"] == int(shards > 1)
+               * GS.round_psum_bytes(r["bucket"], D) for r in rounds)
+    ev = _attrs(spans, "sweep_eval", "glm_streamed_eval")[0]
+    assert ev["shards"] == shards and ev["eval_route"] == "heldout_once"
+
+
+def test_validate_never_brings_the_rows_to_the_host(data, small_routes,
+                                                    monkeypatch):
+    """np.asarray of a sharded array reads `ArrayImpl._value` (and
+    `__array__` where Python sees the call): neither may be asked for
+    anything with a row dimension."""
+    from jax._src.array import ArrayImpl
+    seen = []
+    value, array = ArrayImpl.__dict__["_value"], ArrayImpl.__array__
+
+    def spy_value(self):
+        seen.append(tuple(self.shape))
+        return value.fget(self)
+
+    def spy_array(self, *a, **kw):
+        seen.append(tuple(self.shape))
+        return array(self, *a, **kw)
+    monkeypatch.setattr(ArrayImpl, "_value", property(spy_value))
+    monkeypatch.setattr(ArrayImpl, "__array__", spy_array)
+    _sweep(data["Xs"], data["ys"])
+    assert seen, "the spy saw no fetch at all"
+    assert not [s for s in seen if N in s or N // 4 in s], seen
+
+
+def test_host_weights_and_masks_join_the_resident_matrix(data, mesh,
+                                                         small_routes):
+    val = CrossValidation(Evaluators.BinaryClassification.au_pr(),
+                          num_folds=FOLDS, seed=42, sweep_dtype=jnp.bfloat16)
+    masks = val.fold_masks(data["y"])
+    spans = []
+    collector.enable("mesh_test")
+    try:
+        best = val.validate(
+            [(OpLogisticRegression(max_iter=15, standardization=False),
+              [dict(g) for g in GRIDS])], data["Xs"], data["ys"],
+            w=np.ones(N, np.float32), masks=masks)
+        spans.extend((s.kind, s.name, dict(s.attrs))
+                     for s in collector.trace.spans)
+    finally:
+        collector.finish()
+        collector.disable()
+    place = _attrs(spans, "validate_phase", "device_place")[0]
+    assert place["route"] == "resident_sharded"
+    assert place["h2d_bytes"] == masks.nbytes + 4 * N
+    assert val.last_streamed_telemetry["shards"] == 4
+    assert all(v.route == "streamed" for v in best.validated)
+
+
+def test_host_arrays_with_a_mesh_keep_the_host_put_branch(data, mesh, sweeps,
+                                                          small_routes):
+    spans = []
+    val, best = _sweep(data["X"], data["y"], spans, mesh=mesh)
+    place = _attrs(spans, "validate_phase", "device_place")[0]
+    assert place["route"] == "host_put" and place["h2d_bytes"] > 0
+    assert _attrs(spans, "validate_phase", "fold_assign")[0]["shards"] == 1
+    assert val.last_streamed_telemetry["shards"] == 4
+    assert val.last_streamed_telemetry["eval_route"] == "heldout_once"
+    for a, b in zip(sweeps["one"][1].validated, best.validated):
+        assert np.max(np.abs(np.array(a.fold_metrics)
+                             - np.array(b.fold_metrics))) <= TOL_LAYOUT
+
+
+def test_a_sharded_matrix_sweeps_on_its_mesh_through_the_selector(
+        data, small_routes):
+    from transmogrifai_tpu.automl.selector import ModelSelector
+    val = CrossValidation(Evaluators.BinaryClassification.au_pr(),
+                          num_folds=FOLDS, seed=42, sweep_dtype=jnp.bfloat16)
+    sel = ModelSelector(val, None, [
+        (OpLogisticRegression(max_iter=15, standardization=False),
+         [dict(g) for g in GRIDS[:2]])])
+    sel.fit_arrays(data["Xs"], data["ys"])
+    assert val.last_streamed_telemetry["shards"] == 4
+    assert val.last_streamed_telemetry["rows_per_shard"] == N // 4
+
+
+# -- the programs -----------------------------------------------------------------
+
+@pytest.mark.parametrize("bucket", [8, 32])
+def test_round_program_issues_the_collectives_it_declares(mesh, bucket):
+    """ONE psum call inside the iteration loop, of the four accumulators
+    together, and one outside it (the fold weight sums). jax lowers a
+    psum of four arrays to four all_reduce ops side by side, which the
+    TPU's compiler merges into one (compiled for a described v5e:2x2 the
+    program's text holds 2 all-reduces: PERF.md section 6, PR 35)."""
+    S = jax.ShapeDtypeStruct
+    args = (S((N, D), jnp.bfloat16), S((N,), jnp.float32),
+            S((N,), jnp.float32), S((FOLDS, N), jnp.float32),
+            S((FOLDS, bucket), jnp.float32), S((bucket,), jnp.float32),
+            S((bucket,), jnp.float32), S((bucket, D), jnp.float32),
+            S((bucket,), jnp.float32), S((D,), jnp.float32),
+            S((D,), jnp.float32), S((), jnp.int32), S((), jnp.float32))
+    text = GS._sharded_round_fn(mesh, "logistic", True).lower(*args).as_text()
+    assert text.count("all_reduce") == 1 + 4
+    assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
+
+
+def test_sharded_programs_keep_the_names_traces_find_them_by(mesh):
+    S = jax.ShapeDtypeStruct
+    ev = V._sharded_eval_heldout_fn(mesh, "au_pr", 64)
+    text = ev.lower(S((N, D), jnp.bfloat16), S((N,), jnp.float32),
+                    S((N,), jnp.float32), S((FOLDS, N), jnp.float32),
+                    S((FOLDS, 6, D), jnp.float32),
+                    S((FOLDS, 6), jnp.float32)).as_text()
+    assert "jit__streamed_eval_heldout_sharded" in text
+    # ONE psum call of the two classes' counts (two ops side by side in
+    # jax's lowering, one all-reduce once the TPU's compiler is done)
+    assert text.count("all_reduce") == 2
+    from transmogrifai_tpu.automl.tuning import folds
+    fold = folds._sharded_fold_masks_fn(mesh, N, 5, None, False)
+    text = fold.lower(S((2,), jnp.uint32)).as_text()
+    assert "jit_assign_fold_masks_sharded" in text
+    assert "all_reduce" not in text and "all_gather" not in text
+    rounds = GS._sharded_round_fn(mesh, "logistic", True)
+    assert rounds.__wrapped__.__name__ == "sweep_glm_round_sharded" \
+        or "sweep_glm_round_sharded" in repr(rounds)

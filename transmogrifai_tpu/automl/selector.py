@@ -182,8 +182,14 @@ class ModelSelector(PredictorEstimator):
                 else PreparedData(indices=np.arange(len(train_idx)),
                                   weights=np.ones(len(train_idx), np.float32)))
         use_idx = train_idx[prep.indices]
-        Xt, yt = X[use_idx], y[use_idx]
-        wt = w[use_idx] * prep.weights
+        if len(use_idx) == n and np.array_equal(use_idx, train_idx):
+            # every row, in order: no gather, so a matrix that lives on
+            # the device (row-sharded over a mesh) reaches validate() as
+            # it is and sweeps where it lives
+            Xt, yt, wt = X, y, w * prep.weights
+        else:
+            Xt, yt = X[use_idx], y[use_idx]
+            wt = w[use_idx] * prep.weights
         if prep.label_map and any(k != v for k, v in prep.label_map.items()):
             yt = _remap_labels(yt, prep.label_map)
 

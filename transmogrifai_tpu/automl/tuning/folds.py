@@ -14,9 +14,17 @@ the labels when stratified) alone. The random words come from the Threefry
 follows the global `jax_threefry_partitionable` flag) and every other step
 is integer arithmetic or a stable sort, so the CPU backend and the chip
 draw the same folds and a checkpoint replays on either.
+
+On a mesh (`assign_fold_masks_sharded`) the SAME assignment comes back
+sharded on rows: the random words are a function of the row id alone, so
+every chip forms the one global order for itself (the labels, when
+stratified, all-gathered first), keeps the folds of its own rows and
+builds only its `[F, rows / shards]` block of the mask. Bit for bit the
+one-device masks, on any number of shards.
 """
 from __future__ import annotations
 
+import functools
 import math
 from functools import partial
 from typing import Optional
@@ -115,7 +123,8 @@ def _shuffled_rows(key, n: int, *leading):
     """(*leading, row ids 0..n-1) sorted by the `leading` keys, then by 64
     seeded random bits a row: ONE stable sort (two Threefry words; at 25M
     rows two rows share all 64 with probability 2e-5, and stability then
-    decides). With no leading key the row ids come back in a uniformly
+    decides). Row i's two words are the Threefry-2x32 block of the counter
+    pair (i, n + i) under the key words `fold_key(seed)`. With no leading key the row ids come back in a uniformly
     random order; with the labels, grouped by class and in random order
     within each."""
     words = threefry_2x32((key[0], key[1]),
@@ -123,6 +132,37 @@ def _shuffled_rows(key, n: int, *leading):
     out = lax.sort((*leading, words[0], words[1], lax.iota(jnp.int32, n)),
                    num_keys=len(leading) + 2, is_stable=True)
     return (*out[:len(leading)], out[-1])
+
+
+def _fold_of(key, y, n: int, n_folds: int, val_fraction: Optional[float],
+             stratify: bool):
+    """int32[n] fold that holds each row out (k-fold), or 0 = held out /
+    1 = train (single split): assign_fold_masks' rule, before the mask."""
+    split = val_fraction is not None
+    if not stratify:
+        # the sorted row ids ARE a uniformly random permutation: read as
+        # "row i has rank ids[i]", no inverse needed
+        rank, = _shuffled_rows(key, n)
+        return (rank >= int(round(n * val_fraction))).astype(jnp.int32) \
+            if split else rank % n_folds
+    cls, rows = _shuffled_rows(key, n, y)
+    pos = lax.iota(jnp.int32, n)
+    first = jnp.concatenate([jnp.ones((1,), bool), cls[1:] != cls[:-1]])
+    start = lax.cummax(jnp.where(first, pos, 0))
+    rank = pos - start
+    if split:
+        last = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
+        end = lax.cummin(jnp.where(last, pos, n - 1), reverse=True)
+        n_val = _round_count_share(end - start + 1, val_fraction)
+        fold_sorted = (rank >= n_val).astype(jnp.int32)
+    else:
+        fold_sorted = rank % n_folds
+    return lax.sort_key_val(rows, fold_sorted)[1]
+
+
+def _train_masks(fold_of, n_folds: int, split: bool):
+    folds = lax.iota(jnp.int32, 1 if split else n_folds)
+    return (fold_of[None, :] != folds[:, None]).astype(jnp.float32)
 
 
 @partial(jax.jit,
@@ -142,26 +182,45 @@ def assign_fold_masks(key, y, *, n: int, n_folds: int,
     sorted by class then random bits, rank = position less the class's
     segment start), so the balance holds per class; the per-row result
     returns to row order by a second sort on the row ids."""
-    split = val_fraction is not None
-    if not stratify:
-        # the sorted row ids ARE a uniformly random permutation: read as
-        # "row i has rank ids[i]", no inverse needed
-        rank, = _shuffled_rows(key, n)
-        fold_of = (rank >= int(round(n * val_fraction))).astype(jnp.int32) \
-            if split else rank % n_folds
-    else:
-        cls, rows = _shuffled_rows(key, n, y)
-        pos = lax.iota(jnp.int32, n)
-        first = jnp.concatenate([jnp.ones((1,), bool), cls[1:] != cls[:-1]])
-        start = lax.cummax(jnp.where(first, pos, 0))
-        rank = pos - start
-        if split:
-            last = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
-            end = lax.cummin(jnp.where(last, pos, n - 1), reverse=True)
-            n_val = _round_count_share(end - start + 1, val_fraction)
-            fold_sorted = (rank >= n_val).astype(jnp.int32)
-        else:
-            fold_sorted = rank % n_folds
-        fold_of = lax.sort_key_val(rows, fold_sorted)[1]
-    folds = lax.iota(jnp.int32, 1 if split else n_folds)
-    return (fold_of[None, :] != folds[:, None]).astype(jnp.float32)
+    return _train_masks(
+        _fold_of(key, y, n, n_folds, val_fraction, stratify), n_folds,
+        val_fraction is not None)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_fold_masks_fn(mesh, n: int, n_folds: int,
+                           val_fraction: Optional[float], stratify: bool):
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.mesh import (
+        BATCH_AXIS, build_shard_map, mesh_batch_count,
+    )
+    n_local = n // mesh_batch_count(mesh)
+
+    def assign_fold_masks_sharded(key, *y_local):
+        y = lax.all_gather(y_local[0], BATCH_AXIS, tiled=True) \
+            if stratify else None
+        own = lax.dynamic_slice_in_dim(
+            _fold_of(key, y, n, n_folds, val_fraction, stratify),
+            lax.axis_index(BATCH_AXIS) * n_local, n_local)
+        return _train_masks(own, n_folds, val_fraction is not None)
+
+    return jax.jit(build_shard_map(
+        assign_fold_masks_sharded, mesh,
+        in_specs=(P(),) + (P(BATCH_AXIS),) * int(stratify),
+        out_specs=P(None, BATCH_AXIS)))
+
+
+def assign_fold_masks_sharded(mesh, key, y, *, n: int, n_folds: int,
+                              val_fraction: Optional[float] = None,
+                              stratify: bool = False):
+    """assign_fold_masks on a mesh: the same [F, n] masks bit for bit,
+    sharded on rows over the batch axis (`sharded_along(mesh, 1, 2)`), no
+    chip holding more than its [F, n / shards] block. Every chip sorts the
+    whole order (the words depend on the row id alone; `y`, sharded on
+    rows and read only when `stratify`, is all-gathered), so the program
+    issues no collective unless stratified. `n` divides by the shards, as
+    the rows of a sharded array do."""
+    fn = _sharded_fold_masks_fn(mesh, n, n_folds, val_fraction,
+                                bool(stratify))
+    return fn(key, y) if stratify else fn(key)

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -29,7 +29,7 @@ from ...models.prediction import make_prediction_column
 from ...ops import metrics_ops as M
 from ...stages.params import ParamMap
 from ...utils.metrics import collector
-from .folds import assign_fold_masks, fold_key
+from .folds import assign_fold_masks, assign_fold_masks_sharded, fold_key
 
 
 def _phase(name: str, **attrs: Any):
@@ -257,6 +257,20 @@ def _heldout_scores(X, masks, Bc, b0c):
                              jnp.zeros((Gc, n), jnp.float32)), fold_of
 
 
+def _eval_heldout_core(X, y, w, masks, Bc, b0c, *, metric, rank_bins,
+                       axis_name=None):
+    """The held-out-once metric pass over the rows at hand: all of them,
+    or under `axis_name` a chip's own, whose [F, Gc, bins] counts are
+    summed over that mesh axis (ONE psum of both classes' counts) before
+    the metric is taken from them."""
+    scores, fold_of = _heldout_scores(X, masks, Bc, b0c)
+    vw = (1.0 - jnp.min(masks, axis=0)) * w
+    counts = M.heldout_cum_counts_lanes(scores, y, vw, fold_of, Bc.shape[0],
+                                        rank_bins)
+    counts = counts if axis_name is None else jax.lax.psum(counts, axis_name)
+    return M.RANK_METRIC_FROM_COUNTS[metric](*counts)
+
+
 @partial(jax.jit, static_argnames=("metric", "rank_bins"))
 def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, *, metric, rank_bins):
     """[F, Gc] binned rank metrics of EVERY fold's grid chunk in one pass
@@ -266,11 +280,29 @@ def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, *, metric, rank_bins):
     per-fold route's up to float32 summation order. The program's name
     keeps `streamed_eval`: traces and the benchmark find the metric pass
     by it."""
-    scores, fold_of = _heldout_scores(X, masks, Bc, b0c)
-    vw = (1.0 - jnp.min(masks, axis=0)) * w
-    fn = {"au_pr": M.au_pr_heldout_lanes,
-          "au_roc": M.au_roc_heldout_lanes}[metric]
-    return fn(scores, y, vw, fold_of, Bc.shape[0], rank_bins)
+    return _eval_heldout_core(X, y, w, masks, Bc, b0c, metric=metric,
+                              rank_bins=rank_bins)
+
+
+@lru_cache(maxsize=None)
+def _sharded_eval_heldout_fn(mesh, metric, rank_bins):
+    """_streamed_eval_heldout on a mesh: every chip scores and bins its
+    OWN rows once (the Pallas histogram kernel sees local rows), the
+    [F, Gc, bins] counts are summed over the batch axis in one psum and
+    every chip takes the same metrics from the sum."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.mesh import BATCH_AXIS, build_shard_map
+
+    def _streamed_eval_heldout_sharded(X, y, w, masks, Bc, b0c):
+        return _eval_heldout_core(X, y, w, masks, Bc, b0c, metric=metric,
+                                  rank_bins=rank_bins, axis_name=BATCH_AXIS)
+
+    return jax.jit(build_shard_map(
+        _streamed_eval_heldout_sharded, mesh,
+        in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
+                  P(None, BATCH_AXIS), P(None, None, None), P(None, None)),
+        out_specs=P(None, None)))
 
 
 # the metric programs' executables bake the lanes-kernel (pallas) choice
@@ -278,6 +310,9 @@ def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, *, metric, rank_bins):
 from ...ops import pallas_hist as _pallas_hist  # noqa: E402
 _pallas_hist.register_cache_consumer(_streamed_eval)
 _pallas_hist.register_cache_consumer(_streamed_eval_heldout)
+# (the mesh form's programs hang off an lru_cache: the kill switch drops it)
+_sharded_eval_heldout_fn.clear_cache = _sharded_eval_heldout_fn.cache_clear
+_pallas_hist.register_cache_consumer(_sharded_eval_heldout_fn)
 
 
 @partial(jax.jit,
@@ -352,8 +387,25 @@ class Validator:
         # inside the jitted sweep then becomes an ICI psum inserted by
         # GSPMD; program text is unchanged (SURVEY §2.9 translation of
         # Spark partitioning). Rows pad to the axis size with zero weights,
-        # which every kernel treats as absent.
+        # which every kernel treats as absent. A matrix that already lives
+        # row-sharded on the device needs no mesh here: validate() reads
+        # the mesh from where X lives (parallel/mesh.resident_row_mesh).
         self.mesh = mesh
+        # the mesh X of the current validate() call lives row-sharded on
+        # (None: a host array, one device) — _sweep_mesh, _resident
+        self._resident_mesh = None
+
+    @property
+    def _sweep_mesh(self):
+        """The mesh the current validate() call sweeps on: `mesh`, or the
+        resident matrix's own."""
+        return self.mesh if self.mesh is not None else self._resident_mesh
+
+    @property
+    def _resident(self) -> bool:
+        """Does X already live row-sharded on the sweep's mesh?"""
+        return self._resident_mesh is not None \
+            and self._resident_mesh == self._sweep_mesh
 
     # -- folds -------------------------------------------------------------
     def _fold_spec(self) -> Dict[str, Any]:
@@ -361,15 +413,22 @@ class Validator:
         validator holds rows out: `n_folds`, `val_fraction`."""
         raise NotImplementedError
 
-    def device_fold_masks(self, y) -> jax.Array:
+    def device_fold_masks(self, y, mesh: Optional[Any] = None) -> jax.Array:
         """[F, n] float32 train-membership masks (1=train, 0=validation) on
         the device: one dispatch of folds.assign_fold_masks, a function of
         (seed, rows, folds or ratio, and `y` when stratified) alone —
-        the same for a host `y` and a device `y`, on any backend."""
-        return assign_fold_masks(
-            fold_key(self.seed),
-            jnp.asarray(y, jnp.float32) if self.stratify else None,
-            n=len(y), stratify=self.stratify, **self._fold_spec())
+        the same for a host `y` and a device `y`, on any backend and, with
+        `mesh`, sharded on rows over it (folds.assign_fold_masks_sharded:
+        no chip holds the whole block)."""
+        spec = dict(n=len(y), stratify=self.stratify, **self._fold_spec())
+        y = jnp.asarray(y, jnp.float32) if self.stratify else None
+        if mesh is None:
+            return assign_fold_masks(fold_key(self.seed), y, **spec)
+        if y is not None:
+            from ...parallel.mesh import batch_sharding
+            y = jax.device_put(y, batch_sharding(mesh, 1))
+        return assign_fold_masks_sharded(mesh, fold_key(self.seed), y,
+                                         **spec)
 
     def fold_masks(self, y) -> np.ndarray:
         """The same masks on the host: what validate() ran on, bit for
@@ -386,12 +445,20 @@ class Validator:
         (leakage-free in-fold DAG refits, OpValidator.applyDAG:228) feeds
         one fold-fitted matrix at a time with that fold's single mask, so
         its inner (model x grid) sweep rides the same device routes."""
+        from ...parallel.mesh import (
+            batch_sharding, mesh_batch_count, resident_row_mesh,
+        )
         n_folds = int(masks.shape[0] if masks is not None
                       else getattr(self, "num_folds", 1))
+        # where X lives decides where the sweep runs: a matrix row-sharded
+        # over a mesh sweeps on that mesh, nothing of it through the host
+        self._resident_mesh = resident = resident_row_mesh(X)
+        shards = mesh_batch_count(self._sweep_mesh)
         with collector.trace_span(
                 type(self).__name__, kind="validate", rows=len(y),
                 folds=n_folds, models=len(models),
-                grid_points=sum(max(len(g), 1) for _, g in models)):
+                grid_points=sum(max(len(g), 1) for _, g in models),
+                shards=shards):
             n_classes = 2
             if problem_type == "multiclass":
                 # before the fold program is dispatched: the scalar's
@@ -400,11 +467,14 @@ class Validator:
                     n_classes = label_classes(y)
             with _phase("fold_assign",
                         route="device" if masks is None else "external",
-                        rows=len(y), folds=n_folds, stratify=self.stratify):
+                        rows=len(y), folds=n_folds, stratify=self.stratify,
+                        shards=shards if self._resident else 1):
                 if w is None:
-                    w = jnp.ones(len(y), jnp.float32)
+                    w = jnp.ones(len(y), jnp.float32, device=batch_sharding(
+                        resident, 1) if self._resident else None)
                 if masks is None:
-                    masks = self.device_fold_masks(y)
+                    masks = self.device_fold_masks(
+                        y, mesh=resident if self._resident else None)
                     self._external_mask_tag = ""
                     # k folds or one split: a row is held out at most once
                     self._heldout_once = True
@@ -512,7 +582,7 @@ class Validator:
         multiclass = problem_type == "multiclass"
         if multiclass:
             if getattr(est, "streamed_multiclass_loss", None) is None \
-                    or self.mesh is not None:
+                    or self._sweep_mesh is not None:
                 return False
         elif getattr(est, "streamed_loss", None) is None \
                 or problem_type not in ("binary", "regression"):
@@ -561,7 +631,8 @@ class Validator:
         holds, one device (a mesh keeps the feature-tiled rounds, which
         have a shard_map form and stop at 1 792 columns)."""
         from ...ops.glm_sweep import TRI_MAX_D
-        return loss == "logistic" and d > TRI_MAX_D and self.mesh is None
+        return loss == "logistic" and d > TRI_MAX_D \
+            and self._sweep_mesh is None
 
     # -- shared helpers for the device-sweep paths --------------------------
     def _margin_threshold(self, est) -> float:
@@ -582,21 +653,30 @@ class Validator:
         if self.grid_chunk is not None:
             return max(1, int(self.grid_chunk))
         lane_bytes = max(n * d * itemsize, 1)
-        if self.mesh is not None:  # rows shard: per-chip lane cost shrinks
-            from ...parallel.mesh import BATCH_AXIS
+        if self._sweep_mesh is not None:
+            # rows shard: per-chip lane cost shrinks
+            from ...parallel.mesh import mesh_batch_count
             lane_bytes = max(
-                lane_bytes // max(self.mesh.shape.get(BATCH_AXIS, 1), 1), 1)
+                lane_bytes // mesh_batch_count(self._sweep_mesh), 1)
         lanes = max(int(SWEEP_LANE_BUDGET_BYTES / lane_bytes), 1)
         # cap: total vmap lanes also scale XLA compile time — past ~8 grid
         # points per program the compile cost outweighs the dispatch savings
         return int(np.clip(lanes // max(n_folds, 1), 1, min(n_grids, 8)))
 
     def _device_arrays(self, X, y, w, masks, dtype):
-        """Place sweep arrays on device; with a mesh, rows pad to the batch
-        axis (zero weight = inert everywhere: fits see mask*w, metrics see
+        """Place sweep arrays on device. A matrix that already lives
+        row-sharded on the sweep's mesh stays where it is (route
+        `resident_sharded`: y, w and the masks join it in its layout,
+        nothing is fetched, padded or copied through the host); host
+        arrays with a mesh (`host_put`) pad rows to the batch axis (zero
+        weight = inert everywhere: fits see mask*w, metrics see
         (1-mask)*w) and shard across it."""
-        with _phase("device_place", h2d_bytes=_host_bytes(X, y, w, masks)):
-            if self.mesh is None:
+        mesh = self._sweep_mesh
+        route = "one_device" if mesh is None else \
+            "resident_sharded" if self._resident else "host_put"
+        with _phase("device_place", h2d_bytes=_host_bytes(X, y, w, masks),
+                    route=route):
+            if mesh is None:
                 return (jnp.asarray(X, dtype), jnp.asarray(y, jnp.float32),
                         jnp.asarray(w, jnp.float32),
                         jnp.asarray(masks, jnp.float32))
@@ -604,26 +684,37 @@ class Validator:
                 BATCH_AXIS, batch_sharding, mesh_is_multiprocess,
                 pad_rows_to_multiple, sharded_along,
             )
-            if mesh_is_multiprocess(self.mesh):
+            if self._resident:
+                # device_put of an array already in the layout is the
+                # array itself; a host y or w goes straight to its shards
+                def put(a, sharding):
+                    return jax.device_put(
+                        jnp.asarray(a, jnp.float32)
+                        if isinstance(a, jax.Array)
+                        else np.asarray(a, np.float32), sharding)
+                rows = batch_sharding(mesh, 1)
+                return (jnp.asarray(X, dtype), put(y, rows), put(w, rows),
+                        put(masks, sharded_along(mesh, 1, 2)))
+            if mesh_is_multiprocess(mesh):
                 # SPMD pod sweep: X/y/w/masks hold THIS PROCESS's rows; each
                 # block lands as the process's batch-axis stripe of a global
                 # array (same pad semantics as the single-host branch below:
                 # X repeats its last row, weights pad 0 = inert, masks pad 1)
                 from ...parallel import multihost as MH
-                layout = MH.row_layout(np.asarray(X).shape[0], self.mesh)
+                layout = MH.row_layout(np.asarray(X).shape[0], mesh)
                 return (
                     MH.host_local_block(
                         np.asarray(np.asarray(X), jnp.dtype(dtype)),
-                        self.mesh, layout, pad_value=None),
+                        mesh, layout, pad_value=None),
                     MH.host_local_block(np.asarray(y, np.float32),
-                                        self.mesh, layout),
+                                        mesh, layout),
                     MH.host_local_block(np.asarray(w, np.float32),
-                                        self.mesh, layout),
+                                        mesh, layout),
                     MH.host_local_block(np.asarray(masks, np.float32),
-                                        self.mesh, layout, pad_value=1.0,
+                                        mesh, layout, pad_value=1.0,
                                         axis=1),
                 )
-            nb = self.mesh.shape[BATCH_AXIS]
+            nb = mesh.shape[BATCH_AXIS]
             # X pads by repeating the last real row (pad_value=None): tree
             # quantile binning is unweighted, so synthetic values would shift
             # bin edges. Labels/weights pad with zeros — inert in every
@@ -639,11 +730,11 @@ class Validator:
             put = jax.device_put
             return (
                 put(np.asarray(X, jnp.dtype(dtype)),
-                    batch_sharding(self.mesh, 2)),
-                put(np.asarray(y, np.float32), batch_sharding(self.mesh, 1)),
-                put(np.asarray(w, np.float32), batch_sharding(self.mesh, 1)),
+                    batch_sharding(mesh, 2)),
+                put(np.asarray(y, np.float32), batch_sharding(mesh, 1)),
+                put(np.asarray(w, np.float32), batch_sharding(mesh, 1)),
                 put(np.asarray(masks, np.float32),
-                    sharded_along(self.mesh, 1, 2)),
+                    sharded_along(mesh, 1, 2)),
             )
 
     def _sweep_path(self, base: str) -> str:
@@ -655,10 +746,10 @@ class Validator:
         estimators) must not replay one fold's cells into another."""
         if self._external_mask_tag:
             base = f"{base}:masks{self._external_mask_tag}"
-        if self.mesh is None:
+        if self._sweep_mesh is None:
             return base
         from ...parallel.mesh import BATCH_AXIS
-        return f"{base}:mesh{self.mesh.shape.get(BATCH_AXIS, 1)}"
+        return f"{base}:mesh{self._sweep_mesh.shape.get(BATCH_AXIS, 1)}"
 
     def _cell_bookkeeping(self, est, grids, X, y, metric, n_folds,
                           path: str = ""):
@@ -880,10 +971,10 @@ class Validator:
         if loss == "squared":
             fk = {k: v for k, v in fit_kwargs.items() if k != "loss"}
             mi, tl = fk.pop("max_iter"), fk.pop("tol")
-            if self.mesh is not None:
+            if self._sweep_mesh is not None:
                 B, b0, giters = GS.sweep_glm_squared_gram_sharded(
-                    self.mesh, Xd, yd, wd, md, regs_p, alphas_p, mi, tl,
-                    **fk)
+                    self._sweep_mesh, Xd, yd, wd, md, regs_p, alphas_p, mi,
+                    tl, **fk)
             else:
                 B, b0, giters = GS.sweep_glm_squared_gram(
                     Xd, yd, wd, md, regs_p, alphas_p, mi, tl, **fk)
@@ -915,7 +1006,7 @@ class Validator:
         # know
         B, b0, info = GS.sweep_glm_streamed_rounds(
             Xd, yd, wd, md, np.asarray(regs_p), np.asarray(alphas_p),
-            mesh=self.mesh, state=state, on_round=on_round,
+            mesh=self._sweep_mesh, state=state, on_round=on_round,
             warm_seed=seed_t, **fit_kwargs)
         return jnp.asarray(B), jnp.asarray(b0), info, rc
 
@@ -955,8 +1046,11 @@ class Validator:
                 if base.has_param("standardization") else True)
             if multiclass:
                 fit_kwargs["n_classes"] = int(n_classes)
+            from ...parallel.mesh import mesh_batch_count, mesh_is_multiprocess
+            mesh = self._sweep_mesh
+            shards = mesh_batch_count(mesh)
             fit_attrs = dict(folds=int(masks.shape[0]), grids=len(pending),
-                             classes=int(n_classes))
+                             classes=int(n_classes), shards=shards)
             if not multiclass and self._wide_rounds(fit_kwargs["loss"],
                                                     int(X.shape[1])):
                 from ...ops.glm_sweep import wide_padded_cols
@@ -981,26 +1075,44 @@ class Validator:
                 idx = list(range(s, min(s + chunk, len(pending))))
                 chunks.append(
                     (idx, jnp.asarray(idx + [idx[-1]] * (chunk - len(idx)))))
-            # disjoint held-out sets and a lane-batched binned metric on one
-            # device: every row is scored and binned ONCE for all folds;
-            # otherwise fold by fold over the whole matrix
+            # disjoint held-out sets and a lane-batched binned metric: every
+            # row is scored and binned ONCE for all folds, on a mesh by the
+            # chip that holds it; otherwise (and across processes) fold by
+            # fold over the whole matrix
             heldout_once = (
-                self._heldout_once and self.mesh is None and _lanes_metric_fn(
-                    metric, problem_type, rank_bins) is not None)
+                self._heldout_once and not mesh_is_multiprocess(mesh)
+                and _lanes_metric_fn(metric, problem_type,
+                                     rank_bins) is not None)
+            if heldout_once and mesh is not None:
+                eval_fn = _sharded_eval_heldout_fn(mesh, metric, rank_bins)
+                # one psum of both classes' [F, Gc, bins] counts a chunk
+                eval_psums = len(chunks)
+                eval_psum_bytes = eval_psums * 2 * F * chunk * rank_bins * 4
+            else:
+                eval_fn = partial(_streamed_eval_heldout, metric=metric,
+                                  rank_bins=rank_bins)
+                eval_psums = eval_psum_bytes = 0
             eval_info = {
                 "eval_route": "heldout_once" if heldout_once else "per_fold",
-                "passes": len(chunks) * (1 if heldout_once else F)}
-            self._record_sweep_telemetry(est, dict(sweep_info, **eval_info))
+                "passes": len(chunks) * (1 if heldout_once else F),
+                "shards": shards}
+            # the layout the sweep ran on, and the collectives it declares
+            # (the rounds' own and the metric pass's; a per_fold pass on a
+            # mesh leaves its collectives to GSPMD, uncounted)
+            self._record_sweep_telemetry(est, dict(
+                sweep_info, **eval_info,
+                rows_per_shard=int(Xd.shape[0]) // shards,
+                psums=int(sweep_info.get("psums", 0)) + eval_psums,
+                psum_bytes=int(sweep_info.get("psum_bytes", 0))
+                + eval_psum_bytes))
             out = np.empty((F, len(pending)), np.float64)
             with collector.trace_span(
                     f"glm_streamed_eval:{type(est).__name__}",
                     kind="sweep_eval", cells=len(pending),
                     classes=int(n_classes), **eval_info):
                 if heldout_once:
-                    vals = [_streamed_eval_heldout(
-                        Xd, yd, wd, md, B[:, padded], b0[:, padded],
-                        metric=metric, rank_bins=rank_bins)
-                        for _, padded in chunks]
+                    vals = [eval_fn(Xd, yd, wd, md, B[:, padded],
+                                    b0[:, padded]) for _, padded in chunks]
                     # the chunks' [F, Gc] values wait on the device for
                     # ONE fetch a sweep; the tail's padding comes last
                     with collector.trace_span("metric_fetch",
@@ -1016,7 +1128,7 @@ class Validator:
                                 thr_d, metric=metric,
                                 problem_type=problem_type,
                                 n_classes=n_classes, rank_bins=rank_bins,
-                                chunk=chunk, use_lanes=self.mesh is None)
+                                chunk=chunk, use_lanes=mesh is None)
                             with collector.trace_span("metric_fetch",
                                                       kind="host_step"):
                                 out[f, idx] = np.asarray(vals)[:len(idx)]
@@ -1056,7 +1168,7 @@ class Validator:
         ckpt, keys, results = self._cell_bookkeeping(
             est, grids, X, y, metric, masks.shape[0],
             path=self._sweep_path(
-                "mask_folds:host" if (self.mesh is None
+                "mask_folds:host" if (self._sweep_mesh is None
                                       and est._host_route())
                 else "mask_folds"))
         pending = [gi for gi in range(len(grids)) if gi not in results]
@@ -1080,7 +1192,7 @@ class Validator:
             # mesh runs keep the vmapped metric (pallas must not consume
             # row-sharded operands)
             lanes_fn = _lanes_metric_fn(metric, problem_type, rank_bins) \
-                if self.mesh is None else None
+                if self._sweep_mesh is None else None
 
             @jax.jit
             def fold_metrics(scores, y_, w_, m_, t_):
@@ -1126,9 +1238,9 @@ class Validator:
                 return int(est.get_param("max_depth")) \
                     if est.has_param("max_depth") else 0
             n_shards = 1
-            if self.mesh is not None:
+            if self._sweep_mesh is not None:
                 from ...parallel.mesh import BATCH_AXIS
-                n_shards = max(self.mesh.shape.get(BATCH_AXIS, 1), 1)
+                n_shards = max(self._sweep_mesh.shape.get(BATCH_AXIS, 1), 1)
             try:
                 from ...planner.plan import grid_fuse_enabled
                 plan_fuse_on = grid_fuse_enabled(
@@ -1155,7 +1267,7 @@ class Validator:
                 with _phase("tree_bin", bins=int(bins or 0),
                             configs=len(group)):
                     ctx = est.copy(**grids[group[0]]).mask_sweep_context(
-                        Xd, n_valid=X.shape[0], mesh=self.mesh)
+                        Xd, n_valid=X.shape[0], mesh=self._sweep_mesh)
 
                 def record(gi, scores_f, route=None):
                     with _phase("fold_metrics", lanes=int(md.shape[0]),
@@ -1196,7 +1308,8 @@ class Validator:
                                     ctx, yd, wd, md,
                                     [grids[gi] for gi in gis],
                                     n_classes=n_classes,
-                                    multiclass=multicls, mesh=self.mesh)
+                                    multiclass=multicls,
+                                    mesh=self._sweep_mesh)
                         except Exception as e:  # never lose the sweep to
                             # the fast path: per-config route is the
                             # correctness baseline — but a route that

@@ -339,6 +339,7 @@ def _newton_prox_update(B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye,
 # the one-pass stats engine (ops/stats_engine.py) shares them; the private
 # names stay importable for existing callers
 from ..parallel.mesh import build_shard_map as _build_shard_map  # noqa: E402
+from ..parallel.mesh import mesh_batch_count as _mesh_batch_count  # noqa: E402
 from ..parallel.mesh import mesh_is_multiprocess as _mesh_is_mp  # noqa: E402
 from ..parallel.mesh import shard_vary as _shard_vary  # noqa: E402
 
@@ -404,11 +405,11 @@ def _sharded_stats_fn(mesh):
 
     from ..parallel.mesh import BATCH_AXIS
 
-    def core(X, w):
+    def glm_standardize_stats_sharded(X, w):
         return _psum_moments(
             X, w, lambda v: jax.lax.psum(v, BATCH_AXIS))
 
-    sm = _build_shard_map(core, mesh,
+    sm = _build_shard_map(glm_standardize_stats_sharded, mesh,
                           in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS)),
                           out_specs=(P(None), P(None)))
     return jax.jit(sm)
@@ -655,9 +656,9 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
             (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
              jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32)),
             axis_name)
-        (gA, hA, g0A, h0A), _ = jax.lax.scan(body, acc0, xs)
-        return (allreduce(gA), allreduce(hA),
-                allreduce(g0A), allreduce(h0A))
+        # ONE collective an iteration: the four accumulators merge over
+        # the mesh together (round_psum_bytes)
+        return allreduce(jax.lax.scan(body, acc0, xs)[0])
 
     def cond(state):
         i, _, _, delta = state
@@ -698,20 +699,31 @@ def _sharded_round_fn(mesh, loss, fit_intercept):
 
     from ..parallel.mesh import BATCH_AXIS
 
-    def core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
-             iters_budget, tol):
+    def sweep_glm_round_sharded(X, y, w, fold_masks, sel, l1, l2, B0, b00,
+                                mean, std, iters_budget, tol):
         return _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00,
                            mean, std, iters_budget, tol, loss=loss,
                            fit_intercept=fit_intercept,
                            axis_name=BATCH_AXIS)
 
     sm = _build_shard_map(
-        core, mesh,
+        sweep_glm_round_sharded, mesh,
         in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
                   P(None, BATCH_AXIS), P(None, None), P(None), P(None),
                   P(None, None), P(None), P(None), P(None), P(), P()),
         out_specs=(P(None, None), P(None), P(None), P()))
     return jax.jit(sm)
+
+
+def round_psum_bytes(bucket: int, d: int) -> int:
+    """Bytes of the ONE collective an iteration of the sharded round
+    program of this bucket issues over the mesh, from its static shape:
+    the psum of the gradient, the Gram (or its tile pairs) and the two
+    intercept sums together, float32. The round's other collective, the
+    fold weight sums, is once a program and [F] floats."""
+    tiled, d_work, bt, tile_pairs = _tiling(d)
+    gram = len(tile_pairs) * bt * bt if tiled else d_work * d_work
+    return 4 * bucket * (d_work + gram + 2)
 
 
 # -- tileplane source route (X streamed from disk, never resident) -----------
@@ -1035,6 +1047,10 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             st["warmed"] = True
             warm_seeded = True
 
+    # what the mesh costs a round: 0 collectives on one device
+    shards = _mesh_batch_count(mesh)
+    psums = int(shards > 1)
+
     # span hook: each retirement round is one child span of whatever the
     # validator opened (run -> sweep_fit -> sweep_round), carrying the
     # bucket/active shape — the trace view of the bucket-ladder story, and
@@ -1084,7 +1100,8 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
         mp_round = (not src_mode) and _mesh_is_mp(mesh)
         with _collector.trace_span(
                 f"glm_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
-                active=int(k), iters_budget=int(budget)), \
+                active=int(k), iters_budget=int(budget), shards=shards,
+                psums=psums, psum_bytes=psums * round_psum_bytes(Lb, d)), \
                 _podtrace.pod_round(st["rounds"], bucket=int(Lb),
                                     active=int(k)):
             args = None
@@ -1177,7 +1194,13 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             "driver": "tileplane" if src_mode else "resident",
             **_rounds_info(st, tol_f, max_iter),
             "warm_start": bool(st["warmed"]),
-            "warm_seeded": warm_seeded}
+            "warm_seeded": warm_seeded,
+            # what the mesh cost the rounds: one collective an iteration
+            # and one a round program (the fold weight sums)
+            "psums": psums * (st["data_passes"] + st["rounds"]),
+            "psum_bytes": psums * sum(
+                int(it) * round_psum_bytes(int(Lb), d) for it, Lb in
+                zip(st["iters_per_round"], st["bucket_sizes"]))}
     return B.reshape(F, Gn, d), b0.reshape(F, Gn), info
 
 

@@ -269,6 +269,14 @@ def au_roc_heldout_lanes(scores: jax.Array, labels: jax.Array,
         scores, labels, w, fold_of, n_folds, n_bins))
 
 
+#: (tps, fps) cumulative counts, bins last -> the rank metric: what the
+#: *_heldout_lanes and *_binned_lanes functions end in, for a caller that
+#: sums the counts of several row sets first (a mesh: every chip bins its
+#: own rows, validators._eval_heldout_core)
+RANK_METRIC_FROM_COUNTS = {"au_pr": _au_pr_from_counts,
+                           "au_roc": _au_roc_from_counts}
+
+
 def au_pr_binned(scores: jax.Array, labels: jax.Array,
                  w: Optional[jax.Array] = None,
                  n_bins: int = 4096) -> jax.Array:

@@ -7,9 +7,20 @@ partitions), CV-fold and hyperparameter-grid replication ride ``model``
 (thread-pool parallelism of OpValidator.scala:318), and XLA inserts the
 all-reduce/all-gather collectives over ICI/DCN that replace shuffle + Rabit.
 
-All kernels in ops/ and models/ are written mesh-oblivious (pure jnp) and get
-distribution purely through input shardings — single-chip and pod runs use
-identical program text.
+Kernels written in pure jnp (the vmapped sweep, the exact metrics, the GLM
+solvers' Gram reductions) get their distribution from input shardings alone:
+GSPMD partitions them and inserts the collectives. Two kinds do not, and
+carry an explicit `shard_map` form with its own psums (`build_shard_map`
+below, proved by tmoglint SHD001-SHD005): the streamed row scans (the GLM
+rounds and moments, the one-pass stats engine, the fused tree passes, the
+fold program, the held-out metric pass), whose accumulators must merge once
+a pass and not once a block, and every Pallas kernel, which Mosaic refuses
+on a row-sharded operand and which therefore sees a chip's LOCAL rows
+inside a `shard_map`.
+
+A matrix that already lives row-sharded on the batch axis names its own
+mesh (`resident_row_mesh`): the validators read it from the array and need
+no `mesh=` argument.
 """
 from __future__ import annotations
 
@@ -51,6 +62,22 @@ def build_shard_map(core, mesh, in_specs, out_specs):
     (docs/static_analysis.md)."""
     return jax.shard_map(core, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+def resident_row_mesh(x) -> Optional[Mesh]:
+    """The mesh a device-resident array is row-sharded over: `x` is a
+    jax.Array under a NamedSharding whose rows ride BATCH_AXIS over more
+    than one device, every other dimension whole. None for anything else
+    (a host array, one device, a replicated or column-sharded array),
+    which runs as it always has."""
+    sh = getattr(x, "sharding", None)
+    if not isinstance(x, jax.Array) or not isinstance(sh, NamedSharding):
+        return None
+    spec = tuple(sh.spec) + (None,) * (x.ndim - len(sh.spec))
+    if not spec or spec[0] not in (BATCH_AXIS, (BATCH_AXIS,)) \
+            or any(s is not None for s in spec[1:]):
+        return None
+    return sh.mesh if mesh_batch_count(sh.mesh) > 1 else None
 
 
 def mesh_batch_count(mesh) -> int:
