@@ -43,14 +43,16 @@ def as_v5e(monkeypatch):
     monkeypatch.setattr(P, "device_spec",
                         lambda kind=None: P.DEVICE_SPECS["TPU v5 lite"])
     monkeypatch.setattr(pallas_hist, "available", lambda: True)
-    GS.sweep_mlr_round.clear_cache()
-    GS.sweep_glm_wide_round.clear_cache()
+    rounds = (GS.sweep_mlr_round, GS.sweep_glm_wide_round,
+              GS.sweep_glm_round, GS._sharded_round_fn)
+    for fn in rounds:
+        fn.clear_cache()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield
-    GS.sweep_mlr_round.clear_cache()
-    GS.sweep_glm_wide_round.clear_cache()
+    for fn in rounds:
+        fn.clear_cache()
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -126,3 +128,80 @@ def test_fused_wide_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
         fit_intercept=True).compile()
     assert "wide_gradient" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2
+
+
+def _glm_round_shapes(S, n, d, Lb, F, row=None, cols=None):
+    """sweep_glm_round's arguments; `row` / `cols` shard the row axis of
+    the vectors / the masks on a mesh."""
+    return (S((n, d), BF16, row), S((n,), F32, row), S((n,), F32, row),
+            S((F, n), F32, cols), S((F, Lb), F32), S((Lb,), F32),
+            S((Lb,), F32), S((Lb, d), F32), S((Lb,), F32), S((d,), F32),
+            S((d,), F32), S((), jnp.int32), S((), F32))
+
+
+@pytest.mark.parametrize("n,d,Lb,F,loss", [
+    (25_000_000, 64, 32, 5, "logistic"),        # sweep-glm's round
+    (25_000_000, 64, 8, 5, "logistic"),         # its smallest bucket
+    (1_000_003, 100, 16, 3, "squared_hinge"),   # ragged rows and columns
+    (1_000_003, 37, 128, 3, "logistic"),        # the largest bucket
+], ids=["glm-bucket32", "glm-bucket8", "ragged-hinge", "bucket128"])
+def test_fused_binary_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
+                                               loss):
+    """The whole binary round program around the fused pass: Mosaic takes
+    the kernel (the [lanes, 1, rows] x [1, d, rows] broadcast, the cast
+    and the merge of its leading dimensions among the rest), and the
+    program holds no copy of X, padded or transposed: what it keeps beside
+    the matrix is y and w laid out for the kernel."""
+    def S(shape, dt, _=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    assert GS.glm_round_kernel(d, BF16, Lb) == "pallas_fused"
+    compiled = GS.sweep_glm_round.lower(
+        *_glm_round_shapes(S, n, d, Lb, F), loss=loss,
+        fit_intercept=True).compile()
+    assert "glm_moments" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2
+
+
+def test_a_121_column_matrix_would_be_copied(one_chip, as_v5e, monkeypatch):
+    """Why round_kernel leaves 121 to 128 columns to the XLA blocks:
+    rows-minor such a matrix pads to the size it has columns-minor, the
+    chip keeps it columns-minor, and the fused round would hold a
+    transposed copy of it. 120 columns live rows-minor and are read in
+    place."""
+    def S(shape, dt, _=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    n = 4_000_003
+    assert GS.round_kernel(120) == "pallas_fused"
+    assert GS.round_kernel(121) == GS.round_kernel(127) == "xla_blocks"
+    monkeypatch.setattr(GS, "round_kernel", lambda d: "pallas_fused")
+    for d, copied in ((120, False), (121, True)):
+        compiled = GS.sweep_glm_round.lower(
+            *_glm_round_shapes(S, n, d, 8, 5), loss="logistic",
+            fit_intercept=True).compile()
+        assert "glm_moments" in compiled.as_text()
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                >= n * d * 2) == copied
+
+
+def test_fused_binary_round_compiles_for_the_four_chip_mesh(topo, as_v5e):
+    """sweep-glm-4chip's round: the kernel inside the shard_map over every
+    chip's 32M local rows, the ONE all-reduce an iteration (and the fold
+    weight sums') still there, no copy of a chip's rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+    from transmogrifai_tpu.parallel.mesh import BATCH_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1),
+                (BATCH_AXIS, MODEL_AXIS))
+
+    def S(shape, dt, spec=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(
+            mesh, spec or Ps()))
+    n, d = 128_000_000, 64
+    compiled = GS._sharded_round_fn(mesh, "logistic", True).lower(
+        *_glm_round_shapes(S, n, d, 32, 5, Ps(BATCH_AXIS),
+                           Ps(None, BATCH_AXIS))).compile()
+    text = compiled.as_text()
+    assert "glm_moments" in text
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.25 * n // 4 * d * 2

@@ -1,0 +1,280 @@
+"""The fused binary GLM pass (ops/pallas_glm.glm_moments) on the CPU in
+interpret mode, held to the XLA body it stands in for
+(ops/glm_sweep._moments_blocks). On the chip the two round the matrix
+unit's operands alike; the CPU's XLA body multiplies in float32, so the pass
+is held twice: to float32 rounding against a twin that writes the chip's
+roundings out, and to the operands' rounding against the XLA body itself.
+Then which body the program chooses from what it can observe, one whole
+round through each, and what the telemetry and the round's span say ran.
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from transmogrifai_tpu.ops import glm_sweep as GS
+from transmogrifai_tpu.ops import pallas_glm as PG
+from transmogrifai_tpu.ops import pallas_hist
+from transmogrifai_tpu.utils.metrics import collector
+
+FOLDS = 3
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+def _problem(n, d, Lb, live, seed=0):
+    """Seeded inputs of one pass: shifted, scaled columns (so that
+    standardising does something), weights of which a tenth are zero,
+    complementary fold masks (a third of the rows held out of each fold),
+    `live` lanes of the bucket mapped to folds and the rest inert."""
+    rng = np.random.default_rng(seed)
+    X = (rng.normal(size=(n, d)) * 1.5 + 0.3).astype(np.float32)
+    y = (rng.uniform(size=n) < 0.4).astype(np.float32)
+    w = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+    w[rng.uniform(size=n) < 0.1] = 0.0
+    fold = rng.integers(0, FOLDS, size=n)
+    masks = (fold[None, :] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    sel = np.zeros((FOLDS, Lb), np.float32)
+    sel[rng.integers(0, FOLDS, size=live), np.arange(live)] = 1.0
+    B = (rng.normal(size=(Lb, d)) * 0.2).astype(np.float32)
+    B[live:] = 0.0
+    b0 = rng.normal(size=Lb).astype(np.float32)
+    return (jnp.asarray(X).astype(BF16), jnp.asarray(y), jnp.asarray(w),
+            jnp.asarray(masks), jnp.asarray(sel),
+            jnp.asarray(B).astype(BF16), jnp.asarray(b0),
+            jnp.asarray(X.mean(0)), jnp.asarray(X.std(0)))
+
+
+def _nan_past(a, n_pad):
+    """`a` with NaN planted in n_pad more rows (its last axis)."""
+    width = [(0, 0)] * (a.ndim - 1) + [(0, n_pad)]
+    return jnp.pad(a, width, constant_values=jnp.nan)
+
+
+def _fused(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
+    """The kernel over buffers that run n_pad rows past n, NaN there."""
+    n = X.shape[0]
+    return PG.glm_moments(
+        _nan_past(X.T, n_pad), PG.dense_rows(_nan_past(y, n_pad), n),
+        PG.dense_rows(_nan_past(w, n_pad), n), _nan_past(masks, n_pad),
+        sel, Bt, b0, mean, std, loss=loss, n_rows=n, interpret=True)
+
+
+@functools.partial(jax.jit, static_argnames="loss")
+def _blocks(X, y, w, masks, sel, Bt, b0, mean, std, loss):
+    """_round_core's XLA body over the same inputs."""
+    c = min(GS._row_block(X.shape[1]), X.shape[0])
+    return GS._moments_blocks(GS._blocked(X, y, w, masks, c), sel, Bt.T, b0,
+                              mean, std, loss=loss)
+
+
+@functools.partial(jax.jit, static_argnames="loss")
+def _chip_twin(X, y, w, masks, sel, Bt, b0, mean, std, loss):
+    """The pass with the chip's roundings written out: what DEFAULT
+    precision does to the XLA body's float32 operands there (one bfloat16
+    pass, float32 sums), and what the kernel does by its casts."""
+    def low(v):
+        return v.astype(X.dtype).astype(F32)
+    xf = low((X.astype(F32) - mean) / std)
+    eta = xf @ Bt.astype(F32).T + b0
+    r0, s0 = PG.residual_curvature(loss)(eta, y[:, None])
+    wl = (masks.T * w[:, None]) @ sel
+    R, S = r0 * wl, s0 * wl
+    hA = jnp.einsum("cld,ce->lde", low(S[:, :, None] * xf[:, None, :]), xf)
+    return low(R).T @ xf, hA, R.sum(0), S.sum(0)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and np.isfinite(a).all()
+    return np.abs(a - b).max() / np.abs(b).max()
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 512 rows in two bodies of two chunks: the test sizes make
+    whole tiles and a ragged last one, the accumulators revisited."""
+    monkeypatch.setattr(PG, "_CHUNK", 128)
+    monkeypatch.setattr(PG, "_UNROLL", 2)
+    monkeypatch.setattr(PG, "_TILE_BODIES", 2)
+    moments = PG.glm_moments        # the jitted function, whoever wraps it
+    moments.clear_cache()
+    yield
+    moments.clear_cache()
+
+
+# (rows, columns, bucket, live lanes, loss, NaN rows past n)
+CASES = {
+    # 1 300 rows: two whole tiles and 276 rows of a third, NaN after them,
+    # which only the select on the row index keeps out of the sums
+    "ragged-rows": (1300, 64, 8, 6, "logistic", 236),
+    "one-live-lane": (1300, 64, 8, 1, "logistic", 236),
+    "squared-hinge": (1300, 64, 8, 6, "squared_hinge", 236),
+    "squared": (1300, 64, 8, 6, "squared", 236),
+    "bucket-32": (1300, 64, 32, 30, "logistic", 0),
+    # 100 columns are no whole sublane tile: the block reaches 12 rows past
+    # the matrix's width
+    "width-100": (700, 100, 32, 30, "squared_hinge", 0),
+}
+
+
+@pytest.mark.parametrize("case", CASES.values(), ids=CASES.keys())
+def test_fused_pass_equals_the_xla_body(case, small_tiles):
+    """(gA, hA, g0A, h0A) of the kernel: float32 sums in another order
+    against the twin (1e-4 of the largest: a margin summed in another order
+    can send one residual of the ~1e3 to the other side of a bfloat16 tie),
+    the operands' rounding against the CPU's XLA body (2^-9 a term: 4e-3 of
+    the largest; the intercept's sums are unrounded in both: 1e-6), inert
+    lanes at zero, and a second run bit for bit (one sequential grid axis:
+    every sum has a fixed order)."""
+    n, d, Lb, live, loss, n_pad = case
+    args = _problem(n, d, Lb, live, seed=n + d + Lb) + (loss,)
+    got = _fused(*args, n_pad=n_pad)
+    for a, b in zip(got, _chip_twin(*args)):
+        assert _rel(a, b) <= 1e-4
+    for a, b, tol in zip(got, _blocks(*args), (4e-3, 4e-3, 1e-6, 1e-6)):
+        assert _rel(a, b) <= tol
+    assert all((np.asarray(v)[live:] == 0).all() for v in got)
+    again = _fused(*args, n_pad=n_pad)
+    assert all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(got, again))
+
+
+@pytest.mark.parametrize("mosaic,no_pallas,d,dtype,lanes,vmem,says", [
+    (False, False, 64, BF16, 32, None, "xla_blocks"),   # the CPU, Tier-1
+    (True, False, 64, BF16, 32, None, "pallas_fused"),  # sweep-glm
+    (True, False, 64, BF16, 8, None, "pallas_fused"),
+    (True, False, 100, BF16, 128, None, "pallas_fused"),
+    (True, False, 120, BF16, 32, None, "pallas_fused"),
+    (True, False, 121, BF16, 32, None, "xla_blocks"),   # columns-minor: a copy
+    (True, False, 128, BF16, 32, None, "xla_blocks"),
+    (True, False, 136, BF16, 32, None, "xla_blocks"),   # the feature tiles
+    (True, False, 4104, BF16, 32, None, "xla_blocks"),
+    (True, False, 64, F32, 32, None, "xla_blocks"),     # another precision
+    (True, False, 64, jnp.float16, 32, None, "xla_blocks"),
+    (True, True, 64, BF16, 32, None, "xla_blocks"),     # TMOG_NO_PALLAS
+    (True, False, 64, BF16, 32, 10 << 20, "xla_blocks"),     # they do not fit
+    (True, False, 64, BF16, 8, 10 << 20, "pallas_fused"),  # these do
+], ids=["cpu", "sweep-glm", "bucket-8", "width-100", "120-columns",
+        "121-columns", "128-columns",
+        "136-columns", "4104-columns", "float32", "float16",
+        "TMOG_NO_PALLAS", "small-vmem", "small-vmem-bucket-8"])
+def test_the_body_is_chosen_from_backend_width_dtype_and_vmem(
+        monkeypatch, mosaic, no_pallas, d, dtype, lanes, vmem, says):
+    """glm_round_kernel's table. TMOG_NO_PALLAS reaches it through
+    pallas_hist.available() (the switch is read at import, so the case sets
+    what it sets); VMEM as a v5e's unless the case gives another."""
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "tpu" if mosaic else "cpu")
+    monkeypatch.setattr(pallas_hist, "_enabled", not no_pallas)
+    monkeypatch.setattr(pallas_hist, "_vmem_limit",
+                        lambda: vmem or (96 << 20))
+    assert GS.glm_round_kernel(d, dtype, lanes) == says
+    assert PG.vmem_bytes(64, 32) < 24 << 20 < PG.vmem_bytes(128, 128)
+
+
+# -- a whole round, a whole sweep ---------------------------------------------
+
+N, D = 1536, 64
+
+
+@pytest.fixture
+def backend(monkeypatch, small_tiles):
+    """backend(mosaic) makes the program choose as it would on a backend
+    with (or without) Mosaic, the fused body interpreted: steered here, not
+    by an option of the program. The round programs bake the choice in, so
+    their caches go with every change of it."""
+    monkeypatch.setattr(PG, "glm_moments", functools.partial(
+        PG.glm_moments, interpret=True))
+    monkeypatch.setattr(pallas_hist, "_vmem_limit", lambda: 96 << 20)
+
+    def choose(mosaic: bool):
+        monkeypatch.setattr(pallas_hist, "available", lambda: mosaic)
+        GS.sweep_glm_round.clear_cache()
+    yield choose
+    GS.sweep_glm_round.clear_cache()
+
+
+def _sweep_data(seed=3):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(N, D)).astype(np.float32)
+    beta = rng.normal(size=D).astype(np.float32) / np.sqrt(D)
+    y = (rng.uniform(size=N) < 1 / (1 + np.exp(-(X @ beta - 0.3)))) \
+        .astype(np.float32)
+    fold = rng.integers(0, FOLDS, size=N)
+    masks = (fold[None, :] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    w = rng.uniform(0.5, 2.0, size=N).astype(np.float32)
+    return (jnp.asarray(X).astype(BF16), jnp.asarray(y), jnp.asarray(w),
+            jnp.asarray(masks))
+
+
+@pytest.mark.parametrize("loss,fit_intercept", [
+    ("logistic", True), ("logistic", False), ("squared_hinge", True)],
+    ids=["intercept", "no-intercept", "squared-hinge"])
+def test_a_whole_round_through_the_kernel(backend, loss, fit_intercept):
+    """sweep_glm_round with either body around ONE iteration (the Newton
+    solve, the proximal step, the intercept step): the same number of
+    iterations and the same iterate to what the operands' rounding moves a
+    Newton step by on the CPU (3e-3), inert lanes at rest; without an
+    intercept b0 stays where it was."""
+    X, y, w, masks = _sweep_data()
+    mean, std = GS.glm_standardize_stats(X, w)
+    Lb, live = 8, 5
+    sel = np.zeros((FOLDS, Lb), np.float32)
+    sel[np.arange(live) % FOLDS, np.arange(live)] = 1.0
+    l2 = jnp.asarray([1e-3, 1e-2, 1e-1, 1e-3, 1e-2, 1, 1, 1], F32)
+    args = (X, y, w, masks, jnp.asarray(sel), l2 * 0.5, l2,
+            jnp.zeros((Lb, D), F32), jnp.zeros(Lb, F32), mean, std,
+            jnp.asarray(4, jnp.int32), jnp.asarray(1e-9, F32))
+    outs = []
+    for mosaic in (False, True):
+        backend(mosaic)
+        assert GS.glm_round_kernel(D, X.dtype, Lb) \
+            == ("pallas_fused" if mosaic else "xla_blocks")
+        outs.append([np.asarray(v) for v in GS.sweep_glm_round(
+            *args, loss=loss, fit_intercept=fit_intercept)])
+    B, b0, delta, iters = zip(*outs)
+    assert int(iters[0]) == int(iters[1]) == 4
+    assert np.abs(B[0][:live]).max() > 1e-2
+    np.testing.assert_allclose(B[1], B[0], rtol=0, atol=3e-3)
+    np.testing.assert_allclose(b0[1], b0[0], rtol=0, atol=3e-3)
+    assert (b0[1] == 0).all() != fit_intercept
+    assert (B[1][live:] == 0).all() and (delta[1][live:] == 0).all()
+
+
+def _streamed(regs=(0.01, 0.1)):
+    X, y, w, masks = _sweep_data(seed=5)
+    collector.disable()     # whatever an earlier test file left behind
+    collector.enable("glm_round_kernel")
+    try:
+        B, b0, info = GS.sweep_glm_streamed_rounds(
+            X, y, w, masks, np.float32(regs), np.float32([0.5] * len(regs)),
+            loss="logistic", max_iter=6, tol=1e-6, round_iters=3,
+            standardize=False)
+        spans = [s for s in collector.trace.spans if s.kind == "sweep_round"]
+    finally:
+        collector.finish()
+        collector.disable()
+    return B, b0, info, spans
+
+
+def test_telemetry_and_span_name_the_fused_body_where_it_runs(backend):
+    """Where the backend has Mosaic the sweep runs the fused body and says
+    so in `round_kernel` and on every round's span; its answer is the XLA
+    body's to the operands' rounding; `kernel` stays the route's name."""
+    backend(True)
+    B, b0, info, spans = _streamed()
+    assert info["round_kernel"] == "pallas_fused"
+    assert info["kernel"] == "rounds"
+    assert spans and {s.attrs["kernel"] for s in spans} == {"pallas_fused"}
+    assert all(s.name.startswith("glm_round[") for s in spans)
+    backend(False)
+    B_x, b0_x, info_x, spans_x = _streamed()
+    assert info_x["round_kernel"] == "xla_blocks"
+    assert {s.attrs["kernel"] for s in spans_x} == {"xla_blocks"}
+    assert info_x["iters_per_round"] == info["iters_per_round"]
+    assert info_x["bucket_sizes"] == info["bucket_sizes"]
+    np.testing.assert_allclose(B, B_x, rtol=0, atol=3e-3)
+    np.testing.assert_allclose(b0, b0_x, rtol=0, atol=3e-3)

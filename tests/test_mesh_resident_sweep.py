@@ -285,6 +285,56 @@ def test_round_program_issues_the_collectives_it_declares(mesh, bucket):
     assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
 
 
+def test_sharded_round_with_the_fused_pass_is_the_one_device_round(
+        mesh, data, monkeypatch):
+    """Where the backend has Mosaic every chip runs the fused pass
+    (ops/pallas_glm.glm_moments, interpreted here) over its LOCAL rows
+    inside the shard_map, and the four accumulators still merge in the ONE
+    psum an iteration: the same program text around another body, the same
+    answer as the fused round on one device to float32 rounding."""
+    import functools
+
+    from transmogrifai_tpu.ops import pallas_glm, pallas_hist
+    monkeypatch.setattr(pallas_glm, "glm_moments", functools.partial(
+        pallas_glm.glm_moments, interpret=True))
+    monkeypatch.setattr(pallas_hist, "available", lambda: True)
+    monkeypatch.setattr(pallas_hist, "_vmem_limit", lambda: 96 << 20)
+    bucket = 8
+    assert GS.glm_round_kernel(D, jnp.bfloat16, bucket) == "pallas_fused"
+    rng = np.random.default_rng(1)
+    fold = rng.integers(0, FOLDS, size=N)
+    masks = (fold[None, :] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    sel = np.zeros((FOLDS, bucket), np.float32)
+    sel[np.arange(6) % FOLDS, np.arange(6)] = 1.0
+    tail = (jnp.asarray(sel), jnp.zeros(bucket), jnp.full(bucket, 1e-2),
+            jnp.zeros((bucket, D)), jnp.zeros(bucket), jnp.zeros(D),
+            jnp.ones(D), jnp.asarray(3, jnp.int32),
+            jnp.asarray(0.0, jnp.float32))
+    GS.sweep_glm_round.clear_cache()
+    GS._sharded_round_fn.cache_clear()
+    try:
+        one = GS.sweep_glm_round(
+            data["X1"], data["y1"], jnp.ones(N), jnp.asarray(masks), *tail,
+            loss="logistic", fit_intercept=True)
+        rounds = GS._sharded_round_fn(mesh, "logistic", True)
+        on_mesh = (data["Xs"], data["ys"],
+                   jax.device_put(np.ones(N, np.float32),
+                                  batch_sharding(mesh, 1)),
+                   jax.device_put(masks, sharded_along(mesh, 1, 2))) + tail
+        four = rounds(*on_mesh)
+        text = rounds.lower(*on_mesh).as_text()
+    finally:
+        GS.sweep_glm_round.clear_cache()
+        GS._sharded_round_fn.cache_clear()
+    assert int(one[3]) == int(four[3]) == 3
+    assert np.abs(np.asarray(one[0])[:6]).max() > 1e-2
+    for a, b in zip(one[:3], four[:3]):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
+                                   atol=1e-5)
+    assert text.count("all_reduce") == 1 + 4
+    assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
+
+
 def test_sharded_programs_keep_the_names_traces_find_them_by(mesh):
     S = jax.ShapeDtypeStruct
     ev = V._sharded_eval_heldout_fn(mesh, "au_pr", 64)

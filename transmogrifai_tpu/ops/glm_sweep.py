@@ -14,8 +14,19 @@ This kernel restructures the math so X streams ONCE per iteration for ALL
 lanes (reference workload: the 8-thread pool of OpValidator.scala:270-332,
 every thread refitting against the same cached DataFrame):
 
-- one row-block scan per Newton iteration, carrying per-lane accumulators
-  (g [L, d], Hessians [L, d, d], intercept sums);
+- one pass over the rows per Newton iteration, carrying per-lane
+  accumulators (g [L, d], Hessians [L, d, d], intercept sums). Which body
+  runs the pass is read from what the program can observe
+  (`glm_round_kernel`; no option): on a backend that has Mosaic, for a
+  resident bfloat16 matrix of at most 120 columns, ONE Pallas program
+  (`ops/pallas_glm.glm_moments`) that reads row tiles of X.T in place and
+  keeps everything between the block and the sums in VMEM — margins,
+  residual and curvature, every lane's weighted copy of the block, and one
+  contraction of the block against all of them for the Grams and the
+  gradient together, on one device and on every chip of a mesh alike;
+  everywhere else (the CPU, a float32 matrix, 121 to 128 columns, the
+  feature tiles past 128, the tileplane source steps) the XLA scan over
+  row blocks described next (`_moments_blocks`), the same sums;
 - lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]);
 - every lane's weighted Gram from ONE batched einsum 'cl,cd,ce->lde'
   with S [c, L] the per-lane curvature weights (narrow path, d <= 128).
@@ -23,9 +34,9 @@ every thread refitting against the same cached DataFrame):
   [L, c] x [c, T] matmul) halves the arithmetic but its column GATHER
   dominated the pass on TPU — 7.8 TF/s vs the einsum's 25.8 TF/s on a
   v5 lite at the BASELINE shapes (tools/tpu_glm_hess_ab.py). No
-  per-lane scaled copy of X exists anywhere;
+  per-lane scaled copy of X exists anywhere in HBM;
 - per-lane 64x64 Newton solves + proximal L1 + intercept steps are
-  batched dense linalg on [L, d, d] — microscopic next to the scan.
+  batched dense linalg on [L, d, d] — microscopic next to the pass.
 
 Convergence awareness (docs/performance.md "Convergence-aware GLM
 sweep"): one streamed kernel a loss, on top of the shared scan machinery.
@@ -91,15 +102,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import glm as G
-from . import pallas_hist, pallas_softmax, pallas_wide
+from . import pallas_glm, pallas_hist, pallas_softmax, pallas_wide
 
 EPS = 1e-12
 
-# Rows per scan block on the narrow path: bounds the [c, d, d] pairwise
-# intermediate XLA materializes when lowering the Gram einsum (f32, 512MB
-# at d=64/c=32768) and the [c, L] residual/curvature blocks. _row_block()
-# halves c as d grows so the transient never exceeds that budget (d=128
-# would otherwise double it).
+# Rows per scan block of the XLA bodies on the narrow path (the fused pass
+# of the binary rounds, ops/pallas_glm.py, sizes its own tiles and makes no
+# blocks): bounds the [c, d, d] pairwise intermediate XLA materializes when
+# lowering the Gram einsum (f32, 512MB at d=64/c=32768) and the [c, L]
+# residual/curvature blocks. _row_block() halves c as d grows so the
+# transient never exceeds that budget (d=128 would otherwise double it).
 _ROW_BLOCK = 32_768
 
 
@@ -198,26 +210,10 @@ def streamed_route_ok(d: int, lanes: int, budget_bytes: float) -> bool:
     return bucket_lanes(lanes) * d_work * d_work * 4.0 * 4.0 <= budget_bytes
 
 
-def _residual_curvature(loss: str):
-    """Unweighted per-row residual r and curvature s for eta [c, L]."""
-    if loss == "logistic":
-        def rc(eta, y):
-            p = jax.nn.sigmoid(eta)
-            return p - y[:, None], jnp.maximum(p * (1.0 - p), 1e-6)
-    elif loss == "squared":
-        def rc(eta, y):
-            return eta - y[:, None], jnp.ones_like(eta)
-    elif loss == "squared_hinge":
-        def rc(eta, y):
-            # loss 0.5*gap^2 (NOT gap^2): matches glm.fit_linear_svc's
-            # residual/curvature so the streamed and per-lane routes see
-            # the same effective L2 for a given reg_param
-            ypm = (2.0 * y - 1.0)[:, None]
-            gap = jnp.maximum(1.0 - ypm * eta, 0.0)
-            return -gap * ypm, (gap > 0.0).astype(eta.dtype)
-    else:
-        raise ValueError(f"unknown streamed loss {loss!r}")
-    return rc
+# the IRLS losses' residual and curvature: ONE elementwise rule, kept
+# beside the fused kernel (a Mosaic body carries its source lines, and this
+# file's move often) and read by every XLA body here
+_residual_curvature = pallas_glm.residual_curvature
 
 
 # -- shared scan geometry ----------------------------------------------------
@@ -238,7 +234,12 @@ def _gram_fns(tiled: bool, d_work: int, lanes: int, bt: int, tile_pairs):
     """(hess_blocks, assemble, blocks0) for `lanes` weighted Grams of a
     d_work-wide block. `hess_blocks(xf [c, d_work] f32, S [c, lanes])`
     returns per-block accumulator contributions; `assemble` turns the
-    summed accumulator into the full symmetric [lanes, d_work, d_work]."""
+    summed accumulator into the full symmetric [lanes, d_work, d_work].
+    These are the XLA bodies' Grams (_moments_blocks, _gram_core, the
+    tileplane steps); where the binary rounds run the fused pass
+    (glm_round_kernel) the narrow accumulator comes from
+    pallas_glm.glm_moments in this layout, and only `assemble` (the
+    identity there) is used."""
     if tiled:
         def hess_blocks(xf, S):
             # Tile-pair contributions [npairs, lanes, bt*bt] — the wide-d
@@ -586,6 +587,51 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
 
 # -- round kernel + host retirement driver (IRLS losses) ---------------------
 
+def _moments_blocks(blocks, sel, Bt, b0, mean, std, *, loss,
+                    axis_name: Optional[str] = None, acc0=None):
+    """One Newton iteration's pass over X as an XLA scan over row blocks
+    (`blocks` is `_blocked`'s), for a backend without Mosaic and for the
+    shapes the fused pass leaves alone (`glm_round_kernel`): (gA [Lb, d],
+    Hessian blocks, g0A [Lb], h0A [Lb]), the sums over rows of R xs', S xs
+    xs', R and S (pallas_glm.glm_moments is the same sums in one program).
+    Bt [d, Lb] is the coefficients in the matrix's dtype; mean / std are
+    column-padded to the Gram geometry's width; `acc0` are sums to go on
+    from (the tileplane steps' carry) in place of zeros."""
+    rc = _residual_curvature(loss)
+    d_work, Lb = mean.shape[0], sel.shape[1]
+    tiled, _, bt, tile_pairs = _tiling(d_work)
+    hess_blocks, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
+
+    def body(acc, sl):
+        x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
+        gA, hA, g0A, h0A = acc
+        # standardize on the fly; the low-precision cast keeps the
+        # eta contraction on the bf16 MXU path exactly like the
+        # materialized-Xs route
+        xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
+                  / std[None, :]).astype(x_blk.dtype)
+        eta = jnp.matmul(xs_low, Bt,
+                         preferred_element_type=jnp.float32) + b0[None, :]
+        r0, s0 = rc(eta, y_blk[:, None])            # [c, Lb]
+        wlf = m_blk.T * w_blk[:, None]              # [c, F]
+        wl = jnp.matmul(wlf, sel,
+                        preferred_element_type=jnp.float32)  # [c, Lb]
+        R = r0 * wl
+        S = s0 * wl
+        xf = xs_low.astype(jnp.float32)
+        gA = gA + jnp.matmul(xf.T, R,
+                             preferred_element_type=jnp.float32).T
+        hA = hA + hess_blocks(xf, S)
+        return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
+
+    if acc0 is None:
+        acc0 = _shard_vary(
+            (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
+             jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32)),
+            axis_name)
+    return jax.lax.scan(body, acc0, blocks)[0]
+
+
 def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
                 iters_budget, tol, *, loss, fit_intercept,
                 axis_name: Optional[str] = None):
@@ -599,14 +645,16 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     B0/b00 carry the lanes' standardized-space state between rounds (the
     host unstandardizes once at the end); mean/std are applied on the fly
     per block, so no standardized [n, d] copy is materialized per round.
+    glm_round_kernel(d, dtype, Lb) names the body of the pass over X (one
+    Mosaic program, or an XLA scan over row blocks); the iteration around
+    it is one.
     The while cond early-exits as soon as EVERY bucket lane's delta clears
     tol, so a round never burns budget on an already-converged bucket.
     Returns (B [Lb, d] standardized space, b0 [Lb], delta [Lb], iters)."""
     n, d = X.shape
-    F = fold_masks.shape[0]
     Lb = sel.shape[1]
-    rc = _residual_curvature(loss)
     tiled, d_work, bt, tile_pairs = _tiling(d)
+    fused = glm_round_kernel(d, X.dtype, Lb) == "pallas_fused"
     if d_work > d:
         dp = d_work - d
         X = jnp.pad(X, ((0, 0), (0, dp)))
@@ -621,44 +669,28 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
         allreduce((fold_masks * w[None, :]).sum(1)), EPS)         # [F]
     wsum_l = jnp.maximum((wsum_f[:, None] * sel).sum(0), EPS)     # [Lb]
 
-    c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), n)
-    xs = _blocked(X, y, w, fold_masks, c)
+    # once a round program, outside the iteration: the vectors the kernel
+    # reads beside X.T, or the XLA body's padded row blocks
+    if fused:
+        y_rows, w_rows = (pallas_glm.dense_rows(v) for v in (y, w))
+    else:
+        c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), n)
+        blocks = _blocked(X, y, w, fold_masks, c)
     eye = jnp.eye(d_work, dtype=jnp.float32)
-    hess_blocks, assemble, h_acc0 = _gram_fns(tiled, d_work, Lb, bt,
-                                              tile_pairs)
+    assemble = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)[1]
 
     def accumulate(B, b0):
-        Bt = B.T.astype(X.dtype)                        # [d, Lb]
-
-        def body(acc, sl):
-            x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
-            gA, hA, g0A, h0A = acc
-            # standardize on the fly; the low-precision cast keeps the
-            # eta contraction on the bf16 MXU path exactly like the
-            # materialized-Xs route
-            xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
-                      / std[None, :]).astype(X.dtype)
-            eta = jnp.matmul(xs_low, Bt,
-                             preferred_element_type=jnp.float32) + b0[None, :]
-            r0, s0 = rc(eta, y_blk)                     # [c, Lb]
-            wlf = m_blk.T * w_blk[:, None]              # [c, F]
-            wl = jnp.matmul(wlf, sel,
-                            preferred_element_type=jnp.float32)  # [c, Lb]
-            R = r0 * wl
-            S = s0 * wl
-            xf = xs_low.astype(jnp.float32)
-            gA = gA + jnp.matmul(xf.T, R,
-                                 preferred_element_type=jnp.float32).T
-            hA = hA + hess_blocks(xf, S)
-            return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
-
-        acc0 = _shard_vary(
-            (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
-             jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32)),
-            axis_name)
+        Bt = B.astype(X.dtype)                          # [Lb, d]
+        if fused:
+            moments = pallas_glm.glm_moments(
+                X.T, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std,
+                loss=loss)
+        else:
+            moments = _moments_blocks(blocks, sel, Bt.T, b0, mean, std,
+                                      loss=loss, axis_name=axis_name)
         # ONE collective an iteration: the four accumulators merge over
         # the mesh together (round_psum_bytes)
-        return allreduce(jax.lax.scan(body, acc0, xs)[0])
+        return allreduce(moments)
 
     def cond(state):
         i, _, _, delta = state
@@ -715,6 +747,13 @@ def _sharded_round_fn(mesh, loss, fit_intercept):
     return jax.jit(sm)
 
 
+# both bake glm_round_kernel's answer in, so the Pallas switch clears them
+# (the mesh form's programs hang off an lru_cache: the switch drops it)
+pallas_hist.register_cache_consumer(sweep_glm_round)
+_sharded_round_fn.clear_cache = _sharded_round_fn.cache_clear
+pallas_hist.register_cache_consumer(_sharded_round_fn)
+
+
 def round_psum_bytes(bucket: int, d: int) -> int:
     """Bytes of the ONE collective an iteration of the sharded round
     program of this bucket issues over the mesh, from its static shape:
@@ -753,53 +792,19 @@ def _source_prep_step(carry, xt, yt, wt, mt):
 def _source_round_step(carry, xt, yt, wt, mt, B, b0, sel, mean, std, *,
                        loss: str):
     """One fixed-shape tile's contribution to the round accumulators
-    (g [Lb, d_work], Hessian blocks, intercept sums) — the per-tile slice
-    of _round_core.accumulate's scan body, standardizing on the fly.
+    (g [Lb, d_work], Hessian blocks, intercept sums) — _moments_blocks,
+    the resident rounds' XLA body, over one tile, standardizing on the fly.
     B/b0/sel/mean/std are per-PASS constants (mean/std column-padded to
     d_work by the driver); the donated carry is the pass's only
     accumulator. mt is [c, F] row-major (the natural source layout)."""
-    rc = _residual_curvature(loss)
     d_work = mean.shape[0]
-    Lb = B.shape[0]
-    tiled, _, bt, tile_pairs = _tiling(d_work)
     if d_work > xt.shape[1]:
         xt = jnp.pad(xt, ((0, 0), (0, d_work - xt.shape[1])))
-    hess_blocks, _, _ = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
-    gA, hA, g0A, h0A = carry
-    Bt = B.T.astype(xt.dtype)
-
-    c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), xt.shape[0])
-    nb = -(-xt.shape[0] // c)
-    pad = nb * c - xt.shape[0]
-    if pad:
-        xt = jnp.pad(xt, ((0, pad), (0, 0)))
-        yt = jnp.pad(yt, (0, pad))
-        wt = jnp.pad(wt, (0, pad))
-        mt = jnp.pad(mt, ((0, pad), (0, 0)))
-    xs = (xt.reshape(nb, c, d_work), yt.reshape(nb, c), wt.reshape(nb, c),
-          mt.reshape(nb, c, mt.shape[1]))
-
-    def body(acc, sl):
-        x_blk, y_blk, w_blk, m_blk = sl
-        gA, hA, g0A, h0A = acc
-        xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
-                  / std[None, :]).astype(x_blk.dtype)
-        eta = jnp.matmul(xs_low, Bt,
-                         preferred_element_type=jnp.float32) + b0[None, :]
-        r0, s0 = rc(eta, y_blk)                         # [c, Lb]
-        wlf = m_blk * w_blk[:, None]                    # [c, F]
-        wl = jnp.matmul(wlf, sel,
-                        preferred_element_type=jnp.float32)  # [c, Lb]
-        R = r0 * wl
-        S = s0 * wl
-        xf = xs_low.astype(jnp.float32)
-        gA = gA + jnp.matmul(xf.T, R,
-                             preferred_element_type=jnp.float32).T
-        hA = hA + hess_blocks(xf, S)
-        return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
-
-    (gA, hA, g0A, h0A), _ = jax.lax.scan(body, (gA, hA, g0A, h0A), xs)
-    return gA, hA, g0A, h0A
+    c = min(_ROW_BLOCK_WIDE if _tiling(d_work)[0] else _row_block(d_work),
+            xt.shape[0])
+    return _moments_blocks(_blocked(xt, yt, wt, mt.T, c), sel,
+                           B.T.astype(xt.dtype), b0, mean, std, loss=loss,
+                           acc0=carry)
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
@@ -1094,13 +1099,23 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 break
         return np.asarray(B)[:, :d], np.asarray(b0j), delta, it
 
+    def pass_body(Lb):
+        # the tileplane steps are XLA programs of their own
+        return "xla_blocks" if src_mode \
+            else glm_round_kernel(d, X.dtype, Lb)
+
+    bodies = set()
+
     def run_round(idx, budget):
         k = len(idx)
         Lb = bucket_lanes(k)
         mp_round = (not src_mode) and _mesh_is_mp(mesh)
+        kernel = pass_body(Lb)
+        bodies.add(kernel)
         with _collector.trace_span(
                 f"glm_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
-                active=int(k), iters_budget=int(budget), shards=shards,
+                active=int(k), iters_budget=int(budget), kernel=kernel,
+                shards=shards,
                 psums=psums, psum_bytes=psums * round_psum_bytes(Lb, d)), \
                 _podtrace.pod_round(st["rounds"], bucket=int(Lb),
                                     active=int(k)):
@@ -1192,6 +1207,10 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     b0 = st["b0"] - (B * mean_h[None, :]).sum(1, dtype=np.float32)
     info = {"route": "streamed", "kernel": "rounds",
             "driver": "tileplane" if src_mode else "resident",
+            # the body of the rounds' pass over X (both, where a bucket's
+            # sums outgrew the kernel's VMEM and a smaller one's did not)
+            "round_kernel": "+".join(sorted(
+                bodies or {pass_body(bucket_lanes(L))})),
             **_rounds_info(st, tol_f, max_iter),
             "warm_start": bool(st["warmed"]),
             "warm_seeded": warm_seeded,
@@ -1351,21 +1370,26 @@ def mlr_gram_factor(X: jax.Array, w: jax.Array, fold_masks: jax.Array,
 
 
 def round_kernel(d: int, tile_rows: int = 128) -> str:
-    """Which body a round's pass over a [n, d] matrix runs, for both round
-    families that have a fused one (the multinomial and the wide binary
-    rounds): "pallas_fused" (ops/pallas_softmax.mlr_gradient,
-    ops/pallas_wide.wide_gradient: one Mosaic program a pass) where the
+    """Which body a round's pass over a [n, d] matrix runs, for the round
+    families that have a fused one (the multinomial, the wide binary and,
+    through `glm_round_kernel`, the narrow binary rounds): "pallas_fused"
+    (ops/pallas_softmax.mlr_gradient, ops/pallas_wide.wide_gradient,
+    ops/pallas_glm.glm_moments: one Mosaic program a pass) where the
     backend has one, as the tree kernels choose theirs
     (pallas_hist.available()), else "xla_blocks" (_mlr_gradient_blocks,
-    _wide_gradient_blocks). A matrix whose width is a multiple of 128 stays
-    with the blocks: the chip keeps it columns-minor, so X.T is not the
-    layout it has and the program would hold a transposed copy of it
-    (compiled for a v5e: 6.4 GB at 25M x 128; 64, 100 and 4 104 columns
-    live rows-minor and are read in place). `tile_rows` is how many rows of
-    X a grid step of the kernel can hold in VMEM at this width: under 128
-    there is no tile, and the blocks run (the multinomial kernel tiles any
-    width it is routed; pallas_wide.tile_rows)."""
-    if d % 128 == 0 or tile_rows < 128 or not pallas_hist.available():
+    _wide_gradient_blocks, _moments_blocks). A matrix whose width fills its
+    last 128-column group to within a sublane tile (a multiple of 128, or 1
+    to 7 columns short of one) stays with the blocks: rows-minor it would
+    pad to the same size, so the chip keeps it columns-minor, X.T is not
+    the layout it has and the program would hold a transposed copy of it
+    (compiled for a v5e: 6.4 GB at 25M x 128, a whole copy at 121 to 127
+    columns; 64, 100, 120 and 4 104 columns live rows-minor and are read in
+    place). `tile_rows` is how many rows of X a grid step of the kernel can
+    hold in VMEM at this width: under 128 there is no tile, and the blocks
+    run (the narrow kernels tile any width they are routed;
+    pallas_wide.tile_rows)."""
+    if -(-d // 8) * 8 % 128 == 0 or tile_rows < 128 \
+            or not pallas_hist.available():
         return "xla_blocks"
     return "pallas_fused"
 
@@ -1379,6 +1403,22 @@ def wide_round_kernel(d: int, dtype) -> str:
     if jnp.dtype(dtype) != jnp.bfloat16:
         return "xla_blocks"
     return round_kernel(d, pallas_wide.tile_rows(d))
+
+
+def glm_round_kernel(d: int, dtype, lanes: int) -> str:
+    """`round_kernel` for the binary IRLS rounds (_round_core): the fused
+    pass (ops/pallas_glm.glm_moments) is written for the narrow Gram of a
+    bfloat16 matrix, every lane's [d, d] block of it in one float32
+    output block in VMEM. Past TRI_MAX_D columns the feature-tiled scan
+    runs, a float32 matrix contracts in another precision than the blocks
+    give it (one bfloat16 pass), and a bucket whose sums outgrow the VMEM
+    one kernel may claim has no tile: all three stay with the XLA blocks,
+    as do 121 to 128 columns and a backend without Mosaic
+    (`round_kernel`)."""
+    if d > TRI_MAX_D or jnp.dtype(dtype) != jnp.bfloat16 \
+            or pallas_glm.vmem_bytes(d, lanes) > pallas_hist._vmem_limit():
+        return "xla_blocks"
+    return round_kernel(d)
 
 
 def _mlr_gradient_blocks(X, y, w, fold_masks, sel, Bt_hi, Bt_lo, b0, mean,

@@ -1,0 +1,263 @@
+"""The binary GLM round's pass over X as ONE Pallas program.
+
+`glm_moments` is what `ops/glm_sweep._round_core` runs a Newton iteration on
+a backend that has Mosaic (the XLA block scan, `_moments_blocks` there, is
+the same sums for every other backend): for one compacted lane bucket it
+reads the resident matrix once, as row tiles of `X.T`, and returns the
+bucket's gradient, per-lane Gram and intercept sums. Inside a tile nothing
+of shape [lanes, rows] or [lanes x d, rows] leaves VMEM: standardise ->
+margins (one contraction) -> residual and curvature -> lane weights -> the
+gradient's contraction -> every lane's weighted copy of the block, lane
+above lane -> ONE contraction of the block against all of them for every
+lane's Gram, summed into float32 output blocks that every grid step
+revisits. The XLA body makes each of those a fusion of its own, each reads
+and writes its [rows, lanes] blocks in HBM, and its Gram keeps the block,
+64 columns wide, as the matrix unit's stationary operand, which fills half
+of the array; here the weighted copies are the stationary operand and fill
+it. The grid is one sequential axis, so the order of every sum is fixed and
+a job repeats bit for bit.
+
+Precision is what the XLA body gets on the chip: the matrix unit's operands
+in the matrix's dtype (the standardised block, the coefficients, the
+residual x weight and the curvature x weight x block), float32 sums; the
+intercept's two sums take residual and curvature unrounded.
+
+Kept apart from ops/pallas_hist.py, ops/pallas_softmax.py and
+ops/pallas_wide.py on purpose: a Mosaic body carries its source locations,
+so an edit that moves a file's lines makes every kernel of it miss the
+compile cache (PERF.md, PR 27).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from . import pallas_hist
+from .pallas_softmax import _round_up
+
+# Columns of a tile (rows of X) worked on at a time, chunks in one body of
+# the tile's loop, and bodies a grid step. The probes that set them (v5e,
+# 25M x 64, ms a pass at 32 / 8 lanes; PERF.md, PR 36): chunks of 256 rows
+# 59.5 / 29.7, 512 53.0 / 22.7, 1 024 47.9 / 18.3, 2 048 47.3 / 17.5 — every
+# chunk pays the margins' contraction, the sums' read and write and the
+# fill of the matrix unit once — two chunks a body 2 ms under one, 16 384
+# rows a tile 0.6 ms under 8 192. The contraction alone takes 40.5 / —,
+# the vector work alone 10.6.
+_CHUNK = 1024
+_UNROLL = 2
+_TILE_BODIES = 8
+
+
+def residual_curvature(loss: str):
+    """Unweighted residual r and curvature s of an IRLS loss, elementwise:
+    rc(eta, y) for margins `eta` and labels `y` of a shape that broadcasts
+    against them (the XLA bodies hand [rows, lanes] and [rows, 1], the
+    kernel [lanes, rows] and [1, rows])."""
+    if loss == "logistic":
+        def rc(eta, y):
+            p = jax.nn.sigmoid(eta)
+            return p - y, jnp.maximum(p * (1.0 - p), 1e-6)
+    elif loss == "squared":
+        def rc(eta, y):
+            return eta - y, jnp.ones_like(eta)
+    elif loss == "squared_hinge":
+        def rc(eta, y):
+            # loss 0.5*gap^2 (NOT gap^2): matches glm.fit_linear_svc's
+            # residual/curvature so the streamed and per-lane routes see
+            # the same effective L2 for a given reg_param
+            ypm = 2.0 * y - 1.0
+            gap = jnp.maximum(1.0 - ypm * eta, 0.0)
+            return -gap * ypm, (gap > 0.0).astype(eta.dtype)
+    else:
+        raise ValueError(f"unknown streamed loss {loss!r}")
+    return rc
+
+
+def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
+            std_ref, h_ref, g_ref, g0_ref, h0_ref, *, n, d, tile, loss):
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+    chunk, groups = _CHUNK, _CHUNK // 128
+    i = pl.program_id(0)
+    dp, dtype = xT_ref.shape[0], xT_ref.dtype
+    lanes, folds = selT_ref.shape
+    pack = 8 * 4 // jnp.dtype(dtype).itemsize
+    rc = residual_curvature(loss)
+    over_rows = (((1,), (1,)), ((), ()))
+
+    @pl.when(i == 0)
+    def _():
+        for ref in (h_ref, g_ref, g0_ref, h0_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    def lane_iota(rows):
+        return jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
+
+    x_cols, f_cols = lane_iota(dp), lane_iota(folds)
+    feat_ok = None if dp == d else \
+        jax.lax.broadcasted_iota(jnp.int32, (dp, chunk), 0) < d
+    bt, b0, selT = bt_ref[...], b0_ref[...], selT_ref[...]
+    # across the chunk once a grid step, not once a chunk: 3.4 ms a pass
+    mean, std = (jnp.broadcast_to(v[...], (dp, chunk))
+                 for v in (mean_ref, std_ref))
+
+    def lane_sums(ref, V):
+        part = V[:, 0:128]
+        for k in range(1, groups):
+            part = part + V[:, k * 128:(k + 1) * 128]
+        ref[...] += part
+
+    def one_chunk(j):
+        off = pl.multiple_of(j * chunk, chunk)
+        cols = pl.ds(off, chunk)
+        # rows of X past n (the last tile's tail, whatever the buffer holds
+        # there) lose x and the fold weights by a select, as y and w lost
+        # theirs in `dense_rows`: a zero weight alone would leave NaN x 0
+        left = n - (i * tile + off)
+        x_ok = x_cols < left
+        if feat_ok is not None:
+            x_ok = x_ok & feat_ok
+        xs = jnp.where(x_ok, (xT_ref[:, cols].astype(f32) - mean) / std,
+                       0.0).astype(dtype)                        # [dp, c]
+        eta = jnp.dot(bt, xs, preferred_element_type=f32) + b0   # [L, c]
+        # y and w come dense, 128 rows of X a sublane (`dense_rows`)
+        sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
+        y_row, w_row = (jnp.concatenate(
+            [v[k:k + 1, :] for k in range(groups)], axis=1)
+            for v in (y_ref[sub, :], w_ref[sub, :]))             # [1, c]
+        r0, s0 = rc(eta, y_row)
+        mw = jnp.where(f_cols < left, m_ref[:, cols] * w_row, 0.0)
+        # lane weights: sel is 0/1 with one fold a lane, so the sum is exact
+        wl = selT[:, 0:1] * mw[0:1, :]
+        for f in range(1, folds):
+            wl = wl + selT[:, f:f + 1] * mw[f:f + 1, :]          # [L, c]
+        R, S = r0 * wl, s0 * wl
+        lane_sums(g0_ref, R)
+        lane_sums(h0_ref, S)
+        # The block streams through the matrix unit against what stays in
+        # it: the residual, then every lane's weighted copy of the block,
+        # one above the other. The copies fill the array's 128 columns; the
+        # block, 64 wide, would fill half of them, and as the stationary
+        # operand it measured 83.6 ms a pass where this form takes 57.5
+        # (PERF.md, PR 36). They go to the contraction as a value: through
+        # a VMEM scratch of the kernel's own the pass is 4 ms longer.
+        if lanes % pack:    # the cast fills whole sublane tiles
+            R = jnp.concatenate(
+                [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
+        g_ref[:, 0:R.shape[0]] += jax.lax.dot_general(
+            xs, R.astype(dtype), over_rows, preferred_element_type=f32)
+        h_ref[...] += jax.lax.dot_general(
+            xs, (S[:, None, :] * xs.astype(f32)[None, :, :]).astype(dtype)
+            .reshape(lanes * dp, chunk), over_rows,
+            preferred_element_type=f32)
+
+    def body(j, carry):
+        for u in range(_UNROLL):
+            one_chunk(_UNROLL * j + u)
+        return carry
+
+    jax.lax.fori_loop(0, tile // (chunk * _UNROLL), body, 0)
+
+
+def _tile_rows(n: int) -> int:
+    body = _CHUNK * _UNROLL
+    return body * min(_TILE_BODIES, -(-n // body))
+
+
+def _padded(d: int, lanes: int, dtype) -> tuple:
+    """(columns, lanes) as the kernel lays them out: columns in whole
+    sublane tiles of the matrix's dtype (a vector register holds 8 rows of
+    32 bits, 16 of 16), so that a lane's weighted block is whole tiles;
+    lanes in whole float32 tiles, the padding lanes inert."""
+    return _round_up(d, 8 * 4 // jnp.dtype(dtype).itemsize), \
+        _round_up(lanes, 8)
+
+
+def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
+    """What the kernel keeps in VMEM for a bucket of `lanes`: the weighted
+    blocks of every chunk of a body, the float32 sums and the tile of X.T,
+    y, w and the fold masks twice each for the pipeline's buffers. (The
+    float32 products before the cast never exist whole: compiled for a
+    v5e, 256 lanes of 127 columns fit its 96 MiB.)"""
+    dp, lp = _padded(d, lanes, dtype)
+    item = jnp.dtype(dtype).itemsize
+    tile = _CHUNK * _UNROLL * _TILE_BODIES
+    return _UNROLL * lp * dp * _CHUNK * item \
+        + 2 * dp * (lp * dp + _round_up(lp, 128)) * 4 \
+        + 2 * tile * (dp * item + 8 * 4 + 2 * 4)
+
+
+def dense_rows(v, n_rows=None):
+    """A per-row vector (y, the weights) as `glm_moments` reads it: float32
+    [R, 128], 128 rows of X a sublane, its first `n_rows` entries (default:
+    all) and zeros up to whole tiles; made once a round program and not
+    once a pass (`pallas_softmax.dense_rows`, at this kernel's tile)."""
+    n = v.shape[0] if n_rows is None else int(n_rows)
+    return jnp.pad(v[:n].astype(jnp.float32),
+                   (0, _round_up(n, _tile_rows(n)) - n)).reshape(-1, 128)
+
+
+@functools.partial(jax.jit, static_argnames=("loss", "n_rows", "interpret"))
+def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
+                loss: str, n_rows=None, interpret: bool = False):
+    """(gA [lanes, d], hA [lanes, d, d], g0A [lanes], h0A [lanes]) float32:
+    the sums over the first `n_rows` rows (default: all) of R xs', S xs xs',
+    R and S, where xs is the standardised row in the matrix's dtype, R and S
+    the loss's residual and curvature at xs' B + b0 times the lane's fold
+    weight — one Newton iteration's pass of `_round_core` for a lane bucket.
+
+    XT [d, n] is X.T, the layout a resident matrix of such a width already
+    has on the chip (no padded or re-laid-out copy is made of it: the last
+    tile reads past n and masks); y_rows, w_rows are `dense_rows` of y and
+    w; fold_masks [F, n]; sel [F, lanes] maps lanes to folds; Bt [lanes, d]
+    the coefficients in the matrix's dtype; b0 [lanes]; mean, std [d].
+    Columns pad to whole sublane tiles with zeros, cut from what is
+    returned."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    d, n_buf = XT.shape
+    n = n_buf if n_rows is None else int(n_rows)
+    F, lanes = sel.shape
+    dp, lp = _padded(d, lanes, XT.dtype)
+    tile = _tile_rows(n)
+
+    def column(v, fill):
+        return jnp.pad(v.astype(f32), (0, dp - d),
+                       constant_values=fill).reshape(dp, 1)
+
+    def by_rows(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    def whole(a):
+        return by_rows(a.shape, lambda i: (0, 0))
+    dense = by_rows((tile // 128, 128), lambda i: (i, 0))
+    resident = (
+        jnp.pad(Bt.astype(XT.dtype), ((0, lp - lanes), (0, dp - d))),
+        jnp.pad(b0.astype(f32), (0, lp - lanes)).reshape(lp, 1),
+        jnp.pad(sel.T.astype(f32), ((0, lp - lanes), (0, 0))),
+        column(mean, 0.0), column(std, 1.0))
+    out_shape = (jax.ShapeDtypeStruct((dp, lp * dp), f32),
+                 jax.ShapeDtypeStruct((dp, _round_up(lp, 128)), f32),
+                 jax.ShapeDtypeStruct((lp, 128), f32),
+                 jax.ShapeDtypeStruct((lp, 128), f32))
+    h, g, g0, h0 = pl.pallas_call(
+        functools.partial(_kernel, n=n, d=d, tile=tile, loss=loss),
+        grid=(-(-n // tile),),
+        in_specs=[by_rows((dp, tile), lambda i: (0, i)), dense, dense,
+                  by_rows((F, tile), lambda i: (0, i))]
+        + [whole(a) for a in resident],
+        out_specs=tuple(whole(s) for s in out_shape),
+        out_shape=out_shape,
+        compiler_params=pallas_hist._compiler_params(),
+        name="glm_moments",
+        interpret=interpret,
+    )(XT, y_rows, w_rows, fold_masks.astype(f32), *resident)
+    # h[j, lane * dp + i] = sum_c xs[j, c] (S[lane, c] xs[i, c])
+    hA = h.reshape(dp, lp, dp).transpose(1, 2, 0)
+    return (g[:d, :lanes].T, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes],
+            h0.sum(axis=1)[:lanes])
