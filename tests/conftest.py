@@ -53,7 +53,34 @@ _STALE_MANIFEST_PINS = (
 )
 
 
+# The files that take a worker longest, longest first (their seconds in a
+# cold 6-worker run of PR 37's tree): xdist's `loadfile` hands files out in
+# collection order, so tests/test_trees.py, a tenth of the suite's work and
+# last but three in the alphabet, started when the other workers were
+# nearly done and ran on alone; started first, the same work ends ~250 s
+# sooner (PERF.md, Tier-1's wall). Order only: nothing is dropped or marked.
+_HEAVY_FIRST = (
+    "tests/test_trees.py",                          # 626
+    "tests/benchmark/test_benchmark_forest.py",     # 618
+    "tests/test_pallas_hist.py",                    # 560
+    "tests/test_hist_batched.py",                   # 315
+    "tests/test_sweep_scale.py",                    # 293
+    "tests/test_glm_sweep.py",                      # 286
+    "tests/test_forest_lanes.py",                   # 283
+    "tests/test_loco_batched.py",                   # 269
+    "tests/benchmark/test_benchmark_wide.py",       # 268
+    "tests/test_mlr_fused_kernel.py",               # 209
+    "tests/test_glm_wide.py",                       # 202
+    "tests/test_tree_levels.py",                    # 202
+    "tests/benchmark/test_benchmark_mlr.py",        # 193
+    "tests/benchmark/test_benchmark_nulls.py",      # 186
+)
+
+
 def pytest_collection_modifyitems(config, items):
+    rank = {name: i for i, name in enumerate(_HEAVY_FIRST)}
+    items.sort(key=lambda item: rank.get(item.nodeid.split("::")[0],
+                                         len(rank)))    # stable
     for item in items:
         if item.nodeid.endswith(_STALE_MANIFEST_PINS):
             item.add_marker(pytest.mark.xfail(

@@ -144,7 +144,11 @@ def _glm_round_shapes(S, n, d, Lb, F, row=None, cols=None):
     (25_000_000, 64, 8, 5, "logistic"),         # its smallest bucket
     (1_000_003, 100, 16, 3, "squared_hinge"),   # ragged rows and columns
     (1_000_003, 37, 128, 3, "logistic"),        # the largest bucket
-], ids=["glm-bucket32", "glm-bucket8", "ragged-hinge", "bucket128"])
+    # sweep-glm-nulls128's round: [rows, 128] tiles of X as the chip keeps
+    # it, turned over in VMEM; 64 lanes hold 49 MiB of its 96
+    (25_000_000, 128, 64, 5, "logistic"),
+], ids=["glm-bucket32", "glm-bucket8", "ragged-hinge", "bucket128",
+        "nulls128-bucket64"])
 def test_fused_binary_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
                                                loss):
     """The whole binary round program around the fused pass: Mosaic takes
@@ -160,6 +164,10 @@ def test_fused_binary_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
         fit_intercept=True).compile()
     assert "glm_moments" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2
+    if d == 128:    # the cell's bound; the XLA body there holds 7.4 GB
+        assert GS.glm_x_tile(d) == "cols_minor"
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 0.05 * n * d * 2
 
 
 def test_a_121_column_matrix_would_be_copied(one_chip, as_v5e, monkeypatch):

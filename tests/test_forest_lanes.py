@@ -203,9 +203,16 @@ def test_bootstrap_vectors_differ_across_trees_and_fold_lanes_share_them(
     monkeypatch.setattr(T, "_grow_tree_folds", spy)
     _, Xb, y, W = _data(n=512, f=4, folds=folds)
     rw, kf = T.forest_bootstrap(key, 0, 1.0, n_rows=512, n_trees=2, group=2)
-    T.fit_forest_lanes.__wrapped__(
-        Xb, y, W, rw, kf, jnp.zeros(W.shape), depth=2, n_bins=8,
-        feature_frac=0.5)
+
+    def lanes(*args):
+        """The program traced ONCE around the spy (op by op, untraced, it
+        took 40 s of a worker): what the spy saw comes out as results."""
+        T.fit_forest_lanes.__wrapped__(*args, depth=2, n_bins=8,
+                                       feature_frac=0.5)
+        return seen["H"], seen["keys"]
+    # tmoglint: disable=TRC001  one call
+    seen["H"], seen["keys"] = jax.jit(lanes)(Xb, y, W, rw, kf,
+                                             jnp.zeros(W.shape))
     H = np.asarray(seen["H"])[:, :512]
     for t in range(2):
         for f in range(folds):

@@ -24,13 +24,17 @@ FOLDS = 3
 BF16, F32 = jnp.bfloat16, jnp.float32
 
 
-def _problem(n, d, Lb, live, seed=0):
+def _problem(n, d, Lb, live, seed=0, wide_scales=False):
     """Seeded inputs of one pass: shifted, scaled columns (so that
-    standardising does something), weights of which a tenth are zero,
-    complementary fold masks (a third of the rows held out of each fold),
-    `live` lanes of the bucket mapped to folds and the rest inert."""
+    standardising does something; `wide_scales`: standard deviations from
+    0.03 to 16, a power of two a column, as null-tracked numerics have),
+    weights of which a tenth are zero, complementary fold masks (a third of
+    the rows held out of each fold), `live` lanes of the bucket mapped to
+    folds and the rest inert."""
     rng = np.random.default_rng(seed)
-    X = (rng.normal(size=(n, d)) * 1.5 + 0.3).astype(np.float32)
+    scale, shift = (2.0 ** ((np.arange(d) * 3) % 10 - 5),) * 2 \
+        if wide_scales else (1.5, 0.3)
+    X = (rng.normal(size=(n, d)) * scale + shift).astype(np.float32)
     y = (rng.uniform(size=n) < 0.4).astype(np.float32)
     w = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
     w[rng.uniform(size=n) < 0.1] = 0.0
@@ -54,12 +58,17 @@ def _nan_past(a, n_pad):
 
 
 def _fused(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
-    """The kernel over buffers that run n_pad rows past n, NaN there."""
+    """The kernel over buffers that run n_pad rows past n, NaN there, in
+    the tile form the width takes (glm_x_tile: X.T, or X as it is)."""
     n = X.shape[0]
+    x_tile = GS.glm_x_tile(X.shape[1])
+    XT = _nan_past(X.T, n_pad)
     return PG.glm_moments(
-        _nan_past(X.T, n_pad), PG.dense_rows(_nan_past(y, n_pad), n),
+        XT.T if x_tile == "cols_minor" else XT,
+        PG.dense_rows(_nan_past(y, n_pad), n),
         PG.dense_rows(_nan_past(w, n_pad), n), _nan_past(masks, n_pad),
-        sel, Bt, b0, mean, std, loss=loss, n_rows=n, interpret=True)
+        sel, Bt, b0, mean, std, loss=loss, n_rows=n, interpret=True,
+        x_tile=x_tile)
 
 
 @functools.partial(jax.jit, static_argnames="loss")
@@ -117,6 +126,13 @@ CASES = {
     # 100 columns are no whole sublane tile: the block reaches 12 rows past
     # the matrix's width
     "width-100": (700, 100, 32, 30, "squared_hinge", 0),
+    # 128 columns: [rows, 128] tiles of X itself, turned over in VMEM
+    # (x_tile cols_minor); a ragged last tile with NaN after it, 8 lanes,
+    # 40 live lanes of a 64-lane bucket (upstream's whole LR grid x 5
+    # folds) and a full one
+    "cols-128-ragged": (1300, 128, 8, 6, "logistic", 236),
+    "cols-128-40-of-64": (700, 128, 64, 40, "logistic", 0),
+    "cols-128-64": (600, 128, 64, 64, "squared_hinge", 0),
 }
 
 
@@ -130,7 +146,12 @@ def test_fused_pass_equals_the_xla_body(case, small_tiles):
     lanes at zero, and a second run bit for bit (one sequential grid axis:
     every sum has a fixed order)."""
     n, d, Lb, live, loss, n_pad = case
-    args = _problem(n, d, Lb, live, seed=n + d + Lb) + (loss,)
+    args = _problem(n, d, Lb, live, seed=n + d + Lb,
+                    wide_scales=d == 128) + (loss,)
+    assert GS.glm_x_tile(d) == ("cols_minor" if d == 128 else "rows_minor")
+    if d == 128:    # standardize on, std spanning 0.03 to 16
+        std = np.asarray(args[8])
+        assert std.min() < 0.04 and std.max() > 15
     got = _fused(*args, n_pad=n_pad)
     for a, b in zip(got, _chip_twin(*args)):
         assert _rel(a, b) <= 1e-4
@@ -149,7 +170,12 @@ def test_fused_pass_equals_the_xla_body(case, small_tiles):
     (True, False, 100, BF16, 128, None, "pallas_fused"),
     (True, False, 120, BF16, 32, None, "pallas_fused"),
     (True, False, 121, BF16, 32, None, "xla_blocks"),   # columns-minor: a copy
-    (True, False, 128, BF16, 32, None, "xla_blocks"),
+    (True, False, 127, BF16, 32, None, "xla_blocks"),
+    (True, False, 128, BF16, 32, None, "pallas_fused"),  # cols_minor tiles
+    (True, False, 128, BF16, 64, None, "pallas_fused"),  # sweep-glm-nulls128
+    (False, False, 128, BF16, 64, None, "xla_blocks"),  # the CPU
+    (True, False, 128, BF16, 256, None, "xla_blocks"),  # 170 MiB of VMEM
+    (True, False, 128, F32, 64, None, "xla_blocks"),
     (True, False, 136, BF16, 32, None, "xla_blocks"),   # the feature tiles
     (True, False, 4104, BF16, 32, None, "xla_blocks"),
     (True, False, 64, F32, 32, None, "xla_blocks"),     # another precision
@@ -158,7 +184,8 @@ def test_fused_pass_equals_the_xla_body(case, small_tiles):
     (True, False, 64, BF16, 32, 10 << 20, "xla_blocks"),     # they do not fit
     (True, False, 64, BF16, 8, 10 << 20, "pallas_fused"),  # these do
 ], ids=["cpu", "sweep-glm", "bucket-8", "width-100", "120-columns",
-        "121-columns", "128-columns",
+        "121-columns", "127-columns", "128-columns", "nulls128",
+        "nulls128-cpu", "128-columns-256-lanes", "128-columns-float32",
         "136-columns", "4104-columns", "float32", "float16",
         "TMOG_NO_PALLAS", "small-vmem", "small-vmem-bucket-8"])
 def test_the_body_is_chosen_from_backend_width_dtype_and_vmem(
@@ -173,6 +200,9 @@ def test_the_body_is_chosen_from_backend_width_dtype_and_vmem(
                         lambda: vmem or (96 << 20))
     assert GS.glm_round_kernel(d, dtype, lanes) == says
     assert PG.vmem_bytes(64, 32) < 24 << 20 < PG.vmem_bytes(128, 128)
+    assert PG.vmem_bytes(128, 64) < 50 << 20
+    # the families whose fused body reads X.T still leave 128 columns alone
+    assert GS.round_kernel(128) == "xla_blocks"
 
 
 # -- a whole round, a whole sweep ---------------------------------------------

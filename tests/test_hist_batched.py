@@ -282,14 +282,17 @@ def test_route_hist_exact_at_every_node_count(n_nodes, lanes):
     route_pallas THEN hist_pallas, at every level width. Wide bins (ids
     past 300, thresholds to 256) ride the narrow levels; the 2 048-node
     level keeps two lanes (its histogram block is lanes x 6 144 rows);
-    517 rows are two ragged grid steps at 40 x 32 one-hot columns."""
+    517 rows are two ragged grid steps at 40 x 32 one-hot columns, and
+    261 rows three at the wide levels' 310 x 257 (whose interpreted grid
+    steps are most of this file's time: 517 rows there were five)."""
     wide = n_nodes <= 2
     lanes = min(lanes, 2) if n_nodes > 256 else lanes
     b = 257 if wide else 32
+    n = 261 if wide else 517
     Xb_t, node, f_lvl, t_lvl, m_lvl, stray = _route_inputs(
-        n_nodes, lanes, 517, seed=3 * n_nodes + lanes, wide=wide)
+        n_nodes, lanes, n, seed=3 * n_nodes + lanes, wide=wide)
     rng = np.random.default_rng(n_nodes)
-    pay = jnp.asarray(rng.integers(-8, 9, size=(2 * lanes, 517)),
+    pay = jnp.asarray(rng.integers(-8, 9, size=(2 * lanes, n)),
                       jnp.float32)
     hist, new_node = PH.route_hist(Xb_t, pay, node, f_lvl, t_lvl, m_lvl,
                                    n_nodes=n_nodes, n_bins=b,

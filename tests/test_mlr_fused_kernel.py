@@ -66,11 +66,25 @@ def _fused(X, y, w, masks, tail, n, n_pad):
         *tail, n_rows=n, interpret=True)
 
 
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 1 024 rows in two bodies of two chunks (the chip's are 8
+    bodies of 6): the test sizes make a whole tile and a ragged one, the
+    accumulators revisited, and the interpreted body, whose chunks and
+    lanes are unrolled, compiles in a third of the time."""
+    monkeypatch.setattr(PS, "_UNROLL", 2)
+    monkeypatch.setattr(PS, "_TILE_BODIES", 2)
+    gradient = PS.mlr_gradient      # the jitted function, whoever wraps it
+    gradient.clear_cache()
+    yield
+    gradient.clear_cache()
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("classes", [2, 3, 32])
 @pytest.mark.parametrize("bucket", [1, 4, 16])
-def test_fused_pass_equals_the_xla_body(bucket, classes, dtype):
+def test_fused_pass_equals_the_xla_body(bucket, classes, dtype, small_tiles):
     """(gA, g0A) at 64 columns for every bucket and for class counts that
     pad a sublane tile (2, 3) and fill four (32); 1 300 rows are no multiple
     of the 256-row chunk, and the buffers hold NaN in the 236 rows after
@@ -84,7 +98,7 @@ def test_fused_pass_equals_the_xla_body(bucket, classes, dtype):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("d", [8, 37])
-def test_fused_pass_at_widths_that_fill_no_tile(d, dtype):
+def test_fused_pass_at_widths_that_fill_no_tile(d, dtype, small_tiles):
     """Columns pad to whole sublane tiles INSIDE the kernel (the block
     reaches past the matrix; a select on the column index zeroes what it
     reads there): 37 is no multiple of 8 or 16, 8 is half a bf16 tile."""
@@ -130,7 +144,7 @@ def _sweep_data(seed=3):
 
 
 @pytest.fixture
-def backend(monkeypatch):
+def backend(monkeypatch, small_tiles):
     """backend(mosaic) makes the program choose as it would on a backend
     with (or without) Mosaic, the fused body interpreted: steered here, not
     by an option of the program. The round program bakes the choice in, so

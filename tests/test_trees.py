@@ -5,9 +5,12 @@ OpXGBoost*Test.scala (core/src/test/.../impl/{classification,regression}/):
 fitted model emits Prediction(pred, rawPrediction, probability); quality
 checks on separable/nonlinear synthetic data; save/load round-trip.
 """
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from transmogrifai_tpu.ops import trees as T
@@ -378,10 +381,12 @@ class TestHistogramPaths:
             monkeypatch.setattr(
                 T.jax, "default_backend",
                 (lambda: "tpu") if force_tpu else real_backend)
-            # bypass the jit cache: call the wrapped fn directly
-            return T.grow_tree.__wrapped__(
-                Xb, G, H, key, depth=3, n_bins=16, reg_lambda=1.0,
-                leaf_mode="newton")
+            # bypass the jit cache with a jit of its own around the wrapped
+            # fn (op by op, untraced, the two growths took 5 minutes of a
+            # loaded worker): the backend is read when it is traced
+            return jax.jit(functools.partial(
+                T.grow_tree.__wrapped__, depth=3, n_bins=16, reg_lambda=1.0,
+                leaf_mode="newton"))(Xb, G, H, key)
 
         t_mat = grow(True)
         t_seg = grow(False)
@@ -464,9 +469,11 @@ class TestHistogramPaths:
         key = __import__("jax").random.PRNGKey(2)
 
         def fit():
-            return T.fit_gbt.__wrapped__(
-                Xb, jnp.asarray(y), w, key, n_rounds=2, depth=4, n_bins=B,
-                learning_rate=0.3, loss="logistic")
+            # a jit of its own: traced anew under either backend
+            return jax.jit(functools.partial(
+                T.fit_gbt.__wrapped__, n_rounds=2, depth=4, n_bins=B,
+                learning_rate=0.3, loss="logistic"))(
+                    Xb, jnp.asarray(y), w, key)
 
         monkeypatch.setattr(T.jax, "default_backend", lambda: "tpu")
         trees_t, base_t = fit()

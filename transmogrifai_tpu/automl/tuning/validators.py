@@ -1049,13 +1049,15 @@ class Validator:
             from ...parallel.mesh import mesh_batch_count, mesh_is_multiprocess
             mesh = self._sweep_mesh
             shards = mesh_batch_count(mesh)
+            from ...ops.glm_sweep import bucket_lanes, wide_padded_cols
+            d = int(X.shape[1])
+            lanes = int(masks.shape[0]) * len(pending)
             fit_attrs = dict(folds=int(masks.shape[0]), grids=len(pending),
-                             classes=int(n_classes), shards=shards)
-            if not multiclass and self._wide_rounds(fit_kwargs["loss"],
-                                                    int(X.shape[1])):
-                from ...ops.glm_sweep import wide_padded_cols
-                d = int(X.shape[1])
-                fit_attrs.update(cols=d, padded_cols=wide_padded_cols(d))
+                             classes=int(n_classes), shards=shards, cols=d,
+                             lanes=lanes, bucket=bucket_lanes(lanes),
+                             standardize=fit_kwargs["standardize"])
+            if not multiclass and self._wide_rounds(fit_kwargs["loss"], d):
+                fit_attrs.update(padded_cols=wide_padded_cols(d))
             with collector.trace_span(
                     f"glm_streamed:{type(est).__name__}", kind="sweep_fit",
                     **fit_attrs) as sp:

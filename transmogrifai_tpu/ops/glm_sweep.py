@@ -18,13 +18,14 @@ every thread refitting against the same cached DataFrame):
   accumulators (g [L, d], Hessians [L, d, d], intercept sums). Which body
   runs the pass is read from what the program can observe
   (`glm_round_kernel`; no option): on a backend that has Mosaic, for a
-  resident bfloat16 matrix of at most 120 columns, ONE Pallas program
-  (`ops/pallas_glm.glm_moments`) that reads row tiles of X.T in place and
+  resident bfloat16 matrix of at most 120 columns or of 128, ONE Pallas
+  program (`ops/pallas_glm.glm_moments`) that reads tiles of X in place,
+  in the layout the chip keeps that width in (`glm_x_tile`), and
   keeps everything between the block and the sums in VMEM — margins,
   residual and curvature, every lane's weighted copy of the block, and one
   contraction of the block against all of them for the Grams and the
   gradient together, on one device and on every chip of a mesh alike;
-  everywhere else (the CPU, a float32 matrix, 121 to 128 columns, the
+  everywhere else (the CPU, a float32 matrix, 121 to 127 columns, the
   feature tiles past 128, the tileplane source steps) the XLA scan over
   row blocks described next (`_moments_blocks`), the same sums;
 - lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]);
@@ -646,8 +647,8 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     host unstandardizes once at the end); mean/std are applied on the fly
     per block, so no standardized [n, d] copy is materialized per round.
     glm_round_kernel(d, dtype, Lb) names the body of the pass over X (one
-    Mosaic program, or an XLA scan over row blocks); the iteration around
-    it is one.
+    Mosaic program, which reads X in the layout glm_x_tile(d) names, or an
+    XLA scan over row blocks); the iteration around it is one.
     The while cond early-exits as soon as EVERY bucket lane's delta clears
     tol, so a round never burns budget on an already-converged bucket.
     Returns (B [Lb, d] standardized space, b0 [Lb], delta [Lb], iters)."""
@@ -655,6 +656,7 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     Lb = sel.shape[1]
     tiled, d_work, bt, tile_pairs = _tiling(d)
     fused = glm_round_kernel(d, X.dtype, Lb) == "pallas_fused"
+    x_tile = glm_x_tile(d)
     if d_work > d:
         dp = d_work - d
         X = jnp.pad(X, ((0, 0), (0, dp)))
@@ -683,8 +685,9 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
         Bt = B.astype(X.dtype)                          # [Lb, d]
         if fused:
             moments = pallas_glm.glm_moments(
-                X.T, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std,
-                loss=loss)
+                X if x_tile == "cols_minor" else X.T, y_rows, w_rows,
+                fold_masks, sel, Bt, b0, mean, std, loss=loss,
+                x_tile=x_tile)
         else:
             moments = _moments_blocks(blocks, sel, Bt.T, b0, mean, std,
                                       loss=loss, axis_name=axis_name)
@@ -752,6 +755,27 @@ def _sharded_round_fn(mesh, loss, fit_intercept):
 pallas_hist.register_cache_consumer(sweep_glm_round)
 _sharded_round_fn.clear_cache = _sharded_round_fn.cache_clear
 pallas_hist.register_cache_consumer(_sharded_round_fn)
+
+
+def glm_round_temp_bytes(X, y, w, fold_masks, bucket: int, *, loss: str,
+                         fit_intercept: bool = True) -> int:
+    """Bytes of temporaries the compiled one-device round program of this
+    bucket holds beside its arguments (the compiler's `memory_analysis()`):
+    the counter in which a padded or re-laid-out copy of X would show,
+    whichever body runs the pass. Lowered from the arrays' shapes and
+    compiled (a load, where the persistent cache holds the program the
+    sweep ran): ask once, after a warm-up, not inside a timed job."""
+    f32 = jnp.float32
+    F, d = fold_masks.shape[0], X.shape[1]
+
+    def shape(*dims, dtype=f32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+    compiled = sweep_glm_round.lower(
+        X, y, w, fold_masks, shape(F, bucket), shape(bucket), shape(bucket),
+        shape(bucket, d), shape(bucket), shape(d), shape(d),
+        shape(dtype=jnp.int32), shape(), loss=loss,
+        fit_intercept=fit_intercept).compile()
+    return int(compiled.memory_analysis().temp_size_in_bytes)
 
 
 def round_psum_bytes(bucket: int, d: int) -> int:
@@ -1115,7 +1139,7 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
         with _collector.trace_span(
                 f"glm_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
                 active=int(k), iters_budget=int(budget), kernel=kernel,
-                shards=shards,
+                body=kernel, x_tile=glm_x_tile(d), shards=shards,
                 psums=psums, psum_bytes=psums * round_psum_bytes(Lb, d)), \
                 _podtrace.pod_round(st["rounds"], bucket=int(Lb),
                                     active=int(k)):
@@ -1371,22 +1395,26 @@ def mlr_gram_factor(X: jax.Array, w: jax.Array, fold_masks: jax.Array,
 
 def round_kernel(d: int, tile_rows: int = 128) -> str:
     """Which body a round's pass over a [n, d] matrix runs, for the round
-    families that have a fused one (the multinomial, the wide binary and,
-    through `glm_round_kernel`, the narrow binary rounds): "pallas_fused"
+    families whose fused body reads row tiles of X.T (the multinomial and
+    the wide binary rounds and, through `glm_round_kernel`, the narrow
+    binary rounds of a width that is no multiple of 128): "pallas_fused"
     (ops/pallas_softmax.mlr_gradient, ops/pallas_wide.wide_gradient,
     ops/pallas_glm.glm_moments: one Mosaic program a pass) where the
     backend has one, as the tree kernels choose theirs
     (pallas_hist.available()), else "xla_blocks" (_mlr_gradient_blocks,
     _wide_gradient_blocks, _moments_blocks). A matrix whose width fills its
     last 128-column group to within a sublane tile (a multiple of 128, or 1
-    to 7 columns short of one) stays with the blocks: rows-minor it would
-    pad to the same size, so the chip keeps it columns-minor, X.T is not
-    the layout it has and the program would hold a transposed copy of it
-    (compiled for a v5e: 6.4 GB at 25M x 128, a whole copy at 121 to 127
-    columns; 64, 100, 120 and 4 104 columns live rows-minor and are read in
-    place). `tile_rows` is how many rows of X a grid step of the kernel can
-    hold in VMEM at this width: under 128 there is no tile, and the blocks
-    run (the narrow kernels tile any width they are routed;
+    to 7 columns short of one) stays with the blocks HERE: rows-minor it
+    would pad to the same size, so the chip keeps it columns-minor,
+    X.T is not the layout it has and a program that read
+    X.T would hold a transposed copy of it (compiled for a v5e: 6.4 GB at
+    25M x 128, a whole copy at 121 to 127 columns; 64, 100, 120 and 4 104
+    columns live rows-minor and are read in place). The narrow binary
+    rounds have a tile form for a multiple of 128 (`glm_round_kernel`); the
+    multinomial and the wide rounds, and 121 to 127 columns everywhere, do
+    not yet. `tile_rows` is how many rows of X a grid step of the kernel
+    can hold in VMEM at this width: under 128 there is no tile, and the
+    blocks run (the narrow kernels tile any width they are routed;
     pallas_wide.tile_rows)."""
     if -(-d // 8) * 8 % 128 == 0 or tile_rows < 128 \
             or not pallas_hist.available():
@@ -1405,19 +1433,38 @@ def wide_round_kernel(d: int, dtype) -> str:
     return round_kernel(d, pallas_wide.tile_rows(d))
 
 
+def glm_x_tile(d: int) -> str:
+    """Which tile form the binary rounds' fused pass reads a resident
+    [n, d] matrix in (`pallas_glm.glm_moments`' `x_tile`), from the width
+    alone: "cols_minor" ([rows, d] tiles of X itself, the columns on the
+    lanes) where the width is whole 128-column groups, which the chip keeps
+    columns-minor; "rows_minor" ([d, rows] tiles of X.T) everywhere else.
+    (121 to 127 columns the chip keeps columns-minor too, and no tile form
+    reads them in place: `round_kernel` leaves them to the blocks.)"""
+    return "cols_minor" if d % 128 == 0 else "rows_minor"
+
+
 def glm_round_kernel(d: int, dtype, lanes: int) -> str:
     """`round_kernel` for the binary IRLS rounds (_round_core): the fused
     pass (ops/pallas_glm.glm_moments) is written for the narrow Gram of a
     bfloat16 matrix, every lane's [d, d] block of it in one float32
-    output block in VMEM. Past TRI_MAX_D columns the feature-tiled scan
-    runs, a float32 matrix contracts in another precision than the blocks
-    give it (one bfloat16 pass), and a bucket whose sums outgrow the VMEM
-    one kernel may claim has no tile: all three stay with the XLA blocks,
-    as do 121 to 128 columns and a backend without Mosaic
-    (`round_kernel`)."""
+    output block in VMEM, in two tile forms that the width chooses
+    (`glm_x_tile`): up to 120 columns row tiles of X.T, at 128 columns
+    [rows, 128] tiles of X turned over chunk by chunk in VMEM, each the
+    layout the chip already keeps, so neither program holds a second copy
+    of X. Past TRI_MAX_D columns the feature-tiled scan runs, a float32
+    matrix contracts in another precision than the blocks give it (one
+    bfloat16 pass), and a bucket whose sums outgrow the VMEM one kernel may
+    claim has no tile (at 128 columns a 64-lane bucket holds 49 MiB and a
+    128-lane one 89 MiB of a v5e's 96; 256 lanes do not fit): all three
+    stay with the XLA blocks, as do 121 to 127 columns (columns-minor, and
+    no whole 128-column group: `round_kernel`) and a backend without
+    Mosaic."""
     if d > TRI_MAX_D or jnp.dtype(dtype) != jnp.bfloat16 \
             or pallas_glm.vmem_bytes(d, lanes) > pallas_hist._vmem_limit():
         return "xla_blocks"
+    if d % 128 == 0:
+        return "pallas_fused" if pallas_hist.available() else "xla_blocks"
     return round_kernel(d)
 
 

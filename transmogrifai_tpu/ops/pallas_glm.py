@@ -177,11 +177,15 @@ def _padded(d: int, lanes: int, dtype) -> tuple:
 
 
 def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
-    """What the kernel keeps in VMEM for a bucket of `lanes`: the weighted
-    blocks of every chunk of a body, the float32 sums and the tile of X.T,
-    y, w and the fold masks twice each for the pipeline's buffers. (The
-    float32 products before the cast never exist whole: compiled for a
-    v5e, 256 lanes of 127 columns fit its 96 MiB.)"""
+    """What the kernel keeps in VMEM for a bucket of `lanes`, in either
+    tile form: the weighted blocks of every chunk of a body, the float32
+    sums and the tile of X (as X.T or as X: the same bytes), y, w and the
+    fold masks twice each for the pipeline's buffers. (The float32 products
+    before the cast never exist whole: compiled for a v5e, 256 lanes of 127
+    columns fit its 96 MiB.) At 128 columns a 64-lane bucket holds 32 MiB
+    of weighted blocks, 8 MiB of sums and 9 MiB of tiles, 49 MiB in all,
+    and a 128-lane bucket 89 MiB (both compile for a v5e); 256 lanes hold
+    170 MiB, and `glm_round_kernel` leaves that bucket to the blocks."""
     dp, lp = _padded(d, lanes, dtype)
     item = jnp.dtype(dtype).itemsize
     tile = _CHUNK * _UNROLL * _TILE_BODIES
@@ -200,35 +204,52 @@ def dense_rows(v, n_rows=None):
                    (0, _round_up(n, _tile_rows(n)) - n)).reshape(-1, 128)
 
 
-@functools.partial(jax.jit, static_argnames=("loss", "n_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("loss", "n_rows", "interpret",
+                                             "x_tile"))
 def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
-                loss: str, n_rows=None, interpret: bool = False):
+                loss: str, n_rows=None, interpret: bool = False,
+                x_tile: str = "rows_minor"):
     """(gA [lanes, d], hA [lanes, d, d], g0A [lanes], h0A [lanes]) float32:
     the sums over the first `n_rows` rows (default: all) of R xs', S xs xs',
     R and S, where xs is the standardised row in the matrix's dtype, R and S
     the loss's residual and curvature at xs' B + b0 times the lane's fold
     weight — one Newton iteration's pass of `_round_core` for a lane bucket.
 
-    XT [d, n] is X.T, the layout a resident matrix of such a width already
-    has on the chip (no padded or re-laid-out copy is made of it: the last
-    tile reads past n and masks); y_rows, w_rows are `dense_rows` of y and
-    w; fold_masks [F, n]; sel [F, lanes] maps lanes to folds; Bt [lanes, d]
-    the coefficients in the matrix's dtype; b0 [lanes]; mean, std [d].
-    Columns pad to whole sublane tiles with zeros, cut from what is
-    returned."""
+    The matrix comes in the layout it already has on the chip, named by
+    `x_tile` (`glm_sweep.glm_x_tile(d)`: what the width makes of it, no
+    choice of the caller's), and no padded or re-laid-out copy is made of
+    it in HBM: the last tile reads past n and masks.
+
+    - "rows_minor": XT [d, n] is X.T, how the chip keeps a matrix whose
+      width does not fill its last 128-column group (64, 100, 120
+      columns); a grid step reads a [d, tile] tile of it.
+    - "cols_minor": XT is X itself, [n, d] with d a multiple of 128, which
+      the chip keeps as it is written; a grid step reads a [tile, d] tile,
+      standardises a chunk of it with the columns on the lanes and turns
+      the chunk over in VMEM (one float32 transpose of [chunk, d] a chunk),
+      after which the two forms are one computation.
+
+    y_rows, w_rows are `dense_rows` of y and w; fold_masks [F, n]; sel
+    [F, lanes] maps lanes to folds; Bt [lanes, d] the coefficients in the
+    matrix's dtype; b0 [lanes]; mean, std [d]. Columns pad to whole sublane
+    tiles with zeros, cut from what is returned."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
-    d, n_buf = XT.shape
+    cols_minor = x_tile == "cols_minor"
+    n_buf, d = XT.shape if cols_minor else XT.shape[::-1]
     n = n_buf if n_rows is None else int(n_rows)
     F, lanes = sel.shape
     dp, lp = _padded(d, lanes, XT.dtype)
+    if cols_minor and d % 128:
+        raise ValueError(f"a cols_minor tile is whole 128-column groups, "
+                         f"not {d} columns")
     tile = _tile_rows(n)
 
     def column(v, fill):
-        return jnp.pad(v.astype(f32), (0, dp - d),
-                       constant_values=fill).reshape(dp, 1)
+        v = jnp.pad(v.astype(f32), (0, dp - d), constant_values=fill)
+        return v.reshape((1, dp) if cols_minor else (dp, 1))
 
     def by_rows(shape, index):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
@@ -245,10 +266,16 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
                  jax.ShapeDtypeStruct((dp, _round_up(lp, 128)), f32),
                  jax.ShapeDtypeStruct((lp, 128), f32),
                  jax.ShapeDtypeStruct((lp, 128), f32))
+    if cols_minor:
+        kernel = functools.partial(_kernel_cols, n=n, tile=tile, loss=loss)
+        x_spec = by_rows((tile, d), lambda i: (i, 0))
+    else:
+        kernel = functools.partial(_kernel, n=n, d=d, tile=tile, loss=loss)
+        x_spec = by_rows((dp, tile), lambda i: (0, i))
     h, g, g0, h0 = pl.pallas_call(
-        functools.partial(_kernel, n=n, d=d, tile=tile, loss=loss),
+        kernel,
         grid=(-(-n // tile),),
-        in_specs=[by_rows((dp, tile), lambda i: (0, i)), dense, dense,
+        in_specs=[x_spec, dense, dense,
                   by_rows((F, tile), lambda i: (0, i))]
         + [whole(a) for a in resident],
         out_specs=tuple(whole(s) for s in out_shape),
@@ -261,3 +288,79 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     hA = h.reshape(dp, lp, dp).transpose(1, 2, 0)
     return (g[:d, :lanes].T, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes],
             h0.sum(axis=1)[:lanes])
+
+
+def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
+                 mean_ref, std_ref, h_ref, g_ref, g0_ref, h0_ref, *, n, tile,
+                 loss):
+    """`_kernel` for a [tile, d] tile of X, the columns on the lanes: a
+    chunk is standardised as it lies, turned over ([chunk, d] -> [d, chunk],
+    float32, in VMEM) and cast, and from there on every line is `_kernel`'s
+    (kept apart from it, and after it in the file, so that the 64-column
+    body keeps its source lines and with them its place in the compile
+    cache). d is whole 128-column groups: no column is padded."""
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+    chunk, groups = _CHUNK, _CHUNK // 128
+    i = pl.program_id(0)
+    d, dtype = x_ref.shape[1], x_ref.dtype
+    lanes, folds = selT_ref.shape
+    pack = 8 * 4 // jnp.dtype(dtype).itemsize
+    rc = residual_curvature(loss)
+    over_rows = (((1,), (1,)), ((), ()))
+
+    @pl.when(i == 0)
+    def _():
+        for ref in (h_ref, g_ref, g0_ref, h0_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    x_rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, d), 0)
+    f_cols = jax.lax.broadcasted_iota(jnp.int32, (folds, chunk), 1)
+    bt, b0, selT = bt_ref[...], b0_ref[...], selT_ref[...]
+    mean, std = (jnp.broadcast_to(v[...], (chunk, d))
+                 for v in (mean_ref, std_ref))
+
+    def lane_sums(ref, V):
+        part = V[:, 0:128]
+        for k in range(1, groups):
+            part = part + V[:, k * 128:(k + 1) * 128]
+        ref[...] += part
+
+    def one_chunk(j):
+        off = pl.multiple_of(j * chunk, chunk)
+        left = n - (i * tile + off)
+        xs = jnp.where(
+            x_rows < left,
+            (x_ref[pl.ds(off, chunk), :].astype(f32) - mean) / std,
+            0.0).T.astype(dtype)                                  # [d, c]
+        eta = jnp.dot(bt, xs, preferred_element_type=f32) + b0    # [L, c]
+        sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
+        y_row, w_row = (jnp.concatenate(
+            [v[k:k + 1, :] for k in range(groups)], axis=1)
+            for v in (y_ref[sub, :], w_ref[sub, :]))              # [1, c]
+        r0, s0 = rc(eta, y_row)
+        mw = jnp.where(f_cols < left,
+                       m_ref[:, pl.ds(off, chunk)] * w_row, 0.0)
+        wl = selT[:, 0:1] * mw[0:1, :]
+        for f in range(1, folds):
+            wl = wl + selT[:, f:f + 1] * mw[f:f + 1, :]           # [L, c]
+        R, S = r0 * wl, s0 * wl
+        lane_sums(g0_ref, R)
+        lane_sums(h0_ref, S)
+        if lanes % pack:
+            R = jnp.concatenate(
+                [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
+        g_ref[:, 0:R.shape[0]] += jax.lax.dot_general(
+            xs, R.astype(dtype), over_rows, preferred_element_type=f32)
+        h_ref[...] += jax.lax.dot_general(
+            xs, (S[:, None, :] * xs.astype(f32)[None, :, :]).astype(dtype)
+            .reshape(lanes * d, chunk), over_rows,
+            preferred_element_type=f32)
+
+    def body(j, carry):
+        for u in range(_UNROLL):
+            one_chunk(_UNROLL * j + u)
+        return carry
+
+    jax.lax.fori_loop(0, tile // (chunk * _UNROLL), body, 0)
