@@ -75,7 +75,7 @@ def _fused(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
 def _blocks(X, y, w, masks, sel, Bt, b0, mean, std, loss):
     """_round_core's XLA body over the same inputs."""
     c = min(GS._row_block(X.shape[1]), X.shape[0])
-    return GS._moments_blocks(GS._blocked(X, y, w, masks, c), sel, Bt.T, b0,
+    return GS._moments_blocks(GS._blocked(X, y, w, masks, c), sel, Bt, b0,
                               mean, std, loss=loss)
 
 
@@ -161,6 +161,90 @@ def test_fused_pass_equals_the_xla_body(case, small_tiles):
     again = _fused(*args, n_pad=n_pad)
     assert all(np.array_equal(np.asarray(a), np.asarray(b))
                for a, b in zip(got, again))
+
+
+# -- the coefficients' precision ----------------------------------------------
+
+# the two tile forms: X.T tiles at 64 columns, tiles of X itself at 128
+PRECISION_CASES = {"rows-minor": (1300, 64, 8, 6, "logistic", 236),
+                   "cols-minor": (700, 128, 64, 40, "logistic", 0)}
+
+
+def _inexact(args):
+    """`_problem`'s inputs with coefficients that bfloat16 does not hold:
+    every live one moved by a third of its own bfloat16 step."""
+    B = args[5].astype(F32)
+    return args[:5] + (B * (1 + 2.0 ** -9 / 3),) + args[6:]
+
+
+@pytest.mark.parametrize("case", PRECISION_CASES.values(),
+                         ids=PRECISION_CASES.keys())
+def test_margins_see_float32_coefficients_in_both_bodies(case, small_tiles):
+    """A float32 B that is NOT exact in the matrix's dtype reaches the
+    margins unrounded in the kernel and in the XLA body alike: the
+    intercept's sums (residual and curvature unrounded, so the margins'
+    precision is all they carry) agree to float32 rounding between the
+    bodies and with the twin, and sit where B's own rounding would not
+    leave them — the kernel handed bfloat16(B) reads 1e-4 or more away."""
+    n, d, Lb, live, loss, n_pad = case
+    args = _inexact(_problem(n, d, Lb, live, seed=n + d + Lb,
+                             wide_scales=d == 128)) + (loss,)
+    B = np.asarray(args[5])
+    assert (B[:live] != np.asarray(args[5].astype(BF16).astype(F32))[:live]) \
+        .mean() > 0.9
+    got = _fused(*args, n_pad=n_pad)
+    for a, b in zip(got, _chip_twin(*args)):
+        assert _rel(a, b) <= 1e-4
+    for a, b, tol in zip(got, _blocks(*args), (4e-3, 4e-3, 2e-6, 2e-6)):
+        assert _rel(a, b) <= tol
+    rounded = _fused(*args[:5], args[5].astype(BF16), *args[6:],
+                     n_pad=n_pad)
+    assert _rel(rounded[2], got[2]) >= 1e-4
+    assert all((np.asarray(v)[live:] == 0).all() for v in got)
+
+
+@pytest.mark.parametrize("case", PRECISION_CASES.values(),
+                         ids=PRECISION_CASES.keys())
+def test_exact_coefficients_give_the_one_part_sums_to_the_bit(
+        case, small_tiles, monkeypatch):
+    """Coefficients that ARE exact in the matrix's dtype leave every part
+    after the first zero, and the four sums are, bit for bit, those of the
+    program with one part — which is the kernel as it was before the
+    margins took parts (bt = Bt.astype(X.dtype), one slab)."""
+    n, d, Lb, live, loss, n_pad = case
+    args = _problem(n, d, Lb, live, seed=n + d + Lb,
+                    wide_scales=d == 128) + (loss,)
+    assert PG.n_parts(BF16) == 3 and PG.n_parts(F32) == 1
+    parts = np.asarray(PG.coefficient_parts(args[5].astype(F32), BF16)
+                       .astype(F32))
+    assert parts.shape == (3 * Lb, d) and (parts[Lb:] == 0).all()
+    got = _fused(*args, n_pad=n_pad)
+    got_f32 = _fused(*args[:5], args[5].astype(F32), *args[6:], n_pad=n_pad)
+    monkeypatch.setattr(PG, "n_parts", lambda dtype: 1)
+    PG.glm_moments.clear_cache()
+    one_part = _fused(*args, n_pad=n_pad)
+    for a, b, c in zip(got, got_f32, one_part):
+        assert np.array_equal(np.asarray(a), np.asarray(c))
+        assert np.array_equal(np.asarray(b), np.asarray(c))
+
+
+def test_coefficient_parts_sum_to_the_float32_coefficients():
+    """Three bfloat16 parts hold a float32 exactly (8 + 8 + 8 significant
+    bits), largest first; a float32 matrix takes B itself."""
+    rng = np.random.default_rng(0)
+    B = (rng.normal(size=(8, 64)) * 10.0 ** rng.integers(-6, 3, (8, 64))) \
+        .astype(np.float32)
+    parts = np.asarray(PG.coefficient_parts(jnp.asarray(B), BF16)
+                       .astype(F32)).reshape(3, 8, 64)
+    assert np.array_equal(parts[2] + parts[1] + parts[0], B)
+    assert np.array_equal(parts[0], np.asarray(
+        jnp.asarray(B).astype(BF16).astype(F32)))
+    assert (np.abs(parts[1]) <= np.abs(B) * 2.0 ** -8).all()
+    assert np.array_equal(np.asarray(PG.coefficient_parts(
+        jnp.asarray(B), F32)), B)
+    stacked = jnp.asarray(parts.reshape(24, 64))
+    assert np.array_equal(np.asarray(PG.margins(stacked, 8)), B)
+    assert np.array_equal(np.asarray(PG.margins(stacked.T, 8, axis=1)), B.T)
 
 
 @pytest.mark.parametrize("mosaic,no_pallas,d,dtype,lanes,vmem,says", [
@@ -308,3 +392,86 @@ def test_telemetry_and_span_name_the_fused_body_where_it_runs(backend):
     assert info_x["bucket_sizes"] == info["bucket_sizes"]
     np.testing.assert_allclose(B, B_x, rtol=0, atol=3e-3)
     np.testing.assert_allclose(b0, b0_x, rtol=0, atol=3e-3)
+
+
+# -- the rounds converge --------------------------------------------------------
+
+TOL, MAX_ITER = 1e-6, 50
+
+
+def _null_tracked(n=8000, raw=8, seed=0):
+    """A null-tracked table as transmogrify() makes it, bfloat16: `raw`
+    fields at scales 2^-4 .. 2^4 and missing rates 0.01 .. 0.5, each
+    followed by its 0/1 null indicator (the first at rate 0.01), the
+    missing entries filled with the observed mean (a point mass there)."""
+    rng = np.random.default_rng(seed)
+    rates = np.logspace(-2, np.log10(0.5), raw)
+    scale = 2.0 ** np.linspace(-4, 4, raw)
+    loc = scale * rng.uniform(0.25, 2.0, raw) * rng.choice([-1, 1], raw)
+    v = loc + scale * rng.normal(size=(n, raw))
+    miss = rng.uniform(size=(n, raw)) < rates
+    v = np.where(miss, np.nanmean(np.where(miss, np.nan, v), 0), v)
+    X = np.empty((n, 2 * raw), np.float32)
+    X[:, 0::2], X[:, 1::2] = v, miss
+    Xs = (X - X.mean(0)) / X.std(0)
+    beta = rng.normal(size=2 * raw) * 2.5 / np.sqrt(2 * raw)
+    y = (rng.uniform(size=n) < 1 / (1 + np.exp(-(Xs @ beta - 1.5)))) \
+        .astype(np.float32)
+    fold = rng.integers(0, FOLDS, n)
+    masks = (fold[None] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    return jnp.asarray(X).astype(BF16), jnp.asarray(y), jnp.asarray(masks)
+
+
+def _converged_sweep(X, y, masks, standardize):
+    regs = np.float32([0.01, 0.1, 0.2])
+    st = GS._new_round_state(FOLDS * len(regs), X.shape[1])
+    _, _, info = GS.sweep_glm_streamed_rounds(
+        X, y, jnp.ones(X.shape[0], F32), masks, regs,
+        np.float32([0.1] * len(regs)), loss="logistic", max_iter=MAX_ITER,
+        tol=TOL, standardize=standardize, state=st)
+    return info, st
+
+
+@pytest.fixture(scope="module")
+def null_tracked_sweeps():
+    """The standardised sweep over the bfloat16 table, and the same sweep
+    over the float32 copy of the block its passes see — bfloat16((x - mean)
+    / std) as float32, not standardised again — whose margins never had a
+    cast to lose the coefficients in."""
+    X, y, masks = _null_tracked()
+    assert (np.asarray(X.astype(F32))[:, 1] != 0).mean() < 0.015
+    mean, std = GS.glm_standardize_stats(X, jnp.ones(X.shape[0], F32))
+    assert float(std.min()) < 0.1 and float(std.max()) > 10
+    block = ((X.astype(F32) - mean) / std).astype(BF16).astype(F32)
+    return {"bf16": _converged_sweep(X, y, masks, True),
+            "f32": _converged_sweep(block, y, masks, False)}
+
+
+@pytest.mark.parametrize("which", ["bf16", "f32"])
+def test_every_lane_retires_at_tol_and_none_at_the_cap(null_tracked_sweeps,
+                                                       which):
+    """The step is taken where the gradient was read, so delta falls
+    through tol: no lane runs to max_iter, on the bfloat16 matrix as on its
+    float32 copy. (With the margins at bfloat16(B) every lane's delta
+    stayed at ~3e-3 and all nine ran to the cap, 55 passes.)"""
+    info, st = null_tracked_sweeps[which]
+    assert info["lanes_at_cap"] == 0
+    assert info["lanes_retired"] == info["lanes_total"] == 9
+    assert (st["delta"] <= TOL).all() and st["retired"].all()
+    assert int(st["iters"].max()) < MAX_ITER
+    assert info["data_passes"] < MAX_ITER
+
+
+def test_the_rounds_on_bfloat16_are_the_rounds_on_the_float32_copy(
+        null_tracked_sweeps):
+    """The same retirement history and the same coefficients to float32
+    noise (the parts' sum is xs' B to float32): the matrix's dtype rounds
+    the block, once, and no longer the iterate."""
+    (info, st), (info_f, st_f) = (null_tracked_sweeps[k]
+                                  for k in ("bf16", "f32"))
+    for key in ("data_passes", "iters_per_round", "bucket_sizes",
+                "padded_lane_passes"):
+        assert info[key] == info_f[key]
+    assert np.abs(st_f["B"]).max() > 0.5
+    np.testing.assert_allclose(st["B"], st_f["B"], rtol=0, atol=2e-6)
+    np.testing.assert_allclose(st["b0"], st_f["b0"], rtol=0, atol=2e-6)

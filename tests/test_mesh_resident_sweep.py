@@ -264,6 +264,66 @@ def test_a_sharded_matrix_sweeps_on_its_mesh_through_the_selector(
     assert val.last_streamed_telemetry["rows_per_shard"] == N // 4
 
 
+# -- the rounds converge ----------------------------------------------------------
+
+LAYOUTS = ("one_device", "mesh", "source")
+
+
+@pytest.fixture(scope="module")
+def converged(mesh, data):
+    """sweep_glm_streamed_rounds over the bfloat16 matrix on one device,
+    row-sharded on the mesh (the shard_map form of the round), and as a
+    RowSource (the tileplane steps): (info, state) of each."""
+    from transmogrifai_tpu.parallel import tileplane as TP
+    rng = np.random.default_rng(1)
+    fold = rng.integers(0, FOLDS, size=N)
+    masks = (fold[None, :] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    w = np.ones(N, np.float32)
+    regs = np.float32([g["reg_param"] for g in GRIDS])
+    alphas = np.float32([g["elastic_net_param"] for g in GRIDS])
+    inputs = {
+        "one_device": (data["X1"], data["y1"], jnp.asarray(w),
+                       jnp.asarray(masks), {}),
+        "mesh": (data["Xs"], data["ys"],
+                 jax.device_put(w, batch_sharding(mesh, 1)),
+                 jax.device_put(masks, sharded_along(mesh, 1, 2)),
+                 {"mesh": mesh}),
+        "source": (TP.ArraySource(np.asarray(data["X1"]), data["y"], w,
+                                  masks.T.copy(), chunk_rows=1000),
+                   None, None, None, {})}
+    out = {}
+    for name, (X, y, w_, m, kw) in inputs.items():
+        st = GS._new_round_state(FOLDS * len(GRIDS), D)
+        _, _, info = GS.sweep_glm_streamed_rounds(
+            X, y, w_, m, regs, alphas, loss="logistic", max_iter=15,
+            tol=1e-6, standardize=False, state=st, **kw)
+        out[name] = (info, st)
+    return out
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_lanes_retire_at_tol_in_every_layout(converged, layout):
+    """The margins see the float32 coefficients in the shard_map form and
+    in the source rounds as on one device: every lane's delta clears tol
+    inside max_iter 15 (with the margins at bfloat16(B) 17 of the 18 lanes
+    ran to the cap on one device and on the mesh, delta 3e-3), the same
+    retirement history in all three, one psum an iteration on the mesh."""
+    info, st = converged[layout]
+    assert info["driver"] == ("tileplane" if layout == "source"
+                              else "resident")
+    assert info["lanes_at_cap"] == 0
+    assert info["lanes_retired"] == info["lanes_total"] == 18
+    assert (st["delta"] <= 1e-6).all()
+    assert info["data_passes"] < 15
+    one = converged["one_device"][0]
+    for key in ("data_passes", "iters_per_round", "bucket_sizes"):
+        assert info[key] == one[key]
+    assert info["psums"] == (layout == "mesh") * (
+        info["data_passes"] + info["glm_rounds"])
+    np.testing.assert_allclose(st["B"], converged["one_device"][1]["B"],
+                               rtol=0, atol=1e-5)
+
+
 # -- the programs -----------------------------------------------------------------
 
 @pytest.mark.parametrize("bucket", [8, 32])

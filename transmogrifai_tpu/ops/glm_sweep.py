@@ -28,7 +28,11 @@ every thread refitting against the same cached DataFrame):
   everywhere else (the CPU, a float32 matrix, 121 to 127 columns, the
   feature tiles past 128, the tileplane source steps) the XLA scan over
   row blocks described next (`_moments_blocks`), the same sums;
-- lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]);
+- lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]), B the
+  float32 coefficients the iteration carries as their exact parts of the
+  matrix's dtype (`pallas_glm.coefficient_parts`: [d, 3 L] against a
+  bfloat16 block): a step taken at coefficients rounded to that dtype
+  never settles under `tol`, and every lane ran to `max_iter`;
 - every lane's weighted Gram from ONE batched einsum 'cl,cd,ce->lde'
   with S [c, L] the per-lane curvature weights (narrow path, d <= 128).
   A compressed upper-triangle form (xf[:, iu0] * xf[:, iu1] then an
@@ -588,20 +592,26 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
 
 # -- round kernel + host retirement driver (IRLS losses) ---------------------
 
-def _moments_blocks(blocks, sel, Bt, b0, mean, std, *, loss,
+def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
                     axis_name: Optional[str] = None, acc0=None):
     """One Newton iteration's pass over X as an XLA scan over row blocks
     (`blocks` is `_blocked`'s), for a backend without Mosaic and for the
     shapes the fused pass leaves alone (`glm_round_kernel`): (gA [Lb, d],
     Hessian blocks, g0A [Lb], h0A [Lb]), the sums over rows of R xs', S xs
     xs', R and S (pallas_glm.glm_moments is the same sums in one program).
-    Bt [d, Lb] is the coefficients in the matrix's dtype; mean / std are
-    column-padded to the Gram geometry's width; `acc0` are sums to go on
-    from (the tileplane steps' carry) in place of zeros."""
+    B [Lb, d] is the coefficients, float32 as the iteration carries them:
+    the margins see them unrounded, as the fused pass's do and by the same
+    split (`pallas_glm.coefficient_parts`: the three-part product against a
+    bfloat16 block, which keeps the matrix unit's bfloat16 path; a float32
+    block has one part and contracts as it did) — a step taken at rounded
+    coefficients never settles under tol. mean / std are column-padded to
+    the Gram geometry's width; `acc0` are sums to go on from (the tileplane
+    steps' carry) in place of zeros."""
     rc = _residual_curvature(loss)
     d_work, Lb = mean.shape[0], sel.shape[1]
     tiled, _, bt, tile_pairs = _tiling(d_work)
     hess_blocks, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
+    Bparts = pallas_glm.coefficient_parts(B, blocks[0].dtype).T
 
     def body(acc, sl):
         x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
@@ -611,8 +621,9 @@ def _moments_blocks(blocks, sel, Bt, b0, mean, std, *, loss,
         # materialized-Xs route
         xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
                   / std[None, :]).astype(x_blk.dtype)
-        eta = jnp.matmul(xs_low, Bt,
-                         preferred_element_type=jnp.float32) + b0[None, :]
+        eta = pallas_glm.margins(
+            jnp.matmul(xs_low, Bparts, preferred_element_type=jnp.float32),
+            Lb, axis=1) + b0[None, :]
         r0, s0 = rc(eta, y_blk[:, None])            # [c, Lb]
         wlf = m_blk.T * w_blk[:, None]              # [c, F]
         wl = jnp.matmul(wlf, sel,
@@ -649,6 +660,12 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     glm_round_kernel(d, dtype, Lb) names the body of the pass over X (one
     Mosaic program, which reads X in the layout glm_x_tile(d) names, or an
     XLA scan over row blocks); the iteration around it is one.
+    B is float32 in the carry AND in the pass: the margins xs' B + b0 see
+    the coefficients the step updates, not their cast to the matrix's dtype
+    (either body splits B into exact parts of that dtype), so the step is
+    taken where the gradient was read, delta falls through tol, and a lane
+    retires there and not at max_iter; the other operands of the matrix
+    unit (the block, R, S x block) stay in the matrix's dtype.
     The while cond early-exits as soon as EVERY bucket lane's delta clears
     tol, so a round never burns budget on an already-converged bucket.
     Returns (B [Lb, d] standardized space, b0 [Lb], delta [Lb], iters)."""
@@ -682,14 +699,13 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     assemble = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)[1]
 
     def accumulate(B, b0):
-        Bt = B.astype(X.dtype)                          # [Lb, d]
         if fused:
             moments = pallas_glm.glm_moments(
                 X if x_tile == "cols_minor" else X.T, y_rows, w_rows,
-                fold_masks, sel, Bt, b0, mean, std, loss=loss,
+                fold_masks, sel, B, b0, mean, std, loss=loss,
                 x_tile=x_tile)
         else:
-            moments = _moments_blocks(blocks, sel, Bt.T, b0, mean, std,
+            moments = _moments_blocks(blocks, sel, B, b0, mean, std,
                                       loss=loss, axis_name=axis_name)
         # ONE collective an iteration: the four accumulators merge over
         # the mesh together (round_psum_bytes)
@@ -826,9 +842,8 @@ def _source_round_step(carry, xt, yt, wt, mt, B, b0, sel, mean, std, *,
         xt = jnp.pad(xt, ((0, 0), (0, d_work - xt.shape[1])))
     c = min(_ROW_BLOCK_WIDE if _tiling(d_work)[0] else _row_block(d_work),
             xt.shape[0])
-    return _moments_blocks(_blocked(xt, yt, wt, mt.T, c), sel,
-                           B.T.astype(xt.dtype), b0, mean, std, loss=loss,
-                           acc0=carry)
+    return _moments_blocks(_blocked(xt, yt, wt, mt.T, c), sel, B, b0,
+                           mean, std, loss=loss, acc0=carry)
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))

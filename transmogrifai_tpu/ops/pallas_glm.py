@@ -17,10 +17,17 @@ of the array; here the weighted copies are the stationary operand and fill
 it. The grid is one sequential axis, so the order of every sum is fixed and
 a job repeats bit for bit.
 
-Precision is what the XLA body gets on the chip: the matrix unit's operands
-in the matrix's dtype (the standardised block, the coefficients, the
-residual x weight and the curvature x weight x block), float32 sums; the
-intercept's two sums take residual and curvature unrounded.
+Precision: the matrix unit's operands in the matrix's dtype (the
+standardised block, the residual x weight and the curvature x weight x
+block), float32 sums; the intercept's two sums take residual and curvature
+unrounded. The coefficients are the one operand that is NOT rounded: the
+margins xs' B + b0 see the float32 B the iteration carries, as its exact
+split into parts of the matrix's dtype (`coefficient_parts`: three for
+bfloat16, one left operand of the margins' contraction, the slabs added in
+float32). A Newton step taken at rounded coefficients has no fixed point —
+near the optimum it moves B by B's own rounding error, 2^-9 |B| a step, and
+no lane's delta ever clears a tolerance of 1e-6 (PERF.md, PR 38) — and the
+XLA body (`glm_sweep._moments_blocks`) splits B the same way.
 
 Kept apart from ops/pallas_hist.py, ops/pallas_softmax.py and
 ops/pallas_wide.py on purpose: a Mosaic body carries its source locations,
@@ -75,6 +82,43 @@ def residual_curvature(loss: str):
     return rc
 
 
+def n_parts(dtype) -> int:
+    """Parts of `dtype` that hold a float32's 24 significant bits."""
+    return -(-24 // (jnp.finfo(dtype).nmant + 1))
+
+
+def coefficient_parts(B, dtype):
+    """[parts x lanes, d] in `dtype`: the float32 coefficients B [lanes, d]
+    as parts whose float32 sum is B exactly, the largest first and one
+    above the other — as many as 24 significant bits take of `dtype`'s
+    (three of bfloat16, one of float32: B itself). A product of a part with
+    a `dtype` block is exact in float32, so the contraction of the stack
+    against a block, its slabs added (`margins`), is xs' B to float32.
+    Coefficients that are exact in `dtype` leave every part after the first
+    zero. Each part is cut by `reduce_precision`: inside a fusion the chip
+    may hand a float32 -> bfloat16 -> float32 round trip back unrounded, and
+    the next part would be zero (PERF.md, PR 29)."""
+    info, rest, parts = jnp.finfo(dtype), B.astype(jnp.float32), []
+    for _ in range(n_parts(dtype)):
+        part = jax.lax.reduce_precision(rest, exponent_bits=info.nexp,
+                                        mantissa_bits=info.nmant)
+        parts.append(part.astype(dtype))
+        rest = rest - part
+    return jnp.concatenate(parts, axis=0)
+
+
+def margins(stacked, lanes: int, axis: int = 0):
+    """The float32 sum of the slabs of `lanes` that a contraction of
+    `coefficient_parts`' stack leaves along `axis`, the smallest part's
+    first."""
+    slabs = [jax.lax.slice_in_dim(stacked, k, k + lanes, axis=axis)
+             for k in range(0, stacked.shape[axis], lanes)]
+    eta = slabs[-1]
+    for slab in slabs[-2::-1]:
+        eta = eta + slab
+    return eta
+
+
 def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
             std_ref, h_ref, g_ref, g0_ref, h0_ref, *, n, d, tile, loss):
     import jax.experimental.pallas as pl
@@ -122,7 +166,8 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
             x_ok = x_ok & feat_ok
         xs = jnp.where(x_ok, (xT_ref[:, cols].astype(f32) - mean) / std,
                        0.0).astype(dtype)                        # [dp, c]
-        eta = jnp.dot(bt, xs, preferred_element_type=f32) + b0   # [L, c]
+        eta = margins(jnp.dot(bt, xs, preferred_element_type=f32),
+                      lanes) + b0                                # [L, c]
         # y and w come dense, 128 rows of X a sublane (`dense_rows`)
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
@@ -180,7 +225,9 @@ def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
     """What the kernel keeps in VMEM for a bucket of `lanes`, in either
     tile form: the weighted blocks of every chunk of a body, the float32
     sums and the tile of X (as X.T or as X: the same bytes), y, w and the
-    fold masks twice each for the pipeline's buffers. (The float32 products
+    fold masks twice each for the pipeline's buffers, and twice the
+    coefficients' parts (three of a bfloat16 matrix: 0.1 MiB at 64 lanes
+    of 128 columns). (The float32 products
     before the cast never exist whole: compiled for a v5e, 256 lanes of 127
     columns fit its 96 MiB.) At 128 columns a 64-lane bucket holds 32 MiB
     of weighted blocks, 8 MiB of sums and 9 MiB of tiles, 49 MiB in all,
@@ -191,7 +238,8 @@ def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
     tile = _CHUNK * _UNROLL * _TILE_BODIES
     return _UNROLL * lp * dp * _CHUNK * item \
         + 2 * dp * (lp * dp + _round_up(lp, 128)) * 4 \
-        + 2 * tile * (dp * item + 8 * 4 + 2 * 4)
+        + 2 * tile * (dp * item + 8 * 4 + 2 * 4) \
+        + 2 * n_parts(dtype) * lp * dp * item
 
 
 def dense_rows(v, n_rows=None):
@@ -230,9 +278,13 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
       after which the two forms are one computation.
 
     y_rows, w_rows are `dense_rows` of y and w; fold_masks [F, n]; sel
-    [F, lanes] maps lanes to folds; Bt [lanes, d] the coefficients in the
-    matrix's dtype; b0 [lanes]; mean, std [d]. Columns pad to whole sublane
-    tiles with zeros, cut from what is returned."""
+    [F, lanes] maps lanes to folds; Bt [lanes, d] the coefficients, float32
+    as the iteration carries them (or any dtype that holds them): the
+    margins see them unrounded, through `coefficient_parts` made here once
+    a pass — coefficients that are exact in the matrix's dtype leave every
+    part but the first zero, and the sums are then, to the bit, those of
+    the one-part contraction; b0 [lanes]; mean, std [d]. Columns pad to
+    whole sublane tiles with zeros, cut from what is returned."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -258,7 +310,8 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
         return by_rows(a.shape, lambda i: (0, 0))
     dense = by_rows((tile // 128, 128), lambda i: (i, 0))
     resident = (
-        jnp.pad(Bt.astype(XT.dtype), ((0, lp - lanes), (0, dp - d))),
+        coefficient_parts(
+            jnp.pad(Bt, ((0, lp - lanes), (0, dp - d))), XT.dtype),
         jnp.pad(b0.astype(f32), (0, lp - lanes)).reshape(lp, 1),
         jnp.pad(sel.T.astype(f32), ((0, lp - lanes), (0, 0))),
         column(mean, 0.0), column(std, 1.0))
@@ -334,7 +387,8 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
             x_rows < left,
             (x_ref[pl.ds(off, chunk), :].astype(f32) - mean) / std,
             0.0).T.astype(dtype)                                  # [d, c]
-        eta = jnp.dot(bt, xs, preferred_element_type=f32) + b0    # [L, c]
+        eta = margins(jnp.dot(bt, xs, preferred_element_type=f32),
+                      lanes) + b0                                 # [L, c]
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
             [v[k:k + 1, :] for k in range(groups)], axis=1)
