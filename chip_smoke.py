@@ -354,8 +354,11 @@ def kernel_checks(calls, Xb_t, y, masks, margin, *, toy: bool) -> list:
             scores = jax.random.normal(k1, (L, N), jnp.float32) * 2.0
             wl = jnp.broadcast_to(masks[:1], (L, N)) \
                 * (jax.random.uniform(k2, (L, N)) < 0.7)
+            # (wl holds zeros and ones: the body and the payload parts the
+            # sweep's call ran are the ones replayed)
             got = M._binned_cum_counts_lanes_pallas(
-                scores, y, wl, bins, interpret=interpret)
+                scores, y, wl, bins, interpret=interpret,
+                unit_payload=bool(st.get("unit_payload", False)))
             ref = M._binned_cum_counts_lanes_jnp(scores, y, wl, bins)
             res["counts_exact"] = bool(all(
                 np.array_equal(np.asarray(g), np.asarray(r))

@@ -213,3 +213,86 @@ def test_fused_binary_round_compiles_for_the_four_chip_mesh(topo, as_v5e):
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 2
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 0.25 * n // 4 * d * 2
+
+
+def _heldout_eval_shapes(S, n, d, F, Gc, row=None, cols=None):
+    """_streamed_eval_heldout's arguments; `row` / `cols` as above."""
+    return (S((n, d), BF16, row), S((n,), F32, row), S((n,), F32, row),
+            S((F, n), F32, cols), S((F, Gc, d), F32), S((F, Gc), F32))
+
+
+@pytest.fixture
+def metric_programs(as_v5e, monkeypatch):
+    """The lane-batched binned counts take the pallas route, as on the
+    chip, and no metric program traced under another answer survives."""
+    from transmogrifai_tpu.automl.tuning import validators as V
+    from transmogrifai_tpu.ops import metrics_ops as M
+    monkeypatch.setattr(M, "_pallas_route", lambda: True)
+    fns = (V._streamed_eval_heldout, V._sharded_eval_heldout_fn)
+    for fn in fns:
+        fn.clear_cache()
+    yield V
+    for fn in fns:
+        fn.clear_cache()
+
+
+@pytest.mark.parametrize("n,Gc,unit", [
+    (25_000_000, 6, True),      # sweep-glm's metric pass: one payload part
+    (25_000_000, 6, False),     # sample weights handed in: three
+    (25_000_000, 8, True),      # sweep-glm-nulls128's chunk of 8 points
+    (1_000_003, 3, False),      # ragged rows
+], ids=["glm-unit", "glm-weights", "nulls-chunk8", "ragged"])
+def test_heldout_metric_pass_compiles_for_a_v5e(one_chip, metric_programs,
+                                                n, Gc, unit):
+    """The whole held-out-once metric program around the two-level
+    histogram body (ops/pallas_rank_hist.py): Mosaic takes the kernel —
+    the integer shifts and masks of the bins, the payload's cuts, the
+    [rows, 1, blk] x [1, hi, blk] broadcast and its merge, the contraction
+    over the block's rows — at the block the chip's VMEM is asked for."""
+    def S(shape, dt, _=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = metric_programs._streamed_eval_heldout.lower(
+        *_heldout_eval_shapes(S, n, 64, 5, Gc), unit, metric="au_pr",
+        rank_bins=4096).compile()
+    assert "_hist_two_level_jit" in compiled.as_text()
+    assert "_hist_pallas_jit" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("unit", [True, False], ids=["unit", "weights"])
+def test_lane_metric_call_compiles_for_a_v5e(one_chip, metric_programs,
+                                             unit):
+    """The tree route's fold_metrics form at sweep-gbt's shape: F = 1, the
+    ten lanes as slots over 100M flattened elements, 640 left rows a
+    payload part."""
+    from transmogrifai_tpu.ops import metrics_ops as M
+    L, n = 10, 10_000_000
+    fn = jax.jit(lambda s, y, wl: M.au_pr_binned_lanes(
+        s, y, wl, 4096, unit_payload=unit))
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((L, n), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((L, n), F32, sharding=one_chip)).compile()
+    assert "_hist_two_level_jit" in compiled.as_text()
+
+
+def test_heldout_metric_pass_compiles_for_the_four_chip_mesh(
+        topo, metric_programs):
+    """sweep-glm-4chip's metric pass: the two-level body inside the
+    shard_map over every chip's 32M local rows, ONE all-reduce of the
+    counts."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+    from transmogrifai_tpu.parallel.mesh import BATCH_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1),
+                (BATCH_AXIS, MODEL_AXIS))
+
+    def S(shape, dt, spec=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(
+            mesh, spec or Ps()))
+    compiled = metric_programs._sharded_eval_heldout_fn(
+        mesh, "au_pr", 4096).lower(*_heldout_eval_shapes(
+            S, 128_000_000, 64, 5, 6, Ps(BATCH_AXIS),
+            Ps(None, BATCH_AXIS)), True).compile()
+    text = compiled.as_text()
+    assert "_hist_two_level_jit" in text
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1

@@ -248,9 +248,12 @@ def test_set_pallas_enabled_toggles_and_clears_caches():
 
 def test_lanes_4096_bins_block_sizing():
     """The production rank-metric shape (4096 bins): block_rows shrinks
-    the tile, results still match the scatter path."""
+    the one-level body's tile; hist_pallas runs the two-level body there
+    (ops/pallas_rank_hist.py), and results still match the scatter path."""
     from transmogrifai_tpu.ops import metrics_ops as M
+    from transmogrifai_tpu.ops import pallas_rank_hist as RH
     assert PH.block_rows(4096) < PH._BLK
+    assert RH.hist_body(4096, False) == "two_level"
     rng = np.random.default_rng(17)
     L, n = 3, 700
     scores = jnp.asarray(rng.normal(size=(L, n)), jnp.float32)

@@ -194,6 +194,8 @@ def _route_case(route, monkeypatch):
             common, device_place=1, record=1,
             **{"sweep_fit:glm_vmapped:OpLogisticRegression": 1})
     if route == "mask_folds":
+        # the binned rank metric at any size: fold_metrics' lane route
+        monkeypatch.setattr(V, "BINNED_RANK_METRIC_MIN_ROWS", 0)
         grids = param_grid(eta=[0.1, 0.3])
         return OpXGBoostClassifier(num_round=3, max_depth=2, max_bins=8), \
             grids, dict(common, device_place=1, tree_bin=1, tree_fit=2,
@@ -298,6 +300,13 @@ def test_validate_is_spanned_phase_by_phase(route, tmp_path, monkeypatch):
             assert e["stats"]["lanes"] == 3 and e["stats"]["depth"] == 2
         tb, = named(events, "tmog.validate_phase:tree_bin")
         assert tb["stats"]["bins"] == 8 and tb["stats"]["configs"] == 2
+        # the lane-batched binned counts say which histogram body ran (off
+        # the TPU the scatter twins) and the parts of their payload: no
+        # sample weights, the validator's own masks
+        for e in named(events, "tmog.validate_phase:fold_metrics"):
+            assert e["stats"]["lanes"] == 3 and e["stats"]["depth"] == 2
+            assert e["stats"]["hist_body"] == "scatter"
+            assert e["stats"]["payload_parts"] == 1
 
 
 @pytest.mark.parametrize("case,n_grid,route,passes", [
@@ -334,6 +343,12 @@ def test_binned_sweep_eval_says_its_route_and_fetches_once(
     assert ev["stats"]["cells"] == n_grid
     tele = cv.last_streamed_telemetry
     assert (tele["eval_route"], tele["passes"]) == (route, passes)
+    # the histogram body (off the TPU the scatter twins) and the parts its
+    # payload goes in: one only where validate() made the masks itself
+    parts = 1 if case == "device_masks" else 3
+    for said in (ev["stats"], tele):
+        assert said["hist_body"] == "scatter"
+        assert said["payload_parts"] == parts
     fetches = named(events, "tmog.host_step:metric_fetch")
     assert len(fetches) == (1 if route == "heldout_once" else passes)
     assert all(inside(f, ev) for f in fetches)

@@ -145,18 +145,21 @@ def grid_fuse_max_failures() -> int:
     past. Read per sweep, like every other TMOG_GRID_FUSE_* knob."""
     return int(os.environ.get("TMOG_GRID_FUSE_MAX_FAILURES", "3"))
 
-def _lanes_metric_fn(metric: str, problem_type: str, rank_bins):
+def _lanes_metric_fn(metric: str, problem_type: str, rank_bins,
+                     unit_payload: bool = False):
     """(scores [L, n], labels [n], w_lanes [L, n]) -> [L] metric values
     when the metric has a lane-batched binned kernel, else None. Single
     source of the guard for every sweep path (streamed eval, tree fold
-    metrics)."""
+    metrics). `unit_payload`: validate()'s word that weights x labels are
+    zeros and ones (Validator._unit_payload), handed on to the kernel."""
     if not (rank_bins and problem_type == "binary"):
         return None
-    if metric == "au_pr":
-        return lambda s, y, wl: M.au_pr_binned_lanes(s, y, wl, rank_bins)
-    if metric == "au_roc":
-        return lambda s, y, wl: M.au_roc_binned_lanes(s, y, wl, rank_bins)
-    return None
+    lanes = {"au_pr": M.au_pr_binned_lanes,
+             "au_roc": M.au_roc_binned_lanes}.get(metric)
+    if lanes is None:
+        return None
+    return lambda s, y, wl: lanes(s, y, wl, rank_bins,
+                                  unit_payload=unit_payload)
 
 
 def _held_out_at_most_once(masks) -> bool:
@@ -198,9 +201,11 @@ def _streamed_confusion(X, y, vw, Bc, b0c, n_classes: int):
 
 @partial(jax.jit,
          static_argnames=("metric", "problem_type", "n_classes",
-                          "rank_bins", "chunk", "use_lanes"))
+                          "rank_bins", "chunk", "use_lanes",
+                          "unit_payload"))
 def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
-                   n_classes=2, rank_bins=None, chunk=8, use_lanes=True):
+                   n_classes=2, rank_bins=None, chunk=8, use_lanes=True,
+                   unit_payload=False):
     """Metrics for one fold's grid chunk of streamed-sweep coefficients:
     scores in one MXU contraction; binned rank metrics go through the
     lane-batched kernel (one pallas histogram for the whole chunk on TPU
@@ -215,8 +220,8 @@ def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
             M.multiclass_metrics_from_confusion(cf), metric))(conf)
     from ...ops.glm_sweep import sweep_scores_fold
     s = sweep_scores_fold(X, Bc, b0c)                   # [n, chunk]
-    lanes_fn = _lanes_metric_fn(metric, problem_type, rank_bins) \
-        if use_lanes else None
+    lanes_fn = _lanes_metric_fn(metric, problem_type, rank_bins,
+                                unit_payload) if use_lanes else None
     if lanes_fn is not None:
         wl = jnp.broadcast_to(vw[None, :], (s.shape[1], vw.shape[0]))
         return lanes_fn(s.T, y, wl)
@@ -258,7 +263,7 @@ def _heldout_scores(X, masks, Bc, b0c):
 
 
 def _eval_heldout_core(X, y, w, masks, Bc, b0c, *, metric, rank_bins,
-                       axis_name=None):
+                       unit_payload=False, axis_name=None):
     """The held-out-once metric pass over the rows at hand: all of them,
     or under `axis_name` a chip's own, whose [F, Gc, bins] counts are
     summed over that mesh axis (ONE psum of both classes' counts) before
@@ -266,43 +271,53 @@ def _eval_heldout_core(X, y, w, masks, Bc, b0c, *, metric, rank_bins,
     scores, fold_of = _heldout_scores(X, masks, Bc, b0c)
     vw = (1.0 - jnp.min(masks, axis=0)) * w
     counts = M.heldout_cum_counts_lanes(scores, y, vw, fold_of, Bc.shape[0],
-                                        rank_bins)
+                                        rank_bins, unit_payload=unit_payload)
     counts = counts if axis_name is None else jax.lax.psum(counts, axis_name)
     return M.RANK_METRIC_FROM_COUNTS[metric](*counts)
 
 
-@partial(jax.jit, static_argnames=("metric", "rank_bins"))
-def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, *, metric, rank_bins):
+@partial(jax.jit, static_argnames=("metric", "rank_bins", "unit_payload"))
+def _streamed_eval_heldout(X, y, w, masks, Bc, b0c, unit_payload=False, *,
+                           metric, rank_bins):
     """[F, Gc] binned rank metrics of EVERY fold's grid chunk in one pass
     over X, for folds whose held-out sets are disjoint (k-fold, a single
     split): _streamed_eval called fold by fold scores and bins all n rows
     F times, all but a row's own fold with weight zero. Values equal the
     per-fold route's up to float32 summation order. The program's name
     keeps `streamed_eval`: traces and the benchmark find the metric pass
-    by it."""
+    by it. `unit_payload` (static; the mesh form takes it in the same
+    place): validate()'s word that w and the masks hold zeros and ones."""
     return _eval_heldout_core(X, y, w, masks, Bc, b0c, metric=metric,
-                              rank_bins=rank_bins)
+                              rank_bins=rank_bins, unit_payload=unit_payload)
 
 
 @lru_cache(maxsize=None)
 def _sharded_eval_heldout_fn(mesh, metric, rank_bins):
-    """_streamed_eval_heldout on a mesh: every chip scores and bins its
-    OWN rows once (the Pallas histogram kernel sees local rows), the
-    [F, Gc, bins] counts are summed over the batch axis in one psum and
-    every chip takes the same metrics from the sum."""
+    """_streamed_eval_heldout on a mesh, with its arguments (`unit_payload`
+    static, last): every chip scores and bins its OWN rows once (the Pallas
+    histogram kernel sees local rows), the [F, Gc, bins] counts are summed
+    over the batch axis in one psum and every chip takes the same metrics
+    from the sum."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import BATCH_AXIS, build_shard_map
 
-    def _streamed_eval_heldout_sharded(X, y, w, masks, Bc, b0c):
-        return _eval_heldout_core(X, y, w, masks, Bc, b0c, metric=metric,
-                                  rank_bins=rank_bins, axis_name=BATCH_AXIS)
+    def _streamed_eval_heldout_sharded(X, y, w, masks, Bc, b0c,
+                                       unit_payload=False):
+        def local(X, y, w, masks, Bc, b0c):
+            return _eval_heldout_core(
+                X, y, w, masks, Bc, b0c, metric=metric, rank_bins=rank_bins,
+                unit_payload=unit_payload, axis_name=BATCH_AXIS)
 
-    return jax.jit(build_shard_map(
-        _streamed_eval_heldout_sharded, mesh,
-        in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
-                  P(None, BATCH_AXIS), P(None, None, None), P(None, None)),
-        out_specs=P(None, None)))
+        return build_shard_map(
+            local, mesh,
+            in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
+                      P(None, BATCH_AXIS), P(None, None, None),
+                      P(None, None)),
+            out_specs=P(None, None))(X, y, w, masks, Bc, b0c)
+
+    return jax.jit(_streamed_eval_heldout_sharded,
+                   static_argnames=("unit_payload",))
 
 
 # the metric programs' executables bake the lanes-kernel (pallas) choice
@@ -369,6 +384,11 @@ class Validator:
         # are the folds' held-out sets disjoint? (set per validate() call;
         # the streamed sweep's one-pass metric route needs it)
         self._heldout_once = False
+        # is every weight x label the metric kernels will see 0 or 1? (set
+        # per validate() call: no sample weights handed in, the fold masks
+        # made here; the binned rank metrics' histogram then takes one
+        # bfloat16 part of its payload and not three)
+        self._unit_payload = False
         # grid points swept per XLA call (None = auto from the HBM budget);
         # checkpoints land after every chunk, so a preempted vmapped sweep
         # resumes mid-grid
@@ -469,6 +489,9 @@ class Validator:
                         route="device" if masks is None else "external",
                         rows=len(y), folds=n_folds, stratify=self.stratify,
                         shards=shards if self._resident else 1):
+                # unit weights under 0/1 masks (device_fold_masks') and the
+                # binary route's 0/1 labels; masks handed in may hold anything
+                self._unit_payload = w is None and masks is None
                 if w is None:
                     w = jnp.ones(len(y), jnp.float32, device=batch_sharding(
                         resident, 1) if self._resident else None)
@@ -1081,10 +1104,12 @@ class Validator:
             # row is scored and binned ONCE for all folds, on a mesh by the
             # chip that holds it; otherwise (and across processes) fold by
             # fold over the whole matrix
+            binned_lanes = _lanes_metric_fn(metric, problem_type,
+                                            rank_bins) is not None
+            unit = self._unit_payload
             heldout_once = (
                 self._heldout_once and not mesh_is_multiprocess(mesh)
-                and _lanes_metric_fn(metric, problem_type,
-                                     rank_bins) is not None)
+                and binned_lanes)
             if heldout_once and mesh is not None:
                 eval_fn = _sharded_eval_heldout_fn(mesh, metric, rank_bins)
                 # one psum of both classes' [F, Gc, bins] counts a chunk
@@ -1098,6 +1123,10 @@ class Validator:
                 "eval_route": "heldout_once" if heldout_once else "per_fold",
                 "passes": len(chunks) * (1 if heldout_once else F),
                 "shards": shards}
+            if binned_lanes and (heldout_once or mesh is None):
+                # the lane-batched counts run: which histogram body, and
+                # the parts it takes the weights in
+                eval_info.update(M.rank_hist_kernel(rank_bins, unit))
             # the layout the sweep ran on, and the collectives it declares
             # (the rounds' own and the metric pass's; a per_fold pass on a
             # mesh leaves its collectives to GSPMD, uncounted)
@@ -1113,8 +1142,11 @@ class Validator:
                     kind="sweep_eval", cells=len(pending),
                     classes=int(n_classes), **eval_info):
                 if heldout_once:
+                    # (`unit` by position: what wraps a metric program
+                    # hands its positional arguments on)
                     vals = [eval_fn(Xd, yd, wd, md, B[:, padded],
-                                    b0[:, padded]) for _, padded in chunks]
+                                    b0[:, padded], unit)
+                            for _, padded in chunks]
                     # the chunks' [F, Gc] values wait on the device for
                     # ONE fetch a sweep; the tail's padding comes last
                     with collector.trace_span("metric_fetch",
@@ -1130,7 +1162,8 @@ class Validator:
                                 thr_d, metric=metric,
                                 problem_type=problem_type,
                                 n_classes=n_classes, rank_bins=rank_bins,
-                                chunk=chunk, use_lanes=mesh is None)
+                                chunk=chunk, use_lanes=mesh is None,
+                                unit_payload=unit)
                             with collector.trace_span("metric_fetch",
                                                       kind="host_step"):
                                 out[f, idx] = np.asarray(vals)[:len(idx)]
@@ -1193,8 +1226,11 @@ class Validator:
             thr_d = jnp.asarray(margin_thr, jnp.float32)
             # mesh runs keep the vmapped metric (pallas must not consume
             # row-sharded operands)
-            lanes_fn = _lanes_metric_fn(metric, problem_type, rank_bins) \
+            lanes_fn = _lanes_metric_fn(
+                metric, problem_type, rank_bins, self._unit_payload) \
                 if self._sweep_mesh is None else None
+            hist_attrs = {} if lanes_fn is None else \
+                M.rank_hist_kernel(rank_bins, self._unit_payload)
 
             @jax.jit
             def fold_metrics(scores, y_, w_, m_, t_):
@@ -1273,7 +1309,7 @@ class Validator:
 
                 def record(gi, scores_f, route=None):
                     with _phase("fold_metrics", lanes=int(md.shape[0]),
-                                depth=depth_of(gi)):
+                                depth=depth_of(gi), **hist_attrs):
                         out = np.asarray(fold_metrics(scores_f, yd, wd, md,
                                                       thr_d))
                     with _phase("record", cells=1):

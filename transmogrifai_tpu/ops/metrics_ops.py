@@ -105,8 +105,34 @@ def _binned_cum_counts(scores: jax.Array, labels: jax.Array,
     return tps, fps
 
 
+def _pallas_route() -> bool:
+    """Do the lane-batched counts take the pallas histogram here? (A TPU
+    backend with the kernels enabled; everywhere else the scatter twins.)"""
+    if jax.default_backend() != "tpu":
+        return False
+    from . import pallas_hist
+    return pallas_hist.available()
+
+
+def rank_hist_kernel(n_bins: int, unit_payload: bool = False
+                     ) -> Dict[str, object]:
+    """What the lane-batched counts below run at `n_bins`, for the spans
+    that record it: `hist_body`, the histogram body pallas_hist.hist_pallas
+    chooses for a rank-metric call ("two_level" | "one_level":
+    pallas_rank_hist.hist_body, the function that makes the choice) or
+    "scatter" where the jnp twins run, and `payload_parts`, the bfloat16
+    parts the two-level body takes the weights in (pallas_rank_hist.
+    payload_parts: 1 where the caller vouches by `unit_payload` that
+    weights x labels are zeros and ones, else 3)."""
+    from . import pallas_rank_hist
+    return {"hist_body": pallas_rank_hist.hist_body(n_bins, False)
+            if _pallas_route() else "scatter",
+            "payload_parts": pallas_rank_hist.payload_parts(unit_payload)}
+
+
 def binned_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
-                            w_lanes: jax.Array, n_bins: int
+                            w_lanes: jax.Array, n_bins: int, *,
+                            unit_payload: bool = False
                             ) -> Tuple[jax.Array, jax.Array]:
     """Per-lane weighted TP/FP cumulative counts: scores [L, n] (one lane
     per fold/grid cell over the SAME rows), labels [n], w_lanes [L, n].
@@ -114,14 +140,16 @@ def binned_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
     TPU route: ONE pallas histogram call for all lanes — the lane id is
     the kernel's slot axis (ops/pallas_hist.py), so the [L, n] scatter-add
     the vmapped path would lower to (TPU serializes scatters) becomes MXU
-    one-hot contractions over VMEM tiles. CPU/fallback: vmap of the
+    one-hot contractions over VMEM tiles: at the rank metrics' 4 096 bins
+    the two-level body (ops/pallas_rank_hist.py), which takes the weights
+    as three bfloat16 parts, or as one where `unit_payload` vouches that
+    w_lanes x labels holds zeros and ones only. CPU/fallback: vmap of the
     scatter path. Identical results.
     """
-    if jax.default_backend() == "tpu":
-        from . import pallas_hist
-        if pallas_hist.available():
-            return _binned_cum_counts_lanes_pallas(scores, labels, w_lanes,
-                                                   n_bins)
+    if _pallas_route():
+        return _binned_cum_counts_lanes_pallas(scores, labels, w_lanes,
+                                               n_bins,
+                                               unit_payload=unit_payload)
     return _binned_cum_counts_lanes_jnp(scores, labels, w_lanes, n_bins)
 
 
@@ -134,7 +162,8 @@ def _binned_cum_counts_lanes_jnp(scores, labels, w_lanes, n_bins):
 
 
 def _binned_cum_counts_lanes_pallas(scores, labels, w_lanes, n_bins,
-                                    interpret: bool = False):
+                                    interpret: bool = False,
+                                    unit_payload: bool = False):
     from . import pallas_hist
     L, n = scores.shape
     idx = _bin_idx(scores, n_bins)
@@ -147,8 +176,8 @@ def _binned_cum_counts_lanes_pallas(scores, labels, w_lanes, n_bins,
     pay = jnp.concatenate([flat(pos_w), flat(neg_w)], axis=0)
     # ragged totals pad inside the kernel call (dropped-slot rows)
     hist = pallas_hist.hist_pallas(flat(idx), pay, flat(lane), n_slots=L,
-                                   n_bins=n_bins,
-                                   interpret=interpret)  # [L*2, bins]
+                                   n_bins=n_bins, interpret=interpret,
+                                   unit_payload=unit_payload)  # [L*2, bins]
     hist = hist.reshape(L, 2, n_bins)
     tps = jnp.cumsum(hist[:, 0, ::-1], axis=1)
     fps = jnp.cumsum(hist[:, 1, ::-1], axis=1)
@@ -157,7 +186,8 @@ def _binned_cum_counts_lanes_pallas(scores, labels, w_lanes, n_bins,
 
 def heldout_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
                              w: jax.Array, fold_of: jax.Array,
-                             n_folds: int, n_bins: int
+                             n_folds: int, n_bins: int, *,
+                             unit_payload: bool = False
                              ) -> Tuple[jax.Array, jax.Array]:
     """Weighted TP/FP cumulative counts [n_folds, Gc, n_bins] of a k-fold
     sweep in ONE pass over the rows: a row is held out by at most one fold,
@@ -172,12 +202,15 @@ def heldout_cum_counts_lanes(scores: jax.Array, labels: jax.Array,
     (_bin_idx), weights and products as that route, same dispatch: on the
     TPU ONE pallas histogram call in the tree histograms' own form — the
     Gc grid points are its features, the fold is its slot, so the weights
-    and the fold are read once a ROW (ops/pallas_hist.py)."""
-    if jax.default_backend() == "tpu":
-        from . import pallas_hist
-        if pallas_hist.available():
-            return _heldout_cum_counts_lanes_pallas(
-                scores, labels, w, fold_of, n_folds, n_bins)
+    and the fold are read once a ROW (ops/pallas_hist.py; at 4 096 bins
+    its two-level body, ops/pallas_rank_hist.py, where the slot-and-class
+    rows are built once a row block for all Gc). `unit_payload`: the
+    caller vouches that w x labels holds zeros and ones only, and the
+    kernel takes one bfloat16 part of it instead of three."""
+    if _pallas_route():
+        return _heldout_cum_counts_lanes_pallas(
+            scores, labels, w, fold_of, n_folds, n_bins,
+            unit_payload=unit_payload)
     return _heldout_cum_counts_lanes_jnp(scores, labels, w, fold_of,
                                          n_folds, n_bins)
 
@@ -203,13 +236,15 @@ def _heldout_cum_counts_lanes_jnp(scores, labels, w, fold_of, n_folds,
 
 
 def _heldout_cum_counts_lanes_pallas(scores, labels, w, fold_of, n_folds,
-                                     n_bins, interpret: bool = False):
+                                     n_bins, interpret: bool = False,
+                                     unit_payload: bool = False):
     from . import pallas_hist
     Gc = scores.shape[0]
     hist = pallas_hist.hist_pallas(
         _bin_idx(scores, n_bins), jnp.stack([w * labels, w * (1.0 - labels)]),
         fold_of.astype(jnp.float32)[None, :], n_slots=n_folds,
-        n_bins=n_bins, interpret=interpret)      # [n_folds * 2, Gc * bins]
+        n_bins=n_bins, interpret=interpret,
+        unit_payload=unit_payload)               # [n_folds * 2, Gc * bins]
     hist = hist.reshape(n_folds, 2, Gc, n_bins)
     return _cum_from_high(hist[:, 0]), _cum_from_high(hist[:, 1])
 
@@ -238,35 +273,40 @@ def _au_roc_from_counts(tps: jax.Array, fps: jax.Array) -> jax.Array:
 
 
 def au_pr_binned_lanes(scores: jax.Array, labels: jax.Array,
-                       w_lanes: jax.Array, n_bins: int) -> jax.Array:
+                       w_lanes: jax.Array, n_bins: int, *,
+                       unit_payload: bool = False) -> jax.Array:
     """[L] average-precision values from per-lane binned counts (same
     approximation contract as au_pr_binned)."""
-    return _au_pr_from_counts(
-        *binned_cum_counts_lanes(scores, labels, w_lanes, n_bins))
+    return _au_pr_from_counts(*binned_cum_counts_lanes(
+        scores, labels, w_lanes, n_bins, unit_payload=unit_payload))
 
 
 def au_roc_binned_lanes(scores: jax.Array, labels: jax.Array,
-                        w_lanes: jax.Array, n_bins: int) -> jax.Array:
+                        w_lanes: jax.Array, n_bins: int, *,
+                        unit_payload: bool = False) -> jax.Array:
     """[L] AuROC values from per-lane binned counts."""
-    return _au_roc_from_counts(
-        *binned_cum_counts_lanes(scores, labels, w_lanes, n_bins))
+    return _au_roc_from_counts(*binned_cum_counts_lanes(
+        scores, labels, w_lanes, n_bins, unit_payload=unit_payload))
 
 
 def au_pr_heldout_lanes(scores: jax.Array, labels: jax.Array, w: jax.Array,
-                        fold_of: jax.Array, n_folds: int,
-                        n_bins: int) -> jax.Array:
+                        fold_of: jax.Array, n_folds: int, n_bins: int, *,
+                        unit_payload: bool = False) -> jax.Array:
     """[n_folds, Gc] average-precision values, every row binned once
     (heldout_cum_counts_lanes; au_pr_binned's approximation contract)."""
     return _au_pr_from_counts(*heldout_cum_counts_lanes(
-        scores, labels, w, fold_of, n_folds, n_bins))
+        scores, labels, w, fold_of, n_folds, n_bins,
+        unit_payload=unit_payload))
 
 
 def au_roc_heldout_lanes(scores: jax.Array, labels: jax.Array,
                          w: jax.Array, fold_of: jax.Array, n_folds: int,
-                         n_bins: int) -> jax.Array:
+                         n_bins: int, *,
+                         unit_payload: bool = False) -> jax.Array:
     """[n_folds, Gc] AuROC values, every row binned once."""
     return _au_roc_from_counts(*heldout_cum_counts_lanes(
-        scores, labels, w, fold_of, n_folds, n_bins))
+        scores, labels, w, fold_of, n_folds, n_bins,
+        unit_payload=unit_payload))
 
 
 #: (tps, fps) cumulative counts, bins last -> the rank metric: what the

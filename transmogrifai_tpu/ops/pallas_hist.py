@@ -56,9 +56,9 @@ def _tile_budget() -> int:
 
 
 def block_rows(n_onehot_cols: int) -> int:
-    """Rows per grid step, sized so the [cols, blk] f32 one-hot tile stays
-    within the device's tile budget (v5e tree histograms: F*B ~ 2048 ->
-    2048 rows; 4096-bin rank metrics -> 1024)."""
+    """Rows per grid step of the one-level bodies, sized so the [cols, blk]
+    f32 one-hot tile stays within the tile budget (v5e trees: F*B ~ 2048 ->
+    2048 rows; 4096 bins -> 1024, which pallas_rank_hist takes instead)."""
     blk = _BLK
     budget = _tile_budget()
     while blk > 128 and n_onehot_cols * blk * 4 > budget:
@@ -338,7 +338,7 @@ def _feature_onehot(xf, *, F, B, blk, use_bf16):
     below); bf16 mode therefore builds the one-hot feature-by-feature,
     casting each [B, blk] slice down immediately — one full-size f32
     one-hot next to its bf16 copy would blow the 16MB scoped-VMEM stack.
-    f32 mode (the metric pass) builds it as one 3D broadcast-compare
+    f32 mode (parity tests, narrow metric calls) builds a 3D broadcast-compare
     reshaped [F, B, blk] -> [F*B, blk], a leading-dim merge.
     Mosaic's tpu.iota only produces integer vectors; build int32 and cast
     (f32 iota verified fine in interpret mode but fails TPU lowering)."""
@@ -402,41 +402,41 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
 
 
 def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
-                *, n_slots: int, n_bins: int,
-                interpret: bool = False,
-                allow_bf16: bool = False,
-                derive_count: bool = False) -> jax.Array:
-    """Gradient histograms [n_folds * n_slots * Co, F * n_bins] (f32).
+                *, n_slots: int, n_bins: int, interpret: bool = False,
+                allow_bf16: bool = False, derive_count: bool = False,
+                unit_payload: bool = False) -> jax.Array:
+    """Histograms [n_folds * n_slots * Co, F * n_bins] (f32) of payload sums.
 
     Xb_t [F, N] int bins; pay_t [n_folds * C, N] f32 payload channels;
     slot_t [n_folds, N] f32 slot ids (n_slots drops the row). The fold
     axis batches independent slot assignments over the SAME binned matrix
-    (CV fold masks AND fused config lanes in the tree sweep): one
-    (feature, bin) one-hot serves every lane and the contraction M dim
-    scales with n_folds. n_folds is slot_t's leading dim (C must divide
-    pay_t's). Ragged N pads internally with dropped-slot rows; the block
-    size adapts to the one-hot width so VMEM tiles stay bounded (see
-    block_rows), and the sequential grid double-buffers the HBM->VMEM
-    tile streams (pallas pipelines the next block's DMA under the current
-    block's contraction).
+    (CV fold masks AND fused config lanes in the tree sweep): one one-hot
+    serves every lane and the contraction's M scales with n_folds. Ragged
+    N pads with dropped-slot rows; the grid double-buffers the tiles.
 
-    derive_count: append a unit-count channel computed IN VMEM as
-    (last-channel > 0) — grow_tree's count_unit = (H > 0) without its own
-    HBM plane (Co = C + 1; counts stay integer-exact, bf16 included).
+    Two bodies, chosen from the arguments (pallas_rank_hist.hist_body):
+    f32 mode over whole 128-bin groups, 1 024 bins and up (the rank
+    metrics), factors the bin one-hot in two and takes the f32 payload as
+    three exact bf16 parts — one where `unit_payload` vouches that every
+    payload value is 0 or 1; all else builds a one-hot row a bin (_kernel).
 
-    allow_bf16: opt-in to bf16 contraction INPUTS (f32 accumulation) when
-    the module flag agrees (_HIST_BF16, on) — the tree-fit
-    consumers take it (one-hots and unit counts are exact in bf16; the
-    g/h payloads quantize ~0.4% relative, within the tree-quality gates);
-    the rank-metric consumer keeps full-precision weights. The resolved
-    dtype choice is a jit-cache key of the inner impl (NOT a trace-time
-    global read), so set_hist_bf16 toggles cannot serve stale-dtype
-    executables even through wrapped/monkeypatched references.
+    derive_count: append a unit-count channel computed IN VMEM as (last
+    channel > 0): grow_tree's count_unit without an HBM plane (Co = C + 1).
+
+    allow_bf16: bf16 contraction INPUTS (f32 accumulation) when the module
+    flag agrees (_HIST_BF16) — the tree fits take it (one-hots and counts
+    exact, g/h quantize ~0.4%); the rank metrics keep f32 weights. Resolved
+    OUTSIDE the jit, so set_hist_bf16 cannot serve stale-dtype programs.
     """
-    return _hist_pallas_jit(Xb_t, pay_t, slot_t, n_slots=n_slots,
-                            n_bins=n_bins, interpret=interpret,
-                            use_bf16=allow_bf16 and _HIST_BF16,
-                            derive_count=derive_count)
+    use_bf16 = allow_bf16 and _HIST_BF16
+    kw = dict(n_slots=n_slots, n_bins=n_bins, interpret=interpret,
+              derive_count=derive_count)
+    # imported here: pallas_rank_hist imports this module for its helpers
+    from . import pallas_rank_hist as two
+    if two.hist_body(n_bins, use_bf16) == "two_level":
+        return two._hist_two_level_jit(
+            Xb_t, pay_t, slot_t, parts=two.payload_parts(unit_payload), **kw)
+    return _hist_pallas_jit(Xb_t, pay_t, slot_t, use_bf16=use_bf16, **kw)
 
 
 @functools.partial(jax.jit,
