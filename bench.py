@@ -264,7 +264,6 @@ def device_sweeps(X, y, cfg, sweep_dtype, errors):
         tree_s = time.perf_counter() - t0
         kernel_roofline = [k.to_json()
                            for k in _mc.current.kernel_metrics]
-        harvest_spans_to_corpus("bench_tree_sweep")
         log(f"tree sweep done in {tree_s:.2f}s")
     except Exception as e:
         errors.append(f"tree sweep: {type(e).__name__}: {str(e)[:200]}")
@@ -1400,8 +1399,7 @@ def multihost_bench(n_rows=None):
                 "per-host cores")
 
         # pod flight recorder on the real pod arm: merge the per-rank
-        # artifact dirs into skew / collective-wait / MFU columns and
-        # harvest the measured spans into the cpu-pc2 planner corpus
+        # artifact dirs into skew / collective-wait / MFU columns
         # (docs/observability.md "Pod tracing"). This child runs
         # one-shot sharded entry points, no engine rounds, so the merge
         # aligns on one synthetic round — collective_share and the MFU
@@ -1422,7 +1420,6 @@ def multihost_bench(n_rows=None):
                 "skew": rep["skew"],
                 "mfu_top_sinks": rep["mfu_table"][:3],
                 "problems": rep["problems"],
-                "corpus_rows_harvested": PT.harvest_pod(trace_dir),
             }
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -1859,291 +1856,6 @@ def fleet_bench(n_requests=None):
     return out
 
 
-# -- plan-time autotuning A/B (--plan-ab) -----------------------------------
-
-#: the flagship-shaped (scaled) config both plan-A/B arms run — seeds are
-#: fixed inside device_data/glm_grids/gbt_grids, so the two arms execute
-#: the IDENTICAL workload and differ only in TMOG_PLAN
-PLAN_AB_CFG = dict(n_rows=100_000, n_cols=32, folds=5, glm_grid=12,
-                   gbt_grid=4, gbt_rounds=5, gbt_depth=4, gbt_bins=32,
-                   serve_singles=300, serve_max_batch=64)
-
-
-def harvest_spans_to_corpus(src):
-    """Append this process's TraceTree kernel spans to the plan corpus
-    (docs/planning.md): every bench run makes the planner smarter.
-    Best-effort by contract — corpus IO must never fail a bench."""
-    try:
-        import tempfile
-        from transmogrifai_tpu.planner.corpus import (Corpus,
-                                                      harvest_metrics_file)
-        from transmogrifai_tpu.planner.plan import corpus_dir
-        from transmogrifai_tpu.utils.metrics import collector
-        if not collector.enabled:
-            return 0
-        import jax
-        backend = jax.default_backend()
-        fd, tmp = tempfile.mkstemp(suffix=".json")
-        os.close(fd)
-        try:
-            collector.save(tmp, close=False)
-            recs = harvest_metrics_file(tmp, backend, src=src)
-        finally:
-            os.unlink(tmp)
-        return Corpus(corpus_dir()).append(recs) if recs else 0
-    except Exception:
-        return 0
-
-
-def plan_ab_arm(arm):
-    """Child body (--plan-ab-arm hand|auto): the identical seeded
-    workload under TMOG_PLAN=0 (hand plan) or TMOG_PLAN=1 (autotuned).
-
-    Phases: the flagship-shaped GLM + tree sweeps through the framework
-    validator (cold then warm — warm is the plan-quality signal, cold
-    includes compiles), then a serving phase whose p50/p99 come from the
-    ENGINE'S OWN latency histograms (the bench does not re-time what the
-    engine measures). The resolved FitPlan/ServePlan ride along with full
-    per-decision provenance, and the run's kernel spans are appended to
-    the corpus before exiting. One PLANAB| JSON line out."""
-    import jax
-    import jax.numpy as jnp
-    from transmogrifai_tpu.automl.tuning.validators import CrossValidation
-    from transmogrifai_tpu.evaluators.evaluators import Evaluators
-    from transmogrifai_tpu.models.glm import OpLogisticRegression
-    from transmogrifai_tpu.models.trees import OpXGBoostClassifier
-    from transmogrifai_tpu.planner import plan_enabled, plan_fit, \
-        plan_serving
-    from transmogrifai_tpu.utils.metrics import collector
-
-    cfg = json.loads(os.environ.get("BENCH_PLAN_AB_CFG") or "null") \
-        or dict(PLAN_AB_CFG)
-    backend = jax.default_backend()
-    out = {"arm": arm, "backend": backend,
-           "plan_enabled": plan_enabled(), "cfg": cfg}
-    collector.enable(f"plan_ab_{arm}")
-
-    X, y, _ = device_data(cfg["n_rows"], cfg["n_cols"], cfg["folds"],
-                          jnp.float32)
-    ev = Evaluators.BinaryClassification.au_pr()
-    val = CrossValidation(ev, num_folds=cfg["folds"], seed=42)
-    lr = OpLogisticRegression(max_iter=15, standardization=False)
-    ggrids = [dict(g) for g in glm_grids(cfg["glm_grid"])]
-    tgrids = [dict(g) for g in gbt_grids(cfg)]
-
-    t0 = time.perf_counter()
-    best_glm = val.validate([(lr, [dict(g) for g in ggrids])], X, y)
-    # tmoglint: disable=TPU005  validate() blocks via np.asarray
-    glm_cold_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    val.validate([(lr, [dict(g) for g in ggrids])], X, y)
-    # tmoglint: disable=TPU005  validate() blocks via np.asarray
-    glm_warm_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    best_tree = val.validate([(OpXGBoostClassifier(),
-                               [dict(g) for g in tgrids])], X, y)
-    # tmoglint: disable=TPU005  validate() blocks via np.asarray
-    tree_cold_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    val.validate([(OpXGBoostClassifier(),
-                   [dict(g) for g in tgrids])], X, y)
-    # tmoglint: disable=TPU005  validate() blocks via np.asarray
-    tree_warm_s = time.perf_counter() - t0
-
-    out["sweep"] = {
-        "glm_cold_s": round(glm_cold_s, 3),
-        "glm_warm_s": round(glm_warm_s, 3),
-        "tree_cold_s": round(tree_cold_s, 3),
-        "tree_warm_s": round(tree_warm_s, 3),
-        "warm_total_s": round(glm_warm_s + tree_warm_s, 3),
-        "cold_total_s": round(glm_cold_s + tree_cold_s, 3),
-        "glm_route": best_glm.validated[0].route,
-        "glm_au_pr": round(float(best_glm.best_metric), 4),
-        "tree_au_pr": round(float(best_tree.best_metric), 4)}
-
-    out["serving"] = _plan_ab_serving(cfg)
-
-    # the resolved plans, with per-decision provenance — what actually
-    # differed between the arms, straight from the choke point the call
-    # sites consult
-    fit_plan = plan_fit(cfg["n_rows"], cfg["n_cols"],
-                        n_folds=cfg["folds"], n_grids=cfg["glm_grid"],
-                        depth=cfg["gbt_depth"], n_bins=cfg["gbt_bins"])
-    serve_plan = plan_serving(cfg["serve_max_batch"])
-    out["plan"] = fit_plan.to_json()
-    out["serve_buckets"] = list(serve_plan.buckets)
-    out["corpus_harvested"] = harvest_spans_to_corpus(f"plan_ab_{arm}")
-    collector.disable()
-    print("PLANAB|" + json.dumps(out), flush=True)
-
-
-def _plan_ab_serving(cfg):
-    """Serving phase of one A/B arm: tiny fitted workflow served through
-    the (planned or hand) bucket ladder; p50/p99 read from the engine's
-    own histograms."""
-    from transmogrifai_tpu import FeatureBuilder
-    from transmogrifai_tpu.automl import BinaryClassificationModelSelector
-    from transmogrifai_tpu.automl.transmogrifier import transmogrify
-    from transmogrifai_tpu.models.glm import OpLogisticRegression
-    from transmogrifai_tpu.readers.readers import ListReader
-    from transmogrifai_tpu.serve import MicroBatcher, ServingEngine
-    from transmogrifai_tpu.stages.params import param_grid
-    from transmogrifai_tpu.workflow import Workflow
-
-    d = 8
-    rng = np.random.default_rng(0)
-    beta = rng.normal(size=d)
-
-    def rec(i):
-        x = rng.normal(size=d)
-        return {**{f"x{j}": float(x[j]) for j in range(d)},
-                "y": float(x @ beta > 0)}
-
-    train_rows = [rec(i) for i in range(2000)]
-    preds = [FeatureBuilder.Real(f"x{j}").extract(
-        lambda r, j=j: r.get(f"x{j}")).as_predictor() for j in range(d)]
-    fy = FeatureBuilder.RealNN("y").extract(
-        lambda r: r.get("y")).as_response()
-    pred = BinaryClassificationModelSelector.with_train_validation_split(
-        models_and_parameters=[(OpLogisticRegression(),
-                                param_grid(reg_param=[0.01]))],
-    ).set_input(fy, transmogrify(preds)).get_output()
-    with contextlib.redirect_stdout(io.StringIO()):
-        model = Workflow().set_reader(ListReader(train_rows)) \
-            .set_result_features(pred).train()
-
-    engine = ServingEngine(model, max_batch=cfg["serve_max_batch"],
-                           strict_keys=False)
-    warm = engine.prewarm()
-    batcher = MicroBatcher(engine, max_wait_ms=1.0, max_queue=4096)
-    singles = [{k: v for k, v in rec(i).items() if k != "y"}
-               for i in range(cfg["serve_singles"])]
-    for r in singles:
-        batcher.submit(r)
-    batcher.shutdown(drain=True)
-    m = engine.metrics()
-    return {"buckets": warm["buckets"],
-            "prewarm_s": warm["wall_s"],
-            "requests": m["requests"],
-            "p50_ms": m["latency"]["total"]["p50_ms"],
-            "p99_ms": m["latency"]["total"]["p99_ms"],
-            "device_score_p50_ms":
-                m["latency"]["device_score"]["p50_ms"]}
-
-
-def plan_ab_bench():
-    """--plan-ab parent: hand plan (TMOG_PLAN=0) vs autotuned plan
-    (TMOG_PLAN=1) over the identical seeded workload, each arm in its own
-    child process so neither inherits the other's warm jit caches. A cold
-    corpus is seeded first through `plan calibrate` (skippable with
-    BENCH_PLAN_AB_CALIBRATE=0 — then a cold corpus makes the arms
-    bit-identical by the no-op guarantee). The verdict `autotuned_ok`
-    asserts the autotuned plan is no slower than the hand plan OUTSIDE
-    the noise margin (BENCH_PLAN_AB_NOISE, default 15% — single-shot
-    walls on a contended box swing), on both the warm sweep wall and the
-    serving p50."""
-    from transmogrifai_tpu.planner.corpus import Corpus
-    from transmogrifai_tpu.planner.plan import corpus_dir
-
-    # this parent only launches children, one after another: it must not
-    # initialize a backend (a chip belongs to one process)
-    backend = pinned_backend()
-    env_base = dict(os.environ)
-    path = corpus_dir()
-    env_base["TMOG_PLAN_CORPUS_DIR"] = path
-    corpus = Corpus(path)
-    out = {"metric": "plan_ab", "backend": backend, "corpus_dir": path}
-
-    n_before = len(corpus.load(backend))
-    if n_before == 0 and \
-            os.environ.get("BENCH_PLAN_AB_CALIBRATE", "1") != "0":
-        log("cold corpus: seeding via `plan calibrate`")
-        env = dict(env_base)
-        env.pop("TMOG_PLAN", None)
-        try:
-            r = subprocess.run(
-                [sys.executable, "-m", "transmogrifai_tpu", "plan",
-                 "calibrate", "--budget-s", "150", "--scale", "0.5"],
-                capture_output=True, text=True, timeout=600, env=env,
-                cwd=os.path.dirname(os.path.abspath(__file__)))
-            try:
-                out["calibration"] = json.loads(
-                    r.stdout.strip().splitlines()[-1])
-            except (ValueError, IndexError):
-                out["calibration"] = {"rc": r.returncode,
-                                      "stderr": (r.stderr or "")[-300:]}
-        except subprocess.TimeoutExpired:
-            # a hung calibrate must not kill the A/B: the cold corpus
-            # makes both arms bit-identical (the no-op guarantee)
-            out["calibration"] = {"error": "HANG killed at 600s"}
-    out["corpus_records"] = len(corpus.load(backend))
-
-    arms = {}
-    for arm in ("hand", "auto"):
-        env = dict(env_base)
-        env["TMOG_PLAN"] = "0" if arm == "hand" else "1"
-        log(f"plan-ab arm: {arm} (TMOG_PLAN={env['TMOG_PLAN']})")
-        try:
-            r = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--plan-ab-arm", arm],
-                capture_output=True, text=True, timeout=1800, env=env,
-                cwd=os.path.dirname(os.path.abspath(__file__)))
-        except subprocess.TimeoutExpired:
-            # fault-isolation contract: a hung arm records an error and
-            # the parent still emits its one JSON line
-            out.setdefault("errors", []).append(
-                f"{arm} arm: HANG killed at 1800s")
-            continue
-        line = next((l for l in (r.stdout or "").splitlines()
-                     if l.startswith("PLANAB|")), None)
-        if line is None:
-            out.setdefault("errors", []).append(
-                f"{arm} arm rc={r.returncode}: "
-                f"{(r.stderr or '').strip()[-300:]}")
-            continue
-        arms[arm] = json.loads(line[7:])
-        log(f"plan-ab {arm}: sweep={arms[arm]['sweep']['warm_total_s']}s "
-            f"serve_p50={arms[arm]['serving']['p50_ms']}ms")
-    out["hand"], out["auto"] = arms.get("hand"), arms.get("auto")
-
-    if "hand" in arms and "auto" in arms:
-        noise = float(os.environ.get("BENCH_PLAN_AB_NOISE", "0.15"))
-        h_sweep = arms["hand"]["sweep"]["warm_total_s"]
-        a_sweep = arms["auto"]["sweep"]["warm_total_s"]
-        h_p50 = arms["hand"]["serving"]["p50_ms"]
-        a_p50 = arms["auto"]["serving"]["p50_ms"]
-        # the serving verdict judges the DEVICE-SCORE histogram — the
-        # number the planned ladder actually moves (padding waste per
-        # bucket). End-to-end single p50 is reported alongside but is
-        # dominated by the micro-batcher's max_wait timer jitter on a
-        # contended box (±1ms run to run), which no plan controls.
-        h_dev = arms["hand"]["serving"]["device_score_p50_ms"]
-        a_dev = arms["auto"]["serving"]["device_score_p50_ms"]
-        hv = {n: d["value"]
-              for n, d in arms["hand"]["plan"]["decisions"].items()}
-        av = {n: d["value"]
-              for n, d in arms["auto"]["plan"]["decisions"].items()}
-        out["deltas"] = {
-            "noise_margin": noise,
-            "sweep_warm_hand_s": h_sweep, "sweep_warm_auto_s": a_sweep,
-            "sweep_auto_over_hand": round(a_sweep / max(h_sweep, 1e-9),
-                                          3),
-            "serve_p50_hand_ms": h_p50, "serve_p50_auto_ms": a_p50,
-            "serve_device_p50_hand_ms": h_dev,
-            "serve_device_p50_auto_ms": a_dev,
-            "decisions_moved": sorted(
-                n for n in hv if av.get(n) != hv[n]),
-            "glm_au_pr_delta": round(
-                arms["auto"]["sweep"]["glm_au_pr"]
-                - arms["hand"]["sweep"]["glm_au_pr"], 4)}
-        out["autotuned_ok"] = bool(
-            a_sweep <= h_sweep * (1 + noise)
-            and a_dev <= h_dev * (1 + noise) + 0.05)
-    return out
-
-
 # -- cpu-subprocess phases --------------------------------------------------
 # The example flows and the host-transform-dominated wide bench measure
 # host work; they run in CPU-pinned child processes (which never want
@@ -2261,12 +1973,6 @@ def main():
     if len(sys.argv) > 1 and sys.argv[1] == "--fleet":
         print(json.dumps(fleet_bench(
             sys.argv[2] if len(sys.argv) > 2 else None)))
-        return
-    if len(sys.argv) > 2 and sys.argv[1] == "--plan-ab-arm":
-        plan_ab_arm(sys.argv[2])
-        return
-    if len(sys.argv) > 1 and sys.argv[1] == "--plan-ab":
-        print(json.dumps(plan_ab_bench()), flush=True)
         return
 
     signal.signal(signal.SIGALRM, emit_and_exit)
@@ -2453,8 +2159,6 @@ def main():
         from transmogrifai_tpu.utils.metrics import collector as _coll
         _coll.event("run_end", run_type="bench")
         save_trace_artifacts()
-        # every traced bench run feeds the plan corpus (docs/planning.md)
-        harvest_spans_to_corpus("bench_trace")
         _coll.detach_event_log()
         _coll.disable()
     if not errors:

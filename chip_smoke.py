@@ -937,12 +937,8 @@ def main() -> int:
     os.makedirs(out_dir, exist_ok=True)
     cfg = sizes(args.toy, args.chips)
     compile_log = CompileLog()
-    from transmogrifai_tpu.planner import Corpus, corpus_dir
-    n_plan = Corpus(corpus_dir()).summary()["total"]
     result = {"ok": False, "device": device, "toy": bool(args.toy),
-              "cuts": CUTS, "compile_cache_dir": compile_cache_dir(),
-              "planner_corpus": {"dir": corpus_dir(), "records": n_plan,
-                                 "cold": n_plan == 0}}
+              "cuts": CUTS, "compile_cache_dir": compile_cache_dir()}
     failed = None
     try:
         result.update(native_report())

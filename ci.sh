@@ -12,14 +12,7 @@
 set -euo pipefail
 cd "$(dirname "$0")"
 
-# plan-time autotuner (docs/planning.md): the smokes below assert
-# HAND-plan contracts (exact bucket ladders, tile shapes), so CI must
-# not inherit whatever calibration corpus this box's bench runs have
-# accumulated in the user cache — every step sees a cold scratch corpus
-# (the dedicated planner step 6/7 swaps in its own seeded scratch dir)
-export TMOG_PLAN_CORPUS_DIR="$(mktemp -d)/corpus"
-
-echo "== 1/8 import + native kernel build =="
+echo "== 1/7 import + native kernel build =="
 python - <<'PY'
 import transmogrifai_tpu
 from transmogrifai_tpu.ops import native_bridge
@@ -27,7 +20,7 @@ print("package import ok; native kernels:",
       "built" if native_bridge.available() else "UNAVAILABLE (numpy fallbacks)")
 PY
 
-echo "== 2/8 tmoglint (static JAX/TPU discipline + stage contracts) =="
+echo "== 2/7 tmoglint (static JAX/TPU discipline + stage contracts) =="
 # fails fast on findings not in tools/tmoglint/baseline.json and on stale
 # baseline entries (docs/static_analysis.md); runs before the test tiers
 # because it needs no imports and catches contract breaks in seconds.
@@ -35,15 +28,14 @@ echo "== 2/8 tmoglint (static JAX/TPU discipline + stage contracts) =="
 # the v2 concurrency (THR001-004) + buffer-lifetime (BUF001-003)
 # families, the v3 SPMD/collective-correctness (SHD001-005) +
 # contract-drift (ENV001/EVT001) families and the v4 trace-contract
-# (TRC001-005) + plan-precedence (PLN001) families all run in the same
-# scan with the SAME empty baseline — SHD is the pre-hardware gate for
-# the multi-host GSPMD push (correct-at-N=1/wrong-at-N>1 bugs the
-# CPU-mesh tiers cannot see), ENV/EVT keep the knob registry and the
-# event table honest, TRC/PLN statically prove the zero-recompile and
-# plan-precedence contracts no CPU tier can time-out on (correct on
-# the warm test box, wrong on hardware). The --format json report is
-# saved as a CI artifact so finding
-# counts per rule ride the build outputs next to the BENCH_*.json
+# (TRC001-005) family all run in the same scan with the SAME empty
+# baseline — SHD is the pre-hardware gate for the multi-host GSPMD push
+# (correct-at-N=1/wrong-at-N>1 bugs the CPU-mesh tiers cannot see),
+# ENV/EVT keep the knob registry and the event table honest, TRC
+# statically proves the zero-recompile contract no CPU tier can time-out
+# on (correct on the warm test box, wrong on hardware). The --format
+# json report is saved as a CI artifact so finding counts per rule ride
+# the build outputs next to the BENCH_*.json
 # series, and the documented 10s full-scan budget is asserted from its
 # --stats block.
 ARTIFACTS_DIR="${TMOG_CI_ARTIFACTS:-$(mktemp -d)}"
@@ -85,19 +77,18 @@ PY
 # family selection must run clean against the SAME baseline with the
 # stale-entry scoping guard active — v2 (concurrency + buffer lifetime),
 # v3 (SPMD/collective correctness + contract drift) and v4
-# (trace-contract + plan-precedence) each alone, no TPU/DAG noise
+# (trace-contract) each alone, no TPU/DAG noise
 python -m tools.tmoglint transmogrifai_tpu/ tests/ bench.py tools/ \
   --rules THR,BUF
 python -m tools.tmoglint transmogrifai_tpu/ tests/ bench.py tools/ \
   --rules SHD,ENV,EVT
 python -m tools.tmoglint transmogrifai_tpu/ tests/ bench.py tools/ \
-  --rules TRC,PLN
-# mutation drives, one per v4 family: the clean scan above is only
-# meaningful if the rules FIRE when the contract actually breaks. Each
+  --rules TRC
+# mutation drive for the v4 family: the clean scan above is only
+# meaningful if the rules FIRE when the contract actually breaks. The
 # drive copies the real serve hot path aside, scans the copy clean,
 # seeds the canonical contract break (a per-request jit construction
-# for TRC001; a raw governed TMOG_* read bypassing the planner for
-# PLN001), asserts the real CLI exits 1 naming the rule, then deletes
+# for TRC001), asserts the real CLI exits 1 naming the rule, then deletes
 # the mutation and asserts the scan is clean again — through
 # `python -m tools.tmoglint`, not library calls.
 MUT_TMP=$(mktemp -d)
@@ -143,13 +134,11 @@ def drive(rule, family, mutation):
 
 drive("TRC001", "TRC",
       "        _mut = jax.jit(lambda x: x)  # seeded: per-request jit\n")
-drive("PLN001", "PLN",
-      '        _mut = os.environ.get("TMOG_TILE_MB")  # seeded: raw read\n')
 PY
 rm -rf "$MUT_TMP"
-echo "  tmoglint: full scan (<10s) + THR,BUF + SHD,ENV,EVT + TRC,PLN family scans clean, v4 mutation drives fire (artifact: $ARTIFACTS_DIR/tmoglint_report.json)"
+echo "  tmoglint: full scan (<10s) + THR,BUF + SHD,ENV,EVT + TRC family scans clean, v4 mutation drive fires (artifact: $ARTIFACTS_DIR/tmoglint_report.json)"
 
-echo "== 3/8 test suite (8-device virtual CPU mesh) =="
+echo "== 3/7 test suite (8-device virtual CPU mesh) =="
 # fused histogram planner + CPU-fallback smoke first, explicitly under
 # JAX_PLATFORMS=cpu: the tier-1 guarantee that the pure-jnp twin of the
 # batched sweep kernel stays live on hosts with no TPU
@@ -164,7 +153,7 @@ JAX_PLATFORMS=cpu python -m pytest \
   -q -m 'not slow'
 python -m pytest tests/ -q
 
-echo "== 4/8 examples =="
+echo "== 4/7 examples =="
 for ex in op_titanic_simple op_titanic_mini op_iris op_boston; do
   JAX_PLATFORMS=cpu PYTHONPATH="$PWD" python "examples/${ex}.py" > /dev/null
   echo "  ${ex} ok"
@@ -177,7 +166,7 @@ if [ -f "$REF_RES/EmailDataset/Clicks.csv" ]; then
   echo "  op_dataprep ok"
 fi
 
-echo "== 5/8 observability smoke (traced workflow + GLM sweep) =="
+echo "== 5/7 observability smoke (traced workflow + GLM sweep) =="
 # a tiny traced run must produce a loadable span hierarchy: Chrome trace +
 # AppMetrics-with-spans + streaming events.jsonl, all validated by the
 # schema checks in `trace-report --check` (docs/observability.md)
@@ -1449,60 +1438,7 @@ print("tileplane copy/compute overlap ok")
 PY
 rm -rf "$TRACE_DIR"
 
-echo "== 6/8 plan-time autotuner (docs/planning.md) =="
-# the cold-corpus no-op proof FIRST: with an empty corpus every resolved
-# decision must be bit-identical to the hand default its call site
-# shipped with — the planner's no-regression guarantee. (tmoglint
-# already scanned the planner package with the EMPTY baseline in 2/7:
-# ENV001 covers the new TMOG_PLAN* knobs, EVT001 the plan_* events.)
-PLAN_TMP=$(mktemp -d)
-JAX_PLATFORMS=cpu TMOG_PLAN_CORPUS_DIR="$PLAN_TMP/corpus" \
-  PYTHONPATH="$PWD" python - <<'PY'
-from transmogrifai_tpu.planner import plan_fit, plan_serving
-from transmogrifai_tpu.planner.model import HAND_DEFAULTS
-from transmogrifai_tpu.serve.engine import bucket_ladder
-
-plan = plan_fit(1_000_000, 64, n_folds=5, n_grids=12, depth=6, n_bins=32)
-for name, d in plan.decisions.items():
-    assert d.value == HAND_DEFAULTS[name], (name, d.value, d.source)
-assert plan_serving(64).buckets == bucket_ladder(64)
-print("cold-corpus no-op ok: plan == hand defaults, ladder == hand ladder")
-PY
-# seed the scratch corpus with a scaled micro-bench grid, then exercise
-# the corpus/explain CLIs against it
-JAX_PLATFORMS=cpu TMOG_PLAN_CORPUS_DIR="$PLAN_TMP/corpus" \
-  PYTHONPATH="$PWD" python -m transmogrifai_tpu plan calibrate \
-  --budget-s 150 --scale 0.25
-JAX_PLATFORMS=cpu TMOG_PLAN_CORPUS_DIR="$PLAN_TMP/corpus" \
-  PYTHONPATH="$PWD" python -m transmogrifai_tpu plan show > /dev/null
-JAX_PLATFORMS=cpu TMOG_PLAN_CORPUS_DIR="$PLAN_TMP/corpus" \
-  PYTHONPATH="$PWD" python -m transmogrifai_tpu plan explain \
-  --rows 200000 --feat 32 > /dev/null
-# --plan-ab smoke: the identical seeded workload under the hand plan vs
-# the autotuned plan (fresh child processes, no shared jit caches); the
-# autotuned plan must be no slower OUTSIDE the noise margin (generous
-# 25% — this is a scaled smoke on a contended 1-core runner; the tight
-# comparison is bench.py's full-size artifact)
-JAX_PLATFORMS=cpu TMOG_PLAN_CORPUS_DIR="$PLAN_TMP/corpus" \
-  BENCH_PLAN_AB_CALIBRATE=0 BENCH_PLAN_AB_NOISE=0.25 \
-  BENCH_PLAN_AB_CFG='{"n_rows":30000,"n_cols":16,"folds":3,"glm_grid":6,"gbt_grid":2,"gbt_rounds":3,"gbt_depth":3,"gbt_bins":16,"serve_singles":200,"serve_max_batch":64}' \
-  PYTHONPATH="$PWD" python bench.py --plan-ab > "$PLAN_TMP/plan_ab.json"
-python - "$PLAN_TMP/plan_ab.json" <<'PY'
-import json
-import sys
-
-doc = json.load(open(sys.argv[1]))
-assert doc.get("hand") and doc.get("auto"), doc.get("errors")
-assert doc["autotuned_ok"], doc["deltas"]
-d = doc["deltas"]
-print(f"plan-ab smoke ok: warm sweep auto/hand="
-      f"{d['sweep_auto_over_hand']} (noise {d['noise_margin']}), "
-      f"serve p50 {d['serve_p50_hand_ms']} -> {d['serve_p50_auto_ms']}ms"
-      f", moved={d['decisions_moved']}")
-PY
-rm -rf "$PLAN_TMP"
-
-echo "== 7/8 driver-contract smoke =="
+echo "== 6/7 driver-contract smoke =="
 python - <<'PY'
 import __graft_entry__ as g
 g.dryrun_multichip(8)
@@ -1523,7 +1459,7 @@ print("bench JSON ok:", out["metric"], out["value"], out["unit"])
 # sweep, then a chaos kill of child 1 at the first GLM round boundary
 # and a full-pod relaunch that resumes from the rank-0 RoundCheckpoint
 # bit-identically (docs/performance.md "Multi-host pod scaling")
-echo "== 8/8 multihost pod smoke =="
+echo "== 7/7 multihost pod smoke =="
 JAX_PLATFORMS=cpu python - <<'PY'
 import os, shutil, tempfile
 import numpy as np
@@ -1632,11 +1568,10 @@ PY
 # pod flight recorder (docs/observability.md "Pod tracing"): a clean
 # traced 2-process pod must merge green (round-aligned swimlanes,
 # >= 75% span coverage of every rank's round wall, 0 post-warmup
-# recompiles, >= 1 new planner-corpus row at the cpu-pc2 key); a chaos
-# pod with a debug-sleep stall injected on rank 1 must be NAMED by
-# trace-report --pod; a wedged pod's timeout error must name the
-# straggler's rank/round/phase from heartbeats
-echo "== 8/8b pod flight recorder =="
+# recompiles); a chaos pod with a debug-sleep stall injected on rank 1
+# must be NAMED by trace-report --pod; a wedged pod's timeout error must
+# name the straggler's rank/round/phase from heartbeats
+echo "== 7/7b pod flight recorder =="
 JAX_PLATFORMS=cpu python - <<'PY'
 import json, os, shutil, subprocess, sys, tempfile
 import numpy as np
@@ -1719,7 +1654,7 @@ def round_compiles(rank_dir):
         key=lambda r: r[0])
     out = []
     for rnd, bucket, t0, t1 in rounds:
-        n = sum(int(s["attrs"].get("compiles") or 0) for s in spans
+        n = sum(int(s.get("attrs", {}).get("compiles") or 0) for s in spans
                 if s["kind"] != "pod_round"
                 and s.get("t_start") is not None
                 and s.get("t_end") is not None
@@ -1753,13 +1688,6 @@ try:
             seen.add(bucket)
         assert not bad, f"rank {rank}: post-warmup recompiles {bad}"
 
-    # planner corpus grows at the (backend, process-count) key
-    corpus = os.path.join(tmp, "corpus")
-    rows = PT.harvest_pod(clean, corpus_path=corpus)
-    assert rows >= 1, rows
-    assert os.path.exists(os.path.join(corpus, "corpus-cpu-pc2.jsonl"))
-    assert PT.harvest_pod(clean, corpus_path=corpus) == 0  # dedupe
-
     # 2. chaos straggler: injected debug-sleep on rank 1 must be named,
     # through the CLI surface
     chaos = os.path.join(tmp, "chaos")
@@ -1781,9 +1709,8 @@ try:
     assert "likely straggler: rank 1" in pod.error, pod.error
     assert "compute:wedged" in pod.error, pod.error
     print("pod flight recorder ok: %d rounds merged, coverage %.0f%%, "
-          "%d corpus rows at cpu-pc2, chaos straggler + wedge both "
-          "named rank 1" % (len(rep["rounds"]),
-                            100.0 * rep["coverage_min_seen"], rows))
+          "chaos straggler + wedge both named rank 1"
+          % (len(rep["rounds"]), 100.0 * rep["coverage_min_seen"]))
 finally:
     shutil.rmtree(tmp, ignore_errors=True)
 PY

@@ -17,15 +17,6 @@ from transmogrifai_tpu.utils.platform import force_cpu  # noqa: E402
 
 force_cpu(8)
 
-# the plan-time autotuner (docs/planning.md) must see a COLD corpus in
-# tests: tier-1 behavior is pinned to the hand defaults, not to whatever
-# measurements this box's bench/calibrate runs have accumulated in the
-# user-level cache dir (the planner tests build their own corpora)
-import tempfile  # noqa: E402
-
-os.environ["TMOG_PLAN_CORPUS_DIR"] = tempfile.mkdtemp(
-    prefix="tmog_test_plan_corpus_")
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

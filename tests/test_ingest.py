@@ -5,7 +5,7 @@ read_avro_columns == the per-record readers, cell for cell), the
 ShardedSource reassembly contract (serial == parallel chunk stream,
 bit for bit, at any worker count; worker crash => failed pass, never a
 hang; single-shard / workers=1 degradation), the depth-N prefetch ring
-(bit-identical results at any depth, env + planner precedence), the
+(bit-identical results at any depth, the env knob), the
 end-to-end bit-identity matrix (stats Summary / GLM fit / tree binning
 across workers {1,2,4} x prefetch {1,3}), the ingest_pass/tile_parse
 telemetry, and the FileStreamingReader shard-order determinism the
@@ -37,20 +37,10 @@ from transmogrifai_tpu.utils.metrics import collector
 
 
 @pytest.fixture(autouse=True)
-def _clean_knobs(monkeypatch, tmp_path):
-    """Isolate every test from ambient ingest knobs and from the real
-    user plan corpus (the planner would otherwise read measured tile
-    spans from previous local runs)."""
+def _clean_knobs(monkeypatch):
+    """Isolate every test from ambient ingest knobs."""
     monkeypatch.delenv("TMOG_INGEST_WORKERS", raising=False)
     monkeypatch.delenv("TMOG_TILE_PREFETCH", raising=False)
-    monkeypatch.delenv("TMOG_PLAN", raising=False)
-    monkeypatch.setenv("TMOG_PLAN_CORPUS_DIR", str(tmp_path / "corpus"))
-    from transmogrifai_tpu.planner import plan as P
-    P._model_cache.clear()
-    P._decision_cache.clear()
-    yield
-    P._model_cache.clear()
-    P._decision_cache.clear()
 
 
 @pytest.fixture
@@ -294,7 +284,7 @@ class TestShardedSource:
 
 class TestPrefetchRing:
     def test_env_knob_precedence(self, monkeypatch):
-        assert TP.tile_prefetch_depth() == 1  # hand default, cold corpus
+        assert TP.tile_prefetch_depth() == 1  # the default
         monkeypatch.setenv("TMOG_TILE_PREFETCH", "3")
         assert TP.tile_prefetch_depth() == 3
         monkeypatch.setenv("TMOG_TILE_PREFETCH", "garbage")
@@ -340,33 +330,6 @@ class TestPrefetchRing:
         evs = [json.loads(l) for l in log.read_text().splitlines()]
         [ev] = [e for e in evs if e["event"] == "tileplane_pass"]
         assert ev["prefetch_depth"] == 2
-
-    def test_planner_sizes_ring_from_span_ratio(self, tmp_path,
-                                                monkeypatch):
-        from transmogrifai_tpu.planner import plan as P
-        from transmogrifai_tpu.planner.corpus import Corpus, PlanRecord
-
-        def rec(family, wall):
-            return PlanRecord(family=family, backend=jax.default_backend(),
-                              route="", shape={"rows": 1000.0}, knobs={},
-                              wall_s=wall, compile_s=0.0, work=1000.0,
-                              cold=False)
-
-        corpus = Corpus(P.corpus_dir())
-        # feed (parse 1.5 + copy 1.0) / compute 1.0 = 2.5 -> depth 3
-        corpus.append([rec("tileplane_compute", 1.0),
-                       rec("ingest_parse", 1.5),
-                       rec("tileplane_copy", 1.0)])
-        P._model_cache.clear()
-        P._decision_cache.clear()
-        assert P.planned_tile_prefetch() == 3
-        # env always wins over the measured model
-        monkeypatch.setenv("TMOG_TILE_PREFETCH", "2")
-        assert P.planned_tile_prefetch() == 2
-        # kill switch restores the hand default
-        monkeypatch.delenv("TMOG_TILE_PREFETCH")
-        monkeypatch.setenv("TMOG_PLAN", "0")
-        assert P.planned_tile_prefetch() == 1
 
 
 # -- end-to-end bit-identity matrix ------------------------------------------

@@ -5,7 +5,7 @@ it launches actual OS processes). Everything here runs in-process on the
 8-virtual-device CPU mesh: the 1-process degradation contract (a mesh
 that spans one process must take exactly the pre-pod code paths), the
 row-layout/landing round trips, the file striping arithmetic, the
-padded stream source, the planner corpus keying, and the launch
+padded stream source, and the launch
 helper's containment guarantees (which spawn trivial children that
 never build a jax pod, so they stay fast)."""
 import numpy as np
@@ -289,19 +289,6 @@ def test_run_tileplane_multiprocess_shardings_run_synchronously(
         jnp.asarray(0.0), tile_rows=4,
         shardings=(FakePodSharding(),) * 3)
     assert float(carry) == float(X.sum())
-
-
-# -- planner corpus keying ---------------------------------------------------
-
-def test_planner_corpus_key_isolated_per_process_count(monkeypatch):
-    from transmogrifai_tpu.planner import plan
-
-    base = plan._backend()
-    assert "-pc" not in base               # single process: plain backend
-    monkeypatch.setattr(jax, "process_count", lambda: 2)
-    assert plan._backend() == f"{base}-pc2"
-    monkeypatch.setattr(jax, "process_count", lambda: 4)
-    assert plan._backend() == f"{base}-pc4"
 
 
 # -- launch helper containment (no jax in the children: fast) ----------------

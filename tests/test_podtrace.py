@@ -253,26 +253,6 @@ def test_recorder_off_is_inert(tmp_path, monkeypatch):
     assert P.rank_dirs(str(tmp_path)) == []
 
 
-def test_harvest_pod_keys_by_process_count(tmp_path):
-    _mk_rank(tmp_path, 0, _rounds_rank(0.05))
-    _mk_rank(tmp_path, 1, _rounds_rank(0.05))
-    corpus_dir = tmp_path / "corpus"
-    n = P.harvest_pod(str(tmp_path), corpus_path=str(corpus_dir))
-    assert n > 0
-    from transmogrifai_tpu.planner.corpus import Corpus
-    # the backend key carries -pc<N> (plan._backend's pod convention)
-    # so the rows land in the corpus file the pod's own plans read
-    recs = Corpus(str(corpus_dir)).load("cpu-pc2")
-    pods = [r for r in recs if r.family.startswith("pod_")]
-    assert pods
-    for r in pods:
-        assert r.shape.get("procs") == 2.0, r
-        assert r.src == "podtrace"
-    # same evidence harvested twice adds nothing (content-hash dedupe)
-    assert P.harvest_pod(str(tmp_path),
-                         corpus_path=str(corpus_dir)) == 0
-
-
 # -- heartbeat contract -------------------------------------------------------
 
 

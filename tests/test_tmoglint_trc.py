@@ -1,7 +1,7 @@
-"""tmoglint v4: trace-contract (TRC001-005) + plan-precedence (PLN001).
+"""tmoglint v4: trace-contract (TRC001-005).
 
-The two contracts these rules prove — zero recompiles in steady state,
-planner-arbitrated knob precedence — fail in the one way tier-1 cannot
+The contract these rules prove — zero recompiles in steady state —
+fails in the one way tier-1 cannot
 catch: correct on the warm CPU test box, wrong on hardware. So the
 tests here are adversarial about vacuity: every rule has known-bad
 fixtures that MUST fire and known-good fixtures that MUST stay silent,
@@ -20,7 +20,6 @@ import textwrap
 from tools.tmoglint.core import (
     LintContext, expand_rule_selection, run_rules, scan_paths,
 )
-from tools.tmoglint.rules_trc import _governed_knobs
 from tools.tmoglint.traceflow import (
     CHOKED, VARYING, hot_path_kind, is_test_path, trace_flow,
 )
@@ -285,16 +284,6 @@ class TestTRC003:
         """, path="serve/engine.py", rules=["TRC003"])
         assert out == []
 
-    def test_planned_getter_chokes_silent(self):
-        out = lint("""
-            import numpy as np
-
-            def tile(records):
-                rows = planned_score_tile_rows(len(records))
-                return np.empty(rows, dtype=object)
-        """, path="readers/streaming.py", rules=["TRC003"])
-        assert out == []
-
     def test_non_hot_path_silent(self):
         # fit-time code: one compile per dataset is the design
         out = lint("""
@@ -435,107 +424,6 @@ class TestTRC005:
         assert out == []
 
 
-# -- PLN001: plan-precedence bypass ------------------------------------------
-
-class TestPLN001:
-    def test_function_level_read_of_governed_knob(self):
-        out = lint("""
-            import os
-
-            def tile_budget():
-                return int(os.environ.get("TMOG_TILE_MB", "32"))
-        """, path="parallel/tileplane.py", rules=["PLN001"])
-        assert len(out) == 1
-        assert "TMOG_TILE_MB" in out[0].message
-        assert "planned_" in out[0].message
-
-    def test_subscript_read_in_serve_path(self):
-        out = lint("""
-            import os
-
-            def ladder(self):
-                return os.environ["TMOG_GRID_FUSE"]
-        """, path="serve/engine.py", rules=["PLN001"])
-        assert len(out) == 1
-
-    def test_fallback_without_planner_consult_still_fires(self):
-        # an except-arm read is only blessed when the TRY really was
-        # the precedence ladder
-        out = lint("""
-            import os
-
-            def rows(ds):
-                try:
-                    return ds.tile_rows
-                except AttributeError:
-                    return int(os.environ.get("TMOG_STATS_TILE_ROWS",
-                                              "262144"))
-        """, path="ops/stats_engine.py", rules=["PLN001"])
-        assert len(out) == 1
-
-    def test_module_level_pin_silent(self):
-        out = lint("""
-            import os
-
-            _TILE_MB = int(os.environ.get("TMOG_TILE_MB", "32"))
-        """, path="parallel/tileplane.py", rules=["PLN001"])
-        assert out == []
-
-    def test_planner_fallback_idiom_silent(self):
-        out = lint("""
-            import os
-
-            def rows():
-                try:
-                    from ..planner import plan_fit
-                    return plan_fit().stats_tile_rows
-                except Exception:
-                    return int(os.environ.get("TMOG_STATS_TILE_ROWS",
-                                              "262144"))
-        """, path="ops/stats_engine.py", rules=["PLN001"])
-        assert out == []
-
-    def test_ungoverned_knob_silent(self):
-        out = lint("""
-            import os
-
-            def no_pallas():
-                return os.environ.get("TMOG_NO_PALLAS", "") == "1"
-        """, path="ops/pallas_hist.py", rules=["PLN001"])
-        assert out == []
-
-    def test_planner_and_tests_out_of_scope(self):
-        src = """
-            import os
-
-            def resolve():
-                return os.environ.get("TMOG_TILE_MB")
-        """
-        assert lint(src, path="planner/plan.py", rules=["PLN001"]) == []
-        assert lint(src, path="tests/conftest.py", rules=["PLN001"]) == []
-
-    def test_governed_set_parsed_from_scanned_planner(self):
-        # a scanned planner/plan.py's _ENV_FOR dict REPLACES the frozen
-        # fallback set — the governed set cannot drift from the planner
-        planner = """
-            _ENV_FOR = {"custom": "TMOG_CUSTOM_KNOB"}
-        """
-        reader = """
-            import os
-
-            def custom():
-                return os.environ.get("TMOG_CUSTOM_KNOB")
-
-            def tile_mb():
-                return os.environ.get("TMOG_TILE_MB")
-        """
-        out = lint_many([("planner/plan.py", planner),
-                         ("parallel/tileplane.py", reader)],
-                        rules=["PLN001"])
-        assert len(out) == 1
-        assert "TMOG_CUSTOM_KNOB" in out[0].message
-
-
 # -- suppression + family selection ------------------------------------------
 
 class TestSuppressionAndSelection:
@@ -551,18 +439,18 @@ class TestSuppressionAndSelection:
 
     def test_disable_all_with_justification(self):
         out = lint("""
-            import os
+            import numpy as np
 
-            def tile_budget():
-                return os.environ.get("TMOG_TILE_MB")  # tmoglint: disable=PLN001  boot probe
-        """, path="parallel/tileplane.py", rules=["PLN001"])
+            def assemble(records):
+                return np.zeros(len(records))  # tmoglint: disable=TRC003  boot probe
+        """, path="serve/engine.py", rules=["TRC003"])
         assert out == []
 
     def test_family_prefix_expansion(self):
         assert expand_rule_selection(["TRC"]) == set(TRC_ALL)
-        assert expand_rule_selection(["PLN"]) == {"PLN001"}
-        got = expand_rule_selection(["TRC", "PLN"])
-        assert got == set(TRC_ALL) | {"PLN001"}
+        assert expand_rule_selection(["ENV"]) == {"ENV001"}
+        got = expand_rule_selection(["TRC", "ENV"])
+        assert got == set(TRC_ALL) | {"ENV001"}
 
     def test_list_rules_names_new_families(self):
         env = dict(os.environ, PYTHONPATH=REPO_ROOT)
@@ -570,7 +458,7 @@ class TestSuppressionAndSelection:
             [sys.executable, "-m", "tools.tmoglint", "--list-rules"],
             cwd=REPO_ROOT, env=env, capture_output=True, text=True)
         assert proc.returncode == 0
-        for rid in TRC_ALL + ["PLN001"]:
+        for rid in TRC_ALL:
             assert rid in proc.stdout, rid
 
     def test_family_scope_composes_with_baseline_guard(self, tmp_path):
@@ -595,13 +483,13 @@ class TestSuppressionAndSelection:
         assert wrote.returncode == 0, wrote.stdout + wrote.stderr
         entries = json.load(open(base))["findings"]
         assert any(e["rule"] == "TRC003" for e in entries), entries
-        # PLN-scoped scan: the TRC003 entry is out of scope, not stale
-        pln = subprocess.run(
+        # ENV-scoped scan: the TRC003 entry is out of scope, not stale
+        other = subprocess.run(
             [sys.executable, "-m", "tools.tmoglint", "serve",
              "--root", str(tmp_path), "--baseline", str(base),
-             "--rules", "PLN"],
+             "--rules", "ENV"],
             cwd=REPO_ROOT, env=env, capture_output=True, text=True)
-        assert pln.returncode == 0, pln.stdout + pln.stderr
+        assert other.returncode == 0, other.stdout + other.stderr
         # TRC-scoped scan sees it baselined: green
         trc = subprocess.run(
             [sys.executable, "-m", "tools.tmoglint", "serve",
@@ -622,7 +510,7 @@ class TestSuppressionAndSelection:
 # -- CLI: parallel parity, SARIF, TMOG_LINT_JOBS -----------------------------
 
 def _fixture_tree(tmp_path):
-    """One TRC003 + one PLN001 finding, plus clean neighbours."""
+    """One TRC003 + one ENV001 finding, plus clean neighbours."""
     serve = tmp_path / "serve"
     serve.mkdir()
     (serve / "eng.py").write_text(textwrap.dedent("""
@@ -636,7 +524,7 @@ def _fixture_tree(tmp_path):
         import os
 
         def tile_budget():
-            return int(os.environ.get("TMOG_TILE_MB", "32"))
+            return int(os.environ.get("TMOG_NOT_A_KNOB", "32"))
     """))
     (tmp_path / "clean.py").write_text("x = 1\n")
 
@@ -658,13 +546,13 @@ class TestCLI:
         outs = []
         for jobs in ("1", "2"):
             proc = _scan_json(tmp_path, "--jobs", jobs, "--format", "json",
-                              "--rules", "TRC,PLN")
+                              "--rules", "TRC,ENV")
             assert proc.returncode == 1, proc.stdout + proc.stderr
             rep = json.loads(proc.stdout)
             outs.append([(f["rule"], f["path"], f["fingerprint"])
                          for f in rep["new"]])
         assert outs[0] == outs[1]
-        assert {r for r, _, _ in outs[0]} == {"TRC003", "PLN001"}
+        assert {r for r, _, _ in outs[0]} == {"TRC003", "ENV001"}
 
     def test_sarif_round_trips_against_json_report(self, tmp_path):
         _fixture_tree(tmp_path)
@@ -735,7 +623,7 @@ class TestRepoScan:
         ctxs, errors = scan_paths(
             [os.path.join(REPO_ROOT, "transmogrifai_tpu")], REPO_ROOT)
         assert not errors
-        findings = run_rules(ctxs, only=TRC_ALL + ["PLN001"])
+        findings = run_rules(ctxs, only=TRC_ALL)
         assert findings == [], [(f.rule, f.path, f.line) for f in findings]
         # ...and the interpreter actually interpreted: the clean verdict
         # is backed by discovered-and-analysed sites, not empty scans
@@ -763,15 +651,6 @@ class TestRepoScan:
         assert totals["jit_sites"] > 5, totals
         assert totals["call_bindings"] > 50, totals
         assert totals["host_funcs"] > 10, totals
-
-    def test_governed_set_comes_from_real_planner(self):
-        ctxs, _ = scan_paths(
-            [os.path.join(REPO_ROOT, "transmogrifai_tpu", "planner",
-                          "plan.py")], REPO_ROOT)
-        governed = _governed_knobs(ctxs)
-        assert len(governed) >= 8
-        assert {"TMOG_TILE_MB", "TMOG_GRID_FUSE",
-                "TMOG_STATS_TILE_ROWS"} <= governed
 
 
 # -- mutation drives: the canonical contract breaks, through the CLI ---------
@@ -822,9 +701,3 @@ class TestMutationDrives:
         _drive(tmp_path, "TRC003", "TRC",
                ("        bucket = self.pick_bucket(n)\n",
                 "        bucket = n\n"))
-
-    def test_raw_governed_read_fires_pln001(self, tmp_path):
-        _drive(tmp_path, "PLN001", "PLN",
-               (self.ANCHOR,
-                self.ANCHOR +
-                '        _mb = os.environ.get("TMOG_TILE_MB")\n'))

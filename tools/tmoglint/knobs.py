@@ -89,10 +89,10 @@ KNOBS: List[Dict[str, str]] = [
     {"name": "TMOG_SCORE_TILE_ROWS", "default": "1024",
      "doc": "docs/performance.md",
      "desc": "records per bulk-scoring tile (0 = legacy per-record path)"},
-    {"name": "TMOG_TILE_PREFETCH", "default": "1 (planner may raise)",
+    {"name": "TMOG_TILE_PREFETCH", "default": "1",
      "doc": "docs/performance.md",
      "desc": "tileplane prefetch ring depth (tiles queued ahead of compute)"},
-    {"name": "TMOG_INGEST_WORKERS", "default": "1 (planner may raise)",
+    {"name": "TMOG_INGEST_WORKERS", "default": "1",
      "doc": "docs/performance.md",
      "desc": "parse-worker pool size for sharded columnar ingest"},
     # -- multi-host pod -----------------------------------------------------
@@ -163,15 +163,6 @@ KNOBS: List[Dict[str, str]] = [
     {"name": "TMOG_EVENTLOG_KEEP", "default": "3",
      "doc": "docs/observability.md",
      "desc": "rotated event-log segments kept"},
-    # -- plan-time autotuning -----------------------------------------------
-    {"name": "TMOG_PLAN", "default": "1",
-     "doc": "docs/planning.md",
-     "desc": "plan-time autotuner kill switch (0 = every decision pins "
-             "to its hand default; explicit TMOG_* overrides still win)"},
-    {"name": "TMOG_PLAN_CORPUS_DIR", "default": "~/.cache (auto)",
-     "doc": "docs/planning.md",
-     "desc": "calibration-corpus directory the measured cost model "
-             "reads and calibrate/bench runs append to"},
     # -- static analysis ----------------------------------------------------
     {"name": "TMOG_LINT_JOBS", "default": "min(8, cpus)",
      "doc": "docs/static_analysis.md",

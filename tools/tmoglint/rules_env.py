@@ -7,8 +7,9 @@ ledger used to be prose, and it drifted (three knobs were read by code
 that no doc file named). ENV001 checks the machine-readable registry
 (tools/tmoglint/knobs.py) both ways:
 
-* an ``os.environ.get``/``os.getenv``/``os.environ[...]``/``env_on``
-  access of a ``TMOG_*`` name with no registry row — an undeclared knob;
+* an ``os.environ.get``/``os.getenv``/``os.environ[...]`` or
+  ``env_on``/``env_int``/``env_float`` (utils/env.py) access of a
+  ``TMOG_*`` name with no registry row — an undeclared knob;
 * a registry row whose ``doc`` file does not mention the knob — the
   human-facing contract dropped it (checked only when the registry file
   itself is in the scan, so partial scans of unrelated trees stay
@@ -34,9 +35,9 @@ _TMOG = re.compile(r"^TMOG_[A-Z0-9_]+$")
 
 def _env_read_name(node: ast.AST) -> Optional[Tuple[ast.AST, str]]:
     """(anchor, name) when `node` reads/writes a TMOG_* env var —
-    environ.get/getenv/env_on, environ[...], environ.setdefault/pop,
-    and `"TMOG_X" in os.environ` membership tests all establish
-    knob-dependent behavior."""
+    environ.get/getenv, env_on/env_int/env_float, environ[...],
+    environ.setdefault/pop, and `"TMOG_X" in os.environ` membership
+    tests all establish knob-dependent behavior."""
     if isinstance(node, ast.Call):
         d = dotted_name(node.func)
         if not d:
@@ -45,7 +46,7 @@ def _env_read_name(node: ast.AST) -> Optional[Tuple[ast.AST, str]]:
         parts = d.split(".")
         envish = (tail in ("get", "setdefault", "pop")
                   and len(parts) >= 2 and parts[-2] == "environ") or \
-            tail in ("getenv", "env_on")
+            tail in ("getenv", "env_on", "env_int", "env_float")
         if envish and node.args and isinstance(node.args[0],
                                                ast.Constant) and \
                 isinstance(node.args[0].value, str) and \

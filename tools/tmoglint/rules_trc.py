@@ -1,13 +1,12 @@
-"""TRC001-TRC005 + PLN001 — the trace-contract and plan-precedence rules.
+"""TRC001-TRC005 — the trace-contract rules.
 
-The production loop rests on two contracts that were only ever checked
-*after the fact* (RecompileTracker counters at smoke time, planner event
-logs): the zero-recompile serving contract and the PR 15 plan precedence
-(explicit env > TMOG_PLAN=0 > measured model > hand default). These
-rules prove both statically, over the traced-vs-static lattice in
-traceflow.py. The framing is the same N=1-correct/N>1-wrong story as
-SHD: every one of these bugs is invisible on a warm 2-CPU test box and
-catastrophic on hardware where one Mosaic compile costs minutes.
+The production loop rests on a contract that was only ever checked
+*after the fact* (RecompileTracker counters at smoke time): the
+zero-recompile serving contract. These rules prove it statically, over
+the traced-vs-static lattice in traceflow.py. The framing is the same
+N=1-correct/N>1-wrong story as SHD: every one of these bugs is invisible
+on a warm 2-CPU test box and catastrophic on hardware where one Mosaic
+compile costs minutes.
 
 * TRC001 — jitted-callable construction per call: `jax.jit(f)` minted
   inside a loop and invoked there, or constructed-and-called inline, or
@@ -22,7 +21,7 @@ catastrophic on hardware where one Mosaic compile costs minutes.
   on direct nonstatic params of a jit entry stay TPU002's.
 * TRC003 — call-varying host scalars (`len(batch)`, `x.shape[0]`
   arithmetic) flowing into a shape position in a hot-path module
-  without passing a bucket-ladder/planner choke point — the exact bug
+  without passing a bucket-ladder choke point — the exact bug
   the serving ladder exists to prevent.
 * TRC004 — pytree structure built from unordered set iteration feeding
   a jitted/jax call: treedef order varies across processes, so the
@@ -34,25 +33,17 @@ catastrophic on hardware where one Mosaic compile costs minutes.
   beyond under-lock sites. Taint is positive (the value came from a
   known-jitted callable), so the tileplane's *designed* span fences
   (which sync device_put results, not jit outputs) stay silent.
-* PLN001 — a read of a plan-governed TMOG_* knob (planner/plan.py's
-  `_ENV_FOR` table) that bypasses `plan_fit`/`plan_serving`: the raw
-  env read silently re-inverts the measured-model precedence. The two
-  blessed shapes are a module-level read (an import-time pin, itself a
-  hand setting) and the repo-wide fallback idiom — the env read lives
-  in the `except` handler of a `try` whose body consults the planner.
 
 Tests and bench files are out of scope for the whole family: they
-deliberately provoke retraces (that is how RecompileTracker is proven)
-and pin knobs directly.
+deliberately provoke retraces (that is how RecompileTracker is proven).
 """
 from __future__ import annotations
 
 import ast
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
-from .core import Finding, LintContext, dotted_name, file_rule, project_rule
+from .core import Finding, LintContext, dotted_name, file_rule
 from .jitgraph import jnp_aliases, numpy_aliases
-from .rules_env import _env_read_name
 from .traceflow import (
     CHOKED, TRACED, VARYING, hot_path_kind, is_test_path, trace_flow,
 )
@@ -179,7 +170,7 @@ _SHAPE_CREATORS = {"zeros", "ones", "empty", "full", "arange"}
 
 
 @file_rule("TRC003", "call-varying scalar reaches a shape position in a "
-                     "hot path without a bucket-ladder/planner choke point")
+                     "hot path without a bucket-ladder choke point")
 def check_trc003(ctx: LintContext) -> List[Finding]:
     if hot_path_kind(ctx.path) is None:
         return []
@@ -223,8 +214,7 @@ def check_trc003(ctx: LintContext) -> List[Finding]:
                 f"()` in hot-path `{fi.name}` — every distinct size is a "
                 f"fresh XLA program (minutes of Mosaic compile on "
                 f"hardware, invisible on a warm test box); route the size "
-                f"through pick_bucket/bucket_ladder or a planned_* getter "
-                f"and pad to the bucket")
+                f"through pick_bucket/bucket_ladder and pad to the bucket")
             if f is not None:
                 findings.append(f)
     return findings
@@ -424,123 +414,4 @@ def check_trc005(ctx: LintContext) -> List[Finding]:
                     f"reduction on device")
                 if f is not None:
                     findings.append(f)
-    return findings
-
-
-# -- PLN001: plan-precedence bypass ------------------------------------------
-
-#: snapshot of planner/plan.py's _ENV_FOR values — the fallback when the
-#: scan does not include the planner (fixture scans); a scanned
-#: planner/plan.py always wins so the governed set cannot drift
-_GOVERNED_FALLBACK = frozenset({
-    "TMOG_GRID_FUSE", "TMOG_GRID_FUSE_HBM_LANES", "TMOG_GRID_FUSE_OUT_MB",
-    "TMOG_TILE_MB", "TMOG_STATS_TILE_ROWS", "TMOG_SCORE_TILE_ROWS",
-    "TMOG_TILE_PREFETCH", "TMOG_INGEST_WORKERS",
-})
-
-_PLANNER_GETTER_TAILS = {"plan_serving", "plan_fit", "grid_fuse_enabled",
-                         "glm_streamed_min_rows"}
-
-
-def _governed_knobs(ctxs: Sequence[LintContext]) -> Set[str]:
-    """The plan-governed knob set: string values of the module-level
-    `_ENV_FOR = {...}` literal in any scanned planner/plan.py."""
-    out: Set[str] = set()
-    for ctx in ctxs:
-        if not ctx.path.endswith("planner/plan.py"):
-            continue
-        for node in ctx.tree.body:
-            if not (isinstance(node, ast.Assign)
-                    and any(isinstance(t, ast.Name) and t.id == "_ENV_FOR"
-                            for t in node.targets)
-                    and isinstance(node.value, ast.Dict)):
-                continue
-            for v in node.value.values:
-                if isinstance(v, ast.Constant) and \
-                        isinstance(v.value, str) and \
-                        v.value.startswith("TMOG_"):
-                    out.add(v.value)
-    return out or set(_GOVERNED_FALLBACK)
-
-
-def _consults_planner(try_node: ast.Try) -> bool:
-    """Does the TRY BODY (not its handlers) reach for the planner? The
-    fallback idiom is only blessed when the primary path really was the
-    precedence ladder."""
-    for stmt in try_node.body:
-        for sub in ast.walk(stmt):
-            if isinstance(sub, ast.ImportFrom) and sub.module and \
-                    "planner" in sub.module:
-                return True
-            if isinstance(sub, ast.Call):
-                d = dotted_name(sub.func)
-                tail = d.split(".")[-1] if d else ""
-                if tail in _PLANNER_GETTER_TAILS or \
-                        tail.startswith("planned_"):
-                    return True
-    return False
-
-
-def _pln001_scoped(path: str) -> bool:
-    parts = path.split("/")
-    base = parts[-1]
-    if base.startswith("test_") or base.startswith("bench") or \
-            base == "conftest.py":
-        return False
-    dirs = set(parts[:-1])
-    if dirs & {"tests", "tools", "planner"}:
-        # the planner itself OWNS the governed reads (that is where the
-        # precedence ladder lives); tests/bench pin knobs by design
-        return False
-    return True
-
-
-@project_rule("PLN001", "plan-governed TMOG_* knob read outside the "
-                        "planner precedence ladder (raw env bypasses the "
-                        "measured model)")
-def check_pln001(ctxs: Sequence[LintContext]) -> List[Finding]:
-    governed = _governed_knobs(ctxs)
-    findings: List[Finding] = []
-    for ctx in ctxs:
-        if not _pln001_scoped(ctx.path) or "TMOG_" not in ctx.source:
-            continue
-
-        def walk(node: ast.AST, in_func: bool,
-                 handler_tries: List[ast.Try]) -> None:
-            hit = _env_read_name(node)
-            if hit is not None and not (
-                    isinstance(node, ast.Subscript)
-                    and not isinstance(node.ctx, ast.Load)):
-                anchor, name = hit
-                if name in governed:
-                    if not in_func:
-                        pass  # module-level read: an import-time pin is
-                        #       itself a hand setting (ops/trees.py)
-                    elif any(_consults_planner(t)
-                             for t in handler_tries):
-                        pass  # the blessed fallback idiom: env read in
-                        #       the except arm of a planner consult
-                    else:
-                        f = ctx.finding(
-                            "PLN001", anchor,
-                            f"`{name}` is plan-governed (planner/plan.py "
-                            f"_ENV_FOR) but read here outside the "
-                            f"precedence ladder — a raw env read beats "
-                            f"the measured model even when the user "
-                            f"never set the knob; call the planned_* "
-                            f"getter (its except-fallback may read the "
-                            f"env) or read at module level")
-                        if f is not None:
-                            findings.append(f)
-            for child in ast.iter_child_nodes(node):
-                c_in_func = in_func or isinstance(
-                    child, (ast.FunctionDef, ast.AsyncFunctionDef,
-                            ast.Lambda))
-                c_tries = handler_tries
-                if isinstance(node, ast.Try) and \
-                        isinstance(child, ast.ExceptHandler):
-                    c_tries = handler_tries + [node]
-                walk(child, c_in_func, c_tries)
-
-        walk(ctx.tree, False, [])
     return findings

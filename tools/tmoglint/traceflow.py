@@ -17,8 +17,8 @@ interpretations share one ModuleGraph and one ancestor-annotated walk:
 * **host shape flow** (TRC003): inside *host* functions of hot-path
   files, each scalar is ``VARYING`` (derived from ``len(arg)`` /
   ``arg.shape[i]`` — a different number every call, i.e. a fresh XLA
-  program every call), ``CHOKED`` (routed through a bucket-ladder /
-  planner choke point, the only shapes the zero-recompile contract
+  program every call), ``CHOKED`` (routed through a bucket-ladder
+  choke point, the only shapes the zero-recompile contract
   allows), or ``STATIC``. A scalar *parameter* inherits the join of
   what its intra-module call sites pass, so a ``bucket`` threaded from
   ``pick_bucket`` stays proven-choked through helper calls.
@@ -29,7 +29,7 @@ interpretations share one ModuleGraph and one ancestor-annotated walk:
   fresh callable is invoked inline or inside the same loop.
 
 Everything is stdlib-``ast`` only and cached per file on the ctx (like
-``module_graph``): the walk is the expensive part, the six TRC/PLN
+``module_graph``): the walk is the expensive part, the five TRC
 rules are queries. ``TraceFlow.stats`` counts what was actually
 interpreted so tests can assert the analysis SAW the hot paths rather
 than silently skipping them (the SHD non-vacuity discipline).
@@ -48,19 +48,15 @@ STATIC = "static"
 VARYING = "varying"
 CHOKED = "choked"
 
-# host calls that return a *bucketed/planned* size — the only values the
+# host calls that return a *bucketed* size — the only values the
 # zero-recompile contract lets into a shape position on a hot path.
 # Matched on the last dotted component so `self.pick_bucket(...)` and
-# `plan.planned_tile_mb()` both count.
+# `TP.tile_rows_for(...)` both count.
 CHOKE_TAILS = {
-    "pick_bucket", "bucket_ladder", "planned_bucket_ladder",
-    "plan_serving", "plan_fit", "tile_rows_for", "stats_row_block",
+    "pick_bucket", "bucket_ladder", "tile_rows_for", "stats_row_block",
     "stream_tile_rows_default", "score_tile_rows_default",
     "tile_budget_bytes", "tile_prefetch_depth", "ingest_workers",
 }
-# any `planned_*` getter is a choke too (planner/plan.py grows one per
-# knob; keep the prefix rule so new getters stay covered)
-_CHOKE_PREFIX = "planned_"
 
 # accessors whose result is a static python value under trace
 _STATIC_ACCESSORS = {"shape", "ndim", "dtype", "size", "itemsize"}
@@ -103,7 +99,7 @@ def hot_path_kind(path: str) -> Optional[str]:
 
 
 def is_test_path(path: str) -> bool:
-    """Out of scope for the whole TRC/PLN family: tests deliberately
+    """Out of scope for the whole TRC family: tests deliberately
     provoke retraces (that is how RecompileTracker is proven) and bench
     deliberately constructs jits inline (it measures the compile)."""
     parts = path.split("/")
@@ -492,11 +488,11 @@ class TraceFlow:
         if not d:
             return False
         tail = d.split(".")[-1]
-        return tail in CHOKE_TAILS or tail.startswith(_CHOKE_PREFIX)
+        return tail in CHOKE_TAILS
 
     def _shape_state(self, expr: ast.AST, env: Dict[str, str]) -> str:
         """VARYING iff `expr` is a call-varying host scalar; CHOKED when
-        it provably went through a bucket/planner choke point."""
+        it provably went through a bucket choke point."""
         if isinstance(expr, ast.Constant):
             return STATIC
         if isinstance(expr, ast.Name):

@@ -129,13 +129,18 @@ def _metric_fn(problem_type: str, metric: str, n_classes: int = 2,
 # Rows above which GLM sweeps route through the streaming lane-batched
 # kernel (ops/glm_sweep.py): one X pass per Newton iteration for ALL
 # (fold x grid) lanes instead of one per lane. Below it, the per-lane
-# vmapped program is simpler and compile-cheaper. Since the autotuning
-# PR this is the HAND default of a plan-time decision (docs/planning.md)
-# — but reassigning the module global still pins the route outright
-# (hand beats model, same precedence as an env knob): tests and
-# bench.py's vmapped-retry path rely on exactly that.
+# vmapped program is simpler and compile-cheaper. Read at each sweep:
+# tests and bench.py's vmapped-retry path reassign it to pin a route.
 STREAMED_SWEEP_MIN_ROWS = 200_000
-_STREAMED_SWEEP_MIN_ROWS_HAND = STREAMED_SWEEP_MIN_ROWS
+
+
+def grid_fuse_on() -> bool:
+    """TMOG_GRID_FUSE: the config-fused tree route, opt-in (its widest
+    Mosaic compiles took 20+ minutes, r5). A whitelist of 1 / true / on,
+    so "yes" stays off."""
+    return os.environ.get("TMOG_GRID_FUSE", "").strip().lower() \
+        in ("1", "true", "on")
+
 
 def grid_fuse_max_failures() -> int:
     """Consecutive config-fused route failures tolerated before the
@@ -613,23 +618,8 @@ class Validator:
         # an assigned across-time warm seed (retrain refit) is only
         # consumable by the streamed rounds kernel — a seeded refit
         # takes this route regardless of scale, else the seed would be
-        # silently dropped (and warm_seeded honestly reported False).
-        # The row floor is a plan-time decision (docs/planning.md): the
-        # measured crossover between the streamed and vmapped kernels
-        # at this (feat, lanes) shape, falling back to the hand
-        # STREAMED_SWEEP_MIN_ROWS on a cold corpus / TMOG_PLAN=0 /
-        # planner fault. A REASSIGNED module global is a hand override
-        # and wins over the model — the same precedence an explicitly
-        # set TMOG_* var gets
-        min_rows = STREAMED_SWEEP_MIN_ROWS
-        if min_rows == _STREAMED_SWEEP_MIN_ROWS_HAND:
-            try:
-                from ...planner.plan import glm_streamed_min_rows
-                min_rows = glm_streamed_min_rows(
-                    X.shape[1], n_folds * max(len(grids), 1))
-            except Exception:
-                min_rows = STREAMED_SWEEP_MIN_ROWS
-        if X.shape[0] < min_rows and (
+        # silently dropped (and warm_seeded honestly reported False)
+        if X.shape[0] < STREAMED_SWEEP_MIN_ROWS and (
                 multiclass or getattr(self, "warm_seed", None) is None):
             return False
         from ...ops import glm_sweep as GS
@@ -1261,42 +1251,13 @@ class Validator:
                 groups.setdefault(bins_of(gi), []).append(gi)
             multicls = problem_type == "multiclass"
 
-            # config-fusion gate, resolved ONCE per sweep through the
-            # plan-time autotuner (docs/planning.md): an explicitly-set
-            # TMOG_GRID_FUSE wins either way (hand beats model, logged
-            # as plan_override); otherwise fusion turns on only when the
-            # corpus measured the fused route faster AND the planned
-            # out-block clears the compile-knee term — the 20-minute
-            # Mosaic compile r5 paid is now rejected at plan time. Cold
-            # corpus keeps today's opt-in default (off).
             def depth_of(gi):
                 g = grids[gi]
                 if "max_depth" in g:
                     return int(g["max_depth"])
                 return int(est.get_param("max_depth")) \
                     if est.has_param("max_depth") else 0
-            n_shards = 1
-            if self._sweep_mesh is not None:
-                from ...parallel.mesh import BATCH_AXIS
-                n_shards = max(self._sweep_mesh.shape.get(BATCH_AXIS, 1), 1)
-            try:
-                from ...planner.plan import grid_fuse_enabled
-                plan_fuse_on = grid_fuse_enabled(
-                    n_rows=X.shape[0], n_feat=X.shape[1],
-                    n_folds=masks.shape[0], n_grids=len(pending),
-                    depth=max((depth_of(gi) for gi in pending),
-                              default=0),
-                    n_bins=int(max((b for b in groups if b), default=0)
-                               or 0),
-                    n_shards=n_shards)
-            except Exception:
-                # the degraded path must keep today's hand behavior
-                # EXACTLY: the pre-planner gate was an opt-IN whitelist
-                # (env_on's falsy-list parse would flip fusion ON for
-                # nonstandard truthy spellings like "yes")
-                plan_fuse_on = os.environ.get(
-                    "TMOG_GRID_FUSE", "").strip().lower() \
-                    in ("1", "true", "on")
+            fuse_on = grid_fuse_on()
             for bins, group in sorted(groups.items(),
                                       key=lambda kv: str(kv[0])):
                 # n_valid: mesh runs pad rows (repeat-last) — the quantile
@@ -1334,10 +1295,8 @@ class Validator:
                     # the widened-M hist programs are bitwise-correct
                     # (ops-level parity suite) but their Mosaic compiles
                     # ran 20+ minutes at the 2M x 20-lane shape on first
-                    # hardware contact — plan_fuse_on (resolved above)
-                    # keeps fusion opt-in until measured evidence clears
-                    # both the wall and the compile knee
-                    if key[0] == "fuse" and len(gis) > 1 and plan_fuse_on:
+                    # hardware contact, hence the opt-in above
+                    if key[0] == "fuse" and len(gis) > 1 and fuse_on:
                         try:
                             with _phase("tree_fit",
                                         lanes=int(md.shape[0]) * len(gis),

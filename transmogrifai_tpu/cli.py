@@ -558,42 +558,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     rw.add_argument("spec", help="RefitSpec JSON written by the "
                                  "controller (champion dir, builder, "
                                  "history + window data, holdout split)")
-    pl = sub.add_parser(
-        "plan",
-        help="plan-time autotuner (docs/planning.md): `calibrate` seeds "
-             "the measured-cost corpus with a bounded micro-bench grid "
-             "on the current backend, `show` summarizes the corpus, "
-             "`explain` prints the resolved plan for a shape with "
-             "per-decision predicted-vs-alternative costs")
-    pl.add_argument("action", choices=["calibrate", "show", "explain"])
-    pl.add_argument("--corpus-dir", default=None,
-                    help="corpus directory (default TMOG_PLAN_CORPUS_DIR "
-                         "or the per-user cache dir)")
-    pl.add_argument("--budget-s", type=float, default=180.0,
-                    help="calibrate: wall budget; families past it are "
-                         "skipped (partial corpora are fine)")
-    pl.add_argument("--scale", type=float, default=1.0,
-                    help="calibrate: micro-bench size multiplier "
-                         "(CI smokes pass <1 for speed)")
-    pl.add_argument("--rows", type=int, default=1_000_000,
-                    help="explain: sweep row count")
-    pl.add_argument("--feat", type=int, default=64,
-                    help="explain: feature count")
-    pl.add_argument("--folds", type=int, default=5,
-                    help="explain: CV fold count")
-    pl.add_argument("--grids", type=int, default=12,
-                    help="explain: grid-point count")
-    pl.add_argument("--depth", type=int, default=6,
-                    help="explain: tree depth")
-    pl.add_argument("--bins", type=int, default=32,
-                    help="explain: histogram bins")
-    pl.add_argument("--shards", type=int, default=1,
-                    help="explain: mesh batch-axis size (the grid-fuse "
-                         "knee judges the sharded chunk's out-block)")
-    pl.add_argument("--max-batch", type=int, default=64,
-                    help="explain: serving ladder top")
-    pl.add_argument("--json", action="store_true",
-                    help="explain: machine-readable output")
     mo = sub.add_parser(
         "monitor",
         help="offline drift report: score a bulk file through the "
@@ -658,9 +622,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if a.command == "fleet":
         from .fleet.frontend import run_fleet
         return run_fleet(a)
-    if a.command == "plan":
-        from .planner.calibrate import run_plan_cli
-        return run_plan_cli(a)
     if a.command == "monitor":
         from .monitor.offline import run_monitor
         return run_monitor(a)

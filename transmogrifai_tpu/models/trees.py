@@ -323,17 +323,17 @@ class _TreeEstimator(PredictorEstimator):
         F = masks.shape[0]
         n = y.shape[0]
         G = len(grids)
-        # chunk size from the single planner (ops/pallas_hist
+        # chunk size from the one chunker (ops/pallas_hist
         # plan_lane_chunk): the fused kernel's VMEM residents scale with
         # lane count, HBM carries 4 lane-sized f32 planes (W, g, h,
         # margins), and Mosaic's layout search explodes when the out
         # block nears the scoped-VMEM boundary (r5 session 2: 20+ min
-        # compiles at a 16MB out block) — the planner gates all three,
+        # compiles at a 16MB out block) — the chunker gates all three,
         # INCLUDING at chunk == 1 (a single config's fold lanes that
         # clear the VMEM gate can still bust the HBM/out-block caps;
         # ADVICE round 5), where 0 falls back per-config. On a mesh the
         # lane row-planes shard, so the HBM lane budget scales with the
-        # shard count (the planner's lane-shard budget).
+        # shard count (the chunker's lane-shard budget).
         chunk = pallas_hist.plan_lane_chunk(
             Xb.shape[1], n_bins + 1, F, G, depth, n_shards=n_shards)
         if chunk == 0:
@@ -662,8 +662,8 @@ class _ForestBase(_TreeEstimator):
             n_rows, n_feat, int(self.get_param("max_bins")) + 1, n_folds,
             cfg["n_trees"], depth)
         if group == 0:
-            return 0, (f"depth {depth}: the planner refuses the slot-dense "
-                       f"output block of its deepest level")
+            return 0, (f"depth {depth}: plan_forest_group refuses the "
+                       f"slot-dense output block of its deepest level")
         return group, ""
 
     def mask_sweep_context(self, X, n_valid: int = None, mesh=None):

@@ -99,7 +99,6 @@ small problems through the per-lane path).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -159,37 +158,12 @@ ROUND_ITERS_DEFAULT = 5
 _BUCKET_MIN = 8
 
 
-_bucket_floor_cached = None
-
-
-def _bucket_floor() -> int:
-    """The compaction ladder's smallest bucket — a plan-time decision
-    since the autotuning PR (family ``glm_bucket``, docs/planning.md):
-    a measured corpus may move it, a cold corpus (or TMOG_PLAN=0, or
-    any planner fault) keeps the hand _BUCKET_MIN. Resolved ONCE per
-    process: bucket_lanes is read per retirement round, and a corpus
-    append from another process mid-sweep must not flip the floor
-    between rounds of one sweep — the padded program shapes (and the
-    'at most log2(L/floor)+1 distinct round programs' compile pin) are
-    fixed for the process lifetime. A planner fault is NOT cached, so
-    a transiently unreadable corpus can still resolve later."""
-    global _bucket_floor_cached
-    if _bucket_floor_cached is None:
-        try:
-            from ..planner.plan import planned_glm_bucket_floor
-            _bucket_floor_cached = max(planned_glm_bucket_floor(), 1)
-        except Exception:
-            return _BUCKET_MIN
-    return _bucket_floor_cached
-
-
 def bucket_lanes(n_active: int) -> int:
-    """Smallest power-of-two bucket >= n_active (floor _bucket_floor,
-    hand default _BUCKET_MIN): the round kernel's lane axis is padded
-    to this, so a sweep compiles at most log2(L/floor)+1 distinct round
-    programs per (n, d, F) shape, reused across rounds, grid chunks and
-    repeated sweeps."""
-    b = _bucket_floor()
+    """Smallest power-of-two bucket >= n_active (floor _BUCKET_MIN): the
+    round kernel's lane axis is padded to this, so a sweep compiles at
+    most log2(L/floor)+1 distinct round programs per (n, d, F) shape,
+    reused across rounds, grid chunks and repeated sweeps."""
+    b = _BUCKET_MIN
     while b < n_active:
         b *= 2
     return b
@@ -308,13 +282,6 @@ def _blocked(Xs, y, w, fold_masks, c: int):
         fold_masks = jnp.pad(fold_masks, ((0, 0), (0, pad)))
     return (Xs.reshape(nb, c, Xs.shape[1]), y.reshape(nb, c),
             w.reshape(nb, c), fold_masks.reshape(F, nb, c).transpose(1, 0, 2))
-
-
-def env_on(name: str, default: str = "1") -> bool:
-    """Tri-state TMOG_* toggle parse (TMOG_PLAN, TMOG_STATS_FUSED import
-    it) so the accepted falsy spellings cannot drift between modules."""
-    return os.environ.get(name, default).strip().lower() \
-        not in ("0", "false", "off")
 
 
 def _newton_prox_update(B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye,

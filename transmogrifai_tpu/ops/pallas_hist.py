@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.env import env_float, env_int
+
 _BLK = 4096
 
 
@@ -172,19 +174,8 @@ def plan_lane_chunk(n_feat: int, n_bins: int, n_folds: int, n_configs: int,
     shard count and the lane budget multiplies by it. VMEM and
     out-block caps are PER DEVICE and do not scale — the fused output
     block is replicated on every shard (psum-merged)."""
-    # caps resolve through the plan-time autotuner (docs/planning.md):
-    # explicitly-set TMOG_GRID_FUSE_HBM_LANES / _OUT_MB win (hand beats
-    # model, logged as plan_override), a measured corpus may move them
-    # (the out-MB candidates are pre-filtered through the compile-knee
-    # term, so the cap can never reach a block size whose predicted
-    # Mosaic compile busts the budget), and a cold corpus / TMOG_PLAN=0
-    # / any planner fault keeps the 64-lane / 8MB hand defaults
-    try:
-        from ..planner.plan import planned_grid_fuse_caps
-        lane_cap, out_mb_cap = planned_grid_fuse_caps()
-    except Exception:
-        lane_cap = int(os.environ.get("TMOG_GRID_FUSE_HBM_LANES", "64"))
-        out_mb_cap = float(os.environ.get("TMOG_GRID_FUSE_OUT_MB", "8"))
+    lane_cap = env_int("TMOG_GRID_FUSE_HBM_LANES", 64)
+    out_mb_cap = env_float("TMOG_GRID_FUSE_OUT_MB", 8.0)
     hbm_lane_budget = lane_cap * max(int(n_shards), 1)
 
     def ok(chunk: int) -> bool:

@@ -72,8 +72,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import stats as S
-from .glm_sweep import env_on
 from ..parallel.mesh import BATCH_AXIS, build_shard_map, shard_vary
+from ..utils.env import env_int, env_on
 
 EPS = 1e-12
 
@@ -110,16 +110,10 @@ def stream_threshold_bytes() -> int:
 
 
 def stream_tile_rows_default() -> int:
-    """Rows per streamed statistics tile. An explicitly-set
-    TMOG_STATS_TILE_ROWS wins (hand beats model, logged as a
-    plan_override event); otherwise the plan-time autotuner picks the
-    tile shape — cold corpus / TMOG_PLAN=0 / any planner fault all
-    yield the 2^18 hand default (docs/planning.md)."""
-    try:
-        from ..planner.plan import planned_stats_tile_rows
-        return planned_stats_tile_rows()
-    except Exception:
-        return int(os.environ.get("TMOG_STATS_TILE_ROWS", str(1 << 18)))
+    """Rows per streamed statistics tile: TMOG_STATS_TILE_ROWS, default
+    2^18 (64 MiB of float32 at 64 columns). One fixed tile shape, so the
+    tile step compiles once a pass."""
+    return env_int("TMOG_STATS_TILE_ROWS", 1 << 18)
 
 
 def stats_pass_bytes(n: int, d: int, *, itemsize: int = 4,
@@ -825,7 +819,7 @@ def stream_stats(X, y=None, w=None, *, tile_rows: Optional[int] = None,
     of its rows). TMOG_TILEPLANE=0 restores the legacy synchronous loop
     with per-tile host f64 merge. Still exactly one read of every row of
     X per pass. `prefetch` overrides the tileplane ring depth for this
-    pass (None = env > planner > hand default 1; bit-identical at any
+    pass (None = TMOG_TILE_PREFETCH, default 1; bit-identical at any
     depth). Returns (merged host state, shift)."""
     from ..parallel import mesh as M
     from ..parallel import tileplane as TP
@@ -943,7 +937,7 @@ def stream_stats(X, y=None, w=None, *, tile_rows: Optional[int] = None,
         from ..parallel import multihost as MH
         carry0 = jax.tree_util.tree_map(
             lambda a: MH.replicated_global(np.asarray(a), mesh), carry0)
-    # depth resolved HERE (env > planner > hand default 1) so the pass
+    # depth resolved HERE (TMOG_TILE_PREFETCH, default 1) so the pass
     # stats record the ring the pass actually ran with; depth never
     # changes tile boundaries, so results are bit-identical at any value
     depth = max(1, int(prefetch)) if prefetch else TP.tile_prefetch_depth()

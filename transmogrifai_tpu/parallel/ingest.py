@@ -28,15 +28,13 @@ WITHOUT changing a single downstream bit:
   (readers/readers.columnar_f32) instead of the per-cell dict walk;
 - each worker wraps every decoded chunk in a `tile_parse` span carrying
   a per-worker `lane` attr, so parse/copy/compute overlap renders as
-  separate Perfetto swimlanes (docs/observability.md) and the planner
-  can derive TMOG_TILE_PREFETCH from measured span ratios.
+  separate Perfetto swimlanes (docs/observability.md).
 
-TMOG_INGEST_WORKERS sizes the pool (env > planner > hand default 1);
-the pass emits an `ingest_pass` event + IngestPass telemetry record.
+TMOG_INGEST_WORKERS sizes the pool (default 1); the pass emits an
+`ingest_pass` event + IngestPass telemetry record.
 """
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -45,9 +43,9 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List,
 
 import numpy as np
 
+from ..utils.env import env_int
 from .tileplane import RowSource
 
-_INGEST_WORKERS_DEFAULT = 1
 #: per-shard queue depth: how many chunks a worker may decode ahead of
 #: reassembly on each shard it owns (host buffering is bounded by
 #: shards * ahead chunks, independent of file size)
@@ -55,21 +53,11 @@ _SHARD_QUEUE_AHEAD = 2
 
 
 def ingest_workers() -> int:
-    """Parse-worker pool size for sharded sources. An explicitly-set
-    TMOG_INGEST_WORKERS wins (hand beats model); otherwise the
-    plan-time autotuner picks from measured ingest_parse throughput —
-    a cold corpus (or TMOG_PLAN=0, or any planner fault) yields the
-    serial hand default 1 (docs/planning.md). Per-pass the pool is
-    additionally clamped to the shard count."""
-    try:
-        from ..planner.plan import planned_ingest_workers
-        return max(1, int(planned_ingest_workers()))
-    except Exception:
-        try:
-            return max(1, int(os.environ.get(
-                "TMOG_INGEST_WORKERS", str(_INGEST_WORKERS_DEFAULT))))
-        except ValueError:
-            return _INGEST_WORKERS_DEFAULT
+    """Parse-worker pool size for sharded sources: TMOG_INGEST_WORKERS,
+    default 1 (serial parse, the order-preserving baseline), never
+    below 1. Per pass the pool is additionally clamped to the shard
+    count."""
+    return max(1, env_int("TMOG_INGEST_WORKERS", 1))
 
 
 def _put(q: "queue.Queue", item: Any, stop: threading.Event) -> bool:
@@ -142,7 +130,7 @@ class ShardedSource(RowSource):
                  label: str = "ingest"):
         self.shard_factories = list(shard_factories)
         self.n_rows = n_rows
-        #: None = resolve ingest_workers() (env > planner > hand) per pass
+        #: None = resolve ingest_workers() (TMOG_INGEST_WORKERS) per pass
         self.workers = workers
         self.ahead = max(1, int(ahead))
         self.label = label

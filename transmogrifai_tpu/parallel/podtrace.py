@@ -35,10 +35,7 @@ module is that signal path, in three layers:
    round width is the slowest rank's width. Per round it computes the
    straggler rank, the max/median derived-compute ratio and each rank's
    collective-wait share; an MFU pass attributes analytic FLOPs/bytes
-   (the planner's priors) to the measured spans and names the top
-   sinks (`mfu_table`); `harvest_pod` feeds the same spans into the
-   per-backend planner corpus keyed by process count — the feedback
-   flywheel ROADMAP item 4 names, now fed by every pod run.
+   to the measured spans and names the top sinks (`mfu_table`).
 
 Surfaces: ``trace-report --pod <dir>`` (merged timeline + skew table,
 exit 1 on undercoverage or broken round alignment), ``bench.py
@@ -66,7 +63,7 @@ __all__ = [
     "enabled", "active", "start", "finish", "beat", "pod_round",
     "compute", "collective", "ingest", "note_collective",
     "read_heartbeat", "straggler_table", "rank_dirs", "merge_pod",
-    "harvest_pod", "pod_report", "pod_report_rc", "COVERAGE_MIN",
+    "pod_report", "pod_report_rc", "COVERAGE_MIN",
     "STRAGGLER_RATIO", "HEARTBEAT_NAME", "METRICS_NAME", "META_NAME",
 ]
 
@@ -543,9 +540,7 @@ def _median(vals: List[float]) -> float:
 
 
 # analytic FLOPs/bytes priors per collective/compute site, from the
-# attrs the instrumentation sites stamp (rows/feat/lanes/iters). These
-# are the planner's closed-form work models, reused so the MFU table's
-# numerator and the calibration corpus agree on what "work" means.
+# attrs the instrumentation sites stamp (rows/feat/lanes/iters).
 def _analytic_cost(name: str, attrs: Dict[str, Any]
                    ) -> Tuple[float, float]:
     """(flops, bytes) attributed to one measured span; (0, 0) when the
@@ -862,43 +857,6 @@ def _mfu_table(live: List[Dict[str, Any]],
             row["mfu"] = round(flops / wall / (roof_gflops * 1e9), 4)
         rows.append(row)
     return rows[:top]
-
-
-# -- planner-corpus harvest --------------------------------------------------
-
-def harvest_pod(pod_dir: str, corpus_path: Optional[str] = None,
-                backend: Optional[str] = None) -> int:
-    """Harvest every rank's measured spans into the per-backend planner
-    corpus, keyed by process count twice over: the backend key carries
-    the ``-pc<N>`` suffix (the SAME convention planner/plan._backend
-    uses inside a pod, so these rows land in the corpus file the pod's
-    own plan lookups read) and the pod span shapes carry
-    ``shape["procs"]`` — pod evidence never mixes with single-process
-    evidence at the same geometry. Returns the number of NEW corpus
-    rows. Reuses `corpus.harvest_metrics_doc` for the kernel/tile spans
-    each rank's metrics.json already carries, plus the pod span
-    families (`corpus.harvest_pod_spans`)."""
-    from ..planner import corpus as C
-    from ..planner.plan import corpus_dir
-    dirs = rank_dirs(pod_dir)
-    if not dirs:
-        return 0
-    procs = len(dirs)
-    records = []
-    for rank, path in dirs:
-        loaded = _load_rank(rank, path)
-        if loaded["torn"]:
-            continue
-        b = backend or str(loaded["meta"].get("backend") or "cpu")
-        if procs > 1 and not b.endswith(f"-pc{procs}"):
-            b = f"{b}-pc{procs}"
-        doc = loaded.get("doc") or {}
-        records.extend(C.harvest_metrics_doc(doc, b, src="podtrace"))
-        records.extend(C.harvest_pod_spans(loaded["spans"], b,
-                                           procs=procs,
-                                           src="podtrace"))
-    store = C.Corpus(corpus_path or corpus_dir())
-    return store.append(records)
 
 
 # -- trace-report --pod ------------------------------------------------------

@@ -22,11 +22,9 @@ This module is the ONE pipeline those consumers now share:
   RING: the copy slot carries `TMOG_TILE_PREFETCH` tokens (released
   when the consumer dequeues a tile), so at most depth+1 tiles are ever
   in flight — the one computing plus up to `depth` copied-ahead. The
-  hand default of 1 is exactly the old two-in-flight double buffering;
-  the plan-time autotuner raises it when measured tile_parse/tile_copy
-  unit costs dominate tile_compute (docs/planning.md). Depth NEVER
-  changes tile sizes or boundaries, so results stay bit-identical at
-  any depth;
+  default of 1 is exactly the old two-in-flight double buffering. Depth
+  NEVER changes tile sizes or boundaries, so results stay bit-identical
+  at any depth;
 - the feed side itself can parallelize: a RowSource may parse file
   shards on a worker pool (parallel/ingest.ShardedSource) as long as
   `chunks()` yields the same chunk sequence as a serial read — the
@@ -65,7 +63,6 @@ ever materializing the matrix.
 """
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -74,17 +71,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List,
 
 import numpy as np
 
-_TILE_MB_DEFAULT = 32
-_TILE_PREFETCH_DEFAULT = 1
-
-
-def env_on(name: str, default: str = "1") -> bool:
-    """Tri-state TMOG_* toggle parse (same falsy spellings as
-    ops/glm_sweep.env_on; duplicated here rather than imported so the
-    parallel/ layer never triggers the ops/ package import at module
-    init)."""
-    return os.environ.get(name, default).strip().lower() \
-        not in ("0", "false", "off")
+from ..utils.env import env_int, env_on
 
 
 def tileplane_enabled() -> bool:
@@ -94,39 +81,20 @@ def tileplane_enabled() -> bool:
 
 
 def tile_budget_bytes() -> int:
-    """Host/device bytes per tile: the knob that sizes every consumer's
-    tile. Two tiles in flight + the carry is the pipeline's whole device
-    footprint. An explicitly-set TMOG_TILE_MB wins (hand beats model);
-    otherwise the plan-time autotuner picks the size — a cold corpus
-    (or TMOG_PLAN=0, or any planner fault) yields the same 32MB hand
-    default this knob always had (docs/planning.md)."""
-    try:
-        from ..planner.plan import planned_tile_mb
-        return planned_tile_mb() << 20
-    except Exception:
-        return int(os.environ.get(
-            "TMOG_TILE_MB", str(_TILE_MB_DEFAULT))) << 20
+    """Host/device bytes per tile: TMOG_TILE_MB, default 32. It sizes
+    every consumer's tile; two tiles in flight + the carry is the
+    pipeline's whole device footprint."""
+    return env_int("TMOG_TILE_MB", 32) << 20
 
 
 def tile_prefetch_depth() -> int:
     """Copy-slot tokens in the prefetch ring: how many tiles the
     producer may run AHEAD of the consumer (device footprint is
-    depth+1 tiles plus the carry). An explicitly-set TMOG_TILE_PREFETCH
-    wins (hand beats model); otherwise the plan-time autotuner derives
-    the depth from measured tile_parse/tile_copy/tile_compute span
-    ratios — a cold corpus (or TMOG_PLAN=0, or any planner fault)
-    yields the depth-1 hand default, i.e. the classic double buffering
-    this pipeline always had. Depth only changes how far the feed side
-    runs ahead, never tile shapes, so any depth is bit-identical."""
-    try:
-        from ..planner.plan import planned_tile_prefetch
-        return max(1, int(planned_tile_prefetch()))
-    except Exception:
-        try:
-            return max(1, int(os.environ.get(
-                "TMOG_TILE_PREFETCH", str(_TILE_PREFETCH_DEFAULT))))
-        except ValueError:
-            return _TILE_PREFETCH_DEFAULT
+    depth+1 tiles plus the carry): TMOG_TILE_PREFETCH, default 1 (the
+    classic double buffering), never below 1. Depth only changes how
+    far the feed side runs ahead, never tile shapes, so any depth is
+    bit-identical."""
+    return max(1, env_int("TMOG_TILE_PREFETCH", 1))
 
 
 def tile_rows_for(row_bytes: int, n_rows: Optional[int] = None,
@@ -496,8 +464,8 @@ def run_tileplane(source: RowSource, step: Callable[..., Any], carry0: Any,
     `step`, returning the final DEVICE carry and the pass stats.
 
     `prefetch` is the ring depth — how many tiles the producer may copy
-    ahead of the consumer (None resolves tile_prefetch_depth(): env >
-    planner > hand default 1). Depth changes device footprint
+    ahead of the consumer (None resolves tile_prefetch_depth():
+    TMOG_TILE_PREFETCH, default 1). Depth changes device footprint
     ((depth+1) tiles + carry) and overlap, never tile boundaries, so
     the carry is bit-identical at any depth.
 
@@ -707,7 +675,7 @@ def pipelined(produce: Iterable[Any], *, label: str = "tileplane",
     prefetch ring for consumers whose 'tile' is a host object (the
     scoring path assembles a Dataset per record tile here while the
     device scores the previous one). Items are produced at most `depth`
-    ahead (None resolves tile_prefetch_depth(); the hand default of 1
+    ahead (None resolves tile_prefetch_depth(); the default of 1
     is the old one-ahead double buffering)."""
     d = max(1, int(depth)) if depth else tile_prefetch_depth()
     q: "queue.Queue" = queue.Queue(maxsize=d)

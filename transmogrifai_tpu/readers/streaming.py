@@ -24,6 +24,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional,
 
 import numpy as np
 
+from ..utils.env import env_int
 from .readers import Reader
 
 Record = Dict[str, Any]
@@ -215,16 +216,9 @@ class CSVStreamingReader(FileStreamingReader):
 
 def score_tile_rows_default() -> int:
     """Records per scoring tile: the fixed batch shape every stage
-    program compiles ONCE for. An explicitly-set TMOG_SCORE_TILE_ROWS
-    wins (hand beats model, logged as a plan_override event); otherwise
-    the plan-time autotuner picks the tile — cold corpus / TMOG_PLAN=0
-    / any planner fault all yield the 1024 hand default
-    (docs/planning.md)."""
-    try:
-        from ..planner.plan import planned_score_tile_rows
-        return planned_score_tile_rows()
-    except Exception:
-        return int(os.environ.get("TMOG_SCORE_TILE_ROWS", "1024"))
+    program compiles ONCE for: TMOG_SCORE_TILE_ROWS, default 1 024; 0
+    sends `score_stream` down the legacy per-record path."""
+    return env_int("TMOG_SCORE_TILE_ROWS", 1024)
 
 
 def _record_tiles(stream_reader: StreamingReader, tile_rows: int

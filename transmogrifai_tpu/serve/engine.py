@@ -68,36 +68,20 @@ _BUCKET_FLOOR = 8
 _NUMERIC_KINDS = (ColumnKind.FLOAT, ColumnKind.INT, ColumnKind.BOOL)
 
 
-def bucket_ladder(max_batch: int,
-                  floor: Optional[int] = None) -> Tuple[int, ...]:
+def bucket_ladder(max_batch: int) -> Tuple[int, ...]:
     """(1, 8, 16, …, 2^ceil(log2(max_batch))): the fixed batch shapes the
     engine compiles. The top rung rounds max_batch UP to a power of two —
-    padding a full batch beats compiling an off-power shape. ``floor``
-    overrides the first rung above the single-record bucket (the
-    planner's serve_bucket_floor decision; default `_BUCKET_FLOOR`)."""
+    padding a full batch beats compiling an off-power shape."""
     mb = max(int(max_batch), 1)
     rungs = [1]
     if mb == 1:
         return (1,)
-    b = max(int(floor) if floor else _BUCKET_FLOOR, 2)
+    b = _BUCKET_FLOOR
     while b < mb:
         rungs.append(b)
         b *= 2
     rungs.append(b)
     return tuple(rungs)
-
-
-def planned_bucket_ladder(max_batch: int) -> Tuple[int, ...]:
-    """The plan-time ladder (docs/planning.md): the planner may move the
-    floor rung from measured per-bucket dispatch walls; a cold corpus
-    (or TMOG_PLAN=0) yields exactly ``bucket_ladder(max_batch)``. Any
-    planner fault degrades to the hand ladder — serving startup must
-    never depend on corpus health."""
-    try:
-        from ..planner.plan import plan_serving
-        return plan_serving(max_batch).buckets
-    except Exception:
-        return bucket_ladder(max_batch)
 
 
 _TEMPLATE_BY_KIND = {
@@ -154,11 +138,10 @@ class ServingEngine:
         if example is None and manifest and \
                 isinstance(manifest.get("example"), dict):
             example = manifest["example"]
-        # explicit buckets / manifest ladders are hand plans and win
-        # outright; only the defaulted ladder consults the planner
+        # explicit buckets, then the manifest's ladder, then the default
         self.buckets: Tuple[int, ...] = (
             tuple(sorted({int(b) for b in buckets})) if buckets
-            else planned_bucket_ladder(max_batch))
+            else bucket_ladder(max_batch))
         if self.buckets[0] < 1:
             raise ValueError(f"bucket sizes must be >= 1: {self.buckets}")
         # manifest freshness (docs/fleet.md "The manifest contract"):
