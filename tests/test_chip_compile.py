@@ -296,3 +296,35 @@ def test_heldout_metric_pass_compiles_for_the_four_chip_mesh(
     text = compiled.as_text()
     assert "_hist_two_level_jit" in text
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
+
+
+@pytest.mark.parametrize("d", [128, 64], ids=["linreg-128", "cols-64"])
+def test_regression_sweep_programs_compile_for_a_v5e(one_chip,
+                                                     metric_programs, d):
+    """sweep-linreg-nulls128's programs at 25M rows, and the same at the
+    width the chip keeps rows-minor: the Gram pass reads X in place (no
+    temporaries at all: a padded, transposed or float32 copy of X would be
+    6.4 GB or more) and the held-out pass of sums holds a few [rows]
+    vectors (under 5 % of the 128-column X), neither a [Gc, rows] array of
+    scores; the moment-space solves hold nothing of the rows' size."""
+    n, F, Gc = 25_000_000, 5, 8
+    x_bytes = n * d * 2
+
+    def S(shape, dt=F32, _=None):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    gram = GS.sweep_gram_moments.lower(
+        S((n, d), BF16), S((n,)), S((n,)), S((F, n)), S((d,)), S((d,))
+    ).compile()
+    assert gram.memory_analysis().temp_size_in_bytes <= 0.05 * x_bytes
+    assert "highest" in gram.as_text()
+    solve = GS.sweep_gram_solve.lower(
+        S((F, d, d)), S((F, d)), S((F, d)), S((F,)), S((F,)), S((d,)),
+        S((d,)), S((Gc,)), S((Gc,)), S((), jnp.int32), S(()),
+        fit_intercept=True).compile()
+    assert solve.memory_analysis().temp_size_in_bytes < 64 << 20
+    metric = metric_programs._streamed_eval_heldout.lower(
+        *_heldout_eval_shapes(S, n, d, F, Gc), False, metric="rmse",
+        rank_bins=4096).compile()
+    assert metric.memory_analysis().temp_size_in_bytes \
+        <= 0.06 * n * 128 * 2
+    assert "_hist_two_level_jit" not in metric.as_text()

@@ -82,7 +82,7 @@ class TestGramFastPath:
         w = np.ones_like(y)
         regs = np.array([0.001, 0.05, 0.5], np.float32)
         alphas = np.array([0.0, 0.5, 0.25], np.float32)
-        B, b0, _ = sweep_glm_squared_gram(
+        B, b0, *_ = sweep_glm_squared_gram(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             50, 1e-6, standardize=standardize)
@@ -106,7 +106,7 @@ class TestGramFastPath:
         X, y = _regression(n=1500, d=5, seed=3)
         masks = _masks(y, folds=2, seed=2)
         w = np.ones_like(y)
-        B, b0, _ = sweep_glm_squared_gram(
+        B, b0, *_ = sweep_glm_squared_gram(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray([0.01], np.float32),
             jnp.asarray([0.25], np.float32), 50, 1e-6,
@@ -124,7 +124,7 @@ class TestGramFastPath:
         rng = np.random.default_rng(11)
         w = rng.uniform(0.25, 3.0, size=len(y)).astype(np.float32)
         masks = _masks(y, folds=2, seed=5)
-        B, b0, _ = sweep_glm_squared_gram(
+        B, b0, *_ = sweep_glm_squared_gram(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray([0.05], np.float32),
             jnp.asarray([0.5], np.float32), 50, 1e-6, standardize=False)
@@ -545,12 +545,12 @@ class TestShardedRounds:
         masks = _masks(y, folds=2, seed=15)
         regs = np.array([0.01, 0.3], np.float32)
         alphas = np.array([0.0, 0.5], np.float32)
-        B1, b01, _ = sweep_glm_squared_gram(
+        B1, b01, *_ = sweep_glm_squared_gram(
             jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
             jnp.asarray(masks), jnp.asarray(regs), jnp.asarray(alphas),
             50, 1e-6, standardize=True)
         Xd, yd, wd, md = self._put(mesh, X, y, w, masks)
-        B2, b02, _ = GS.sweep_glm_squared_gram_sharded(
+        B2, b02, *_ = GS.sweep_glm_squared_gram_sharded(
             mesh, Xd, yd, wd, md, jnp.asarray(regs), jnp.asarray(alphas),
             50, 1e-6, standardize=True)
         assert np.allclose(np.asarray(B1), np.asarray(B2), atol=3e-3)

@@ -41,7 +41,7 @@ def _streamed(X, y, w, masks, regs, alphas, *, loss, max_iter, standardize,
     host arrays or already placed on `mesh`."""
     regs, alphas = jnp.asarray(regs), jnp.asarray(alphas)
     if loss == "squared":
-        B, b0, _ = GS.sweep_glm_squared_gram(
+        B, b0, *_ = GS.sweep_glm_squared_gram(
             X, y, w, masks, regs, alphas, max_iter, standardize=standardize)
     else:
         B, b0, _ = GS.sweep_glm_streamed_rounds(

@@ -125,9 +125,9 @@ out["stream_cnt_err"] = err(st2.cnt, ref.cnt)
 from transmogrifai_tpu.ops import glm_sweep as GS
 regs = np.asarray([0.1, 1.0], np.float32)
 alphas = np.asarray([0.0, 0.5], np.float32)
-B2, b02, _ = GS.sweep_glm_squared_gram_sharded(
+B2, b02, *_ = GS.sweep_glm_squared_gram_sharded(
     mesh, X[lo:hi], y[lo:hi], w[lo:hi], masks[:, lo:hi], regs, alphas)
-B1, b01, _ = GS.sweep_glm_squared_gram(
+B1, b01, *_ = GS.sweep_glm_squared_gram(
     jnp.asarray(X), jnp.asarray(y), jnp.asarray(w), jnp.asarray(masks),
     jnp.asarray(regs), jnp.asarray(alphas))
 out["glm_gram_err"] = max(err(B2, B1), err(b02, b01))

@@ -72,9 +72,9 @@ def test_single_process_engines_never_touch_multihost_landing(
 
     regs = np.asarray([0.5], np.float32)
     alphas = np.asarray([0.0], np.float32)
-    B, b0, _ = GS.sweep_glm_squared_gram_sharded(mesh, X, y, w, masks,
+    B, b0, *_ = GS.sweep_glm_squared_gram_sharded(mesh, X, y, w, masks,
                                                  regs, alphas)
-    B1, b01, _ = GS.sweep_glm_squared_gram(
+    B1, b01, *_ = GS.sweep_glm_squared_gram(
         jnp.asarray(X), jnp.asarray(y), jnp.asarray(w), jnp.asarray(masks),
         jnp.asarray(regs), jnp.asarray(alphas))
     np.testing.assert_allclose(np.asarray(B), np.asarray(B1), atol=1e-5)

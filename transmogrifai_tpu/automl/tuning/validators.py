@@ -153,9 +153,11 @@ def grid_fuse_max_failures() -> int:
 def _lanes_metric_fn(metric: str, problem_type: str, rank_bins,
                      unit_payload: bool = False):
     """(scores [L, n], labels [n], w_lanes [L, n]) -> [L] metric values
-    when the metric has a lane-batched binned kernel, else None. Single
-    source of the guard for every sweep path (streamed eval, tree fold
-    metrics). `unit_payload`: validate()'s word that weights x labels are
+    when the metric has a lane-batched binned kernel, else None: the guard
+    of the binned COUNTS for every sweep path (streamed eval, tree fold
+    metrics). Which metrics take the streamed sweep's one held-out pass is
+    `heldout_metric_body`'s to say, and a binned rank metric is one kind
+    of them. `unit_payload`: validate()'s word that weights x labels are
     zeros and ones (Validator._unit_payload), handed on to the kernel."""
     if not (rank_bins and problem_type == "binary"):
         return None
@@ -165,6 +167,24 @@ def _lanes_metric_fn(metric: str, problem_type: str, rank_bins,
         return None
     return lambda s, y, wl: lanes(s, y, wl, rank_bins,
                                   unit_payload=unit_payload)
+
+
+def heldout_metric_body(metric: str, problem_type: str, rank_bins
+                        ) -> Optional[str]:
+    """How the one-pass held-out route (`eval_route` "heldout_once":
+    `_eval_heldout_core`) takes this metric, or None where it cannot: the
+    route is open to any metric that is a sum or a count over held-out
+    rows — "bins" for a binned rank metric (the cumulative class counts a
+    score bin, `_lanes_metric_fn`), "sums" for the regression metrics
+    (weighted sums of the residual and of the label). THE predicate: the
+    route choice and the telemetry's `metric_body` read it, and the metric
+    program (`_eval_heldout_core`) branches on the same names."""
+    if problem_type == "regression" \
+            and metric in M.RegressionMetrics._fields:
+        return "sums"
+    if _lanes_metric_fn(metric, problem_type, rank_bins) is not None:
+        return "bins"
+    return None
 
 
 def _held_out_at_most_once(masks) -> bool:
@@ -211,8 +231,13 @@ def _streamed_confusion(X, y, vw, Bc, b0c, n_classes: int):
 def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
                    n_classes=2, rank_bins=None, chunk=8, use_lanes=True,
                    unit_payload=False):
-    """Metrics for one fold's grid chunk of streamed-sweep coefficients:
-    scores in one MXU contraction; binned rank metrics go through the
+    """Metrics for one fold's grid chunk of streamed-sweep coefficients
+    (the `per_fold` route: every row scored once a FOLD, all but the
+    fold's own at weight zero — what is left for overlapping masks, a mesh
+    that spans processes, exact rank metrics and thresholded ones):
+    scores in one MXU contraction (a regression sweep's at float32
+    coefficients, `sweep_scores_fold(exact=True)`, as its held-out pass);
+    binned rank metrics go through the
     lane-batched kernel (one pallas histogram for the whole chunk on TPU
     instead of per-lane scatter-adds), everything else vmaps. Mesh
     callers pass use_lanes=False (a pallas_call must not consume
@@ -224,7 +249,10 @@ def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
         return jax.vmap(lambda cf: getattr(
             M.multiclass_metrics_from_confusion(cf), metric))(conf)
     from ...ops.glm_sweep import sweep_scores_fold
-    s = sweep_scores_fold(X, Bc, b0c)                   # [n, chunk]
+    # a regression metric is not invariant to coefficients rounded to X's
+    # dtype (a bias of sum(delta_j mean_j) in every prediction): exact
+    s = sweep_scores_fold(X, Bc, b0c,
+                          exact=problem_type == "regression")  # [n, chunk]
     lanes_fn = _lanes_metric_fn(metric, problem_type, rank_bins,
                                 unit_payload) if use_lanes else None
     if lanes_fn is not None:
@@ -232,6 +260,14 @@ def _streamed_eval(X, y, vw, Bc, b0c, thr, *, metric, problem_type,
         return lanes_fn(s.T, y, wl)
     mfn = _metric_fn(problem_type, metric, n_classes, rank_bins)
     return jax.vmap(lambda col: mfn(col, y, vw, thr), in_axes=1)(s)
+
+
+def _heldout_fold_of(masks):
+    """fold_of [n] int32: the fold that holds the row out (an entry != 1
+    of the [F, n] masks, whose held-out sets are disjoint), F for none."""
+    out = masks != 1.0
+    return jnp.where(jnp.any(out, axis=0), jnp.argmax(out, axis=0),
+                     masks.shape[0]).astype(jnp.int32)
 
 
 def _heldout_scores(X, masks, Bc, b0c):
@@ -245,9 +281,7 @@ def _heldout_scores(X, masks, Bc, b0c):
     from ...ops import glm_sweep as GS
     F, Gc, d = Bc.shape
     n = X.shape[0]
-    out = masks != 1.0
-    fold_of = jnp.where(jnp.any(out, axis=0), jnp.argmax(out, axis=0),
-                        F).astype(jnp.int32)
+    fold_of = _heldout_fold_of(masks)
     Ball = Bc.reshape(F * Gc, d).astype(X.dtype)
     c = GS._mlr_row_block(F * Gc, n)
     nb, take = GS._mlr_blocks(n, c, X.T, fold_of)
@@ -267,12 +301,86 @@ def _heldout_scores(X, masks, Bc, b0c):
                              jnp.zeros((Gc, n), jnp.float32)), fold_of
 
 
+def _heldout_regression(X, y, vw, masks, Bc, b0c, metric, allreduce):
+    """[F, Gc] of a regression metric (a field of M.RegressionMetrics) from
+    ONE pass over X: every row predicted under the grid chunk fitted by
+    the fold that holds it out, and a fold and grid point the weighted
+    sums of the residual r — sum w r^2, sum w |r| — beside the fold's sum
+    w, sum w (y - p), sum w (y - p)^2 about the fold's own held-out label
+    mean p (read first, from y and w alone: a label far from zero then
+    cancels nothing in R2's total sum of squares, as the two-pass
+    M.regression_metrics). No histogram, nothing [Gc, n] resident: a row
+    block's sums land in their own row of a [blocks, F, 4, Gc] array,
+    which is added up at the end (a running float32 sum over hundreds of
+    blocks would lose what the metric must resolve). `allreduce` sums
+    over the mesh axis: the label's [F, 2], then the [F, 4, Gc] sums.
+
+    The contraction sees the coefficients at float32 precision, as their
+    exact parts of X's dtype (`pallas_glm.coefficient_parts`; a float32
+    matrix contracts at HIGHEST): rounded to bfloat16 they shift EVERY
+    prediction by sum(delta_j mean_j) on columns that are not centred,
+    which adds to the squared error in full."""
+    from ...ops import glm_sweep as GS
+    from ...ops import pallas_glm as PG
+    f32 = jnp.float32
+    F, Gc, d = Bc.shape
+    n = X.shape[0]
+    fold_of = _heldout_fold_of(masks)
+    held = [fold_of == f for f in range(F)]
+    label = allreduce(jnp.stack(
+        [jnp.stack([jnp.where(h, vw, 0.0).sum(),
+                    jnp.where(h, vw * y, 0.0).sum()]) for h in held]))
+    sw = jnp.maximum(label[:, 0], M.EPS)                        # [F]
+    pivot = jnp.append(label[:, 1] / sw, 0.0)                   # [F + 1]
+    parts = PG.coefficient_parts(Bc.reshape(F * Gc, d), X.dtype)
+    precision = jax.lax.Precision.HIGHEST \
+        if X.dtype == jnp.float32 else None
+    c = GS._mlr_row_block(parts.shape[0], n)
+    nb, take = GS._mlr_blocks(n, c, fold_of, y, vw)
+    x_block = GS.x_row_blocks(X, c)
+
+    def body(i, sums):
+        f_blk, fresh, y_blk, w_blk = take(i)
+        s = (PG.margins(jnp.matmul(x_block(i), parts.T, precision=precision,
+                                   preferred_element_type=f32), F * Gc,
+                        axis=1) + b0c.reshape(1, F * Gc)).reshape(c, F, Gc)
+        own = s[:, 0]
+        for f in range(1, F):
+            own = jnp.where(f_blk[:, None] == f, s[:, f], own)
+        r = own - y_blk[:, None]                                # [c, Gc]
+        yc = jnp.broadcast_to((y_blk - pivot[f_blk])[:, None], r.shape)
+        q = jnp.stack([r * r, jnp.abs(r), yc, yc * yc], axis=1)
+        wf = jnp.stack([jnp.where(f_blk == f, w_blk * fresh, 0.0)
+                        for f in range(F)], axis=1)             # [c, F]
+        part = jnp.einsum("cf,ckg->fkg", wf, q,
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=f32)
+        return jax.lax.dynamic_update_slice_in_dim(
+            sums, part[None], i, axis=0)
+
+    s_r2, s_abs, s_y, s_y2 = jnp.moveaxis(allreduce(jax.lax.fori_loop(
+        0, nb, body, jnp.zeros((nb, F, 4, Gc), f32)).sum(0)), 1, 0)
+    sw = sw[:, None]
+    mse = s_r2 / sw
+    ss_tot = s_y2 - s_y * s_y / sw
+    return {"mse": mse, "rmse": jnp.sqrt(mse), "mae": s_abs / sw,
+            "r2": 1.0 - s_r2 / jnp.maximum(ss_tot, M.EPS)}[metric]
+
+
 def _eval_heldout_core(X, y, w, masks, Bc, b0c, *, metric, rank_bins,
                        unit_payload=False, axis_name=None):
     """The held-out-once metric pass over the rows at hand: all of them,
-    or under `axis_name` a chip's own, whose [F, Gc, bins] counts are
-    summed over that mesh axis (ONE psum of both classes' counts) before
-    the metric is taken from them."""
+    or under `axis_name` a chip's own, whose sums are added over that mesh
+    axis before the metric is taken from them. One body a kind of metric
+    (`heldout_metric_body`): a regression metric (`rank_bins` is None) its
+    weighted sums (`_heldout_regression`: one psum of the folds' label
+    sums, one of the [F, 4, Gc] residual sums); a binned rank metric the
+    [F, Gc, bins] counts (ONE psum of both classes' counts)."""
+    if metric in M.RegressionMetrics._fields:
+        return _heldout_regression(
+            X, y, (1.0 - jnp.min(masks, axis=0)) * w, masks, Bc, b0c, metric,
+            (lambda v: v) if axis_name is None
+            else (lambda v: jax.lax.psum(v, axis_name)))
     scores, fold_of = _heldout_scores(X, masks, Bc, b0c)
     vw = (1.0 - jnp.min(masks, axis=0)) * w
     counts = M.heldout_cum_counts_lanes(scores, y, vw, fold_of, Bc.shape[0],
@@ -595,6 +703,9 @@ class Validator:
         """Large binary/regression GLM sweeps route through the streaming
         lane-batched kernel (ops/glm_sweep.py) — under a mesh, its
         shard_map variant (per-shard row scans, psum'd accumulators).
+        (How the route then takes its metric — one held-out pass or one a
+        fold — is not decided here: `heldout_metric_body`, a property of
+        the metric, read in `_validate_streamed`.)
         Past TRI_MAX_D features the kernel switches internally to
         feature-tiled Gram accumulation, so width no longer excludes the
         route; the remaining guard is the per-iteration [L, d, d]
@@ -984,18 +1095,36 @@ class Validator:
         if loss == "squared":
             fk = {k: v for k, v in fit_kwargs.items() if k != "loss"}
             mi, tl = fk.pop("max_iter"), fk.pop("tol")
-            if self._sweep_mesh is not None:
-                B, b0, giters = GS.sweep_glm_squared_gram_sharded(
-                    self._sweep_mesh, Xd, yd, wd, md, regs_p, alphas_p, mi,
-                    tl, **fk)
-            else:
-                B, b0, giters = GS.sweep_glm_squared_gram(
-                    Xd, yd, wd, md, regs_p, alphas_p, mi, tl, **fk)
+            mesh = self._sweep_mesh
+            d = int(Xd.shape[1])
+            # the pass's programs are dispatched inside `gram_pass`; the
+            # host then waits in `gram_solve` for the solves' two counts,
+            # the fit's only fetch
+            with collector.trace_span(
+                    "gram_pass", kind="host_step", folds=F, cols=d,
+                    body=GS.GRAM_PASS_BODY, x_tile=GS.glm_x_tile(d)):
+                if mesh is not None:
+                    B, b0, giters, gcap = GS.sweep_glm_squared_gram_sharded(
+                        mesh, Xd, yd, wd, md, regs_p, alphas_p, mi, tl,
+                        **fk)
+                else:
+                    B, b0, giters, gcap = GS.sweep_glm_squared_gram(
+                        Xd, yd, wd, md, regs_p, alphas_p, mi, tl, **fk)
+            with collector.trace_span("gram_solve", kind="host_step",
+                                      lanes=L) as sp:
+                giters, gcap = int(giters), int(gcap)
+                if sp is not None:
+                    sp.attrs.update(iters=giters, lanes_at_cap=gcap)
             info = {"route": "streamed", "kernel": "gram",
+                    "gram_body": GS.GRAM_PASS_BODY,
                     "glm_rounds": 1, "data_passes": 1, "lane_passes": F,
                     "padded_lane_passes": F,  # the Gram pass never pads
-                    "lanes_total": L, "lanes_retired": L,
-                    "gram_solve_iters": int(giters)}
+                    "lanes_total": L, "lanes_retired": L - gcap,
+                    "lanes_at_cap": gcap, "gram_solve_iters": giters,
+                    # whole reads of X by the fit: the column moments'
+                    # two passes (`_psum_moments`) and the Gram pass
+                    # (`x_passes`, once the metric's are known)
+                    "fit_x_passes": 1 + 2 * bool(fk["standardize"])}
             return B, b0, info, None
         rc, state, on_round = round_hooks()
         # across-time warm seed (retrain refit): the previous champion's
@@ -1028,9 +1157,13 @@ class Validator:
                            ) -> List[ValidatedModel]:
         """Streamed convergence-aware sweep: every pending (fold x grid)
         cell fits through _streamed_fit (Gram fast path / retirement round
-        driver / multinomial rounds); metrics then
-        run per fold in grid chunks of one scoring matmul each (multiclass:
-        one block-scanned confusion count each)."""
+        driver / multinomial rounds); the metric then takes ONE pass over
+        X a grid chunk where it is a sum or a count over held-out rows and
+        no row is held out twice (`heldout_metric_body`: binned AuPR /
+        AuROC of a binary sweep, RMSE / MSE / MAE / R2 of a regression
+        sweep; `eval_route` heldout_once, one fetch a sweep), and
+        otherwise runs per fold in grid chunks of one scoring matmul each
+        (multiclass: one block-scanned confusion count each)."""
         multiclass = problem_type == "multiclass"
         regs, alphas = self._grid_axis_arrays(est, grids)
         # constant off-axis grid keys (admitted by _constant_off_axis) must
@@ -1090,21 +1223,26 @@ class Validator:
                 idx = list(range(s, min(s + chunk, len(pending))))
                 chunks.append(
                     (idx, jnp.asarray(idx + [idx[-1]] * (chunk - len(idx)))))
-            # disjoint held-out sets and a lane-batched binned metric: every
-            # row is scored and binned ONCE for all folds, on a mesh by the
-            # chip that holds it; otherwise (and across processes) fold by
-            # fold over the whole matrix
-            binned_lanes = _lanes_metric_fn(metric, problem_type,
-                                            rank_bins) is not None
+            # disjoint held-out sets and a metric that is a sum or a count
+            # over held-out rows (`heldout_metric_body`): every row is
+            # scored ONCE for all folds, on a mesh by the chip that holds
+            # it; otherwise (and across processes) fold by fold over the
+            # whole matrix
+            body = heldout_metric_body(metric, problem_type, rank_bins)
+            binned_lanes = body == "bins"
             unit = self._unit_payload
             heldout_once = (
                 self._heldout_once and not mesh_is_multiprocess(mesh)
-                and binned_lanes)
+                and body is not None)
             if heldout_once and mesh is not None:
                 eval_fn = _sharded_eval_heldout_fn(mesh, metric, rank_bins)
-                # one psum of both classes' [F, Gc, bins] counts a chunk
-                eval_psums = len(chunks)
-                eval_psum_bytes = eval_psums * 2 * F * chunk * rank_bins * 4
+                # a chunk: one psum of both classes' [F, Gc, bins] counts,
+                # or of the folds' [F, 2] label sums and then of the
+                # [F, 4, Gc] residual sums
+                eval_psums = len(chunks) * (1 if binned_lanes else 2)
+                eval_psum_bytes = len(chunks) * 4 * (
+                    2 * F * chunk * rank_bins if binned_lanes
+                    else F * (2 + 4 * chunk))
             else:
                 eval_fn = partial(_streamed_eval_heldout, metric=metric,
                                   rank_bins=rank_bins)
@@ -1113,10 +1251,18 @@ class Validator:
                 "eval_route": "heldout_once" if heldout_once else "per_fold",
                 "passes": len(chunks) * (1 if heldout_once else F),
                 "shards": shards}
+            if heldout_once:
+                eval_info["metric_body"] = body
             if binned_lanes and (heldout_once or mesh is None):
                 # the lane-batched counts run: which histogram body, and
                 # the parts it takes the weights in
                 eval_info.update(M.rank_hist_kernel(rank_bins, unit))
+            if "fit_x_passes" in sweep_info:
+                # the Gram route: whole reads of X in the SWEEP, the fit's
+                # and the metric's (the wide rounds' `x_passes` are their
+                # fit's alone)
+                eval_info["x_passes"] = \
+                    sweep_info["fit_x_passes"] + eval_info["passes"]
             # the layout the sweep ran on, and the collectives it declares
             # (the rounds' own and the metric pass's; a per_fold pass on a
             # mesh leaves its collectives to GSPMD, uncounted)

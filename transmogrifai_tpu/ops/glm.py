@@ -165,7 +165,7 @@ def prox_newton_gram(Gm: jax.Array, cm: jax.Array, sx: jax.Array,
                      sy: jax.Array, sw: jax.Array, l1: jax.Array,
                      l2: jax.Array, beta0: jax.Array, b00: jax.Array,
                      max_iter, tol, fit_intercept: bool = True
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Lane-batched proximal Newton on cached squared-loss moments.
 
     Replays `_newton_prox_fit`'s update rule with every data-dependent term
@@ -175,38 +175,56 @@ def prox_newton_gram(Gm: jax.Array, cm: jax.Array, sx: jax.Array,
     proximal L1 against H's diagonal, intercept step b0 - g0 (h0/wsum == 1
     because wsum IS the lane weight sum). Warm-startable via beta0/b00 —
     the Gram fast path seeds from `ridge_gram_solve` of the same l2
-    (pathwise continuation). max_iter/tol are traced scalars. Returns
-    (beta [L, d], b0 [L], iters executed)."""
+    (pathwise continuation). max_iter/tol are traced scalars.
+
+    The gradient's [L, d, d] x [L, d] product runs at HIGHEST precision: at
+    the default the chip's matrix unit rounds G and beta to bfloat16, the
+    step then moves beta by beta's own rounding error (2^-9 |beta|) and no
+    delta ever clears a tolerance of 1e-6 (the moment-space sibling of
+    PERF.md, PR 38); the products are [40, 128, 128], free at any
+    precision. With an intercept the iteration runs on the label CENTRED
+    by the lane's weighted mean ybar = sy / sw (c - ybar sx for c, b0 -
+    ybar for b0: the same iterates, to rounding): the intercept's step
+    b0 sw - sy is otherwise a difference of two numbers of the label
+    mean's size, and at a label mean of 10 float32 leaves it a noise of
+    1e-6 — `tol` itself — so that delta never settles (PERF.md, PR 43).
+    The lanes iterate together while ANY delta is over `tol` (nothing to
+    retire: an iteration reads no data). Returns (beta [L, d], b0 [L],
+    iters executed, delta [L]: each lane's own last step, so that a caller
+    can count the lanes the cap stopped)."""
     f32 = jnp.float32
     d = Gm.shape[-1]
     eye = jnp.eye(d, dtype=f32)
     sw_ = jnp.maximum(sw, EPS)
     H = Gm / sw_[:, None, None] + (l2 + 1e-6)[:, None, None] * eye[None]
     hdiag = jnp.maximum(jnp.diagonal(H, axis1=1, axis2=2), EPS)
+    ybar = sy / sw_ if fit_intercept else jnp.zeros_like(sy)
+    cm = cm - ybar[:, None] * sx
 
     def cond(state):
         i, _, _, delta = state
-        return (i < max_iter) & (delta > tol)
+        return (i < max_iter) & (delta.max() > tol)
 
     def body(state):
         i, beta, b0, _ = state
-        g = ((jnp.einsum('lde,le->ld', Gm, beta) + b0[:, None] * sx - cm)
+        g = ((jnp.einsum('lde,le->ld', Gm, beta,
+                         precision=jax.lax.Precision.HIGHEST)
+              + b0[:, None] * sx - cm)
              / sw_[:, None] + l2[:, None] * beta)
         step = jnp.linalg.solve(H, g[..., None])[..., 0]
         beta_new = _soft_threshold(beta - step, l1[:, None] / hdiag)
         if fit_intercept:
-            g0 = ((sx * beta).sum(1) + b0 * sw_ - sy) / sw_
-            b0_new = b0 - g0
+            # (sy - ybar sw is zero: the centred label's weighted sum)
+            b0_new = b0 - ((sx * beta).sum(1) / sw_ + b0)
         else:
             b0_new = b0
-        delta = (jnp.abs(beta_new - beta).max(1)
-                 + jnp.abs(b0_new - b0)).max()
+        delta = jnp.abs(beta_new - beta).max(1) + jnp.abs(b0_new - b0)
         return i + 1, beta_new, b0_new, delta
 
     state = (jnp.asarray(0, jnp.int32), beta0.astype(f32),
-             b00.astype(f32), jnp.asarray(jnp.inf, f32))
-    i, beta, b0, _ = jax.lax.while_loop(cond, body, state)
-    return beta, b0, i
+             b00.astype(f32) - ybar, jnp.full(sw.shape, jnp.inf, f32))
+    i, beta, b0, delta = jax.lax.while_loop(cond, body, state)
+    return beta, b0 + ybar, i, delta
 
 
 def fit_logistic(X: jax.Array, y: jax.Array, w: jax.Array,

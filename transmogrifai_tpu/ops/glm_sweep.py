@@ -51,11 +51,13 @@ sweep"): one streamed kernel a loss, on top of the shared scan machinery.
    collapses to the per-FOLD weighted Gram X^T diag(w * mask_f) X:
    iteration-invariant and only F matrices, not L. ONE streaming pass
    builds [F, d, d] Grams + X^T W_f y / X^T W_f 1 moments (psum'd under
-   shard_map); the whole reg x alpha grid then solves off the cached
+   shard_map), the blocks standardised in float32 and contracted at
+   HIGHEST precision (here the moments ARE the fit: `_gram_moments`); the
+   whole reg x alpha grid then solves off the cached
    moments — ridge lanes closed form (`ops/glm.ridge_gram_solve`),
    elastic-net lanes by proximal Newton on the cached Gram
-   (`ops/glm.prox_newton_gram`, seeded from the ridge solution). Up to
-   max_iter full-data passes become exactly one.
+   (`ops/glm.prox_newton_gram`, seeded from the ridge solution, on the
+   centred label). Up to max_iter full-data passes become exactly one.
 2. `sweep_glm_round` + the host driver `sweep_glm_streamed_rounds` — the
    IRLS losses (logistic, squared_hinge) run rounds of K iterations with
    a PER-LANE delta vector in the carry; after each round the host
@@ -209,11 +211,15 @@ def _tiling(d: int):
                                for b in range(a, nt)]
 
 
-def _gram_fns(tiled: bool, d_work: int, lanes: int, bt: int, tile_pairs):
+def _gram_fns(tiled: bool, d_work: int, lanes: int, bt: int, tile_pairs,
+              precision=None):
     """(hess_blocks, assemble, blocks0) for `lanes` weighted Grams of a
     d_work-wide block. `hess_blocks(xf [c, d_work] f32, S [c, lanes])`
     returns per-block accumulator contributions; `assemble` turns the
     summed accumulator into the full symmetric [lanes, d_work, d_work].
+    `precision` is the contractions' (the IRLS rounds take the default:
+    their Hessian only has to point downhill; the squared loss's Gram IS
+    the fit, and takes HIGHEST).
     These are the XLA bodies' Grams (_moments_blocks, _gram_core, the
     tileplane steps); where the binary rounds run the fused pass
     (glm_round_kernel) the narrow accumulator comes from
@@ -232,7 +238,7 @@ def _gram_fns(tiled: bool, d_work: int, lanes: int, bt: int, tile_pairs):
                 xa = xf[:, a * bt:(a + 1) * bt]
                 xb = xf[:, b * bt:(b + 1) * bt]
                 P = (xa[:, :, None] * xb[:, None, :]).reshape(-1, bt * bt)
-                out.append(jnp.matmul(S.T, P,
+                out.append(jnp.matmul(S.T, P, precision=precision,
                                       preferred_element_type=jnp.float32))
             return jnp.stack(out)
 
@@ -261,7 +267,7 @@ def _gram_fns(tiled: bool, d_work: int, lanes: int, bt: int, tile_pairs):
         # end-to-end while this full symmetric einsum runs 25.8 TF/s —
         # 1.7x faster despite doing 2x the arithmetic
         # (tools/tpu_glm_hess_ab.py).
-        return jnp.einsum('cl,cd,ce->lde', S, xf, xf,
+        return jnp.einsum('cl,cd,ce->lde', S, xf, xf, precision=precision,
                           preferred_element_type=jnp.float32)
 
     return (hess_blocks, lambda hA: hA,
@@ -390,66 +396,97 @@ def _sharded_stats_fn(mesh):
 
 # -- squared-loss sufficient-statistics fast path ----------------------------
 
-def _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
-               fit_intercept, standardize,
-               axis_name: Optional[str] = None):
-    """loss="squared" fast path: ONE streaming pass accumulates per-FOLD
-    sufficient statistics (weighted Gram [F, d, d] + X^T W_f y, X^T W_f 1,
-    sums), then the whole reg x alpha grid solves off the cached moments:
-    ridge lanes closed form, elastic-net lanes via proximal Newton seeded
-    from the ridge solution (`ops/glm.{ridge_gram_solve,prox_newton_gram}`
-    — the moment-space replay of the per-lane update rule). When
-    standardize=True the column moments are computed first (one extra
-    stats pass; raw-moment standardization in moment space would cancel
-    catastrophically in f32 for large-mean columns), and standardization
-    is applied per block on the fly — no [n, d] standardized copy."""
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+# the body of the Gram pass over X, as the spans and the telemetry name it
+# (the binary rounds' `glm_round_kernel` has a second answer; this pass has
+# one on every backend)
+GRAM_PASS_BODY = "xla_blocks"
+
+
+def _psum_over(axis_name: Optional[str]):
+    """v -> v summed over the mesh axis; the identity on one device."""
+    return (lambda v: jax.lax.psum(v, axis_name)) if axis_name \
+        else (lambda v: v)
+
+
+def _gram_moments(X, y, w, fold_masks, mean, std, *,
+                  axis_name: Optional[str] = None):
+    """The squared loss's ONE pass over X: per-FOLD sufficient statistics
+    of the standardised rows xs = (x - mean) / std — (Gm [F, d, d] =
+    sum w_f xs xs', cA [F, d] = sum w_f y xs, sxA [F, d] = sum w_f xs,
+    syA [F] = sum w_f y, wsum_f [F]), psum'd over `axis_name`.
+
+    Precision: the block is standardised in float32 and every contraction
+    runs at HIGHEST, float32 sums. The default would round BOTH float32
+    operands to bfloat16 on the chip's matrix unit, and a value that many
+    rows share (an indicator's two standardised values, a fill) is then
+    off by up to 2^-9 in all of them at once: an error of the moment
+    itself, which no number of rows averages out — and here the moments
+    ARE the fit. (Standardising in moment space from raw products, which
+    are exact for a bfloat16 matrix, would cancel catastrophically for a
+    column whose mean is large beside its deviation: `_psum_moments`.)
+
+    X is read in place: a row block is a dynamic slice along the axis the
+    chip keeps major (`x_row_blocks`; y, w and the masks by
+    `_mlr_blocks`), no padded, reshaped or transposed copy — compiled for
+    a v5e the program of a 25M x 128 (or x 64) bfloat16 matrix holds NO
+    temporaries, where the scan over `_blocked`'s reshape held a second
+    copy of X and of the masks, 7.4 GB. Past TRI_MAX_D columns the block,
+    not X, is padded to the feature tiles."""
+    f32 = jnp.float32
     n, d = X.shape
     F = fold_masks.shape[0]
-    Gn = regs.shape[0]
     tiled, d_work, bt, tile_pairs = _tiling(d)
-    if d_work > d:
-        X = jnp.pad(X, ((0, 0), (0, d_work - d)))
-
-    def allreduce(v):
-        return jax.lax.psum(v, axis_name) if axis_name else v
-
-    if standardize:
-        mean, std = _psum_moments(X, w, allreduce)
-    else:
-        mean = jnp.zeros(d_work, jnp.float32)
-        std = jnp.ones(d_work, jnp.float32)
-
-    wsum_f = jnp.maximum(
-        allreduce((fold_masks * w[None, :]).sum(1)), EPS)         # [F]
-
+    allreduce = _psum_over(axis_name)
     c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), n)
-    xs = _blocked(X, y, w, fold_masks, c)
-    hess_blocks, assemble, h_acc0 = _gram_fns(tiled, d_work, F, bt,
-                                              tile_pairs)
+    nb, take = _mlr_blocks(n, c, y, w, fold_masks)
+    x_block = x_row_blocks(X, c)
+    # the label's sums are taken about a constant near its mean and moved
+    # back after the loop: a running float32 sum of thousands of blocks of
+    # a label whose mean is 10 left the fold's label mean 6e-6 off on the
+    # chip, six ulps of the intercept (PERF.md, PR 43)
+    pivot = y[:c].mean()
+    hess_blocks, assemble, h_acc0 = _gram_fns(
+        tiled, d_work, F, bt, tile_pairs, precision=_HIGHEST)
 
-    def body(acc, sl):
-        x_blk, y_blk, w_blk, m_blk = sl                 # m_blk [F, c]
+    def moment(xf, wl):
+        return jnp.matmul(xf.T, wl, precision=_HIGHEST,
+                          preferred_element_type=f32).T
+
+    def body(i, acc):
+        y_blk, fresh, w_blk, m_blk = take(i)            # m_blk [F, c]
         hA, cA, sxA, syA = acc
-        xf = (x_blk.astype(jnp.float32) - mean[None, :]) / std[None, :]
-        wlf = m_blk.T * w_blk[:, None]                  # [c, F]
-        wy = wlf * y_blk[:, None]                       # [c, F]
-        hA = hA + hess_blocks(xf, wlf)
-        cA = cA + jnp.matmul(xf.T, wy,
-                             preferred_element_type=jnp.float32).T
-        sxA = sxA + jnp.matmul(xf.T, wlf,
-                               preferred_element_type=jnp.float32).T
-        syA = syA + wy.sum(0)
-        return (hA, cA, sxA, syA), None
+        xf = (x_block(i).astype(f32) - mean[None, :]) / std[None, :]
+        if d_work > d:
+            xf = jnp.pad(xf, ((0, 0), (0, d_work - d)))
+        wlf = m_blk.T * (w_blk * fresh)[:, None]        # [c, F]
+        wy = wlf * (y_blk - pivot)[:, None]             # [c, F]
+        return (hA + hess_blocks(xf, wlf), cA + moment(xf, wy),
+                sxA + moment(xf, wlf), syA + wy.sum(0))
 
     acc0 = _shard_vary(
-        (h_acc0, jnp.zeros((F, d_work), jnp.float32),
-         jnp.zeros((F, d_work), jnp.float32), jnp.zeros(F, jnp.float32)),
-        axis_name)
-    (hA, cA, sxA, syA), _ = jax.lax.scan(body, acc0, xs)
-    hA, cA, sxA, syA = (allreduce(hA), allreduce(cA),
-                        allreduce(sxA), allreduce(syA))
-    Gm_f = assemble(hA)                                 # [F, d, d]
+        (h_acc0, jnp.zeros((F, d_work), f32), jnp.zeros((F, d_work), f32),
+         jnp.zeros(F, f32)), axis_name)
+    hA, cA, sxA, syA = jax.lax.fori_loop(0, nb, body, acc0)
+    wsum_f = (fold_masks * w[None, :]).sum(1)                     # [F]
+    hA, cA, sxA, syA, wsum_f = allreduce(
+        (hA, cA + pivot * sxA, sxA, syA + pivot * wsum_f, wsum_f))
+    return assemble(hA), cA, sxA, syA, jnp.maximum(wsum_f, EPS)
 
+
+def _gram_solve(Gm_f, cA, sxA, syA, wsum_f, mean, std, regs, alphas,
+                max_iter, tol, *, fit_intercept: bool):
+    """The whole reg x alpha grid off the per-fold moments, no data read:
+    ridge lanes closed form, elastic-net lanes by proximal Newton seeded
+    from the ridge solution (`ops/glm.{ridge_gram_solve,prox_newton_gram}`
+    — the moment-space replay of the per-lane update rule), then back to
+    RAW units (mean 0 / std 1 where the fit was not standardised; `mean`
+    and `std` are the matrix's own width, the moments may be the feature
+    tiles'). Returns (B [F, G, d], b0 [F, G], prox iterations executed,
+    elastic-net lanes the iteration cap stopped over `tol`)."""
+    F, d = Gm_f.shape[0], mean.shape[0]
+    Gn = regs.shape[0]
     # expand per-fold moments to the fold-major lane axis l = f*Gn + g
     l1 = jnp.tile(regs * alphas, F)                     # [L]
     l2 = jnp.tile(regs * (1.0 - alphas), F)             # [L]
@@ -461,33 +498,83 @@ def _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
 
     beta_r, b0_r = G.ridge_gram_solve(Gm, cm, sx, sy, sw, l2,
                                       fit_intercept=fit_intercept)
-    beta_p, b0_p, iters = G.prox_newton_gram(
+    beta_p, b0_p, iters, delta = G.prox_newton_gram(
         Gm, cm, sx, sy, sw, l1, l2, beta_r, b0_r, max_iter, tol,
         fit_intercept=fit_intercept)
     is_l1 = l1 > 0.0
-    B = jnp.where(is_l1[:, None], beta_p, beta_r)
-    b0 = jnp.where(is_l1, b0_p, b0_r)
+    B = jnp.where(is_l1[:, None], beta_p, beta_r)[:, :d] / std[None, :]
+    b0 = jnp.where(is_l1, b0_p, b0_r) - (B * mean[None, :]).sum(1)
+    at_cap = (is_l1 & (delta > tol) & (iters >= max_iter)).sum()
+    return (B.reshape(F, Gn, d), b0.reshape(F, Gn), iters,
+            at_cap.astype(jnp.int32))
 
+
+def _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
+               fit_intercept, standardize,
+               axis_name: Optional[str] = None):
+    """loss="squared" fast path: the column moments when standardize=True
+    (one extra stats pass, two reads of X: `_psum_moments`), ONE streaming
+    pass for the per-FOLD sufficient statistics (`_gram_moments`), then
+    the whole grid solved off them (`_gram_solve`). On a mesh this is one
+    program a sweep; on one device the three are programs of their own
+    (`sweep_glm_squared_gram`), so that a trace tells them apart."""
+    d = X.shape[1]
     if standardize:
-        B = B / std[None, :]
-        b0 = b0 - (B * mean[None, :]).sum(1)
-    B = B[:, :d]
-    return B.reshape(F, Gn, d), b0.reshape(F, Gn), iters
+        mean, std = _psum_moments(X, w, _psum_over(axis_name))
+    else:
+        mean, std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    return _gram_solve(
+        *_gram_moments(X, y, w, fold_masks, mean, std, axis_name=axis_name),
+        mean, std, regs, alphas, max_iter, tol, fit_intercept=fit_intercept)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("fit_intercept", "standardize"))
+@jax.jit
+def sweep_gram_moments(X, y, w, fold_masks, mean, std):
+    """`_gram_moments` on one device: the Gram route's pass over X."""
+    return _gram_moments(X, y, w, fold_masks, mean, std)
+
+
+@functools.partial(jax.jit, static_argnames=("fit_intercept",))
+def sweep_gram_solve(Gm_f, cA, sxA, syA, wsum_f, mean, std, regs, alphas,
+                     max_iter, tol, *, fit_intercept: bool = True):
+    """`_gram_solve` on one device: the Gram route's moment-space solves."""
+    return _gram_solve(Gm_f, cA, sxA, syA, wsum_f, mean, std, regs, alphas,
+                       max_iter, tol, fit_intercept=fit_intercept)
+
+
 def sweep_glm_squared_gram(X: jax.Array, y: jax.Array, w: jax.Array,
                            fold_masks: jax.Array, regs: jax.Array,
                            alphas: jax.Array, max_iter=50, tol=1e-6, *,
                            fit_intercept: bool = True,
                            standardize: bool = True
-                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Squared-loss (fold x grid) sweep from ONE streaming Gram pass.
-    Returns (B [F, G, d] f32 RAW units, b0 [F, G], prox-solve iters)."""
-    return _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol,
-                      fit_intercept=fit_intercept, standardize=standardize,
-                      axis_name=None)
+                           ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                      jax.Array]:
+    """Squared-loss (fold x grid) sweep from ONE streaming Gram pass, as
+    three programs: `glm_standardize_stats` (the rounds' own; skipped when
+    not standardising), `sweep_gram_moments`, `sweep_gram_solve`. Returns
+    (B [F, G, d] f32 RAW units, b0 [F, G], prox-solve iters, elastic-net
+    lanes stopped by `max_iter`), all on the device."""
+    d = X.shape[1]
+    if standardize:
+        mean, std = glm_standardize_stats(X, w)
+    else:
+        mean, std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    return sweep_gram_solve(
+        *sweep_gram_moments(X, y, w, fold_masks, mean, std), mean, std,
+        regs, alphas, max_iter, tol, fit_intercept=bool(fit_intercept))
+
+
+def gram_temp_bytes(X, y, w, fold_masks) -> int:
+    """Bytes of temporaries the compiled one-device Gram pass holds beside
+    its arguments (the compiler's `memory_analysis()`): a padded,
+    transposed or float32 copy of X would show here. Lowered from the
+    arrays' shapes and compiled (a load, where the persistent cache holds
+    the program the sweep ran): ask once, after a warm-up
+    (`glm_round_temp_bytes`)."""
+    d = X.shape[1]
+    col = jax.ShapeDtypeStruct((d,), jnp.float32)
+    return int(sweep_gram_moments.lower(X, y, w, fold_masks, col, col)
+               .compile().memory_analysis().temp_size_in_bytes)
 
 
 @functools.lru_cache(maxsize=None)
@@ -505,7 +592,7 @@ def _sharded_gram_fn(mesh, fit_intercept, standardize):
         core, mesh,
         in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
                   P(None, BATCH_AXIS), P(None), P(None), P(), P()),
-        out_specs=(P(None, None, None), P(None, None), P()))
+        out_specs=(P(None, None, None), P(None, None), P(), P()))
     return jax.jit(sm)
 
 
@@ -514,9 +601,10 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
                                    fit_intercept: bool = True,
                                    standardize: bool = True
                                    ) -> Tuple[jax.Array, jax.Array,
-                                              jax.Array]:
-    """Row-sharded Gram fast path: each shard accumulates its local rows'
-    per-fold moments, one psum combines them, the grid solves replicated.
+                                              jax.Array, jax.Array]:
+    """Row-sharded Gram fast path (`sweep_glm_squared_gram`'s returns):
+    each shard accumulates its local rows' per-fold moments, one psum
+    combines them, the grid solves replicated.
     Rows must be padded to the batch-axis multiple with zero weights (the
     validator's mesh device_put does this).
 
@@ -1229,13 +1317,23 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     return B.reshape(F, Gn, d), b0.reshape(F, Gn), info
 
 
-def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array
-                      ) -> jax.Array:
+def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array,
+                      exact: bool = False) -> jax.Array:
     """[n, Gc] margins for one fold's grid chunk: one MXU contraction
     (bf16 X stays bf16; f32 accumulation). Past TRI_MAX_D columns the
     coefficients go in as two bf16 parts (`_wide_contract`): a margin is then a
     sum over thousands of products, and coefficients rounded to bf16 would
-    move it by more than the fit resolves."""
+    move it by more than the fit resolves. `exact`: the contraction sees
+    the float32 coefficients, as their exact parts of X's dtype
+    (`pallas_glm.coefficient_parts`; a float32 matrix at HIGHEST) — for a
+    metric that is not invariant to their rounding (regression: the
+    held-out pass's `validators._heldout_regression` takes the same)."""
+    if exact:
+        parts = pallas_glm.coefficient_parts(B_f, X.dtype)
+        return pallas_glm.margins(jnp.matmul(
+            X, parts.T, preferred_element_type=jnp.float32,
+            precision=_HIGHEST if X.dtype == jnp.float32 else None),
+            B_f.shape[0], axis=1) + b0_f[None, :]
     if X.shape[1] > TRI_MAX_D:
         return _wide_contract(B_f, X.T).T + b0_f[None, :]
     return jnp.matmul(X, B_f.T.astype(X.dtype),
@@ -1319,6 +1417,25 @@ def _mlr_blocks(n: int, c: int, XT, *rows):
                for a in (XT,) + rows]
         return (cut[0], fresh) + tuple(cut[1:])
     return nb, take
+
+
+def x_row_blocks(X, c: int):
+    """block(i) -> [c, d]: the rows `_mlr_blocks(n, c, ...)`'s take(i)
+    cuts (the same clamped start), read from the resident matrix in place:
+    a slice along the axis the chip keeps major — rows of X where the
+    width is whole 128-column groups (`glm_x_tile`), columns of X.T
+    elsewhere. Compiled for a v5e, the other slice of a 25M x 128 matrix
+    makes the program copy X into the other layout, 6.4 GB."""
+    n, d = X.shape
+    cols_minor = glm_x_tile(d) == "cols_minor"
+    XT = None if cols_minor else X.T
+
+    def block(i):
+        start = jnp.minimum(i * c, n - c)
+        if cols_minor:
+            return jax.lax.dynamic_slice_in_dim(X, start, c, axis=0)
+        return jax.lax.dynamic_slice_in_dim(XT, start, c, axis=1).T
+    return block
 
 
 def mlr_logits_t(xT, Bt_hi, Bt_lo):
