@@ -1,8 +1,9 @@
 """The fused binary GLM pass (ops/pallas_glm.glm_moments) on the CPU in
 interpret mode, held to the XLA body it stands in for
 (ops/glm_sweep._moments_blocks). On the chip the two round the matrix
-unit's operands alike; the CPU's XLA body multiplies in float32, so the pass
-is held twice: to float32 rounding against a twin that writes the chip's
+unit's operands alike (but the residual: its two leading parts in the
+kernel, all of them there); the CPU's XLA body multiplies in float32, so
+the pass is held twice: to float32 rounding against a twin that writes the chip's
 roundings out, and to the operands' rounding against the XLA body itself.
 Then which body the program chooses from what it can observe, one whole
 round through each, and what the telemetry and the round's span say ran.
@@ -57,9 +58,11 @@ def _nan_past(a, n_pad):
     return jnp.pad(a, width, constant_values=jnp.nan)
 
 
-def _fused(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
+def _fused_raw(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
     """The kernel over buffers that run n_pad rows past n, NaN there, in
-    the tile form the width takes (glm_x_tile: X.T, or X as it is)."""
+    the tile form the width takes (glm_x_tile: X.T, or X as it is): its
+    five sums, gA over the rounded residual first and gA_low over what the
+    rounding left last."""
     n = X.shape[0]
     x_tile = GS.glm_x_tile(X.shape[1])
     XT = _nan_past(X.T, n_pad)
@@ -69,6 +72,13 @@ def _fused(X, y, w, masks, sel, Bt, b0, mean, std, loss, n_pad=0):
         PG.dense_rows(_nan_past(w, n_pad), n), _nan_past(masks, n_pad),
         sel, Bt, b0, mean, std, loss=loss, n_rows=n, interpret=True,
         x_tile=x_tile)
+
+
+def _fused(*args, **kw):
+    """The four sums the round steps on, as `_round_core` makes them of the
+    kernel's five: gA + gA_low, hA, g0A, h0A."""
+    gA, hA, g0A, h0A, gA_low = _fused_raw(*args, **kw)
+    return gA + gA_low, hA, g0A, h0A
 
 
 @functools.partial(jax.jit, static_argnames="loss")
@@ -81,9 +91,11 @@ def _blocks(X, y, w, masks, sel, Bt, b0, mean, std, loss):
 
 @functools.partial(jax.jit, static_argnames="loss")
 def _chip_twin(X, y, w, masks, sel, Bt, b0, mean, std, loss):
-    """The pass with the chip's roundings written out: what DEFAULT
-    precision does to the XLA body's float32 operands there (one bfloat16
-    pass, float32 sums), and what the kernel does by its casts."""
+    """The pass with the chip's roundings written out, the kernel's five
+    sums: what the matrix unit's operands are there (the block and the
+    curvature x weight x block one bfloat16 pass, the residual x weight its
+    two leading bfloat16 parts — gA over the first, gA_low over the second
+    — float32 sums), and what the kernel does by its casts."""
     def low(v):
         return v.astype(X.dtype).astype(F32)
     xf = low((X.astype(F32) - mean) / std)
@@ -92,13 +104,24 @@ def _chip_twin(X, y, w, masks, sel, Bt, b0, mean, std, loss):
     wl = (masks.T * w[:, None]) @ sel
     R, S = r0 * wl, s0 * wl
     hA = jnp.einsum("cld,ce->lde", low(S[:, :, None] * xf[:, None, :]), xf)
-    return low(R).T @ xf, hA, R.sum(0), S.sum(0)
+    return (low(R).T @ xf, hA, R.sum(0), S.sum(0),
+            low(R - low(R)).T @ xf)
 
 
-def _rel(a, b):
+def _rel(a, b, of=None):
     a, b = np.asarray(a), np.asarray(b)
     assert a.shape == b.shape and np.isfinite(a).all()
-    return np.abs(a - b).max() / np.abs(b).max()
+    return np.abs(a - b).max() / np.abs(b if of is None else of).max()
+
+
+def _off_twin(raw, twin):
+    """The kernel's five sums against the twin's, each of its own largest
+    entry; gA_low of gA's (a last digit of a margin that sends R to the
+    other side of a bfloat16 tie moves a whole step of R from one part to
+    the other: only on the gradient's scale is the second part's sum a
+    float32 sum in another order)."""
+    return max([_rel(a, b) for a, b in zip(raw[:4], twin[:4])]
+               + [_rel(raw[4], twin[4], of=twin[0])])
 
 
 @pytest.fixture
@@ -138,13 +161,15 @@ CASES = {
 
 @pytest.mark.parametrize("case", CASES.values(), ids=CASES.keys())
 def test_fused_pass_equals_the_xla_body(case, small_tiles):
-    """(gA, hA, g0A, h0A) of the kernel: float32 sums in another order
-    against the twin (1e-4 of the largest: a margin summed in another order
-    can send one residual of the ~1e3 to the other side of a bfloat16 tie),
-    the operands' rounding against the CPU's XLA body (2^-9 a term: 4e-3 of
-    the largest; the intercept's sums are unrounded in both: 1e-6), inert
-    lanes at zero, and a second run bit for bit (one sequential grid axis:
-    every sum has a fixed order)."""
+    """The kernel's five sums: float32 sums in another order against the
+    twin (1e-4 of the largest: a margin summed in another order can send
+    one curvature-weighted entry of the ~1e5 to the other side of a
+    bfloat16 tie); the four the round steps on (gA + gA_low, hA, g0A, h0A)
+    against the CPU's XLA body by the operands' rounding (the Gram's 2^-9 a
+    term: 4e-3 of the largest; the gradient takes the residual's two parts
+    here and all three there, 2^-17 a term: 4e-6; the intercept's sums are
+    unrounded in both: 1e-6), inert lanes at zero, and a second run bit for
+    bit (one sequential grid axis: every sum has a fixed order)."""
     n, d, Lb, live, loss, n_pad = case
     args = _problem(n, d, Lb, live, seed=n + d + Lb,
                     wide_scales=d == 128) + (loss,)
@@ -152,15 +177,15 @@ def test_fused_pass_equals_the_xla_body(case, small_tiles):
     if d == 128:    # standardize on, std spanning 0.03 to 16
         std = np.asarray(args[8])
         assert std.min() < 0.04 and std.max() > 15
+    raw = _fused_raw(*args, n_pad=n_pad)
+    assert _off_twin(raw, _chip_twin(*args)) <= 1e-4
     got = _fused(*args, n_pad=n_pad)
-    for a, b in zip(got, _chip_twin(*args)):
-        assert _rel(a, b) <= 1e-4
-    for a, b, tol in zip(got, _blocks(*args), (4e-3, 4e-3, 1e-6, 1e-6)):
+    for a, b, tol in zip(got, _blocks(*args), (4e-6, 4e-3, 1e-6, 1e-6)):
         assert _rel(a, b) <= tol
-    assert all((np.asarray(v)[live:] == 0).all() for v in got)
-    again = _fused(*args, n_pad=n_pad)
+    assert all((np.asarray(v)[live:] == 0).all() for v in raw)
+    again = _fused_raw(*args, n_pad=n_pad)
     assert all(np.array_equal(np.asarray(a), np.asarray(b))
-               for a, b in zip(got, again))
+               for a, b in zip(raw, again))
 
 
 # -- the coefficients' precision ----------------------------------------------
@@ -192,10 +217,10 @@ def test_margins_see_float32_coefficients_in_both_bodies(case, small_tiles):
     B = np.asarray(args[5])
     assert (B[:live] != np.asarray(args[5].astype(BF16).astype(F32))[:live]) \
         .mean() > 0.9
+    assert _off_twin(_fused_raw(*args, n_pad=n_pad),
+                     _chip_twin(*args)) <= 1e-4
     got = _fused(*args, n_pad=n_pad)
-    for a, b in zip(got, _chip_twin(*args)):
-        assert _rel(a, b) <= 1e-4
-    for a, b, tol in zip(got, _blocks(*args), (4e-3, 4e-3, 2e-6, 2e-6)):
+    for a, b, tol in zip(got, _blocks(*args), (4e-6, 4e-3, 2e-6, 2e-6)):
         assert _rel(a, b) <= tol
     rounded = _fused(*args[:5], args[5].astype(BF16), *args[6:],
                      n_pad=n_pad)
@@ -208,24 +233,42 @@ def test_margins_see_float32_coefficients_in_both_bodies(case, small_tiles):
 def test_exact_coefficients_give_the_one_part_sums_to_the_bit(
         case, small_tiles, monkeypatch):
     """Coefficients that ARE exact in the matrix's dtype leave every part
-    after the first zero, and the four sums are, bit for bit, those of the
-    program with one part — which is the kernel as it was before the
-    margins took parts (bt = Bt.astype(X.dtype), one slab)."""
+    after the first zero, and the five sums are, bit for bit, those of the
+    program whose margins take ONE part (bt = Bt.astype(X.dtype), one slab)
+    — the residual's two parts kept, which have no exact case. With the
+    residual cut to one part as well, which is the kernel as it was before
+    either operand took parts, hA, g0A and h0A are still those to the bit
+    and gA to the order of a float32 sum: the second part's sum comes out
+    beside it, as gA_low, which is then zero, and otherwise no larger than
+    the residual's own rounding."""
     n, d, Lb, live, loss, n_pad = case
     args = _problem(n, d, Lb, live, seed=n + d + Lb,
                     wide_scales=d == 128) + (loss,)
     assert PG.n_parts(BF16) == 3 and PG.n_parts(F32) == 1
-    parts = np.asarray(PG.coefficient_parts(args[5].astype(F32), BF16)
+    assert PG.residual_parts(BF16) == 2 and PG.residual_parts(F32) == 1
+    parts = np.asarray(PG.float32_parts(args[5].astype(F32), BF16)
                        .astype(F32))
     assert parts.shape == (3 * Lb, d) and (parts[Lb:] == 0).all()
-    got = _fused(*args, n_pad=n_pad)
-    got_f32 = _fused(*args[:5], args[5].astype(F32), *args[6:], n_pad=n_pad)
+    got = _fused_raw(*args, n_pad=n_pad)
+    got_f32 = _fused_raw(*args[:5], args[5].astype(F32), *args[6:],
+                         n_pad=n_pad)
     monkeypatch.setattr(PG, "n_parts", lambda dtype: 1)
+    monkeypatch.setattr(PG, "residual_parts", lambda dtype: 2)
     PG.glm_moments.clear_cache()
-    one_part = _fused(*args, n_pad=n_pad)
+    one_part = _fused_raw(*args, n_pad=n_pad)
     for a, b, c in zip(got, got_f32, one_part):
         assert np.array_equal(np.asarray(a), np.asarray(c))
         assert np.array_equal(np.asarray(b), np.asarray(c))
+    monkeypatch.setattr(PG, "residual_parts", lambda dtype: 1)
+    PG.glm_moments.clear_cache()
+    before = _fused_raw(*args, n_pad=n_pad)
+    for a, b in zip(got[1:4], before[1:4]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # (the CPU's matmul orders a sum by its operand's width; the chip's
+    # matrix unit does not, and read gA the parent's to the bit)
+    assert _rel(got[0], before[0]) <= 1e-6
+    assert not np.asarray(before[4]).any()
+    assert 0 < np.abs(got[4]).max() <= 2.0 ** -8 * np.abs(got[0]).max()
 
 
 def test_coefficient_parts_sum_to_the_float32_coefficients():
@@ -234,17 +277,127 @@ def test_coefficient_parts_sum_to_the_float32_coefficients():
     rng = np.random.default_rng(0)
     B = (rng.normal(size=(8, 64)) * 10.0 ** rng.integers(-6, 3, (8, 64))) \
         .astype(np.float32)
-    parts = np.asarray(PG.coefficient_parts(jnp.asarray(B), BF16)
+    parts = np.asarray(PG.float32_parts(jnp.asarray(B), BF16)
                        .astype(F32)).reshape(3, 8, 64)
     assert np.array_equal(parts[2] + parts[1] + parts[0], B)
     assert np.array_equal(parts[0], np.asarray(
         jnp.asarray(B).astype(BF16).astype(F32)))
     assert (np.abs(parts[1]) <= np.abs(B) * 2.0 ** -8).all()
-    assert np.array_equal(np.asarray(PG.coefficient_parts(
+    assert np.array_equal(np.asarray(PG.float32_parts(
         jnp.asarray(B), F32)), B)
     stacked = jnp.asarray(parts.reshape(24, 64))
-    assert np.array_equal(np.asarray(PG.margins(stacked, 8)), B)
-    assert np.array_equal(np.asarray(PG.margins(stacked.T, 8, axis=1)), B.T)
+    assert np.array_equal(np.asarray(PG.slab_sum(stacked, 8)), B)
+    assert np.array_equal(np.asarray(PG.slab_sum(stacked.T, 8, axis=1)), B.T)
+
+
+@pytest.mark.parametrize("in_kernel", [False, True],
+                         ids=["reduce-precision", "in-kernel-casts"])
+def test_two_parts_hold_the_residual_to_2_to_the_minus_17(in_kernel):
+    """The residual x weight as the gradient's contraction takes it: the
+    SAME routine cut to two parts, inside one jitted program as both bodies
+    run it (a round trip fused away would leave the low part zero: PERF.md,
+    PR 29) and by the casts a Mosaic body is left with. The parts sum to R
+    within 2^-17 |R| where the first alone leaves up to 2^-9, the low part
+    is not zero, and it is the first two of the exact three."""
+    rng = np.random.default_rng(1)
+    R = (rng.uniform(-1, 1, size=(16, 256))
+         * rng.uniform(0.5, 2.0, size=(1, 256))).astype(np.float32)
+    assert PG.residual_parts(BF16) == 2 and PG.residual_parts(F32) == 1
+    split = jax.jit(lambda v: PG.float32_parts(
+        v, BF16, PG.residual_parts(BF16), in_kernel=in_kernel).astype(F32))
+    hi, lo = np.asarray(split(jnp.asarray(R))).reshape(2, 16, 256)
+    assert np.array_equal(hi, np.asarray(
+        jnp.asarray(R).astype(BF16).astype(F32)))
+    assert (lo != 0).mean() > 0.95
+    assert (np.abs(lo + hi - R) <= np.abs(R) * 2.0 ** -17).all()
+    assert (np.abs(hi - R) > np.abs(R) * 2.0 ** -11).mean() > 0.5
+    three = np.asarray(PG.float32_parts(jnp.asarray(R), BF16).astype(F32))
+    assert np.array_equal(three[:32], np.concatenate([hi, lo]))
+    assert np.array_equal(np.asarray(PG.float32_parts(
+        jnp.asarray(R), F32, PG.residual_parts(F32))), R)
+
+
+# -- the residual's precision -------------------------------------------------
+
+def _shared_rows(n, d, Lb, live, seed):
+    """One pass's inputs in which the residual's roundings cannot average
+    out: eight distinct rows, each repeated over an eighth of the table
+    with one label and unit weight, so that a lane's R takes a handful of
+    values and every row of a group rounds the same way (what a null
+    indicator's column does to a thousandth of a real table's rows). No
+    standardisation (mean 0, std 1: the block is X itself)."""
+    rng = np.random.default_rng(seed)
+    group = rng.integers(0, 8, size=n)
+    X = jnp.asarray(rng.normal(size=(8, d)).astype(np.float32)[group]) \
+        .astype(BF16)
+    y = (rng.uniform(size=8) < 0.5).astype(np.float32)[group]
+    fold = rng.integers(0, FOLDS, size=n)
+    masks = (fold[None, :] != np.arange(FOLDS)[:, None]).astype(np.float32)
+    sel = np.zeros((FOLDS, Lb), np.float32)
+    sel[np.arange(live) % FOLDS, np.arange(live)] = 1.0
+    B = (rng.normal(size=(Lb, d)) * 0.2).astype(np.float32)
+    B[live:] = 0.0
+    b0 = rng.normal(size=Lb).astype(np.float32) * (np.arange(Lb) < live)
+    return (X, jnp.asarray(y), jnp.ones(n, F32), jnp.asarray(masks),
+            jnp.asarray(sel), jnp.asarray(B), jnp.asarray(b0),
+            jnp.zeros(d, F32), jnp.ones(d, F32))
+
+
+def _gradient_f64(X, y, w, masks, sel, B, b0):
+    """gA of the logistic pass in float64, nothing rounded but the block."""
+    X, y, w, masks, sel, B, b0 = (np.asarray(v.astype(F32), np.float64)
+                                  for v in (X, y, w, masks, sel, B, b0))
+    p = 1 / (1 + np.exp(-(X @ B.T + b0)))
+    return ((p - y[:, None]) * ((masks.T * w[:, None]) @ sel)).T @ X
+
+
+@pytest.mark.parametrize("body", ["kernel", "xla"])
+@pytest.mark.parametrize("case", PRECISION_CASES.values(),
+                         ids=PRECISION_CASES.keys())
+def test_gradient_sees_the_float32_residual_in_both_bodies(
+        case, body, small_tiles, monkeypatch):
+    """sum_rows R xs' where rows share their residual: the gradient the
+    round steps on is the float64 sum to 2^-15 of its largest entry through
+    the kernel's two tile forms (gA + gA_low: R's two leading parts) and
+    through the XLA body (all three: 2^-20); with ONE part (the program as
+    it was: `R.astype(dtype)`, and on the chip the XLA body's float32
+    product at default precision) it is 2^-11 or more away, the rounding of
+    a shared residual added up over its rows. The kernel's gA alone IS that
+    one-part sum (to the order of a float32 sum on the CPU), and hA, g0A,
+    h0A are what that program returned, to the bit."""
+    n, d, Lb, live, loss, n_pad = case
+    args = _shared_rows(n, d, Lb, live, seed=n + d)
+    want = _gradient_f64(*args[:7])
+    split = PG.float32_parts
+
+    def one_part_of_R(V, dtype, parts=None, **kw):
+        """The XLA body's R arrives [lanes, rows of a block] with every
+        part asked for; B [lanes, d] keeps its own."""
+        whole = parts is None and V.shape[1] != d
+        return split(V, dtype, 1 if whole else parts, **kw)
+    assert min(GS._row_block(d), n) != d
+
+    def run():
+        try:    # neither program may outlive the parts it was traced with
+            if body == "kernel":
+                return _fused_raw(*args, loss, n_pad=n_pad)
+            return _blocks(*args, loss) + (0.0,)
+        finally:
+            PG.glm_moments.clear_cache()
+            _blocks.clear_cache()
+    got = run()
+    with monkeypatch.context() as m:
+        m.setattr(PG, "float32_parts", one_part_of_R)
+        m.setattr(PG, "residual_parts", lambda dtype: 1)
+        one_part = run()
+    assert _rel(got[0] + got[4], want) <= 2.0 ** (
+        -15 if body == "kernel" else -20)
+    assert _rel(one_part[0], want) >= 2.0 ** -11
+    assert not np.asarray(one_part[4]).any()
+    for a, b in zip(got[1:4], one_part[1:4]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if body == "kernel":
+        assert _rel(got[0], one_part[0]) <= 1e-6
 
 
 @pytest.mark.parametrize("mosaic,no_pallas,d,dtype,lanes,vmem,says", [
@@ -475,3 +628,143 @@ def test_the_rounds_on_bfloat16_are_the_rounds_on_the_float32_copy(
     assert np.abs(st_f["B"]).max() > 0.5
     np.testing.assert_allclose(st["B"], st_f["B"], rtol=0, atol=2e-6)
     np.testing.assert_allclose(st["b0"], st_f["b0"], rtol=0, atol=2e-6)
+
+
+def test_the_benchmarks_twin_holds_the_sums_at_128_columns():
+    """The benchmark's float64 twin of the kernel
+    (benchmark/reference_nulls.moments_twin, written from the module's
+    docstring: R rounded to ONE part of the matrix's dtype) on the inputs of
+    its own Tier-1 test (tests/benchmark/test_benchmark_nulls.py): gA, hA,
+    g0A, h0A within that test's limits still, since the second part's sum
+    comes out BESIDE gA and not in it — and gA_low, which that twin does
+    not know, against the twin's own lines carried one part further: 1e-4
+    of gA's largest entry, the sum of the two within 2^-15 of the float64
+    gradient with R unrounded, where gA alone is 2e-4 or more away."""
+    from benchmark import reference_nulls as RN
+    rng = np.random.default_rng(5)
+    n, d, Lb, live, F = 640, 128, 8, 6, 3
+    scale = 2.0 ** ((np.arange(d) * 3) % 10 - 5)
+    X = jnp.asarray((rng.normal(size=(n, d)) * scale + 0.3 * scale)
+                    .astype(np.float32)).astype(BF16)
+    Xh = np.asarray(X.astype(F32))
+    y = (rng.uniform(size=n) < 0.4).astype(np.float32)
+    w = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+    fold = rng.integers(0, F, size=n)
+    masks = (fold[None, :] != np.arange(F)[:, None]).astype(np.float32)
+    sel = np.zeros((F, Lb), np.float32)
+    sel[rng.integers(0, F, size=live), np.arange(live)] = 1.0
+    B = (rng.normal(size=(Lb, d)) * 0.1).astype(np.float32)
+    B[live:] = 0.0
+    Bt = jnp.asarray(B).astype(BF16)
+    b0 = rng.normal(size=Lb).astype(np.float32)
+    mean, std = Xh.mean(0), Xh.std(0)
+    got = [np.asarray(v, np.float64) for v in PG.glm_moments(
+        X, PG.dense_rows(jnp.asarray(y)), PG.dense_rows(jnp.asarray(w)),
+        jnp.asarray(masks), jnp.asarray(sel), Bt, jnp.asarray(b0),
+        jnp.asarray(mean), jnp.asarray(std), loss="logistic",
+        x_tile="cols_minor", interpret=True)]
+    assert len(got) == 5
+    Bh = np.asarray(Bt.astype(F32))
+    ref = RN.moments_twin(Xh, y, w, masks, sel, Bh, b0, mean, std)
+
+    def off(a, r, of=None):
+        assert a.shape == r.shape
+        return np.abs(a - r).max() / np.abs(r if of is None else of).max()
+    for a, r, tol in zip(got, ref, (1e-4, 1e-4, 1e-6, 1e-6)):
+        assert off(a, r) <= tol
+    # the twin's own lines, one part further
+    xs = RN.as_bf16((Xh - mean) / std).astype(np.float64)
+    p = 1.0 / (1.0 + np.exp(-(xs @ Bh.astype(np.float64).T + b0)))
+    R = (p - y[:, None]) * ((masks.T * w[:, None]) @ sel)
+    low = RN.as_bf16(R - RN.as_bf16(R).astype(np.float64)).astype(np.float64)
+    assert off(got[4], low.T @ xs, of=ref[0]) <= 1e-4
+    assert off(got[0] + got[4], R.T @ xs) <= 2.0 ** -15
+    assert off(got[0], R.T @ xs) >= 2e-4
+    assert all((v[live:] == 0).all() for v in got)
+
+
+# -- the residual's floor -------------------------------------------------------
+
+def _l1_sweep(X, y, masks, regs, alphas):
+    """The standardised sweep over a grid of (reg, elastic-net) points;
+    per-lane iterations and deltas in the state."""
+    regs, alphas = np.float32(regs), np.float32(alphas)
+    st = GS._new_round_state(FOLDS * len(regs), X.shape[1])
+    _, _, info = GS.sweep_glm_streamed_rounds(
+        X, y, jnp.ones(X.shape[0], F32), masks, regs, alphas,
+        loss="logistic", max_iter=MAX_ITER, tol=TOL, standardize=True,
+        state=st)
+    return info, st
+
+
+def _strong_l1_sweep(X, y, masks):
+    """A grid that holds the default grid's strong-L1 point (reg 0.1 x
+    elastic-net 0.5: the lane that cycled on the residual's rounding
+    floor), a slow ridge-like point and a fast one."""
+    return _l1_sweep(X, y, masks, [0.01, 0.1, 0.1], [0.1, 0.1, 0.5])
+
+
+def test_the_xla_body_takes_the_whole_residual(monkeypatch):
+    """The XLA body contracts R in ALL its parts, which is the float32
+    product it had on the CPU and more than the chip's default precision
+    gave it. Lanes that the penalty shrank to one or two coefficients
+    share a handful of residuals over all their rows, and there even two
+    parts floor delta over tol (2^-17 |R| does not average out): on 32 768
+    rows of a null-tracked table every lane of such a grid retires in 12
+    passes, and with R cut to two parts two lanes run to max_iter."""
+    X, y, masks = _null_tracked(n=32768, seed=1)
+    grid = [0.2, 0.2, 0.3], [0.5, 0.3, 0.5]
+    GS.sweep_glm_round.clear_cache()
+    info, st = _l1_sweep(X, y, masks, *grid)
+    assert info["round_kernel"] == "xla_blocks"
+    assert info["lanes_at_cap"] == 0 and info["data_passes"] <= 14
+    assert (st["delta"] <= TOL).all()
+    assert ((np.asarray(st["B"]) != 0).sum(1) <= 4).all()
+    split, d = PG.float32_parts, X.shape[1]
+
+    def two_parts_of_R(V, dtype, parts=None, **kw):
+        """R arrives [lanes, rows of a block] with every part asked for."""
+        whole = parts is None and V.shape[1] != d
+        return split(V, dtype, 2 if whole else parts, **kw)
+    monkeypatch.setattr(PG, "float32_parts", two_parts_of_R)
+    GS.sweep_glm_round.clear_cache()
+    try:
+        info_2, st_2 = _l1_sweep(X, y, masks, *grid)
+    finally:
+        GS.sweep_glm_round.clear_cache()
+    assert info_2["lanes_at_cap"] >= 1 and info_2["data_passes"] == 55
+    assert st_2["delta"].max() > TOL
+
+
+def test_the_kernels_lanes_retire_where_the_xla_bodys_do(backend,
+                                                         monkeypatch):
+    """The floor itself, through the interpreted kernel: on 32 768 rows of
+    a null-tracked table the kernel that casts the residual x weight to ONE
+    bfloat16 part (the program as it was) leaves deltas of ~2e-5 and most
+    lanes at max_iter, 55 passes; with the two parts every lane retires at
+    tol, the strong-L1 point with the others, and round for round and lane
+    for lane where the XLA body's lanes do (the coefficients apart by what
+    the Gram's own bfloat16 operand moves a thresholded fixed point)."""
+    X, y, masks = _null_tracked(n=32768, seed=5)
+    backend(True)
+    info, st = _strong_l1_sweep(X, y, masks)
+    assert info["round_kernel"] == "pallas_fused"
+    backend(False)
+    info_x, st_x = _strong_l1_sweep(X, y, masks)
+    assert info_x["round_kernel"] == "xla_blocks"
+    for got in (info, info_x):
+        assert got["lanes_at_cap"] == 0
+        assert got["lanes_retired"] == got["lanes_total"] == 9
+    for key in ("iters_per_round", "data_passes", "bucket_sizes",
+                "active_per_round", "padded_lane_passes"):
+        assert info[key] == info_x[key]
+    assert info["data_passes"] < 30
+    assert np.array_equal(st["iters"], st_x["iters"])
+    assert (st["delta"] <= TOL).all() and (st_x["delta"] <= TOL).all()
+    np.testing.assert_allclose(st["B"], st_x["B"], rtol=0, atol=1e-4)
+    monkeypatch.setattr(PG, "residual_parts", lambda dtype: 1)
+    backend(True)
+    PG.glm_moments.func.clear_cache()
+    info_1, st_1 = _strong_l1_sweep(X, y, masks)
+    assert info_1["lanes_at_cap"] >= 6 and info_1["data_passes"] == 55
+    assert 5e-6 < st_1["delta"].max() < 1e-3

@@ -30,9 +30,13 @@ every thread refitting against the same cached DataFrame):
   row blocks described next (`_moments_blocks`), the same sums;
 - lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]), B the
   float32 coefficients the iteration carries as their exact parts of the
-  matrix's dtype (`pallas_glm.coefficient_parts`: [d, 3 L] against a
+  matrix's dtype (`pallas_glm.float32_parts`: [d, 3 L] against a
   bfloat16 block): a step taken at coefficients rounded to that dtype
-  never settles under `tol`, and every lane ran to `max_iter`;
+  never settles under `tol`, and every lane ran to `max_iter`; the
+  gradient's sum takes the residual x weight as parts of that dtype by
+  the same routine, all of them here and the two leading ones in the
+  fused pass (rounded to one, it floors delta at `tol` on tens of
+  millions of rows);
 - every lane's weighted Gram from ONE batched einsum 'cl,cd,ce->lde'
   with S [c, L] the per-lane curvature weights (narrow path, d <= 128).
   A compressed upper-triangle form (xf[:, iu0] * xf[:, iu1] then an
@@ -656,17 +660,27 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
     xs', R and S (pallas_glm.glm_moments is the same sums in one program).
     B [Lb, d] is the coefficients, float32 as the iteration carries them:
     the margins see them unrounded, as the fused pass's do and by the same
-    split (`pallas_glm.coefficient_parts`: the three-part product against a
+    split (`pallas_glm.float32_parts`: the three-part product against a
     bfloat16 block, which keeps the matrix unit's bfloat16 path; a float32
     block has one part and contracts as it did) — a step taken at rounded
-    coefficients never settles under tol. mean / std are column-padded to
-    the Gram geometry's width; `acc0` are sums to go on from (the tileplane
-    steps' carry) in place of zeros."""
+    coefficients never settles under tol. The gradient's sum takes the
+    float32 residual x weight R by the same routine, ALL its parts of the
+    block's dtype in one contraction against the block, the slabs added:
+    the float32 product to the last bit on every backend (a float32 block:
+    R itself, as it was). Left as the float32 product `xf.T @ R`, the
+    chip's default precision cut R to ONE bfloat16 part inside the matrix
+    unit, and that rounding floored a lane's delta at tol (PERF.md, PR 44);
+    the fused pass takes the two leading parts (2^-17 |R|: what its budget
+    of vector work allows) and hands the second part's sum back beside the
+    first, which this body has no reason to. mean / std are column-padded
+    to the Gram geometry's width; `acc0` are sums to go on from (the
+    tileplane steps' carry) in place of zeros."""
     rc = _residual_curvature(loss)
     d_work, Lb = mean.shape[0], sel.shape[1]
     tiled, _, bt, tile_pairs = _tiling(d_work)
     hess_blocks, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
-    Bparts = pallas_glm.coefficient_parts(B, blocks[0].dtype).T
+    dtype = blocks[0].dtype
+    Bparts = pallas_glm.float32_parts(B, dtype).T
 
     def body(acc, sl):
         x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
@@ -676,7 +690,7 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
         # materialized-Xs route
         xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
                   / std[None, :]).astype(x_blk.dtype)
-        eta = pallas_glm.margins(
+        eta = pallas_glm.slab_sum(
             jnp.matmul(xs_low, Bparts, preferred_element_type=jnp.float32),
             Lb, axis=1) + b0[None, :]
         r0, s0 = rc(eta, y_blk[:, None])            # [c, Lb]
@@ -686,8 +700,9 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
         R = r0 * wl
         S = s0 * wl
         xf = xs_low.astype(jnp.float32)
-        gA = gA + jnp.matmul(xf.T, R,
-                             preferred_element_type=jnp.float32).T
+        gA = gA + pallas_glm.slab_sum(jnp.matmul(
+            xs_low.T, pallas_glm.float32_parts(R.T, dtype).T,
+            preferred_element_type=jnp.float32), Lb, axis=1).T
         hA = hA + hess_blocks(xf, S)
         return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
 
@@ -719,8 +734,14 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
     the coefficients the step updates, not their cast to the matrix's dtype
     (either body splits B into exact parts of that dtype), so the step is
     taken where the gradient was read, delta falls through tol, and a lane
-    retires there and not at max_iter; the other operands of the matrix
-    unit (the block, R, S x block) stay in the matrix's dtype.
+    retires there and not at max_iter. The residual x weight R of the
+    gradient's sum goes in at float32 precision too, as parts of that dtype
+    (the fused pass its two leading ones, `pallas_glm.residual_parts`, the
+    second's sum returned as `gA_low` and added here; the XLA body all of
+    them): cut to one, its rounding floored delta AT tol on 20M rows of a
+    null-tracked table and held a few lanes to max_iter (PERF.md, PR 44).
+    The other operands of the matrix unit (the block, S x block) stay in
+    the matrix's dtype.
     The while cond early-exits as soon as EVERY bucket lane's delta clears
     tol, so a round never burns budget on an already-converged bucket.
     Returns (B [Lb, d] standardized space, b0 [Lb], delta [Lb], iters)."""
@@ -755,10 +776,11 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
 
     def accumulate(B, b0):
         if fused:
-            moments = pallas_glm.glm_moments(
+            gA, hA, g0A, h0A, gA_low = pallas_glm.glm_moments(
                 X if x_tile == "cols_minor" else X.T, y_rows, w_rows,
                 fold_masks, sel, B, b0, mean, std, loss=loss,
                 x_tile=x_tile)
+            moments = gA + gA_low, hA, g0A, h0A
         else:
             moments = _moments_blocks(blocks, sel, B, b0, mean, std,
                                       loss=loss, axis_name=axis_name)
@@ -1325,12 +1347,12 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array,
     sum over thousands of products, and coefficients rounded to bf16 would
     move it by more than the fit resolves. `exact`: the contraction sees
     the float32 coefficients, as their exact parts of X's dtype
-    (`pallas_glm.coefficient_parts`; a float32 matrix at HIGHEST) — for a
+    (`pallas_glm.float32_parts`; a float32 matrix at HIGHEST) — for a
     metric that is not invariant to their rounding (regression: the
     held-out pass's `validators._heldout_regression` takes the same)."""
     if exact:
-        parts = pallas_glm.coefficient_parts(B_f, X.dtype)
-        return pallas_glm.margins(jnp.matmul(
+        parts = pallas_glm.float32_parts(B_f, X.dtype)
+        return pallas_glm.slab_sum(jnp.matmul(
             X, parts.T, preferred_element_type=jnp.float32,
             precision=_HIGHEST if X.dtype == jnp.float32 else None),
             B_f.shape[0], axis=1) + b0_f[None, :]

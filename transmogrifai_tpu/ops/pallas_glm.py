@@ -17,17 +17,30 @@ of the array; here the weighted copies are the stationary operand and fill
 it. The grid is one sequential axis, so the order of every sum is fixed and
 a job repeats bit for bit.
 
-Precision: the matrix unit's operands in the matrix's dtype (the
-standardised block, the residual x weight and the curvature x weight x
-block), float32 sums; the intercept's two sums take residual and curvature
-unrounded. The coefficients are the one operand that is NOT rounded: the
-margins xs' B + b0 see the float32 B the iteration carries, as its exact
-split into parts of the matrix's dtype (`coefficient_parts`: three for
-bfloat16, one left operand of the margins' contraction, the slabs added in
-float32). A Newton step taken at rounded coefficients has no fixed point —
-near the optimum it moves B by B's own rounding error, 2^-9 |B| a step, and
-no lane's delta ever clears a tolerance of 1e-6 (PERF.md, PR 38) — and the
-XLA body (`glm_sweep._moments_blocks`) splits B the same way.
+Precision: the matrix unit's operands in the matrix's dtype, float32 sums;
+the intercept's two sums take residual and curvature unrounded. The
+standardised block and the curvature x weight x block are rounded to that
+dtype once. The two operands the iteration's fixed point hangs on are NOT:
+both go in as parts of the matrix's dtype (`float32_parts`), one above the
+other as ONE operand of the contraction that was there, the product's slabs
+added in float32 (`slab_sum`). The coefficients: the margins xs' B + b0 see
+the float32 B the iteration carries, as its exact split (three parts of
+bfloat16), the slabs added chunk by chunk. A Newton step taken at rounded
+coefficients has no fixed point — near the optimum it moves B by B's own
+rounding error, 2^-9 |B| a step, and no lane's delta ever clears a tolerance
+of 1e-6 (PERF.md, PR 38). The residual x weight R of the gradient sum_rows
+R xs': its two leading parts (`residual_parts`: 16 significant bits of
+bfloat16, a rounding of 2^-17 |R| where one part leaves 2^-9), each part's
+slab summed over the rows on its own. The first slab is gA, the sum over R
+rounded to the dtype, as the pass has always returned it; the second comes
+out beside it as gA_low, and the round adds the two once a pass. R's
+roundings do not average out over a column that a thousandth of the rows
+share (a standardised null indicator reads 31 there): at 20M rows they
+floored a lane's delta AT 1e-6, and a point on the soft threshold's kink
+cycled on that floor up to `max_iter` (PERF.md, PR 44). A float32 matrix
+has one part of each, the operand itself. The XLA body
+(`glm_sweep._moments_blocks`) splits B the same way and R into ALL its
+parts: it returns the one gradient, exact on every backend.
 
 Kept apart from ops/pallas_hist.py, ops/pallas_softmax.py and
 ops/pallas_wide.py on purpose: a Mosaic body carries its source locations,
@@ -87,36 +100,53 @@ def n_parts(dtype) -> int:
     return -(-24 // (jnp.finfo(dtype).nmant + 1))
 
 
-def coefficient_parts(B, dtype):
-    """[parts x lanes, d] in `dtype`: the float32 coefficients B [lanes, d]
-    as parts whose float32 sum is B exactly, the largest first and one
-    above the other — as many as 24 significant bits take of `dtype`'s
-    (three of bfloat16, one of float32: B itself). A product of a part with
-    a `dtype` block is exact in float32, so the contraction of the stack
-    against a block, its slabs added (`margins`), is xs' B to float32.
-    Coefficients that are exact in `dtype` leave every part after the first
-    zero. Each part is cut by `reduce_precision`: inside a fusion the chip
-    may hand a float32 -> bfloat16 -> float32 round trip back unrounded, and
-    the next part would be zero (PERF.md, PR 29)."""
-    info, rest, parts = jnp.finfo(dtype), B.astype(jnp.float32), []
-    for _ in range(n_parts(dtype)):
-        part = jax.lax.reduce_precision(rest, exponent_bits=info.nexp,
-                                        mantissa_bits=info.nmant)
-        parts.append(part.astype(dtype))
+def _pack(dtype) -> int:
+    """Rows of `dtype` in a sublane tile: a cast fills whole ones."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def residual_parts(dtype) -> int:
+    """Parts of `dtype` the gradient's contraction takes of the residual x
+    weight: the two leading ones where `dtype` needs more than one (16
+    significant bits of bfloat16), the operand itself where it does not."""
+    return min(2, n_parts(dtype))
+
+
+def float32_parts(V, dtype, parts=None, *, in_kernel: bool = False):
+    """[parts x rows, cols] in `dtype`: the float32 operand V [rows, cols]
+    (the coefficients B [lanes, d]; the residual x weight R [lanes, chunk])
+    as its leading `parts` parts of `dtype`, the largest first and one above
+    the other; the default is as many as 24 significant bits take of
+    `dtype`'s (three of bfloat16, one of float32: V itself), and their
+    float32 sum is then V exactly; k parts of bfloat16 leave 2^-(8k + 1) |V|.
+    A product of a part with a `dtype` block is exact in float32, so the
+    contraction of the stack against a block, its slabs added (`slab_sum`),
+    is the float32 contraction to that much. A V that is exact in `dtype`
+    leaves every part after the first zero. Each part is cut by
+    `reduce_precision`: inside a fusion the chip may hand a float32 ->
+    bfloat16 -> float32 round trip back unrounded, and the next part would
+    be zero (PERF.md, PR 29). `in_kernel`: inside a Mosaic body, which has
+    no `reduce_precision` and fuses nothing away, the cast is the cut."""
+    info, rest, out = jnp.finfo(dtype), V.astype(jnp.float32), []
+    for _ in range(n_parts(dtype) if parts is None else parts):
+        part = rest.astype(dtype).astype(jnp.float32) if in_kernel else \
+            jax.lax.reduce_precision(rest, exponent_bits=info.nexp,
+                                     mantissa_bits=info.nmant)
+        out.append(part.astype(dtype))
         rest = rest - part
-    return jnp.concatenate(parts, axis=0)
+    return jnp.concatenate(out, axis=0)
 
 
-def margins(stacked, lanes: int, axis: int = 0):
+def slab_sum(stacked, lanes: int, axis: int = 0):
     """The float32 sum of the slabs of `lanes` that a contraction of
-    `coefficient_parts`' stack leaves along `axis`, the smallest part's
+    `float32_parts`' stack leaves along `axis`, the smallest part's
     first."""
     slabs = [jax.lax.slice_in_dim(stacked, k, k + lanes, axis=axis)
              for k in range(0, stacked.shape[axis], lanes)]
-    eta = slabs[-1]
+    total = slabs[-1]
     for slab in slabs[-2::-1]:
-        eta = eta + slab
-    return eta
+        total = total + slab
+    return total
 
 
 def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
@@ -128,7 +158,7 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
     i = pl.program_id(0)
     dp, dtype = xT_ref.shape[0], xT_ref.dtype
     lanes, folds = selT_ref.shape
-    pack = 8 * 4 // jnp.dtype(dtype).itemsize
+    pack = _pack(dtype)
     rc = residual_curvature(loss)
     over_rows = (((1,), (1,)), ((), ()))
 
@@ -166,8 +196,8 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
             x_ok = x_ok & feat_ok
         xs = jnp.where(x_ok, (xT_ref[:, cols].astype(f32) - mean) / std,
                        0.0).astype(dtype)                        # [dp, c]
-        eta = margins(jnp.dot(bt, xs, preferred_element_type=f32),
-                      lanes) + b0                                # [L, c]
+        eta = slab_sum(jnp.dot(bt, xs, preferred_element_type=f32),
+                       lanes) + b0                               # [L, c]
         # y and w come dense, 128 rows of X a sublane (`dense_rows`)
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
@@ -183,8 +213,11 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
         lane_sums(g0_ref, R)
         lane_sums(h0_ref, S)
         # The block streams through the matrix unit against what stays in
-        # it: the residual, then every lane's weighted copy of the block,
-        # one above the other. The copies fill the array's 128 columns; the
+        # it: the residual's parts, one above the other (each part's slab
+        # of the product sums on its own and the caller adds them: in here
+        # that is a lane rotation and an add a chunk, 2 ms of a 64-lane
+        # pass at 128 columns), then every lane's weighted copy of
+        # the block, likewise. The copies fill the array's 128 columns; the
         # block, 64 wide, would fill half of them, and as the stationary
         # operand it measured 83.6 ms a pass where this form takes 57.5
         # (PERF.md, PR 36). They go to the contraction as a value: through
@@ -192,8 +225,9 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
         if lanes % pack:    # the cast fills whole sublane tiles
             R = jnp.concatenate(
                 [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
-        g_ref[:, 0:R.shape[0]] += jax.lax.dot_general(
-            xs, R.astype(dtype), over_rows, preferred_element_type=f32)
+        Rp = float32_parts(R, dtype, residual_parts(dtype), in_kernel=True)
+        g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
+            xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(
             xs, (S[:, None, :] * xs.astype(f32)[None, :, :]).astype(dtype)
             .reshape(lanes * dp, chunk), over_rows,
@@ -221,23 +255,34 @@ def _padded(d: int, lanes: int, dtype) -> tuple:
         _round_up(lanes, 8)
 
 
+def _gradient_slabs(lp: int, dtype) -> tuple:
+    """(slabs, columns a slab) of the kernel's gradient block: a slab for
+    each of the residual's parts, side by side, each as wide as a part has
+    rows in the kernel — the lanes in whole sublane tiles of `dtype`, which
+    the cast fills."""
+    return residual_parts(dtype), _round_up(lp, _pack(dtype))
+
+
 def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
     """What the kernel keeps in VMEM for a bucket of `lanes`, in either
-    tile form: the weighted blocks of every chunk of a body, the float32
-    sums and the tile of X (as X.T or as X: the same bytes), y, w and the
-    fold masks twice each for the pipeline's buffers, and twice the
-    coefficients' parts (three of a bfloat16 matrix: 0.1 MiB at 64 lanes
-    of 128 columns). (The float32 products
-    before the cast never exist whole: compiled for a v5e, 256 lanes of 127
-    columns fit its 96 MiB.) At 128 columns a 64-lane bucket holds 32 MiB
-    of weighted blocks, 8 MiB of sums and 9 MiB of tiles, 49 MiB in all,
-    and a 128-lane bucket 89 MiB (both compile for a v5e); 256 lanes hold
-    170 MiB, and `glm_round_kernel` leaves that bucket to the blocks."""
+    tile form: the weighted blocks of every chunk of a body and beside them
+    the residual's parts, [lanes, chunk] each (two of a bfloat16 matrix:
+    0.5 MiB at 64 lanes), the float32 sums and the tile of X (as X.T or as
+    X: the same bytes), y, w and the fold masks twice each for the
+    pipeline's buffers, and twice the coefficients' parts (three of a
+    bfloat16 matrix: 0.1 MiB at 64 lanes of 128 columns). (The float32
+    products before the cast never exist whole: compiled for a v5e, 256
+    lanes of 127 columns fit its 96 MiB.) At 128 columns a 64-lane bucket
+    holds 32 MiB of weighted blocks, 8 MiB of sums and 9 MiB of tiles, 50
+    MiB in all, and a 128-lane bucket 91 MiB (both compile for a v5e); 256
+    lanes hold 172 MiB, and `glm_round_kernel` leaves that bucket to the
+    blocks."""
     dp, lp = _padded(d, lanes, dtype)
+    slabs, slab = _gradient_slabs(lp, dtype)
     item = jnp.dtype(dtype).itemsize
     tile = _CHUNK * _UNROLL * _TILE_BODIES
-    return _UNROLL * lp * dp * _CHUNK * item \
-        + 2 * dp * (lp * dp + _round_up(lp, 128)) * 4 \
+    return _UNROLL * (lp * dp + slabs * slab) * _CHUNK * item \
+        + 2 * dp * (lp * dp + _round_up(slabs * slab, 128)) * 4 \
         + 2 * tile * (dp * item + 8 * 4 + 2 * 4) \
         + 2 * n_parts(dtype) * lp * dp * item
 
@@ -257,11 +302,17 @@ def dense_rows(v, n_rows=None):
 def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
                 loss: str, n_rows=None, interpret: bool = False,
                 x_tile: str = "rows_minor"):
-    """(gA [lanes, d], hA [lanes, d, d], g0A [lanes], h0A [lanes]) float32:
-    the sums over the first `n_rows` rows (default: all) of R xs', S xs xs',
-    R and S, where xs is the standardised row in the matrix's dtype, R and S
-    the loss's residual and curvature at xs' B + b0 times the lane's fold
-    weight — one Newton iteration's pass of `_round_core` for a lane bucket.
+    """(gA [lanes, d], hA [lanes, d, d], g0A [lanes], h0A [lanes], gA_low
+    [lanes, d]) float32: the sums over the first `n_rows` rows (default:
+    all) of R xs', S xs xs', R and S, where xs is the standardised row in
+    the matrix's dtype, R and S the loss's residual and curvature at xs' B
+    + b0 times the lane's fold weight — one Newton iteration's pass of
+    `_round_core` for a lane bucket. gA contracts R rounded to the matrix's
+    dtype, as it always has; gA_low is the same sum over what that rounding
+    left, R - rounded(R), cut to the dtype in its turn (zeros for a float32
+    matrix, which rounds nothing): gA + gA_low is the gradient at the
+    residual's float32 precision, and that is what the iteration steps on
+    (`glm_sweep._round_core` adds the two before the mesh's all-reduce).
 
     The matrix comes in the layout it already has on the chip, named by
     `x_tile` (`glm_sweep.glm_x_tile(d)`: what the width makes of it, no
@@ -280,7 +331,7 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     y_rows, w_rows are `dense_rows` of y and w; fold_masks [F, n]; sel
     [F, lanes] maps lanes to folds; Bt [lanes, d] the coefficients, float32
     as the iteration carries them (or any dtype that holds them): the
-    margins see them unrounded, through `coefficient_parts` made here once
+    margins see them unrounded, through `float32_parts` made here once
     a pass — coefficients that are exact in the matrix's dtype leave every
     part but the first zero, and the sums are then, to the bit, those of
     the one-part contraction; b0 [lanes]; mean, std [d]. Columns pad to
@@ -294,6 +345,7 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     n = n_buf if n_rows is None else int(n_rows)
     F, lanes = sel.shape
     dp, lp = _padded(d, lanes, XT.dtype)
+    slabs, slab = _gradient_slabs(lp, XT.dtype)
     if cols_minor and d % 128:
         raise ValueError(f"a cols_minor tile is whole 128-column groups, "
                          f"not {d} columns")
@@ -310,13 +362,13 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
         return by_rows(a.shape, lambda i: (0, 0))
     dense = by_rows((tile // 128, 128), lambda i: (i, 0))
     resident = (
-        coefficient_parts(
+        float32_parts(
             jnp.pad(Bt, ((0, lp - lanes), (0, dp - d))), XT.dtype),
         jnp.pad(b0.astype(f32), (0, lp - lanes)).reshape(lp, 1),
         jnp.pad(sel.T.astype(f32), ((0, lp - lanes), (0, 0))),
         column(mean, 0.0), column(std, 1.0))
     out_shape = (jax.ShapeDtypeStruct((dp, lp * dp), f32),
-                 jax.ShapeDtypeStruct((dp, _round_up(lp, 128)), f32),
+                 jax.ShapeDtypeStruct((dp, _round_up(slabs * slab, 128)), f32),
                  jax.ShapeDtypeStruct((lp, 128), f32),
                  jax.ShapeDtypeStruct((lp, 128), f32))
     if cols_minor:
@@ -339,8 +391,12 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     )(XT, y_rows, w_rows, fold_masks.astype(f32), *resident)
     # h[j, lane * dp + i] = sum_c xs[j, c] (S[lane, c] xs[i, c])
     hA = h.reshape(dp, lp, dp).transpose(1, 2, 0)
-    return (g[:d, :lanes].T, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes],
-            h0.sum(axis=1)[:lanes])
+    # g[j, part * slab + lane] = sum_c xs[j, c] R_part[lane, c]
+    gA = g[:d, :lanes].T
+    gA_low = slab_sum(g[:, slab:slabs * slab], slab, axis=1)[:d, :lanes].T \
+        if slabs > 1 else jnp.zeros_like(gA)
+    return gA, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes], \
+        h0.sum(axis=1)[:lanes], gA_low
 
 
 def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
@@ -359,7 +415,7 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
     i = pl.program_id(0)
     d, dtype = x_ref.shape[1], x_ref.dtype
     lanes, folds = selT_ref.shape
-    pack = 8 * 4 // jnp.dtype(dtype).itemsize
+    pack = _pack(dtype)
     rc = residual_curvature(loss)
     over_rows = (((1,), (1,)), ((), ()))
 
@@ -387,8 +443,8 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
             x_rows < left,
             (x_ref[pl.ds(off, chunk), :].astype(f32) - mean) / std,
             0.0).T.astype(dtype)                                  # [d, c]
-        eta = margins(jnp.dot(bt, xs, preferred_element_type=f32),
-                      lanes) + b0                                 # [L, c]
+        eta = slab_sum(jnp.dot(bt, xs, preferred_element_type=f32),
+                       lanes) + b0                                # [L, c]
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
             [v[k:k + 1, :] for k in range(groups)], axis=1)
@@ -405,8 +461,9 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
         if lanes % pack:
             R = jnp.concatenate(
                 [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
-        g_ref[:, 0:R.shape[0]] += jax.lax.dot_general(
-            xs, R.astype(dtype), over_rows, preferred_element_type=f32)
+        Rp = float32_parts(R, dtype, residual_parts(dtype), in_kernel=True)
+        g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
+            xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(
             xs, (S[:, None, :] * xs.astype(f32)[None, :, :]).astype(dtype)
             .reshape(lanes * d, chunk), over_rows,
