@@ -275,6 +275,36 @@ def test_lane_metric_call_compiles_for_a_v5e(one_chip, metric_programs,
     assert "_hist_two_level_jit" in compiled.as_text()
 
 
+@pytest.mark.slow     # three sorts: ~100 s of the TPU's compiler
+def test_fold_program_compiles_for_the_four_chip_mesh(topo, as_v5e):
+    """sweep-glm-4chip's fold program: a chip sorts its own 32M keys and
+    the places it receives, ONE all-to-all and ONE all-reduce between
+    them; the 128M-key sort is there once, under the conditional that
+    answers an overflow, and the program's temporaries stay within a run's
+    places of that sort's own (3.58 GB at the parent, by this compiler)."""
+    import re
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+    from transmogrifai_tpu.automl.tuning import folds
+    from transmogrifai_tpu.parallel.mesh import BATCH_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1),
+                (BATCH_AXIS, MODEL_AXIS))
+    n = 128_000_000
+    _, capacity = folds._partition_plan(n, 4)
+    compiled = folds._sharded_fold_masks_fn(mesh, n, 5, None, False).lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=NamedSharding(
+            mesh, Ps()))).compile()
+    text = compiled.as_text()
+    sorts = sorted(int(k) for k in re.findall(
+        r"= \(u32\[(\d+)\][^=]* sort\(", text))
+    assert sorts == [n // 4, 4 * capacity, n]
+    assert len(re.findall(r" all-to-all(-start)?\(", text)) == 1
+    assert len(re.findall(r" all-reduce(-start)?\(", text)) == 1
+    assert " conditional(" in text and "all-gather" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3.6e9 + 4 * (n // 4 + capacity)
+
+
 def test_heldout_metric_pass_compiles_for_the_four_chip_mesh(
         topo, metric_programs):
     """sweep-glm-4chip's metric pass: the two-level body inside the

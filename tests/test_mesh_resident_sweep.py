@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from transmogrifai_tpu.automl.tuning import folds
 from transmogrifai_tpu.automl.tuning import validators as V
 from transmogrifai_tpu.automl.tuning.folds import (
     FOLD_ASSIGNMENT_VERSION, assign_fold_masks, assign_fold_masks_sharded,
@@ -94,36 +95,111 @@ def test_resident_row_mesh_reads_only_a_row_sharded_device_array(mesh, data):
 
 # -- folds ----------------------------------------------------------------------
 
+# a seed, a row count and whether a run is given too few places. At 4 096
+# rows the program's own places for a run are all of a chip's rows; at
+# 200 000 (a shard's rows no power of two) a third of them, the runs cut
+# out of the sorted rows; rows / shards**2 places hold a run without its
+# window's margins, so a run overflows
+FOLD_CASES = {"n4096": (7, N, False),
+              "seed_past_2_32": (2 ** 40 + 5, N, False),
+              "rows_no_power_of_two": (7, 200_000, False),
+              "capacity_overflows": (7, N, True)}
+
+
+@pytest.mark.parametrize("case", list(FOLD_CASES))
 @pytest.mark.parametrize("shards", [2, 4])
 @pytest.mark.parametrize("stratify", [False, True])
 @pytest.mark.parametrize("spec", [dict(n_folds=5),
                                   dict(n_folds=1, val_fraction=0.25)],
                          ids=["kfold", "split"])
-def test_fold_masks_on_a_mesh_are_the_one_device_masks(data, shards, stratify,
-                                                       spec):
+def test_fold_masks_on_a_mesh_are_the_one_device_masks(shards, stratify, spec,
+                                                       case):
+    seed, n, too_few = FOLD_CASES[case]
     m = make_mesh(n_batch=shards, n_model=1, devices=jax.devices()[:shards])
-    y1 = data["y1"] if stratify else None
-    ys = jax.device_put(data["y"], batch_sharding(m, 1)) if stratify else None
-    one = assign_fold_masks(fold_key(7), y1, n=N, stratify=stratify, **spec)
-    on_mesh = assign_fold_masks_sharded(m, fold_key(7), ys, n=N,
-                                        stratify=stratify, **spec)
+    y = (np.random.default_rng(1).random(n) < 0.3).astype(np.float32)
+    y1 = jnp.asarray(y) if stratify else None
+    ys = jax.device_put(y, batch_sharding(m, 1)) if stratify else None
+    one = assign_fold_masks(fold_key(seed), y1, n=n, stratify=stratify,
+                            **spec)
+    if too_few:
+        on_mesh, overflow = folds._sharded_fold_masks_fn(
+            m, n, spec["n_folds"], spec.get("val_fraction"), stratify,
+            n // shards ** 2)(fold_key(seed), *([ys] if stratify else []))
+    else:
+        on_mesh, overflow = assign_fold_masks_sharded(
+            m, fold_key(seed), ys, n=n, stratify=stratify, **spec)
     assert FOLD_ASSIGNMENT_VERSION == 2
+    # which body answered: the partitioned one unless a run overflowed
+    # (the stratified rule has none to overflow)
+    route = folds.sharded_fold_route(m, n, stratify)
+    assert route["route"] == ("replicated" if stratify else "partitioned")
+    assert route["sort_keys"] == (n if stratify
+                                  else shards * route["capacity"])
+    assert route["exchange_bytes"] == 12 * shards * route["capacity"]
+    if case == "rows_no_power_of_two" and not stratify:
+        assert route["capacity"] < n // shards and route["sort_keys"] < n
+    assert bool(overflow) == (too_few and not stratify)
     assert on_mesh.sharding.is_equivalent_to(sharded_along(m, 1, 2), 2)
     # no chip holds the whole [F, n] block
     assert {s.data.shape for s in on_mesh.addressable_shards} == \
-        {(one.shape[0], N // shards)}
+        {(one.shape[0], n // shards)}
     assert np.array_equal(np.asarray(one), np.asarray(on_mesh))
+
+
+def test_partitioned_order_breaks_ties_by_row_id(mesh):
+    """Equal 64-bit keys on different shards come out in row-id order, as
+    the one stable sort gives them: across the positions where one chip's
+    slice of the order ends and the next begins, at the first and last
+    word of a bucket and of a window, and for a key that equals the
+    padding. Threefry hands a test no tie, so the core takes the words."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+    from transmogrifai_tpu.parallel.mesh import BATCH_AXIS, build_shard_map
+    n, shards = 1 << 16, 4     # a run's places: 3/8 of a chip's rows
+    n_local = n // shards
+    margin, capacity = folds._partition_plan(n, shards)
+    assert capacity < n_local
+    rng = np.random.default_rng(3)
+    w = rng.integers(0, 2 ** 32, size=(2, n), dtype=np.uint64) \
+        .astype(np.uint32)
+    order = np.lexsort((np.arange(n), w[1], w[0]))
+    for c in range(1, shards):
+        rows = order[c * n_local - 8:c * n_local + 8]
+        assert len(set(rows // n_local)) > 1        # on several shards
+        w[:, rows] = w[:, rows[:1]]
+    edges = [-(-c * 2 ** 32 // shards) for c in range(shards + 1)]
+    for w0, w1 in ((edges[1], 5), (edges[1] - 1, 5), (edges[2] - margin, 5),
+                   (edges[2] - margin - 1, 5), (edges[1] + margin, 5),
+                   (edges[1] + margin - 1, 5), (0, 0),
+                   (2 ** 32 - 1, 2 ** 32 - 1)):
+        rows = rng.choice(n, 6, replace=False)
+        assert len(set(rows // n_local)) > 1
+        w[0, rows], w[1, rows] = w0, w1
+    want = lax.sort((jnp.asarray(w[0]), jnp.asarray(w[1]),
+                     lax.iota(jnp.int32, n)), num_keys=2, is_stable=True)[2]
+    core = jax.jit(build_shard_map(
+        lambda w0, w1: folds._partitioned_ranks(w0, w1, n, BATCH_AXIS),
+        mesh, in_specs=(P(BATCH_AXIS), P(BATCH_AXIS)),
+        out_specs=(P(BATCH_AXIS), P())))
+    ranks, overflow = core(*(jax.device_put(x, batch_sharding(mesh, 1))
+                             for x in w))
+    assert not bool(overflow)
+    assert np.array_equal(np.asarray(ranks), np.asarray(want))
 
 
 @pytest.mark.parametrize("cls,kw", [
     (CrossValidation, dict(num_folds=5)),
     (TrainValidationSplit, dict(train_ratio=0.75))])
-def test_validator_masks_do_not_depend_on_the_layout(mesh, data, cls, kw):
+@pytest.mark.parametrize("stratify", [True, False])
+def test_validator_masks_do_not_depend_on_the_layout(mesh, data, cls, kw,
+                                                     stratify):
     val = cls(Evaluators.BinaryClassification.au_pr(), seed=11,
-              stratify=True, **kw)
+              stratify=stratify, **kw)
+    one = np.asarray(val.device_fold_masks(data["y1"]))
+    assert val.last_fold_overflow is None       # no mesh, no flag
     assert np.array_equal(
-        np.asarray(val.device_fold_masks(data["y1"])),
-        np.asarray(val.device_fold_masks(data["ys"], mesh=mesh)))
+        one, np.asarray(val.device_fold_masks(data["ys"], mesh=mesh)))
+    assert not bool(val.last_fold_overflow)
 
 
 # -- the sweep ------------------------------------------------------------------
@@ -176,8 +252,19 @@ def _attrs(spans, kind, name):
 def test_spans_name_the_layout(sweeps, which, shards, route):
     spans = sweeps[which]
     assert _attrs(spans, "validate", "CrossValidation")[0]["shards"] == shards
-    assert _attrs(spans, "validate_phase", "fold_assign")[0]["shards"] \
-        == shards
+    assign = _attrs(spans, "validate_phase", "fold_assign")[0]
+    assert assign["shards"] == shards
+    if shards == 1:
+        assert assign["route"] == "device" and "sort_keys" not in assign
+    else:
+        # the fold program's body on the mesh, as planned; the flag says
+        # that body answered, and nothing fetched it
+        _, capacity = folds._partition_plan(N, shards)
+        assert {k: assign[k] for k in ("route", "sort_keys", "capacity",
+                                       "exchange_bytes")} == dict(
+            route="partitioned", sort_keys=shards * capacity,
+            capacity=capacity, exchange_bytes=12 * shards * capacity)
+        assert not bool(sweeps["four"][0].last_fold_overflow)
     place = _attrs(spans, "validate_phase", "device_place")[0]
     assert place["route"] == route and place["h2d_bytes"] == 0
     assert _attrs(spans, "sweep_fit", "glm_streamed")[0]["shards"] == shards
@@ -395,6 +482,13 @@ def test_sharded_round_with_the_fused_pass_is_the_one_device_round(
     assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
 
 
+def _sort_lengths(text):
+    """Operand lengths of the sorts in a lowered program's text, in order."""
+    import re
+    return [int(re.search(r"\}\) : \(tensor<(\d+)x", text[m.end():]).group(1))
+            for m in re.finditer(r'"stablehlo\.sort"', text)]
+
+
 def test_sharded_programs_keep_the_names_traces_find_them_by(mesh):
     S = jax.ShapeDtypeStruct
     ev = V._sharded_eval_heldout_fn(mesh, "au_pr", 64)
@@ -406,11 +500,28 @@ def test_sharded_programs_keep_the_names_traces_find_them_by(mesh):
     # ONE psum call of the two classes' counts (two ops side by side in
     # jax's lowering, one all-reduce once the TPU's compiler is done)
     assert text.count("all_reduce") == 2
-    from transmogrifai_tpu.automl.tuning import folds
-    fold = folds._sharded_fold_masks_fn(mesh, N, 5, None, False)
-    text = fold.lower(S((2,), jnp.uint32)).as_text()
+    # the fold program at the four-chip cell's size (lowered, never run):
+    # ONE exchange of the runs and ONE psum of their lengths, no gather of
+    # anything, and outside the branch that answers an overflow no sort
+    # longer than a chip's rows and one run's places
+    n = 128_000_000
+    _, capacity = folds._partition_plan(n, 4)
+    text = folds._sharded_fold_masks_fn(mesh, n, 5, None, False).lower(
+        S((2,), jnp.uint32)).as_text()
     assert "jit_assign_fold_masks_sharded" in text
-    assert "all_reduce" not in text and "all_gather" not in text
+    assert text.count("stablehlo.all_to_all") == 1
+    assert text.count("stablehlo.all_reduce") == 1
+    assert "all_gather" not in text
+    partitioned, _, fallback = text.partition('"stablehlo.case"')
+    assert _sort_lengths(partitioned) == [n // 4, 4 * capacity]
+    assert 4 * capacity <= n // 4 + capacity
+    assert _sort_lengths(fallback) == [n]
+    # the stratified rule keeps the replicated sort behind an all-gather
+    text = folds._sharded_fold_masks_fn(mesh, N, 5, None, True).lower(
+        S((2,), jnp.uint32), S((N,), jnp.float32)).as_text()
+    assert "jit_assign_fold_masks_sharded" in text
+    assert "all_gather" in text and "all_to_all" not in text
+    assert set(_sort_lengths(text)) == {N}
     rounds = GS._sharded_round_fn(mesh, "logistic", True)
     assert rounds.__wrapped__.__name__ == "sweep_glm_round_sharded" \
         or "sweep_glm_round_sharded" in repr(rounds)

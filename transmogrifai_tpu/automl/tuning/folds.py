@@ -16,11 +16,18 @@ is integer arithmetic or a stable sort, so the CPU backend and the chip
 draw the same folds and a checkpoint replays on either.
 
 On a mesh (`assign_fold_masks_sharded`) the SAME assignment comes back
-sharded on rows: the random words are a function of the row id alone, so
-every chip forms the one global order for itself (the labels, when
-stratified, all-gathered first), keeps the folds of its own rows and
-builds only its `[F, rows / shards]` block of the mask. Bit for bit the
-one-device masks, on any number of shards.
+sharded on rows, and no chip sorts the whole table: the random words are a
+function of the row id alone and uniform, so the one global order is
+range-partitioned on the leading word without a sample. A chip makes the
+words of its OWN rows, sorts them, sends every chip the run that falls in
+that chip's fixed window of the leading word (one `all_to_all` of padded
+runs), sorts what it receives and reads, at an offset that one `psum` of
+the runs' lengths gives it, exactly the slice of the global order that
+ranks its own rows. A seed whose runs outgrow the static padding is
+answered inside the same program by the one sort of all the rows on every
+chip, which is also how the stratified rule runs (it leads with the label,
+whose classes no fixed range balances; the labels all-gathered first). Bit
+for bit the one-device masks, on any number of shards, for every seed.
 """
 from __future__ import annotations
 
@@ -134,6 +141,14 @@ def _shuffled_rows(key, n: int, *leading):
     return (*out[:len(leading)], out[-1])
 
 
+def _fold_of_rank(rank, n: int, n_folds: int,
+                  val_fraction: Optional[float]):
+    """The unstratified rule, from a row's rank in the random order."""
+    if val_fraction is not None:
+        return (rank >= int(round(n * val_fraction))).astype(jnp.int32)
+    return rank % n_folds
+
+
 def _fold_of(key, y, n: int, n_folds: int, val_fraction: Optional[float],
              stratify: bool):
     """int32[n] fold that holds each row out (k-fold), or 0 = held out /
@@ -143,8 +158,7 @@ def _fold_of(key, y, n: int, n_folds: int, val_fraction: Optional[float],
         # the sorted row ids ARE a uniformly random permutation: read as
         # "row i has rank ids[i]", no inverse needed
         rank, = _shuffled_rows(key, n)
-        return (rank >= int(round(n * val_fraction))).astype(jnp.int32) \
-            if split else rank % n_folds
+        return _fold_of_rank(rank, n, n_folds, val_fraction)
     cls, rows = _shuffled_rows(key, n, y)
     pos = lax.iota(jnp.int32, n)
     first = jnp.concatenate([jnp.ones((1,), bool), cls[1:] != cls[:-1]])
@@ -187,9 +201,125 @@ def assign_fold_masks(key, y, *, n: int, n_folds: int,
         val_fraction is not None)
 
 
+# -- the program on a mesh -----------------------------------------------------
+# How far, in standard deviations of a binomial count, the static sizes of
+# the partitioned order stand from what uniform words fill: a window of the
+# leading word reaches that far past the positions its chip returns, and a
+# run's padding that far past the run's expected length. Past it the
+# program answers with the replicated sort, so this sets how often that
+# happens (never, at 12), not what comes back.
+_PARTITION_SIGMAS = 12
+
+
+def _partition_plan(n: int, shards: int) -> tuple:
+    """(margin, capacity) of the range-partitioned order over `n` rows on
+    `shards` chips. Chip c returns the global positions [c, c + 1) *
+    n / shards; its window is the leading words [ceil(c * 2**32 / shards)
+    - margin, ceil((c + 1) * 2**32 / shards) + margin), `margin` the span
+    of words that holds _PARTITION_SIGMAS deviations of the count of keys
+    below a fixed word (at most sqrt(n) / 2). `capacity` is the places a
+    run (one chip's keys in one window) is padded to: its expected length
+    and as many deviations of it, and never more than the chip's rows; a
+    multiple of 2 048, because the chip's sort of the `shards * capacity`
+    places received takes a tenth longer over a length that is not one
+    (32 271 872 keys 0.127 s, 2**25 keys 0.118: PERF.md, PR 45)."""
+    n_local = n // shards
+    margin = math.ceil(_PARTITION_SIGMAS / 2 * math.sqrt(n) * 2 ** 32 / n)
+    run = n_local * min(1.0, 1 / shards + 2 * margin / 2 ** 32)
+    capacity = math.ceil(run + _PARTITION_SIGMAS * math.sqrt(run))
+    return margin, min(n_local, -(-capacity // 2048) * 2048)
+
+
+def _row_words(key, n: int, start, count: int):
+    """The two Threefry words of rows [start, start + count) of `n`:
+    _shuffled_rows' counter pairs (i, n + i), for a slice of the rows."""
+    i = start.astype(jnp.uint32) + lax.iota(jnp.uint32, count)
+    words = threefry_2x32((key[0], key[1]), jnp.stack([i, jnp.uint32(n) + i]))
+    return words[0], words[1]
+
+
+def _partitioned_ranks(w0, w1, n: int, axis_name: str,
+                       capacity: Optional[int] = None):
+    """Inside a shard_map over `axis_name`: (uint32[n / shards] row ids at
+    the chip's own positions of the global order, bool[] overflow), from
+    the chip's own rows' words `w0`, `w1`. The order is _shuffled_rows':
+    by word 0, word 1, then row id — the row id is a third KEY here, which
+    is what the stable two-key sort of ascending ids gives without the
+    index operand a stable sort carries on the chip, and it puts the
+    padding (all ones, no row's id) after every key whatever its words.
+    `overflow` is the same on every chip: a run longer than `capacity`
+    (None: _partition_plan's; tests make a run overflow with a smaller
+    one) or a window that misses a position its chip returns; the ids then
+    mean nothing and the caller answers another way."""
+    n_local = w0.shape[0]
+    shards = n // n_local
+    shard = lax.axis_index(axis_name)
+    margin, planned = _partition_plan(n, shards)
+    capacity = capacity or planned
+    ids = shard.astype(jnp.uint32) * jnp.uint32(n_local) \
+        + lax.iota(jnp.uint32, n_local)
+    w0, w1, ids = lax.sort((w0, w1, ids), num_keys=3, is_stable=False)
+    # window c of the sorted run is [lo[c], hi[c]): the keys whose leading
+    # word lies in [edge c - margin, edge c + 1 + margin)
+    edges = np.array([-(-c * 2 ** 32 // shards) for c in range(shards + 1)])
+
+    def keys_below(words):
+        found = jnp.searchsorted(
+            w0, np.clip(words, 0, 2 ** 32 - 1).astype(np.uint32))
+        return jnp.where(words >= 2 ** 32, n_local, found.astype(jnp.int32))
+
+    lo, hi = keys_below(edges[:-1] - margin), keys_below(edges[1:] + margin)
+    # ONE psum: where every window starts and ends in the global order,
+    # and whether some run outgrew its places
+    counts = lax.psum(jnp.concatenate(
+        [lo, hi, jnp.any(hi - lo > capacity).astype(jnp.int32)[None]]),
+        axis_name)
+    first, last, long_runs = counts[:shards], counts[shards:-1], counts[-1]
+    own = n_local * lax.iota(jnp.int32, shards)
+    overflow = (long_runs > 0) | jnp.any(first > own) \
+        | jnp.any(own + n_local > last)
+    # a run's places: `capacity` of the sorted run from its start (from
+    # further left where the run ends with the rows: a slice does not
+    # leave the array), what lies outside the run padded over
+    start = jnp.minimum(lo, n_local - capacity)
+    at = start[:, None] + lax.iota(jnp.int32, capacity)[None, :]
+    in_run = (at >= lo[:, None]) & (at < hi[:, None])
+    pad = jnp.uint32(0xFFFFFFFF)
+    runs = jnp.stack([
+        jnp.where(in_run, jnp.stack([
+            lax.dynamic_slice_in_dim(x, start[c], capacity)
+            for c in range(shards)]), pad) for x in (w0, w1, ids)])
+    got = lax.all_to_all(runs, axis_name, 1, 1).reshape(3, shards * capacity)
+    order = lax.sort((got[0], got[1], got[2]), num_keys=3,
+                     is_stable=False)[2]
+    return lax.dynamic_slice_in_dim(
+        order, n_local * shard - first[shard], n_local), overflow
+
+
+def sharded_fold_route(mesh, n: int, stratify: bool) -> dict:
+    """What assign_fold_masks_sharded runs on `mesh` over `n` rows, as the
+    `fold_assign` span says it: `route` (`partitioned`: each chip sorts
+    its own rows and the runs it receives; `replicated`: every chip sorts
+    all the rows — the stratified rule, and what answers a `partitioned`
+    program whose overflow flag is set), `sort_keys` (the longest sort a
+    chip runs on that route), `capacity` (places a run is padded to) and
+    `exchange_bytes` (a chip's operand of the all_to_all)."""
+    from ...parallel.mesh import mesh_batch_count
+    if stratify:
+        return dict(route="replicated", sort_keys=n, capacity=0,
+                    exchange_bytes=0)
+    shards = mesh_batch_count(mesh)
+    _, capacity = _partition_plan(n, shards)
+    return dict(route="partitioned", sort_keys=shards * capacity,
+                capacity=capacity, exchange_bytes=12 * shards * capacity)
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_fold_masks_fn(mesh, n: int, n_folds: int,
-                           val_fraction: Optional[float], stratify: bool):
+                           val_fraction: Optional[float], stratify: bool,
+                           capacity: Optional[int] = None):
+    """The jitted program of assign_fold_masks_sharded; `capacity` is
+    _partitioned_ranks'."""
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import (
@@ -198,29 +328,45 @@ def _sharded_fold_masks_fn(mesh, n: int, n_folds: int,
     n_local = n // mesh_batch_count(mesh)
 
     def assign_fold_masks_sharded(key, *y_local):
-        y = lax.all_gather(y_local[0], BATCH_AXIS, tiled=True) \
-            if stratify else None
-        own = lax.dynamic_slice_in_dim(
-            _fold_of(key, y, n, n_folds, val_fraction, stratify),
-            lax.axis_index(BATCH_AXIS) * n_local, n_local)
-        return _train_masks(own, n_folds, val_fraction is not None)
+        first_row = lax.axis_index(BATCH_AXIS) * n_local
+        if stratify:
+            y = lax.all_gather(y_local[0], BATCH_AXIS, tiled=True)
+            own = lax.dynamic_slice_in_dim(
+                _fold_of(key, y, n, n_folds, val_fraction, True),
+                first_row, n_local)
+            overflow = jnp.zeros((), bool)
+        else:
+            rank, overflow = _partitioned_ranks(
+                *_row_words(key, n, first_row, n_local), n, BATCH_AXIS,
+                capacity)
+            rank = lax.cond(
+                overflow,
+                lambda: lax.dynamic_slice_in_dim(
+                    _shuffled_rows(key, n)[0], first_row, n_local),
+                lambda: rank.astype(jnp.int32))
+            own = _fold_of_rank(rank, n, n_folds, val_fraction)
+        return _train_masks(own, n_folds, val_fraction is not None), overflow
 
     return jax.jit(build_shard_map(
         assign_fold_masks_sharded, mesh,
         in_specs=(P(),) + (P(BATCH_AXIS),) * int(stratify),
-        out_specs=P(None, BATCH_AXIS)))
+        out_specs=(P(None, BATCH_AXIS), P())))
 
 
 def assign_fold_masks_sharded(mesh, key, y, *, n: int, n_folds: int,
                               val_fraction: Optional[float] = None,
                               stratify: bool = False):
-    """assign_fold_masks on a mesh: the same [F, n] masks bit for bit,
+    """assign_fold_masks on a mesh: (the same [F, n] masks bit for bit,
     sharded on rows over the batch axis (`sharded_along(mesh, 1, 2)`), no
-    chip holding more than its [F, n / shards] block. Every chip sorts the
-    whole order (the words depend on the row id alone; `y`, sharded on
-    rows and read only when `stratify`, is all-gathered), so the program
-    issues no collective unless stratified. `n` divides by the shards, as
-    the rows of a sharded array do."""
+    chip holding more than its [F, n / shards] block; a device bool, the
+    program's overflow flag). Unstratified, each chip sorts its own rows'
+    keys and the runs of its window of the global order
+    (_partitioned_ranks: one all_to_all, one psum of counts), and where
+    the flag is set — a seed whose runs outgrow the static padding, which
+    uniform words do not — every chip sorted all `n` keys in the same
+    program instead. Stratified (`y` sharded on rows), the labels are
+    all-gathered and every chip sorts the whole order; the flag is False.
+    `n` divides by the shards, as the rows of a sharded array do."""
     fn = _sharded_fold_masks_fn(mesh, n, n_folds, val_fraction,
                                 bool(stratify))
     return fn(key, y) if stratify else fn(key)

@@ -29,7 +29,10 @@ from ...models.prediction import make_prediction_column
 from ...ops import metrics_ops as M
 from ...stages.params import ParamMap
 from ...utils.metrics import collector
-from .folds import assign_fold_masks, assign_fold_masks_sharded, fold_key
+from .folds import (
+    assign_fold_masks, assign_fold_masks_sharded, fold_key,
+    sharded_fold_route,
+)
 
 
 def _phase(name: str, **attrs: Any):
@@ -527,6 +530,10 @@ class Validator:
         # the mesh X of the current validate() call lives row-sharded on
         # (None: a host array, one device) — _sweep_mesh, _resident
         self._resident_mesh = None
+        # overflow flag of the last fold program that ran on a mesh, a
+        # device scalar nothing fetches (True: a run outgrew its padding
+        # and the replicated sort answered); None before one has run
+        self.last_fold_overflow = None
 
     @property
     def _sweep_mesh(self):
@@ -552,7 +559,8 @@ class Validator:
         (seed, rows, folds or ratio, and `y` when stratified) alone —
         the same for a host `y` and a device `y`, on any backend and, with
         `mesh`, sharded on rows over it (folds.assign_fold_masks_sharded:
-        no chip holds the whole block)."""
+        no chip holds the whole block; the program's overflow flag is kept
+        as `last_fold_overflow`)."""
         spec = dict(n=len(y), stratify=self.stratify, **self._fold_spec())
         y = jnp.asarray(y, jnp.float32) if self.stratify else None
         if mesh is None:
@@ -560,8 +568,9 @@ class Validator:
         if y is not None:
             from ...parallel.mesh import batch_sharding
             y = jax.device_put(y, batch_sharding(mesh, 1))
-        return assign_fold_masks_sharded(mesh, fold_key(self.seed), y,
-                                         **spec)
+        masks, self.last_fold_overflow = assign_fold_masks_sharded(
+            mesh, fold_key(self.seed), y, **spec)
+        return masks
 
     def fold_masks(self, y) -> np.ndarray:
         """The same masks on the host: what validate() ran on, bit for
@@ -598,10 +607,15 @@ class Validator:
                 # fetch then waits for this reduction alone
                 with _phase("label_classes"):
                     n_classes = label_classes(y)
-            with _phase("fold_assign",
-                        route="device" if masks is None else "external",
-                        rows=len(y), folds=n_folds, stratify=self.stratify,
-                        shards=shards if self._resident else 1):
+            if masks is not None:
+                how = {"route": "external"}
+            elif self._resident:
+                how = sharded_fold_route(resident, len(y), self.stratify)
+            else:
+                how = {"route": "device"}
+            with _phase("fold_assign", rows=len(y), folds=n_folds,
+                        stratify=self.stratify,
+                        shards=shards if self._resident else 1, **how):
                 # unit weights under 0/1 masks (device_fold_masks') and the
                 # binary route's 0/1 labels; masks handed in may hold anything
                 self._unit_payload = w is None and masks is None
