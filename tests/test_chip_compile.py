@@ -358,3 +358,28 @@ def test_regression_sweep_programs_compile_for_a_v5e(one_chip,
     assert metric.memory_analysis().temp_size_in_bytes \
         <= 0.06 * n * 128 * 2
     assert "_hist_two_level_jit" not in metric.as_text()
+
+
+def test_three_part_histogram_kernels_compile_for_a_v5e(one_chip, as_v5e):
+    """sweep-rf-regression's histogram kernels at 10M rows x 64 columns, 33
+    bins, the 15 lanes plan_forest_group gives five payload rows a (lane,
+    slot): the root pass and the deepest fused route-and-histogram pass
+    (16 slots: a [1 200, 2 112] float32 output block, 10.1 MB of VMEM) with
+    weight x (label - centre) cut into three bfloat16 parts in the kernel
+    (bit masks: Mosaic lowers no reduce_precision)."""
+    n, F, B, lanes, nodes = 10_002_432, 64, 33, 15, 16
+    assert pallas_hist.plan_forest_group(10_000_000, F, B, 5, 10, 6, 5) * 5 \
+        == lanes
+
+    def S(shape, dt=F32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    kw = dict(n_bins=B, interpret=False, use_bf16=True, derive_count=True,
+              parts=3)
+    root = pallas_hist._hist_pallas_jit.lower(
+        S((F, n), jnp.int8), S((2 * lanes, n)), S((lanes, n)), n_slots=1,
+        **kw).compile()
+    deep = pallas_hist._route_hist_pallas_jit.lower(
+        S((F, n), jnp.int8), S((2 * lanes, n)), S((lanes, n)),
+        *[S((lanes, nodes), jnp.int32)] * 3, n_nodes=nodes, **kw).compile()
+    for compiled in (root, deep):
+        assert "tpu_custom_call" in compiled.as_text()

@@ -151,17 +151,31 @@ def test_the_model_halves_the_threshold_for_the_one_channel_payload(
     monkeypatch.setattr(T, "fit_forest_lanes", spy)
     ctx = (Xb, None, 8)
     w = jnp.ones_like(y)
-    for cls, scale in ((MT.OpRandomForestClassifier, 0.5),
-                       (MT.OpRandomForestRegressor, 1.0)):
+    for cls, scale, body, rows in (
+            (MT.OpRandomForestClassifier, 0.5, "indicator", 3),
+            (MT.OpRandomForestRegressor, 1.0, "centred_parts", 5)):
         est = cls(num_trees=2, max_depth=2, max_bins=8, min_info_gain=0.01,
                   min_instances_per_node=3)
         out = est.mask_fit_scores(ctx, y, w, W)
         assert out.shape == W.shape
         assert float(seen["min_info_gain"]) == pytest.approx(0.01 * scale)
         assert float(seen["min_instances"]) == 3.0
-        assert est.last_lane_telemetry == dict(
+        # the payload's word reaches the fit as the predicate gives it,
+        # and a real label goes with its centre (the 0/1 one with none)
+        assert seen["payload"] == body == MT.forest_payload_body(est)
+        tele = dict(est.last_lane_telemetry)
+        centre = tele.pop("label_centre")
+        assert centre is seen["centre"]
+        if body == "indicator":
+            assert centre is None
+        else:   # [the label's mean to 8 bits, the power of two over g]
+            assert abs(float(centre[0]) - float(y.mean())) < 0.01
+            assert float(centre[1]) == 2.0 ** np.ceil(np.log2(
+                16 * float(jnp.abs(y - centre[0]).max())))
+        assert tele == dict(
             tree_lanes=4, lane_groups=1, lanes_per_group=4,
-            bootstrap_draws=1200)
+            bootstrap_draws=1200, payload_body=body, payload_rows=rows,
+            features_per_node=2)
 
 
 def test_bootstrap_vectors_differ_across_trees_and_fold_lanes_share_them(
@@ -323,7 +337,16 @@ def test_validate_takes_the_lane_route_and_answers_like_the_sequential_one(
         np.testing.assert_allclose(a.fold_metrics, b.fold_metrics,
                                    rtol=2e-5)
     trees = params.get("num_trees", 1)
-    assert val1.last_tree_telemetry == {
+    tele = dict(val1.last_tree_telemetry)
+    want = {
         "model": cls.__name__, "route": "forest_lanes",
         "tree_lanes": 2 * trees * 3, "lane_groups": 2,
         "lanes_per_group": trees * 3, "bootstrap_draws": 2 * trees * 3000}
+    if problem == "regression":
+        # how the real-valued payload was carried, and two host floats: the
+        # label's centre and the power of two its payload was divided by
+        assert abs(tele.pop("label_centre") - float(y.mean())) < 0.01
+        assert np.log2(tele.pop("payload_scale")) % 1 == 0
+        want.update(payload_body="centred_parts", payload_rows=5,
+                    features_per_node=3)
+    assert tele == want    # a 0/1 label's lanes: the dict of PR 31

@@ -1031,7 +1031,10 @@ class Validator:
     def _count_tree_lanes(self, est, lanes):
         """Sum one grid point's forest-lane counts into
         last_tree_telemetry (tree_lanes, lane_groups, bootstrap_draws
-        add up over a sweep's points; lanes_per_group is the widest)."""
+        add up over a sweep's points; lanes_per_group is the widest; how
+        a real-valued payload was carried — payload_body, payload_rows,
+        features_per_node, label_centre and payload_scale, the last two
+        fetched here — is the last point's)."""
         tele = self.last_tree_telemetry or {
             "model": type(est).__name__, "route": "forest_lanes",
             "tree_lanes": 0, "lane_groups": 0, "lanes_per_group": 0,
@@ -1040,6 +1043,15 @@ class Validator:
             tele[key] += int(lanes[key])
         tele["lanes_per_group"] = max(tele["lanes_per_group"],
                                       int(lanes["lanes_per_group"]))
+        if lanes["payload_body"] != "indicator":
+            # a 0/1 label's three-row lanes report what they always did
+            # (the accepted sweep-rf test holds that dict to equality);
+            # their word is on the forest_group spans
+            for key in ("payload_body", "payload_rows",
+                        "features_per_node"):
+                tele[key] = lanes[key]
+            tele["label_centre"], tele["payload_scale"] = map(
+                float, lanes["label_centre"])
         self.last_tree_telemetry = tele
 
     def _record_sweep_telemetry(self, est, info):
@@ -1379,8 +1391,15 @@ class Validator:
             lanes_fn = _lanes_metric_fn(
                 metric, problem_type, rank_bins, self._unit_payload) \
                 if self._sweep_mesh is None else None
-            hist_attrs = {} if lanes_fn is None else \
-                M.rank_hist_kernel(rank_bins, self._unit_payload)
+            # what the metric program of this route is, on its span: the
+            # lane-batched binned counts ("bins", with the kernel's own
+            # words) or the metric function vmapped over the folds' whole
+            # score rows ("vmapped": every regression and class metric —
+            # the tree route has no one-pass body of sums)
+            hist_attrs = dict(metric=metric, metric_body="vmapped") \
+                if lanes_fn is None else dict(
+                    M.rank_hist_kernel(rank_bins, self._unit_payload),
+                    metric=metric, metric_body="bins")
 
             @jax.jit
             def fold_metrics(scores, y_, w_, m_, t_):
@@ -1525,8 +1544,10 @@ class Validator:
                         lanes = getattr(est_g, "last_lane_telemetry", None)
                         if lanes:
                             fused_gis[gi] = "mask_folds:forest_lanes"
-                            self._count_tree_lanes(est, lanes)
                         record(gi, scores, route=fused_gis.get(gi))
+                        if lanes:   # after the cell's own fetch: the
+                            # centre's waits for nothing
+                            self._count_tree_lanes(est, lanes)
                 del ctx  # free the binned matrix before the next group
             if fuse_failures:
                 import logging

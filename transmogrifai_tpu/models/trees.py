@@ -542,7 +542,8 @@ class _TreeEstimator(PredictorEstimator):
 
 
 def _feature_frac(strategy: str, n_feat: int, classification: bool) -> float:
-    """Spark featureSubsetStrategy -> fraction (RandomForest.scala defaults)."""
+    """Spark featureSubsetStrategy -> fraction (RandomForest.scala defaults);
+    ops/trees.features_per_node turns it into Spark's count, its ceiling."""
     if strategy == "all":
         return 1.0
     if strategy == "auto":
@@ -576,6 +577,18 @@ def forest_lane_route_ok(est, n_rows: int, n_feat: int, n_folds: int,
     plan = getattr(est, "forest_lane_plan", None)
     return plan is not None and plan(n_rows, n_feat, n_folds,
                                      multiclass=multiclass)[0] > 0
+
+
+def forest_payload_body(est) -> str:
+    """How `est`'s lane route carries its payload g = weight x label into
+    the fused passes' bfloat16 contraction — a key of
+    ops/trees.FOREST_PAYLOAD_PARTS: "indicator" for a class label (0/1: g
+    exact in one part, three rows a (lane, slot)), "centred_parts" for a
+    real-valued one (the label less its weighted mean, g as three exact
+    parts, five rows). THE word: the plan, the fit, the `forest_group`
+    span, `last_tree_telemetry` and the benchmark (which asks before it
+    makes any data) all read it here."""
+    return "indicator" if est.classification else "centred_parts"
 
 
 class _ForestBase(_TreeEstimator):
@@ -660,7 +673,8 @@ class _ForestBase(_TreeEstimator):
         depth = int(self.get_param("max_depth"))
         group = pallas_hist.plan_forest_group(
             n_rows, n_feat, int(self.get_param("max_bins")) + 1, n_folds,
-            cfg["n_trees"], depth)
+            cfg["n_trees"], depth,
+            T.forest_payload_rows(forest_payload_body(self)))
         if group == 0:
             return 0, (f"depth {depth}: plan_forest_group refuses the "
                        f"slot-dense output block of its deepest level")
@@ -702,6 +716,14 @@ class _ForestBase(_TreeEstimator):
         W = masks * w[None, :]
         votes = jnp.zeros((folds, n), jnp.float32)
         groups = -(-n_trees // group)
+        body = forest_payload_body(self)
+        rows = T.forest_payload_rows(body)
+        per_node = T.features_per_node(cfg["feature_frac"], n_feat)
+        # the label's [centre, scale]: device scalars the lanes shift and
+        # divide by and the sums and leaves get back; the fit fetches
+        # neither
+        centre = T.forest_label_centre(y, w) \
+            if body == "centred_parts" else None
         for gi in range(groups):
             with collector.trace_span(
                     "forest_bootstrap", kind="tree_fused", trees=group,
@@ -715,17 +737,22 @@ class _ForestBase(_TreeEstimator):
                     lanes=group * folds, trees=group, folds=folds,
                     depth=depth,
                     slot_passes=sum(T.fused_level_slots(depth)),
-                    route_node_rows=pallas_hist.route_node_rows(depth)):
+                    route_node_rows=pallas_hist.route_node_rows(depth),
+                    payload_body=body, payload_rows=rows,
+                    features_per_node=per_node):
                 votes, _, _ = T.fit_forest_lanes(
                     Xb, y, W, rw, node_keys, votes, depth=depth,
                     n_bins=n_bins, feature_frac=cfg["feature_frac"],
                     min_instances=float(
                         self.get_param("min_instances_per_node")),
-                    min_info_gain=min_info_gain)
+                    min_info_gain=min_info_gain, payload=body,
+                    centre=centre)
         self.last_lane_telemetry = dict(
             tree_lanes=n_trees * folds, lane_groups=groups,
             lanes_per_group=group * folds,
-            bootstrap_draws=groups * group * n)
+            bootstrap_draws=groups * group * n, payload_body=body,
+            payload_rows=rows, features_per_node=per_node,
+            label_centre=centre)
         return T.forest_vote_scores(votes, n_trees=n_trees,
                                     classification=self.classification)
 

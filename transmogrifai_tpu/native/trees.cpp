@@ -234,7 +234,7 @@ void grow_tree(const XbT* Xb, int64_t N, int F, const float* G,
           // per-node feature subset (Spark featureSubsetStrategy):
           // partial Fisher-Yates drawing kf distinct features, in live
           // (sorted-rel) order so the RNG stream is deterministic
-          int kf = std::max(1, (int)std::lround(P.feature_frac * F));
+          int kf = std::min(F, std::max(1, (int)std::ceil(P.feature_frac * F - 1e-9)));
           std::fill(node_fmask.begin(), node_fmask.end(), 0);
           std::vector<int> ids(F);
           for (int f = 0; f < F; ++f) ids[f] = f;

@@ -303,9 +303,24 @@ def available() -> bool:
 
 # Histogram contraction input dtype. bf16 doubles the MXU ceiling (the
 # fused fold fit runs near the f32 matmul peak); the one-hot operand is
-# EXACT in bf16 (0/1) and counts stay integer-exact (1.0 payloads, f32
-# accumulation) — only the g/h payload channels quantize (~0.4%
-# relative). set_hist_bf16(False) is the lever of the float32 parity tests.
+# EXACT in bf16 (0/1), counts stay integer-exact (1.0 payloads, f32
+# accumulation) and so does every payload row whose values are integers
+# under 256: the weight row h under unit sample weights (bootstrap draw x
+# fold mask), and g = h x a 0/1 label — what a forest classifier's lanes
+# carry. A real-valued g row is rounded ONCE to bfloat16 (2^-9 of each
+# value's size) unless the CALLER asks for `payload_parts` = 3: then the
+# kernels cut the g rows into three bfloat16 parts whose sum is the
+# float32 value (_unit_cuts: every product exact) and issue 5 rows a
+# (lane, slot) where they issue 3. The cuts are FIXED-POINT for a payload
+# the caller has scaled into [-1, 1] (a power of two: exact): whole
+# multiples of 2^-7 and of 2^-15, whose float32 sums over millions of rows
+# are themselves exact, and a rest under 2^-16 — so a histogram sum is
+# the float64 sum to ~2^-25 of the scale a row, not float32's rounding
+# of a long accumulation. Who decides: ops/trees.fit_forest_lanes from
+# its `payload` argument (a regression forest's centred, scaled label
+# takes three parts); the boosters' gradients and a weight row under
+# real-valued sample weights still go as one part. set_hist_bf16(False) is
+# the lever of the float32 parity tests.
 _HIST_BF16 = True
 
 
@@ -345,20 +360,68 @@ def _feature_onehot(xf, *, F, B, blk, use_bf16):
     return oh.reshape(F * B, blk)
 
 
-def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count):
+def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts=1):
     """Fold k's payload rows, with the unit-count channel derived in VMEM
     when derive_count: count = (h > 0) on the LAST input channel (the
     hessian) — exactly grow_tree's count_unit, computed on the VPU
-    instead of streamed as its own HBM plane."""
+    instead of streamed as its own HBM plane. `parts` = 3 (a real-valued
+    payload in bf16 mode): the channels before the last go as three
+    bfloat16 parts each (_unit_cuts), part-major, ahead of the last
+    channel and the count (payload_rows(C, parts, derive_count) rows)."""
     pay = pay_ref[k * C:(k + 1) * C, :]                     # [C, blk] f32
     if derive_count:
         cnt = (pay[C - 1:C, :] > 0.0).astype(jnp.float32)
         pay = jnp.concatenate([pay, cnt], axis=0)           # [C+1, blk]
+    if parts == 3:
+        pay = jnp.concatenate(
+            _unit_cuts(pay[:C - 1, :]) + [pay[C - 1:, :]], axis=0)
     return pay.astype(mxu_dtype)
 
 
+def _unit_cuts(x):
+    """float32 x as three float32 arrays whose sum is x, each exact in
+    bfloat16 while |x| <= 1: the nearest multiple of 2^-7 (a whole number
+    of at most eight bits over 128), the nearest multiple of 2^-15 of what
+    is left (the same over 2^15), and the rest, under 2^-16, which the cast
+    rounds at 2^-25. Sums of the first two over millions of rows are whole
+    numbers under 2^24 in their own units: float32 adds them exactly. Past
+    |x| = 1 the casts round the first two as well and the three still hold
+    x's 24 bits. floor(. + 0.5), not round: Mosaic lowers it everywhere."""
+    hi = jnp.floor(x * 128.0 + 0.5) * (1.0 / 128.0)
+    hi = hi.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = x - hi
+    mid = jnp.floor(rest * 32768.0 + 0.5) * (1.0 / 32768.0)
+    mid = mid.astype(jnp.bfloat16).astype(jnp.float32)
+    return [hi, mid, rest - mid]
+
+
+def payload_rows(C: int, parts: int, derive_count: bool) -> int:
+    """Rows a (lane, slot) of the contraction's left operand: the C input
+    channels, the derived count, and parts - 1 more for each channel
+    before the last. `parts` is 1 or 3: what _fold_payload cuts."""
+    if parts not in (1, 3):
+        raise ValueError(f"payload_parts {parts}: one part or three")
+    return C + (1 if derive_count else 0) + (parts - 1) * (C - 1)
+
+
+def _sum_parts(hist, *, C, parts, derive_count):
+    """[lanes * slots * payload_rows, F * B] as the kernels leave it under
+    `parts` > 1 -> the [lanes * slots * Co, F * B] of one part: each cut
+    channel's parts added, the smallest first."""
+    if parts == 1:
+        return hist
+    h = hist.reshape(-1, payload_rows(C, parts, derive_count),
+                     hist.shape[1])
+    cut = h[:, :parts * (C - 1)].reshape(-1, parts, C - 1, hist.shape[1])
+    g = cut[:, -1]
+    for p in range(parts - 2, -1, -1):
+        g = g + cut[:, p]
+    return jnp.concatenate([g, h[:, parts * (C - 1):]], axis=1) \
+        .reshape(-1, hist.shape[1])
+
+
 def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
-            n_folds, use_bf16=False, derive_count=False):
+            n_folds, use_bf16=False, derive_count=False, parts=1):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -375,14 +438,14 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
     # dominant VPU cost — and the Xb traffic are built once for all folds,
     # and the matmul M dim grows n_folds x (the single-fold M of S*C rows
     # is far below the 128-row MXU tile; see BENCH_NOTES round-4 session 2)
-    Co = C + (1 if derive_count else 0)
+    Co = payload_rows(C, parts, derive_count)
     slots = jax.lax.broadcasted_iota(jnp.int32, (n_slots, blk), 0) \
         .astype(jnp.float32)
     qs = []
     for k in range(n_folds):
         slot = slot_ref[k:k + 1, :]                         # [1, blk]
         slot_oh = (slots == slot).astype(mxu_dtype)         # [n_slots, blk]
-        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count)
+        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts)
         qs.append((slot_oh[:, None, :] * pay[None, :, :])
                   .reshape(n_slots * Co, blk))
     q = qs[0] if n_folds == 1 else jnp.concatenate(qs, axis=0)
@@ -395,7 +458,8 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
 def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
                 *, n_slots: int, n_bins: int, interpret: bool = False,
                 allow_bf16: bool = False, derive_count: bool = False,
-                unit_payload: bool = False) -> jax.Array:
+                unit_payload: bool = False,
+                payload_parts: int = 1) -> jax.Array:
     """Histograms [n_folds * n_slots * Co, F * n_bins] (f32) of payload sums.
 
     Xb_t [F, N] int bins; pay_t [n_folds * C, N] f32 payload channels;
@@ -415,9 +479,14 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
     channel > 0): grow_tree's count_unit without an HBM plane (Co = C + 1).
 
     allow_bf16: bf16 contraction INPUTS (f32 accumulation) when the module
-    flag agrees (_HIST_BF16) — the tree fits take it (one-hots and counts
-    exact, g/h quantize ~0.4%); the rank metrics keep f32 weights. Resolved
-    OUTSIDE the jit, so set_hist_bf16 cannot serve stale-dtype programs.
+    flag agrees (_HIST_BF16) — the tree fits take it: one-hots, counts and
+    integer payload rows under 256 exact, a real-valued row rounded once to
+    bfloat16 unless `payload_parts` = 3 cuts the channels before the last
+    into three exact bfloat16 parts (_unit_cuts: fixed-point for values the
+    caller scaled into [-1, 1], their sums exact; the parts are summed
+    here, the layout stays Co rows a slot); the rank metrics keep f32
+    weights. Resolved OUTSIDE the jit, so set_hist_bf16 cannot serve
+    stale-dtype programs.
     """
     use_bf16 = allow_bf16 and _HIST_BF16
     kw = dict(n_slots=n_slots, n_bins=n_bins, interpret=interpret,
@@ -427,14 +496,15 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
     if two.hist_body(n_bins, use_bf16) == "two_level":
         return two._hist_two_level_jit(
             Xb_t, pay_t, slot_t, parts=two.payload_parts(unit_payload), **kw)
-    return _hist_pallas_jit(Xb_t, pay_t, slot_t, use_bf16=use_bf16, **kw)
+    return _hist_pallas_jit(Xb_t, pay_t, slot_t, use_bf16=use_bf16,
+                            parts=payload_parts if use_bf16 else 1, **kw)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_slots", "n_bins", "interpret",
-                                    "use_bf16", "derive_count"))
+                                    "use_bf16", "derive_count", "parts"))
 def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
-                     interpret, use_bf16, derive_count=False):
+                     interpret, use_bf16, derive_count=False, parts=1):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -444,7 +514,7 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
         raise ValueError(f"pay_t channels {pay_t.shape[0]} not a multiple "
                          f"of slot_t folds {n_folds}")
     C = pay_t.shape[0] // n_folds
-    Co = C + (1 if derive_count else 0)
+    Co = payload_rows(C, parts, derive_count)
     B = n_bins
     blk = block_rows(F * B)
     pad = (-N) % blk
@@ -457,8 +527,8 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
 
     kernel = functools.partial(_kernel, F=F, B=B, C=C, n_slots=n_slots,
                                n_folds=n_folds, use_bf16=use_bf16,
-                               derive_count=derive_count)
-    return pl.pallas_call(
+                               derive_count=derive_count, parts=parts)
+    return _sum_parts(pl.pallas_call(
         kernel,
         grid=(N // blk,),
         in_specs=[
@@ -476,7 +546,7 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
             (n_folds * n_slots * Co, F * B), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(Xb_t, pay_t, slot_t)
+    )(Xb_t, pay_t, slot_t), C=C, parts=parts, derive_count=derive_count)
 
 
 def _hist_segment_jnp(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
@@ -516,18 +586,20 @@ def _hist_segment_jnp(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
 
 def hist_folds(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array, *,
                n_slots: int, n_bins: int, interpret: bool = False,
-               allow_bf16: bool = False,
-               derive_count: bool = False) -> jax.Array:
+               allow_bf16: bool = False, derive_count: bool = False,
+               payload_parts: int = 1) -> jax.Array:
     """Batched multi-(fold x lane) histogram dispatcher: the VMEM pallas
     kernel on a live TPU (or in interpret mode for tests), the pure-jnp
     segment-sum fallback everywhere else — same signature and output
     layout as hist_pallas, so CPU CI exercises the exact call shape the
-    TPU sweep runs."""
+    TPU sweep runs. The twin sums float32 payloads as they are, which is
+    what `payload_parts` = 3 gives the kernel."""
     if interpret or available():
         return hist_pallas(Xb_t, pay_t, slot_t, n_slots=n_slots,
                            n_bins=n_bins, interpret=interpret,
                            allow_bf16=allow_bf16,
-                           derive_count=derive_count)
+                           derive_count=derive_count,
+                           payload_parts=payload_parts)
     return _hist_segment_jnp(Xb_t, pay_t, slot_t, n_slots=n_slots,
                              n_bins=n_bins, derive_count=derive_count)
 
@@ -754,7 +826,7 @@ def route(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
 def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
                        node_out_ref, *, F: int, B: int, C: int, n_nodes: int,
                        n_r: int, n_folds: int,
-                       use_bf16=False, derive_count=False):
+                       use_bf16=False, derive_count=False, parts=1):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -770,7 +842,7 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
                           n_folds=n_folds)
     slots = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, blk), 0) \
         .astype(jnp.float32)
-    Co = C + (1 if derive_count else 0)
+    Co = payload_rows(C, parts, derive_count)
     rows, qs = [], []
     for k in range(n_folds):
         node = node_ref[k:k + 1, :]                         # [1, blk]
@@ -781,7 +853,7 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
         # the same dropped-slot encoding hist_pallas uses for padding
         slot_oh = (slots == node + float(n_nodes) * rightf) \
             .astype(mxu_dtype)                              # [n_nodes, blk]
-        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count)
+        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts)
         qs.append((slot_oh[:, None, :] * pay[None, :, :])
                   .reshape(n_nodes * Co, blk))
     q = qs[0] if n_folds == 1 else jnp.concatenate(qs, axis=0)
@@ -794,10 +866,10 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("n_nodes", "n_bins", "interpret",
-                                    "use_bf16", "derive_count"))
+                                    "use_bf16", "derive_count", "parts"))
 def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
                            n_nodes, n_bins, interpret, use_bf16,
-                           derive_count=False):
+                           derive_count=False, parts=1):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -808,7 +880,7 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
         raise ValueError(f"pay_t channels {pay_t.shape[0]} not a multiple "
                          f"of node_t folds {Fo}")
     C = pay_t.shape[0] // Fo
-    Co = C + (1 if derive_count else 0)
+    Co = payload_rows(C, parts, derive_count)
     B = n_bins
     sel, tm, n_r = _route_tables(f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
                                  n_feat=F)
@@ -826,7 +898,8 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
 
     kernel = functools.partial(_route_hist_kernel, F=F, B=B, C=C,
                                n_nodes=n_nodes, n_r=n_r, n_folds=Fo,
-                               use_bf16=use_bf16, derive_count=derive_count)
+                               use_bf16=use_bf16, derive_count=derive_count,
+                               parts=parts)
     hist, node_out = pl.pallas_call(
         kernel,
         grid=(N // blk,),
@@ -855,13 +928,15 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(Xb_t, pay_t, node_t, sel, tm)
-    return hist, node_out[:, :n_orig]
+    return _sum_parts(hist, C=C, parts=parts, derive_count=derive_count), \
+        node_out[:, :n_orig]
 
 
 def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
                f_lvl: jax.Array, t_lvl: jax.Array, m_lvl: jax.Array, *,
                n_nodes: int, n_bins: int, interpret: bool = False,
-               allow_bf16: bool = False, derive_count: bool = False):
+               allow_bf16: bool = False, derive_count: bool = False,
+               payload_parts: int = 1):
     """Route one level AND histogram the next level's left children in a
     single pass over the binned matrix, for every (fold x config) lane.
 
@@ -874,14 +949,15 @@ def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
     layout) — and new_node [Fo, N] = 2*node + right, bitwise what
     route_pallas returns. On CPU the jnp fallback chains the gather-form
     route with the segment-sum histogram (identical decisions; histogram
-    equal up to f32 summation order).
+    equal up to f32 summation order). `payload_parts` as in hist_pallas.
     """
     if interpret or available():
+        use_bf16 = allow_bf16 and _HIST_BF16
         return _route_hist_pallas_jit(
             Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
-            n_bins=n_bins, interpret=interpret,
-            use_bf16=allow_bf16 and _HIST_BF16,
-            derive_count=derive_count)
+            n_bins=n_bins, interpret=interpret, use_bf16=use_bf16,
+            derive_count=derive_count,
+            parts=payload_parts if use_bf16 else 1)
     return _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl,
                            n_nodes=n_nodes, n_bins=n_bins,
                            derive_count=derive_count)
@@ -1018,7 +1094,7 @@ _FOREST_LANE_PLANES = 5
 
 
 def plan_forest_group(n_rows: int, n_feat: int, n_bins: int, n_folds: int,
-                      n_trees: int, depth: int) -> int:
+                      n_trees: int, depth: int, payload_rows: int = 3) -> int:
     """Trees a lane group of the forest route: the most whose (tree x
     fold) lanes clear plan_fused_hist, the output-block cap and 7/16 of
     the device's HBM in lane planes (rows count here: the planes are what
@@ -1026,13 +1102,16 @@ def plan_forest_group(n_rows: int, n_feat: int, n_bins: int, n_folds: int,
     20 trees at a most of 6 a group make 4 groups of 5, not 3 of 6 and a
     2. 0: not even one tree's fold lanes fit (depth 12: the slot-dense
     output block alone is 130 MB), and the caller keeps its sequential
-    path. `n_bins` counts the missing-value bin."""
+    path. `n_bins` counts the missing-value bin; `payload_rows` the rows a
+    (lane, slot) the kernels issue (3, or 5 under a three-part payload:
+    the output block the kernel holds grows with them, the lanes a group
+    shrink)."""
     from ..utils.platform import device_spec
     spec = device_spec()
 
     def ok(trees: int) -> bool:
         lanes = trees * n_folds
-        plan = plan_fused_hist(n_feat, n_bins, lanes, depth)
+        plan = plan_fused_hist(n_feat, n_bins, lanes, depth, payload_rows)
         planes = _FOREST_LANE_PLANES * (-(-lanes // 8) * 8) * n_rows * 4
         return (plan.fits and plan.out_bytes <= _FOREST_OUT_BLOCK_BYTES
                 and (spec is None or planes <= spec.hbm_bytes * 7 // 16))

@@ -44,6 +44,7 @@ Design (TPU-first, not a port):
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -375,11 +376,21 @@ def _split_scores(GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm, reg_lambda,
     return jnp.stack([g_left, g_right], axis=-1)
 
 
+def features_per_node(feature_frac: float, n_feat: int) -> int:
+    """Columns a node's subset holds: Spark's count, the CEILING of the
+    fraction of the columns (DecisionTreeMetadata: `auto` / `onethird` of
+    64 columns are 22, sqrt of 64 is 8), at least one. The native builder
+    (native/trees.cpp) takes the same ceiling. The 1e-9 keeps a fraction
+    that float arithmetic left a hair over a whole count (sqrt(9) / 9 x 9)
+    from gaining a column."""
+    return min(max(1, math.ceil(feature_frac * n_feat - 1e-9)), n_feat)
+
+
 def _feature_mask(key: jax.Array, n_nodes: int, n_feat: int,
                   feature_frac: float) -> jax.Array:
     """Per-node random feature subset mask [n_nodes, F] (RF column sampling,
     Spark featureSubsetStrategy applied per node)."""
-    k = max(1, int(round(feature_frac * n_feat)))
+    k = features_per_node(feature_frac, n_feat)
     if k >= n_feat:
         return jnp.ones((n_nodes, n_feat), bool)
     scores = jax.random.uniform(key, (n_nodes, n_feat))
@@ -1030,12 +1041,15 @@ def _fold_split_scores(reg_lambda, min_child_weight, gamma):
 
 
 def _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
-                  learning_rate, leaf_mode="newton"):
+                  learning_rate, leaf_mode="newton", leaf_offset=None):
     """Per-fold leaves from leaf sufficient statistics [Fo, L(, K)] —
     the one leaf rule of the fused growth form: newton steps for the
-    boosters, grow_tree's weighted mean G / H for forest lanes."""
+    boosters, grow_tree's weighted mean G / H for forest lanes, plus
+    `leaf_offset` where the caller took it out of G (a centred label)."""
     if leaf_mode == "mean":
         leaf = Gl / (Hl + EPS)[..., None]
+        if leaf_offset is not None:
+            leaf = leaf + leaf_offset
     else:
         rl_col = reg_lambda[:, None] \
             if getattr(reg_lambda, "ndim", 0) == 1 else reg_lambda
@@ -1049,7 +1063,7 @@ def _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
 
 
 def _fold_leaves(last, *, n_leaves, reg_lambda, alpha, max_delta_step,
-                 learning_rate, leaf_mode="newton"):
+                 learning_rate, leaf_mode="newton", leaf_offset=None):
     """Leaf payloads [Fo, n_leaves, 1] read off the LAST level's
     cumulative histograms (`last` as produced by the level split) — same
     free-leaf trick as grow_tree's leaf pass, vmapped over folds."""
@@ -1072,7 +1086,7 @@ def _fold_leaves(last, *, n_leaves, reg_lambda, alpha, max_delta_step,
     Gl, Hl, Cl = jax.vmap(leaf_of)(GL, HL, CL, Gt, Ht, Ct, Gm, Hm, Cm,
                                    f_lvl, t_lvl, m_lvl)
     return _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha, max_delta_step,
-                         learning_rate, leaf_mode)
+                         learning_rate, leaf_mode, leaf_offset)
 
 
 def level_slots(depth: int) -> tuple:
@@ -1097,7 +1111,8 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                      level_feature_frac=1.0, level_key=None,
                      feature_mask_count=None, axis_name=None,
                      normalize_gain=False, leaf_mode="newton",
-                     node_feature_frac=1.0, node_keys=None):
+                     node_feature_frac=1.0, node_keys=None,
+                     payload_parts=1, payload_scale=None, leaf_offset=None):
     """Grow one tree PER FOLD level-wise in shared fused passes.
 
     Xb_t [F, N] transposed bins (N pre-padded to the route block size by
@@ -1160,13 +1175,22 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     last = None
     prev = None
     hist = None
+
+    def unscaled(h):
+        # the g rows back in the label's units (a power of two: exact)
+        if payload_scale is None:
+            return h
+        h = h.reshape(Fo, -1, 3, F * B)
+        return h.at[:, :, 0].multiply(payload_scale).reshape(-1, F * B)
+
     for d, n_nodes in enumerate(level_slots(depth)):
         if d == 0:
             # root histogram: all rows slot 0, one plain batched pass
-            hist = _allreduce(pallas_hist.hist_folds(
+            hist = _allreduce(unscaled(pallas_hist.hist_folds(
                 Xb_t, pay, node, n_slots=1, n_bins=B,
                 interpret=interpret, allow_bf16=True,
-                derive_count=True), axis_name)            # [Fo*1*3, F*B]
+                derive_count=True, payload_parts=payload_parts)),
+                axis_name)                                # [Fo*1*3, F*B]
             n_slots = 1
         else:
             # `hist` holds the LEFT-child histograms of THIS level,
@@ -1240,8 +1264,8 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
             hist, node = pallas_hist.route_hist(
                 Xb_t, pay, node, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
                 n_bins=B, interpret=interpret, allow_bf16=True,
-                derive_count=True)
-            hist = _allreduce(hist, axis_name)
+                derive_count=True, payload_parts=payload_parts)
+            hist = _allreduce(unscaled(hist), axis_name)
         else:
             # final level: no further histogram — plain routing pass to
             # land every row on its leaf
@@ -1255,11 +1279,13 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
         Cl = _allreduce((H > 0).astype(jnp.float32).sum(axis=1),
                         axis_name)[:, None]
         leaf = _leaf_payload(Gl, Hl, Cl, reg_lambda, alpha,
-                             max_delta_step, learning_rate, leaf_mode)
+                             max_delta_step, learning_rate, leaf_mode,
+                             leaf_offset)
     else:
         leaf = _fold_leaves(last, n_leaves=n_leaves, reg_lambda=reg_lambda,
                             alpha=alpha, max_delta_step=max_delta_step,
-                            learning_rate=learning_rate, leaf_mode=leaf_mode)
+                            learning_rate=learning_rate, leaf_mode=leaf_mode,
+                            leaf_offset=leaf_offset)
     leaf_rows = pallas_hist.table_lookup(
         leaf[:, :, 0], node, interpret=interpret)         # [Fo, N]
     tree = Tree(jnp.concatenate(feats, axis=1),
@@ -1458,13 +1484,55 @@ def forest_bootstrap(key: jax.Array, start, subsample, *, n_rows: int,
     return rw * live[:, None].astype(jnp.float32), kf
 
 
+#: How a forest lane's payload g = weight x label reaches the bfloat16
+#: contraction, by the word models/trees.forest_payload_body gives an
+#: estimator: the bfloat16 parts of g. "indicator": a 0/1 label, g an
+#: integer under 256 under unit sample weights, exact in ONE part and
+#: today's three rows a (lane, slot). "centred_parts": a real-valued label
+#: less its weighted mean and over a power of two that brings g into
+#: [-1, 1] (forest_label_centre), g as THREE fixed-point parts, five rows
+#: a (lane, slot) — every product exact, the parts' sums exact; the
+#: variance gain does not see the shift, and the leaves get it back.
+FOREST_PAYLOAD_PARTS = {"indicator": 1, "centred_parts": 3}
+
+#: the largest bootstrap draw the payload's scale leaves room for (a
+#: Poisson(1) draw passes it once in ~10^14; past it the parts round, as
+#: pallas_hist._unit_cuts says)
+_PAYLOAD_DRAW_ROOM = 16.0
+
+
+def forest_payload_rows(payload: str) -> int:
+    """Rows a (lane, slot) the fused passes issue under this payload body:
+    g's parts, the weight and the derived count."""
+    from . import pallas_hist
+    return pallas_hist.payload_rows(2, FOREST_PAYLOAD_PARTS[payload], True)
+
+
+@jax.jit
+def forest_label_centre(y: jax.Array, w: jax.Array) -> jax.Array:
+    """[centre, scale] of a real-valued label's payload. The centre is its
+    weighted mean over all rows, rounded to bfloat16's eight significant
+    bits so that y - centre is exact wherever the two lie within a factor
+    of two of each other; the scale the power of two at or over
+    _PAYLOAD_DRAW_ROOM x the largest w x |y - centre|, which brings every
+    lane's weight x (y - centre) into [-1, 1]. One pair for every fold and
+    tree of a sweep: the variance gain is the same about any constant."""
+    c = (w * y).sum() / jnp.maximum(w.sum(), EPS)
+    c = jax.lax.reduce_precision(c, exponent_bits=8, mantissa_bits=7)
+    top = _PAYLOAD_DRAW_ROOM * jnp.max(w * jnp.abs(y - c))
+    scale = jnp.exp2(jnp.ceil(jnp.log2(jnp.maximum(top, 2.0 ** -100))))
+    return jnp.stack([c, scale])
+
+
 @functools.partial(jax.jit, static_argnames=("depth", "n_bins",
-                                             "feature_frac", "interpret"))
+                                             "feature_frac", "interpret",
+                                             "payload"))
 def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
                      rw: jax.Array, node_keys: jax.Array, votes: jax.Array,
                      *, depth: int, n_bins: int, feature_frac: float = 1.0,
                      min_instances=1.0, min_info_gain=0.0,
-                     interpret: bool = False):
+                     interpret: bool = False, payload: str = "indicator",
+                     centre=None):
     """Grow one GROUP of a forest's trees for every CV fold in the fused
     passes and add their votes: lanes = (tree, fold), tree-major.
 
@@ -1479,10 +1547,23 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
     are Spark's (normalised by the node's weight, `min_instances`,
     `min_info_gain`), leaves weighted means.
 
+    `payload` (FOREST_PAYLOAD_PARTS; the CALLER vouches for it) says how
+    g = weight x y enters the kernels' bfloat16 contraction. "indicator":
+    y is 0 or 1, g rounded once — exact while the weights are integers
+    under 256 (unit sample weights), else rounded at 2^-9 of each value
+    like the weight row itself. "centred_parts": y real-valued and
+    `centre` = forest_label_centre's [centre, scale]; g = weight x (y -
+    centre) / scale goes as three fixed-point bfloat16 parts whose sum it
+    is, so every histogram sum the splits and the leaves are read from is
+    the sum of exact products added exactly (to ~2^-25 of the scale a row),
+    and a leaf is centre + G / H.
+
     `min_info_gain` is compared with THIS payload's gain: the variance
-    gain of one channel. For a binary label that is half the two-class
-    Gini gain grow_tree sums over a [w (1 - y), w y] payload, so a caller
-    holding Spark's minInfoGain passes half of it (models/trees).
+    gain of one channel — for a regression target Spark's own (Variance
+    impurity, a weighted row), unhalved. For a binary label that is half
+    the two-class Gini gain grow_tree sums over a [w (1 - y), w y]
+    payload, so a caller holding Spark's minInfoGain passes half of it
+    (models/trees).
 
     Returns (votes + sum over the group's trees of the leaf value each
     row lands on, read off the final routing state — no tree is traversed
@@ -1491,8 +1572,13 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
     from . import pallas_hist
     folds, n_orig = W.shape
     pad = (-n_orig) % pallas_hist._ROUTE_BLK
+    centred = payload == "centred_parts"
     H = (rw[:, None, :] * W[None, :, :]).reshape(-1, n_orig)  # [T*folds, N]
-    G = H * y[None, :]
+    if centred:
+        centre, scale = jnp.asarray(centre, jnp.float32)
+        G = H * ((y - centre) * (1.0 / scale))[None, :]
+    else:
+        G, scale = H * y[None, :], None
     if pad:  # inert: zero payloads, as in _fit_gbt_folds_impl
         Xb = jnp.pad(Xb, ((0, pad), (0, 0)))
         G = jnp.pad(G, ((0, 0), (0, pad)))
@@ -1503,7 +1589,8 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
         min_info_gain=min_info_gain, gamma=0.0, learning_rate=1.0,
         feature_mask=None, interpret=interpret, normalize_gain=True,
         leaf_mode="mean", node_feature_frac=feature_frac,
-        node_keys=node_keys)
+        node_keys=node_keys, payload_parts=FOREST_PAYLOAD_PARTS[payload],
+        payload_scale=scale, leaf_offset=centre if centred else None)
     group_votes = leaf_rows[:, :n_orig].reshape(-1, folds, n_orig).sum(0)
     return votes + group_votes, trees, subsets
 
