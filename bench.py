@@ -1240,7 +1240,7 @@ alphas = np.zeros(4, np.float32)
 jax.block_until_ready(GS.sweep_glm_squared_gram_sharded(
     mesh, Xl, yl, wl, masks, regs, alphas, max_iter=8))
 t0 = time.perf_counter()
-B, b0, iters, _ = GS.sweep_glm_squared_gram_sharded(
+B, b0, iters, *_ = GS.sweep_glm_squared_gram_sharded(
     mesh, Xl, yl, wl, masks, regs, alphas, max_iter=8)
 jax.block_until_ready(B)
 glm_wall = time.perf_counter() - t0

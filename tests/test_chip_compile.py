@@ -332,11 +332,16 @@ def test_heldout_metric_pass_compiles_for_the_four_chip_mesh(
 def test_regression_sweep_programs_compile_for_a_v5e(one_chip,
                                                      metric_programs, d):
     """sweep-linreg-nulls128's programs at 25M rows, and the same at the
-    width the chip keeps rows-minor: the Gram pass reads X in place (no
-    temporaries at all: a padded, transposed or float32 copy of X would be
-    6.4 GB or more) and the held-out pass of sums holds a few [rows]
-    vectors (under 5 % of the 128-column X), neither a [Gc, rows] array of
-    scores; the moment-space solves hold nothing of the rows' size."""
+    width the chip keeps rows-minor: the Gram pass reads X in place (a
+    block's temporaries, a few MB: a padded, transposed or float32 copy of
+    X would be 6.4 GB or more), its two raw branches contract bfloat16
+    operands at the default precision (the block against 1 and against 3
+    parts of the fold-weighted block, and each one's first-order sums) and
+    `highest` is the float32 branch's, under the guard; the held-out pass
+    of sums holds a few [rows] vectors (under 5 % of the 128-column X),
+    neither a [Gc, rows] array of scores; the moment-space solves hold
+    nothing of the rows' size."""
+    import re
     n, F, Gc = 25_000_000, 5, 8
     x_bytes = n * d * 2
 
@@ -345,8 +350,18 @@ def test_regression_sweep_programs_compile_for_a_v5e(one_chip,
     gram = GS.sweep_gram_moments.lower(
         S((n, d), BF16), S((n,)), S((n,)), S((F, n)), S((d,)), S((d,))
     ).compile()
-    assert gram.memory_analysis().temp_size_in_bytes <= 0.05 * x_bytes
-    assert "highest" in gram.as_text()
+    assert gram.memory_analysis().temp_size_in_bytes <= 16 << 20
+    text = gram.as_text()
+    dots = [ln for ln in text.splitlines() if " convolution(" in ln]
+    blocks = [ln for ln in dots if "operand_precision={highest,highest}" in ln]
+    raw = [ln for ln in dots if "highest" not in ln]
+    assert len(blocks) == 3 and len(raw) == 4 and " conditional(" in text
+    for ln in raw:
+        for operand in re.search(r" convolution\((%[\w.-]+), (%[\w.-]+)\)",
+                                 ln).groups():
+            assert re.search(re.escape(operand) + r" = bf16\[", text), ln
+    for parts in (1, 3):
+        assert any(f"= f32[{d},{parts * F * d}]" in ln for ln in raw)
     solve = GS.sweep_gram_solve.lower(
         S((F, d, d)), S((F, d)), S((F, d)), S((F,)), S((F,)), S((d,)),
         S((d,)), S((Gc,)), S((Gc,)), S((), jnp.int32), S(()),

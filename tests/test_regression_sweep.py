@@ -5,7 +5,13 @@ coefficients against a float64 replay of the documented iteration on
 float64 moments, every regression metric of the held-out-once pass against
 the exact value of the sweep's own coefficients and against the per-fold
 route, on one device and on 4 of conftest's host devices, for float32 and
-bfloat16 matrices under a label whose mean lies 5 deviations from zero."""
+bfloat16 matrices under a label whose mean lies 5 deviations from zero;
+and the Gram pass's two bodies (`glm_sweep.gram_pass_body`): the raw
+bfloat16 products standardised in moment space against float64 sums and
+against the float32 blocks, which float32 matrices, far column means and
+feature-tiled widths keep to the last bit."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +25,8 @@ from transmogrifai_tpu.models.glm import OpLinearRegression
 from transmogrifai_tpu.ops import glm as G
 from transmogrifai_tpu.ops import glm_sweep as GS
 from transmogrifai_tpu.ops import metrics_ops as M
-from transmogrifai_tpu.parallel.mesh import batch_sharding, make_mesh
+from transmogrifai_tpu.parallel.mesh import (BATCH_AXIS, batch_sharding,
+                                             make_mesh, sharded_along)
 
 N, FOLDS = 8000, 5
 GRIDS = [dict(reg_param=r, elastic_net_param=a)
@@ -250,3 +257,255 @@ def test_regression_metrics_of_the_pass_are_those_of_the_vmapped_kernel(
         jnp.asarray(pred, jnp.float32), jnp.asarray(y, jnp.float32),
         jnp.asarray(1.0 - masks[0])).r2)
     assert 0.3 < r2[0, 0] < 0.9 and abs(r2[0, 0] - want) < 2e-3
+
+
+# -- the Gram pass's two bodies ----------------------------------------------
+
+_blocks = jax.jit(lambda *a: GS._block_moments(*a, None, lambda v: v))
+
+
+def _table(n, d, F, r, weights, *, dtype=jnp.bfloat16, seed=0):
+    """What `transmogrify()` makes of numeric fields with holes, as the
+    sweep sees it (rounded to `dtype`): numeric columns of deviations 1/32
+    to 16 whose means lie `r` deviations from zero, a tenth of their
+    values a shared fill, every second column a 0/1 null indicator at a
+    rate of 0.001-0.5; a label of mean 10; weights of ones, of powers of
+    two or seeded in 0.5-2; `F` disjoint held-out folds; the columns'
+    float32 moments; and the five sums in float64 of the EXACTLY
+    standardised rows (`R.moments_twin` standardises in float32 as
+    `_block_moments` does: its rounding of an indicator's two values is
+    the same in every row)."""
+    rng = np.random.default_rng(seed)
+    sd = 2.0 ** ((np.arange(d) * 3) % 10 - 5)
+    X = rng.normal(size=(n, d)) * sd + r * sd * rng.choice([-1, 1], d)
+    X = np.where(rng.random((n, d)) < 0.1, X.mean(0), X)
+    X[:, 1::2] = rng.random((n, d // 2)) < np.geomspace(1e-3, 0.5, d // 2)
+    X = jnp.asarray(X.astype(np.float32)).astype(dtype)
+    Xh = np.asarray(X.astype(jnp.float32), np.float64)
+    y = (10.0 + rng.normal(size=n) + Xh[:, 0] / sd[0]).astype(np.float32)
+    w = {"ones": np.ones(n), "pow2": 2.0 ** rng.integers(-1, 2, n),
+         "seeded": rng.uniform(0.5, 2.0, n)}[weights].astype(np.float32)
+    masks = (rng.integers(0, F, n)[None, :]
+             != np.arange(F)[:, None]).astype(np.float32)
+    mean, std = (np.asarray(v) for v in GS.glm_standardize_stats(
+        X, jnp.ones(n, jnp.float32)))
+    xs = (Xh - mean.astype(np.float64)) / std.astype(np.float64)
+    wf = masks.astype(np.float64) * w.astype(np.float64)
+    y64 = y.astype(np.float64)
+    ref = (np.einsum("fn,nd,ne->fde", wf, xs, xs, optimize=True),
+           (wf * y64) @ xs, wf @ xs, wf @ y64, wf.sum(1))
+    return tuple(jnp.asarray(a) for a in (X, y, w, masks, mean, std)), ref
+
+
+def _off(got, ref):
+    """Each sum's worst entry, of its largest."""
+    return [float(np.abs(np.asarray(a, np.float64)
+                         - np.asarray(b, np.float64)).max()
+                  / np.abs(np.asarray(b, np.float64)).max())
+            for a, b in zip(got, ref)]
+
+
+@pytest.mark.parametrize("weights", ["ones", "pow2", "seeded"])
+@pytest.mark.parametrize("r", [0.3, 2.8])
+def test_raw_body_is_the_float64_sums_and_the_float32_blocks(r, weights):
+    """The new body, one part of the weighted rows (fold weights of zeros
+    and powers of two: the program's own look at them) and three (any
+    others): every sum within 1e-5 of its largest entry of the float64
+    sums, and within 2e-5 of today's body, which itself reads 1.1e-5 from
+    them under unit weights (its float32 standardisation of an indicator's
+    two values is off the same way in every row); a weighted operand
+    rounded ONCE to bfloat16 reads 1e-3 on the Gram and 0.1 on the fold's
+    column sums."""
+    args, ref = _table(2048, 128, 5, r, weights)
+    X, y, w, masks, mean, std = args
+    assert GS.gram_pass_body(X.dtype, 128, bool(
+        GS.raw_moments_guard(mean, std))) == GS.GRAM_PASS_RAW
+    got = GS.sweep_gram_moments(*args)
+    assert [a.shape for a in got] == [b.shape for b in ref]
+    assert max(_off(got, ref)) < 1e-5, _off(got, ref)
+    assert max(_off(got, _blocks(*args))) < 2e-5
+    low = R.moments_twin(np.asarray(X.astype(jnp.float32)), y, w, masks,
+                         mean, std, rounded=True)
+    assert _off(low, ref)[0] > 2e-4 and _off(low, ref)[2] > 1e-2
+
+
+@pytest.mark.parametrize("weights, parts", [("ones", 1), ("pow2", 1),
+                                            ("seeded", 3)])
+def test_raw_products_and_their_sums_are_exact(weights, parts):
+    """Values of ONE significant bit, a small integer label: every product
+    and every partial sum is a float32, so the raw pass returns the
+    float64 sums to the last bit whatever the order of the rows — nothing
+    is rounded on the way to the matrix unit; and the parts the weighted
+    rows took are what the weights allow (seeded ones of two bits)."""
+    rng = np.random.default_rng(11)
+    n, d, F = 2048, 128, 3
+    X = rng.choice([0.0, 0.5, 1.0, -2.0, 4.0], size=(n, d))
+    y = rng.integers(-4, 5, n).astype(np.float32)
+    w = {"ones": np.ones(n), "pow2": 2.0 ** rng.integers(-1, 2, n),
+         "seeded": rng.choice([0.75, 1.0, 1.5], n)}[weights] \
+        .astype(np.float32)
+    masks = (rng.integers(0, F, n)[None, :]
+             != np.arange(F)[:, None]).astype(np.float32)
+    zero, one = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    wf = masks.astype(np.float64) * w.astype(np.float64)
+    ref = (np.einsum("fn,nd,ne->fde", wf, X, X), (wf * y) @ X, wf @ X,
+           wf @ y.astype(np.float64), wf.sum(1))
+    assert bool(GS._scale_only_weights(jnp.asarray(masks),
+                                       jnp.asarray(w))) == (parts == 1)
+    for rows in (np.arange(n), rng.permutation(n)):
+        got = GS.sweep_gram_moments(
+            jnp.asarray(X[rows], jnp.bfloat16), jnp.asarray(y[rows]),
+            jnp.asarray(w[rows]), jnp.asarray(masks[:, rows]), zero, one)
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a, np.float64), b)
+
+
+def _far_column(X):
+    """Column 0 constant: its mean 1e6 deviations from zero (the column
+    moments floor a deviation at 1e-6)."""
+    return X.at[:, 0].set(1.0)
+
+
+@pytest.mark.parametrize("case, dtype, d", [
+    ("far_mean", jnp.bfloat16, 128), ("float32", jnp.float32, 128),
+    ("feature_tiles", jnp.bfloat16, 130)])
+def test_todays_body_keeps_what_the_raw_one_cannot_take(case, dtype, d):
+    """A column whose mean is 1e6 of its deviations (under the guard's
+    `lax.cond`), a float32 matrix and a feature-tiled width (static):
+    `gram_pass_body` names today's body and the five sums are its own, bit
+    for bit."""
+    args, _ = _table(1024, d, 3, 0.3, "seeded", dtype=dtype)
+    if case == "far_mean":
+        X = _far_column(args[0])
+        args = (X,) + args[1:4] + GS.glm_standardize_stats(X, args[2])
+    guard = bool(GS.raw_moments_guard(*args[4:]))
+    assert guard == (case != "far_mean")
+    assert GS.gram_pass_body(dtype, d, guard) == GS.GRAM_PASS_BODY
+    got, want = GS.sweep_gram_moments(*args), _blocks(*args)
+    for a, b in zip(got[:4], want[:4]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(got[4]),
+                                  np.maximum(np.asarray(want[4]), GS.EPS))
+
+
+@pytest.mark.parametrize("weights", ["ones", "seeded"])
+def test_mesh_gram_pass_is_the_one_device_pass(weights):
+    """`_gram_moments` inside a shard_map over 4 host devices (each
+    shard's raw sums standardised, then ONE psum) against the one-device
+    program: the five sums to float32 rounding of the psum, and through
+    `sweep_glm_squared_gram_sharded` the same coefficients, the same
+    verdict of the guard."""
+    from jax.sharding import PartitionSpec as P
+    mesh = make_mesh(n_batch=4, n_model=1, devices=jax.devices()[:4])
+    (X, y, w, masks, mean, std), _ = _table(4096, 16, 3, 2.8, weights)
+    one = GS.sweep_gram_moments(X, y, w, masks, mean, std)
+    rows = (jax.device_put(X, batch_sharding(mesh, 2)),
+            jax.device_put(y, batch_sharding(mesh, 1)),
+            jax.device_put(w, batch_sharding(mesh, 1)),
+            jax.device_put(masks, sharded_along(mesh, 1, 2)))
+    four = jax.jit(GS._build_shard_map(
+        lambda *a: GS._gram_moments(*a, axis_name=BATCH_AXIS), mesh,
+        in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
+                  P(None, BATCH_AXIS), P(None), P(None)),
+        out_specs=(P(), P(), P(), P(), P())))(*rows, mean, std)
+    assert max(_off(four, one)) < 1e-6, _off(four, one)
+    regs = jnp.asarray([0.001, 0.01, 0.1, 0.1], jnp.float32)
+    alphas = jnp.asarray([0.0, 0.5, 0.5, 0.0], jnp.float32)
+    fit1 = GS.sweep_glm_squared_gram(X, y, w, masks, regs, alphas)
+    fit4 = GS.sweep_glm_squared_gram_sharded(mesh, *rows, regs, alphas)
+    assert bool(fit1[4]) and bool(fit4[4])
+    for a, b in zip(fit4[:2], fit1[:2]):
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=5e-6 * float(jnp.abs(b).max()))
+    assert int(fit4[2]) == int(fit1[2]) and int(fit4[3]) == int(fit1[3]) == 0
+
+
+@pytest.mark.parametrize("case, body", [
+    ("near_means", "raw_bf16_moments"), ("far_means", "xla_blocks"),
+    ("float32", "xla_blocks")])
+def test_either_body_fetches_twice_and_says_which_it_was(small_routes, case,
+                                                         body):
+    """One `validate()` over OpLinearRegression: the `host_step` spans that
+    fetch are `gram_solve` and `metric_fetch`, one each, whichever body
+    took the moments (the guard's verdict rides with the solves' two
+    counts); `moments_body` on the `gram_pass` span and `gram_moments_body`
+    in the telemetry name it, `body` / `gram_body` stay the kind of
+    program (an XLA loop over row blocks, which both are); and the fit is
+    the float64 replay's either way."""
+    from transmogrifai_tpu.utils.metrics import collector
+    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+    X, y = _data(16, dtype)
+    # `_data`'s own columns lie up to 30 deviations from zero: moved to 2
+    # of them (10 where the guard is to refuse them), rounded to `dtype`
+    X = X - X.mean(0) + (10.0 if case == "far_means" else 2.0) * X.std(0)
+    X = np.asarray(jnp.asarray(X, dtype).astype(jnp.float32), np.float64)
+    collector.disable()     # whatever an earlier test file left behind
+    collector.enable("gram_pass_span")
+    try:
+        val, _, _, (Braw, b0raw) = _sweep(X.astype(np.float32), y, dtype)
+        steps = [s for s in collector.trace.spans if s.kind == "host_step"]
+    finally:
+        collector.finish()
+        collector.disable()
+    names = [s.name for s in steps]
+    assert names.count("gram_solve") == names.count("metric_fetch") == 1
+    assert sorted(names) == ["gram_pass", "gram_solve", "metric_fetch"]
+    gp = next(s.attrs for s in steps if s.name == "gram_pass")
+    tele = val.last_streamed_telemetry
+    assert gp["moments_body"] == tele["gram_moments_body"] == body
+    assert gp["body"] == tele["gram_body"] == GS.GRAM_PASS_BODY
+    assert tele["x_passes"] == 4 and tele["lanes_at_cap"] == 0
+    mean, std = X.mean(0), X.std(0)
+    xs, t = (X - mean) / std, val.fold_masks(y)[0].astype(np.float64)
+    y64 = y.astype(np.float64)
+    m = {"G": (xs * t[:, None]).T @ xs, "sx": t @ xs, "c": (t * y64) @ xs,
+         "sy": float(t @ y64), "sw": float(t.sum())}
+    doc = R.replay(m, GRIDS[0]["reg_param"], GRIDS[0]["elastic_net_param"],
+                   max_iter=50, tol=1e-6)
+    assert np.abs(Braw[0, 0] * std - doc["B"]).max() < 2e-5
+    assert abs(b0raw[0, 0] + (Braw[0, 0] * mean).sum() - doc["b0"]) < 2e-5
+
+
+def _gram_text(dtype, n=4096, d=128, F=3):
+    """The lowered text of a fresh trace of `sweep_gram_moments`' body."""
+    S, f32 = jax.ShapeDtypeStruct, jnp.float32
+    return jax.jit(lambda *a: GS._gram_moments(*a)).lower(
+        S((n, d), dtype), S((n,), f32), S((n,), f32), S((F, n), f32),
+        S((d,), f32), S((d,), f32)).as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lowered_gram_pass_holds_the_raw_body_only_for_bfloat16(
+        monkeypatch, dtype):
+    """With a float32 matrix the lowered program does not depend on the
+    raw body's existence: it is the text lowered with the predicate forced
+    to today's answer, letter for letter (`tools/lowered_digests.py`
+    compares the other cells' programs with the parent's the same way). With
+    a bfloat16 matrix the forced text is that same body's and the free one
+    holds both under the guard's `case`: bfloat16 operands at the default
+    precision in the raw branches (the Gram against 1 and against 3 parts
+    of the weighted rows, the first-order sums of either), HIGHEST left to
+    the float32 branch."""
+    free = _gram_text(dtype)
+    monkeypatch.setattr(GS, "gram_pass_body",
+                        lambda *a, **k: GS.GRAM_PASS_BODY)
+    forced = _gram_text(dtype)
+
+    def dots(text):
+        lines = [ln for ln in text.splitlines()
+                 if "stablehlo.dot_general" in ln]
+        return ([ln for ln in lines if "bf16>" in ln],
+                [ln for ln in lines if "bf16>" not in ln])
+    raw, std = dots(forced)
+    assert not raw and "stablehlo.case" not in forced
+    assert std and all("precision = [HIGHEST, HIGHEST]" in ln for ln in std)
+    if dtype == jnp.float32:
+        assert free == forced
+        return
+    raw, std = dots(free)
+    assert free != forced and "stablehlo.case" in free
+    assert len(raw) == 4 and all("HIGHEST" not in ln for ln in raw)
+    assert std and all("precision = [HIGHEST, HIGHEST]" in ln for ln in std)
+    widths = sorted(int(m) for ln in raw for m in
+                    re.findall(r"x(\d+)xbf16>\) ->", ln))
+    assert widths[-2:] == [3 * 128, 3 * 3 * 128]    # F d, and its 3 parts

@@ -1124,25 +1124,27 @@ class Validator:
             mesh = self._sweep_mesh
             d = int(Xd.shape[1])
             # the pass's programs are dispatched inside `gram_pass`; the
-            # host then waits in `gram_solve` for the solves' two counts,
-            # the fit's only fetch
+            # host then waits in `gram_solve` for the solves' two counts
+            # and the column moments' word on which body took the moments
+            # (`glm_sweep.gram_pass_body`), the fit's only fetch
             with collector.trace_span(
                     "gram_pass", kind="host_step", folds=F, cols=d,
-                    body=GS.GRAM_PASS_BODY, x_tile=GS.glm_x_tile(d)):
-                if mesh is not None:
-                    B, b0, giters, gcap = GS.sweep_glm_squared_gram_sharded(
-                        mesh, Xd, yd, wd, md, regs_p, alphas_p, mi, tl,
-                        **fk)
-                else:
-                    B, b0, giters, gcap = GS.sweep_glm_squared_gram(
-                        Xd, yd, wd, md, regs_p, alphas_p, mi, tl, **fk)
+                    body=GS.GRAM_PASS_BODY, x_tile=GS.glm_x_tile(d),
+                    moments_body=GS.gram_pass_body(Xd.dtype, d)) as gp:
+                gram = GS.sweep_glm_squared_gram if mesh is None \
+                    else partial(GS.sweep_glm_squared_gram_sharded, mesh)
+                B, b0, *counts = gram(Xd, yd, wd, md, regs_p, alphas_p, mi,
+                                      tl, **fk)
             with collector.trace_span("gram_solve", kind="host_step",
                                       lanes=L) as sp:
-                giters, gcap = int(giters), int(gcap)
+                giters, gcap, raw = (int(v) for v in jax.device_get(counts))
+                body = GS.gram_pass_body(Xd.dtype, d, bool(raw))
                 if sp is not None:
                     sp.attrs.update(iters=giters, lanes_at_cap=gcap)
+                    gp.attrs.update(moments_body=body)
             info = {"route": "streamed", "kernel": "gram",
                     "gram_body": GS.GRAM_PASS_BODY,
+                    "gram_moments_body": body,
                     "glm_rounds": 1, "data_passes": 1, "lane_passes": F,
                     "padded_lane_passes": F,  # the Gram pass never pads
                     "lanes_total": L, "lanes_retired": L - gcap,

@@ -55,8 +55,13 @@ sweep"): one streamed kernel a loss, on top of the shared scan machinery.
    collapses to the per-FOLD weighted Gram X^T diag(w * mask_f) X:
    iteration-invariant and only F matrices, not L. ONE streaming pass
    builds [F, d, d] Grams + X^T W_f y / X^T W_f 1 moments (psum'd under
-   shard_map), the blocks standardised in float32 and contracted at
-   HIGHEST precision (here the moments ARE the fit: `_gram_moments`); the
+   shard_map). Here the moments ARE the fit, so no operand of the pass is
+   rounded (`_gram_moments`, `gram_pass_body`): a bfloat16 matrix's RAW
+   rows go through the matrix unit once (their products are exact in
+   float32) and the standardisation is applied to the [F, d, d] sums; a
+   float32 matrix, a feature-tiled width and columns whose means dwarf
+   their deviations are standardised in float32 a block and contracted at
+   HIGHEST precision. The
    whole reg x alpha grid then solves off the cached
    moments — ridge lanes closed form (`ops/glm.ridge_gram_solve`),
    elastic-net lanes by proximal Newton on the cached Gram
@@ -402,10 +407,54 @@ def _sharded_stats_fn(mesh):
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
-# the body of the Gram pass over X, as the spans and the telemetry name it
-# (the binary rounds' `glm_round_kernel` has a second answer; this pass has
-# one on every backend)
+# the bodies of the Gram pass over X, as `gram_pass_body` names them to the
+# `gram_pass` span's `moments_body` and the telemetry's `gram_moments_body`.
+# Both are XLA loops over row blocks, and the span's `body` / the
+# telemetry's `gram_body` say that of either: GRAM_PASS_BODY, as the
+# benchmark's checks hold them
 GRAM_PASS_BODY = "xla_blocks"
+GRAM_PASS_RAW = "raw_bf16_moments"
+
+# |mean| / std past which the pass does not contract raw rows. A column's
+# raw second moment is 1 + r^2 times its variance, r = |mean| / std, and
+# that is what standardising in moment space cancels: whatever the float32
+# accumulation inside the matrix unit leaves on the raw sum (a few 2^-24 of
+# it a block) comes out 1 + r^2 times as large on the standardised one. At
+# r = 4 that is 17, four of float32's 24 bits: a moment good to 2^-20, the
+# fit's own `tol`. A constant of the arithmetic, not a knob; the
+# null-tracked table's largest r is 2.24, an epoch timestamp's millions.
+RAW_MOMENTS_MAX_R = 4.0
+
+# Rows a matrix-unit accumulation of the raw FIRST-order sums runs over: a
+# float32 sum of same-signed terms loses with its length, and the fold's
+# sum of a standardised column, sum w x - mean sum w, is what a
+# cancellation of |mean| sum w leaves of the raw one (1 in 400 at 32 768
+# rows, 1 in 25 000 at 20M). Each chunk of this many rows of a block has
+# its own pair of accumulators; the Gram's sums cancel by 1 + r^2 at most
+# and run over the whole block.
+_RAW_SUM_ROWS = 512
+
+
+def raw_moments_guard(mean, std):
+    """Device bool: every column's mean within RAW_MOMENTS_MAX_R of its
+    deviations of zero, so that the raw body's moment-space step loses
+    four bits at most. The half of `gram_pass_body` that only the device
+    can answer; a NaN moment answers no."""
+    return (jnp.abs(mean) / std).max() <= RAW_MOMENTS_MAX_R
+
+
+def gram_pass_body(dtype, d: int, guard=True) -> str:
+    """The body of the Gram pass over a [*, d] matrix of `dtype`, from what
+    the pass is handed and nothing else: GRAM_PASS_RAW for a bfloat16
+    matrix on the narrow path (two bfloat16 values' product is exact in
+    float32, so the matrix unit takes the rows as they are, once) whose
+    column moments pass `raw_moments_guard` (`guard`: its verdict, where it
+    is known; the program asks it on the device, under a `lax.cond`);
+    GRAM_PASS_BODY, float32 blocks at HIGHEST, for everything else — a
+    float32 matrix's raw products are not exact in one pass nor in six."""
+    if d <= TRI_MAX_D and jnp.dtype(dtype) == jnp.bfloat16 and guard:
+        return GRAM_PASS_RAW
+    return GRAM_PASS_BODY
 
 
 def _psum_over(axis_name: Optional[str]):
@@ -414,35 +463,187 @@ def _psum_over(axis_name: Optional[str]):
         else (lambda v: v)
 
 
-def _gram_moments(X, y, w, fold_masks, mean, std, *,
-                  axis_name: Optional[str] = None):
-    """The squared loss's ONE pass over X: per-FOLD sufficient statistics
-    of the standardised rows xs = (x - mean) / std — (Gm [F, d, d] =
-    sum w_f xs xs', cA [F, d] = sum w_f y xs, sxA [F, d] = sum w_f xs,
-    syA [F] = sum w_f y, wsum_f [F]), psum'd over `axis_name`.
+def _two_sum(a, b):
+    """(s, e): s = fl(a + b) and a + b = s + e exactly (Knuth)."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
 
-    Precision: the block is standardised in float32 and every contraction
-    runs at HIGHEST, float32 sums. The default would round BOTH float32
+
+def _two_prod(a, b):
+    """(p, e): p = fl(a b) and a b = p + e exactly (Dekker; the halves'
+    products are exact, so a fused multiply-add changes nothing)."""
+    def halves(v):
+        t = 4097.0 * v
+        hi = t - (t - v)
+        return hi, v - hi
+    p = a * b
+    ah, al = halves(a)
+    bh, bl = halves(b)
+    return p, ((ah * bh - p) + ah * bl + al * bh) + al * bl
+
+
+def _pair_add(acc, v):
+    """The pair (hi, lo) of float32 arrays, read hi + lo, with the float32
+    array v added: what float32 rounds off hi goes to lo."""
+    hi, e = _two_sum(acc[0], v)
+    return hi, acc[1] + e
+
+
+def _pair_add_parts(acc, stacked, lanes: int, axis: int):
+    """`_pair_add` of every slab of `lanes` that a contraction of
+    `float32_parts`' stack leaves along `axis`, the smallest part's first:
+    each slab is a sum of products of 16 significant bits, which float32
+    holds nearly whole; the slabs' float32 sum (`slab_sum`) would round it
+    to 24 before the pair could keep what is lost."""
+    for k in range(stacked.shape[axis] - lanes, -1, -lanes):
+        acc = _pair_add(acc, jax.lax.slice_in_dim(stacked, k, k + lanes,
+                                                  axis=axis))
+    return acc
+
+
+def _pair_sum0(acc):
+    """A pair of [k, ...] arrays summed over its leading axis (a loop, not
+    k unrolled sums: the program's size is what a process pays to load
+    it)."""
+    def add(k, total):
+        hi, e = _two_sum(total[0], acc[0][k])
+        return hi, total[1] + (e + acc[1][k])
+    return jax.lax.fori_loop(1, acc[0].shape[0], add, (acc[0][0], acc[1][0]))
+
+
+def _pair_less_product(a, b, m):
+    """float32 of (a_hi + a_lo) - (b_hi + b_lo) m for pairs a, b and a
+    float32 m, the product and the difference carried as pairs: two sums
+    of tens of millions of rows that agree in their leading digits leave
+    their difference, not float32's rounding of either."""
+    p, e = _two_prod(b[0], m)
+    e = e + b[1] * m
+    s, r = _two_sum(a[0], -p)
+    return s + ((r - e) + a[1])
+
+
+def _raw_sums(X, y, w, fold_masks, pivot, parts: int,
+              axis_name: Optional[str]):
+    """The raw body's loop over the local rows: (G [d, F d], s [F, d],
+    t [F, d], W [F]) as float32 pairs and syA [F] — the sums over rows of
+    w_f x x', w_f x, w_f (y - pivot) x, w_f and w_f (y - pivot) of the RAW
+    rows. The block as it sits in HBM is one operand of every contraction
+    (no cast, no subtract, no divide); the other is the fold-weighted
+    block m_f w x [c, F d] in `parts` bfloat16 parts (1: the caller has
+    seen that every m_f w is zero or a power of two, so the product is a
+    bfloat16 value itself; 3: the float32 product's three) for the second
+    moments, and the [c, 2 F] weights m_f w and m_f w (y - pivot) as their
+    three parts for the first-order sums, a chunk of `_RAW_SUM_ROWS` rows a
+    sum. Every product is exact in float32 and the matrix unit adds in
+    float32; each part's sums of each block go into pairs
+    (`_pair_add_parts`), since raw products do not average to zero and a
+    running float32 sum of thousands of them drifts. With one part a block
+    is `_ROW_BLOCK` rows whatever the width (nothing here is a [c, d, d]
+    float32 transient); the three parts are cut from a float32 [c, F d]
+    product, which at that length leaves the chip's fast memory: they keep
+    `_row_block`'s rows."""
+    f32, bf = jnp.float32, X.dtype
+    n, d = X.shape
+    F = fold_masks.shape[0]
+    # whole chunks a block (the last block starts early: `_mlr_blocks`)
+    k = min(_RAW_SUM_ROWS, n)
+    c = min(_ROW_BLOCK if parts == 1 else _row_block(d), n)
+    c -= c % k
+    nb, take = _mlr_blocks(n, c, y, w, fold_masks)
+    x_block = x_row_blocks(X, c)
+    over_rows = (((0,), (0,)), ((), ()))
+
+    def body(i, acc):
+        y_blk, fresh, w_blk, m_blk = take(i)            # m_blk [F, c]
+        gA, mA, wA, syA = acc
+        x = x_block(i)                                  # [c, d], raw
+        wlf = m_blk.T * (w_blk * fresh)[:, None]        # [c, F]
+        wy = wlf * (y_blk - pivot)[:, None]             # [c, F]
+        if parts == 1:
+            xw = (wlf.astype(bf)[:, :, None] * x[:, None, :]
+                  ).reshape(c, F * d)
+        else:
+            v = (wlf[:, :, None] * x.astype(f32)[:, None, :]
+                 ).reshape(c, F * d)
+            xw = pallas_glm.float32_parts(v.T, bf).T
+        gA = _pair_add_parts(gA, jax.lax.dot_general(
+            x, xw, over_rows, preferred_element_type=f32), F * d, 1)
+        u = pallas_glm.float32_parts(
+            jnp.concatenate([wlf, wy], axis=1).T, bf)   # [3 x 2 F, c]
+        m = jnp.einsum('psk,skd->spd', u.reshape(-1, c // k, k),
+                       x.reshape(c // k, k, d),
+                       preferred_element_type=f32)      # [c / k, 3 x 2 F, d]
+        return (gA, _pair_add_parts(mA, m, 2 * F, 1),
+                _pair_add(wA, wlf.reshape(c // k, k, F).sum(1)),
+                syA + wy.sum(0))
+
+    def pair0(*shape):
+        return jnp.zeros(shape, f32), jnp.zeros(shape, f32)
+    acc0 = _shard_vary(
+        (pair0(d, F * d), pair0(c // k, 2 * F, d), pair0(c // k, F),
+         jnp.zeros(F, f32)), axis_name)
+    gA, mA, wA, syA = jax.lax.fori_loop(0, nb, body, acc0)
+    mA = _pair_sum0(mA)
+    return (gA, tuple(v[:F] for v in mA), tuple(v[F:] for v in mA),
+            _pair_sum0(wA), syA)
+
+
+def _scale_only_weights(fold_masks, w):
+    """Device bool: every fold weight m_f w is zero or a power of two, so
+    that its product with a bfloat16 value is a bfloat16 value. The cut is
+    `reduce_precision`'s: a cast and back can be fused away on the chip
+    (`pallas_glm.float32_parts`)."""
+    wf = fold_masks * w[None, :]
+    return (jax.lax.reduce_precision(wf, exponent_bits=8, mantissa_bits=0)
+            == wf).all()
+
+
+def _raw_moments(X, y, w, fold_masks, mean, std,
+                 axis_name: Optional[str]):
+    """`_gram_moments`' sums over the local rows of a bfloat16 matrix from
+    RAW products (`_raw_sums`), the standardisation applied to the sums:
+    G_jk - mean_k s_j - mean_j u_k over std_j std_k with s = sum w_f x and
+    u = s - mean sum w_f, the large terms as pairs. The weighted rows take
+    one bfloat16 part where the pass SEES that every fold weight m_f w is
+    zero or a power of two (no sample weights under 0/1 masks: the product
+    with a bfloat16 value is one), three for any other weights: one more
+    read of w and the masks, under a `lax.cond`. On a v5e, 25M x 128 and 5
+    folds, the pass takes 59.5 ms with one part and 172 with three, where
+    the float32 blocks take 214 (PERF.md, PR 48)."""
+    n, d = X.shape
+    # about a constant near the label's mean (`_block_moments`)
+    pivot = y[:min(_ROW_BLOCK, n)].mean()
+    G, s, t, W, syA = jax.lax.cond(
+        _scale_only_weights(fold_masks, w),
+        *(functools.partial(_raw_sums, X, y, w, fold_masks, pivot, parts,
+                            axis_name) for parts in (1, 3)))
+    F = fold_masks.shape[0]
+    G = tuple(v.reshape(d, F, d).transpose(1, 0, 2) for v in G)
+    u = _pair_less_product(s, tuple(v[:, None] for v in W), mean[None, :])
+    Gc = _pair_less_product(G, tuple(v[:, :, None] for v in s),
+                            mean[None, None, :]) \
+        - mean[None, :, None] * u[:, None, :]
+    cA = ((t[0] - mean[None, :] * syA[:, None]) + t[1]) / std[None, :]
+    sxA, wsum_f = u / std[None, :], W[0] + W[1]
+    return (Gc / (std[:, None] * std[None, :])[None], cA + pivot * sxA, sxA,
+            syA + pivot * wsum_f, wsum_f)
+
+
+def _block_moments(X, y, w, fold_masks, mean, std,
+                   axis_name: Optional[str], allreduce):
+    """`_gram_moments`' sums, `allreduce`d, from blocks standardised in
+    float32, every contraction at HIGHEST, float32 sums: the body of a
+    float32 matrix, of the feature tiles, and of columns past
+    `RAW_MOMENTS_MAX_R`. The default precision would round BOTH float32
     operands to bfloat16 on the chip's matrix unit, and a value that many
     rows share (an indicator's two standardised values, a fill) is then
     off by up to 2^-9 in all of them at once: an error of the moment
-    itself, which no number of rows averages out — and here the moments
-    ARE the fit. (Standardising in moment space from raw products, which
-    are exact for a bfloat16 matrix, would cancel catastrophically for a
-    column whose mean is large beside its deviation: `_psum_moments`.)
-
-    X is read in place: a row block is a dynamic slice along the axis the
-    chip keeps major (`x_row_blocks`; y, w and the masks by
-    `_mlr_blocks`), no padded, reshaped or transposed copy — compiled for
-    a v5e the program of a 25M x 128 (or x 64) bfloat16 matrix holds NO
-    temporaries, where the scan over `_blocked`'s reshape held a second
-    copy of X and of the masks, 7.4 GB. Past TRI_MAX_D columns the block,
-    not X, is padded to the feature tiles."""
+    itself, which no number of rows averages out."""
     f32 = jnp.float32
     n, d = X.shape
     F = fold_masks.shape[0]
     tiled, d_work, bt, tile_pairs = _tiling(d)
-    allreduce = _psum_over(axis_name)
     c = min(_ROW_BLOCK_WIDE if tiled else _row_block(d_work), n)
     nb, take = _mlr_blocks(n, c, y, w, fold_masks)
     x_block = x_row_blocks(X, c)
@@ -476,7 +677,49 @@ def _gram_moments(X, y, w, fold_masks, mean, std, *,
     wsum_f = (fold_masks * w[None, :]).sum(1)                     # [F]
     hA, cA, sxA, syA, wsum_f = allreduce(
         (hA, cA + pivot * sxA, sxA, syA + pivot * wsum_f, wsum_f))
-    return assemble(hA), cA, sxA, syA, jnp.maximum(wsum_f, EPS)
+    return assemble(hA), cA, sxA, syA, wsum_f
+
+
+def _gram_moments(X, y, w, fold_masks, mean, std, *,
+                  axis_name: Optional[str] = None):
+    """The squared loss's ONE pass over X: per-FOLD sufficient statistics
+    of the standardised rows xs = (x - mean) / std — (Gm [F, d, d] =
+    sum w_f xs xs', cA [F, d] = sum w_f y xs, sxA [F, d] = sum w_f xs,
+    syA [F] = sum w_f y, wsum_f [F]), psum'd over `axis_name`.
+
+    Precision: here the moments ARE the fit, so no operand is rounded, by
+    one of two bodies that `gram_pass_body` chooses from what the pass is
+    handed:
+
+    - a bfloat16 matrix of at most TRI_MAX_D columns, all within
+      RAW_MOMENTS_MAX_R of their deviations of zero: `_raw_moments`. The
+      RAW rows go through the matrix unit in ONE pass at its default
+      precision and the standardisation is applied to the summed moments;
+    - everything else: `_block_moments`, float32 operands at HIGHEST (six
+      passes). Standardising in moment space cancels 1 + (mean / std)^2 of
+      the raw sums, catastrophically for a column whose mean is large
+      beside its deviation (`_psum_moments`): the `lax.cond` on
+      `raw_moments_guard` keeps such a matrix here, at no fetch. On a mesh
+      each shard standardises its own sums before the psum (the step is
+      linear in them given the global `mean`; a float32 psum of raw sums
+      would round them before the cancellation).
+
+    X is read in place: a row block is a dynamic slice along the axis the
+    chip keeps major (`x_row_blocks`; y, w and the masks by
+    `_mlr_blocks`), no padded, reshaped or transposed copy — compiled for
+    a v5e the program of a 25M x 128 (or x 64) bfloat16 matrix holds NO
+    temporaries beside a block's, where the scan over `_blocked`'s reshape
+    held a second copy of X and of the masks, 7.4 GB. Past TRI_MAX_D
+    columns the block, not X, is padded to the feature tiles."""
+    args = (X, y, w, fold_masks, mean, std, axis_name)
+    allreduce = _psum_over(axis_name)
+    if gram_pass_body(X.dtype, X.shape[1]) == GRAM_PASS_BODY:
+        sums = _block_moments(*args, allreduce)
+    else:
+        sums = allreduce(jax.lax.cond(
+            raw_moments_guard(mean, std), lambda: _raw_moments(*args),
+            lambda: _block_moments(*args, lambda v: v)))
+    return sums[:4] + (jnp.maximum(sums[4], EPS),)
 
 
 def _gram_solve(Gm_f, cA, sxA, syA, wsum_f, mean, std, regs, alphas,
@@ -519,7 +762,8 @@ def _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
     """loss="squared" fast path: the column moments when standardize=True
     (one extra stats pass, two reads of X: `_psum_moments`), ONE streaming
     pass for the per-FOLD sufficient statistics (`_gram_moments`), then
-    the whole grid solved off them (`_gram_solve`). On a mesh this is one
+    the whole grid solved off them (`_gram_solve`, whose returns these are,
+    and after them `raw_moments_guard`'s verdict). On a mesh this is one
     program a sweep; on one device the three are programs of their own
     (`sweep_glm_squared_gram`), so that a trace tells them apart."""
     d = X.shape[1]
@@ -529,13 +773,19 @@ def _gram_core(X, y, w, fold_masks, regs, alphas, max_iter, tol, *,
         mean, std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
     return _gram_solve(
         *_gram_moments(X, y, w, fold_masks, mean, std, axis_name=axis_name),
-        mean, std, regs, alphas, max_iter, tol, fit_intercept=fit_intercept)
+        mean, std, regs, alphas, max_iter, tol, fit_intercept=fit_intercept
+    ) + (raw_moments_guard(mean, std),)
 
 
 @jax.jit
 def sweep_gram_moments(X, y, w, fold_masks, mean, std):
     """`_gram_moments` on one device: the Gram route's pass over X."""
     return _gram_moments(X, y, w, fold_masks, mean, std)
+
+
+# `raw_moments_guard` as a program of its own: the one-device route hands
+# its verdict back beside the solves' counts (`sweep_glm_squared_gram`)
+_sweep_gram_guard = jax.jit(raw_moments_guard)
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
@@ -552,20 +802,27 @@ def sweep_glm_squared_gram(X: jax.Array, y: jax.Array, w: jax.Array,
                            fit_intercept: bool = True,
                            standardize: bool = True
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                      jax.Array]:
+                                      jax.Array, Any]:
     """Squared-loss (fold x grid) sweep from ONE streaming Gram pass, as
     three programs: `glm_standardize_stats` (the rounds' own; skipped when
     not standardising), `sweep_gram_moments`, `sweep_gram_solve`. Returns
     (B [F, G, d] f32 RAW units, b0 [F, G], prox-solve iters, elastic-net
-    lanes stopped by `max_iter`), all on the device."""
+    lanes stopped by `max_iter`, `raw_moments_guard`'s verdict for
+    `gram_pass_body`), all on the device: the verdict is the word of a
+    fourth program of a few hundred operations where the dtype and the
+    width leave the body to the column moments, the host's False where
+    they do not."""
     d = X.shape[1]
     if standardize:
         mean, std = glm_standardize_stats(X, w)
     else:
         mean, std = jnp.zeros(d, jnp.float32), jnp.ones(d, jnp.float32)
+    raw = gram_pass_body(X.dtype, d) == GRAM_PASS_RAW \
+        and _sweep_gram_guard(mean, std)
     return sweep_gram_solve(
         *sweep_gram_moments(X, y, w, fold_masks, mean, std), mean, std,
-        regs, alphas, max_iter, tol, fit_intercept=bool(fit_intercept))
+        regs, alphas, max_iter, tol, fit_intercept=bool(fit_intercept)
+    ) + (raw,)
 
 
 def gram_temp_bytes(X, y, w, fold_masks) -> int:
@@ -596,7 +853,7 @@ def _sharded_gram_fn(mesh, fit_intercept, standardize):
         core, mesh,
         in_specs=(P(BATCH_AXIS, None), P(BATCH_AXIS), P(BATCH_AXIS),
                   P(None, BATCH_AXIS), P(None), P(None), P(), P()),
-        out_specs=(P(None, None, None), P(None, None), P(), P()))
+        out_specs=(P(None, None, None), P(None, None), P(), P(), P()))
     return jax.jit(sm)
 
 
@@ -605,7 +862,8 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
                                    fit_intercept: bool = True,
                                    standardize: bool = True
                                    ) -> Tuple[jax.Array, jax.Array,
-                                              jax.Array, jax.Array]:
+                                              jax.Array, jax.Array,
+                                              jax.Array]:
     """Row-sharded Gram fast path (`sweep_glm_squared_gram`'s returns):
     each shard accumulates its local rows' per-fold moments, one psum
     combines them, the grid solves replicated.
