@@ -2,13 +2,15 @@
 a true compile told from a persistent-cache load, six seconds that add up
 to first contact, nested and concurrent intervals counted once, `te` set
 by the first root job span alone, counts that `activate()` no longer
-resets. (`ServingEngine.prewarm()` and the recompile watch with the
-collector off ride tests/test_serving.py's fitted model:
-TestWatchWithCollectionOff.)"""
+resets; since PR 49 the instant the backend came up (a one-shot timer on
+jax's backend factories) and reaching the device in two by it.
+(`ServingEngine.prewarm()` and the recompile watch with the collector off
+ride tests/test_serving.py's fitted model: TestWatchWithCollectionOff.)"""
 import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -26,10 +28,16 @@ SECONDS = ("startup_import_s", "startup_reach_device_s",
 # one user-style process: collection is NEVER enabled; a validate nested
 # in a workflow span runs one jitted program; the record goes to stdout
 CHILD = """
-import json, jax, jax.numpy as jnp
+import json, threading, jax, jax.numpy as jnp
 import transmogrifai_tpu
 from transmogrifai_tpu.utils import platform, tracing
 from transmogrifai_tpu.utils.metrics import collector
+from jax._src import xla_bridge
+def timed():
+    return sorted(k for k, r in xla_bridge._backend_factories.items()
+                  if "_timed_factory" in getattr(r.factory, "__qualname__", ""))
+before = platform.startup_record()      # no backend is up yet
+timed_before = timed()
 f = jax.jit(lambda x: (x * 3.0).sum())
 with collector.trace_span("Workflow.train", kind="workflow"):
     with collector.trace_span("CrossValidation", kind="validate"):
@@ -41,7 +49,9 @@ jax.jit(lambda x: x - 1.0)(jnp.ones(7)).block_until_ready()
 print(json.dumps({"inner_te": inner_te, "enabled": collector.enabled,
                   "true": tracing.tracker.true_compiles,
                   "hits": tracing.tracker.total_cache_hits, "rec": rec,
-                  "marks": marks,
+                  "marks": marks, "before": before,
+                  "timed": [timed_before, timed()],
+                  "threads": [t.name for t in threading.enumerate()],
                   "later": platform.startup_record()}))
 """
 
@@ -125,11 +135,63 @@ class TestAlwaysOn:
                 + _row(later, "<lambda>", "later_programs")["compiles"] == 1
 
 
-def _ledger(events, t0=100.0, t1=103.0, te=120.0):
+SPLIT = ("startup_backend_up_s", "startup_first_dispatch_s",
+         "startup_backend_up_cpu_s")
+
+
+class TestTheBackendsInstant:
+    """One user-style process on the CPU: the package imported before any
+    backend is up, the CPU's factory timed once, the TPU's never called."""
+
+    def test_the_three_are_none_while_no_backend_is_up(self, cold_and_warm):
+        for run in cold_and_warm:
+            before = run["before"]
+            assert [before[k] for k in SPLIT] == [None] * 3
+            assert before["backend_up_before_import"] is False
+            assert before["complete"] is False and before["backend_inits"] == []
+            assert before["ledger_version"] == 2
+
+    def test_the_two_seconds_add_up_to_reaching_the_device(
+            self, cold_and_warm):
+        for run in cold_and_warm:
+            rec = run["rec"]
+            assert rec["ledger_version"] == 2
+            assert rec["backend_up_before_import"] is False
+            assert all(rec[k] >= 0 for k in SPLIT), rec
+            assert rec["startup_backend_up_s"] \
+                + rec["startup_first_dispatch_s"] == pytest.approx(
+                    rec["startup_reach_device_s"], abs=1e-9)
+            assert rec["startup_backend_up_s"] > 0
+            (row,) = rec["backend_inits"]
+            assert row["platform"] == "cpu" and row["ok"] is True
+            # this process's first jax call is an operation, so its
+            # backend came up INSIDE the first program event: the instant
+            # is held to the interval it splits
+            assert row["begin_s"] + row["init_s"] \
+                >= rec["startup_backend_up_s"]
+            # CPU seconds of every thread: at most the interval on each core
+            assert rec["startup_backend_up_cpu_s"] \
+                <= rec["startup_backend_up_s"] * os.cpu_count() + 0.05
+            assert rec["kernel_import_cpu_s"] is None   # no thread on a CPU pin
+            assert run["later"]["startup_backend_up_s"] \
+                == rec["startup_backend_up_s"]
+
+    def test_nothing_is_left_timed_or_running_after_the_first_job(
+            self, cold_and_warm):
+        for run in cold_and_warm:
+            timed_before, timed_after = run["timed"]
+            assert "cpu" in timed_before and timed_after == []
+            assert not [t for t in run["threads"] if t.startswith("tmog")]
+
+
+def _ledger(events, t0=100.0, t1=103.0, te=120.0, inits=(),
+            up_at_install=False, t1_cpu=1.0):
     tr = RecompileTracker()
     tr.mark_import(t0, t1)
     # tmoglint: disable=THR001  a hand-made ledger, before any thread
     tr._events, tr.te = list(events), te
+    tr.backend_inits, tr.t1_cpu = list(inits), t1_cpu
+    tr.backend_up_at_install = up_at_install
     return tr.startup_record()
 
 
@@ -197,6 +259,203 @@ class TestTheArithmetic:
         rec = tr.startup_record()
         assert tr.true_compiles == 5 and rec["events_dropped"] == 2
         assert rec["startup_programs"] == 3
+
+
+REPLAYED = [
+    ("trace", 105.0, 109.0, "outer"), ("trace", 106.0, 107.0, "inner"),
+    ("compile", 108.0, 108.5, "eager_op"), ("lower", 109.0, 110.0, "outer"),
+    ("cache_load", 110.0, 112.0, "outer"),
+    ("compile", 111.0, 113.0, "helper"), ("trace", 119.0, 125.0, "late")]
+OLD_SIX = (3.0, 2.0, 5.5, 1.0, 2.5, 6.0)
+
+
+@pytest.mark.parametrize("inits,up_at_install,want,flag", [
+    # (platform, begin, end, process CPU at end, came up); t1 103, first
+    # program event 105, the process's CPU at t1 1.0
+    ([("cpu", 103.5, 104.5, 1.25, True)], False, (1.5, 0.5, 0.25), False),
+    ([("tpu", 103.0, 104.0, 1.5, True), ("cpu", 104.0, 104.25, 1.75, True)],
+     False, (1.25, 0.75, 0.75), False),
+    ([("tpu", 103.25, 103.5, 1.5, False), ("cpu", 103.5, 104.0, 2.0, True)],
+     False, (1.0, 1.0, 1.0), False),                 # no chip: the CPU's
+    ([("cpu", 105.5, 106.0, 1.5, True)], False, (2.0, 0.0, 0.5), False),
+    ([("cpu", 102.0, 102.5, 0.5, True)], False, (0.0, 2.0, 0.0), True),
+    ([], True, (0.0, 2.0, 0.0), True),               # jax came up first
+    ([], False, (None, None, None), False),          # no backend yet
+    ([("tpu", 103.5, 104.5, 1.25, False)], False, (None, None, None), False),
+    ([], None, (None, None, None), None),            # another jax
+], ids=["one-platform", "tpu-then-cpu", "tpu-failed", "traced-before-up",
+        "up-during-import", "up-before-import", "not-up", "none-came-up",
+        "names-missing"])
+def test_reaching_the_device_in_two_on_a_replayed_event_list(
+        inits, up_at_install, want, flag):
+    """t_up is the end of the last factory that came up; the two seconds
+    stay inside [0, startup_reach_device_s] and add up to it; the six old
+    fields are what they were without any of it."""
+    rec = _ledger(REPLAYED, inits=inits, up_at_install=up_at_install)
+    assert tuple(rec[k] for k in SECONDS) == pytest.approx(OLD_SIX)
+    assert rec["first_contact_s"] == 20.0 and rec["startup_programs"] == 3
+    assert tuple(rec[k] for k in SPLIT) == want
+    assert rec["backend_up_before_import"] is flag
+    assert rec["ledger_version"] == 2 and rec["complete"] is True
+    assert [r["platform"] for r in rec["backend_inits"]] \
+        == [row[0] for row in inits]
+    if want[0] is not None:
+        assert want[0] + want[1] == rec["startup_reach_device_s"]
+        assert min(want) >= 0
+
+
+def _fake_bridge(monkeypatch, up=False, frozen=False, **missing):
+    """jax's xla_bridge as `_watch_backends` sees it: two registrations
+    with a `factory` each, the lock's question, nothing else."""
+    import jax._src
+
+    class Registration:
+        def __init__(self, factory):
+            object.__setattr__(self, "factory", factory)
+
+        def __setattr__(self, key, value):
+            if frozen:
+                raise AttributeError("cannot assign to field 'factory'")
+            object.__setattr__(self, key, value)
+
+    def no_chip():
+        raise RuntimeError("no chip here")
+    fake = types.SimpleNamespace(
+        backends_are_initialized=lambda: up,
+        _backend_factories={"tpu": Registration(no_chip),
+                            "cpu": Registration(lambda: "a client")})
+    for name in missing:
+        delattr(fake, name)
+    monkeypatch.setattr(jax._src, "xla_bridge", fake)
+    own = {k: r.factory for k, r in fake._backend_factories.items()} \
+        if "_backend_factories" not in missing else {}
+    return fake, own
+
+
+class TestTheFactoryTimers:
+    def test_a_timer_runs_once_and_the_first_job_takes_the_rest_off(
+            self, monkeypatch):
+        fake, own = _fake_bridge(monkeypatch)
+        regs = fake._backend_factories
+        tr = RecompileTracker()
+        tr.install()
+        assert tr.backend_up_at_install is False
+        assert regs["cpu"].factory is not own["cpu"]
+        assert regs["tpu"].factory is not own["tpu"]
+        assert regs["cpu"].factory() == "a client"      # what jax calls
+        assert regs["cpu"].factory is own["cpu"]        # and it is gone
+        assert regs["tpu"].factory is not own["tpu"]    # never called
+        ((platform_, begin, end, cpu, ok),) = tr.backend_inits
+        assert (platform_, ok) == ("cpu", True) and begin <= end
+        rec = tr.startup_record()
+        assert rec["startup_backend_up_s"] is not None
+        tr.install()                                    # once
+        assert regs["tpu"].factory is not own["tpu"]
+        tr.job_enter()
+        tr.job_exit(True)
+        assert regs["tpu"].factory is own["tpu"]
+        assert tr._timed_factories == {}
+
+    def test_a_factory_that_raises_is_no_backend(self, monkeypatch):
+        fake, own = _fake_bridge(monkeypatch)
+        tr = RecompileTracker()
+        tr.install()
+        with pytest.raises(RuntimeError, match="no chip here"):
+            fake._backend_factories["tpu"].factory()
+        assert fake._backend_factories["tpu"].factory is own["tpu"]
+        assert [row[4] for row in tr.backend_inits] == [False]
+        assert tr.startup_record()["startup_backend_up_s"] is None
+
+    def test_a_backend_that_is_up_is_left_alone(self, monkeypatch):
+        fake, own = _fake_bridge(monkeypatch, up=True)
+        tr = RecompileTracker()
+        tr.install()
+        assert tr.backend_up_at_install is True
+        assert {k: r.factory for k, r in
+                fake._backend_factories.items()} == own
+        rec = tr.startup_record()
+        assert rec["backend_up_before_import"] is True
+        assert rec["startup_backend_up_s"] == 0.0
+        assert rec["startup_first_dispatch_s"] \
+            == rec["startup_reach_device_s"]
+
+    @pytest.mark.parametrize("kw", [
+        {"_backend_factories": True}, {"backends_are_initialized": True},
+        {"frozen": True}], ids=["no-table", "no-question", "frozen-field"])
+    def test_another_jax_reads_none_and_nothing_raises(self, monkeypatch, kw):
+        frozen = kw.pop("frozen", False)
+        fake, own = _fake_bridge(monkeypatch, frozen=frozen, **kw)
+        tr = RecompileTracker()
+        tr.install()
+        assert tr.backend_up_at_install is None
+        assert tr._timed_factories == {} and tr.backend_inits == []
+        if own:
+            assert {k: r.factory for k, r in
+                    fake._backend_factories.items()} == own
+        rec = tr.startup_record()
+        assert [rec[k] for k in SPLIT] == [None] * 3
+        assert rec["backend_up_before_import"] is None
+        assert rec["ledger_version"] == 2
+
+    def test_this_process_came_up_before_or_was_timed(self):
+        """The process's own tracker: jax up before the package's import
+        (0, all to the first dispatch), or its CPU factory timed once."""
+        import jax
+        jax.devices()
+        rec = platform.startup_record()
+        assert tracing.tracker.backend_up_at_install is not None
+        assert all(rec[k] is not None and rec[k] >= 0 for k in SPLIT)
+        assert rec["startup_backend_up_s"] + rec["startup_first_dispatch_s"] \
+            == pytest.approx(rec["startup_reach_device_s"], abs=1e-9)
+        if tracing.tracker.backend_up_at_install:
+            assert rec["backend_up_before_import"] is True
+            assert rec["startup_backend_up_s"] == 0.0
+
+
+FORCED = """
+import json, threading, jax
+import transmogrifai_tpu
+from transmogrifai_tpu.utils import platform
+from transmogrifai_tpu.utils.metrics import collector
+from jax._src import xla_bridge
+platform.force_cpu(4)
+devices = [d.platform for d in jax.devices()]
+for t in threading.enumerate():
+    if t.name.startswith("tmog"):
+        t.join(60)
+with collector.trace_span("CV", kind="validate"):
+    jax.jit(lambda x: x + 1.0)(jax.numpy.ones(3)).block_until_ready()
+rec = platform.startup_record()
+print(json.dumps({"devices": devices, "rec": rec,
+    "threads": [t.name for t in threading.enumerate()],
+    "timed": [k for k, r in xla_bridge._backend_factories.items()
+              if "_timed_factory" in getattr(r.factory, "__qualname__", "")]}))
+"""
+
+
+def test_force_cpu_after_the_import_still_works(tmp_path):
+    """Nothing pinned when the package is imported (a TPU host's way in:
+    every factory timed, the kernel modules' thread started where libtpu
+    is installed), then `force_cpu`: jax calls the CPU's factory alone,
+    and the first job hands the TPU's back unused."""
+    env = dict(os.environ, PYTHONPATH=REPO,
+               TMOG_COMPILE_CACHE_DIR=str(tmp_path))
+    for key in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS"):
+        env.pop(key, None)
+    r = subprocess.run([sys.executable, "-c", FORCED], env=env,
+                       capture_output=True, text=True, timeout=180)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["devices"] == ["cpu"] * 4
+    rec = out["rec"]
+    assert [row["platform"] for row in rec["backend_inits"]] == ["cpu"]
+    assert rec["backend_up_before_import"] is False
+    assert rec["startup_backend_up_s"] + rec["startup_first_dispatch_s"] \
+        == pytest.approx(rec["startup_reach_device_s"], abs=1e-9)
+    assert out["timed"] == []
+    assert not [t for t in out["threads"] if t.startswith("tmog")]
+    if rec["kernel_import_s"] is not None:      # libtpu is installed here
+        assert 0 <= rec["kernel_import_cpu_s"] <= rec["kernel_import_s"] + 0.05
 
 
 class TestLifecycle:
@@ -317,7 +576,11 @@ def test_kernel_modules_are_imported_on_a_thread_where_kernels_may_run(
         assert thread.daemon and not thread.is_alive()
         assert seen == ["jax.experimental.pallas",
                         "jax.experimental.pallas.tpu"]
-        assert platform.startup_record()["kernel_import_s"] >= 0.0
+        rec = platform.startup_record()
+        assert rec["kernel_import_s"] >= 0.0
+        assert rec["kernel_import_cpu_s"] >= 0.0    # the thread's own CPU
     else:
         assert seen == []
-        assert platform.startup_record()["kernel_import_s"] is None
+        rec = platform.startup_record()
+        assert rec["kernel_import_s"] is None
+        assert rec["kernel_import_cpu_s"] is None

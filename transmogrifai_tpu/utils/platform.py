@@ -86,7 +86,10 @@ def device_spec(device_kind: Optional[str] = None) -> Optional[DeviceSpec]:
 def startup_record() -> dict:
     """Where this process's start-up went, from the package's import to
     its first finished job (`validate()`, `train()`, `score()`): the six
-    `startup_*_s` seconds that add up to `first_contact_s`, the count of
+    `startup_*_s` seconds that add up to `first_contact_s`, reaching the
+    device in two by the instant the backend came up
+    (`startup_backend_up_s`, `startup_first_dispatch_s`, and the CPU
+    seconds of the first, `startup_backend_up_cpu_s`), the count of
     programs loaded or compiled, and the per-program rows (`fun_name`,
     trace, lower, load or compile seconds, `cache_hit`), slowest first.
     Always on; read it after one job. The ledger is utils/tracing's
@@ -111,8 +114,9 @@ def prefetch_kernel_modules() -> Optional[threading.Thread]:
     package's own import runs beside it and what follows is the backend's
     initialisation, 7-13 s that the main thread spends outside Python. The
     thread's interval goes to the start-up ledger (`startup_record()`'s
-    `kernel_import_s`; it lies under `startup_reach_device_s`, not in the
-    six seconds): 1.7-1.9 s beside the main thread, and in 3 of 14 measured
+    `kernel_import_s`, and `kernel_import_cpu_s` the thread's own CPU
+    seconds of it; it lies under `startup_reach_device_s`, not in the six
+    seconds): 1.7-1.9 s beside the main thread, and in 3 of 14 measured
     runs as long as the backend took to come up (PERF.md, PR 34).
 
     A main thread that asks for one of these modules meanwhile waits on that
@@ -136,13 +140,14 @@ def prefetch_kernel_modules() -> Optional[threading.Thread]:
         return None
 
     def load():
-        start = time.time()
+        start, cpu = time.time(), time.thread_time()
         try:
             for name in _KERNEL_MODULES:
                 importlib.import_module(name)
         except Exception:       # the main thread's own import reports it
             pass
-        tracker.mark_kernel_import(start, time.time())
+        tracker.mark_kernel_import(start, time.time(),
+                                   time.thread_time() - cpu)
 
     thread = threading.Thread(target=load, name="tmog-kernel-imports",
                               daemon=True)

@@ -359,6 +359,11 @@ class RecompileTracker:
     `startup_record()`. `true_compiles` / `total_cache_hits` run for the
     process's life; callers take differences.
 
+    jax emits no event for its backend's start, so `install()` also puts a
+    one-shot timer around each registered backend factory
+    (`_watch_backends`): the instant the backend came up, and the process's
+    CPU seconds up to it, split `startup_reach_device_s` in two.
+
     While a collected run's tree is active (`collector.enable()`), each
     compile is ALSO booked to the innermost open span (`compiles`,
     `compile_seconds`, `cache_hits` attrs) — a view, not the record.
@@ -380,8 +385,19 @@ class RecompileTracker:
         self._jobs = threading.local()
         self.t0 = self.t1 = time.time()   # mark_import() sets the real ones
         self.te: Optional[float] = None
-        # platform.prefetch_kernel_modules' thread, [start, end]
-        self.kernel_import: Optional[Tuple[float, float]] = None
+        self.t1_cpu = time.process_time()   # the process's CPU at t1
+        # platform.prefetch_kernel_modules' thread: start, end, and the
+        # thread's own CPU seconds (time.thread_time) between them
+        self.kernel_import: Optional[Tuple[float, float, float]] = None
+        # was a backend up when install() looked: None where it could not
+        # look (no jax, or a jax without the names _watch_backends reads)
+        self.backend_up_at_install: Optional[bool] = None
+        # each platform's initialisation, in the order jax ran them:
+        # (platform, begin, end, process CPU at end, came up)
+        self.backend_inits: List[Tuple[str, float, float, float, bool]] = []
+        # the factories still timed: platform -> (registration, its own
+        # factory, the timer in its place)
+        self._timed_factories: Dict[str, Tuple[Any, Any, Any]] = {}
         self._events: List[Tuple[str, float, float, str]] = []
         self.events_dropped = 0
         self.listener_seconds = 0.0
@@ -404,25 +420,96 @@ class RecompileTracker:
 
     # -- lifecycle ---------------------------------------------------------
     def install(self) -> None:
-        """Register the listener (once), if jax is imported."""
+        """Register the listener and watch for the backend (once), if jax
+        is imported."""
         with self._lock:
-            if not self._listener_installed \
-                    and sys.modules.get("jax") is not None:
-                import jax.monitoring
-                jax.monitoring.register_event_duration_secs_listener(
-                    self._on_event)
-                self._listener_installed = True
+            if self._listener_installed or sys.modules.get("jax") is None:
+                return
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_event)
+            self._listener_installed = True
+        # outside the lock: jax's backend lock is taken in there, and a
+        # timed factory takes them the other way round
+        self._watch_backends()
 
     def mark_import(self, t0: float, t1: float) -> None:
         """The package's own import, [first, last] statement of its
-        `__init__` on time.time()."""
+        `__init__` on time.time(); called AS that last statement, so the
+        process's CPU clock is read here for t1."""
         with self._lock:
             self.t0, self.t1 = float(t0), float(t1)
+            self.t1_cpu = time.process_time()
 
-    def mark_kernel_import(self, start: float, end: float) -> None:
-        """The interval of platform.prefetch_kernel_modules' thread."""
+    def mark_kernel_import(self, start: float, end: float,
+                           cpu_s: float) -> None:
+        """The interval of platform.prefetch_kernel_modules' thread and
+        the CPU seconds the thread itself spent in it."""
         with self._lock:
-            self.kernel_import = (float(start), float(end))
+            self.kernel_import = (float(start), float(end), float(cpu_s))
+
+    # -- the instant the backend came up --------------------------------------
+    def _watch_backends(self) -> None:
+        """Put a one-shot timer in place of every backend factory jax has
+        registered (`jax._src.xla_bridge._backend_factories`, filled at
+        that module's import), unless a backend is up already.
+
+        `xla_bridge.backends()` calls each factory of the platforms it
+        wants, once, under its lock; a timer hands the registration its
+        own factory back BEFORE running it, notes [begin, end] on
+        time.time() and the process's CPU clock at the end, and is gone.
+        Nothing initialises a backend here, nothing runs or polls, and a
+        factory jax never calls (the TPU's in a process forced to the CPU)
+        keeps an idle timer until the first finished job takes the rest
+        off (`job_exit`) — in a process that reaches neither, one closure
+        a registered platform, never called. The names are private to jax
+        0.9.0: where they are missing or cannot be set, nothing is timed,
+        `backend_up_at_install` stays None and the record's fields read
+        None."""
+        t_in = time.perf_counter()
+        timed: Dict[str, Tuple[Any, Any, Any]] = {}
+        try:
+            from jax._src import xla_bridge
+            up: Optional[bool] = bool(xla_bridge.backends_are_initialized())
+            if not up:
+                for platform, reg in list(
+                        xla_bridge._backend_factories.items()):
+                    factory = reg.factory
+                    timer = self._timed_factory(platform, reg, factory)
+                    reg.factory = timer
+                    timed[platform] = (reg, factory, timer)
+        except (ImportError, AttributeError, TypeError):
+            up = None
+        with self._lock:
+            self.backend_up_at_install = up
+            self._timed_factories.update(timed)
+            self.listener_seconds += time.perf_counter() - t_in
+        if up is None:
+            self._unwatch_backends()
+
+    def _timed_factory(self, platform: str, registration: Any,
+                       factory: Any) -> Any:
+        def timer():
+            registration.factory = factory
+            begin, ok = time.time(), False
+            try:
+                backend = factory()
+                ok = backend is not None
+                return backend
+            finally:
+                end, cpu = time.time(), time.process_time()
+                with self._lock:
+                    self._timed_factories.pop(platform, None)
+                    self.backend_inits.append((platform, begin, end, cpu, ok))
+        return timer
+
+    def _unwatch_backends(self) -> None:
+        """Hand every factory still timed back to its registration."""
+        with self._lock:
+            timed, self._timed_factories = self._timed_factories, {}
+        for registration, factory, timer in timed.values():
+            if registration.factory is timer:
+                registration.factory = factory
 
     def activate(self, tree: TraceTree) -> None:
         """Book compiles to `tree`'s innermost open span from now on. The
@@ -449,8 +536,11 @@ class RecompileTracker:
             getattr(self._jobs, "depth", 1) - 1, 0)
         if ok and depth == 0:
             with self._lock:
-                if self.te is None:
+                first = self.te is None
+                if first:
                     self.te = time.time()
+            if first:
+                self._unwatch_backends()
 
     # -- the listener --------------------------------------------------------
     def _on_event(self, event: str, duration: float, **kw: Any) -> None:
@@ -521,14 +611,33 @@ class RecompileTracker:
         up to the first result, `later_programs` what traced, loaded or
         compiled after it (a warm server: the recompile's name).
 
+        `startup_reach_device_s` in two (ledger_version 2), by `t_up`, the
+        end of the last backend factory that came up (`_watch_backends`):
+        `startup_backend_up_s` (t1 to t_up, held inside the interval) and
+        `startup_first_dispatch_s` (the rest: the host work between the
+        backend and the first program event, the caller's and the
+        program's), which add up to it by construction; and
+        `startup_backend_up_cpu_s`, the process's CPU seconds of all
+        threads inside [t1, t_up] — near the wall figure the interval was
+        host compute, far under it a wait on the driver and the chip. A
+        backend that was up before the package was imported reads 0, all
+        to the first dispatch, with `backend_up_before_import` True; while
+        none is up, or where jax hides its factories, the three are None.
+        `backend_inits` has each platform's initialisation (`begin_s` from
+        t1, `init_s`, `ok`).
+
         Beside the six, `kernel_import_s`: how long the thread that imports
         jax's Pallas modules took (platform.prefetch_kernel_modules; None
-        where none ran or it has not ended). It starts at t1 and runs
-        beside the main thread, under `startup_reach_device_s` where the
-        backend takes longer to come up than the import."""
+        where none ran or it has not ended), and `kernel_import_cpu_s`, the
+        thread's own CPU seconds of it: far under the wall, it was held. It
+        starts at t1 and runs beside the main thread, under
+        `startup_reach_device_s` where the backend takes longer to come up
+        than the import."""
         now = time.time()
         with self._lock:
             t0, t1, te = self.t0, self.t1, self.te
+            t1_cpu, up_at_install = self.t1_cpu, self.backend_up_at_install
+            inits = list(self.backend_inits)
             kernel_import = self.kernel_import
             events = list(self._events)
             out: Dict[str, Any] = {
@@ -547,7 +656,31 @@ class RecompileTracker:
                          if k in ("compile", "cache_load")]) - compile_s
         busy = union_seconds([(s, e) for _, s, e in clipped])
         start = _process_start()
+        reach = first - t1
+        came_up = [row for row in inits if row[4]]
+        if came_up:
+            _, _, t_up, up_cpu, _ = max(came_up, key=lambda row: row[2])
+            before_import = t_up <= t1
+            backend_up_s = min(max(t_up - t1, 0.0), reach)
+            backend_up_cpu_s = max(up_cpu - t1_cpu, 0.0)
+        elif up_at_install:
+            before_import, backend_up_s, backend_up_cpu_s = True, 0.0, 0.0
+        else:
+            before_import = up_at_install
+            backend_up_s = backend_up_cpu_s = None
         out.update({
+            "ledger_version": 2,
+            "backend_up_before_import": before_import,
+            "startup_backend_up_s": backend_up_s,
+            "startup_first_dispatch_s": None if backend_up_s is None
+            else reach - backend_up_s,
+            "startup_backend_up_cpu_s": backend_up_cpu_s,
+            "backend_inits": [
+                {"platform": platform, "begin_s": begin - t1,
+                 "init_s": end_ - begin, "ok": ok}
+                for platform, begin, end_, _, ok in inits],
+            "kernel_import_cpu_s": None if kernel_import is None
+            else kernel_import[2],
             "complete": te is not None,
             "before_import_s": None if start is None else t0 - start,
             "first_contact_s": end - t0,
