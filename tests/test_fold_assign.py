@@ -196,6 +196,100 @@ def test_a_new_seed_does_not_recompile():
     assert F.assign_fold_masks._cache_size() == before
 
 
+# -- version 2, stated plainly -------------------------------------------------
+
+def version2_masks(seed, y, n, folds, val_fraction, stratify,
+                   word_mask=np.uint32(0)):
+    """FOLD_ASSIGNMENT_VERSION 2 in numpy: the rows ordered by ([label,]
+    word 0, word 1, row id) — row i's words the Threefry block of (i,
+    n + i) — and the fold from the rank in that order (within the class
+    when stratified), as assign_fold_masks' docstring says it."""
+    from jax.extend.random import threefry_2x32
+    i = np.arange(n, dtype=np.uint32)
+    key = F.fold_key(seed)
+    w0, w1 = np.asarray(threefry_2x32(
+        (key[0], key[1]), np.stack([i, np.uint32(n) + i]))) | word_mask
+    ids = np.arange(n)
+    if not stratify:
+        # the sorted ids, read as "row i has rank ids[i]"
+        rank = np.lexsort((ids, w1, w0))
+        n_val = None if val_fraction is None else int(round(n * val_fraction))
+    else:
+        order = np.lexsort((ids, w1, w0, y))
+        cls = y[order]
+        first = np.r_[True, cls[1:] != cls[:-1]]
+        start = np.maximum.accumulate(np.where(first, ids, 0))
+        size = np.diff(np.r_[np.flatnonzero(first), n])[np.cumsum(first) - 1]
+        rank = np.empty(n, np.int64)
+        rank[order] = ids - start
+        if val_fraction is not None:
+            n_val = np.empty(n, np.int64)
+            n_val[order] = [round(int(c) * val_fraction) for c in size]
+    fold_of = rank % folds if val_fraction is None else rank >= n_val
+    held_out = np.arange(1 if val_fraction is not None else folds)
+    return (fold_of[None, :] != held_out[:, None]).astype(np.float32)
+
+
+# all but a word's two lowest bits forced to one: four values a word,
+# all-ones (the padding's word) among them, so that hundreds of rows share
+# all 64 bits and the row id decides
+FEW_WORDS = np.uint32(0xFFFFFFFC)
+
+
+@pytest.mark.parametrize("stratify", [False, True], ids=["plain", "strat"])
+@pytest.mark.parametrize("val_fraction", [None, 0.3], ids=["kfold", "split"])
+@pytest.mark.parametrize("n", [1, 5, 2047, 2048, 2049, 10_000, 65_537,
+                               "10_000-collisions"])
+def test_masks_are_version_2_bit_for_bit(n, val_fraction, stratify,
+                                         monkeypatch):
+    word_mask = np.uint32(0)
+    if isinstance(n, str):
+        n, word_mask, row_words = 10_000, FEW_WORDS, F._row_words
+        monkeypatch.setattr(F, "_row_words", lambda *a: [
+            w | FEW_WORDS for w in row_words(*a)])
+    y = labels(n, 3, seed=n)
+    try:
+        F.assign_fold_masks.clear_cache()   # the words are traced in
+        for seed in (7, 2 ** 31 + 11):
+            got = F.assign_fold_masks(
+                F.fold_key(seed), jnp.asarray(y) if stratify else None, n=n,
+                n_folds=4, val_fraction=val_fraction, stratify=stratify)
+            want = version2_masks(seed, y, n, 4, val_fraction, stratify,
+                                  word_mask)
+            assert got.dtype == jnp.float32
+            assert np.array_equal(np.asarray(got), want), seed
+    finally:
+        monkeypatch.undo()
+        F.assign_fold_masks.clear_cache()
+
+
+@pytest.mark.parametrize("n", [5, 2048, 25_000_000])
+def test_the_program_is_one_unstable_sort_of_keys_alone(n):
+    """The unstratified program as lowered: ONE sort, unstable, three
+    operands and every one a key (a stable sort keeps an index of its own
+    on the chip), over a length that is a multiple of 2 048 (any other
+    costs 3 % more a key there: PERF.md, PR 50)."""
+    import re
+    text = F.assign_fold_masks.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32), None, n=n,
+        n_folds=5).as_text()
+    sort, = re.findall(r'"stablehlo\.sort"\((.*?)\) <\{(.*?)\}> \(\{'
+                       r'(.*?)\n    \}\) : \((.*?)\) ->', text, re.S)
+    operands, attrs, comparator, types = sort
+    assert "is_stable = false" in attrs
+    assert len(operands.split(",")) == 3
+    shape = F.fold_sort_shape(n, False)
+    assert shape["sort_keys"] == 3 and shape["sort_places"] % 2048 == 0
+    assert 0 <= shape["pad_places"] == shape["sort_places"] - n < 2048
+    assert types.split(", ") == [
+        f"tensor<{shape['sort_places']}x{t}>" for t in ("ui32", "ui32",
+                                                        "i32")]
+    # every operand decides: the comparator reads all six of its arguments
+    args = re.findall(r"(%arg\d+): tensor", comparator.split("\n")[1])
+    assert len(args) == 6
+    assert all(re.search(rf"compare .*{a}\b", comparator) for a in args)
+
+
 # -- validate() ---------------------------------------------------------------
 
 def _data(n=300, d=5, seed=0):

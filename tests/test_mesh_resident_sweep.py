@@ -133,11 +133,12 @@ def test_fold_masks_on_a_mesh_are_the_one_device_masks(shards, stratify, spec,
     # (the stratified rule has none to overflow)
     route = folds.sharded_fold_route(m, n, stratify)
     assert route["route"] == ("replicated" if stratify else "partitioned")
-    assert route["sort_keys"] == (n if stratify
-                                  else shards * route["capacity"])
+    assert route["sort_keys"] == (4 if stratify else 3)
+    assert route["sort_places"] == (n + -n % 2048 if stratify
+                                    else shards * route["capacity"])
     assert route["exchange_bytes"] == 12 * shards * route["capacity"]
     if case == "rows_no_power_of_two" and not stratify:
-        assert route["capacity"] < n // shards and route["sort_keys"] < n
+        assert route["capacity"] < n // shards and route["sort_places"] < n
     assert bool(overflow) == (too_few and not stratify)
     assert on_mesh.sharding.is_equivalent_to(sharded_along(m, 1, 2), 2)
     # no chip holds the whole [F, n] block
@@ -255,14 +256,17 @@ def test_spans_name_the_layout(sweeps, which, shards, route):
     assign = _attrs(spans, "validate_phase", "fold_assign")[0]
     assert assign["shards"] == shards
     if shards == 1:
-        assert assign["route"] == "device" and "sort_keys" not in assign
+        assert {k: assign[k] for k in ("route", "sort_keys", "sort_places",
+                                       "pad_places")} == dict(
+            route="device", **folds.fold_sort_shape(N, False))
+        assert "capacity" not in assign
     else:
         # the fold program's body on the mesh, as planned; the flag says
         # that body answered, and nothing fetched it
         _, capacity = folds._partition_plan(N, shards)
-        assert {k: assign[k] for k in ("route", "sort_keys", "capacity",
-                                       "exchange_bytes")} == dict(
-            route="partitioned", sort_keys=shards * capacity,
+        assert {k: assign[k] for k in ("route", "sort_keys", "sort_places",
+                                       "capacity", "exchange_bytes")} == dict(
+            route="partitioned", sort_keys=3, sort_places=shards * capacity,
             capacity=capacity, exchange_bytes=12 * shards * capacity)
         assert not bool(sweeps["four"][0].last_fold_overflow)
     place = _attrs(spans, "validate_phase", "device_place")[0]

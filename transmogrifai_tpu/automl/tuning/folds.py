@@ -12,8 +12,9 @@ The assignment is a function of (seed, rows, folds | validation share, and
 the labels when stratified) alone. The random words come from the Threefry
 2x32 hash applied directly (not through `jax.random.bits`, whose layout
 follows the global `jax_threefry_partitionable` flag) and every other step
-is integer arithmetic or a stable sort, so the CPU backend and the chip
-draw the same folds and a checkpoint replays on either.
+is integer arithmetic or a sort whose keys leave no tie (the row id is the
+last of them), so the CPU backend and the chip draw the same folds and a
+checkpoint replays on either.
 
 On a mesh (`assign_fold_masks_sharded`) the SAME assignment comes back
 sharded on rows, and no chip sorts the whole table: the random words are a
@@ -40,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.extend.random import threefry_2x32
+from jax.extend.random import threefry2x32_p
 
 # Version of the fold-assignment algorithm, folded into every sweep
 # checkpoint key (checkpoint.sweep_key): a record written under another
@@ -126,18 +127,62 @@ def _round_count_share(count, fraction: float):
 
 
 # -- the program --------------------------------------------------------------
-def _shuffled_rows(key, n: int, *leading):
-    """(*leading, row ids 0..n-1) sorted by the `leading` keys, then by 64
-    seeded random bits a row: ONE stable sort (two Threefry words; at 25M
-    rows two rows share all 64 with probability 2e-5, and stability then
-    decides). Row i's two words are the Threefry-2x32 block of the counter
-    pair (i, n + i) under the key words `fold_key(seed)`. With no leading key the row ids come back in a uniformly
-    random order; with the labels, grouped by class and in random order
-    within each."""
-    words = threefry_2x32((key[0], key[1]),
-                          lax.iota(jnp.uint32, 2 * n).reshape(2, n))
-    out = lax.sort((*leading, words[0], words[1], lax.iota(jnp.int32, n)),
-                   num_keys=len(leading) + 2, is_stable=True)
+# The chip's sort takes 3 % (25M keys) to a tenth (32M) longer a key over a
+# length that is no multiple of this (PERF.md, PRs 45 and 50): every sort
+# here runs over one.
+_SORT_TILE = 2048
+
+
+def fold_sort_shape(n: int, stratify: bool) -> dict:
+    """The shape of the one-device fold program's sort over `n` rows, as
+    _shuffled_rows builds it and the `fold_assign` span says it:
+    `sort_keys` (key operands — [label,] word 0, word 1, row id — and the
+    sort carries nothing else), `sort_places` (`n` raised to a multiple of
+    _SORT_TILE) and `pad_places` (the places past the rows)."""
+    pad = -n % _SORT_TILE
+    return dict(sort_keys=4 if stratify else 3, sort_places=n + pad,
+                pad_places=pad)
+
+
+def _row_words(key, n: int, start, count: int):
+    """The two Threefry words of rows [start, start + count) of `n`: row
+    i's are the Threefry-2x32 block of the counter pair (i, n + i) under
+    the key words `fold_key(seed)` — what `threefry_2x32(key, [i, n + i])`
+    returns, asked of the primitive itself: two arrays in, two out, where
+    the wrapper concatenates the counters and reshapes the words, a pass
+    over both that the chip runs as copies (PERF.md, PR 50)."""
+    i = start.astype(jnp.uint32) + lax.iota(jnp.uint32, count)
+    w0, w1 = threefry2x32_p.bind(key[0], key[1], i, jnp.uint32(n) + i)
+    return w0, w1
+
+
+def _shuffled_rows(key, n: int, labels=None):
+    """([labels,] row ids) over `fold_sort_shape`'s places, sorted by the
+    labels (when given), then by 64 seeded random bits a row (_row_words),
+    then by the row id: ONE unstable sort whose every operand is a key. At
+    25M rows two rows share all 64 bits with probability 2e-5, and the row
+    id, the last key, then decides — the ids are distinct and ascending in
+    input order, so that IS the order a stable sort on the words alone
+    gives (version 2's tie rule), and stability costs the chip an index
+    of its own: a fourth operand where the ids are no iota the compiler
+    can see (a quarter of the sort: PERF.md, PR 45), 100 MB of temporaries
+    at 25M rows where they are (PR 50). The places past the rows take
+    all-ones words, the ids n, n + 1, ... and a label above every class,
+    made in the fusion that makes the words (nothing is copied): larger
+    than any row in the last key at the least, they sort behind every
+    row, so the first `n` places are the rows'. With no labels the ids
+    come back in a uniformly random order; with them, grouped by class and
+    in random order within each."""
+    shape = fold_sort_shape(n, labels is not None)
+    places, pad = shape["sort_places"], shape["pad_places"]
+    ids = lax.iota(jnp.int32, places)
+    words = _row_words(key, n, jnp.uint32(0), places)
+    if pad:
+        words = [jnp.where(ids < n, w, jnp.uint32(0xFFFFFFFF)) for w in words]
+    leading = () if labels is None else (
+        jnp.pad(labels, (0, pad), constant_values=jnp.inf),)
+    out = lax.sort((*leading, *words, ids), num_keys=shape["sort_keys"],
+                   is_stable=False)
     return (*out[:len(leading)], out[-1])
 
 
@@ -158,20 +203,24 @@ def _fold_of(key, y, n: int, n_folds: int, val_fraction: Optional[float],
         # the sorted row ids ARE a uniformly random permutation: read as
         # "row i has rank ids[i]", no inverse needed
         rank, = _shuffled_rows(key, n)
-        return _fold_of_rank(rank, n, n_folds, val_fraction)
+        return _fold_of_rank(rank[:n], n, n_folds, val_fraction)
+    # over all the sorted places: the padding is one more class behind the
+    # rows', and its ids send its folds behind the rows' again
     cls, rows = _shuffled_rows(key, n, y)
-    pos = lax.iota(jnp.int32, n)
+    places = rows.shape[0]
+    pos = lax.iota(jnp.int32, places)
     first = jnp.concatenate([jnp.ones((1,), bool), cls[1:] != cls[:-1]])
     start = lax.cummax(jnp.where(first, pos, 0))
     rank = pos - start
     if split:
         last = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
-        end = lax.cummin(jnp.where(last, pos, n - 1), reverse=True)
+        end = lax.cummin(jnp.where(last, pos, places - 1), reverse=True)
         n_val = _round_count_share(end - start + 1, val_fraction)
         fold_sorted = (rank >= n_val).astype(jnp.int32)
     else:
         fold_sorted = rank % n_folds
-    return lax.sort_key_val(rows, fold_sorted)[1]
+    # the keys are a permutation: no tie for stability to decide
+    return lax.sort_key_val(rows, fold_sorted, is_stable=False)[1][:n]
 
 
 def _train_masks(fold_of, n_folds: int, split: bool):
@@ -195,7 +244,11 @@ def assign_fold_masks(key, y, *, n: int, n_folds: int,
     held out. Stratified: the rank is taken within the row's class (rows
     sorted by class then random bits, rank = position less the class's
     segment start), so the balance holds per class; the per-row result
-    returns to row order by a second sort on the row ids."""
+    returns to row order by a second sort on the row ids. Rows whose 64
+    random bits are equal keep their input order — the row id is the
+    sort's last key (_shuffled_rows), which is what the stable sort of
+    version 2 decided — so no sort here is stable and none carries an
+    operand that is not a key; `fold_sort_shape` is the sort's shape."""
     return _train_masks(
         _fold_of(key, y, n, n_folds, val_fraction, stratify), n_folds,
         val_fraction is not None)
@@ -220,22 +273,15 @@ def _partition_plan(n: int, shards: int) -> tuple:
     below a fixed word (at most sqrt(n) / 2). `capacity` is the places a
     run (one chip's keys in one window) is padded to: its expected length
     and as many deviations of it, and never more than the chip's rows; a
-    multiple of 2 048, because the chip's sort of the `shards * capacity`
-    places received takes a tenth longer over a length that is not one
-    (32 271 872 keys 0.127 s, 2**25 keys 0.118: PERF.md, PR 45)."""
+    multiple of _SORT_TILE, because the chip's sort of the `shards *
+    capacity` places received takes a tenth longer over a length that is
+    not one (32 271 872 keys 0.127 s, 2**25 keys 0.118: PERF.md, PR 45;
+    the one-device sort's length is fold_sort_shape's, by the same rule)."""
     n_local = n // shards
     margin = math.ceil(_PARTITION_SIGMAS / 2 * math.sqrt(n) * 2 ** 32 / n)
     run = n_local * min(1.0, 1 / shards + 2 * margin / 2 ** 32)
     capacity = math.ceil(run + _PARTITION_SIGMAS * math.sqrt(run))
-    return margin, min(n_local, -(-capacity // 2048) * 2048)
-
-
-def _row_words(key, n: int, start, count: int):
-    """The two Threefry words of rows [start, start + count) of `n`:
-    _shuffled_rows' counter pairs (i, n + i), for a slice of the rows."""
-    i = start.astype(jnp.uint32) + lax.iota(jnp.uint32, count)
-    words = threefry_2x32((key[0], key[1]), jnp.stack([i, jnp.uint32(n) + i]))
-    return words[0], words[1]
+    return margin, min(n_local, capacity + -capacity % _SORT_TILE)
 
 
 def _partitioned_ranks(w0, w1, n: int, axis_name: str,
@@ -243,10 +289,9 @@ def _partitioned_ranks(w0, w1, n: int, axis_name: str,
     """Inside a shard_map over `axis_name`: (uint32[n / shards] row ids at
     the chip's own positions of the global order, bool[] overflow), from
     the chip's own rows' words `w0`, `w1`. The order is _shuffled_rows':
-    by word 0, word 1, then row id — the row id is a third KEY here, which
-    is what the stable two-key sort of ascending ids gives without the
-    index operand a stable sort carries on the chip, and it puts the
-    padding (all ones, no row's id) after every key whatever its words.
+    by word 0, word 1, then row id, every operand a key and the sort
+    unstable, which puts the padding (all ones, no row's id) after every
+    key whatever its words.
     `overflow` is the same on every chip: a run longer than `capacity`
     (None: _partition_plan's; tests make a run overflow with a smaller
     one) or a window that misses a position its chip returns; the ids then
@@ -296,22 +341,31 @@ def _partitioned_ranks(w0, w1, n: int, axis_name: str,
         order, n_local * shard - first[shard], n_local), overflow
 
 
+def device_fold_route(n: int, stratify: bool) -> dict:
+    """What assign_fold_masks runs over `n` rows, as the `fold_assign`
+    span says it: `route` = `device` and fold_sort_shape's words."""
+    return dict(route="device", **fold_sort_shape(n, stratify))
+
+
 def sharded_fold_route(mesh, n: int, stratify: bool) -> dict:
     """What assign_fold_masks_sharded runs on `mesh` over `n` rows, as the
     `fold_assign` span says it: `route` (`partitioned`: each chip sorts
     its own rows and the runs it receives; `replicated`: every chip sorts
     all the rows — the stratified rule, and what answers a `partitioned`
-    program whose overflow flag is set), `sort_keys` (the longest sort a
-    chip runs on that route), `capacity` (places a run is padded to) and
-    `exchange_bytes` (a chip's operand of the all_to_all)."""
+    program whose overflow flag is set), `sort_keys` and `sort_places`
+    (fold_sort_shape's words, for the longest sort a chip runs on that
+    route; `pad_places` where the padding is static, the replicated
+    sort's), `capacity` (places a run is padded to) and `exchange_bytes`
+    (a chip's operand of the all_to_all)."""
     from ...parallel.mesh import mesh_batch_count
     if stratify:
-        return dict(route="replicated", sort_keys=n, capacity=0,
-                    exchange_bytes=0)
+        return dict(route="replicated", **fold_sort_shape(n, True),
+                    capacity=0, exchange_bytes=0)
     shards = mesh_batch_count(mesh)
     _, capacity = _partition_plan(n, shards)
-    return dict(route="partitioned", sort_keys=shards * capacity,
-                capacity=capacity, exchange_bytes=12 * shards * capacity)
+    return dict(route="partitioned", sort_keys=3,
+                sort_places=shards * capacity, capacity=capacity,
+                exchange_bytes=12 * shards * capacity)
 
 
 @functools.lru_cache(maxsize=None)

@@ -30,8 +30,8 @@ from ...ops import metrics_ops as M
 from ...stages.params import ParamMap
 from ...utils.metrics import collector
 from .folds import (
-    assign_fold_masks, assign_fold_masks_sharded, fold_key,
-    sharded_fold_route,
+    assign_fold_masks, assign_fold_masks_sharded, device_fold_route,
+    fold_key, sharded_fold_route,
 )
 
 
@@ -612,7 +612,7 @@ class Validator:
             elif self._resident:
                 how = sharded_fold_route(resident, len(y), self.stratify)
             else:
-                how = {"route": "device"}
+                how = device_fold_route(len(y), self.stratify)
             with _phase("fold_assign", rows=len(y), folds=n_folds,
                         stratify=self.stratify,
                         shards=shards if self._resident else 1, **how):
