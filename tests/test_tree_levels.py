@@ -143,6 +143,31 @@ def route_hist_slots(monkeypatch):
     return seen
 
 
+#: depth -> (the fit's arrays, the n_nodes route_hist was traced with): ONE
+#: trace a depth for the tests that read the level widths, at a row count
+#: no other test fits at (the jit cache cannot hold the program, so the fit
+#: really traces) — a second test of a depth runs the cached program
+_LEVEL_TRACES = {}
+
+
+def _level_trace(depth):
+    if depth not in _LEVEL_TRACES:
+        seen, real = [], PH.route_hist
+
+        def spy(*a, n_nodes, **k):
+            seen.append(n_nodes)
+            return real(*a, n_nodes=n_nodes, **k)
+        data = _data(n=300 + depth, folds=2, seed=depth)
+        PH.route_hist = spy
+        try:
+            T.fit_gbt_folds(*data, jax.random.PRNGKey(0), n_rounds=2,
+                            depth=depth, n_bins=7)
+        finally:
+            PH.route_hist = real
+        _LEVEL_TRACES[depth] = (data, seen)
+    return _LEVEL_TRACES[depth]
+
+
 def _one_tree_of(seen, depth):
     """The per-level slot counts of ONE tree out of a spy log: the round
     scan may trace its body more than once, every trace a whole tree."""
@@ -158,28 +183,24 @@ class TestLevelSlotCounts:
     if a form padded to the deepest level's slots comes back."""
 
     @pytest.mark.parametrize("depth", [3, 4, 5, 6])
-    def test_route_hist_traced_at_level_width(self, depth,
-                                              route_hist_slots):
-        # a row count no other test fits at: the jit cache cannot hold
-        # this program, so the fit really traces
-        Xb, y, W = _data(n=300 + depth, folds=2, seed=depth)
-        T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0), n_rounds=2,
-                        depth=depth, n_bins=7)
-        assert _one_tree_of(route_hist_slots, depth) == \
+    def test_route_hist_traced_at_level_width(self, depth):
+        _, seen = _level_trace(depth)
+        assert _one_tree_of(seen, depth) == \
             [1 << d for d in range(depth - 1)]
 
     @pytest.mark.parametrize("depth,slot_passes", [(6, 31), (4, 7)])
     def test_span_slot_passes_is_what_route_hist_saw(
-            self, depth, slot_passes, route_hist_slots):
+            self, depth, slot_passes):
         from transmogrifai_tpu.models.trees import _TreeEstimator
-        Xb, y, W = _data(n=310 + depth, folds=2, seed=depth)
+        # the fit the level-width test traced: its program, its spy log
+        (Xb, y, W), seen = _level_trace(depth)
         c = collector
         c.enable("tree_levels_span")
         try:
             _TreeEstimator._timed_fused_fit(
-                "tree_sweep_fold_fused", Xb, W.shape[0], depth, 1,
+                "tree_sweep_fold_fused", Xb, W.shape[0], depth, 2,
                 lambda: T.fit_gbt_folds(Xb, y, W, jax.random.PRNGKey(0),
-                                        n_rounds=1, depth=depth,
+                                        n_rounds=2, depth=depth,
                                         n_bins=7))
             c.finish()
         finally:
@@ -188,8 +209,7 @@ class TestLevelSlotCounts:
         assert sp.name == "tree_levels"
         assert sp.attrs["lanes"] == 2 and sp.attrs["depth"] == depth
         assert sp.attrs["slot_passes"] == slot_passes
-        assert sp.attrs["slot_passes"] == \
-            sum(_one_tree_of(route_hist_slots, depth))
+        assert sp.attrs["slot_passes"] == sum(_one_tree_of(seen, depth))
 
     @pytest.mark.parametrize("depth,node_rows", [(1, 24), (3, 40),
                                                  (6, 144)])
@@ -331,9 +351,16 @@ class TestShardedLanes:
         return dict(n_rounds=1, depth=3, n_bins=7, learning_rate=0.5,
                     reg_lambda=1.0, loss="squared", base_score=0.0)
 
-    def test_sharded_bit_exact_on_integer_payloads(self):
-        Xb, y, W = _data(n=640, folds=2, seed=1)
-        mesh = make_mesh(n_batch=2, n_model=1)
+    @pytest.fixture(scope="class")
+    def sharded(self):
+        """One matrix and one mesh for the class: the sharded form takes
+        its algebra scalars as lane vectors, so the integer-payload fits
+        of two tests are ONE sharded program at one shape."""
+        return _data(n=640, folds=2, seed=1), \
+            make_mesh(n_batch=2, n_model=1)
+
+    def test_sharded_bit_exact_on_integer_payloads(self, sharded):
+        (Xb, y, W), mesh = sharded
         key = jax.random.PRNGKey(3)
         un = T.fit_gbt_folds(Xb, y, W, key, **self._int_kw())
         sh = T.fit_gbt_folds_sharded(Xb, y, W, key, mesh=mesh,
@@ -342,9 +369,8 @@ class TestShardedLanes:
         # trees replicate: every shard grew from the same psum'd hists
         assert np.asarray(sh[0].feat).shape == (1, 2, 7)
 
-    def test_sharded_per_lane_vectors_bit_exact(self):
-        Xb, y, W = _data(n=512, folds=2, seed=2)
-        mesh = make_mesh(n_batch=2, n_model=1)
+    def test_sharded_per_lane_vectors_bit_exact(self, sharded):
+        (Xb, y, W), mesh = sharded
         key = jax.random.PRNGKey(5)
         kw = dict(self._int_kw(),
                   learning_rate=jnp.asarray([0.1, 0.3], jnp.float32),
@@ -353,11 +379,10 @@ class TestShardedLanes:
         sh = T.fit_gbt_folds_sharded(Xb, y, W, key, mesh=mesh, **kw)
         _assert_fit_equal(un, sh, "sharded lane vectors")
 
-    def test_sharded_matches_single_device_logistic(self):
+    def test_sharded_matches_single_device_logistic(self, sharded):
         """Multi-round logistic: real-valued payloads, so parity is
         allclose on a seed verified tie-free (see class docstring)."""
-        Xb, y, W = _data(n=640, folds=2, seed=1)
-        mesh = make_mesh(n_batch=2, n_model=1)
+        (Xb, y, W), mesh = sharded
         key = jax.random.PRNGKey(3)
         kw = dict(n_rounds=3, depth=3, n_bins=7, learning_rate=0.3,
                   reg_lambda=1.0, loss="logistic")

@@ -493,8 +493,8 @@ class Validator:
         # executed-FLOP accounting reads it; also mirrored into
         # utils/metrics.collector.sweep_convergence when collection is on)
         self.last_streamed_telemetry: Optional[Dict[str, Any]] = None
-        # forest-lane counts of the last sweep's tree families (None: no
-        # forest ran as lanes) — _count_tree_lanes
+        # what the last sweep's tree family counted: a forest's lanes or a
+        # booster's fold-fused fits (None: neither ran) — _count_tree_lanes
         self.last_tree_telemetry: Optional[Dict[str, Any]] = None
         self._external_mask_tag = ""  # set per validate() call
         # are the folds' held-out sets disjoint? (set per validate() call;
@@ -1029,12 +1029,29 @@ class Validator:
             mean_metric=float(np.mean(finite)) if finite else None)
 
     def _count_tree_lanes(self, est, lanes):
-        """Sum one grid point's forest-lane counts into
-        last_tree_telemetry (tree_lanes, lane_groups, bootstrap_draws
-        add up over a sweep's points; lanes_per_group is the widest; how
-        a real-valued payload was carried — payload_body, payload_rows,
+        """Sum what one tree fit of the sweep counted into
+        last_tree_telemetry — the one place for both families, told apart
+        by the dict's `route`. A forest grid point's lanes (no `route`:
+        "forest_lanes"): tree_lanes, lane_groups, bootstrap_draws add up
+        over a sweep's points; lanes_per_group is the widest; how a
+        real-valued payload was carried — payload_body, payload_rows,
         features_per_node, label_centre and payload_scale, the last two
-        fetched here — is the last point's)."""
+        fetched here — is the last point's. A booster's fold-fused fits
+        ("fold_fused": _TreeEstimator._count_booster_fit): programs,
+        rounds and scale_reductions add up, lanes is the widest
+        program's, payload_body and payload_rows the last fit's."""
+        if lanes.get("route") == "fold_fused":
+            tele = self.last_tree_telemetry or {
+                "model": type(est).__name__, "route": "fold_fused",
+                "programs": 0, "rounds": 0, "scale_reductions": 0,
+                "lanes": 0}
+            for key in ("programs", "rounds", "scale_reductions"):
+                tele[key] += int(lanes[key])
+            tele["lanes"] = max(tele["lanes"], int(lanes["lanes"]))
+            for key in ("payload_body", "payload_rows"):
+                tele[key] = lanes[key]
+            self.last_tree_telemetry = tele
+            return
         tele = self.last_tree_telemetry or {
             "model": type(est).__name__, "route": "forest_lanes",
             "tree_lanes": 0, "lane_groups": 0, "lanes_per_group": 0,
@@ -1533,6 +1550,9 @@ class Validator:
                         for k, gi in enumerate(gis):
                             record(gi, fused[k], route=grid_route)
                             fused_gis[gi] = grid_route
+                        if est.last_lane_telemetry:
+                            self._count_tree_lanes(
+                                est, est.last_lane_telemetry)
                         continue
                     for gi in gis:
                         est_g = est.copy(**grids[gi])
@@ -1544,7 +1564,7 @@ class Validator:
                         # a forest that ran as (tree, fold) lanes of the
                         # fused passes says so, and what it counted
                         lanes = getattr(est_g, "last_lane_telemetry", None)
-                        if lanes:
+                        if lanes and "route" not in lanes:
                             fused_gis[gi] = "mask_folds:forest_lanes"
                         record(gi, scores, route=fused_gis.get(gi))
                         if lanes:   # after the cell's own fetch: the

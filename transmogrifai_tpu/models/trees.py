@@ -196,7 +196,8 @@ class _TreeEstimator(PredictorEstimator):
         device path even on the CPU backend (the virtual-device parity
         story: sharded and single-device sweeps go through the SAME
         kernels; the native builder's near-tie choices differ)."""
-        if mesh is None and self._host_route():
+        if mesh is None and self._host_route() and not self._fused_sweep_here(
+                int(n_valid or X.shape[0])):
             return ("host",) + self._bin_host(X, n_valid=n_valid)
         return self._bin(X, n_valid=n_valid)
 
@@ -334,12 +335,22 @@ class _TreeEstimator(PredictorEstimator):
         # ADVICE round 5), where 0 falls back per-config. On a mesh the
         # lane row-planes shard, so the HBM lane budget scales with the
         # shard count (the chunker's lane-shard budget).
+        sharded = n_shards > 1
+        self.last_lane_telemetry = None
+        # the word of how a round's g enters the contraction: its rows a
+        # (lane, slot) size the plan; the sharded form issues one part
+        word = payload_body(self)
+        if sharded and T.PAYLOAD_PARTS[word] != 1:
+            word = self._decline_parts(
+                "rows sharded over a mesh: fit_gbt_folds_sharded takes the "
+                "payload in one part")
+        rows = T.payload_rows(word)
         chunk = pallas_hist.plan_lane_chunk(
-            Xb.shape[1], n_bins + 1, F, G, depth, n_shards=n_shards)
+            Xb.shape[1], n_bins + 1, F, G, depth, channels=rows,
+            n_shards=n_shards)
         if chunk == 0:
             return None
 
-        sharded = n_shards > 1
         self._last_grid_route = "grid_fused_sharded" if sharded \
             else "grid_fused"
         label = "tree_sweep_grid_fused_sharded" if sharded \
@@ -383,10 +394,11 @@ class _TreeEstimator(PredictorEstimator):
                         lane_vec=lane_vec):
                     return T.fit_gbt_folds(
                         Xb, y, W_lanes, key, n_bins=n_bins, loss=loss,
-                        **shared, **lane_vec)
+                        payload=word, **shared, **lane_vec)
             _, _, margins = self._timed_fused_fit(
                 label, Xb, g_here * F, depth, shared["n_rounds"], fit,
-                span=span)
+                span=span, payload=word)
+            self._count_booster_fit(word, g_here * F, shared["n_rounds"])
             outs.append(margins.reshape(F, g_here, n).transpose(1, 0, 2))
         return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
@@ -402,7 +414,7 @@ class _TreeEstimator(PredictorEstimator):
 
     @staticmethod
     def _timed_fused_fit(label, Xb, lanes, depth, n_rounds, call,
-                         span="tree_levels"):
+                         span="tree_levels", payload="gradient"):
         """Run one fused-sweep fit; when stage metrics are being
         collected, time it to completion and record a kernel-roofline
         span (analytic HBM bytes from the single traffic model in
@@ -419,16 +431,21 @@ class _TreeEstimator(PredictorEstimator):
         (ops/trees.fused_level_slots): 31 at depth 6; `route_node_rows`
         the node rows a lane the routing and lookup kernels lay out for
         one tree (pallas_hist.route_node_rows: 144 at depth 6, 896 when
-        every table was padded to 128). The span is there
+        every table was padded to 128); `payload_body` the word of how a
+        round's g enters the bfloat16 contraction (payload_body(est)),
+        `payload_rows` the rows a (lane, slot) it implies (3 | 5) and
+        `rounds` the boosting rounds of the fit. The span is there
         with collection off too (its profiler annotation costs nothing
         then); the fence and the kernel record are not: they change
         what a timed sweep measures."""
         from ..ops import pallas_hist
         from ..utils.metrics import collector
+        rows = T.payload_rows(payload)
         cm = collector.trace_span(
             span, kind="tree_fused", lanes=int(lanes), depth=int(depth),
             slot_passes=sum(T.fused_level_slots(int(depth))),
-            route_node_rows=pallas_hist.route_node_rows(int(depth)))
+            route_node_rows=pallas_hist.route_node_rows(int(depth)),
+            payload_body=payload, payload_rows=rows, rounds=int(n_rounds))
         if not collector.enabled:
             with cm:
                 return call()
@@ -444,14 +461,48 @@ class _TreeEstimator(PredictorEstimator):
             label, time.perf_counter() - t0,
             pallas_hist.fused_fit_bytes(
                 Xb.shape[0], Xb.shape[1], lanes, depth, n_rounds,
-                xb_itemsize=Xb.dtype.itemsize),
+                xb_itemsize=Xb.dtype.itemsize, payload_rows=rows),
             cold=cold,
             # shape attrs ride into the kernel span of the trace export,
             # so a Perfetto view names the program's sweep geometry
             attrs=dict(lanes=int(lanes), depth=int(depth),
-                       n_rounds=int(n_rounds), n_rows=int(Xb.shape[0])))
+                       n_rounds=int(n_rounds), n_rows=int(Xb.shape[0]),
+                       payload_rows=rows))
         _TreeEstimator._WARM_FUSED_SHAPES.add(sig)
         return out
+
+    #: what the booster fits of the last sweep call counted (programs,
+    #: rounds, scale reductions, the widest program's lanes, the payload
+    #: word and its rows): the validator sums it into last_tree_telemetry
+    last_lane_telemetry: Optional[Dict[str, Any]] = None
+
+    def _count_booster_fit(self, word, lanes, n_rounds) -> None:
+        """One fold-fused booster program into last_lane_telemetry (a
+        grid-fused group runs several: they add up)."""
+        tele = self.last_lane_telemetry or dict(
+            route="fold_fused", programs=0, rounds=0, scale_reductions=0,
+            lanes=0)
+        parts = T.PAYLOAD_PARTS[word]
+        tele.update(
+            programs=tele["programs"] + 1,
+            rounds=tele["rounds"] + int(n_rounds),
+            # one max-reduction over [lanes, N] a round, only in parts
+            scale_reductions=tele["scale_reductions"]
+            + (int(n_rounds) if parts != 1 else 0),
+            lanes=max(tele["lanes"], int(lanes)),
+            payload_body=word, payload_rows=T.payload_rows(word))
+        self.last_lane_telemetry = tele
+
+    def _decline_parts(self, reason: str) -> str:
+        """This fit carries the payload in ONE bfloat16 part where the
+        estimator's word (payload_body) asks for three: say so with a
+        `booster_parts_route_declined` event, as forest_lane_route_declined
+        does for the forests, and return the word that runs."""
+        from ..utils.metrics import collector
+        collector.event("booster_parts_route_declined",
+                        model=type(self).__name__,
+                        payload_body=payload_body(self), reason=reason)
+        return "gradient"
 
     def _sharded_route_ok(self, kw) -> bool:
         """Gate for the mesh-sharded fused sweep (mask_fit_scores_grid
@@ -480,8 +531,14 @@ class _TreeEstimator(PredictorEstimator):
             return True
 
     def _fused_route_ok(self, ctx, y, masks=None, depth=None):
-        """Shared gate for the fold-fused booster path: live pallas on a
-        single-device TPU above the fold-vmap row limit. Mesh-sharded
+        return not self._fused_route_why(ctx, y, masks, depth)
+
+    def _fused_route_why(self, ctx, y, masks=None, depth=None) -> str:
+        """Shared gate for the fold-fused booster path ("" = taken, else
+        why not): fused kernels to run on (a live pallas TPU; the
+        backends and the row floor are FOREST_LANE_BACKENDS /
+        FOREST_LANE_MIN_ROWS, the fused lane routes' hand overrides) on a
+        single device above the fold-vmap row limit. Mesh-sharded
         contexts keep the per-fold path HERE (single-config fits);
         the GRID sweep has its own mesh route — mask_fit_scores_grid
         dispatches to fit_gbt_folds_sharded under _sharded_route_ok.
@@ -489,21 +546,63 @@ class _TreeEstimator(PredictorEstimator):
         the fused kernel's VMEM footprint is checked too — its output
         block scales with folds x slots x F x bins, and an over-budget
         shape is a Mosaic compile failure, so those fall back to the
-        sequential per-fold path."""
+        sequential per-fold path. The footprint is that of the rows a
+        (lane, slot) the estimator's payload word implies."""
         from ..ops import pallas_hist
         Xb, _, n_bins = ctx
-        if (jax.default_backend() != "tpu"
-                or not pallas_hist.available()
-                or y.shape[0] <= self._VMAP_FOLD_MAX_ROWS):
-            return False
+        why = fused_lanes_why(int(y.shape[0]), self._VMAP_FOLD_MAX_ROWS)
+        if why:
+            return why
         if not self._one_device(Xb):
-            return False
+            return "the binned matrix is laid over a mesh"
         if masks is not None and depth is not None:
             # fit_gbt_folds histograms with B = n_bins + 1 slots per bin axis
+            rows = T.payload_rows(payload_body(self))
             if not pallas_hist.fused_hist_fits(
-                    Xb.shape[1], n_bins + 1, masks.shape[0], depth):
-                return False
-        return True
+                    Xb.shape[1], n_bins + 1, masks.shape[0], depth,
+                    channels=rows):
+                return (f"depth {depth} at {rows} rows a (lane, slot): the "
+                        f"fused output block outgrows VMEM")
+        return ""
+
+    def _fused_sweep_here(self, n_rows: int) -> bool:
+        """Would a sweep of this many rows take the boosters' fold-fused
+        route on this backend (_sweep_kw: the booster families)? Then it
+        runs on the device context whatever the backend — a CPU rehearsal
+        takes the kernels' jnp twins, not the native builder."""
+        return self._sweep_kw() is not None and not fused_lanes_why(
+            n_rows, self._VMAP_FOLD_MAX_ROWS)
+
+    def _fold_fused_scores(self, ctx, y, w, masks, kw, loss):
+        """The boosters' fold-fused fit (ops/trees.fit_gbt_folds: every
+        fold a lane of one program) under the estimator's payload word,
+        or None where _fused_route_why declines — with a
+        `booster_parts_route_declined` event where the word asked for
+        three parts and the sequential fits will issue one."""
+        word = payload_body(self)
+        self.last_lane_telemetry = None
+        if not self._fused_route_ok(ctx, y, masks, kw["depth"]):
+            if T.PAYLOAD_PARTS[word] != 1:
+                self._decline_parts(
+                    self._fused_route_why(ctx, y, masks, kw["depth"]))
+            return None
+        Xb, edges, n_bins = ctx
+        lanes = int(masks.shape[0])
+        _, _, margins = self._timed_fused_fit(
+            "tree_sweep_fold_fused", Xb, lanes, kw["depth"], kw["n_rounds"],
+            lambda: T.fit_gbt_folds(
+                Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
+                loss=loss, payload=word, **kw),
+            payload=word)
+        self._count_booster_fit(word, lanes, kw["n_rounds"])
+        return margins
+
+    def _decline_sequential(self) -> None:
+        """A single fit on the device grows one tree a program through
+        grow_tree, whose kernels take the payload in one part."""
+        if T.PAYLOAD_PARTS[payload_body(self)] != 1:
+            self._decline_parts("the sequential fit_gbt: one tree a "
+                                "program, its payload in one part")
 
     def _mask_score(self, ctx, y, w, n_classes, multiclass):
         raise NotImplementedError
@@ -569,6 +668,24 @@ FOREST_LANE_BACKENDS = ("tpu",)
 FOREST_LANE_MIN_ROWS = _TreeEstimator._VMAP_FOLD_MAX_ROWS
 
 
+def fused_lanes_why(n_rows: int, min_rows: Optional[int] = None) -> str:
+    """"" where a sweep of `n_rows` rows has fused kernels to run its
+    lanes on here, else why not: the backend and the row floor, the first
+    two questions of both fused lane routes (the forests'
+    forest_lane_plan, the boosters' _fused_route_why, which hands over
+    its estimator's own fold-vmap limit: the lower floor holds)."""
+    from ..ops import pallas_hist
+    floor = FOREST_LANE_MIN_ROWS if min_rows is None \
+        else min(min_rows, FOREST_LANE_MIN_ROWS)
+    backend = jax.default_backend()
+    if backend not in FOREST_LANE_BACKENDS or (
+            backend == "tpu" and not pallas_hist.available()):
+        return f"backend {backend}: no fused kernels to run on"
+    if n_rows <= floor:
+        return f"{n_rows} rows: at or under the fold-vmap limit {floor}"
+    return ""
+
+
 def forest_lane_route_ok(est, n_rows: int, n_feat: int, n_folds: int,
                          multiclass: bool = False) -> bool:
     """Does `est`'s mask-fold sweep of an [n_rows, n_feat] matrix run as
@@ -579,21 +696,33 @@ def forest_lane_route_ok(est, n_rows: int, n_feat: int, n_folds: int,
                                      multiclass=multiclass)[0] > 0
 
 
-def forest_payload_body(est) -> str:
-    """How `est`'s lane route carries its payload g = weight x label into
-    the fused passes' bfloat16 contraction — a key of
-    ops/trees.FOREST_PAYLOAD_PARTS: "indicator" for a class label (0/1: g
-    exact in one part, three rows a (lane, slot)), "centred_parts" for a
-    real-valued one (the label less its weighted mean, g as three exact
-    parts, five rows). THE word: the plan, the fit, the `forest_group`
-    span, `last_tree_telemetry` and the benchmark (which asks before it
-    makes any data) all read it here."""
-    return "indicator" if est.classification else "centred_parts"
+def payload_body(est) -> str:
+    """How `est`'s fused passes carry its payload g into the kernels'
+    bfloat16 contraction — a key of ops/trees.PAYLOAD_PARTS. A forest's g
+    is weight x label: "indicator" for a class label (0/1: g exact in one
+    part, three rows a (lane, slot)), "centred_parts" for a real-valued
+    one (the label less its weighted mean, g as three exact parts, five
+    rows). A booster's g is its loss's gradient x weight: "gradient" for
+    the logistic loss (|g| < 1, one part, three rows — what the boosters
+    always issued), "residual_parts" for the squared loss (w (F - y),
+    each round over that round's own scale, three exact parts, five
+    rows). THE word of both families: the plans (forest_lane_plan,
+    _fused_route_why, plan_lane_chunk), the fits, the `tree_fused` spans,
+    `last_tree_telemetry` and the benchmark (which asks before it makes
+    any data) all read it here."""
+    if isinstance(est, _ForestBase):
+        return "indicator" if est.classification else "centred_parts"
+    squared = (getattr(est, "_regression", False)
+               or getattr(est, "_loss", "logistic") == "squared")
+    return "residual_parts" if squared else "gradient"
+
+
+#: the name the forest cells' driver and tests ask the word under
+forest_payload_body = payload_body
 
 
 class _ForestBase(_TreeEstimator):
     classification = True
-    last_lane_telemetry: Optional[Dict[str, int]] = None
 
     def _forest_cfg(self, n_feat: int) -> Dict[str, Any]:
         return dict(
@@ -658,13 +787,9 @@ class _ForestBase(_TreeEstimator):
         trees. THE gate of the route: mask_sweep_context, the fused hook
         and the benchmark's predicate (forest_lane_route_ok) all ask it."""
         from ..ops import pallas_hist
-        backend = jax.default_backend()
-        if backend not in FOREST_LANE_BACKENDS or (
-                backend == "tpu" and not pallas_hist.available()):
-            return 0, f"backend {backend}: no fused kernels to run on"
-        if n_rows <= FOREST_LANE_MIN_ROWS:
-            return 0, (f"{n_rows} rows: at or under the fold-vmap limit "
-                       f"{FOREST_LANE_MIN_ROWS}")
+        why = fused_lanes_why(n_rows)
+        if why:
+            return 0, why
         if not self._one_channel(n_classes, multiclass):
             return 0, ("a payload of more than one channel (a multiclass "
                        "forest, or min_instances_per_node < 1): the fused "
@@ -674,7 +799,7 @@ class _ForestBase(_TreeEstimator):
         group = pallas_hist.plan_forest_group(
             n_rows, n_feat, int(self.get_param("max_bins")) + 1, n_folds,
             cfg["n_trees"], depth,
-            T.forest_payload_rows(forest_payload_body(self)))
+            T.payload_rows(payload_body(self)))
         if group == 0:
             return 0, (f"depth {depth}: plan_forest_group refuses the "
                        f"slot-dense output block of its deepest level")
@@ -716,8 +841,8 @@ class _ForestBase(_TreeEstimator):
         W = masks * w[None, :]
         votes = jnp.zeros((folds, n), jnp.float32)
         groups = -(-n_trees // group)
-        body = forest_payload_body(self)
-        rows = T.forest_payload_rows(body)
+        body = payload_body(self)
+        rows = T.payload_rows(body)
         per_node = T.features_per_node(cfg["feature_frac"], n_feat)
         # the label's [centre, scale]: device scalars the lanes shift and
         # divide by and the sums and leaves get back; the fit fetches
@@ -948,13 +1073,24 @@ class _GBTBase(_TreeEstimator):
     _loss = "logistic"  # subclass override; used by the mask-fold sweep
 
     def _gbt_kw(self):
+        """What every route of this family hands its fit (fit_gbt,
+        fit_gbt_folds and its sharded form, the native builder). The
+        SPLIT rule is Spark's, as the forests hold it: `normalize_gain`
+        compares min_info_gain with the gain a weighted row, so upstream's
+        grid (0.001 / 0.01 / 0.1) means here what it means there — not
+        with the gain summed over a node's rows, XGBoost's rule and
+        _XGBBase's. The BOOSTING rule stays this library's (see
+        OpGBTRegressor): base score the weighted label mean, Newton
+        leaves under reg_lambda 1.0 (the fits' default; none is passed),
+        step_size on every tree."""
         return dict(
             n_rounds=int(self.get_param("max_iter")),
             depth=int(self.get_param("max_depth")),
             learning_rate=float(self.get_param("step_size")),
             min_instances=float(self.get_param("min_instances_per_node")),
             min_info_gain=float(self.get_param("min_info_gain")),
-            subsample=float(self.get_param("subsampling_rate")))
+            subsample=float(self.get_param("subsampling_rate")),
+            normalize_gain=True)
 
     _sweep_kw = _gbt_kw  # config-fused sweep hook
 
@@ -971,6 +1107,7 @@ class _GBTBase(_TreeEstimator):
                 trees, base = out
                 return self._freeze(trees, jnp.asarray(edges)), float(base)
         Xb, edges, n_bins = self._bin(X)
+        self._decline_sequential()
         trees, base = T.fit_gbt(
             Xb, jnp.asarray(y, jnp.float32), jnp.asarray(w), self._key(),
             n_bins=n_bins, loss=loss, **kw)
@@ -986,16 +1123,7 @@ class _GBTBase(_TreeEstimator):
 
     def _mask_scores_fused(self, ctx, y, w, masks, n_classes, multiclass):
         kw = self._gbt_kw()
-        if not self._fused_route_ok(ctx, y, masks, kw["depth"]):
-            return None
-        Xb, edges, n_bins = ctx
-        _, _, margins = self._timed_fused_fit(
-            "tree_sweep_fold_fused", Xb, masks.shape[0], kw["depth"],
-            kw["n_rounds"],
-            lambda: T.fit_gbt_folds(
-                Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
-                loss=self._loss, **kw))
-        return margins
+        return self._fold_fused_scores(ctx, y, w, masks, kw, self._loss)
 
     def _mask_score_host(self, ctx, y, w, n_classes, multiclass):
         from ..ops import trees_host as TH
@@ -1012,7 +1140,19 @@ class _GBTBase(_TreeEstimator):
 
 class OpGBTClassifier(_GBTBase):
     """Reference OpGBTClassifier (147 LoC). Binary only — matching Spark's
-    GBTClassifier; multiclass boosting lives in OpXGBoostClassifier."""
+    GBTClassifier; multiclass boosting lives in OpXGBoostClassifier.
+
+    The SPLIT rule is Spark's: `min_info_gain` is compared with the gain a
+    weighted row of the node (_gbt_kw, `normalize_gain`), `min_instances_
+    per_node` with the rows a side. The BOOSTING rule is this library's,
+    not Spark's GradientBoostedTrees.boost: Spark fits regression trees
+    with plain-mean leaves to the LogLoss pseudo-residual of labels in
+    {-1, +1}, its first tree to the label itself at weight 1; here the
+    margin starts at the prior's logit and every round is a second-order
+    (Newton) step on the logistic loss, leaf -G / (H + 1) x step_size,
+    the gain G^2 / (H + 1) of the same sums. Its gradient p - y is under
+    1 in size and goes to the fused passes in one bfloat16 part
+    (payload_body: "gradient")."""
 
     problem_types = ("binary",)
 
@@ -1027,7 +1167,29 @@ class OpGBTClassifier(_GBTBase):
 
 
 class OpGBTRegressor(_GBTBase):
-    """Reference OpGBTRegressor (145 LoC)."""
+    """Reference OpGBTRegressor (145 LoC), squared loss.
+
+    The SPLIT rule is Spark's: `min_info_gain` is compared with the gain a
+    weighted row of the node (_gbt_kw, `normalize_gain`) on every route —
+    fit_gbt, fit_gbt_folds and its sharded form, the native builder — so
+    upstream's grid (0.001 / 0.01 / 0.1) means here what it means there.
+    The BOOSTING rule is this library's and departs from Spark 2.3's
+    GradientBoostedTrees.boost in three stated ways
+    (benchmark/configs/regression-10m-64-gbt.json `assumed` has their
+    sizes on the benchmark's data): (1) F starts at the weighted label
+    mean and the first tree is a round like the others, at step_size —
+    Spark fits its first tree to the label itself at weight 1; (2) a
+    round fits the residual y - F — Spark the pseudo-residual 2 (y - F),
+    twice the step a round and 4 x the gain its threshold sees; (3) a leaf
+    is the Newton step G / (H + reg_lambda) with reg_lambda 1.0 (the
+    fits' default; none is passed) — Spark's the plain mean G / H.
+
+    In the fold-fused sweep (fit_gbt_folds, one chip) a round's residual
+    reaches the kernels' bfloat16 contraction as three exact parts over
+    that round's own scale (payload_body: "residual_parts", five rows a
+    (lane, slot)); a fit that cannot take them — a mesh, rows under the
+    fold-vmap limit, the sequential fit_gbt — issues one part and says so
+    with a `booster_parts_route_declined` event."""
 
     problem_types = ("regression",)
     produces_probabilities = False
@@ -1181,18 +1343,9 @@ class _XGBBase(_TreeEstimator):
     def _mask_scores_fused(self, ctx, y, w, masks, n_classes, multiclass):
         if multiclass and not self._regression:
             return None   # softmax boosting keeps the per-fold path
-        kw = self._common()
-        if not self._fused_route_ok(ctx, y, masks, kw["depth"]):
-            return None
-        Xb, edges, n_bins = ctx
-        _, _, margins = self._timed_fused_fit(
-            "tree_sweep_fold_fused", Xb, masks.shape[0], kw["depth"],
-            kw["n_rounds"],
-            lambda: T.fit_gbt_folds(
-                Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
-                loss="squared" if self._regression else "logistic",
-                **kw))
-        return margins
+        return self._fold_fused_scores(
+            ctx, y, w, masks, self._common(),
+            "squared" if self._regression else "logistic")
 
     def _mask_score(self, ctx, y, w, n_classes, multiclass):
         Xb, edges, n_bins = ctx
@@ -1308,6 +1461,7 @@ class OpXGBoostRegressor(_XGBBase):
                     depth=kw["depth"], mode="regress_sum", base=float(base),
                     operation_name=self.operation_name, **frozen)
         Xb, edges, n_bins = self._bin(X)
+        self._decline_sequential()
         trees, base = T.fit_gbt(
             Xb, jnp.asarray(y, jnp.float32), jnp.asarray(w), self._key(),
             n_bins=n_bins, loss="squared", **kw)

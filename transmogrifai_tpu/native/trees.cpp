@@ -354,6 +354,7 @@ int gbt_fit_impl(const XbT* Xb, int64_t N, int32_t F, int32_t B,
                  double reg_lambda, double min_child_weight,
                  double min_instances, double min_info_gain, double gamma,
                  double subsample, double feature_frac, uint64_t seed,
+                 bool normalize_gain,
                  int32_t* feat, int32_t* thresh, int32_t* miss, float* leaf,
                  float* base_out) {
   if (N <= 0 || depth < 1 || depth > 20) return 1;
@@ -375,8 +376,10 @@ int gbt_fit_impl(const XbT* Xb, int64_t N, int32_t F, int32_t B,
   std::vector<float> gsub(N), hsub(N);
   std::vector<int32_t> nodeid(N);
   std::vector<uint8_t> fmask;
+  // normalize_gain: Spark's minInfoGain, a weighted row (the GBT family);
+  // false: XGBoost's, summed over the node
   GrowParams P{depth, B, 1, reg_lambda, min_child_weight, min_instances,
-               min_info_gain, gamma, false, lr, 0, 1.0};
+               min_info_gain, gamma, normalize_gain, lr, 0, 1.0};
   for (int t = 0; t < n_rounds; ++t) {
     for (int64_t r = 0; r < N; ++r) {
       if (loss == 0) {
@@ -573,20 +576,21 @@ int tmog_gbt_fit(const void* Xb, int64_t N, int32_t F, int32_t B,
                  double reg_lambda, double min_child_weight,
                  double min_instances, double min_info_gain, double gamma,
                  double subsample, double feature_frac, uint64_t seed,
+                 int32_t normalize_gain,
                  int32_t* feat, int32_t* thresh, int32_t* miss, float* leaf,
                  float* base_out) {
   if (xb_itemsize == 1)
     return gbt_fit_impl((const uint8_t*)Xb, N, F, B, y, w, loss, n_rounds,
                         depth, lr, reg_lambda, min_child_weight,
                         min_instances, min_info_gain, gamma, subsample,
-                        feature_frac, seed, feat, thresh, miss, leaf,
-                        base_out);
+                        feature_frac, seed, normalize_gain != 0, feat,
+                        thresh, miss, leaf, base_out);
   if (xb_itemsize == 4)
     return gbt_fit_impl((const int32_t*)Xb, N, F, B, y, w, loss, n_rounds,
                         depth, lr, reg_lambda, min_child_weight,
                         min_instances, min_info_gain, gamma, subsample,
-                        feature_frac, seed, feat, thresh, miss, leaf,
-                        base_out);
+                        feature_frac, seed, normalize_gain != 0, feat,
+                        thresh, miss, leaf, base_out);
   return 2;
 }
 

@@ -241,16 +241,21 @@ def sweep_level_bytes(n_rows: int, n_feat: int, lanes: int, *,
 
 
 def fused_fit_bytes(n_rows: int, n_feat: int, lanes: int, depth: int,
-                    n_rounds: int, *, xb_itemsize: int = 1) -> int:
+                    n_rounds: int, *, xb_itemsize: int = 1,
+                    payload_rows: int = 3) -> int:
     """Analytic HBM bytes for one whole fused-sweep GBT fit (all rounds).
 
     Per round: the level-0 histogram pass (Xb + per-lane payload + slot),
     depth-1 fused route+hist passes (_grow_tree_folds calls route_hist
     for every d in 0..depth-2; sweep_level_bytes each), the final
     standalone route (Xb + node read/write per lane) and the leaf lookup
-    + margin update (3 lane planes). Used by the sweep's roofline spans
-    (utils/metrics collector) — analytic by construction since the whole
-    fit is one jitted program."""
+    + margin update (3 lane planes). `payload_rows` = 5 (a payload in
+    three parts, ops/trees.PAYLOAD_PARTS): the kernels cut the parts in
+    VMEM from the same float32 planes, so a pass moves what it moved; what
+    a round adds is the scale — one more read of the gradient plane for
+    its max-reduction and the write of the scaled plane (2 lane planes).
+    Used by the sweep's roofline spans (utils/metrics collector) — analytic
+    by construction since the whole fit is one jitted program."""
     xb = n_rows * n_feat * xb_itemsize
     plane = 4 * n_rows
     level0 = xb + lanes * (2 * plane + plane)      # g/h + slot ids
@@ -258,7 +263,8 @@ def fused_fit_bytes(n_rows: int, n_feat: int, lanes: int, depth: int,
         n_rows, n_feat, lanes, xb_itemsize=xb_itemsize, fused=True)
     final_route = (xb + lanes * 2 * plane) if depth >= 1 else 0
     leaf_margin = lanes * 3 * plane
-    return n_rounds * (level0 + mid + final_route + leaf_margin)
+    scaled = lanes * 2 * plane if payload_rows > 3 else 0
+    return n_rounds * (level0 + mid + final_route + leaf_margin + scaled)
 
 
 # THE pallas kill switch — single flag for every consumer (tree
@@ -316,11 +322,14 @@ def available() -> bool:
 # multiples of 2^-7 and of 2^-15, whose float32 sums over millions of rows
 # are themselves exact, and a rest under 2^-16 — so a histogram sum is
 # the float64 sum to ~2^-25 of the scale a row, not float32's rounding
-# of a long accumulation. Who decides: ops/trees.fit_forest_lanes from
-# its `payload` argument (a regression forest's centred, scaled label
-# takes three parts); the boosters' gradients and a weight row under
-# real-valued sample weights still go as one part. set_hist_bf16(False) is
-# the lever of the float32 parity tests.
+# of a long accumulation. Who decides: the word models/trees.payload_body
+# gives the estimator (ops/trees.PAYLOAD_PARTS), handed to
+# ops/trees.fit_forest_lanes and fit_gbt_folds as their `payload` argument:
+# a regression forest's centred, scaled label takes three parts, and so
+# does a squared-loss booster's residual, each round over that round's own
+# scale; the logistic boosters' gradients (under 1 in size) and a weight
+# row under real-valued sample weights still go as one part.
+# set_hist_bf16(False) is the lever of the float32 parity tests.
 _HIST_BF16 = True
 
 

@@ -937,7 +937,8 @@ def _squared_grad(pred, y, w):
     jax.jit,
     static_argnames=("n_rounds", "depth", "n_bins", "loss", "subsample",
                      "feature_frac", "alpha", "max_delta_step",
-                     "colsample_bylevel", "base_score", "allow_pallas"))
+                     "colsample_bylevel", "base_score", "allow_pallas",
+                     "normalize_gain"))
 def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
             n_rounds: int, depth: int, n_bins: int,
             learning_rate: float = 0.1, reg_lambda: float = 1.0,
@@ -947,7 +948,8 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
             loss: str = "logistic", alpha: float = 0.0,
             max_delta_step: float = 0.0, colsample_bylevel: float = 1.0,
             base_score: Optional[float] = None,
-            allow_pallas: bool = True) -> Tuple[Tree, jax.Array]:
+            allow_pallas: bool = True,
+            normalize_gain: bool = False) -> Tuple[Tree, jax.Array]:
     """Second-order boosted trees (XGBoost `hist` equivalent, one XLA program).
 
     loss='logistic' -> binary margins; loss='squared' -> regression. Returns
@@ -960,6 +962,11 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
     callers pass it when Xb is laid out over several devices — a
     pallas_call under plain GSPMD is not partitioned, each device would
     gather and histogram the whole matrix.
+    `normalize_gain`: compare `min_info_gain` with the gain a weighted row
+    (Spark's minInfoGain: the GBT family, models/trees._GBTBase) and not
+    with the gain summed over the node's rows (XGBoost's: the default).
+    The payload g goes to grow_tree as it is, in one part: the three-part
+    form is fit_gbt_folds' (models/trees.payload_body).
     """
     grad_fn = _logistic_grad if loss == "logistic" else _squared_grad
     wsum = w.sum() + EPS
@@ -991,7 +998,8 @@ def fit_gbt(Xb: jax.Array, y: jax.Array, w: jax.Array, key: jax.Array, *,
                          min_instances=min_instances,
                          min_info_gain=min_info_gain, gamma=gamma,
                          leaf_mode="newton", feature_mask=fm,
-                         learning_rate=learning_rate, normalize_gain=False,
+                         learning_rate=learning_rate,
+                         normalize_gain=normalize_gain,
                          allow_pallas=allow_pallas,
                          alpha=alpha, max_delta_step=max_delta_step,
                          level_feature_frac=colsample_bylevel,
@@ -1177,11 +1185,15 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     hist = None
 
     def unscaled(h):
-        # the g rows back in the label's units (a power of two: exact)
+        # the g rows back in the label's units (a power of two: exact);
+        # one scale for every lane (a forest's) or one a lane [Fo] (a
+        # booster round's)
         if payload_scale is None:
             return h
+        by = payload_scale if getattr(payload_scale, "ndim", 0) == 0 \
+            else payload_scale[:, None, None]
         h = h.reshape(Fo, -1, 3, F * B)
-        return h.at[:, :, 0].multiply(payload_scale).reshape(-1, F * B)
+        return h.at[:, :, 0].multiply(by).reshape(-1, F * B)
 
     for d, n_nodes in enumerate(level_slots(depth)):
         if d == 0:
@@ -1295,6 +1307,52 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
         (jnp.concatenate(subsets, axis=1) if subsets else None)
 
 
+#: How a lane's payload g reaches the kernels' bfloat16 contraction, by the
+#: word models/trees.payload_body gives an estimator: the bfloat16 parts of
+#: g. A forest lane's g = weight x label. "indicator": a 0/1 label, g an
+#: integer under 256 under unit sample weights, exact in ONE part and three
+#: rows a (lane, slot). "centred_parts": a real-valued label less its
+#: weighted mean and over a power of two that brings g into [-1, 1]
+#: (forest_label_centre), g as THREE fixed-point parts, five rows a (lane,
+#: slot) — every product exact, the parts' sums exact; the variance gain
+#: does not see the shift, and the leaves get it back. A booster lane's g is
+#: the loss's gradient x weight. "gradient": the logistic p - y, under 1 in
+#: size, rounded ONCE to bfloat16 — what the boosters always issued.
+#: "residual_parts": the squared loss's w (F - y), real-valued and as large
+#: as the label's spread: a ROUND takes each lane's scale from its largest
+#: size (_residual_scale) and hands g / scale on as three fixed-point
+#: parts, five rows. No centre is taken out of a residual: the base score
+#: is the label's weighted mean (base = wy / wsum), so round 1's g is the
+#: centred label already and later rounds' lie about zero.
+PAYLOAD_PARTS = {"indicator": 1, "centred_parts": 3,
+                 "gradient": 1, "residual_parts": 3}
+
+
+def payload_rows(payload: str) -> int:
+    """Rows a (lane, slot) the fused passes issue under this payload word:
+    g's parts, the weight and the derived count (3 | 5)."""
+    from . import pallas_hist
+    return pallas_hist.payload_rows(2, PAYLOAD_PARTS[payload], True)
+
+
+#: the name the forests' callers took before the boosters had a word
+forest_payload_rows = payload_rows
+
+
+def _residual_scale(g: jax.Array) -> jax.Array:
+    """[Fo] the power of two just over each lane's largest |g| (g [Fo, N]
+    float32): g / scale lies inside (-1, 1), where pallas_hist._unit_cuts'
+    parts are fixed-point and their sums exact. Read off the float32's own
+    exponent field — no log2 / exp2 whose last bit a backend may round —
+    so it IS a power of two and the division and the sums' way back are
+    exact. One max-reduction a round over [Fo, N]; a lane of zeros reads
+    2^-126 and stays zeros."""
+    top = jnp.max(jnp.abs(g), axis=1)
+    bits = jax.lax.bitcast_convert_type(top, jnp.int32)
+    up = jnp.minimum((bits >> 23) + 1, 254) << 23
+    return jax.lax.bitcast_convert_type(up, jnp.float32)
+
+
 def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
                         learning_rate=0.1, reg_lambda=1.0,
                         min_child_weight=0.0, min_instances=1.0,
@@ -1302,12 +1360,17 @@ def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
                         feature_frac=1.0, loss="logistic",
                         interpret=False, alpha=0.0, max_delta_step=0.0,
                         colsample_bylevel=1.0, base_score=None,
-                        axis_name=None):
+                        axis_name=None, normalize_gain=False,
+                        payload="gradient"):
     """Shared body of fit_gbt_folds (single device, axis_name=None) and
     fit_gbt_folds_sharded (inside shard_map: inputs hold this shard's
     LOCAL rows and every histogram/base-score reduction psums over
-    `axis_name`)."""
+    `axis_name`; the sharded form hands over no `payload`: one part)."""
     grad_fn = _logistic_grad if loss == "logistic" else _squared_grad
+    parts = PAYLOAD_PARTS[payload]
+    if parts != 1 and axis_name is not None:
+        raise ValueError("a payload in parts takes its scale from one "
+                         "device's rows: the sharded route hands over none")
     Fo, N = W.shape
     n_orig = N
     if subsample < 1.0 and axis_name is not None:
@@ -1382,6 +1445,13 @@ def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
         # like grow_tree splits its key, so the fused and sequential
         # routes draw identical level subsets); per-node resampling stays
         # unused — boosting samples features per tree/level, not per node
+        scale = None
+        if parts == 3:
+            # THIS round's residual, every lane by its own largest size:
+            # rounds shrink it, and a scale left from round 1 would push
+            # the parts down the fixed-point grid bit by bit
+            scale = _residual_scale(g)
+            g = g * (1.0 / scale)[:, None]
         tree, leaf_rows, _ = _grow_tree_folds(
             Xb_t, g, h, depth=depth, n_bins=n_bins,
             reg_lambda=reg_lambda, min_child_weight=min_child_weight,
@@ -1395,7 +1465,8 @@ def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
                 # tmoglint: disable=TPU001  static python scalar
                 max(1, int(round(feature_frac * Xb_t.shape[0])))
                 if feature_frac < 1.0 else None),
-            axis_name=axis_name)
+            axis_name=axis_name, normalize_gain=normalize_gain,
+            payload_parts=parts, payload_scale=scale)
         return (margin + leaf_rows,), tree
 
     init = jnp.broadcast_to(base[:, None], (Fo, N)).astype(jnp.float32)
@@ -1409,7 +1480,8 @@ def _fit_gbt_folds_impl(Xb, y, W, key, *, n_rounds, depth, n_bins,
     jax.jit,
     static_argnames=("n_rounds", "depth", "n_bins", "loss", "subsample",
                      "feature_frac", "interpret", "alpha",
-                     "max_delta_step", "colsample_bylevel", "base_score"))
+                     "max_delta_step", "colsample_bylevel", "base_score",
+                     "normalize_gain", "payload"))
 def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
                   key: jax.Array, *, n_rounds: int, depth: int,
                   n_bins: int, learning_rate: float = 0.1,
@@ -1420,7 +1492,8 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
                   interpret: bool = False, alpha: float = 0.0,
                   max_delta_step: float = 0.0,
                   colsample_bylevel: float = 1.0,
-                  base_score: Optional[float] = None):
+                  base_score: Optional[float] = None,
+                  normalize_gain: bool = False, payload: str = "gradient"):
     """Boosted trees for every CV fold in ONE device program.
 
     The mask-fold sweep (models/trees.mask_fit_scores) above the fold-vmap
@@ -1442,6 +1515,22 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
     margins are the fitted scores for ALL rows (held-out rows are routed
     through each fold's trees), i.e. exactly what the sequential
     per-fold `base + predict_forest_bins(...)` loop produces.
+
+    `normalize_gain` as in fit_gbt: Spark's minInfoGain, a weighted row.
+    `payload` (PAYLOAD_PARTS; the CALLER vouches for it, by
+    models/trees.payload_body) says how a round's g enters the kernels'
+    bfloat16 contraction. "gradient": rounded once, at 2^-9 of each value —
+    the logistic loss's, whose |g| < 1. "residual_parts": the squared
+    loss's g = w (F - y) computed in float32, divided by the round's scale
+    (_residual_scale: a power of two a lane, from that lane's largest |g|
+    THIS round) and cut into three fixed-point bfloat16 parts, five rows a
+    (lane, slot): every histogram sum of g the splits and the leaves are
+    read from is the sum of exact products added exactly, to ~2^-25 of the
+    scale a row. Who is exact: g whatever the sample weights (the parts
+    hold all 24 bits of w (F - y)); h = w in its ONE part only while w x
+    fold mask is 0 or 1 or another value of eight significant bits — under
+    real-valued sample weights h is rounded at 2^-9 of each value, on
+    every route (ROADMAP R7 (d) (4)).
     """
     return _fit_gbt_folds_impl(
         Xb, y, W, key, n_rounds=n_rounds, depth=depth, n_bins=n_bins,
@@ -1450,7 +1539,8 @@ def fit_gbt_folds(Xb: jax.Array, y: jax.Array, W: jax.Array,
         min_info_gain=min_info_gain, gamma=gamma, subsample=subsample,
         feature_frac=feature_frac, loss=loss, interpret=interpret,
         alpha=alpha, max_delta_step=max_delta_step,
-        colsample_bylevel=colsample_bylevel, base_score=base_score)
+        colsample_bylevel=colsample_bylevel, base_score=base_score,
+        normalize_gain=normalize_gain, payload=payload)
 
 
 # -- forest lanes -----------------------------------------------------------
@@ -1484,28 +1574,10 @@ def forest_bootstrap(key: jax.Array, start, subsample, *, n_rows: int,
     return rw * live[:, None].astype(jnp.float32), kf
 
 
-#: How a forest lane's payload g = weight x label reaches the bfloat16
-#: contraction, by the word models/trees.forest_payload_body gives an
-#: estimator: the bfloat16 parts of g. "indicator": a 0/1 label, g an
-#: integer under 256 under unit sample weights, exact in ONE part and
-#: today's three rows a (lane, slot). "centred_parts": a real-valued label
-#: less its weighted mean and over a power of two that brings g into
-#: [-1, 1] (forest_label_centre), g as THREE fixed-point parts, five rows
-#: a (lane, slot) — every product exact, the parts' sums exact; the
-#: variance gain does not see the shift, and the leaves get it back.
-FOREST_PAYLOAD_PARTS = {"indicator": 1, "centred_parts": 3}
-
 #: the largest bootstrap draw the payload's scale leaves room for (a
 #: Poisson(1) draw passes it once in ~10^14; past it the parts round, as
 #: pallas_hist._unit_cuts says)
 _PAYLOAD_DRAW_ROOM = 16.0
-
-
-def forest_payload_rows(payload: str) -> int:
-    """Rows a (lane, slot) the fused passes issue under this payload body:
-    g's parts, the weight and the derived count."""
-    from . import pallas_hist
-    return pallas_hist.payload_rows(2, FOREST_PAYLOAD_PARTS[payload], True)
 
 
 @jax.jit
@@ -1516,7 +1588,10 @@ def forest_label_centre(y: jax.Array, w: jax.Array) -> jax.Array:
     of two of each other; the scale the power of two at or over
     _PAYLOAD_DRAW_ROOM x the largest w x |y - centre|, which brings every
     lane's weight x (y - centre) into [-1, 1]. One pair for every fold and
-    tree of a sweep: the variance gain is the same about any constant."""
+    tree of a sweep: the variance gain is the same about any constant. (A
+    booster's residual needs no centre and no leaf_offset: its base score
+    is the label's weighted mean, so its payload lies about zero from
+    round 1 — PAYLOAD_PARTS, "residual_parts".)"""
     c = (w * y).sum() / jnp.maximum(w.sum(), EPS)
     c = jax.lax.reduce_precision(c, exponent_bits=8, mantissa_bits=7)
     top = _PAYLOAD_DRAW_ROOM * jnp.max(w * jnp.abs(y - c))
@@ -1547,7 +1622,7 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
     are Spark's (normalised by the node's weight, `min_instances`,
     `min_info_gain`), leaves weighted means.
 
-    `payload` (FOREST_PAYLOAD_PARTS; the CALLER vouches for it) says how
+    `payload` (PAYLOAD_PARTS; the CALLER vouches for it) says how
     g = weight x y enters the kernels' bfloat16 contraction. "indicator":
     y is 0 or 1, g rounded once — exact while the weights are integers
     under 256 (unit sample weights), else rounded at 2^-9 of each value
@@ -1589,7 +1664,7 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
         min_info_gain=min_info_gain, gamma=0.0, learning_rate=1.0,
         feature_mask=None, interpret=interpret, normalize_gain=True,
         leaf_mode="mean", node_feature_frac=feature_frac,
-        node_keys=node_keys, payload_parts=FOREST_PAYLOAD_PARTS[payload],
+        node_keys=node_keys, payload_parts=PAYLOAD_PARTS[payload],
         payload_scale=scale, leaf_offset=centre if centred else None)
     group_votes = leaf_rows[:, :n_orig].reshape(-1, folds, n_orig).sum(0)
     return votes + group_votes, trees, subsets
@@ -1655,7 +1730,8 @@ def fit_gbt_folds_sharded(Xb: jax.Array, y: jax.Array, W: jax.Array,
                           loss: str = "logistic", interpret: bool = False,
                           alpha: float = 0.0, max_delta_step: float = 0.0,
                           colsample_bylevel: float = 1.0,
-                          base_score: Optional[float] = None):
+                          base_score: Optional[float] = None,
+                          normalize_gain: bool = False):
     """fit_gbt_folds with rows sharded over the mesh batch axis.
 
     The DrJAX MapReduce shape over parallel/mesh.py: each device streams
@@ -1699,7 +1775,8 @@ def fit_gbt_folds_sharded(Xb: jax.Array, y: jax.Array, W: jax.Array,
         ("interpret", bool(interpret)), ("alpha", float(alpha)),
         ("max_delta_step", float(max_delta_step)),
         ("colsample_bylevel", float(colsample_bylevel)),
-        ("base_score", None if base_score is None else float(base_score)))
+        ("base_score", None if base_score is None else float(base_score)),
+        ("normalize_gain", bool(normalize_gain)))
     fn = _sharded_gbt_fn(mesh, static_kw)
     if mesh_is_multiprocess(mesh):
         from ..parallel import multihost as MH

@@ -133,9 +133,11 @@ def fit_gbt_host(Xb: np.ndarray, y: np.ndarray, w: np.ndarray, *,
                  min_child_weight: float = 0.0, min_instances: float = 1.0,
                  min_info_gain: float = 0.0, gamma: float = 0.0,
                  subsample: float = 1.0, feature_frac: float = 1.0,
-                 seed: int = 42, loss: str = "logistic"):
-    """Native fit_gbt twin. Returns (Tree-of-ndarrays [R, ...], base) or
-    None when the library is unavailable."""
+                 seed: int = 42, loss: str = "logistic",
+                 normalize_gain: bool = False):
+    """Native fit_gbt twin (`normalize_gain` as there: Spark's minInfoGain
+    a weighted row). Returns (Tree-of-ndarrays [R, ...], base) or None
+    when the library is unavailable."""
     lib = _load()
     if lib is None:
         return None
@@ -161,6 +163,7 @@ def fit_gbt_host(Xb: np.ndarray, y: np.ndarray, w: np.ndarray, *,
         ctypes.c_double(min_info_gain), ctypes.c_double(gamma),
         ctypes.c_double(subsample), ctypes.c_double(feature_frac),
         ctypes.c_uint64(seed & (2**64 - 1)),
+        ctypes.c_int32(1 if normalize_gain else 0),
         _c(feat, _i32p), _c(thresh, _i32p), _c(miss, _i32p),
         _c(leaf, _f32p), ctypes.byref(base))
     if rc != 0:
