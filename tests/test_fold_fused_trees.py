@@ -153,7 +153,10 @@ def test_mask_fit_scores_routes_through_fused_hook(monkeypatch):
 
     def fake_fit_gbt_folds(Xb_a, y_a, W_a, key, **kw):
         seen.update(kw, W=np.asarray(W_a))
-        return None, None, jnp.full((W_a.shape[0], y_a.shape[0]), 0.5)
+        nodes = jnp.zeros((kw["n_rounds"], W_a.shape[0], 7), jnp.int32)
+        stumps = T.Tree(nodes, nodes, jnp.zeros(nodes.shape[:2] + (8, 1)),
+                        nodes)
+        return stumps, None, jnp.full((W_a.shape[0], y_a.shape[0]), 0.5)
 
     monkeypatch.setattr(T, "fit_gbt_folds", fake_fit_gbt_folds)
     monkeypatch.setattr(type(est), "_fused_route_ok",

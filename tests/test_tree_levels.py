@@ -21,7 +21,14 @@ Contracts pinned here:
      axis, psum-merged per-level histograms) matches the single-device
      fused fit on the 2-device CPU mesh, and mask_fit_scores_grid takes
      it instead of falling back per-fold;
-  5. uint8 binning for 128..255 bins is decision-identical to int32.
+  5. uint8 binning for 128..255 bins is decision-identical to int32;
+  6. a level at which no lane has a node left to split ends the tree
+     (PR 52): the remaining passes sit under a cond and are not run, a
+     dead node's all-left child holds the node's own sums whether its
+     pass ran or not — so contract 1 holds when one lane's level is dead
+     and another's is live — the passes run are counted off the trees,
+     and a fit whose nodes or levels draw feature subsets keeps the
+     unconditional loop.
 """
 import functools
 
@@ -538,3 +545,325 @@ def test_fused_folds_still_equal_single_fold_runs_in_interpret_mode():
         np.testing.assert_array_equal(np.asarray(margins[k]),
                                       np.asarray(m1[0]))
         assert float(base[k]) == float(base1[0])
+
+
+# -- a dead level ends the tree ----------------------------------------------
+
+DEAD_ROUNDS, DEAD_DEPTH, DEAD_BINS = 5, 4, 7
+DEAD_KW = dict(n_rounds=DEAD_ROUNDS, depth=DEAD_DEPTH, n_bins=DEAD_BINS,
+               learning_rate=0.5, loss="squared", normalize_gain=True,
+               payload="residual_parts")
+#: min_info_gain -> the live nodes of every (round, lane, level) the twins
+#: grow on _reg_data, and so the level passes the program runs. 0.2: round
+#: 0's last level dead in every lane (levels >= 3), round 1's levels >= 1,
+#: the ROOT of every lane from round 2 on. 0.002: in round 4 lane 0's
+#: levels 2 and 3 are dead while lanes 1 and 2 split there. 0.0: every
+#: lane splits at every level of every round.
+DEAD_CASES = {
+    0.2: dict(run=4, dead_root_from=2),
+    0.002: dict(run=20, lane_dead_where_others_live=(4, 0, 2)),
+    0.0: dict(run=20, nothing_dead=True),
+}
+
+
+def _reg_data(n=700, f=6, b=DEAD_BINS, folds=3, seed=0):
+    rng = np.random.default_rng(seed)
+    Xb = rng.integers(0, b + 1, size=(n, f)).astype(np.int8)
+    y = (Xb[:, 0] * 0.5 + (Xb[:, 1] > 3) * 1.0
+         + 0.2 * rng.normal(size=n)).astype(np.float32)
+    masks = (rng.integers(0, folds, size=n)[None, :]
+             != np.arange(folds)[:, None]).astype(np.float32)
+    return jnp.asarray(Xb), jnp.asarray(y), jnp.asarray(masks)
+
+
+def _live_by_level(trees, depth=DEAD_DEPTH, bins=DEAD_BINS):
+    """[rounds, lanes, depth] live nodes a level, off the split tables."""
+    dead = np.asarray((trees.feat == 0) & (trees.thresh == bins)
+                      & (trees.miss == 0))
+    return np.stack([(~dead[..., (1 << d) - 1:(2 << d) - 1]).sum(-1)
+                     for d in range(depth)], axis=-1)
+
+
+@pytest.fixture(scope="module")
+def dead_fits():
+    """min_info_gain -> (fused, singles) of the squared-loss booster."""
+    data = _reg_data()
+    return {mig: _fit_lanes_and_each(*data, jax.random.PRNGKey(7),
+                                     min_info_gain=mig, **DEAD_KW)
+            for mig in DEAD_CASES}
+
+
+@pytest.fixture(scope="module")
+def every_pass_fits():
+    """The same boosters through the loop that runs every pass (the rule
+    switched off around a private jit: what the parent commit ran)."""
+    data = _reg_data()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(T, "dead_levels_end_tree", lambda *a, **k: False)
+        fit = jax.jit(functools.partial(T._fit_gbt_folds_impl, **DEAD_KW))
+        return {mig: jax.block_until_ready(
+            fit(*data, jax.random.PRNGKey(7), min_info_gain=mig))
+            for mig in DEAD_CASES}
+
+
+class TestDeadLevels:
+    """A level with no live node in any lane ends the tree."""
+
+    @pytest.mark.parametrize("mig", list(DEAD_CASES))
+    def test_lanes_equal_singles_bit_for_bit(self, dead_fits, mig):
+        fused, singles = dead_fits[mig]
+        assert len(fused) == 3          # (trees, base, margins), as ever
+        _assert_lanes_equal_singles(fused, singles, f"mig={mig}")
+        live = _live_by_level(fused[0])
+        case = DEAD_CASES[mig]
+        if "dead_root_from" in case:
+            r0 = case["dead_root_from"]
+            assert (live[:r0, :, 0] == 1).all() and (live[r0:] == 0).all()
+            # levels >= 3 of round 0 and levels >= 1 of round 1
+            assert (live[0, :, 3] == 0).all() and (live[0, :, 2] > 0).all()
+            assert (live[1, :, 1:] == 0).all()
+        if "lane_dead_where_others_live" in case:
+            r, lane, lvl = case["lane_dead_where_others_live"]
+            others = [k for k in range(live.shape[1]) if k != lane]
+            assert live[r, lane, lvl] == 0 and (live[r, others, lvl] > 0).all()
+            # alone, that lane's tree ended there: its own program skipped
+            # the passes the fused one ran
+            alone = T.level_passes_run(singles[lane][0], depth=DEAD_DEPTH,
+                                       n_bins=DEAD_BINS)
+            assert int(alone) < DEAD_ROUNDS * DEAD_DEPTH == case["run"]
+        if case.get("nothing_dead"):
+            assert (live > 0).all()     # a dead node or two, no dead level
+
+    @pytest.mark.parametrize("mig", list(DEAD_CASES))
+    def test_passes_run_are_counted_off_the_trees(self, dead_fits, mig):
+        trees = dead_fits[mig][0][0]
+        run = T.level_passes_run(trees, depth=DEAD_DEPTH, n_bins=DEAD_BINS)
+        assert run.dtype == jnp.int32 and int(run) == DEAD_CASES[mig]["run"]
+        # a round runs the levels before its first level dead in EVERY lane
+        live = _live_by_level(trees).sum(axis=1) > 0        # [rounds, depth]
+        assert int(run) == int(np.cumprod(live, axis=1).sum())
+
+    @pytest.mark.parametrize("mig", list(DEAD_CASES))
+    def test_against_the_loop_that_runs_every_pass(
+            self, dead_fits, every_pass_fits, mig):
+        """Split arrays, base and margins bit for bit; a dead node's leaf
+        within one float32 re-summation (the unconditional loop sums the
+        same rows again in another pass's order) — and where nothing is
+        dead, every array equal."""
+        (trees, base, margins), _ = dead_fits[mig]
+        ref_trees, ref_base, ref_margins = every_pass_fits[mig]
+        for fld in ("feat", "thresh", "miss"):
+            np.testing.assert_array_equal(np.asarray(getattr(trees, fld)),
+                                          np.asarray(getattr(ref_trees, fld)))
+        np.testing.assert_array_equal(np.asarray(base), np.asarray(ref_base))
+        if DEAD_CASES[mig].get("nothing_dead"):
+            np.testing.assert_array_equal(np.asarray(trees.leaf),
+                                          np.asarray(ref_trees.leaf))
+            np.testing.assert_array_equal(np.asarray(margins),
+                                          np.asarray(ref_margins))
+        else:
+            np.testing.assert_allclose(np.asarray(trees.leaf),
+                                       np.asarray(ref_trees.leaf),
+                                       rtol=0, atol=1e-6)
+            np.testing.assert_allclose(np.asarray(margins),
+                                       np.asarray(ref_margins),
+                                       rtol=0, atol=1e-5)
+
+    @pytest.mark.parametrize("mig", [0.2])
+    def test_against_the_sequential_fit_a_fold(self, dead_fits, mig):
+        """fit_gbt on each fold's weights: the split arrays equal, the
+        leaves within 1e-6. (At 0.002 a node without missing rows ties its
+        two missing directions and the routes' histogram algebra breaks
+        the tie differently, as it did before the rule: not held here.)"""
+        Xb, y, W = _reg_data()
+        trees = dead_fits[mig][0][0]
+        seq_kw = {k: v for k, v in DEAD_KW.items() if k != "payload"}
+        for k in range(W.shape[0]):
+            seq, _ = T.fit_gbt(Xb, y, W[k], jax.random.PRNGKey(7),
+                               min_info_gain=mig, **seq_kw)
+            for fld in ("feat", "thresh", "miss"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(trees, fld))[:, k],
+                    np.asarray(getattr(seq, fld)), err_msg=f"{fld} lane={k}")
+            np.testing.assert_allclose(np.asarray(trees.leaf)[:, k],
+                                       np.asarray(seq.leaf),
+                                       rtol=0, atol=1e-6)
+
+    def test_a_dead_lane_beside_live_ones_by_gamma(self):
+        """Per-lane gamma vectors: lane 1's root is dead in every round
+        (its own program runs no level pass at all), lanes 0 and 2 grow
+        full trees — each lane still what it is alone."""
+        Xb, y, W = _reg_data(seed=2)
+        kw = dict(DEAD_KW, min_info_gain=0.0, n_rounds=3,
+                  gamma=jnp.asarray([0.0, 1e9, 0.0], jnp.float32))
+        fused, singles = _fit_lanes_and_each(Xb, y, W,
+                                             jax.random.PRNGKey(3), **kw)
+        _assert_lanes_equal_singles(fused, singles, "gamma lanes")
+        live = _live_by_level(fused[0])
+        assert (live[:, 1] == 0).all() and (live[:, [0, 2], 0] == 1).all()
+        count = functools.partial(T.level_passes_run, depth=DEAD_DEPTH,
+                                  n_bins=DEAD_BINS)
+        assert int(count(singles[1][0])) == 0
+        assert int(count(fused[0])) == 3 * DEAD_DEPTH
+
+    def test_half_one_is_what_holds_a_dead_node_beside_a_live_lane(
+            self, monkeypatch):
+        """A pass that sums in another order than the one before it (the
+        twins do not: here every fused pass's sums are off by a rounding's
+        worth) moves no bit of a dead node: its left child is the held
+        histogram whether the pass ran (beside a live lane) or not
+        (alone). Without _held_by_dead_nodes the dead lane's leaves would
+        differ between the two programs."""
+        ok = jnp.asarray([[True, False]])
+        out = np.asarray(T._held_by_dead_nodes(
+            ok, jnp.ones((1, 2, 3, 2, 2)), jnp.full((1, 2, 3, 2, 2), 7.0)))
+        assert (out[0, 0] == 1.0).all() and (out[0, 1] == 7.0).all()
+
+        real = PH.route_hist
+
+        def another_order(*a, **k):
+            hist, node = real(*a, **k)
+            return hist * (1.0 + 2.0 ** -18), node
+        monkeypatch.setattr(PH, "route_hist", another_order)
+        Xb, y, W = _reg_data(seed=2)
+        kw = dict(DEAD_KW, min_info_gain=0.0, n_rounds=2)
+        fit = jax.jit(functools.partial(T._fit_gbt_folds_impl, **kw))
+        gamma = jnp.asarray([0.0, 1e9], jnp.float32)
+        key = jax.random.PRNGKey(3)
+        trees, base, margins = fit(Xb, y, W[:2], key, gamma=gamma)
+        alone = fit(Xb, y, W[1:2], key, gamma=gamma[1:])
+        assert (_live_by_level(trees)[:, 1] == 0).all()
+        _assert_fit_equal(
+            (T.Tree(*(getattr(trees, f)[:, 1:2] for f in T.Tree._fields)),
+             base[1:2], margins[1:2]), alone, "the dead lane")
+
+    def test_interpret_mode_lanes_equal_singles_with_dead_levels(self):
+        """The bfloat16 contraction in three parts as the chip issues it,
+        at the smallest shape: the passes of a dead level are skipped in a
+        lane's own program and run in the fused one."""
+        Xb, y, W = _reg_data(n=513, f=5, folds=2, seed=8)
+        kw = dict(DEAD_KW, n_rounds=3, depth=3, min_info_gain=0.05,
+                  interpret=True)
+        fused, singles = _fit_lanes_and_each(Xb, y, W,
+                                             jax.random.PRNGKey(7), **kw)
+        _assert_lanes_equal_singles(fused, singles, "interpret")
+        run = int(T.level_passes_run(fused[0], depth=3, n_bins=DEAD_BINS))
+        assert 0 < run < 3 * 3
+
+
+def _tables(levels_live, depth, bins=DEAD_BINS):
+    """A [1, lanes, 2^depth - 1] Tree whose level d of lane k has
+    levels_live[k][d] live nodes (the first ones), the rest dead."""
+    lanes = len(levels_live)
+    feat = np.zeros((1, lanes, (1 << depth) - 1), np.int32)
+    thresh = np.full_like(feat, bins)
+    for k, per_level in enumerate(levels_live):
+        for d, n_live in enumerate(per_level):
+            lo = (1 << d) - 1
+            feat[0, k, lo:lo + n_live] = 1
+            thresh[0, k, lo:lo + n_live] = 2
+    return T.Tree(jnp.asarray(feat), jnp.asarray(thresh),
+                  jnp.zeros((1, lanes, 1 << depth, 1)),
+                  jnp.zeros_like(jnp.asarray(feat)))
+
+
+@pytest.mark.parametrize("levels_live,want", [
+    ([[0, 0, 0, 0]], 0),                    # a lone leaf: no pass of 4
+    ([[1, 2, 4, 8]], 4),                    # a full tree: every pass
+    ([[1, 2, 0, 0]], 2),                    # first dead level k -> k
+    ([[1, 0, 0, 0]], 1),
+    ([[1, 0, 0, 0], [1, 1, 1, 0]], 3),      # one live lane keeps the level
+    ([[0, 0, 0, 0], [1, 2, 4, 8]], 4),
+    ([[1, 0, 3, 0]], 1),    # nothing runs past the first dead level
+], ids=["lone_leaf", "full_tree", "dead_from_2", "dead_from_1",
+        "one_live_lane", "dead_lane_beside_full", "first_dead_level_ends"])
+def test_level_passes_run_on_hand_built_tables(levels_live, want):
+    tree = _tables(levels_live, 4)
+    assert int(T.level_passes_run(tree, depth=4, n_bins=DEAD_BINS)) == want
+    # a live split on feature 0 with every row left but default-right
+    # missing is no dead table
+    odd = tree._replace(miss=tree.miss.at[0, 0, 0].set(1))
+    assert int(T.level_passes_run(odd, depth=4, n_bins=DEAD_BINS)) \
+        == max(want, 1)
+
+
+class TestWhereTheRuleEngages:
+    """Decided by what the code can see: no per-node and no per-level
+    feature draw. Elsewhere the program holds no cond."""
+
+    def _booster_text(self, **kw):
+        Xb, y, W = _reg_data(n=256)
+        return str(jax.make_jaxpr(lambda *a: T._fit_gbt_folds_impl(
+            *a, n_rounds=2, depth=3, n_bins=DEAD_BINS, loss="squared",
+            **kw))(Xb, y, W, jax.random.PRNGKey(0)))
+
+    def test_a_booster_puts_every_level_pass_under_a_cond(self):
+        assert T.dead_levels_end_tree(1.0, 1.0)
+        assert self._booster_text().count(" cond[") == 3    # depth, a round
+
+    def test_a_level_draw_keeps_the_unconditional_loop(self):
+        assert not T.dead_levels_end_tree(0.5, 1.0)
+        assert " cond[" not in self._booster_text(colsample_bylevel=0.5)
+        # a per-tree subset is the same candidates at parent and child
+        assert self._booster_text(feature_frac=0.5).count(" cond[") == 3
+
+    def test_forest_lanes_hold_no_cond(self):
+        assert not T.dead_levels_end_tree(1.0, 22 / 64)
+        Xb, y, W = _reg_data(n=256, folds=2)
+        rw, node_keys = T.forest_bootstrap(
+            jax.random.PRNGKey(0), 0, 1.0, n_rows=256, n_trees=2, group=2)
+        text = str(jax.make_jaxpr(functools.partial(
+            T.fit_forest_lanes, depth=3, n_bins=DEAD_BINS,
+            feature_frac=0.5))(Xb, y, W, rw, node_keys,
+                               jnp.zeros((2, 256), jnp.float32)))
+        assert "cond[" not in text
+
+
+def test_the_sweep_reports_passes_run_of_passes_planned(monkeypatch):
+    """RegressionModelSelector -> mask_folds -> fit_gbt_folds on the twins:
+    the estimator's last_lane_telemetry holds the passes planned and run as
+    ints once the validator has fetched them, one `tree_levels_skipped`
+    event a grid point carries both, and last_tree_telemetry is the dict
+    it was."""
+    from transmogrifai_tpu.automl.selectors import RegressionModelSelector
+    from transmogrifai_tpu.automl.tuning.splitters import DataSplitter
+    from transmogrifai_tpu.models import trees as MT
+    rng = np.random.default_rng(0)
+    n, f, rounds, depth = 2048, 6, 3, 3
+    X = rng.normal(size=(n, f)).astype(np.float32)
+    y = (10 + 1.7 * (X @ rng.normal(size=f) / np.sqrt(f)
+                     + 0.65 * rng.normal(size=n))).astype(np.float32)
+    monkeypatch.setattr(MT, "FOREST_LANE_BACKENDS", ("tpu", "cpu"))
+    monkeypatch.setattr(MT, "FOREST_LANE_MIN_ROWS", 0)
+    est = MT.OpGBTRegressor(max_iter=rounds, max_depth=depth, max_bins=8,
+                            min_instances_per_node=10)
+    sel = RegressionModelSelector.with_cross_validation(
+        splitter=DataSplitter(seed=42, reserve_test_fraction=0.0),
+        num_folds=3, seed=42, models_and_parameters=[
+            (est, [{"min_info_gain": 0.001}, {"min_info_gain": 1.5}])])
+    events, lanes_seen = [], []
+    monkeypatch.setattr(
+        collector, "event",
+        lambda name, **kw: events.append(dict(kw, event=name)))
+    real = MT._TreeEstimator._count_booster_fit
+
+    def count(self, *a, **k):
+        real(self, *a, **k)
+        lanes_seen.append(self.last_lane_telemetry)
+    monkeypatch.setattr(MT._TreeEstimator, "_count_booster_fit", count)
+    sel.fit_arrays(X, y)
+    assert sel.validator.last_tree_telemetry == {
+        "model": "OpGBTRegressor", "route": "fold_fused", "programs": 2,
+        "rounds": 2 * rounds, "scale_reductions": 2 * rounds, "lanes": 3,
+        "payload_body": "residual_parts", "payload_rows": 5}
+    sent = [e for e in events if e["event"] == "tree_levels_skipped"]
+    assert len(sent) == len(lanes_seen) == 2
+    for e, tele in zip(sent, lanes_seen):
+        assert e["model"] == "OpGBTRegressor" and e["lanes"] == 3
+        assert e["rounds"] == rounds
+        assert e["planned"] == tele["level_passes_planned"] == rounds * depth
+        assert type(tele["level_passes_run"]) is int
+        assert e["run"] == tele["level_passes_run"]
+    # the loose point grows full trees; 1.5 a weighted row kills the deep
+    # levels of the toy matrix's trees
+    assert sent[0]["run"] == rounds * depth > sent[1]["run"]

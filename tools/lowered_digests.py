@@ -1,7 +1,8 @@
 """SHA-256 of the lowered text (`.lower(...).as_text()`, no locations) of
 the programs the benchmark's cells run OUTSIDE the squared loss's Gram
-pass, at small fixed shapes: run it from the root of two checkouts and
-compare, to rule a change out of a program it says it does not touch.
+pass and the boosters' fused tree growth, at small fixed shapes: run it
+from the root of two checkouts and compare, to rule a change out of a
+program it says it does not touch.
 
     JAX_PLATFORMS=cpu python tools/lowered_digests.py [checkout]
 
@@ -19,6 +20,7 @@ def digests(root: str) -> dict:
 
     from transmogrifai_tpu.automl.tuning import folds
     from transmogrifai_tpu.ops import glm_sweep as GS
+    from transmogrifai_tpu.ops import trees as T
 
     if not GS.__file__.startswith(root):
         raise SystemExit(f"{GS.__file__} is not of the checkout {root}")
@@ -32,7 +34,18 @@ def digests(root: str) -> dict:
     col, lane, lanes = S((d,), f32), S((L,), f32), S((L, d), f32)
     budget, tol = S((), i32), S((), f32)
     round_args = (S((F, L), f32), lane, lane, lanes, lane, col, col)
+    # the fused tree growth where a node or a level draws its features
+    # (the forests' cells; a booster under colsample_bylevel): the programs
+    # a dead level does not end (PR 52)
+    Xb, trees, fo, bins = S((n, 16), jnp.int8), 2, 10, 32
     out = {
+        "fit_forest_lanes[22 of 64 a node]": T.fit_forest_lanes.lower(
+            Xb, y, masks, S((trees, n), f32), S((trees, 2), jnp.uint32),
+            S((F, n), f32), depth=4, n_bins=bins, feature_frac=22 / 64,
+            payload="centred_parts", centre=S((2,), f32)),
+        "fit_gbt_folds[colsample_bylevel 0.5]": T.fit_gbt_folds.lower(
+            Xb, y, S((fo, n), f32), S((2,), jnp.uint32), n_rounds=2,
+            depth=4, n_bins=bins, colsample_bylevel=0.5),
         "glm_standardize_stats": GS.glm_standardize_stats.lower(X, w),
         "sweep_glm_round": GS.sweep_glm_round.lower(
             X, y, w, masks, *round_args, budget, tol, loss="logistic"),

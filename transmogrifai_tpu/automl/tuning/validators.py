@@ -1039,7 +1039,12 @@ class Validator:
         fetched here — is the last point's. A booster's fold-fused fits
         ("fold_fused": _TreeEstimator._count_booster_fit): programs,
         rounds and scale_reductions add up, lanes is the widest
-        program's, payload_body and payload_rows the last fit's."""
+        program's, payload_body and payload_rows the last fit's. The
+        level passes its fits ran of those planned are fetched here, behind
+        the cell's own fetch like a forest's centre, left as ints in the
+        estimator's dict and sent as one `tree_levels_skipped` event; they
+        stay out of last_tree_telemetry, which accepted tests hold to
+        equality."""
         if lanes.get("route") == "fold_fused":
             tele = self.last_tree_telemetry or {
                 "model": type(est).__name__, "route": "fold_fused",
@@ -1051,6 +1056,12 @@ class Validator:
             for key in ("payload_body", "payload_rows"):
                 tele[key] = lanes[key]
             self.last_tree_telemetry = tele
+            lanes["level_passes_run"] = int(lanes["level_passes_run"])
+            collector.event(
+                "tree_levels_skipped", model=type(est).__name__,
+                lanes=int(lanes["lanes"]), rounds=int(lanes["rounds"]),
+                planned=int(lanes["level_passes_planned"]),
+                run=lanes["level_passes_run"])
             return
         tele = self.last_tree_telemetry or {
             "model": type(est).__name__, "route": "forest_lanes",

@@ -395,10 +395,10 @@ class _TreeEstimator(PredictorEstimator):
                     return T.fit_gbt_folds(
                         Xb, y, W_lanes, key, n_bins=n_bins, loss=loss,
                         payload=word, **shared, **lane_vec)
-            _, _, margins = self._timed_fused_fit(
+            trees, _, margins = self._timed_fused_fit(
                 label, Xb, g_here * F, depth, shared["n_rounds"], fit,
                 span=span, payload=word)
-            self._count_booster_fit(word, g_here * F, shared["n_rounds"])
+            self._count_booster_fit(word, g_here * F, shared, n_bins, trees)
             outs.append(margins.reshape(F, g_here, n).transpose(1, 0, 2))
         return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
@@ -476,21 +476,37 @@ class _TreeEstimator(PredictorEstimator):
     #: word and its rows): the validator sums it into last_tree_telemetry
     last_lane_telemetry: Optional[Dict[str, Any]] = None
 
-    def _count_booster_fit(self, word, lanes, n_rounds) -> None:
-        """One fold-fused booster program into last_lane_telemetry (a
-        grid-fused group runs several: they add up)."""
+    def _count_booster_fit(self, word, lanes, kw, n_bins, trees) -> None:
+        """One fold-fused booster program (the fit's own `kw`, the `trees`
+        it returned) into last_lane_telemetry (a grid-fused group runs
+        several: they add up). `level_passes_run`,
+        the fused and routing passes the fit ran of the rounds x depth
+        planned (ops/trees.level_passes_run: a level with no node left to
+        split in any lane ends the tree), stays a DEVICE scalar here, read
+        off the trees behind the fit in the queue: whoever fetches the
+        fit's scores fetches it after them (the validator's
+        _count_tree_lanes, which then holds an int), so the fit waits for
+        nothing."""
         tele = self.last_lane_telemetry or dict(
             route="fold_fused", programs=0, rounds=0, scale_reductions=0,
-            lanes=0)
+            lanes=0, level_passes_planned=0, level_passes_run=0)
         parts = T.PAYLOAD_PARTS[word]
+        n_rounds, depth = int(kw["n_rounds"]), int(kw["depth"])
+        planned = ran = n_rounds * depth
+        if T.dead_levels_end_tree(float(kw.get("colsample_bylevel", 1.0))):
+            ran = T.level_passes_run(trees, depth=depth, n_bins=n_bins)
         tele.update(
             programs=tele["programs"] + 1,
-            rounds=tele["rounds"] + int(n_rounds),
+            rounds=tele["rounds"] + n_rounds,
             # one max-reduction over [lanes, N] a round, only in parts
             scale_reductions=tele["scale_reductions"]
-            + (int(n_rounds) if parts != 1 else 0),
+            + (n_rounds if parts != 1 else 0),
             lanes=max(tele["lanes"], int(lanes)),
-            payload_body=word, payload_rows=T.payload_rows(word))
+            payload_body=word, payload_rows=T.payload_rows(word),
+            level_passes_planned=tele["level_passes_planned"] + planned,
+            # no eager add on the device for the one program of a point
+            level_passes_run=tele["level_passes_run"] + ran
+            if tele["programs"] else ran)
         self.last_lane_telemetry = tele
 
     def _decline_parts(self, reason: str) -> str:
@@ -588,13 +604,13 @@ class _TreeEstimator(PredictorEstimator):
             return None
         Xb, edges, n_bins = ctx
         lanes = int(masks.shape[0])
-        _, _, margins = self._timed_fused_fit(
+        trees, _, margins = self._timed_fused_fit(
             "tree_sweep_fold_fused", Xb, lanes, kw["depth"], kw["n_rounds"],
             lambda: T.fit_gbt_folds(
                 Xb, y, masks * w[None, :], self._key(), n_bins=n_bins,
                 loss=loss, payload=word, **kw),
             payload=word)
-        self._count_booster_fit(word, lanes, kw["n_rounds"])
+        self._count_booster_fit(word, lanes, kw, n_bins, trees)
         return margins
 
     def _decline_sequential(self) -> None:

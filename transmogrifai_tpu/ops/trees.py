@@ -1112,6 +1112,49 @@ def fused_level_slots(depth: int) -> tuple:
     return level_slots(depth)[:-1]
 
 
+def dead_levels_end_tree(level_feature_frac: float = 1.0,
+                         node_feature_frac: float = 1.0) -> bool:
+    """Does a level at which no lane has a node left to split end the
+    tree for the fused growth form? Only where a dead node's all-left
+    child faces the candidates the node faced: no per-level and no
+    per-node feature draw (both static). A child that draws its own
+    subset may split where its parent could not, so there the level loop
+    runs every pass, as it always did. THE predicate: _grow_tree_folds
+    asks it before it puts a pass under a cond, the callers before they
+    count the passes a fit ran (level_passes_run)."""
+    return level_feature_frac >= 1.0 and node_feature_frac >= 1.0
+
+
+def _held_by_dead_nodes(ok, passed, held):
+    """Half (i) of the dead-level rule. A node with no allowed split sends
+    every row LEFT, so its left child's histogram IS its own: take it from
+    what is held (`held`, the parent level's) and not from the pass that
+    summed the same rows again in another order (`passed`); the right
+    child is then an exact zero by sibling subtraction. A lane's result no
+    longer depends on whether the pass ran. ok [..., n]; passed / held
+    [..., n, 3, F, B]."""
+    return jnp.where(ok[..., None, None, None], passed, held)
+
+
+@functools.partial(jax.jit, static_argnames=("depth", "n_bins"))
+def level_passes_run(trees: Tree, *, depth: int, n_bins: int) -> jax.Array:
+    """int32 count of the fused and routing passes the fits that returned
+    `trees` RAN under the dead-level rule (planned: rounds x depth; the
+    root's histogram pass is not a level pass). Read off the split tables
+    [rounds, Fo, 2^depth - 1], on the device: a node is dead iff its table
+    is (feat, thresh, miss) = (0, n_bins, 0) — no allowed split sends
+    every row left, min_instances >= 1 and a gain that must exceed
+    min_info_gain >= 0 refuse it — and a round ran the pass of every level
+    before its first level with no live node in ANY lane. Only for a fit
+    under dead_levels_end_tree; any other ran rounds x depth."""
+    live = ~((trees.feat == 0) & (trees.thresh == n_bins)
+             & (trees.miss == 0))                       # [R, Fo, 2^depth - 1]
+    by_level = jnp.stack(
+        [live[..., (1 << d) - 1:(2 << d) - 1].any(axis=(-2, -1))
+         for d in range(depth)], axis=-1)                          # [R, depth]
+    return jnp.cumprod(by_level.astype(jnp.int32), axis=-1).sum()
+
+
 def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                      reg_lambda, min_child_weight, min_instances,
                      min_info_gain, gamma, learning_rate, feature_mask,
@@ -1147,6 +1190,18 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     over: every level histogram psums across shards before the split
     algebra (DrJAX-style psum-merged MapReduce), routing stays local.
 
+    A level at which NO lane has a node left to split ends the tree
+    (dead_levels_end_tree: only where no node and no level draws its own
+    features): the level's pass sits under a lax.cond on `any(ok)` and is
+    not run — every row goes left, as the dead tables (0, B - 1, 0) send
+    it, and the left children hold their parents' sums; the last level's
+    routing and lookup likewise, the rows reading their nodes' left
+    leaves. Every later level is then dead again and costs its split
+    algebra alone. A dead node beside live ones takes its left child from
+    the held histogram too (_held_by_dead_nodes), so a lane grows the
+    same tree whether its pass ran or not. level_passes_run counts the
+    passes run off the returned tables.
+
     Forest lanes (fit_forest_lanes) take the same loop with Spark's
     rules: `normalize_gain` compares the gain a weighted row with
     min_info_gain, `leaf_mode="mean"` makes leaves G / H, and
@@ -1168,6 +1223,7 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     Fo = G.shape[0]
     B = n_bins + 1
     split_scores_f = _fold_split_scores(reg_lambda, min_child_weight, gamma)
+    ends_dead = dead_levels_end_tree(level_feature_frac, node_feature_frac)
 
     def interleave_f(left, right, n_nodes):
         # children along axis 1: [Fo, 2p, ...] from per-parent pairs
@@ -1273,16 +1329,35 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
         if d < depth - 1:
             # fused pass: route with this level's tables AND accumulate
             # the next level's left-child histograms in ONE Xb read
-            hist, node = pallas_hist.route_hist(
-                Xb_t, pay, node, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
-                n_bins=B, interpret=interpret, allow_bf16=True,
-                derive_count=True, payload_parts=payload_parts)
-            hist = _allreduce(unscaled(hist), axis_name)
+            def fused_pass(node):
+                hist, node = pallas_hist.route_hist(
+                    Xb_t, pay, node, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
+                    n_bins=B, interpret=interpret, allow_bf16=True,
+                    derive_count=True, payload_parts=payload_parts)
+                return _allreduce(unscaled(hist), axis_name), node
+
+            if not ends_dead:
+                hist, node = fused_pass(node)
+            else:
+                # a level with no live node in any lane: the pass is not
+                # run. Every row goes left (what the tables (0, B - 1, 0)
+                # do) and the left children hold their parents' sums
+                held = jnp.stack([hg[..., 0], hh, hc], axis=2)
+                hist, node = jax.lax.cond(
+                    jnp.any(ok), fused_pass,
+                    lambda node: (held.reshape(-1, F * B), node * 2.0), node)
+                hist = _held_by_dead_nodes(
+                    ok, hist.reshape(held.shape), held).reshape(-1, F * B)
         else:
             # final level: no further histogram — plain routing pass to
             # land every row on its leaf
-            node = pallas_hist.route(Xb_t, node, f_lvl, t_lvl, m_lvl,
-                                     n_nodes=n_nodes, interpret=interpret)
+            def route_pass(node):
+                return pallas_hist.route(
+                    Xb_t, node, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
+                    interpret=interpret)
+
+            if not ends_dead:
+                node = route_pass(node)
 
     n_leaves = 1 << depth
     if depth == 0:
@@ -1298,8 +1373,21 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                             alpha=alpha, max_delta_step=max_delta_step,
                             learning_rate=learning_rate, leaf_mode=leaf_mode,
                             leaf_offset=leaf_offset)
-    leaf_rows = pallas_hist.table_lookup(
-        leaf[:, :, 0], node, interpret=interpret)         # [Fo, N]
+    tbl = leaf[:, :, 0]
+    if ends_dead and depth > 0:
+        # the last level: routed and looked up, or - no node left to split
+        # in any lane - every row lands on its node's LEFT leaf as it
+        # stands: the even leaves' table read at the node ids themselves
+        thin = jnp.pad(tbl[:, ::2], ((0, 0), (0, n_leaves // 2)))
+        leaf_rows = jax.lax.cond(
+            jnp.any(ok),
+            lambda node: pallas_hist.table_lookup(
+                tbl, route_pass(node), interpret=interpret),
+            lambda node: pallas_hist.table_lookup(
+                thin, node, interpret=interpret), node)
+    else:
+        leaf_rows = pallas_hist.table_lookup(
+            tbl, node, interpret=interpret)                   # [Fo, N]
     tree = Tree(jnp.concatenate(feats, axis=1),
                 jnp.concatenate(threshs, axis=1), leaf,
                 jnp.concatenate(misses, axis=1))
