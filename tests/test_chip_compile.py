@@ -398,3 +398,30 @@ def test_three_part_histogram_kernels_compile_for_a_v5e(one_chip, as_v5e):
         *[S((lanes, nodes), jnp.int32)] * 3, n_nodes=nodes, **kw).compile()
     for compiled in (root, deep):
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_class_channel_histogram_kernels_compile_for_a_v5e(one_chip, as_v5e):
+    """sweep-rf-multiclass's histogram kernels at 10M rows x 64 columns, 33
+    bins, K = 7 classes: the 10 lanes plan_forest_group gives K + 1 = 8 rows
+    a (lane, slot) — the root pass and the deepest fused pass (16 slots: a
+    [1 280, 2 112] float32 output block, 10.8 MB of VMEM), the class
+    channels weight x (id == k) built in the kernel from [class id,
+    weight]."""
+    n, F, B, K, lanes, nodes = 10_002_432, 64, 33, 7, 10, 16
+    rows = pallas_hist.payload_rows(2, 1, True, K)
+    assert rows == K + 1 and pallas_hist.plan_forest_group(
+        10_000_000, F, B, 5, 10, 6, rows, classes=K) * 5 == lanes
+
+    def S(shape, dt=F32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    kw = dict(n_bins=B, interpret=False, use_bf16=True, derive_count=True,
+              classes=K)
+    root = pallas_hist._hist_pallas_jit.lower(
+        S((F, n), jnp.int8), S((2 * lanes, n)), S((lanes, n)), n_slots=1,
+        **kw).compile()
+    deep = pallas_hist._route_hist_pallas_jit.lower(
+        S((F, n), jnp.int8), S((2 * lanes, n)), S((lanes, n)),
+        *[S((lanes, nodes), jnp.int32)] * 3, n_nodes=nodes, **kw).compile()
+    for compiled in (root, deep):
+        assert "tpu_custom_call" in compiled.as_text()
+    assert f"f32[{lanes * nodes * rows},{F * B}]" in deep.as_text()

@@ -246,10 +246,14 @@ def test_the_gate_declines_and_says_why(monkeypatch):
     assert group >= 1 and why == "" \
         and MT.forest_lane_route_ok(rf, 10_000_000, 64, 5)
     assert "fold-vmap limit" in rf.forest_lane_plan(2_000_000, 64, 5)[1]
-    assert "more than one channel" in rf.forest_lane_plan(
-        10_000_000, 64, 5, n_classes=3, multiclass=True)[1]
-    assert not MT.forest_lane_route_ok(rf, 10_000_000, 64, 5,
-                                       multiclass=True)
+    # a multiclass sweep's K class channels have the route since PR 54
+    # (tests/test_forest_multiclass_lanes.py), until K outgrows a group
+    assert rf.forest_lane_plan(10_000_000, 64, 5, n_classes=3,
+                               multiclass=True)[0] >= 1
+    assert MT.forest_lane_route_ok(rf, 10_000_000, 64, 5, multiclass=True)
+    wide = rf.copy(max_depth=6, max_bins=32)
+    assert "K = 40" in wide.forest_lane_plan(
+        10_000_000, 64, 5, n_classes=40, multiclass=True)[1]
     loose = rf.copy(min_instances_per_node=0)
     assert "min_instances_per_node < 1" in loose.forest_lane_plan(
         10_000_000, 64, 5)[1]
@@ -270,7 +274,9 @@ def test_the_gate_declines_and_says_why(monkeypatch):
                         lambda name, **kw: events.append((name, kw)))
     w = jnp.ones_like(y)
     assert deep._mask_scores_fused((Xb, None, 8), y, w, W, 2, False) is None
-    assert rf._mask_scores_fused((Xb, None, 8), y, w, W, 3, True) is None
+    assert wide._mask_scores_fused(
+        (jnp.zeros((512, 64), jnp.int8), None, 33), y, w, W, 100,
+        True) is None
     devs = jax.devices()
     if len(devs) > 1:    # a mesh: the binned matrix over several devices
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -281,7 +287,7 @@ def test_the_gate_declines_and_says_why(monkeypatch):
     assert [e[0] for e in events] == \
         ["forest_lane_route_declined"] * len(events)
     assert "depth 12" in events[0][1]["reason"] \
-        and "more than one channel" in events[1][1]["reason"]
+        and "K = 100" in events[1][1]["reason"]
 
 
 def test_the_planner_sizes_a_group(monkeypatch):

@@ -27,6 +27,7 @@ from ...evaluators.evaluators import Evaluator
 from ...models.base import PredictionModel, PredictorEstimator
 from ...models.prediction import make_prediction_column
 from ...ops import metrics_ops as M
+from ...ops.trees import ClassMajorScores
 from ...stages.params import ParamMap
 from ...utils.metrics import collector
 from .folds import (
@@ -225,6 +226,45 @@ def _streamed_confusion(X, y, vw, Bc, b0c, n_classes: int):
 
     return jax.lax.fori_loop(0, nb, body, jnp.zeros(
         (Bc.shape[0], n_classes, n_classes), jnp.float32))
+
+
+_CLASS_MAJOR_BLOCK = 1 << 19
+
+
+@partial(jax.jit, static_argnames=("metric", "n_classes"))
+def _class_major_metrics(scores, y, w, masks, *, metric: str,
+                         n_classes: int):
+    """[folds] class metric of class-major scores [folds, K, n]
+    (ops/trees.ClassMajorScores: a forest's lane route) on each fold's
+    held-out rows: the argmax over the class axis, then the weighted
+    confusion count [folds, K, K] summed a block of rows at a time —
+    nothing [folds, K, n] is built beside the scores — and every class
+    metric read off it (M.multiclass_metrics_from_confusion, as the
+    streamed multinomial sweep's). Unit weights count exactly."""
+    pred = jnp.argmax(scores, axis=1)                          # [folds, n]
+    vw = (1.0 - masks) * w[None, :]
+    folds, n = pred.shape
+    classes = jnp.arange(n_classes, dtype=jnp.int32)
+
+    def count(start, size):
+        p = jax.lax.dynamic_slice_in_dim(pred, start, size, axis=1)
+        P = (p[:, None, :] == classes[None, :, None]).astype(jnp.float32)
+        Y = (jax.lax.dynamic_slice_in_dim(y, start, size).astype(jnp.int32)[
+            None, :] == classes[:, None]).astype(jnp.float32)  # [K, size]
+        A = Y[None] * jax.lax.dynamic_slice_in_dim(
+            vw, start, size, axis=1)[:, None, :]               # [folds, K, c]
+        return jnp.einsum("ftc,fpc->ftp", A, P,
+                          precision=jax.lax.Precision.HIGHEST)
+
+    c = min(_CLASS_MAJOR_BLOCK, n)
+    nb, tail = divmod(n, c)
+    conf = jax.lax.fori_loop(
+        0, nb, lambda i, acc: acc + count(i * c, c),
+        jnp.zeros((folds, n_classes, n_classes), jnp.float32))
+    if tail:
+        conf = conf + count(nb * c, tail)
+    return jax.vmap(lambda cf: getattr(
+        M.multiclass_metrics_from_confusion(cf), metric))(conf)
 
 
 @partial(jax.jit,
@@ -1036,7 +1076,9 @@ class Validator:
         over a sweep's points; lanes_per_group is the widest; how a
         real-valued payload was carried — payload_body, payload_rows,
         features_per_node, label_centre and payload_scale, the last two
-        fetched here — is the last point's. A booster's fold-fused fits
+        fetched here — is the last point's, as are a class label's K
+        channels (payload_body class_indicators, payload_rows K + 1,
+        `classes`). A booster's fold-fused fits
         ("fold_fused": _TreeEstimator._count_booster_fit): programs,
         rounds and scale_reductions add up, lanes is the widest
         program's, payload_body and payload_rows the last fit's. The
@@ -1078,8 +1120,11 @@ class Validator:
             for key in ("payload_body", "payload_rows",
                         "features_per_node"):
                 tele[key] = lanes[key]
-            tele["label_centre"], tele["payload_scale"] = map(
-                float, lanes["label_centre"])
+            if lanes["label_centre"] is not None:   # a real-valued label's
+                tele["label_centre"], tele["payload_scale"] = map(
+                    float, lanes["label_centre"])
+            if "classes" in lanes:                  # K class channels'
+                tele["classes"] = int(lanes["classes"])
         self.last_tree_telemetry = tele
 
     def _record_sweep_telemetry(self, est, info):
@@ -1478,10 +1523,20 @@ class Validator:
                         Xd, n_valid=X.shape[0], mesh=self._sweep_mesh)
 
                 def record(gi, scores_f, route=None):
+                    # a forest's lane route hands a class label's scores
+                    # over class-major: their own metric program
+                    major = isinstance(scores_f, ClassMajorScores)
+                    attrs = dict(hist_attrs, classes=int(n_classes)) \
+                        if multicls else hist_attrs
+                    if major:
+                        attrs["metric_body"] = "class_major_confusion"
                     with _phase("fold_metrics", lanes=int(md.shape[0]),
-                                depth=depth_of(gi), **hist_attrs):
-                        out = np.asarray(fold_metrics(scores_f, yd, wd, md,
-                                                      thr_d))
+                                depth=depth_of(gi), **attrs):
+                        out = np.asarray(
+                            _class_major_metrics(
+                                scores_f.scores, yd, wd, md, metric=metric,
+                                n_classes=int(n_classes)) if major
+                            else fold_metrics(scores_f, yd, wd, md, thr_d))
                     with _phase("record", cells=1):
                         fm = [float(v) for v in out]
                         results[gi] = fm

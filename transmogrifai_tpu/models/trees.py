@@ -703,22 +703,26 @@ def fused_lanes_why(n_rows: int, min_rows: Optional[int] = None) -> str:
 
 
 def forest_lane_route_ok(est, n_rows: int, n_feat: int, n_folds: int,
-                         multiclass: bool = False) -> bool:
+                         multiclass: bool = False,
+                         n_classes: int = 2) -> bool:
     """Does `est`'s mask-fold sweep of an [n_rows, n_feat] matrix run as
-    (tree, fold) lanes of the fused passes here? Shapes only: a caller
-    (the benchmark) asks before it makes any data."""
+    (tree, fold) lanes of the fused passes here? Shapes and the class
+    count only: a caller (the benchmark) asks before it makes any data."""
     plan = getattr(est, "forest_lane_plan", None)
     return plan is not None and plan(n_rows, n_feat, n_folds,
+                                     n_classes=n_classes,
                                      multiclass=multiclass)[0] > 0
 
 
-def payload_body(est) -> str:
+def payload_body(est, multiclass: bool = False, n_classes: int = 2) -> str:
     """How `est`'s fused passes carry its payload g into the kernels'
     bfloat16 contraction — a key of ops/trees.PAYLOAD_PARTS. A forest's g
     is weight x label: "indicator" for a class label (0/1: g exact in one
     part, three rows a (lane, slot)), "centred_parts" for a real-valued
     one (the label less its weighted mean, g as three exact parts, five
-    rows). A booster's g is its loss's gradient x weight: "gradient" for
+    rows), "class_indicators" where the label's classes go as K channels
+    (a multiclass sweep: K + 1 rows, T.payload_rows(word, K)). A
+    booster's g is its loss's gradient x weight: "gradient" for
     the logistic loss (|g| < 1, one part, three rows — what the boosters
     always issued), "residual_parts" for the squared loss (w (F - y),
     each round over that round's own scale, three exact parts, five
@@ -727,7 +731,10 @@ def payload_body(est) -> str:
     `last_tree_telemetry` and the benchmark (which asks before it makes
     any data) all read it here."""
     if isinstance(est, _ForestBase):
-        return "indicator" if est.classification else "centred_parts"
+        if not est.classification:
+            return "centred_parts"
+        return "indicator" if est._one_channel(n_classes, multiclass) \
+            else "class_indicators"
     squared = (getattr(est, "_regression", False)
                or getattr(est, "_loss", "logistic") == "squared")
     return "residual_parts" if squared else "gradient"
@@ -769,11 +776,11 @@ class _ForestBase(_TreeEstimator):
         cfg = self._forest_cfg(Xb.shape[1])
         depth = int(self.get_param("max_depth"))
         one = self._one_channel(n_classes, multiclass)
-        min_info_gain = float(self.get_param("min_info_gain"))
+        min_info_gain = T.payload_min_info_gain(
+            payload_body(self, multiclass, n_classes),
+            float(self.get_param("min_info_gain")))
         if one:
             G = (y * w)[:, None]
-            if self.classification:  # Spark's threshold is two-class
-                min_info_gain *= 0.5
         else:
             G = jax.nn.one_hot(y.astype(jnp.int32), n_classes,
                                dtype=jnp.float32) * w[:, None]
@@ -806,18 +813,26 @@ class _ForestBase(_TreeEstimator):
         why = fused_lanes_why(n_rows)
         if why:
             return 0, why
-        if not self._one_channel(n_classes, multiclass):
-            return 0, ("a payload of more than one channel (a multiclass "
-                       "forest, or min_instances_per_node < 1): the fused "
-                       "passes carry [g, h, count]")
+        body = payload_body(self, multiclass, n_classes)
+        by_class = body == "class_indicators"
+        if by_class and not multiclass:
+            return 0, ("a binary label as two class channels "
+                       "(min_instances_per_node < 1): its margin is read "
+                       "from one channel's votes")
         cfg = self._forest_cfg(n_feat)
         depth = int(self.get_param("max_depth"))
+        rows = T.payload_rows(body, n_classes)
         group = pallas_hist.plan_forest_group(
             n_rows, n_feat, int(self.get_param("max_bins")) + 1, n_folds,
-            cfg["n_trees"], depth,
-            T.payload_rows(payload_body(self)))
+            cfg["n_trees"], depth, rows,
+            classes=n_classes if by_class else 0)
         if group == 0:
-            return 0, (f"depth {depth}: plan_forest_group refuses the "
+            return 0, (f"depth {depth}, K = {n_classes} class channels "
+                       f"({rows} rows a (lane, slot), {n_folds} folds): "
+                       f"plan_forest_group admits no tree's fold lanes — "
+                       f"the output block of the deepest level, or the "
+                       f"group's row planes in HBM" if by_class else
+                       f"depth {depth}: plan_forest_group refuses the "
                        f"slot-dense output block of its deepest level")
         return group, ""
 
@@ -851,14 +866,19 @@ class _ForestBase(_TreeEstimator):
         cfg = self._forest_cfg(n_feat)
         depth = int(self.get_param("max_depth"))
         n_trees = cfg["n_trees"]
-        min_info_gain = float(self.get_param("min_info_gain")) \
-            * (0.5 if self.classification else 1.0)
+        body = payload_body(self, multiclass, n_classes)
+        classes = n_classes if body == "class_indicators" else 0
+        # what only K class channels say: a 0/1 or a real-valued label's
+        # spans, calls and telemetry stay what they were
+        by_class = {"classes": classes} if classes else {}
+        rows = T.payload_rows(body, n_classes)
+        min_info_gain = T.payload_min_info_gain(
+            body, float(self.get_param("min_info_gain")))
         key = self._key()
         W = masks * w[None, :]
-        votes = jnp.zeros((folds, n), jnp.float32)
+        votes = jnp.zeros((folds, classes, n) if classes else (folds, n),
+                          jnp.float32)
         groups = -(-n_trees // group)
-        body = payload_body(self)
-        rows = T.payload_rows(body)
         per_node = T.features_per_node(cfg["feature_frac"], n_feat)
         # the label's [centre, scale]: device scalars the lanes shift and
         # divide by and the sums and leaves get back; the fit fetches
@@ -880,20 +900,23 @@ class _ForestBase(_TreeEstimator):
                     slot_passes=sum(T.fused_level_slots(depth)),
                     route_node_rows=pallas_hist.route_node_rows(depth),
                     payload_body=body, payload_rows=rows,
-                    features_per_node=per_node):
+                    features_per_node=per_node, **by_class):
                 votes, _, _ = T.fit_forest_lanes(
                     Xb, y, W, rw, node_keys, votes, depth=depth,
                     n_bins=n_bins, feature_frac=cfg["feature_frac"],
                     min_instances=float(
                         self.get_param("min_instances_per_node")),
                     min_info_gain=min_info_gain, payload=body,
-                    centre=centre)
+                    centre=centre, **by_class)
         self.last_lane_telemetry = dict(
             tree_lanes=n_trees * folds, lane_groups=groups,
             lanes_per_group=group * folds,
             bootstrap_draws=groups * group * n, payload_body=body,
             payload_rows=rows, features_per_node=per_node,
-            label_centre=centre)
+            label_centre=centre, **by_class)
+        if classes:
+            return T.ClassMajorScores(
+                T.forest_class_scores(votes, n_trees=n_trees))
         return T.forest_vote_scores(votes, n_trees=n_trees,
                                     classification=self.classification)
 

@@ -369,15 +369,26 @@ def _feature_onehot(xf, *, F, B, blk, use_bf16):
     return oh.reshape(F * B, blk)
 
 
-def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts=1):
+def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts=1,
+                  classes=0):
     """Fold k's payload rows, with the unit-count channel derived in VMEM
     when derive_count: count = (h > 0) on the LAST input channel (the
     hessian) — exactly grow_tree's count_unit, computed on the VPU
     instead of streamed as its own HBM plane. `parts` = 3 (a real-valued
     payload in bf16 mode): the channels before the last go as three
     bfloat16 parts each (_unit_cuts), part-major, ahead of the last
-    channel and the count (payload_rows(C, parts, derive_count) rows)."""
+    channel and the count (payload_rows(C, parts, derive_count) rows).
+    `classes` = K (a class label): the lane's two planes are [class id,
+    weight] and the K class channels weight x (id == k) are built HERE,
+    beside the count — no K planes a lane in HBM, and no weight row: the
+    weight is the channels' sum, taken outside (K + 1 rows)."""
     pay = pay_ref[k * C:(k + 1) * C, :]                     # [C, blk] f32
+    if classes:
+        ids = jax.lax.broadcasted_iota(
+            jnp.int32, (classes, pay.shape[1]), 0).astype(jnp.float32)
+        chans = (ids == pay[0:1, :]).astype(jnp.float32) * pay[1:2, :]
+        cnt = (pay[1:2, :] > 0.0).astype(jnp.float32)
+        return jnp.concatenate([chans, cnt], axis=0).astype(mxu_dtype)
     if derive_count:
         cnt = (pay[C - 1:C, :] > 0.0).astype(jnp.float32)
         pay = jnp.concatenate([pay, cnt], axis=0)           # [C+1, blk]
@@ -404,12 +415,22 @@ def _unit_cuts(x):
     return [hi, mid, rest - mid]
 
 
-def payload_rows(C: int, parts: int, derive_count: bool) -> int:
+def payload_rows(C: int, parts: int, derive_count: bool,
+                 classes: int = 0) -> int:
     """Rows a (lane, slot) of the contraction's left operand: the C input
     channels, the derived count, and parts - 1 more for each channel
-    before the last. `parts` is 1 or 3: what _fold_payload cuts."""
+    before the last. `parts` is 1 or 3: what _fold_payload cuts. Under
+    `classes` = K the two input planes [class id, weight] become the K
+    class channels and the count: K + 1."""
     if parts not in (1, 3):
         raise ValueError(f"payload_parts {parts}: one part or three")
+    if classes:
+        if (C, parts, derive_count) != (2, 1, True):
+            raise ValueError(
+                f"{classes} class channels are built from [class id, "
+                f"weight] in one part beside the derived count, not from "
+                f"{C} channels in {parts} part(s)")
+        return classes + 1
     return C + (1 if derive_count else 0) + (parts - 1) * (C - 1)
 
 
@@ -430,7 +451,8 @@ def _sum_parts(hist, *, C, parts, derive_count):
 
 
 def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
-            n_folds, use_bf16=False, derive_count=False, parts=1):
+            n_folds, use_bf16=False, derive_count=False, parts=1,
+            classes=0):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -447,14 +469,15 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, B, C, n_slots,
     # dominant VPU cost — and the Xb traffic are built once for all folds,
     # and the matmul M dim grows n_folds x (the single-fold M of S*C rows
     # is far below the 128-row MXU tile; see BENCH_NOTES round-4 session 2)
-    Co = payload_rows(C, parts, derive_count)
+    Co = payload_rows(C, parts, derive_count, classes)
     slots = jax.lax.broadcasted_iota(jnp.int32, (n_slots, blk), 0) \
         .astype(jnp.float32)
     qs = []
     for k in range(n_folds):
         slot = slot_ref[k:k + 1, :]                         # [1, blk]
         slot_oh = (slots == slot).astype(mxu_dtype)         # [n_slots, blk]
-        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts)
+        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts,
+                            classes)
         qs.append((slot_oh[:, None, :] * pay[None, :, :])
                   .reshape(n_slots * Co, blk))
     q = qs[0] if n_folds == 1 else jnp.concatenate(qs, axis=0)
@@ -468,7 +491,7 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
                 *, n_slots: int, n_bins: int, interpret: bool = False,
                 allow_bf16: bool = False, derive_count: bool = False,
                 unit_payload: bool = False,
-                payload_parts: int = 1) -> jax.Array:
+                payload_parts: int = 1, classes: int = 0) -> jax.Array:
     """Histograms [n_folds * n_slots * Co, F * n_bins] (f32) of payload sums.
 
     Xb_t [F, N] int bins; pay_t [n_folds * C, N] f32 payload channels;
@@ -496,6 +519,10 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
     here, the layout stays Co rows a slot); the rank metrics keep f32
     weights. Resolved OUTSIDE the jit, so set_hist_bf16 cannot serve
     stale-dtype programs.
+
+    classes: K > 0 reads a lane's two planes as [class id, weight] and
+    sums weight x (id == k) for k < K, then the count (_fold_payload):
+    Co = K + 1, whole numbers under 256 exact in bfloat16 as a 0/1 label's.
     """
     use_bf16 = allow_bf16 and _HIST_BF16
     kw = dict(n_slots=n_slots, n_bins=n_bins, interpret=interpret,
@@ -506,14 +533,17 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
         return two._hist_two_level_jit(
             Xb_t, pay_t, slot_t, parts=two.payload_parts(unit_payload), **kw)
     return _hist_pallas_jit(Xb_t, pay_t, slot_t, use_bf16=use_bf16,
-                            parts=payload_parts if use_bf16 else 1, **kw)
+                            parts=payload_parts if use_bf16 else 1,
+                            classes=classes, **kw)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_slots", "n_bins", "interpret",
-                                    "use_bf16", "derive_count", "parts"))
+                                    "use_bf16", "derive_count", "parts",
+                                    "classes"))
 def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
-                     interpret, use_bf16, derive_count=False, parts=1):
+                     interpret, use_bf16, derive_count=False, parts=1,
+                     classes=0):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -523,7 +553,7 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
         raise ValueError(f"pay_t channels {pay_t.shape[0]} not a multiple "
                          f"of slot_t folds {n_folds}")
     C = pay_t.shape[0] // n_folds
-    Co = payload_rows(C, parts, derive_count)
+    Co = payload_rows(C, parts, derive_count, classes)
     B = n_bins
     blk = block_rows(F * B)
     pad = (-N) % blk
@@ -536,7 +566,8 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
 
     kernel = functools.partial(_kernel, F=F, B=B, C=C, n_slots=n_slots,
                                n_folds=n_folds, use_bf16=use_bf16,
-                               derive_count=derive_count, parts=parts)
+                               derive_count=derive_count, parts=parts,
+                               classes=classes)
     return _sum_parts(pl.pallas_call(
         kernel,
         grid=(N // blk,),
@@ -558,8 +589,17 @@ def _hist_pallas_jit(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
     )(Xb_t, pay_t, slot_t), C=C, parts=parts, derive_count=derive_count)
 
 
+def _class_channels(pay_k, classes: int):
+    """[class id, weight] [2, N] -> the K class channels weight x (id ==
+    k) and the count [K + 1, N]: _fold_payload's `classes` rows, in jnp."""
+    ids = jnp.arange(classes, dtype=jnp.float32)[:, None]
+    return jnp.concatenate(
+        [(ids == pay_k[0:1, :]).astype(jnp.float32) * pay_k[1:2, :],
+         (pay_k[1:2, :] > 0.0).astype(jnp.float32)], axis=0)
+
+
 def _hist_segment_jnp(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
-                      derive_count=False):
+                      derive_count=False, classes=0):
     """Pure-jnp twin of hist_pallas (CPU/GPU fallback): one fused
     segment-sum per fold lane over (slot, feature, bin) cells, same
     [n_folds * n_slots * Co, F * B] output layout. Out-of-range slot ids
@@ -574,7 +614,9 @@ def _hist_segment_jnp(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
     seg = n_slots * F * B
 
     def one_fold(slot_k, pay_k):
-        if derive_count:
+        if classes:
+            pay_k = _class_channels(pay_k, classes)
+        elif derive_count:
             cnt = (pay_k[C - 1:C, :] > 0.0).astype(jnp.float32)
             pay_k = jnp.concatenate([pay_k, cnt], axis=0)
         Co = pay_k.shape[0]
@@ -596,21 +638,23 @@ def _hist_segment_jnp(Xb_t, pay_t, slot_t, *, n_slots, n_bins,
 def hist_folds(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array, *,
                n_slots: int, n_bins: int, interpret: bool = False,
                allow_bf16: bool = False, derive_count: bool = False,
-               payload_parts: int = 1) -> jax.Array:
+               payload_parts: int = 1, classes: int = 0) -> jax.Array:
     """Batched multi-(fold x lane) histogram dispatcher: the VMEM pallas
     kernel on a live TPU (or in interpret mode for tests), the pure-jnp
     segment-sum fallback everywhere else — same signature and output
     layout as hist_pallas, so CPU CI exercises the exact call shape the
     TPU sweep runs. The twin sums float32 payloads as they are, which is
-    what `payload_parts` = 3 gives the kernel."""
+    what `payload_parts` = 3 gives the kernel. `classes` as in
+    hist_pallas."""
     if interpret or available():
         return hist_pallas(Xb_t, pay_t, slot_t, n_slots=n_slots,
                            n_bins=n_bins, interpret=interpret,
                            allow_bf16=allow_bf16,
                            derive_count=derive_count,
-                           payload_parts=payload_parts)
+                           payload_parts=payload_parts, classes=classes)
     return _hist_segment_jnp(Xb_t, pay_t, slot_t, n_slots=n_slots,
-                             n_bins=n_bins, derive_count=derive_count)
+                             n_bins=n_bins, derive_count=derive_count,
+                             classes=classes)
 
 
 # -- level routing ----------------------------------------------------------
@@ -835,7 +879,8 @@ def route(Xb_t: jax.Array, node_t: jax.Array, f_lvl: jax.Array,
 def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
                        node_out_ref, *, F: int, B: int, C: int, n_nodes: int,
                        n_r: int, n_folds: int,
-                       use_bf16=False, derive_count=False, parts=1):
+                       use_bf16=False, derive_count=False, parts=1,
+                       classes=0):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
@@ -851,7 +896,7 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
                           n_folds=n_folds)
     slots = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, blk), 0) \
         .astype(jnp.float32)
-    Co = payload_rows(C, parts, derive_count)
+    Co = payload_rows(C, parts, derive_count, classes)
     rows, qs = [], []
     for k in range(n_folds):
         node = node_ref[k:k + 1, :]                         # [1, blk]
@@ -862,7 +907,8 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
         # the same dropped-slot encoding hist_pallas uses for padding
         slot_oh = (slots == node + float(n_nodes) * rightf) \
             .astype(mxu_dtype)                              # [n_nodes, blk]
-        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts)
+        pay = _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts,
+                            classes)
         qs.append((slot_oh[:, None, :] * pay[None, :, :])
                   .reshape(n_nodes * Co, blk))
     q = qs[0] if n_folds == 1 else jnp.concatenate(qs, axis=0)
@@ -875,10 +921,11 @@ def _route_hist_kernel(xb_ref, pay_ref, node_ref, sel_ref, tm_ref, hist_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("n_nodes", "n_bins", "interpret",
-                                    "use_bf16", "derive_count", "parts"))
+                                    "use_bf16", "derive_count", "parts",
+                                    "classes"))
 def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
                            n_nodes, n_bins, interpret, use_bf16,
-                           derive_count=False, parts=1):
+                           derive_count=False, parts=1, classes=0):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -889,7 +936,7 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
         raise ValueError(f"pay_t channels {pay_t.shape[0]} not a multiple "
                          f"of node_t folds {Fo}")
     C = pay_t.shape[0] // Fo
-    Co = payload_rows(C, parts, derive_count)
+    Co = payload_rows(C, parts, derive_count, classes)
     B = n_bins
     sel, tm, n_r = _route_tables(f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
                                  n_feat=F)
@@ -908,7 +955,7 @@ def _route_hist_pallas_jit(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *,
     kernel = functools.partial(_route_hist_kernel, F=F, B=B, C=C,
                                n_nodes=n_nodes, n_r=n_r, n_folds=Fo,
                                use_bf16=use_bf16, derive_count=derive_count,
-                               parts=parts)
+                               parts=parts, classes=classes)
     hist, node_out = pl.pallas_call(
         kernel,
         grid=(N // blk,),
@@ -945,7 +992,7 @@ def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
                f_lvl: jax.Array, t_lvl: jax.Array, m_lvl: jax.Array, *,
                n_nodes: int, n_bins: int, interpret: bool = False,
                allow_bf16: bool = False, derive_count: bool = False,
-               payload_parts: int = 1):
+               payload_parts: int = 1, classes: int = 0):
     """Route one level AND histogram the next level's left children in a
     single pass over the binned matrix, for every (fold x config) lane.
 
@@ -958,7 +1005,8 @@ def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
     layout) — and new_node [Fo, N] = 2*node + right, bitwise what
     route_pallas returns. On CPU the jnp fallback chains the gather-form
     route with the segment-sum histogram (identical decisions; histogram
-    equal up to f32 summation order). `payload_parts` as in hist_pallas.
+    equal up to f32 summation order). `payload_parts` and `classes` as in
+    hist_pallas.
     """
     if interpret or available():
         use_bf16 = allow_bf16 and _HIST_BF16
@@ -966,21 +1014,22 @@ def route_hist(Xb_t: jax.Array, pay_t: jax.Array, node_t: jax.Array,
             Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
             n_bins=n_bins, interpret=interpret, use_bf16=use_bf16,
             derive_count=derive_count,
-            parts=payload_parts if use_bf16 else 1)
+            parts=payload_parts if use_bf16 else 1, classes=classes)
     return _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl,
                            n_nodes=n_nodes, n_bins=n_bins,
-                           derive_count=derive_count)
+                           derive_count=derive_count, classes=classes)
 
 
 def _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *, n_nodes,
-                    n_bins, derive_count=False):
+                    n_bins, derive_count=False, classes=0):
     """Pure-jnp twin of the fused route+hist kernel: the gather-form
     route chained with the segment-sum histogram."""
     new_node = _route_level_jnp(Xb_t, node_t, f_lvl, t_lvl, m_lvl)
     right = new_node - 2.0 * node_t                          # 0/1
     slots = node_t + float(n_nodes) * right                  # left keeps id
     hist = _hist_segment_jnp(Xb_t, pay_t, slots, n_slots=n_nodes,
-                             n_bins=n_bins, derive_count=derive_count)
+                             n_bins=n_bins, derive_count=derive_count,
+                             classes=classes)
     return hist, new_node
 
 
@@ -1092,7 +1141,10 @@ def _table_lookup_jnp(tbl: jax.Array, idx_t: jax.Array) -> jax.Array:
 # the boosters' lanes — the VMEM plan, the fused output block, the lane
 # planes in HBM — at other figures: a forest lane carries five row planes
 # (two payload channels, node ids in and out, leaf rows) where a booster
-# lane carries four, and the planes' sublane axis pads to 8 in HBM.
+# lane carries four, and the planes' sublane axis pads to 8 in HBM. A
+# K-class group lays out the same five a lane (its two payload planes are
+# [class id, weight]: the K channels are built in VMEM) and, a GROUP, the
+# votes it takes and hands back: [folds, K, rows] twice, K padded to 8.
 
 #: cap of the deepest level's output block [lanes * slots * 3, F * B] f32.
 #: Three quarters of the 16 MB at which r5 saw 20-minute Mosaic compiles;
@@ -1102,26 +1154,42 @@ _FOREST_OUT_BLOCK_BYTES = 12 << 20
 _FOREST_LANE_PLANES = 5
 
 
+def _pad8(n: int) -> int:
+    return -(-n // 8) * 8
+
+
+def forest_group_planes(lanes: int, n_folds: int, classes: int = 0) -> int:
+    """float32 row planes a lane group of the forest route lays out in
+    HBM: _FOREST_LANE_PLANES a lane (the lane axis padded to 8) and, under
+    K class channels, the votes in and out, [n_folds, K -> 8, rows] each.
+    THE count plan_forest_group budgets."""
+    votes = 2 * n_folds * _pad8(classes) if classes else 0
+    return _FOREST_LANE_PLANES * _pad8(lanes) + votes
+
+
 def plan_forest_group(n_rows: int, n_feat: int, n_bins: int, n_folds: int,
-                      n_trees: int, depth: int, payload_rows: int = 3) -> int:
+                      n_trees: int, depth: int, payload_rows: int = 3,
+                      classes: int = 0) -> int:
     """Trees a lane group of the forest route: the most whose (tree x
     fold) lanes clear plan_fused_hist, the output-block cap and 7/16 of
-    the device's HBM in lane planes (rows count here: the planes are what
-    grows with them), then evened out over the groups the forest needs —
+    the device's HBM in row planes (forest_group_planes; rows count here:
+    the planes are what grows with them), then evened out over the groups
+    the forest needs —
     20 trees at a most of 6 a group make 4 groups of 5, not 3 of 6 and a
     2. 0: not even one tree's fold lanes fit (depth 12: the slot-dense
-    output block alone is 130 MB), and the caller keeps its sequential
+    output block alone is 130 MB; K class channels past ~17 at five folds
+    and depth 6), and the caller keeps its sequential
     path. `n_bins` counts the missing-value bin; `payload_rows` the rows a
-    (lane, slot) the kernels issue (3, or 5 under a three-part payload:
-    the output block the kernel holds grows with them, the lanes a group
-    shrink)."""
+    (lane, slot) the kernels issue (3, 5 under a three-part payload, K + 1
+    under `classes` = K class channels: the output block the kernel holds
+    grows with them, the lanes a group shrink)."""
     from ..utils.platform import device_spec
     spec = device_spec()
 
     def ok(trees: int) -> bool:
         lanes = trees * n_folds
         plan = plan_fused_hist(n_feat, n_bins, lanes, depth, payload_rows)
-        planes = _FOREST_LANE_PLANES * (-(-lanes // 8) * 8) * n_rows * 4
+        planes = forest_group_planes(lanes, n_folds, classes) * n_rows * 4
         return (plan.fits and plan.out_bytes <= _FOREST_OUT_BLOCK_BYTES
                 and (spec is None or planes <= spec.hbm_bytes * 7 // 16))
 

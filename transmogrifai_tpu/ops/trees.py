@@ -1163,7 +1163,8 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                      feature_mask_count=None, axis_name=None,
                      normalize_gain=False, leaf_mode="newton",
                      node_feature_frac=1.0, node_keys=None,
-                     payload_parts=1, payload_scale=None, leaf_offset=None):
+                     payload_parts=1, payload_scale=None, leaf_offset=None,
+                     classes=0, leaf_rows_of=None):
     """Grow one tree PER FOLD level-wise in shared fused passes.
 
     Xb_t [F, N] transposed bins (N pre-padded to the route block size by
@@ -1210,6 +1211,17 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     as grow_tree splits its own, its subsets shared by the Fo // T lanes
     (tree-major) that are the tree's folds.
 
+    `classes` = K carries K class channels a lane (a multiclass forest):
+    G holds every row's class id, H its weight, and the kernels build the
+    channels weight x (id == k) in VMEM and issue K + 1 rows a (lane,
+    slot) — the K class sums and the count; the weight sums are the class
+    sums added. The split algebra is the same code at a last axis of K
+    (with `normalize_gain`, Spark's Gini gain a weighted row), the leaves
+    [Fo, 2^depth, K]; and since a row's K leaf values are K lookups, the
+    caller says what becomes of them: `leaf_rows_of(leaf, node)` gets the
+    leaf table and the final routing state [Fo, N] and its result comes
+    back in leaf_rows' place.
+
     Returns (Tree with leading [Fo] axes, leaf_rows [Fo, N], subsets)
     where leaf_rows are the learning-rate-scaled per-row leaf payloads —
     bitwise what predict_bins returns for each fold's tree, read off the
@@ -1235,6 +1247,14 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
     # [Fo*C]; g/h are level-invariant, so build [Fo, 2, N] -> [2Fo, N]
     # once — the count channel is derived in VMEM (derive_count)
     pay = jnp.stack([G, H], axis=1).reshape(2 * Fo, N)
+    # rows a (lane, slot) out of the kernels, and what is asked of them
+    # (`classes` only where set: a one-channel call's keywords, which the
+    # benchmark's spies record and replay, stay what they were)
+    rows_a_slot = classes + 1 if classes else 3
+    hist_kw = dict(interpret=interpret, allow_bf16=True, derive_count=True,
+                   payload_parts=payload_parts)
+    if classes:
+        hist_kw["classes"] = classes
     feats, threshs, misses, subsets = [], [], [], []
     last = None
     prev = None
@@ -1255,9 +1275,7 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
         if d == 0:
             # root histogram: all rows slot 0, one plain batched pass
             hist = _allreduce(unscaled(pallas_hist.hist_folds(
-                Xb_t, pay, node, n_slots=1, n_bins=B,
-                interpret=interpret, allow_bf16=True,
-                derive_count=True, payload_parts=payload_parts)),
+                Xb_t, pay, node, n_slots=1, n_bins=B, **hist_kw)),
                 axis_name)                                # [Fo*1*3, F*B]
             n_slots = 1
         else:
@@ -1266,10 +1284,15 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
             # previous iteration (sibling subtraction: right = parent -
             # left, same trick as grow_tree)
             n_slots = n_nodes // 2
-        hist = hist.reshape(Fo, n_slots, 3, F, B)
-        hgl = hist[:, :, 0][..., None]                        # [Fo,S,F,B,1]
-        hhl = hist[:, :, 1]                                   # [Fo,S,F,B]
-        hcl = hist[:, :, 2]
+        hist = hist.reshape(Fo, n_slots, rows_a_slot, F, B)
+        if classes:
+            hgl = jnp.moveaxis(hist[:, :, :classes], 2, -1)   # [Fo,S,F,B,K]
+            hhl = hgl.sum(-1)            # the weight: the class sums added
+            hcl = hist[:, :, classes]
+        else:
+            hgl = hist[:, :, 0][..., None]                    # [Fo,S,F,B,1]
+            hhl = hist[:, :, 1]                               # [Fo,S,F,B]
+            hcl = hist[:, :, 2]
         if d == 0:
             hg, hh, hc = hgl, hhl, hcl
         else:
@@ -1332,8 +1355,7 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
             def fused_pass(node):
                 hist, node = pallas_hist.route_hist(
                     Xb_t, pay, node, f_lvl, t_lvl, m_lvl, n_nodes=n_nodes,
-                    n_bins=B, interpret=interpret, allow_bf16=True,
-                    derive_count=True, payload_parts=payload_parts)
+                    n_bins=B, **hist_kw)
                 return _allreduce(unscaled(hist), axis_name), node
 
             if not ends_dead:
@@ -1342,7 +1364,9 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                 # a level with no live node in any lane: the pass is not
                 # run. Every row goes left (what the tables (0, B - 1, 0)
                 # do) and the left children hold their parents' sums
-                held = jnp.stack([hg[..., 0], hh, hc], axis=2)
+                held = jnp.concatenate(
+                    [jnp.moveaxis(hg, -1, 2), hc[:, :, None]], axis=2) \
+                    if classes else jnp.stack([hg[..., 0], hh, hc], axis=2)
                 hist, node = jax.lax.cond(
                     jnp.any(ok), fused_pass,
                     lambda node: (held.reshape(-1, F * B), node * 2.0), node)
@@ -1361,7 +1385,12 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
 
     n_leaves = 1 << depth
     if depth == 0:
-        Gl = _allreduce(G.sum(axis=1), axis_name)[:, None, None]
+        if classes:
+            Gl = _allreduce((H[:, :, None] * jax.nn.one_hot(
+                G.astype(jnp.int32), classes)).sum(axis=1),
+                axis_name)[:, None, :]
+        else:
+            Gl = _allreduce(G.sum(axis=1), axis_name)[:, None, None]
         Hl = _allreduce(H.sum(axis=1), axis_name)[:, None]
         Cl = _allreduce((H > 0).astype(jnp.float32).sum(axis=1),
                         axis_name)[:, None]
@@ -1374,7 +1403,12 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
                             learning_rate=learning_rate, leaf_mode=leaf_mode,
                             leaf_offset=leaf_offset)
     tbl = leaf[:, :, 0]
-    if ends_dead and depth > 0:
+    if leaf_rows_of is not None:
+        if ends_dead and depth > 0:   # routed, or every row left as it is
+            node = jax.lax.cond(jnp.any(ok), route_pass,
+                                lambda node: node * 2.0, node)
+        leaf_rows = leaf_rows_of(leaf, node)
+    elif ends_dead and depth > 0:
         # the last level: routed and looked up, or - no node left to split
         # in any lane - every row lands on its node's LEFT leaf as it
         # stands: the even leaves' table read at the node ids themselves
@@ -1412,15 +1446,38 @@ def _grow_tree_folds(Xb_t, G, H, *, depth, n_bins,
 #: parts, five rows. No centre is taken out of a residual: the base score
 #: is the label's weighted mean (base = wy / wsum), so round 1's g is the
 #: centred label already and later rounds' lie about zero.
+#: "class_indicators": a label of K classes under a multiclass sweep. A
+#: lane hands over [class id, weight] and the kernels build the K channels
+#: weight x (id == k) in VMEM: whole numbers under 256 under unit sample
+#: weights, exact in ONE part, K + 1 rows a (lane, slot) — the K class sums
+#: and the count, the weight sums being the class sums added. Its gain is
+#: Spark's K-class Gini gain whole: minInfoGain as it stands.
 PAYLOAD_PARTS = {"indicator": 1, "centred_parts": 3,
-                 "gradient": 1, "residual_parts": 3}
+                 "gradient": 1, "residual_parts": 3,
+                 "class_indicators": 1}
 
 
-def payload_rows(payload: str) -> int:
+def payload_rows(payload: str, classes: int = 0) -> int:
     """Rows a (lane, slot) the fused passes issue under this payload word:
-    g's parts, the weight and the derived count (3 | 5)."""
+    g's parts, the weight and the derived count (3 | 5); under
+    "class_indicators" the `classes` class sums and the count (K + 1: the
+    word's rows are asked with the class count)."""
     from . import pallas_hist
-    return pallas_hist.payload_rows(2, PAYLOAD_PARTS[payload], True)
+    by_class = payload == "class_indicators"
+    if by_class and classes < 2:
+        raise ValueError(f"class_indicators: rows of {classes} classes")
+    return pallas_hist.payload_rows(2, PAYLOAD_PARTS[payload], True,
+                                    classes if by_class else 0)
+
+
+def payload_min_info_gain(payload: str, min_info_gain: float) -> float:
+    """Spark's minInfoGain on the scale of THIS payload's gain, the one
+    place it is scaled: the variance gain of the one channel [w y] of a
+    0/1 label ("indicator") is HALF the two-class Gini gain at every
+    candidate, so its threshold is halved with it; every other payload's
+    gain is Spark's own (the variance gain of a real label, the K-class
+    Gini gain of K class channels) and meets the threshold whole."""
+    return min_info_gain * (0.5 if payload == "indicator" else 1.0)
 
 
 #: the name the forests' callers took before the boosters had a word
@@ -1689,13 +1746,13 @@ def forest_label_centre(y: jax.Array, w: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("depth", "n_bins",
                                              "feature_frac", "interpret",
-                                             "payload"))
+                                             "payload", "classes"))
 def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
                      rw: jax.Array, node_keys: jax.Array, votes: jax.Array,
                      *, depth: int, n_bins: int, feature_frac: float = 1.0,
                      min_instances=1.0, min_info_gain=0.0,
                      interpret: bool = False, payload: str = "indicator",
-                     centre=None):
+                     centre=None, classes: int = 0):
     """Grow one GROUP of a forest's trees for every CV fold in the fused
     passes and add their votes: lanes = (tree, fold), tree-major.
 
@@ -1719,14 +1776,21 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
     centre) / scale goes as three fixed-point bfloat16 parts whose sum it
     is, so every histogram sum the splits and the leaves are read from is
     the sum of exact products added exactly (to ~2^-25 of the scale a row),
-    and a leaf is centre + G / H.
+    and a leaf is centre + G / H. "class_indicators": y holds class ids
+    0 .. `classes` - 1 and the lanes carry K = `classes` channels weight x
+    (id == k), built in the kernels from [class id, weight]; the gain is
+    Spark's K-class Gini gain a weighted row, a leaf the weighted class
+    distribution of its rows, and votes [folds, K, N] — class-major: K as
+    the minor axis would pad to 128 lanes on the chip — the sum over trees
+    of each row's leaf distribution, a class at a time (one lookup a
+    class of the same final routing state, one lane plane live).
 
     `min_info_gain` is compared with THIS payload's gain: the variance
     gain of one channel — for a regression target Spark's own (Variance
     impurity, a weighted row), unhalved. For a binary label that is half
     the two-class Gini gain grow_tree sums over a [w (1 - y), w y]
-    payload, so a caller holding Spark's minInfoGain passes half of it
-    (models/trees).
+    payload, so a caller holding Spark's minInfoGain passes half of it;
+    K class channels' gain is Spark's whole (payload_min_info_gain).
 
     Returns (votes + sum over the group's trees of the leaf value each
     row lands on, read off the final routing state — no tree is traversed
@@ -1736,10 +1800,15 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
     folds, n_orig = W.shape
     pad = (-n_orig) % pallas_hist._ROUTE_BLK
     centred = payload == "centred_parts"
+    by_class = payload == "class_indicators"
+    if by_class != bool(classes):
+        raise ValueError(f"payload {payload!r} with classes={classes}")
     H = (rw[:, None, :] * W[None, :, :]).reshape(-1, n_orig)  # [T*folds, N]
     if centred:
         centre, scale = jnp.asarray(centre, jnp.float32)
         G = H * ((y - centre) * (1.0 / scale))[None, :]
+    elif by_class:   # the class id a row: the kernels make the channels
+        G, scale = jnp.broadcast_to(y[None, :], H.shape), None
     else:
         G, scale = H * y[None, :], None
     if pad:  # inert: zero payloads, as in _fit_gbt_folds_impl
@@ -1753,9 +1822,53 @@ def fit_forest_lanes(Xb: jax.Array, y: jax.Array, W: jax.Array,
         feature_mask=None, interpret=interpret, normalize_gain=True,
         leaf_mode="mean", node_feature_frac=feature_frac,
         node_keys=node_keys, payload_parts=PAYLOAD_PARTS[payload],
-        payload_scale=scale, leaf_offset=centre if centred else None)
+        payload_scale=scale, leaf_offset=centre if centred else None,
+        **(dict(classes=classes, leaf_rows_of=functools.partial(
+            _add_class_votes, votes, folds=folds, interpret=interpret))
+           if by_class else {}))
+    if by_class:
+        return leaf_rows, trees, subsets
     group_votes = leaf_rows[:, :n_orig].reshape(-1, folds, n_orig).sum(0)
     return votes + group_votes, trees, subsets
+
+
+def _add_class_votes(votes, leaf, node, *, folds, interpret):
+    """votes [folds, K, n] + the sum over a lane group's trees (lanes
+    tree-major) of each row's leaf distribution: leaf [lanes, leaves, K],
+    node [lanes, N >= n] the final routing state. A class at a time: one
+    lookup of the lanes' [leaves] column and one sum over its trees, the
+    next class's lookup held behind it (optimization_barrier), so that ONE
+    [lanes, N] plane of leaf rows is live and not K; the K sums join the
+    votes in one pass."""
+    from . import pallas_hist
+    n = votes.shape[2]
+    sums = []
+    for k in range(votes.shape[1]):
+        rows = pallas_hist.table_lookup(leaf[:, :, k], node,
+                                        interpret=interpret)[:, :n]
+        node, of_class = jax.lax.optimization_barrier(
+            (node, rows.reshape(-1, folds, n).sum(0)))
+        sums.append(of_class)
+    return votes + jnp.stack(sums, axis=1)
+
+
+class ClassMajorScores(NamedTuple):
+    """Class scores [folds, K, n] of a multiclass sweep, class-major: the
+    [folds, n, K] a validator's metric takes elsewhere would pad K to the
+    128 lanes of the chip's tiles (18 x the bytes at K = 7). The wrapper
+    is how the validator tells the layout (validators._class_major_metrics
+    takes the argmax over axis 1)."""
+    scores: jax.Array
+
+
+@functools.partial(jax.jit, static_argnames=("n_trees",))
+def forest_class_scores(votes: jax.Array, *, n_trees: int) -> jax.Array:
+    """[folds, K, n] class scores from summed leaf distributions:
+    _ForestBase._mask_score's rule — the mean over trees, clipped at 0,
+    renormalised over the classes (a tree counts 0 for a row on a
+    training-empty leaf)."""
+    prob = jnp.clip(votes / n_trees, 0.0, None)
+    return prob / jnp.maximum(prob.sum(axis=1, keepdims=True), 1e-12)
 
 
 @functools.partial(jax.jit, static_argnames=("n_trees", "classification"))
