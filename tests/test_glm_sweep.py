@@ -113,7 +113,12 @@ class TestKernelParity:
         tile-pair granularity, so wide transmogrified matrices (the r2
         wide bench is d=567) use the one-pass kernel too. Parity vs the
         per-lane logistic solver at d=600 (tiled, non-multiple of the
-        64-tile so column padding is exercised)."""
+        64-tile so column padding is exercised). 600 columns on 600
+        training rows leave the reg 0.01 lanes nearly separable and the
+        intercept coupled to the coefficients: the sweep, whose intercept
+        steps with them, is at its fixed point in 17 iterations; the
+        per-lane solver, which alternates, needs 65-75 to the same one, so
+        it is given them (at 20 its intercept is still 0.012 short)."""
         from transmogrifai_tpu.ops.glm_sweep import TRI_MAX_D
         rng = np.random.default_rng(11)
         n, d = 1200, 600
@@ -138,10 +143,10 @@ class TestKernelParity:
                 beta_ref, b0_ref = fit_logistic(
                     jnp.asarray(X), jnp.asarray(y),
                     jnp.asarray(masks[f] * w), jnp.asarray(regs[g]),
-                    jnp.asarray(0.0), max_iter=20, standardize=False)
+                    jnp.asarray(0.0), max_iter=200, standardize=False)
                 assert np.allclose(B[f, g], np.asarray(beta_ref),
-                                   atol=5e-3), (f, g)
-                assert abs(float(b0[f, g]) - float(b0_ref)) < 5e-3
+                                   atol=1e-4), (f, g)
+                assert abs(float(b0[f, g]) - float(b0_ref)) < 1e-4
 
     def test_streamed_hinge_matches_per_lane_svc(self):
         """Streamed squared_hinge must reproduce fit_linear_svc per lane —

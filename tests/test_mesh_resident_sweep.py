@@ -419,11 +419,14 @@ def test_lanes_retire_at_tol_in_every_layout(converged, layout):
 
 @pytest.mark.parametrize("bucket", [8, 32])
 def test_round_program_issues_the_collectives_it_declares(mesh, bucket):
-    """ONE psum call inside the iteration loop, of the four accumulators
-    together, and one outside it (the fold weight sums). jax lowers a
-    psum of four arrays to four all_reduce ops side by side, which the
-    TPU's compiler merges into one (compiled for a described v5e:2x2 the
-    program's text holds 2 all-reduces: PERF.md section 6, PR 35)."""
+    """ONE psum call inside the iteration loop, of the five accumulators
+    together (the gradient, the Gram, the two intercept sums and, since the
+    intercept steps with the coefficients, the Gram's border: 4 x bucket x
+    D more bytes, no further collective), and one outside it (the fold
+    weight sums). jax lowers a psum of five arrays to five all_reduce ops
+    side by side, which the TPU's compiler merges into one (compiled for a
+    described v5e:2x2 the program's text holds 2 all-reduces: PERF.md
+    section 6, PR 35)."""
     S = jax.ShapeDtypeStruct
     args = (S((N, D), jnp.bfloat16), S((N,), jnp.float32),
             S((N,), jnp.float32), S((FOLDS, N), jnp.float32),
@@ -432,15 +435,16 @@ def test_round_program_issues_the_collectives_it_declares(mesh, bucket):
             S((bucket,), jnp.float32), S((D,), jnp.float32),
             S((D,), jnp.float32), S((), jnp.int32), S((), jnp.float32))
     text = GS._sharded_round_fn(mesh, "logistic", True).lower(*args).as_text()
-    assert text.count("all_reduce") == 1 + 4
-    assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
+    assert text.count("all_reduce") == 1 + 5
+    assert GS.round_psum_bytes(bucket, D) \
+        == 4 * bucket * (D + D * D + 2 + D)
 
 
 def test_sharded_round_with_the_fused_pass_is_the_one_device_round(
         mesh, data, monkeypatch):
     """Where the backend has Mosaic every chip runs the fused pass
     (ops/pallas_glm.glm_moments, interpreted here) over its LOCAL rows
-    inside the shard_map, and the four accumulators still merge in the ONE
+    inside the shard_map, and the five accumulators still merge in the ONE
     psum an iteration: the same program text around another body, the same
     answer as the fused round on one device to float32 rounding."""
     import functools
@@ -482,8 +486,9 @@ def test_sharded_round_with_the_fused_pass_is_the_one_device_round(
     for a, b in zip(one[:3], four[:3]):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
                                    atol=1e-5)
-    assert text.count("all_reduce") == 1 + 4
-    assert GS.round_psum_bytes(bucket, D) == 4 * bucket * (D + D * D + 2)
+    assert text.count("all_reduce") == 1 + 5
+    assert GS.round_psum_bytes(bucket, D) \
+        == 4 * bucket * (D + D * D + 2 + D)
 
 
 def _sort_lengths(text):

@@ -15,7 +15,8 @@ lanes (reference workload: the 8-thread pool of OpValidator.scala:270-332,
 every thread refitting against the same cached DataFrame):
 
 - one pass over the rows per Newton iteration, carrying per-lane
-  accumulators (g [L, d], Hessians [L, d, d], intercept sums). Which body
+  accumulators (g [L, d], Hessians [L, d, d], their borders sum S xs
+  [L, d], intercept sums). Which body
   runs the pass is read from what the program can observe
   (`glm_round_kernel`; no option): on a backend that has Mosaic, for a
   resident bfloat16 matrix of at most 120 columns or of 128, ONE Pallas
@@ -45,7 +46,16 @@ every thread refitting against the same cached DataFrame):
   v5 lite at the BASELINE shapes (tools/tpu_glm_hess_ab.py). No
   per-lane scaled copy of X exists anywhere in HBM;
 - per-lane 64x64 Newton solves + proximal L1 + intercept steps are
-  batched dense linalg on [L, d, d] — microscopic next to the pass.
+  batched dense linalg on [L, d, d] — microscopic next to the pass. B and
+  the intercept step TOGETHER (`_newton_prox_update`): one solve with two
+  right-hand sides, u = H^-1 g and v = H^-1 c (c the Hessian's border,
+  the curvature-weighted column means), then block Gauss-Seidel on the
+  iteration's own quadratic model — dB = soft(B - u + v db0) - B, db0 =
+  (g0 + c . dB) / h0, a few sweeps on [L, d] vectors. Its fixed points are
+  those of the alternation it replaced (B by H^-1 g, the intercept by
+  g0 / h0, each as if the other stood still), which on columns whose
+  curvature-weighted means are far from zero (null indicators) converged
+  linearly at ~0.62 a step: 30 passes over X where this takes 9.
 
 Convergence awareness (docs/performance.md "Convergence-aware GLM
 sweep"): one streamed kernel a loss, on top of the shared scan machinery.
@@ -299,25 +309,72 @@ def _blocked(Xs, y, w, fold_masks, c: int):
             w.reshape(nb, c), fold_masks.reshape(F, nb, c).transpose(1, 0, 2))
 
 
-def _newton_prox_update(B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye,
+# Sweeps of the coupled update's inner iteration after its first (a constant
+# of the arithmetic, not a knob: on the null-tracked table's grid 2, 8 and 32
+# stop every lane at the same iteration)
+INTERCEPT_SWEEPS = 2
+
+
+def _newton_prox_update(B, b0, gA, hA, g0A, h0A, cA, wsum_l, l1, l2, eye,
                         assemble, fit_intercept: bool):
     """THE damped-Newton + proximal-L1 + intercept update from streamed
     accumulators, shared by the resident round kernel and the tileplane
-    source rounds — the parity contract between them (and the
-    moment-space replay in ops/glm.prox_newton_gram) lives in this one
-    function, so a change to the update rule reaches every route at
-    once. Returns (B_new, b0_new, delta_vec [L])."""
+    source rounds, so a change to the update rule reaches both at once.
+    (`ops/glm.prox_newton_gram`, the squared loss's moment-space solve, and
+    `ops/glm._newton_prox_fit`, the per-lane solver, are functions of their
+    own with the alternation this one had: the same fixed points.)
+
+    With g, H the coefficients' gradient and Hessian (ridge included), g0,
+    h0 the intercept's and c = cA / wsum the Hessian's border (the
+    curvature-weighted column means), u, v = H^-1 g, H^-1 c and soft the
+    threshold by l1 / diag(H):
+
+        dB  = soft(B - u) - B;  db0 = (g0 + c . dB) / h0
+        INTERCEPT_SWEEPS times:
+            dB  = soft(B - u + v db0) - B    # B's step, the intercept
+            db0 = (g0 + c . dB) / h0         #   moving by -db0; and back
+        B, b0 <- B + dB, b0 - db0
+
+    block Gauss-Seidel on the iteration's own quadratic model, on [lanes, d]
+    vectors and with no further pass over X. The first line alone is the
+    alternation this function had (each step as if the other stood still:
+    block Jacobi, linear at ~0.62 a step where c is far from zero, as on
+    null indicators). Where soft(B - u) = B and g0 = 0 every dB and db0
+    here is zero, and a fixed point of this map has db0 = 0, hence g0 = 0
+    and soft(B - u) = B: the two iterations have the same fixed points, so
+    no documented answer moves, only the count of passes to it. (A bordered
+    [d + 1, d + 1] solve followed by the threshold does NOT: the threshold
+    moves B after the joint solve and the intercept never hears of it,
+    which leaves g0 off zero.) cA's rounding moves no fixed point: there it
+    multiplies a zero. Without an intercept, and in a lane whose B is
+    thresholded to zero (dB = 0), the step is the older one to the letter.
+    Returns (B_new, b0_new, delta_vec [L])."""
     g = gA / wsum_l[:, None] + l2[:, None] * B
     H = assemble(hA) / wsum_l[:, None, None]
     H = H + (l2[:, None, None] + 1e-6) * eye[None]
-    step = jnp.linalg.solve(H, g[..., None])[..., 0]
-    B_new = B - step
     hdiag = jnp.maximum(jnp.diagonal(H, axis1=1, axis2=2), EPS)
-    B_new = (jnp.sign(B_new)
-             * jnp.maximum(jnp.abs(B_new) - l1[:, None] / hdiag, 0.0))
+    thresh = l1[:, None] / hdiag
+
+    def soft(z):
+        return jnp.sign(z) * jnp.maximum(jnp.abs(z) - thresh, 0.0)
+
     if fit_intercept:
-        b0_new = b0 - (g0A / wsum_l) / jnp.maximum(h0A / wsum_l, EPS)
+        c = cA / wsum_l[:, None]
+        uv = jnp.linalg.solve(H, jnp.stack([g, c], axis=-1))
+        Bu, v = B - uv[..., 0], uv[..., 1]
+        g0 = g0A / wsum_l
+        h0 = jnp.maximum(h0A / wsum_l, EPS)
+
+        def intercept_step(B_new):
+            return (g0 + (c * (B_new - B)).sum(axis=1)) / h0
+        B_new = soft(Bu)
+        db0 = intercept_step(B_new)
+        for _ in range(INTERCEPT_SWEEPS):
+            B_new = soft(Bu + v * db0[:, None])
+            db0 = intercept_step(B_new)
+        b0_new = b0 - db0
     else:
+        B_new = soft(B - jnp.linalg.solve(H, g[..., None])[..., 0])
         b0_new = b0
     delta = jnp.abs(B_new - B).max(axis=1) + jnp.abs(b0_new - b0)
     return B_new, b0_new, delta
@@ -909,13 +966,24 @@ def sweep_glm_squared_gram_sharded(mesh, X, y, w, fold_masks, regs, alphas,
 
 # -- round kernel + host retirement driver (IRLS losses) ---------------------
 
+def _moments_acc0(Lb: int, d_work: int):
+    """Zeros of one pass's sums (gA, Hessian blocks, g0A, h0A, cA)."""
+    tiled, _, bt, tile_pairs = _tiling(d_work)
+    _, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
+    return (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
+            jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32),
+            jnp.zeros((Lb, d_work), jnp.float32))
+
+
 def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
                     axis_name: Optional[str] = None, acc0=None):
     """One Newton iteration's pass over X as an XLA scan over row blocks
     (`blocks` is `_blocked`'s), for a backend without Mosaic and for the
     shapes the fused pass leaves alone (`glm_round_kernel`): (gA [Lb, d],
-    Hessian blocks, g0A [Lb], h0A [Lb]), the sums over rows of R xs', S xs
-    xs', R and S (pallas_glm.glm_moments is the same sums in one program).
+    Hessian blocks, g0A [Lb], h0A [Lb], cA [Lb, d]), the sums over rows of
+    R xs', S xs xs', R, S and S xs' (pallas_glm.glm_moments is the same
+    sums in one program; cA, the Hessian's border, takes S rounded to the
+    block's dtype as it does there: it shapes the step, not its fixed point).
     B [Lb, d] is the coefficients, float32 as the iteration carries them:
     the margins see them unrounded, as the fused pass's do and by the same
     split (`pallas_glm.float32_parts`: the three-part product against a
@@ -936,13 +1004,13 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
     rc = _residual_curvature(loss)
     d_work, Lb = mean.shape[0], sel.shape[1]
     tiled, _, bt, tile_pairs = _tiling(d_work)
-    hess_blocks, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
+    hess_blocks = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)[0]
     dtype = blocks[0].dtype
     Bparts = pallas_glm.float32_parts(B, dtype).T
 
     def body(acc, sl):
         x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
-        gA, hA, g0A, h0A = acc
+        gA, hA, g0A, h0A, cA = acc
         # standardize on the fly; the low-precision cast keeps the
         # eta contraction on the bf16 MXU path exactly like the
         # materialized-Xs route
@@ -962,13 +1030,12 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
             xs_low.T, pallas_glm.float32_parts(R.T, dtype).T,
             preferred_element_type=jnp.float32), Lb, axis=1).T
         hA = hA + hess_blocks(xf, S)
-        return (gA, hA, g0A + R.sum(0), h0A + S.sum(0)), None
+        cA = cA + jnp.matmul(S.T.astype(dtype), xs_low,
+                             preferred_element_type=jnp.float32)
+        return (gA, hA, g0A + R.sum(0), h0A + S.sum(0), cA), None
 
     if acc0 is None:
-        acc0 = _shard_vary(
-            (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
-             jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32)),
-            axis_name)
+        acc0 = _shard_vary(_moments_acc0(Lb, d_work), axis_name)
     return jax.lax.scan(body, acc0, blocks)[0]
 
 
@@ -1034,15 +1101,15 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
 
     def accumulate(B, b0):
         if fused:
-            gA, hA, g0A, h0A, gA_low = pallas_glm.glm_moments(
+            gA, hA, g0A, h0A, gA_low, cA = pallas_glm.glm_moments(
                 X if x_tile == "cols_minor" else X.T, y_rows, w_rows,
                 fold_masks, sel, B, b0, mean, std, loss=loss,
                 x_tile=x_tile)
-            moments = gA + gA_low, hA, g0A, h0A
+            moments = gA + gA_low, hA, g0A, h0A, cA
         else:
             moments = _moments_blocks(blocks, sel, B, b0, mean, std,
                                       loss=loss, axis_name=axis_name)
-        # ONE collective an iteration: the four accumulators merge over
+        # ONE collective an iteration: the five accumulators merge over
         # the mesh together (round_psum_bytes)
         return allreduce(moments)
 
@@ -1052,9 +1119,8 @@ def _round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
 
     def body(state):
         i, B, b0, _ = state
-        gA, hA, g0A, h0A = accumulate(B, b0)
         B_new, b0_new, delta_vec = _newton_prox_update(
-            B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2, eye, assemble,
+            B, b0, *accumulate(B, b0), wsum_l, l1, l2, eye, assemble,
             fit_intercept)
         return i + 1, B_new, b0_new, delta_vec
 
@@ -1132,12 +1198,12 @@ def glm_round_temp_bytes(X, y, w, fold_masks, bucket: int, *, loss: str,
 def round_psum_bytes(bucket: int, d: int) -> int:
     """Bytes of the ONE collective an iteration of the sharded round
     program of this bucket issues over the mesh, from its static shape:
-    the psum of the gradient, the Gram (or its tile pairs) and the two
-    intercept sums together, float32. The round's other collective, the
-    fold weight sums, is once a program and [F] floats."""
+    the psum of the gradient, the Gram (or its tile pairs), its border and
+    the two intercept sums together, float32. The round's other collective,
+    the fold weight sums, is once a program and [F] floats."""
     tiled, d_work, bt, tile_pairs = _tiling(d)
     gram = len(tile_pairs) * bt * bt if tiled else d_work * d_work
-    return 4 * bucket * (d_work + gram + 2)
+    return 4 * bucket * (2 * d_work + gram + 2)
 
 
 # -- tileplane source route (X streamed from disk, never resident) -----------
@@ -1182,7 +1248,7 @@ def _source_round_step(carry, xt, yt, wt, mt, B, b0, sel, mean, std, *,
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
-def _source_round_update(gA, hA, g0A, h0A, B, b0, wsum_l, l1, l2, *,
+def _source_round_update(gA, hA, g0A, h0A, cA, B, b0, wsum_l, l1, l2, *,
                          fit_intercept: bool):
     """The Newton/prox/intercept update from one streamed pass's merged
     accumulators — the SAME _newton_prox_update as every other route, so
@@ -1193,15 +1259,8 @@ def _source_round_update(gA, hA, g0A, h0A, B, b0, wsum_l, l1, l2, *,
     tiled, _, bt, tile_pairs = _tiling(d_work)
     _, assemble, _ = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
     eye = jnp.eye(d_work, dtype=jnp.float32)
-    return _newton_prox_update(B, b0, gA, hA, g0A, h0A, wsum_l, l1, l2,
+    return _newton_prox_update(B, b0, gA, hA, g0A, h0A, cA, wsum_l, l1, l2,
                                eye, assemble, fit_intercept)
-
-
-def _source_round_acc0(Lb: int, d_work: int):
-    tiled, _, bt, tile_pairs = _tiling(d_work)
-    _, _, h_acc0 = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)
-    return (jnp.zeros((Lb, d_work), jnp.float32), h_acc0,
-            jnp.zeros(Lb, jnp.float32), jnp.zeros(Lb, jnp.float32))
 
 
 def _new_round_state(L: int, d: int, n_classes: int = 0) -> Dict[str, Any]:
@@ -1262,8 +1321,13 @@ def _run_rounds(st, run_round: Callable, round_iters: int, max_iter: int,
             on_round(st)
 
 
-def _rounds_info(st, tol: float, max_iter: int) -> Dict[str, Any]:
-    """The convergence telemetry every round driver reports."""
+def _rounds_info(st, tol: float, max_iter: int,
+                 intercept_sweeps: int = 0) -> Dict[str, Any]:
+    """The convergence telemetry every round driver reports.
+    `intercept_sweeps`: the inner sweeps of the update that steps B and the
+    intercept together (`_newton_prox_update`: INTERCEPT_SWEEPS where the
+    binary rounds fit an intercept; 0 where none is fitted and in the
+    drivers whose update is another)."""
     return {"glm_rounds": int(st["rounds"]),
             "data_passes": int(st["data_passes"]),
             "lane_passes": int(st["lane_passes"]),
@@ -1274,7 +1338,8 @@ def _rounds_info(st, tol: float, max_iter: int) -> Dict[str, Any]:
                                  & (st["iters"] >= max_iter)).sum()),
             "active_per_round": [int(v) for v in st["active_per_round"]],
             "iters_per_round": [int(v) for v in st["iters_per_round"]],
-            "bucket_sizes": [int(v) for v in st["bucket_sizes"]]}
+            "bucket_sizes": [int(v) for v in st["bucket_sizes"]],
+            "intercept_sweeps": int(intercept_sweeps)}
 
 
 def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
@@ -1429,6 +1494,8 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
     # what the mesh costs a round: 0 collectives on one device
     shards = _mesh_batch_count(mesh)
     psums = int(shards > 1)
+    # which update the rounds take (`_newton_prox_update`)
+    sweeps = INTERCEPT_SWEEPS if fit_intercept else 0
 
     # span hook: each retirement round is one child span of whatever the
     # validator opened (run -> sweep_fit -> sweep_round), carrying the
@@ -1458,12 +1525,12 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 return _source_round_step(carry, xt, yt, wt, mt, B, b0j,
                                           sel_j, mean, std, loss=loss)
 
-            (gA, hA, g0A, h0A), _ps = TP.run_tileplane(
-                X, step, _source_round_acc0(Lb, d_work),
+            sums, _ps = TP.run_tileplane(
+                X, step, _moments_acc0(Lb, d_work),
                 tile_rows=tile_rows, label="glm_round",
                 prefetch=prefetch)
             B, b0j, delta_dev = _source_round_update(
-                gA, hA, g0A, h0A, B, b0j, wsum_l, l1j, l2j,
+                *sums, B, b0j, wsum_l, l1j, l2j,
                 fit_intercept=bool(fit_intercept))
             it += 1
             with _collector.trace_span("round_fetch", kind="host_step"):
@@ -1490,7 +1557,8 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
                 f"glm_round[{Lb}]", kind="sweep_round", bucket=int(Lb),
                 active=int(k), iters_budget=int(budget), kernel=kernel,
                 body=kernel, x_tile=glm_x_tile(d), shards=shards,
-                psums=psums, psum_bytes=psums * round_psum_bytes(Lb, d)), \
+                psums=psums, psum_bytes=psums * round_psum_bytes(Lb, d),
+                intercept_sweeps=sweeps), \
                 _podtrace.pod_round(st["rounds"], bucket=int(Lb),
                                     active=int(k)):
             args = None
@@ -1585,7 +1653,7 @@ def sweep_glm_streamed_rounds(X, y, w, fold_masks, regs, alphas, *,
             # sums outgrew the kernel's VMEM and a smaller one's did not)
             "round_kernel": "+".join(sorted(
                 bodies or {pass_body(bucket_lanes(L))})),
-            **_rounds_info(st, tol_f, max_iter),
+            **_rounds_info(st, tol_f, max_iter, sweeps),
             "warm_start": bool(st["warmed"]),
             "warm_seeded": warm_seeded,
             # what the mesh cost the rounds: one collective an iteration

@@ -42,6 +42,15 @@ has one part of each, the operand itself. The XLA body
 (`glm_sweep._moments_blocks`) splits B the same way and R into ALL its
 parts: it returns the one gradient, exact on every backend.
 
+Under the residual's parts rides one more slab of the same contraction: the
+curvature x weight S, rounded to the matrix's dtype once, whose product with
+the block is cA = sum_rows S xs', the Hessian's border. It couples the
+coefficients' step to the intercept's (`glm_sweep._newton_prox_update`), it
+shapes the step and multiplies a zero at the fixed point, so its rounding
+moves no answer: the standing the Gram's operands have. No further pass over
+the block, one more [lanes, chunk] row group in a contraction that is 1/64
+to 1/128 of the Gram's.
+
 Kept apart from ops/pallas_hist.py, ops/pallas_softmax.py and
 ops/pallas_wide.py on purpose: a Mosaic body carries its source locations,
 so an edit that moves a file's lines makes every kernel of it miss the
@@ -222,10 +231,16 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
         # operand it measured 83.6 ms a pass where this form takes 57.5
         # (PERF.md, PR 36). They go to the contraction as a value: through
         # a VMEM scratch of the kernel's own the pass is 4 ms longer.
+        Rw, Sw = R, S
         if lanes % pack:    # the cast fills whole sublane tiles
-            R = jnp.concatenate(
-                [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
-        Rp = float32_parts(R, dtype, residual_parts(dtype), in_kernel=True)
+            Rw, Sw = (jnp.concatenate(
+                [V, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
+                for V in (R, S))
+        # under the residual's parts the curvature x weight, one part: its
+        # slab of the product is sum_rows S xs', the Hessian's border
+        Rp = jnp.concatenate(
+            [float32_parts(Rw, dtype, residual_parts(dtype), in_kernel=True),
+             Sw.astype(dtype)], axis=0)
         g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
             xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(
@@ -257,22 +272,22 @@ def _padded(d: int, lanes: int, dtype) -> tuple:
 
 def _gradient_slabs(lp: int, dtype) -> tuple:
     """(slabs, columns a slab) of the kernel's gradient block: a slab for
-    each of the residual's parts, side by side, each as wide as a part has
-    rows in the kernel — the lanes in whole sublane tiles of `dtype`, which
-    the cast fills."""
-    return residual_parts(dtype), _round_up(lp, _pack(dtype))
+    each of the residual's parts and after them one for the curvature x
+    weight, side by side, each as wide as a part has rows in the kernel —
+    the lanes in whole sublane tiles of `dtype`, which the cast fills."""
+    return residual_parts(dtype) + 1, _round_up(lp, _pack(dtype))
 
 
 def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
     """What the kernel keeps in VMEM for a bucket of `lanes`, in either
     tile form: the weighted blocks of every chunk of a body and beside them
-    the residual's parts, [lanes, chunk] each (two of a bfloat16 matrix:
-    0.5 MiB at 64 lanes), the float32 sums and the tile of X (as X.T or as
-    X: the same bytes), y, w and the fold masks twice each for the
-    pipeline's buffers, and twice the coefficients' parts (three of a
-    bfloat16 matrix: 0.1 MiB at 64 lanes of 128 columns). (The float32
-    products before the cast never exist whole: compiled for a v5e, 256
-    lanes of 127 columns fit its 96 MiB.) At 128 columns a 64-lane bucket
+    the residual's parts and the curvature's one, [lanes, chunk] each (three
+    slabs of a bfloat16 matrix: 0.75 MiB at 64 lanes), the float32 sums and
+    the tile of X (as X.T or as X: the same bytes), y, w and the fold masks
+    twice each for the pipeline's buffers, and twice the coefficients' parts
+    (three of a bfloat16 matrix: 0.1 MiB at 64 lanes of 128 columns). (The
+    float32 products before the cast never exist whole: compiled for a v5e,
+    256 lanes of 127 columns fit its 96 MiB.) At 128 columns a 64-lane bucket
     holds 32 MiB of weighted blocks, 8 MiB of sums and 9 MiB of tiles, 50
     MiB in all, and a 128-lane bucket 91 MiB (both compile for a v5e); 256
     lanes hold 172 MiB, and `glm_round_kernel` leaves that bucket to the
@@ -303,16 +318,23 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
                 loss: str, n_rows=None, interpret: bool = False,
                 x_tile: str = "rows_minor"):
     """(gA [lanes, d], hA [lanes, d, d], g0A [lanes], h0A [lanes], gA_low
-    [lanes, d]) float32: the sums over the first `n_rows` rows (default:
-    all) of R xs', S xs xs', R and S, where xs is the standardised row in
-    the matrix's dtype, R and S the loss's residual and curvature at xs' B
-    + b0 times the lane's fold weight — one Newton iteration's pass of
-    `_round_core` for a lane bucket. gA contracts R rounded to the matrix's
+    [lanes, d], cA [lanes, d]) float32: the sums over the first `n_rows`
+    rows (default: all) of R xs', S xs xs', R and S, where xs is the
+    standardised row in the matrix's dtype, R and S the loss's residual and
+    curvature at xs' B + b0 times the lane's fold weight — one Newton
+    iteration's pass of `_round_core` for a lane bucket. gA contracts R rounded to the matrix's
     dtype, as it always has; gA_low is the same sum over what that rounding
     left, R - rounded(R), cut to the dtype in its turn (zeros for a float32
     matrix, which rounds nothing): gA + gA_low is the gradient at the
     residual's float32 precision, and that is what the iteration steps on
     (`glm_sweep._round_core` adds the two before the mesh's all-reduce).
+    cA, the last, is sum_rows S xs', the curvature-weighted column sums:
+    the border of the Hessian, which couples the coefficients' step to the
+    intercept's (`glm_sweep._newton_prox_update`). S rides the gradient's
+    contraction as one more slab under the residual's parts, rounded to the
+    matrix's dtype once: cA shapes the step and multiplies a zero at the
+    fixed point, the standing the Gram's operands have. Every sum is zero
+    in an inert lane.
 
     The matrix comes in the layout it already has on the chip, named by
     `x_tile` (`glm_sweep.glm_x_tile(d)`: what the width makes of it, no
@@ -391,12 +413,14 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     )(XT, y_rows, w_rows, fold_masks.astype(f32), *resident)
     # h[j, lane * dp + i] = sum_c xs[j, c] (S[lane, c] xs[i, c])
     hA = h.reshape(dp, lp, dp).transpose(1, 2, 0)
-    # g[j, part * slab + lane] = sum_c xs[j, c] R_part[lane, c]
+    # g[j, part * slab + lane] = sum_c xs[j, c] R_part[lane, c], and in the
+    # last slab sum_c xs[j, c] S[lane, c]
     gA = g[:d, :lanes].T
-    gA_low = slab_sum(g[:, slab:slabs * slab], slab, axis=1)[:d, :lanes].T \
-        if slabs > 1 else jnp.zeros_like(gA)
+    border = (slabs - 1) * slab
+    gA_low = slab_sum(g[:, slab:border], slab, axis=1)[:d, :lanes].T \
+        if slabs > 2 else jnp.zeros_like(gA)
     return gA, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes], \
-        h0.sum(axis=1)[:lanes], gA_low
+        h0.sum(axis=1)[:lanes], gA_low, g[:d, border:border + lanes].T
 
 
 def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
@@ -458,10 +482,14 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
         R, S = r0 * wl, s0 * wl
         lane_sums(g0_ref, R)
         lane_sums(h0_ref, S)
+        Rw, Sw = R, S
         if lanes % pack:
-            R = jnp.concatenate(
-                [R, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
-        Rp = float32_parts(R, dtype, residual_parts(dtype), in_kernel=True)
+            Rw, Sw = (jnp.concatenate(
+                [V, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
+                for V in (R, S))
+        Rp = jnp.concatenate(
+            [float32_parts(Rw, dtype, residual_parts(dtype), in_kernel=True),
+             Sw.astype(dtype)], axis=0)
         g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
             xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(
