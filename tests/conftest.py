@@ -45,26 +45,36 @@ _STALE_MANIFEST_PINS = (
 
 
 # The files that take a worker longest, longest first (their seconds in a
-# cold 6-worker run of PR 37's tree): xdist's `loadfile` hands files out in
-# collection order, so tests/test_trees.py, a tenth of the suite's work and
-# last but three in the alphabet, started when the other workers were
-# nearly done and ran on alone; started first, the same work ends ~250 s
-# sooner (PERF.md, Tier-1's wall). Order only: nothing is dropped or marked.
+# cold 6-worker run: of PR 37's tree, and of PR 56's for the four files
+# PRs 51 and 54 added). What the order does: xdist (3.8) sorts `loadfile`'s
+# files by their NUMBER of tests, most first (`--loadscope-reorder`, its
+# default), and only then hands them out, so this list is the tie-break
+# among files of equal count and no more. Taking that sort off from here was
+# tried (PR 56) and is worse: the two files whose many small host-threaded
+# programs fight the other workers for cores (tests/test_glm_sweep.py, 8 s
+# alone and 506 s loaded, and tests/test_tree_quality_oracle.py) then start
+# together and slow each other (769 s of wall against 590), and a test that
+# counts a process's compiles meets other neighbours. Order only: nothing is
+# dropped or marked.
 _HEAVY_FIRST = (
     "tests/test_trees.py",                          # 626
     "tests/benchmark/test_benchmark_forest.py",     # 618
     "tests/test_pallas_hist.py",                    # 560
     "tests/test_hist_batched.py",                   # 315
+    "tests/benchmark/test_benchmark_forest_mc.py",  # 293 (PR 56's tree)
     "tests/test_sweep_scale.py",                    # 293
     "tests/test_glm_sweep.py",                      # 286
     "tests/test_forest_lanes.py",                   # 283
     "tests/test_loco_batched.py",                   # 269
     "tests/benchmark/test_benchmark_wide.py",       # 268
+    "tests/test_forest_multiclass_lanes.py",        # 259 (PR 56's tree)
     "tests/test_mlr_fused_kernel.py",               # 209
     "tests/test_glm_wide.py",                       # 202
     "tests/test_tree_levels.py",                    # 202
     "tests/benchmark/test_benchmark_mlr.py",        # 193
     "tests/benchmark/test_benchmark_nulls.py",      # 186
+    "tests/benchmark/test_benchmark_gbt_reg.py",    # 176 (PR 56's tree)
+    "tests/test_gbt_regression_payload.py",         # 151 (PR 56's tree)
 )
 
 

@@ -57,6 +57,18 @@ def as_v5e(monkeypatch):
     compilation_cache.reset_cache()
 
 
+def _mosaic_call_named(compiled, name: str) -> bool:
+    """Is a Mosaic custom call of the compiled program an instruction NAMED
+    `name` (`%glm_moments.4 = ... custom-call(...)`)? The chip's compiler
+    names it after the function of the call's innermost frame, the traces
+    show that name and the benchmark's readers match it: with
+    `jax_include_full_tracebacks_in_locations` off every one reads
+    `tpu_custom_call.N` (PERF.md §6, PR 56)."""
+    import re
+    return re.search(rf"%?{name}[.\d]* = [^\n]*custom-call\(",
+                     compiled.as_text()) is not None
+
+
 def _round_shapes(one_chip, n, d, K, Lb, F, dtype):
     def S(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -80,7 +92,7 @@ def test_fused_multinomial_round_compiles_for_a_v5e(
     compiled = GS.sweep_mlr_round.lower(
         *_round_shapes(one_chip, n, d, K, Lb, F, dtype),
         fit_intercept=True).compile()
-    assert "mlr_gradient" in compiled.as_text()
+    assert _mosaic_call_named(compiled, "mlr_gradient")
     x_bytes = n * d * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * x_bytes
 
@@ -126,7 +138,7 @@ def test_fused_wide_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
     compiled = GS.sweep_glm_wide_round.lower(
         *_wide_round_shapes(one_chip, n, d, Lb, F, BF16),
         fit_intercept=True).compile()
-    assert "wide_gradient" in compiled.as_text()
+    assert _mosaic_call_named(compiled, "wide_gradient")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2
 
 
@@ -162,7 +174,7 @@ def test_fused_binary_round_compiles_for_a_v5e(one_chip, as_v5e, n, d, Lb, F,
     compiled = GS.sweep_glm_round.lower(
         *_glm_round_shapes(S, n, d, Lb, F), loss=loss,
         fit_intercept=True).compile()
-    assert "glm_moments" in compiled.as_text()
+    assert _mosaic_call_named(compiled, "glm_moments")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * n * d * 2
     if d == 128:    # the cell's bound; the XLA body there holds 7.4 GB
         assert GS.glm_x_tile(d) == "cols_minor"
@@ -254,7 +266,7 @@ def test_heldout_metric_pass_compiles_for_a_v5e(one_chip, metric_programs,
     compiled = metric_programs._streamed_eval_heldout.lower(
         *_heldout_eval_shapes(S, n, 64, 5, Gc), unit, metric="au_pr",
         rank_bins=4096).compile()
-    assert "_hist_two_level_jit" in compiled.as_text()
+    assert _mosaic_call_named(compiled, "_hist_two_level_jit")
     assert "_hist_pallas_jit" not in compiled.as_text()
 
 
@@ -425,3 +437,44 @@ def test_class_channel_histogram_kernels_compile_for_a_v5e(one_chip, as_v5e):
     for compiled in (root, deep):
         assert "tpu_custom_call" in compiled.as_text()
     assert f"f32[{lanes * nodes * rows},{F * B}]" in deep.as_text()
+
+
+def test_a_mosaic_bodys_bytes_do_not_hold_its_callers_lines():
+    """The serialised Mosaic body inside the custom call (lowered for the TPU
+    here, nothing compiled) is byte-equal when the CALLER's source is
+    compiled with and without three leading blank lines: one frame of
+    traceback a location (utils/platform.enable_compilation_cache). With
+    jax's ten frames the same two bodies differ, which moved the kernel's
+    compile-cache key with every edit above its call site (PERF.md §6,
+    PR 56)."""
+    import re
+    from transmogrifai_tpu.ops import pallas_glm as PG
+    n, d, F, L = 8192, 64, 5, 8
+    S = jax.ShapeDtypeStruct
+    args = (S((d, n), BF16), S((n,), F32), S((n,), F32), S((F, n), F32),
+            S((F, L), F32), S((L, d), F32), S((L,), F32), S((d,), F32),
+            S((d,), F32))
+    src = ("def caller(XT, y, w, m, sel, B, b0, mean, std):\n"
+           "    return PG.glm_moments(XT, PG.dense_rows(y), PG.dense_rows(w),"
+           " m, sel, B, b0, mean, std, loss='logistic')\n")
+
+    def bodies():
+        out = []
+        for blanks in (0, 3):
+            ns = {"PG": PG}
+            exec(compile("\n" * blanks + src, "caller_of_glm_moments.py",
+                         "exec"), ns)
+            PG.glm_moments.clear_cache()
+            text = jax.jit(ns["caller"]).trace(*args).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+            out.append(re.findall(r'backend_config = "([^"]*)"', text))
+        PG.glm_moments.clear_cache()
+        return out
+    own, moved = bodies()
+    assert len(own) == 1 and own == moved
+    jax.config.update("jax_traceback_in_locations_limit", 10)
+    try:
+        own, moved = bodies()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", 1)
+    assert own != moved

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from transmogrifai_tpu.ops import glm_sweep as GS
 from transmogrifai_tpu.ops import pallas_glm as PG
 from transmogrifai_tpu.ops import pallas_hist
+from transmogrifai_tpu.ops import parts as P
 from transmogrifai_tpu.utils.metrics import collector
 
 FOLDS = 3
@@ -253,15 +254,17 @@ def test_exact_coefficients_give_the_one_part_sums_to_the_bit(
     n, d, Lb, live, loss, n_pad = case
     args = _problem(n, d, Lb, live, seed=n + d + Lb,
                     wide_scales=d == 128) + (loss,)
-    assert PG.n_parts(BF16) == 3 and PG.n_parts(F32) == 1
+    assert P.n_parts(BF16) == 3 and P.n_parts(F32) == 1
     assert PG.residual_parts(BF16) == 2 and PG.residual_parts(F32) == 1
-    parts = np.asarray(PG.float32_parts(args[5].astype(F32), BF16)
-                       .astype(F32))
-    assert parts.shape == (3 * Lb, d) and (parts[Lb:] == 0).all()
+    parts = np.asarray(P.float32_parts(args[5].astype(F32), BF16))
+    assert parts.shape == (3, Lb, d) and (parts[1:] == 0).all()
     got = _fused_raw(*args, n_pad=n_pad)
     got_f32 = _fused_raw(*args[:5], args[5].astype(F32), *args[6:],
                          n_pad=n_pad)
-    monkeypatch.setattr(PG, "n_parts", lambda dtype: 1)
+    split = P.float32_parts
+    monkeypatch.setattr(
+        P, "float32_parts", lambda V, dtype, parts=None, **kw: split(
+            V, dtype, 1 if parts is None else parts, **kw))
     monkeypatch.setattr(PG, "residual_parts", lambda dtype: 2)
     PG.glm_moments.clear_cache()
     one_part = _fused_raw(*args, n_pad=n_pad)
@@ -286,17 +289,19 @@ def test_coefficient_parts_sum_to_the_float32_coefficients():
     rng = np.random.default_rng(0)
     B = (rng.normal(size=(8, 64)) * 10.0 ** rng.integers(-6, 3, (8, 64))) \
         .astype(np.float32)
-    parts = np.asarray(PG.float32_parts(jnp.asarray(B), BF16)
-                       .astype(F32)).reshape(3, 8, 64)
+    parts = np.asarray(P.float32_parts(jnp.asarray(B), BF16))
+    assert parts.shape == (3, 8, 64)
+    assert np.array_equal(parts, np.asarray(
+        jnp.asarray(parts).astype(BF16).astype(F32)))
     assert np.array_equal(parts[2] + parts[1] + parts[0], B)
     assert np.array_equal(parts[0], np.asarray(
         jnp.asarray(B).astype(BF16).astype(F32)))
     assert (np.abs(parts[1]) <= np.abs(B) * 2.0 ** -8).all()
-    assert np.array_equal(np.asarray(PG.float32_parts(
-        jnp.asarray(B), F32)), B)
+    (whole,) = P.float32_parts(jnp.asarray(B), F32)
+    assert np.array_equal(np.asarray(whole), B)
     stacked = jnp.asarray(parts.reshape(24, 64))
-    assert np.array_equal(np.asarray(PG.slab_sum(stacked, 8)), B)
-    assert np.array_equal(np.asarray(PG.slab_sum(stacked.T, 8, axis=1)), B.T)
+    assert np.array_equal(np.asarray(P.slab_sum(stacked, 8)), B)
+    assert np.array_equal(np.asarray(P.slab_sum(stacked.T, 8, axis=1)), B.T)
 
 
 @pytest.mark.parametrize("in_kernel", [False, True],
@@ -312,18 +317,20 @@ def test_two_parts_hold_the_residual_to_2_to_the_minus_17(in_kernel):
     R = (rng.uniform(-1, 1, size=(16, 256))
          * rng.uniform(0.5, 2.0, size=(1, 256))).astype(np.float32)
     assert PG.residual_parts(BF16) == 2 and PG.residual_parts(F32) == 1
-    split = jax.jit(lambda v: PG.float32_parts(
-        v, BF16, PG.residual_parts(BF16), in_kernel=in_kernel).astype(F32))
-    hi, lo = np.asarray(split(jnp.asarray(R))).reshape(2, 16, 256)
+    split = jax.jit(lambda v: [p.astype(BF16).astype(F32) for p in
+                               P.float32_parts(v, BF16,
+                                               PG.residual_parts(BF16),
+                                               in_kernel=in_kernel)])
+    hi, lo = (np.asarray(p) for p in split(jnp.asarray(R)))
     assert np.array_equal(hi, np.asarray(
         jnp.asarray(R).astype(BF16).astype(F32)))
     assert (lo != 0).mean() > 0.95
     assert (np.abs(lo + hi - R) <= np.abs(R) * 2.0 ** -17).all()
     assert (np.abs(hi - R) > np.abs(R) * 2.0 ** -11).mean() > 0.5
-    three = np.asarray(PG.float32_parts(jnp.asarray(R), BF16).astype(F32))
-    assert np.array_equal(three[:32], np.concatenate([hi, lo]))
-    assert np.array_equal(np.asarray(PG.float32_parts(
-        jnp.asarray(R), F32, PG.residual_parts(F32))), R)
+    three = np.asarray(P.float32_parts(jnp.asarray(R), BF16))
+    assert np.array_equal(three[:2], np.stack([hi, lo]))
+    (whole,) = P.float32_parts(jnp.asarray(R), F32, PG.residual_parts(F32))
+    assert np.array_equal(np.asarray(whole), R)
 
 
 # -- the residual's precision -------------------------------------------------
@@ -377,7 +384,7 @@ def test_gradient_sees_the_float32_residual_in_both_bodies(
     n, d, Lb, live, loss, n_pad = case
     args = _shared_rows(n, d, Lb, live, seed=n + d)
     want = _gradient_f64(*args[:7])
-    split = PG.float32_parts
+    split = P.float32_parts
 
     def one_part_of_R(V, dtype, parts=None, **kw):
         """The XLA body's R arrives [lanes, rows of a block] with every
@@ -397,7 +404,7 @@ def test_gradient_sees_the_float32_residual_in_both_bodies(
             _blocks.clear_cache()
     got = run()
     with monkeypatch.context() as m:
-        m.setattr(PG, "float32_parts", one_part_of_R)
+        m.setattr(P, "float32_parts", one_part_of_R)
         m.setattr(PG, "residual_parts", lambda dtype: 1)
         one_part = run()
     assert _rel(got[0] + got[4], want) <= 2.0 ** (
@@ -806,13 +813,13 @@ def test_the_xla_body_takes_the_whole_residual(monkeypatch):
     assert info["lanes_at_cap"] == 0 and info["data_passes"] <= 14
     assert (st["delta"] <= TOL).all()
     assert ((np.asarray(st["B"]) != 0).sum(1) <= 4).all()
-    split, d = PG.float32_parts, X.shape[1]
+    split, d = P.float32_parts, X.shape[1]
 
     def two_parts_of_R(V, dtype, parts=None, **kw):
         """R arrives [lanes, rows of a block] with every part asked for."""
         whole = parts is None and V.shape[1] != d
         return split(V, dtype, 2 if whole else parts, **kw)
-    monkeypatch.setattr(PG, "float32_parts", two_parts_of_R)
+    monkeypatch.setattr(P, "float32_parts", two_parts_of_R)
     GS.sweep_glm_round.clear_cache()
     try:
         info_2, st_2 = _l1_sweep(X, y, masks, *grid)

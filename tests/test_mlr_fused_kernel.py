@@ -37,8 +37,7 @@ def _problem(n, d, K, Lb, dtype, seed=0):
     B = (rng.normal(size=(Lb, d, K)) * 0.3).astype(np.float32)
     b0 = rng.normal(size=(Lb, K)).astype(np.float32)
     Xd = jnp.asarray(X).astype(dtype)
-    hi, lo = GS._split_low(
-        jnp.asarray(B).transpose(0, 2, 1).reshape(Lb * K, d), dtype)
+    hi, lo = GS._mlr_coefficient_parts(jnp.asarray(B), dtype)
     tail = (jnp.asarray(sel), hi, lo, jnp.asarray(b0),
             jnp.asarray(X.mean(0)), jnp.asarray(1.0 / X.std(0)))
     return Xd, jnp.asarray(y), jnp.asarray(w), jnp.asarray(masks), tail

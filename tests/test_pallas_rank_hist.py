@@ -17,6 +17,7 @@ from transmogrifai_tpu.models.glm import OpLogisticRegression
 from transmogrifai_tpu.ops import metrics_ops as M
 from transmogrifai_tpu.ops import pallas_hist as PH
 from transmogrifai_tpu.ops import pallas_rank_hist as RH
+from transmogrifai_tpu.ops import parts as P
 
 
 def _operands(rng, F, n_folds, n_slots, C, n_bins, N, unit):
@@ -90,16 +91,18 @@ def test_derived_count_channel():
 
 
 def test_bf16_cuts_sum_to_the_float32():
+    """The payload's cuts as the kernel's body makes them (ops/parts.py,
+    `in_kernel`); with ONE part the payload itself: no operation."""
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 256))
                     * np.logspace(-6, 6, 256), jnp.float32)
-    cuts = RH._bf16_cuts(x, 3)
+    cuts = P.float32_parts(x, jnp.bfloat16, 3, in_kernel=True)
     for c in cuts:      # each part survives bfloat16 unchanged
         np.testing.assert_array_equal(
             np.asarray(c.astype(jnp.bfloat16).astype(jnp.float32)),
             np.asarray(c))
     np.testing.assert_array_equal(np.asarray(cuts[0] + cuts[1] + cuts[2]),
                                   np.asarray(x))
-    assert RH._bf16_cuts(x, 1)[0] is x
+    assert P.float32_parts(x, jnp.bfloat16, 1, in_kernel=True)[0] is x
 
 
 @pytest.mark.parametrize("allow_bf16,flag,n_bins,body", [

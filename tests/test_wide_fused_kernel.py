@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from transmogrifai_tpu.ops import glm_sweep as GS
 from transmogrifai_tpu.ops import pallas_hist
 from transmogrifai_tpu.ops import pallas_wide as PW
+from transmogrifai_tpu.ops import parts as P
 from transmogrifai_tpu.utils.metrics import collector
 
 FOLDS = 3
@@ -54,7 +55,9 @@ def _assert_same_sums(got, ref):
 
 def _fused(X, y, w, masks, sel, B, b0):
     return PW.wide_gradient(X.T, PW.side_rows(y, w, masks), sel,
-                            *GS._two_parts(B, X.dtype), b0, interpret=True)
+                            *(p.astype(X.dtype) for p in
+                              P.float32_parts(B, X.dtype, 2)),
+                            b0, interpret=True)
 
 
 @pytest.fixture

@@ -359,12 +359,12 @@ def _heldout_regression(X, y, vw, masks, Bc, b0c, metric, allreduce):
     over the mesh axis: the label's [F, 2], then the [F, 4, Gc] sums.
 
     The contraction sees the coefficients at float32 precision, as their
-    exact parts of X's dtype (`pallas_glm.float32_parts`; a float32
+    exact parts of X's dtype (`ops/parts.float32_parts`; a float32
     matrix contracts at HIGHEST): rounded to bfloat16 they shift EVERY
     prediction by sum(delta_j mean_j) on columns that are not centred,
     which adds to the squared error in full."""
     from ...ops import glm_sweep as GS
-    from ...ops import pallas_glm as PG
+    from ...ops import parts as P
     f32 = jnp.float32
     F, Gc, d = Bc.shape
     n = X.shape[0]
@@ -375,7 +375,7 @@ def _heldout_regression(X, y, vw, masks, Bc, b0c, metric, allreduce):
                     jnp.where(h, vw * y, 0.0).sum()]) for h in held]))
     sw = jnp.maximum(label[:, 0], M.EPS)                        # [F]
     pivot = jnp.append(label[:, 1] / sw, 0.0)                   # [F + 1]
-    parts = PG.float32_parts(Bc.reshape(F * Gc, d), X.dtype)
+    parts = P.stacked_parts(Bc.reshape(F * Gc, d), X.dtype)
     precision = jax.lax.Precision.HIGHEST \
         if X.dtype == jnp.float32 else None
     c = GS._mlr_row_block(parts.shape[0], n)
@@ -384,9 +384,9 @@ def _heldout_regression(X, y, vw, masks, Bc, b0c, metric, allreduce):
 
     def body(i, sums):
         f_blk, fresh, y_blk, w_blk = take(i)
-        s = (PG.slab_sum(jnp.matmul(x_block(i), parts.T, precision=precision,
-                                    preferred_element_type=f32), F * Gc,
-                         axis=1) + b0c.reshape(1, F * Gc)).reshape(c, F, Gc)
+        s = (P.slab_sum(jnp.matmul(x_block(i), parts.T, precision=precision,
+                                   preferred_element_type=f32), F * Gc,
+                        axis=1) + b0c.reshape(1, F * Gc)).reshape(c, F, Gc)
         own = s[:, 0]
         for f in range(1, F):
             own = jnp.where(f_blk[:, None] == f, s[:, f], own)

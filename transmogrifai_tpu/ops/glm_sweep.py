@@ -31,7 +31,7 @@ every thread refitting against the same cached DataFrame):
   row blocks described next (`_moments_blocks`), the same sums;
 - lane etas in one MXU contraction `X_blk @ B.T` ([c, d] x [d, L]), B the
   float32 coefficients the iteration carries as their exact parts of the
-  matrix's dtype (`pallas_glm.float32_parts`: [d, 3 L] against a
+  matrix's dtype (`parts.float32_parts`: [d, 3 L] against a
   bfloat16 block): a step taken at coefficients rounded to that dtype
   never settles under `tol`, and every lane ran to `max_iter`; the
   gradient's sum takes the residual x weight as parts of that dtype by
@@ -128,6 +128,7 @@ import numpy as np
 
 from . import glm as G
 from . import pallas_glm, pallas_hist, pallas_softmax, pallas_wide
+from . import parts as _parts
 
 EPS = 1e-12
 
@@ -623,10 +624,10 @@ def _raw_sums(X, y, w, fold_masks, pivot, parts: int,
         else:
             v = (wlf[:, :, None] * x.astype(f32)[:, None, :]
                  ).reshape(c, F * d)
-            xw = pallas_glm.float32_parts(v.T, bf).T
+            xw = _parts.stacked_parts(v.T, bf).T
         gA = _pair_add_parts(gA, jax.lax.dot_general(
             x, xw, over_rows, preferred_element_type=f32), F * d, 1)
-        u = pallas_glm.float32_parts(
+        u = _parts.stacked_parts(
             jnp.concatenate([wlf, wy], axis=1).T, bf)   # [3 x 2 F, c]
         m = jnp.einsum('psk,skd->spd', u.reshape(-1, c // k, k),
                        x.reshape(c // k, k, d),
@@ -650,7 +651,7 @@ def _scale_only_weights(fold_masks, w):
     """Device bool: every fold weight m_f w is zero or a power of two, so
     that its product with a bfloat16 value is a bfloat16 value. The cut is
     `reduce_precision`'s: a cast and back can be fused away on the chip
-    (`pallas_glm.float32_parts`)."""
+    (ops/parts.py)."""
     wf = fold_masks * w[None, :]
     return (jax.lax.reduce_precision(wf, exponent_bits=8, mantissa_bits=0)
             == wf).all()
@@ -986,7 +987,7 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
     block's dtype as it does there: it shapes the step, not its fixed point).
     B [Lb, d] is the coefficients, float32 as the iteration carries them:
     the margins see them unrounded, as the fused pass's do and by the same
-    split (`pallas_glm.float32_parts`: the three-part product against a
+    split (`parts.float32_parts`: the three-part product against a
     bfloat16 block, which keeps the matrix unit's bfloat16 path; a float32
     block has one part and contracts as it did) — a step taken at rounded
     coefficients never settles under tol. The gradient's sum takes the
@@ -1006,7 +1007,7 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
     tiled, _, bt, tile_pairs = _tiling(d_work)
     hess_blocks = _gram_fns(tiled, d_work, Lb, bt, tile_pairs)[0]
     dtype = blocks[0].dtype
-    Bparts = pallas_glm.float32_parts(B, dtype).T
+    Bparts = _parts.stacked_parts(B, dtype).T
 
     def body(acc, sl):
         x_blk, y_blk, w_blk, m_blk = sl             # m_blk [F, c]
@@ -1016,7 +1017,7 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
         # materialized-Xs route
         xs_low = ((x_blk.astype(jnp.float32) - mean[None, :])
                   / std[None, :]).astype(x_blk.dtype)
-        eta = pallas_glm.slab_sum(
+        eta = _parts.slab_sum(
             jnp.matmul(xs_low, Bparts, preferred_element_type=jnp.float32),
             Lb, axis=1) + b0[None, :]
         r0, s0 = rc(eta, y_blk[:, None])            # [c, Lb]
@@ -1026,8 +1027,8 @@ def _moments_blocks(blocks, sel, B, b0, mean, std, *, loss,
         R = r0 * wl
         S = s0 * wl
         xf = xs_low.astype(jnp.float32)
-        gA = gA + pallas_glm.slab_sum(jnp.matmul(
-            xs_low.T, pallas_glm.float32_parts(R.T, dtype).T,
+        gA = gA + _parts.slab_sum(jnp.matmul(
+            xs_low.T, _parts.stacked_parts(R.T, dtype).T,
             preferred_element_type=jnp.float32), Lb, axis=1).T
         hA = hA + hess_blocks(xf, S)
         cA = cA + jnp.matmul(S.T.astype(dtype), xs_low,
@@ -1673,12 +1674,12 @@ def sweep_scores_fold(X: jax.Array, B_f: jax.Array, b0_f: jax.Array,
     sum over thousands of products, and coefficients rounded to bf16 would
     move it by more than the fit resolves. `exact`: the contraction sees
     the float32 coefficients, as their exact parts of X's dtype
-    (`pallas_glm.float32_parts`; a float32 matrix at HIGHEST) — for a
+    (`parts.float32_parts`; a float32 matrix at HIGHEST) — for a
     metric that is not invariant to their rounding (regression: the
     held-out pass's `validators._heldout_regression` takes the same)."""
     if exact:
-        parts = pallas_glm.float32_parts(B_f, X.dtype)
-        return pallas_glm.slab_sum(jnp.matmul(
+        parts = _parts.stacked_parts(B_f, X.dtype)
+        return _parts.slab_sum(jnp.matmul(
             X, parts.T, preferred_element_type=jnp.float32,
             precision=_HIGHEST if X.dtype == jnp.float32 else None),
             B_f.shape[0], axis=1) + b0_f[None, :]
@@ -1735,18 +1736,6 @@ def streamed_mlr_route_ok(d: int, lanes: int, n_classes: int,
         <= budget_bytes
 
 
-def _split_low(B, dtype):
-    """(hi, lo) parts of f32 coefficients for a contraction against a
-    `dtype` matrix: bf16 X keeps the MXU's bf16 path, and the second part
-    gives back what rounding B to bf16 would lose (4e-3 relative, the same
-    for every row, so it would shift the fixed point). lo is None for an
-    f32 matrix."""
-    hi = B.astype(dtype)
-    if jnp.dtype(dtype) == jnp.float32:
-        return hi, None
-    return hi, (B - hi.astype(jnp.float32)).astype(dtype)
-
-
 def _mlr_blocks(n: int, c: int, XT, *rows):
     """(number of row blocks, take(i)) over the resident matrix WITHOUT a
     padded, reshaped or re-laid-out copy of it. XT is X.T [d, n]: on the
@@ -1784,6 +1773,20 @@ def x_row_blocks(X, c: int):
             return jax.lax.dynamic_slice_in_dim(X, start, c, axis=0)
         return jax.lax.dynamic_slice_in_dim(XT, start, c, axis=1).T
     return block
+
+
+def _mlr_coefficient_parts(B, dtype):
+    """(Bt_hi, Bt_lo) [lanes * K, d] in `dtype`: the multinomial
+    coefficients B [lanes, d, K], float32, as their two leading parts of the
+    matrix's dtype (`parts.float32_parts`). A bfloat16 X keeps the matrix
+    unit's bfloat16 path, and the second part gives back what rounding B to
+    bfloat16 would lose (4e-3 relative, the same for every row, so it would
+    shift the fixed point). Bt_lo is None for a float32 matrix, which has
+    one part."""
+    lanes, d, K = B.shape
+    cut = _parts.float32_parts(B.transpose(0, 2, 1).reshape(lanes * K, d),
+                               dtype, 2)
+    return (*(p.astype(dtype) for p in cut), None)[:2]
 
 
 def mlr_logits_t(xT, Bt_hi, Bt_lo):
@@ -1981,8 +1984,7 @@ def _mlr_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean, std,
         y_rows, w_rows = (pallas_softmax.dense_rows(v) for v in (y, w))
 
     def accumulate(B, b0):
-        Bt_hi, Bt_lo = _split_low(
-            B.transpose(0, 2, 1).reshape(Lb * K, d), X.dtype)
+        Bt_hi, Bt_lo = _mlr_coefficient_parts(B, X.dtype)
         if fused:
             gA, g0A = pallas_softmax.mlr_gradient(
                 X.T, y_rows, w_rows, fold_masks, sel, Bt_hi, Bt_lo, b0,
@@ -2126,10 +2128,9 @@ def sweep_logits_fold_t(xT: jax.Array, B_f: jax.Array, b0_f: jax.Array
                         ) -> jax.Array:
     """[Gc, K, c] f32 logits of one row block xT [d, c] under one fold's
     grid chunk of multinomial coefficients B_f [Gc, d, K], b0_f [Gc, K]."""
-    Gc, d, K = B_f.shape
-    hi, lo = _split_low(B_f.transpose(0, 2, 1).reshape(Gc * K, d),
-                        xT.dtype)
-    return mlr_logits_t(xT, hi, lo).reshape(Gc, K, -1) + b0_f[:, :, None]
+    Gc, _, K = B_f.shape
+    return mlr_logits_t(xT, *_mlr_coefficient_parts(B_f, xT.dtype)
+                        ).reshape(Gc, K, -1) + b0_f[:, :, None]
 
 
 # -- streamed wide route (binary logistic, d > TRI_MAX_D, one device) ---------
@@ -2216,33 +2217,20 @@ def wide_footprint_bytes(d: int, lanes: int) -> float:
         * 2 * Lb * 4.0 + 8.0 * Lb * dp * 4.0
 
 
-def _two_parts(B, dtype):
-    """_split_low for the wide route, with the high part cut by an explicit
-    `reduce_precision`: on the v5e the metric program's fused f32 -> bf16 ->
-    f32 round trip of a PARAMETER came back unrounded (excess precision is
-    allowed inside a fusion), the low part was zero, and the sweep scored
-    with coefficients rounded to bf16 (PERF.md, PR 29). lo is None for an
-    f32 matrix."""
-    if jnp.dtype(dtype) == jnp.float32:
-        return B.astype(dtype), None
-    hi = jax.lax.reduce_precision(B, exponent_bits=8, mantissa_bits=7)
-    return hi.astype(dtype), (B - hi).astype(dtype)
-
-
 def _wide_contract(A, xT, over_rows: bool = False):
     """float32 A against a block xT [d, c] of X.T (the matrix's dtype),
     accumulated in float32: A [k, d] -> the [k, c] margins A xT, or, with
     `over_rows`, A [k, c] -> the [k, d] moments A xT'. A bf16 matrix keeps
-    the MXU's bf16 path: A goes in as its two bf16 parts stacked
-    (`_two_parts`; one contraction of twice the height, which a 128-wide
-    MXU does for nothing while 2 k <= 128) and the halves are added."""
+    the MXU's bf16 path: A goes in as its two leading bf16 parts stacked
+    (`parts.stacked_parts`; one contraction of twice the height, which a
+    128-wide MXU does for nothing while 2 k <= 128) and the halves are
+    added. A float32 matrix has one part: A itself, at HIGHEST."""
     dims = (((1,), (1 if over_rows else 0,)), ((), ()))
-    hi, lo = _two_parts(A, xT.dtype)
-    if lo is None:
-        return jax.lax.dot_general(hi, xT, dims,
+    if _parts.n_parts(xT.dtype) == 1:
+        return jax.lax.dot_general(A.astype(xT.dtype), xT, dims,
                                    precision=jax.lax.Precision.HIGHEST)
     k = A.shape[0]
-    out = jax.lax.dot_general(jnp.concatenate([hi, lo], axis=0), xT, dims,
+    out = jax.lax.dot_general(_parts.stacked_parts(A, xT.dtype, 2), xT, dims,
                               preferred_element_type=jnp.float32)
     return out[:k] + out[k:]
 
@@ -2344,7 +2332,9 @@ def _wide_round_core(X, y, w, fold_masks, sel, l1, l2, B0, b00, mean,
         b0_raw = b0 - (Braw * mean[None, :]).sum(1)
         if fused:
             gA, g0A = pallas_wide.wide_gradient(
-                X.T, rows, sel, *_two_parts(Braw, X.dtype), b0_raw)
+                X.T, rows, sel, *(p.astype(X.dtype) for p in
+                                  _parts.float32_parts(Braw, X.dtype, 2)),
+                b0_raw)
         else:
             gA, g0A = _wide_gradient_blocks(X, y, w, fold_masks, sel, Braw,
                                             b0_raw)

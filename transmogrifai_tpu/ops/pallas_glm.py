@@ -21,8 +21,8 @@ Precision: the matrix unit's operands in the matrix's dtype, float32 sums;
 the intercept's two sums take residual and curvature unrounded. The
 standardised block and the curvature x weight x block are rounded to that
 dtype once. The two operands the iteration's fixed point hangs on are NOT:
-both go in as parts of the matrix's dtype (`float32_parts`), one above the
-other as ONE operand of the contraction that was there, the product's slabs
+both go in as parts of the matrix's dtype (`parts.float32_parts`), one above
+the other as ONE operand of the contraction that was there, the product's slabs
 added in float32 (`slab_sum`). The coefficients: the margins xs' B + b0 see
 the float32 B the iteration carries, as its exact split (three parts of
 bfloat16), the slabs added chunk by chunk. A Newton step taken at rounded
@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from . import pallas_hist
+from . import parts as _parts
 from .pallas_softmax import _round_up
 
 # Columns of a tile (rows of X) worked on at a time, chunks in one body of
@@ -104,11 +105,6 @@ def residual_curvature(loss: str):
     return rc
 
 
-def n_parts(dtype) -> int:
-    """Parts of `dtype` that hold a float32's 24 significant bits."""
-    return -(-24 // (jnp.finfo(dtype).nmant + 1))
-
-
 def _pack(dtype) -> int:
     """Rows of `dtype` in a sublane tile: a cast fills whole ones."""
     return 8 * 4 // jnp.dtype(dtype).itemsize
@@ -118,44 +114,7 @@ def residual_parts(dtype) -> int:
     """Parts of `dtype` the gradient's contraction takes of the residual x
     weight: the two leading ones where `dtype` needs more than one (16
     significant bits of bfloat16), the operand itself where it does not."""
-    return min(2, n_parts(dtype))
-
-
-def float32_parts(V, dtype, parts=None, *, in_kernel: bool = False):
-    """[parts x rows, cols] in `dtype`: the float32 operand V [rows, cols]
-    (the coefficients B [lanes, d]; the residual x weight R [lanes, chunk])
-    as its leading `parts` parts of `dtype`, the largest first and one above
-    the other; the default is as many as 24 significant bits take of
-    `dtype`'s (three of bfloat16, one of float32: V itself), and their
-    float32 sum is then V exactly; k parts of bfloat16 leave 2^-(8k + 1) |V|.
-    A product of a part with a `dtype` block is exact in float32, so the
-    contraction of the stack against a block, its slabs added (`slab_sum`),
-    is the float32 contraction to that much. A V that is exact in `dtype`
-    leaves every part after the first zero. Each part is cut by
-    `reduce_precision`: inside a fusion the chip may hand a float32 ->
-    bfloat16 -> float32 round trip back unrounded, and the next part would
-    be zero (PERF.md, PR 29). `in_kernel`: inside a Mosaic body, which has
-    no `reduce_precision` and fuses nothing away, the cast is the cut."""
-    info, rest, out = jnp.finfo(dtype), V.astype(jnp.float32), []
-    for _ in range(n_parts(dtype) if parts is None else parts):
-        part = rest.astype(dtype).astype(jnp.float32) if in_kernel else \
-            jax.lax.reduce_precision(rest, exponent_bits=info.nexp,
-                                     mantissa_bits=info.nmant)
-        out.append(part.astype(dtype))
-        rest = rest - part
-    return jnp.concatenate(out, axis=0)
-
-
-def slab_sum(stacked, lanes: int, axis: int = 0):
-    """The float32 sum of the slabs of `lanes` that a contraction of
-    `float32_parts`' stack leaves along `axis`, the smallest part's
-    first."""
-    slabs = [jax.lax.slice_in_dim(stacked, k, k + lanes, axis=axis)
-             for k in range(0, stacked.shape[axis], lanes)]
-    total = slabs[-1]
-    for slab in slabs[-2::-1]:
-        total = total + slab
-    return total
+    return min(2, _parts.n_parts(dtype))
 
 
 def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
@@ -205,8 +164,8 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
             x_ok = x_ok & feat_ok
         xs = jnp.where(x_ok, (xT_ref[:, cols].astype(f32) - mean) / std,
                        0.0).astype(dtype)                        # [dp, c]
-        eta = slab_sum(jnp.dot(bt, xs, preferred_element_type=f32),
-                       lanes) + b0                               # [L, c]
+        eta = _parts.slab_sum(
+            jnp.dot(bt, xs, preferred_element_type=f32), lanes) + b0  # [L, c]
         # y and w come dense, 128 rows of X a sublane (`dense_rows`)
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
@@ -238,9 +197,8 @@ def _kernel(xT_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref, mean_ref,
                 for V in (R, S))
         # under the residual's parts the curvature x weight, one part: its
         # slab of the product is sum_rows S xs', the Hessian's border
-        Rp = jnp.concatenate(
-            [float32_parts(Rw, dtype, residual_parts(dtype), in_kernel=True),
-             Sw.astype(dtype)], axis=0)
+        Rp = jnp.concatenate([V.astype(dtype) for V in _parts.float32_parts(
+            Rw, dtype, residual_parts(dtype), in_kernel=True) + [Sw]], axis=0)
         g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
             xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(
@@ -299,7 +257,7 @@ def vmem_bytes(d: int, lanes: int, dtype=jnp.bfloat16) -> int:
     return _UNROLL * (lp * dp + slabs * slab) * _CHUNK * item \
         + 2 * dp * (lp * dp + _round_up(slabs * slab, 128)) * 4 \
         + 2 * tile * (dp * item + 8 * 4 + 2 * 4) \
-        + 2 * n_parts(dtype) * lp * dp * item
+        + 2 * _parts.n_parts(dtype) * lp * dp * item
 
 
 def dense_rows(v, n_rows=None):
@@ -384,7 +342,7 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
         return by_rows(a.shape, lambda i: (0, 0))
     dense = by_rows((tile // 128, 128), lambda i: (i, 0))
     resident = (
-        float32_parts(
+        _parts.stacked_parts(
             jnp.pad(Bt, ((0, lp - lanes), (0, dp - d))), XT.dtype),
         jnp.pad(b0.astype(f32), (0, lp - lanes)).reshape(lp, 1),
         jnp.pad(sel.T.astype(f32), ((0, lp - lanes), (0, 0))),
@@ -417,7 +375,7 @@ def glm_moments(XT, y_rows, w_rows, fold_masks, sel, Bt, b0, mean, std, *,
     # last slab sum_c xs[j, c] S[lane, c]
     gA = g[:d, :lanes].T
     border = (slabs - 1) * slab
-    gA_low = slab_sum(g[:, slab:border], slab, axis=1)[:d, :lanes].T \
+    gA_low = _parts.slab_sum(g[:, slab:border], slab, axis=1)[:d, :lanes].T \
         if slabs > 2 else jnp.zeros_like(gA)
     return gA, hA[:lanes, :d, :d], g0.sum(axis=1)[:lanes], \
         h0.sum(axis=1)[:lanes], gA_low, g[:d, border:border + lanes].T
@@ -467,8 +425,8 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
             x_rows < left,
             (x_ref[pl.ds(off, chunk), :].astype(f32) - mean) / std,
             0.0).T.astype(dtype)                                  # [d, c]
-        eta = slab_sum(jnp.dot(bt, xs, preferred_element_type=f32),
-                       lanes) + b0                                # [L, c]
+        eta = _parts.slab_sum(
+            jnp.dot(bt, xs, preferred_element_type=f32), lanes) + b0  # [L, c]
         sub = pl.ds(pl.multiple_of(j * groups, groups), groups)
         y_row, w_row = (jnp.concatenate(
             [v[k:k + 1, :] for k in range(groups)], axis=1)
@@ -487,9 +445,8 @@ def _kernel_cols(x_ref, y_ref, w_ref, m_ref, bt_ref, b0_ref, selT_ref,
             Rw, Sw = (jnp.concatenate(
                 [V, jnp.zeros((pack - lanes % pack, chunk), f32)], axis=0)
                 for V in (R, S))
-        Rp = jnp.concatenate(
-            [float32_parts(Rw, dtype, residual_parts(dtype), in_kernel=True),
-             Sw.astype(dtype)], axis=0)
+        Rp = jnp.concatenate([V.astype(dtype) for V in _parts.float32_parts(
+            Rw, dtype, residual_parts(dtype), in_kernel=True) + [Sw]], axis=0)
         g_ref[:, 0:Rp.shape[0]] += jax.lax.dot_general(
             xs, Rp, over_rows, preferred_element_type=f32)
         h_ref[...] += jax.lax.dot_general(

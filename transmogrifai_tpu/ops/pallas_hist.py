@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.env import env_float, env_int
+from . import parts as _parts
 
 _BLK = 4096
 
@@ -316,7 +317,7 @@ def available() -> bool:
 # carry. A real-valued g row is rounded ONCE to bfloat16 (2^-9 of each
 # value's size) unless the CALLER asks for `payload_parts` = 3: then the
 # kernels cut the g rows into three bfloat16 parts whose sum is the
-# float32 value (_unit_cuts: every product exact) and issue 5 rows a
+# float32 value (parts.unit_cuts: every product exact) and issue 5 rows a
 # (lane, slot) where they issue 3. The cuts are FIXED-POINT for a payload
 # the caller has scaled into [-1, 1] (a power of two: exact): whole
 # multiples of 2^-7 and of 2^-15, whose float32 sums over millions of rows
@@ -376,7 +377,7 @@ def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts=1,
     hessian) — exactly grow_tree's count_unit, computed on the VPU
     instead of streamed as its own HBM plane. `parts` = 3 (a real-valued
     payload in bf16 mode): the channels before the last go as three
-    bfloat16 parts each (_unit_cuts), part-major, ahead of the last
+    bfloat16 parts each (parts.unit_cuts), part-major, ahead of the last
     channel and the count (payload_rows(C, parts, derive_count) rows).
     `classes` = K (a class label): the lane's two planes are [class id,
     weight] and the K class channels weight x (id == k) are built HERE,
@@ -394,25 +395,8 @@ def _fold_payload(pay_ref, k, C, mxu_dtype, derive_count, parts=1,
         pay = jnp.concatenate([pay, cnt], axis=0)           # [C+1, blk]
     if parts == 3:
         pay = jnp.concatenate(
-            _unit_cuts(pay[:C - 1, :]) + [pay[C - 1:, :]], axis=0)
+            _parts.unit_cuts(pay[:C - 1, :]) + [pay[C - 1:, :]], axis=0)
     return pay.astype(mxu_dtype)
-
-
-def _unit_cuts(x):
-    """float32 x as three float32 arrays whose sum is x, each exact in
-    bfloat16 while |x| <= 1: the nearest multiple of 2^-7 (a whole number
-    of at most eight bits over 128), the nearest multiple of 2^-15 of what
-    is left (the same over 2^15), and the rest, under 2^-16, which the cast
-    rounds at 2^-25. Sums of the first two over millions of rows are whole
-    numbers under 2^24 in their own units: float32 adds them exactly. Past
-    |x| = 1 the casts round the first two as well and the three still hold
-    x's 24 bits. floor(. + 0.5), not round: Mosaic lowers it everywhere."""
-    hi = jnp.floor(x * 128.0 + 0.5) * (1.0 / 128.0)
-    hi = hi.astype(jnp.bfloat16).astype(jnp.float32)
-    rest = x - hi
-    mid = jnp.floor(rest * 32768.0 + 0.5) * (1.0 / 32768.0)
-    mid = mid.astype(jnp.bfloat16).astype(jnp.float32)
-    return [hi, mid, rest - mid]
 
 
 def payload_rows(C: int, parts: int, derive_count: bool,
@@ -514,8 +498,8 @@ def hist_pallas(Xb_t: jax.Array, pay_t: jax.Array, slot_t: jax.Array,
     flag agrees (_HIST_BF16) — the tree fits take it: one-hots, counts and
     integer payload rows under 256 exact, a real-valued row rounded once to
     bfloat16 unless `payload_parts` = 3 cuts the channels before the last
-    into three exact bfloat16 parts (_unit_cuts: fixed-point for values the
-    caller scaled into [-1, 1], their sums exact; the parts are summed
+    into three exact bfloat16 parts (parts.unit_cuts: fixed-point for values
+    the caller scaled into [-1, 1], their sums exact; the parts are summed
     here, the layout stays Co rows a slot); the rank metrics keep f32
     weights. Resolved OUTSIDE the jit, so set_hist_bf16 cannot serve
     stale-dtype programs.
@@ -1044,17 +1028,6 @@ def _route_hist_jnp(Xb_t, pay_t, node_t, f_lvl, t_lvl, m_lvl, *, n_nodes,
 _LOOKUP_ROWS = 16   # a lane's lhs tile: one bf16 sublane tile, 3 rows used
 
 
-def _three_parts(t: jax.Array):
-    """f32 -> (hi, mid, lo) bf16 with hi + mid + lo == t exactly. The
-    cuts are lax.reduce_precision, not an astype round trip: fused, that
-    came back unrounded for a program parameter on the v5e (PERF.md §6,
-    PR 29)."""
-    hi = jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
-    rest = t - hi
-    mid = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
-    return [p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid)]
-
-
 def _lookup_kernel(tbl_ref, idx_ref, out_ref, *, m_r, n_folds):
     blk = idx_ref.shape[1]
     mi = jax.lax.broadcasted_iota(jnp.int32, (m_r, blk), 0) \
@@ -1090,7 +1063,8 @@ def table_lookup_pallas(tbl: jax.Array, idx_t: jax.Array, *,
     N = idx_t.shape[1]
     n_orig = N
     m_r = node_rows(M, 2)
-    parts = jnp.stack(_three_parts(tbl.astype(jnp.float32)), axis=1)
+    parts = jnp.stack([p.astype(jnp.bfloat16) for p in
+                       _parts.float32_parts(tbl, jnp.bfloat16)], axis=1)
     tblp = jnp.pad(parts, ((0, 0), (0, _LOOKUP_ROWS - 3), (0, m_r - M))) \
         .reshape(Fo * _LOOKUP_ROWS, m_r)                    # [16 Fo, m_r]
     blk = _ROUTE_BLK

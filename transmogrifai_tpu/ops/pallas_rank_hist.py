@@ -16,10 +16,11 @@ one-hot, and ONE contraction over the block's rows of that left operand
 against the [128, blk] `lo` one-hot, which fills the array's 128 columns.
 
 Both one-hots are exact in bfloat16; the payload keeps its float32 value
-as `parts` bfloat16 parts whose sum it is (three cuts of eight significant
-bits), stacked in the left operand, every product exact, accumulation
-float32. A payload of zeros and ones (unit weights under 0/1 masks and
-labels) IS its first part, and the caller that can vouch for it says so.
+as `parts` bfloat16 parts whose sum it is (ops/parts.float32_parts: three
+hold its 24 bits), stacked in the left operand, every product exact,
+accumulation float32. A payload of zeros and ones (unit weights under 0/1
+masks and labels) IS its first part, and the caller that can vouch for it
+says so.
 
 Same contract as pallas_hist.hist_pallas, which dispatches here (hist_body)
 and stays the one entry. Kept apart from ops/pallas_hist.py on purpose: a
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from . import pallas_hist
+from . import parts as _parts
 
 # bins of the `lo` one-hot, the contraction's right operand: the array's
 # 128 columns. Probed on the v5e at the sweep's two forms (25M rows x 6 grid
@@ -73,23 +75,6 @@ def payload_parts(unit_payload: bool) -> int:
     return 1 if unit_payload else 3
 
 
-def _bf16_cuts(x, parts: int):
-    """float32 x as `parts` float32 arrays, each exact in bfloat16, whose
-    sum is x: cuts of the top eight significant bits by a mask (round to
-    zero, so what is left keeps its sign and loses eight bits a cut; three
-    cuts hold all 24). The last takes what is left whole: with one part x
-    itself, which the caller vouches is exact. (pallas_hist._three_parts
-    cuts by lax.reduce_precision, which Mosaic does not lower.)"""
-    cuts, rest = [], x
-    for _ in range(parts - 1):
-        bits = jax.lax.bitcast_convert_type(rest, jnp.int32)
-        top = jax.lax.bitcast_convert_type(bits & jnp.int32(-65536),
-                                           jnp.float32)
-        cuts.append(top)
-        rest = rest - top
-    return cuts + [rest]
-
-
 def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, C, n_slots, n_folds,
             hi_rows, parts, derive_count):
     import jax.experimental.pallas as pl
@@ -110,7 +95,10 @@ def _kernel(xb_ref, pay_ref, slot_ref, out_ref, *, F, C, n_slots, n_folds,
     for k in range(n_folds):
         slot_oh = (slots == slot_ref[k:k + 1, :]).astype(f32)  # [S, blk]
         pay = pallas_hist._fold_payload(pay_ref, k, C, f32, derive_count)
-        for p, cut in enumerate(_bf16_cuts(pay, parts)):       # [Co, blk]
+        # with one part the payload itself, which the caller vouches is
+        # exact in bfloat16: the cut adds no operation to the body
+        for p, cut in enumerate(_parts.float32_parts(
+                pay, jnp.bfloat16, parts, in_kernel=True)):    # [Co, blk]
             cuts[p].append((slot_oh[:, None, :] * cut[None, :, :])
                            .reshape(n_slots * Co, blk))
     pieces = [piece for part in cuts for piece in part]

@@ -157,7 +157,7 @@ def mlr_gradient(XT, y_rows, w_rows, fold_masks, sel, Bt_hi, Bt_lo, b0, mean,
     (no padded or re-laid-out copy is made of it: the last tile reads past n
     and masks); y_rows, w_rows are `dense_rows` of y and w; fold_masks
     [F, n]; sel [F, lanes] maps lanes to folds; Bt_hi, Bt_lo [lanes * K, d]
-    are `_split_low`'s two parts of the coefficients (Bt_lo None for a
+    are `glm_sweep._mlr_coefficient_parts`' two parts (Bt_lo None for a
     float32 matrix); b0 [lanes, K]; mean, inv_std [d]. Classes pad to whole
     sublane tiles with their logits at -inf and features to whole tiles
     with zero columns; both pads are cut from what is returned."""

@@ -148,7 +148,7 @@ def wide_gradient(XT, rows, sel, B_hi, B_lo, b0, *, interpret: bool = False):
     XT [d, n] is X.T, a bfloat16 matrix in the layout it already has on
     the chip at such a width (no padded or re-laid-out copy is made of it: the
     last tile reads past n and masks); rows is `side_rows`; sel [F, lanes]
-    maps lanes to folds; B_hi, B_lo [lanes, d] are `glm_sweep._two_parts`
+    maps lanes to folds; B_hi, B_lo [lanes, d] are `parts.float32_parts`' two
     of the coefficients in the matrix's dtype; b0 [lanes]."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu

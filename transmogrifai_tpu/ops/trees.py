@@ -1486,7 +1486,7 @@ forest_payload_rows = payload_rows
 
 def _residual_scale(g: jax.Array) -> jax.Array:
     """[Fo] the power of two just over each lane's largest |g| (g [Fo, N]
-    float32): g / scale lies inside (-1, 1), where pallas_hist._unit_cuts'
+    float32): g / scale lies inside (-1, 1), where ops/parts.unit_cuts'
     parts are fixed-point and their sums exact. Read off the float32's own
     exponent field — no log2 / exp2 whose last bit a backend may round —
     so it IS a power of two and the division and the sums' way back are
@@ -1721,7 +1721,7 @@ def forest_bootstrap(key: jax.Array, start, subsample, *, n_rows: int,
 
 #: the largest bootstrap draw the payload's scale leaves room for (a
 #: Poisson(1) draw passes it once in ~10^14; past it the parts round, as
-#: pallas_hist._unit_cuts says)
+#: ops/parts.unit_cuts says)
 _PAYLOAD_DRAW_ROOM = 16.0
 
 

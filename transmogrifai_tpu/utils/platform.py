@@ -228,6 +228,18 @@ def enable_compilation_cache() -> None:
                           _CACHE_MAX_BYTES)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # A Mosaic body is serialised into its custom call WITH its operations'
+    # locations, by default the ten innermost frames of the stack that
+    # traced them, so an edit that moved a line of a CALLER (validators.py,
+    # glm_sweep.py, models/trees.py) recompiled every kernel under it once
+    # a machine, two minutes a tree program (ROADMAP.md S2 (1)). ONE frame,
+    # the operation's own: the checkout's path is still in a kernel's key,
+    # and so are the lines of the kernel's own file. (Not
+    # `jax_include_full_tracebacks_in_locations` off, which also drops the
+    # frame's FUNCTION name, and the chip's compiler names a Mosaic custom
+    # call after it: `glm_moments.4`, `_route_hist_pallas_jit.9` would read
+    # `tpu_custom_call.N` in every trace — PERF.md §6, PR 56.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     if _cache_dir != loc:
         _cache_dir = loc
         _log.info("persistent compile cache: ACTIVE at %s", loc)
